@@ -41,8 +41,8 @@ train-smoke:
 # Training-step A/B matrix: sweeps scan x chunk x remat x donation x
 # depth through bench.py's worker gang (fresh chip state per row) and
 # writes per-config rows + the machine-picked winners to BENCH_AB.json
-# (tokens/s, MFU, peak HBM, allocator fragmentation). On a TPU host run
-# WITHOUT JAX_PLATFORMS=cpu.
+# (tokens/s, MFU, peak HBM, allocator fragmentation). Needs the chip:
+# bench.py has no CPU mode.
 perf-train:
 	RAY_TPU_BENCH_AB=1 $(PY) bench.py
 
@@ -165,7 +165,7 @@ native-test: build/rts_store_test build/rts_pump_test native-tsan native-asan na
 	./build/rts_pump_test
 
 clean:
-	rm -rf build $(EXT) $(PUMP_EXT)
+	rm -rf build $(EXT) $(PUMP_EXT) .jax_cache
 
 # Sanitizer builds of the C++ unit tests (ref analogue: the reference's
 # TSAN/ASAN CI jobs over the C++ core). `make native-tsan native-asan`

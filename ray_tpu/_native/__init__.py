@@ -9,7 +9,7 @@ Two CPython extensions are built in-place by the repo Makefile:
 On first import, if a .so is missing and a toolchain is available, we build
 on demand; callers fall back to the pure-Python implementations when a
 native module is unavailable, so the framework works (slower) on machines
-without g++. ``RAY_TPU_NO_NATIVE_BUILD=1`` suppresses the on-demand build;
+without g++ — a failed build says so on stderr. ``RAY_TPU_NO_NATIVE_BUILD=1`` suppresses the on-demand build;
 ``RTPU_NO_NATIVE=1`` makes the frame-pump callers ignore the extension even
 when present (see core/frame_pump.py).
 """
@@ -37,19 +37,37 @@ def _try_import(name: str):
         return None
 
 
+def build(timeout: float = 300.0) -> "subprocess.CompletedProcess":
+    """``make native`` from the tracked sources (the Makefile rebuilds an
+    extension whose sources are newer). Raises FileNotFoundError where
+    there is no ``make``; the caller reads the return code."""
+    return subprocess.run(
+        ["make", "-C", _REPO_ROOT, "native", f"PY={sys.executable}"],
+        capture_output=True,
+        text=True,
+        timeout=timeout,
+    )
+
+
 def _try_build() -> bool:
-    makefile = os.path.join(_REPO_ROOT, "Makefile")
-    if not os.path.exists(makefile):
+    if not os.path.exists(os.path.join(_REPO_ROOT, "Makefile")):
         return False
     try:
-        proc = subprocess.run(
-            ["make", "-C", _REPO_ROOT, "native", f"PY={sys.executable}"],
-            capture_output=True,
-            timeout=120,
-        )
-        return proc.returncode == 0
-    except Exception:
-        return False
+        proc = build(timeout=120)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        detail = repr(e)
+    else:
+        if proc.returncode == 0:
+            return True
+        detail = (proc.stderr or proc.stdout)[-400:]
+    # The pure-Python store and pump are slower, not wrong, so this is
+    # not fatal; it must not be silent either.
+    print(
+        f"[ray_tpu._native] WARNING: `make native` failed, falling back "
+        f"to the pure-Python store and pump: {detail}",
+        file=sys.stderr,
+    )
+    return False
 
 
 def _load(name: str):

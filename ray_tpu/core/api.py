@@ -23,6 +23,7 @@ from .node_manager import NodeManager
 from .reference import ObjectRef
 from .remote_function import RemoteFunction
 from .runtime import DriverRuntime
+from .tpu import local_chip_count, node_tpu_labels
 from . import runtime_context
 
 
@@ -87,7 +88,7 @@ def init(
     if num_tpus is not None:
         res["TPU"] = num_tpus
     elif address is None:
-        detected = _detect_tpu_chips()
+        detected = local_chip_count()
         if detected:
             res.setdefault("TPU", detected)
 
@@ -97,8 +98,6 @@ def init(
         f"session-{int(time.time())}-{uuid.uuid4().hex[:8]}",
     )
     os.makedirs(session_dir, exist_ok=True)
-
-    from .tpu import node_tpu_labels
 
     node_id = NodeID.from_random()
     gcs_address = None
@@ -128,17 +127,6 @@ def init(
         rt.log_monitor.start()
     atexit.register(_atexit_shutdown)
     return rt
-
-
-def _detect_tpu_chips() -> int:
-    """Count local TPU chips without importing jax (ref analogue:
-    _private/accelerators/tpu.py device detection)."""
-    try:
-        import glob
-
-        return len(glob.glob("/dev/accel*")) or len(glob.glob("/dev/vfio/*"))
-    except Exception:
-        return 0
 
 
 def _atexit_shutdown():

@@ -1,6 +1,6 @@
 """Exception types surfaced by the public API.
 
-Mirrors the reference's exception taxonomy (ref: python/ray/exceptions.py —
+Mirrors the reference's exception hierarchy (ref: python/ray/exceptions.py —
 RayTaskError, RayActorError, WorkerCrashedError, GetTimeoutError,
 TaskCancelledError, ObjectLostError, ObjectStoreFullError).
 """

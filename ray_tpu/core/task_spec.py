@@ -44,7 +44,7 @@ class TaskSpec:
     """``slots=True`` across spec/arg records: a 1M-deep task queue holds
     one of each per task, and their per-instance ``__dict__``s were a
     leading slice of the 4.4 GB driver RSS the r5 envelope probe
-    measured (PERF_r05.json)."""
+    measured."""
 
     task_id: TaskID
     task_type: TaskType

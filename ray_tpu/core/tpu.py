@@ -4,7 +4,9 @@ Ref analogue: python/ray/_private/accelerators/tpu.py:22-56 — the reference
 detects TPU pods/slices from GCE metadata + env vars (``TPU_NAME``,
 ``TPU_WORKER_ID``, ``TPU_ACCELERATOR_TYPE``, ``TPU_WORKER_HOSTNAMES``) and
 isolates chips with ``TPU_VISIBLE_CHIPS``, but stops at a flat ``"TPU"``
-resource. Here slice membership becomes *node labels* so the scheduler can
+resource. (No per-worker chip isolation exists here yet — ROADMAP R6:
+one ``tpu`` worker process owns every chip of its host.) Here slice
+membership becomes *node labels* so the scheduler can
 gang-place one bundle per host of a slice (ICI-topology-aware placement,
 SURVEY.md §7 phase 5 — the framework's north star).
 
@@ -18,8 +20,17 @@ from __future__ import annotations
 
 import glob
 import os
+import sys
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List, Mapping, Optional
+
+_DEV = "/dev"
+_REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# One fixed, git-ignored directory in the checkout (`make clean` drops it).
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 # Node-label keys published by every TPU host (ref analogue: the reference's
 # ray.io/accelerator-type label plus the slice fields its tpu.py discovers).
@@ -59,17 +70,57 @@ class TpuSliceInfo:
 
 def local_chip_count() -> int:
     """Count local TPU chips without importing jax (device files first,
-    ref analogue: accelerators/tpu.py device detection)."""
+    ref analogue: accelerators/tpu.py device detection). The one chip
+    detector: ``ray_tpu.init`` advertises this many ``TPU`` and slice
+    discovery falls back to it. ``/dev/vfio/vfio`` is the VFIO control
+    node, not a chip, hence the ``[0-9]*`` form."""
     override = os.environ.get("TPU_CHIPS_PER_HOST_OVERRIDE")
     if override:
         try:
             return int(override)
         except ValueError:
             pass
-    n = len(glob.glob("/dev/accel*"))
+    n = len(glob.glob(os.path.join(_DEV, "accel*")))
     if n:
         return n
-    return len(glob.glob("/dev/vfio/[0-9]*"))
+    return len(glob.glob(os.path.join(_DEV, "vfio", "[0-9]*")))
+
+
+def require_driver_off_jax() -> None:
+    """For launchers whose ``tpu`` workers need the chip: a chip belongs
+    to one process, and a parent that has imported jax may hold it."""
+    if "jax" in sys.modules:
+        raise RuntimeError(
+            "this driver process imported jax: it would hold the chip "
+            "its tpu workers need"
+        )
+
+
+def worker_jax_env(worker_type: str, env: Mapping[str, str]
+                   ) -> Dict[str, str]:
+    """JAX placement for a spawned worker, given the parent's ``env``.
+
+    ``cpu`` workers never open the chip (it is exclusive to one
+    process). ``tpu`` workers inherit an explicit ``JAX_PLATFORMS``
+    (the test suite's ``cpu`` must reach them); with none set they are
+    pinned to ``tpu,cpu``, so a runtime that cannot open the chip is an
+    error at backend init, not a silent CPU run.
+
+    A ``JAX_COMPILATION_CACHE_DIR`` placed from outside is inherited as
+    it is. With none, a worker that may compile for the TPU gets
+    :data:`DEFAULT_COMPILE_CACHE_DIR` — never a session dir, pid or temp
+    name: a directory that moves between runs is never found again, and
+    every fresh gang then recompiles its ~20 s step. CPU-only workers
+    get none: XLA:CPU compiles here take about a second, and its loader
+    logs an error-level line of machine features for every cached
+    executable it reads back."""
+    if worker_type == "cpu":
+        out = {"JAX_PLATFORMS": "cpu"}
+    else:
+        out = {"JAX_PLATFORMS": env.get("JAX_PLATFORMS") or "tpu,cpu"}
+    if out["JAX_PLATFORMS"] != "cpu" and not env.get(COMPILE_CACHE_ENV):
+        out[COMPILE_CACHE_ENV] = DEFAULT_COMPILE_CACHE_DIR
+    return out
 
 
 def _generation(accelerator_type: str) -> str:

@@ -23,7 +23,7 @@ from .llama import LlamaConfig, rms_norm, rope
 # Paged decode attention implementation choice, read ONCE at import (it
 # is baked into the traced program — flipping the env after the first
 # compile has no effect): default is the XLA gather path, which measured
-# faster in the full decode step (PERF_r04 paged section).
+# faster in the full decode step on a v5e (before PR 1).
 import os as _os
 
 _USE_PAGED_KERNEL = _os.environ.get("RAY_TPU_PAGED_KERNEL") == "1"
@@ -229,7 +229,7 @@ def _attend_paged_xla(q, ck, cv, page_table, lengths, cfg):
 
 def _attend_paged(q, ck, cv, page_table, lengths, cfg):
     """Single-token decode over the paged pool. Default: the XLA gather
-    path — measured on chip (PERF_r04 paged section) its cost is bounded
+    path — measured on a v5e (before PR 1) its cost is bounded
     by the attention WINDOW (B * Pmax * page tokens), independent of
     pool size, and it edges out the Pallas page-walk kernel in the full
     decode step (2.04 vs 2.35 ms at pool=256 pages). The kernel
@@ -259,8 +259,8 @@ def _layer_paged_decode(cfg, lp, x, ck, cv, page_table, lengths,
     layer scan); page_ids/offsets [B] name each slot's write cell for
     this token (inactive slots scatter to id -1 → dropped).
 
-    Measured design note (PERF_r04): three structures were benchmarked
-    on the real chip for the step's pool traffic — (a) this scan over
+    Measured design note (before PR 1): three structures were benchmarked
+    on a v5e for the step's pool traffic — (a) this scan over
     per-layer slices, (b) an unrolled layer loop scattering/gathering
     the full [L, ...] pool with static layer indices + donation, and
     (c) the pre-head-major layout with a per-step pool transpose. (a)

@@ -21,13 +21,15 @@ framework owns its compute path (SURVEY.md §7 phase 7).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
-from ..parallel.sharding import with_logical_constraint
+from ..parallel.sharding import prune_spec, with_logical_constraint
 from ..parallel.mesh import mesh_axis_size
 from ..parallel.ring_attention import ring_attention
 from ..parallel.moe import moe_ffn
@@ -60,7 +62,7 @@ class LlamaConfig:
     # automatically the XLA einsum path off-TPU or for odd shapes.
     # On by default: with the fused Pallas backward (KV-head-grid dK/dV,
     # GQA reduced in-kernel) flash beats the XLA path for training too —
-    # 0.596 vs 0.532 MFU on the 8B-shaped bench (PERF_r04.json A/B).
+    # 0.596 vs 0.532 MFU on the 8B-shaped bench (v5e A/B, before PR 1).
     use_flash: bool = True
     # Cross-entropy sequence chunk: the loss streams over S/chunk slices
     # so the [B, S, V] float32 logits (4.3 GB at B=16, S=2k, V=32k — and
@@ -217,7 +219,18 @@ def _attention(cfg: LlamaConfig, mesh, q, k, v):
         from ..ops.flash_attention import flash_attention
 
         # Pallas kernel on TPU; transparently the XLA path elsewhere.
-        return flash_attention(q, k, v, causal=True)
+        attend = functools.partial(flash_attention, causal=True)
+        if mesh is not None:
+            # GSPMD cannot partition a Mosaic kernel ("wrap the call in
+            # a shard_map"): hand each device its (batch, heads) shard.
+            # GQA groups stay whole because heads and kv_heads split
+            # over the same tp axis in the same order.
+            spec = prune_spec(mesh, P(("dp", "fsdp"), None, "tp", None))
+            attend = jax.shard_map(
+                attend, mesh=mesh, in_specs=(spec, spec, spec),
+                out_specs=spec, check_vma=False,
+            )
+        return attend(q, k, v)
     return mha_attention(q, k, v, causal=True)
 
 

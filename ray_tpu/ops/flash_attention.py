@@ -486,7 +486,4 @@ def flash_attention(
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"

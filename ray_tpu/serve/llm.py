@@ -96,6 +96,7 @@ class LLMEngine:
         )
         self._jnp = jnp
         self._jax = jax
+        self._device = jax.devices()[0]
 
         self.cache = PagedKVCache.create(
             cfg, max_batch, self.total_pages, page_size,
@@ -185,6 +186,10 @@ class LLMEngine:
         self._decode.flush_taps()
         with self._lock:
             return {
+                # Where the engine's programs run: a rate read from
+                # these stats is a device number only on a "tpu".
+                "platform": self._device.platform,
+                "device_kind": self._device.device_kind,
                 "active_slots": len(self._slot_req),
                 "free_slots": len(self._slot_free),
                 "decode_steps": self._step_count,

@@ -3,7 +3,7 @@
 The bench's previous hot path composed the step in the train loop (a
 ``jax.value_and_grad`` + optax update jitted ad hoc per caller); the
 full-depth scan schedule OOM'd at 16.4 GB with 43-46% allocator
-fragmentation (PERF_r05 ab_matrix) because the stacked ``[L, ...]`` scan
+fragmentation (a v5e A/B before PR 1) because the stacked ``[L, ...]`` scan
 residuals plus host-staged init buffers shattered the HBM arena. This
 module is the single train-step authority (ROADMAP item 3):
 
@@ -186,15 +186,10 @@ class CompiledTrainStep:
     def compile_stats(self) -> Dict[str, Any]:
         """Executable-cache telemetry for this step (also published as
         ray_tpu_device_jit_* series through the KV metrics pipeline)."""
-        jitted = getattr(self._step, "__wrapped_jit__", None)
-        cache_size = getattr(jitted, "_cache_size", None)
-        out: Dict[str, Any] = {"fn": "train_step"}
-        if cache_size is not None:
-            try:
-                out["executables"] = int(cache_size())
-            except Exception:
-                out["executables"] = None
-        return out
+        return {
+            "fn": "train_step",
+            "executables": int(self._step.__wrapped_jit__._cache_size()),
+        }
 
     def memory_snapshot(self) -> Dict[str, Any]:
         """The HBM/allocator probe for the step's device: live + peak +

@@ -94,11 +94,8 @@ def node_tag() -> str:
 
 
 def _memory_stats(device) -> Optional[Dict[str, Any]]:
-    try:
-        stats = device.memory_stats()
-    except Exception:
-        return None
-    return stats if isinstance(stats, dict) else None
+    """PJRT allocator stats: a dict on TPU, None on the CPU backend."""
+    return device.memory_stats()
 
 
 def fragmentation_from_stats(stats: Dict[str, Any]) -> Optional[float]:
@@ -133,15 +130,13 @@ def fragmentation_from_stats(stats: Dict[str, Any]) -> Optional[float]:
 def hbm_snapshot(device=None) -> Dict[str, Any]:
     """One device's allocator state as a plain dict — the bench's
     fragmentation probe (recorded into BENCH ab_matrix rows) and the
-    payload behind the fragmentation gauge. Empty dict when the backend
-    exposes no memory_stats (CPU)."""
+    payload behind the fragmentation gauge. Empty dict only where the
+    backend keeps no memory_stats (CPU); a backend that fails to
+    answer raises."""
     if device is None:
-        try:
-            import jax
+        import jax
 
-            device = jax.local_devices()[0]
-        except Exception:
-            return {}
+        device = jax.local_devices()[0]
     stats = _memory_stats(device)
     if not stats:
         return {}
@@ -277,26 +272,7 @@ def instrumented_jit(fn, *, sample_memory: bool = False,
 
     jitted = jax.jit(fn, **jit_kwargs)
     name = getattr(fn, "__name__", "jit")
-    cache_size = getattr(jitted, "_cache_size", None)
-
-    if cache_size is None:
-        # No cache introspection on this jax version: passthrough, zero
-        # per-call overhead (memory still sampled on the throttled path
-        # when requested — train steps are seconds-long, the lock is
-        # noise there).
-        if sample_memory:
-            @functools.wraps(fn)
-            def wrapped(*args, **kwargs):
-                out = jitted(*args, **kwargs)
-                maybe_sample()
-                return out
-        else:
-            wrapped = functools.wraps(fn)(
-                lambda *args, **kwargs: jitted(*args, **kwargs)
-            )
-        wrapped.__wrapped_jit__ = jitted
-        wrapped.flush_taps = lambda: None
-        return wrapped
+    cache_size = jitted._cache_size
 
     # [last_seen_cache_size, bound_compiles, bound_seconds, countdown,
     # window_max_dt]; a mutable cell instead of nonlocal keeps the
@@ -317,14 +293,9 @@ def instrumented_jit(fn, *, sample_memory: bool = False,
     def _flush_taps_locked():
         state[3] = tap_stride
         before = state[0]
-        if before is None or before < 0:
+        if before is None:
             return
-        try:
-            after = cache_size()
-        except Exception:
-            state[0] = -1
-            return
-        state[0] = after
+        after = state[0] = cache_size()
         window_dt, state[4] = state[4], 0.0
         if after > before:
             if state[1] is None:
@@ -334,25 +305,14 @@ def instrumented_jit(fn, *, sample_memory: bool = False,
             state[1].inc(after - before)
             state[2].inc(window_dt)
             if sample_memory:
-                try:
-                    sample(force=True)
-                except Exception:
-                    pass
+                sample(force=True)
         elif sample_memory:
             maybe_sample()
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
-        before = state[0]
-        if before is None:
-            try:
-                before = state[0] = cache_size()
-            except Exception:
-                # Introspection broken: record nothing, stop polling.
-                state[0] = -1
-                before = -1
-        if before < 0:
-            return jitted(*args, **kwargs)
+        if state[0] is None:
+            state[0] = cache_size()
         t0 = time.perf_counter()
         out = jitted(*args, **kwargs)
         dt = time.perf_counter() - t0

@@ -392,6 +392,9 @@ def test_peer_close_fails_pending_requests_immediately():
                 pass
             finally:
                 del framed
+                # Python 3.12's Server.wait_closed() waits for every
+                # accepted connection to be closed, not just the listener.
+                writer.close()
 
         server = await asyncio.start_server(
             silent_server, "127.0.0.1", 0

@@ -1,0 +1,200 @@
+"""Compile the main path's kernels and serving programs for a TPU v5e
+that is described, not attached (on-chip-measurement guide, rehearsal 3).
+
+The TPU compiler installed with JAX lowers Mosaic kernels and whole
+programs for ``v5e:2x2`` without a chip, so what it would refuse on the
+machine (a slice not aligned to the tiling, too much VMEM, a program over
+HBM) fails here first, at no chip time. Nothing executes: these tests
+say nothing about results or speed.
+
+Shapes are the 8B-shaped config ``chip_smoke.py`` and ``bench.py`` run:
+32 query / 8 KV heads of dim 128, hidden 4096, b8 x 2048 for training,
+page 16 for serving.
+"""
+
+import dataclasses
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.experimental.compilation_cache import (  # noqa: E402
+    compilation_cache,
+)
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from ray_tpu.models import LlamaConfig, init_params  # noqa: E402
+from ray_tpu.models import generation  # noqa: E402
+from ray_tpu.ops import paged_attention  # noqa: E402
+
+# ray_tpu.ops re-exports the function under the module's own name.
+flash_mod = importlib.import_module("ray_tpu.ops.flash_attention")
+
+B, S, H, HKV, D = 8, 2048, 32, 8, 128
+PAGE, POOL_PAGES, PAGES_PER_SEQ = 16, 4096, 64
+BUCKET = 512
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one device of a described v5e 2x2 host."""
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    # A compile for a described device is written to the persistent
+    # cache but cannot be read back without the chip (JAX warns and
+    # recompiles); keep these out of it.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The process's default backend is the CPU, so the kernels'
+    platform dispatch would take the XLA path; steer it here."""
+    monkeypatch.setattr(flash_mod, "_on_tpu", lambda: True)
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _arr(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _serve_cfg():
+    return LlamaConfig(
+        vocab_size=32_768, hidden_size=4096, intermediate_size=14_336,
+        num_layers=4, num_heads=H, num_kv_heads=HKV, dtype=jnp.bfloat16,
+    )
+
+
+def _serve_shapes(cfg, sharding):
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))
+    )
+    cache = jax.eval_shape(
+        lambda: generation.PagedKVCache.create(
+            cfg, B, POOL_PAGES, PAGE, PAGES_PER_SEQ
+        )
+    )
+    return _shapes(params, sharding), _shapes(cache, sharding)
+
+
+def test_flash_forward_compiles_for_v5e(v5e, as_tpu):
+    fn = jax.jit(
+        lambda q, k, v: flash_mod.flash_attention(q, k, v, causal=True)
+    )
+    compiled = fn.lower(
+        _arr(v5e, (B, S, H, D)), _arr(v5e, (B, S, HKV, D)),
+        _arr(v5e, (B, S, HKV, D)),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_backward_compiles_for_v5e(v5e, as_tpu):
+    def loss(q, k, v):
+        out = flash_mod.flash_attention(q, k, v, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _arr(v5e, (B, S, H, D)), _arr(v5e, (B, S, HKV, D)),
+        _arr(v5e, (B, S, HKV, D)),
+    ).compile()
+    # Forward (for residuals) + the dQ kernel + the dK/dV kernel.
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_paged_decode_kernel_compiles_for_v5e(v5e):
+    """GQA rep = 4 page walk over a 4,096-page pool."""
+    compiled = jax.jit(paged_attention.paged_decode_attention).lower(
+        _arr(v5e, (B, H, D)),
+        _arr(v5e, (HKV, POOL_PAGES, PAGE, D)),
+        _arr(v5e, (HKV, POOL_PAGES, PAGE, D)),
+        _arr(v5e, (B, PAGES_PER_SEQ), jnp.int32),
+        _arr(v5e, (B,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_program_compiles_for_v5e(v5e, as_tpu):
+    cfg = _serve_cfg()
+    params, cache = _serve_shapes(cfg, v5e)
+
+    def decode(params, cache, tok, active):
+        return generation.paged_decode(
+            params, tok, cache, cfg, active=active
+        )
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (B,), jnp.int32),
+        _arr(v5e, (B,), jnp.bool_),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+
+
+def test_paged_decode_program_with_kernel_compiles_for_v5e(
+        v5e, as_tpu, monkeypatch):
+    """The page-walk kernel inside the layer scan (the path
+    RAY_TPU_PAGED_KERNEL=1 selects at import)."""
+    monkeypatch.setattr(generation, "_USE_PAGED_KERNEL", True)
+    cfg = _serve_cfg()
+    params, cache = _serve_shapes(cfg, v5e)
+
+    def decode(params, cache, tok, active):
+        return generation.paged_decode(
+            params, tok, cache, cfg, active=active
+        )
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (B,), jnp.int32),
+        _arr(v5e, (B,), jnp.bool_),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_prefill_program_compiles_for_v5e(v5e, as_tpu):
+    """A several-hundred-token prompt lands in the 512 bucket, whose
+    fresh-prefill attention is the flash kernel."""
+    cfg = _serve_cfg()
+    params, cache = _serve_shapes(cfg, v5e)
+
+    def prefill(params, cache, tokens, real_len, slot, pages):
+        return generation.paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages
+        )
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (1, BUCKET), jnp.int32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
+        _arr(v5e, (BUCKET // PAGE,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_falls_back_off_tpu():
+    """Unsteered, the CPU process takes the XLA reference: the kernel
+    is chosen by platform name, not by a user option."""
+    assert flash_mod._on_tpu() is False
+    cfg = dataclasses.replace(LlamaConfig.tiny(), use_flash=True)
+    q = jnp.ones((1, 128, cfg.num_heads, cfg.dh), jnp.float32)
+    k = jnp.ones((1, 128, cfg.num_kv_heads, cfg.dh), jnp.float32)
+    text = jax.jit(
+        lambda q, k, v: flash_mod.flash_attention(q, k, v, causal=True)
+    ).lower(q, k, k).as_text()
+    assert "tpu_custom_call" not in text

@@ -7,6 +7,9 @@ N concurrent HTTP clients -> per-node proxy -> deployment. Two probes:
 1. noop deployment: request throughput + latency percentiles.
 2. LLMDeployment (tiny model) via SSE streaming: client-measured TTFT
    percentiles + aggregate decode tokens/s under continuous batching.
+   The replica asks for the chip (a ``tpu`` worker); a replica that
+   finds another platform fails the probe — TTFT and tokens/s are
+   device numbers. The driver never imports jax.
 
 Usage: python tools/run_serve_perf.py [out.json]
 """
@@ -131,10 +134,11 @@ def llm_probe(port: int, clients: int = 4, requests_per_client: int = 3,
 def main():
     import ray_tpu
     from ray_tpu import serve
+    from ray_tpu.core.tpu import require_driver_off_jax
     from ray_tpu.serve import http_proxy
     from ray_tpu.serve.llm import LLMDeployment
 
-    ray_tpu.init(num_cpus=max(2, (os.cpu_count() or 1)),
+    ray_tpu.init(num_cpus=max(2, (os.cpu_count() or 1)), num_tpus=1,
                  system_config={"log_to_driver": False})
     out = {}
     proxies = {}
@@ -153,11 +157,18 @@ def main():
         urllib.request.urlopen(req, timeout=60).read()
         out["noop_http"] = noop_probe(port)
 
+        require_driver_off_jax()
         dep = serve.deployment(LLMDeployment).options(
             name="llm",
-            ray_actor_options={"max_concurrency": 8, "num_cpus": 1},
+            ray_actor_options={"max_concurrency": 8, "num_tpus": 1},
         )
-        serve.run(dep.bind(max_batch=4, max_len=64), name="llm")
+        llm = serve.run(dep.bind(max_batch=4, max_len=64), name="llm")
+        stats = llm.options(method="stats").remote().result(timeout=300)
+        if stats["platform"] != "tpu":
+            raise SystemExit(
+                f"llm probe needs the TPU; the replica's jax found "
+                f"{stats['platform']!r}"
+            )
         # warmup (compiles the tiny model's prefill/decode)
         wreq = urllib.request.Request(
             f"http://127.0.0.1:{port}/llm",
@@ -166,6 +177,8 @@ def main():
             headers={"Content-Type": "application/json"})
         urllib.request.urlopen(wreq, timeout=300).read()
         out["llm_sse"] = llm_probe(port)
+        out["llm_sse"]["device"] = {"platform": stats["platform"],
+                                    "kind": stats["device_kind"]}
     finally:
         for actor, _ in proxies.values():
             try:

@@ -46,11 +46,10 @@ def main() -> int:
     params, opt_state, loss = step(params, opt_state, tokens)
     assert np.isfinite(float(loss))
     stats = step.compile_stats()
-    if stats.get("executables") is not None:
-        assert stats["executables"] == 1, f"unexpected recompile: {stats}"
+    assert stats["executables"] == 1, f"unexpected recompile: {stats}"
     print(
         f"train-smoke OK: loss {loss0:.4f} -> {float(loss):.4f}, "
-        f"{stats.get('executables', '?')} executable(s), "
+        f"{stats['executables']} executable(s), "
         f"{time.perf_counter() - t0:.1f}s"
     )
     return 0
