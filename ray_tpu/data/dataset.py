@@ -521,10 +521,19 @@ class Dataset:
         def put(batch):
             return {k: convert(v) for k, v in batch.items()}
 
+        # Profiler annotations (inert unless a jax.profiler trace is
+        # open): the wait on the upstream batch and the transfer's
+        # enqueue, on the device trace's clock beside the step they feed.
+        span = jax.profiler.TraceAnnotation
         it = self.iter_batches(batch_size=batch_size, drop_last=drop_last)
         prev = None
-        for batch in it:
-            nxt = put(batch)  # enqueue transfer before yielding previous
+        while True:
+            with span("data.next_batch"):
+                batch = next(it, None)
+            if batch is None:
+                break
+            with span("data.device_put"):
+                nxt = put(batch)  # enqueue transfer before yielding previous
             if prev is not None:
                 yield prev
             prev = nxt

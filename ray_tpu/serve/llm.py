@@ -17,9 +17,11 @@ waits for pages instead of OOMing. All shapes stay static for XLA.
 
 from __future__ import annotations
 
+import collections
 import math
 import queue
 import threading
+import time
 from typing import Any, Dict, Iterator, List, Optional
 
 import numpy as np
@@ -34,11 +36,47 @@ class _Request:
         self.output: List[int] = []
         self.done = threading.Event()
         self.error: Optional[BaseException] = None
-        self.ttft_s: Optional[float] = None
-        self._t0 = None
+        # Lifecycle on ``time.time()``, the clock of ``core/timeline``
+        # spans: submitted, slot and pages assigned, first token out,
+        # finished or failed. The engine's loop only assigns the floats.
+        self.t_submit: Optional[float] = None
+        self.t_admit: Optional[float] = None
+        self.t_first: Optional[float] = None
+        self.t_done: Optional[float] = None
+        self.prompt_len = len(self.prompt)
+        self.bucket: Optional[int] = None
         # Incremental consumers (token streaming) read from here; None is
         # the end-of-stream sentinel.
         self._live: "queue.Queue[Optional[int]]" = queue.Queue()
+
+    @property
+    def ttft_s(self) -> Optional[float]:
+        """Submit to first token, queue wait and prefill together."""
+        if self.t_first is None:
+            return None
+        return self.t_first - self.t_submit
+
+    def row(self) -> List:
+        """This request as ``LLMEngine.stats()["requests"]`` shows it."""
+        return [self.t_submit, self.t_admit, self.t_first, self.t_done,
+                self.prompt_len, self.bucket]
+
+    def record_spans(self, parent: Optional[tuple] = None) -> None:
+        """The engine's part of this request as ``core/timeline`` spans
+        under ``parent`` ((trace_id, span_id), or the calling thread's
+        active span): ``engine.queued`` (submit to admit),
+        ``engine.prefill`` (admit to first token), ``engine.decode``
+        (first token to done). Call it from the request's own thread,
+        never from the engine's loop: recording may flush the whole span
+        buffer to the KV inline, and every open stream would wait."""
+        from ..core.timeline import record_span
+
+        stamps = (self.t_submit, self.t_admit, self.t_first, self.t_done)
+        for name, start, end in zip(
+                ("engine.queued", "engine.prefill", "engine.decode"),
+                stamps, stamps[1:]):
+            if start is not None and end is not None:
+                record_span(name, start, end, parent)
 
     def result(self, timeout: Optional[float] = None) -> List[int]:
         if not self.done.wait(timeout):
@@ -56,6 +94,16 @@ class _Request:
                     raise self.error
                 return
             yield tok
+
+
+# LLMEngine.stats(): the monotonic counts, the loop's phases, and how many
+# finished requests' rows it keeps.
+_COUNTERS = ("decode_slot_steps", "decode_kv_tokens", "prefills",
+             "prefill_tokens", "prefill_bucket_tokens", "submitted",
+             "admitted", "finished", "failed", "cache_resets", "page_waits")
+_PHASES = ("admit", "admit_stalling", "inputs", "decode", "readback",
+           "emit", "idle")
+_REQUEST_ROWS = 1024
 
 
 class LLMEngine:
@@ -115,6 +163,13 @@ class LLMEngine:
         self._lock = threading.Lock()
         self._stop = False
         self._step_count = 0
+        # What stats() reports beside the gauges. Written by the loop
+        # thread alone (plain adds, no lock) except ``submitted``, which
+        # callers' threads bump under the lock.
+        self._counts = dict.fromkeys(_COUNTERS, 0)
+        self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        self._finished_rows: "collections.deque[List]" = collections.deque(
+            maxlen=_REQUEST_ROWS)
 
         def decode_step(params, cache, last_tok, active, key):
             logits, cache = paged_decode(
@@ -169,9 +224,9 @@ class LLMEngine:
                 f"request needs {need} pages but the pool has only "
                 f"{self.total_pages} (page_size={self.page_size})"
             )
-        import time
-
-        req._t0 = time.perf_counter()
+        with self._lock:
+            self._counts["submitted"] += 1
+        req.t_submit = time.time()
         self._queue.put(req)
         return req
 
@@ -181,11 +236,43 @@ class LLMEngine:
         return self.submit(prompt, max_new_tokens, eos_token).result(timeout)
 
     def stats(self) -> Dict[str, Any]:
+        """Gauges of the engine now, and monotonic counts since it
+        started; take two readings and subtract for a window.
+
+        Gauges: ``active_slots``, ``free_slots``, ``free_pages``,
+        ``queued`` (submitted, not yet admitted), beside the constants
+        ``platform``, ``device_kind``, ``total_pages``, ``page_size``.
+
+        Counts: ``decode_steps``; ``decode_slot_steps`` (sequences, summed
+        over decode steps) and ``decode_kv_tokens`` (their cached tokens,
+        prompt and generated so far, summed likewise), so that the mean
+        batch and context of a step are quotients of differences;
+        ``prefills``, ``prefill_tokens`` (real) and
+        ``prefill_bucket_tokens`` (padded to the bucket); ``submitted``,
+        ``admitted``, ``finished``, ``failed`` (requests); ``cache_resets``;
+        ``page_waits`` (admission rounds that stopped for want of pages).
+
+        ``phase_s``: seconds the loop thread has spent in each phase, from
+        the ``perf_counter()`` boundaries that also delimit its
+        ``engine.*`` profiler annotations: ``admit`` (admission rounds,
+        prefills included), ``admit_stalling`` (the part of ``admit`` in
+        rounds entered with a stream open, which all of them wait
+        through), ``inputs``, ``decode`` (the dispatch), ``readback`` (the
+        host waiting for the device), ``emit``, ``idle`` (the 2 ms poll).
+
+        ``requests``: the newest requests that have finished and those now
+        decoding, each ``[t_submit, t_admit, t_first, t_done or None,
+        prompt_len, bucket]`` in ``time.time()`` seconds and tokens."""
         # Telemetry read: publish whatever the decode tap ring has
         # accumulated so /metrics never lags a long burst.
         self._decode.flush_taps()
         with self._lock:
             return {
+                **self._counts,
+                "queued": self._queue.qsize() + len(self._waiting),
+                "phase_s": dict(self._phase_s),
+                "requests": list(self._finished_rows) + [
+                    req.row() for req in self._slot_req.values()],
                 # Where the engine's programs run: a rate read from
                 # these stats is a device number only on a "tpu".
                 "platform": self._device.platform,
@@ -227,17 +314,27 @@ class LLMEngine:
             self._free_pages = list(range(self.total_pages))
             self._slot_pages.clear()
             self._table[:] = 0
+        self._counts["cache_resets"] += 1
         for _slot, req in victims:
             if not req.done.is_set():
-                req.error = RuntimeError(
+                self._close(req, RuntimeError(
                     f"engine cache reset after runtime failure: {cause!r}"
-                )
-                req.done.set()
-                req._live.put(None)
+                ))
         self.cache = PagedKVCache.create(
             self.cfg, self.max_batch, self.total_pages, self.page_size,
             self.max_pages_per_seq,
         )
+
+    def _close(self, req: _Request, error: Optional[BaseException] = None):
+        """End of a request, finished or failed: wake its waiters and
+        keep its row for stats()."""
+        req.t_done = time.time()
+        req.error = error
+        self._counts["failed" if error else "finished"] += 1
+        with self._lock:
+            self._finished_rows.append(req.row())
+        req.done.set()
+        req._live.put(None)
 
     def _release_slot(self, slot: int):
         pages = self._slot_pages.pop(slot, [])
@@ -254,9 +351,10 @@ class LLMEngine:
         return min(bucket, self.max_len)
 
     def _admit(self):
-        import time
-
+        """One admission round: prefill queued requests into free slots
+        until slots, pages or the queue run out."""
         jnp = self._jnp
+        counts = self._counts
         while self._slot_free:
             if self._waiting:
                 req = self._waiting.pop(0)
@@ -265,37 +363,43 @@ class LLMEngine:
                     req = self._queue.get_nowait()
                 except queue.Empty:
                     return
-            real_len = len(req.prompt)
+            real_len = req.prompt_len
             bucket = self._bucket(real_len)
             need = self._pages_needed(req, bucket)
             if need > len(self._free_pages):
                 # Paged admission control: wait for pages to recycle
                 # instead of OOMing or over-reserving a dense max_len row.
                 self._waiting.insert(0, req)
+                counts["page_waits"] += 1
                 return
             slot = self._slot_free.pop()
             pages = [self._free_pages.pop() for _ in range(need)]
-            self._slot_pages[slot] = pages
-            self._table[slot, :] = 0
-            self._table[slot, :need] = pages
-            prefill_pages = pages[: bucket // self.page_size]
-            self.cache = self.cache._replace(
-                page_table=jnp.asarray(self._table)
-            )
-            padded = req.prompt + [0] * (bucket - real_len)
-            tokens = jnp.asarray([padded], dtype=jnp.int32)
+            req.bucket = bucket
+            req.t_admit = time.time()
+            counts["admitted"] += 1
             try:
-                self.cache, first = self._prefill(
-                    self.params, self.cache, tokens,
-                    jnp.asarray(real_len, dtype=jnp.int32),
-                    jnp.asarray(slot, dtype=jnp.int32),
-                    jnp.asarray(prefill_pages, dtype=jnp.int32),
-                )
-                first = int(first)
+                # Table upload, padding, dispatch and the wait for the
+                # first token: what every open stream stalls through.
+                with self._jax.profiler.TraceAnnotation(
+                        "engine.prefill", bucket=bucket, slot=slot):
+                    self._slot_pages[slot] = pages
+                    self._table[slot, :] = 0
+                    self._table[slot, :need] = pages
+                    prefill_pages = pages[: bucket // self.page_size]
+                    self.cache = self.cache._replace(
+                        page_table=jnp.asarray(self._table)
+                    )
+                    padded = req.prompt + [0] * (bucket - real_len)
+                    tokens = jnp.asarray([padded], dtype=jnp.int32)
+                    self.cache, first = self._prefill(
+                        self.params, self.cache, tokens,
+                        jnp.asarray(real_len, dtype=jnp.int32),
+                        jnp.asarray(slot, dtype=jnp.int32),
+                        jnp.asarray(prefill_pages, dtype=jnp.int32),
+                    )
+                    first = int(first)
             except Exception as e:  # noqa: BLE001
-                req.error = e
-                req.done.set()
-                req._live.put(None)
+                self._close(req, e)
                 self._release_slot(slot)
                 # The cache was DONATED into the failed call — its
                 # buffers may already be invalid. Rebuild the pool and
@@ -303,7 +407,10 @@ class LLMEngine:
                 # dead buffers (engine reset; callers see clean errors).
                 self._reset_cache(e)
                 continue
-            req.ttft_s = time.perf_counter() - req._t0
+            req.t_first = time.time()
+            counts["prefills"] += 1
+            counts["prefill_tokens"] += real_len
+            counts["prefill_bucket_tokens"] += bucket
             req.output.append(first)
             req._live.put(first)
             with self._lock:
@@ -317,16 +424,37 @@ class LLMEngine:
             with self._lock:
                 self._slot_req.pop(slot, None)
             self._release_slot(slot)
-            req.done.set()
-            req._live.put(None)
+            self._close(req)
 
     def _loop(self):
-        import time
-
+        """Admit, then one decode step for every active slot. Each phase
+        is a profiler annotation (inert unless a ``jax.profiler`` trace
+        is open; then it lands in the trace's host plane, on the device
+        trace's clock) and, from the same ``perf_counter()`` boundaries,
+        a running total in ``phase_s``."""
         jnp = self._jnp
         jax = self._jax
+        span = jax.profiler.TraceAnnotation
+        clock = time.perf_counter
+        counts, phase_s = self._counts, self._phase_s
+        t = clock()
+
+        def lap(phase: str) -> float:
+            """Close ``phase`` at a boundary shared with the next one."""
+            nonlocal t
+            now = clock()
+            dt, t = now - t, now
+            phase_s[phase] += dt
+            return dt
+
         while not self._stop:
-            self._admit()
+            # Only this thread adds slots, so no lock to look.
+            stalling = bool(self._slot_req)
+            with span("engine.admit"):
+                self._admit()
+            dt = lap("admit")
+            if stalling:
+                phase_s["admit_stalling"] += dt
             with self._lock:
                 active_slots = dict(self._slot_req)
             if not active_slots:
@@ -334,33 +462,45 @@ class LLMEngine:
                 # batched metric taps accumulated over the burst.
                 self._decode.flush_taps()
                 time.sleep(0.002)
+                lap("idle")
                 continue
-            active = np.zeros((self.max_batch,), dtype=bool)
-            for s in active_slots:
-                active[s] = True
-            self._rng, key = jax.random.split(self._rng)
+            with span("engine.inputs"):
+                active = np.zeros((self.max_batch,), dtype=bool)
+                for s in active_slots:
+                    active[s] = True
+                self._rng, key = jax.random.split(self._rng)
+                last_tok = jnp.asarray(self._last_tok)
+                active = jnp.asarray(active)
+            lap("inputs")
             try:
-                nxt, self.cache = self._decode(
-                    self.params,
-                    self.cache,
-                    jnp.asarray(self._last_tok),
-                    jnp.asarray(active),
-                    key,
-                )
+                with span("engine.decode"):
+                    nxt, self.cache = self._decode(
+                        self.params, self.cache, last_tok, active, key)
             except Exception as e:  # noqa: BLE001
                 # The cache was donated into the failed call — recover
                 # like the prefill path: rebuild the pool, fail in-flight
                 # requests cleanly, keep the loop alive for new work.
                 self._reset_cache(e)
                 continue
-            self._step_count += 1
-            nxt = np.asarray(nxt)
-            for slot, req in active_slots.items():
-                tok = int(nxt[slot])
-                req.output.append(tok)
-                req._live.put(tok)
-                self._last_tok[slot] = tok
-                self._finish_if_done(slot, req, tok)
+            lap("decode")
+            with span("engine.readback"):
+                nxt = np.asarray(nxt)
+            lap("readback")
+            with span("engine.emit"):
+                self._step_count += 1
+                counts["decode_slot_steps"] += len(active_slots)
+                # The step attended to each prompt and every token
+                # generated before this one.
+                counts["decode_kv_tokens"] += sum(
+                    req.prompt_len + len(req.output)
+                    for req in active_slots.values())
+                for slot, req in active_slots.items():
+                    tok = int(nxt[slot])
+                    req.output.append(tok)
+                    req._live.put(tok)
+                    self._last_tok[slot] = tok
+                    self._finish_if_done(slot, req, tok)
+            lap("emit")
 
 
 class LLMDeployment:
@@ -392,23 +532,36 @@ class LLMDeployment:
                                 page_size=page_size,
                                 total_pages=total_pages)
 
-    def __call__(self, request: Dict[str, Any]) -> Dict[str, Any]:
-        tokens = self.engine.generate(
+    def _submit(self, request: Dict[str, Any]) -> _Request:
+        return self.engine.submit(
             list(request["prompt"]),
             int(request.get("max_new_tokens", 32)),
             request.get("eos_token"),
         )
-        return {"tokens": tokens}
+
+    def __call__(self, request: Dict[str, Any]) -> Dict[str, Any]:
+        req = self._submit(request)
+        try:
+            return {"tokens": req.result(300.0)}
+        finally:
+            req.record_spans()
 
     def stream(self, request: Dict[str, Any]):
-        """Generator endpoint: one token per yield, as decoded."""
-        req = self.engine.submit(
-            list(request["prompt"]),
-            int(request.get("max_new_tokens", 32)),
-            request.get("eos_token"),
-        )
-        for tok in req.tokens(timeout=300.0):
-            yield {"token": tok}
+        """Generator endpoint: one token per yield, as decoded. When the
+        stream ends, the engine's part of the request joins its trace
+        (``_Request.record_spans``), from this thread and not the
+        engine's."""
+        from ..core.timeline import current_span
+
+        # Now: a generator is resumed wherever its consumer runs, but it
+        # is entered under the replica's span of this request.
+        parent = current_span()
+        req = self._submit(request)
+        try:
+            for tok in req.tokens(timeout=300.0):
+                yield {"token": tok}
+        finally:
+            req.record_spans(parent)
 
     def stats(self) -> Dict[str, Any]:
         return self.engine.stats()

@@ -129,6 +129,7 @@ class CompiledTrainStep:
             return params, opt_state
 
         self._init = jax.jit(_init)
+        self._step_num = 0  # calls so far, for the profiler's step groups
 
     # ------------------------------------------------------------ state
 
@@ -161,17 +162,26 @@ class CompiledTrainStep:
     def __call__(self, params, opt_state, tokens):
         """One fused step: returns (params, opt_state, loss). The input
         params/opt_state buffers are DONATED — dead after the call.
-        Each step records a ``train_step`` span under the rank's active
-        trace (no-op outside one), so a gang's waterfall shows step
-        cadence beside checkpoint save/restore windows."""
+
+        The call returns when the step is dispatched, not when the device
+        has run it. Each call records a ``train_step`` span of that
+        DISPATCH under the rank's active trace (no-op outside one): a
+        gang's waterfall shows step cadence beside checkpoint
+        save/restore windows, and a span much longer than its
+        neighbours is a host that waited for a free dispatch slot. The
+        device's time for a step is in a profiled run, where the
+        ``StepTraceAnnotation`` groups the device's work by step number."""
         import time as _time
 
         from ..core.timeline import record_span
 
         t0 = _time.time()
         try:
-            return self._step(params, opt_state, tokens)
+            with jax.profiler.StepTraceAnnotation(
+                    "train_step", step_num=self._step_num):
+                return self._step(params, opt_state, tokens)
         finally:
+            self._step_num += 1
             try:
                 record_span("train_step", t0, _time.time())
             # A lost span only blanks telemetry, never a step.

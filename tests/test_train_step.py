@@ -99,6 +99,49 @@ def test_compiled_step_smoke_and_compile_cache():
     assert step.num_params(params) > 0
 
 
+def test_profiled_run_groups_steps_and_names_the_input_waits(tmp_path):
+    """Under a profiler trace each call is a ``train_step`` step
+    annotation carrying its step number (ROADMAP D7: the timeline span
+    times the dispatch; the device's time is the profiler's), and the
+    input iterator's wait and put are ``data.*`` annotations."""
+    import glob
+    import os
+
+    import ray_tpu.data as rd
+
+    cfg = _tiny(depth=2, scan_layers=True, scan_chunk=1)
+    step = CompiledTrainStep(cfg)
+    params, opt_state = step.init(jax.random.PRNGKey(0))
+    rows = np.random.RandomState(1).randint(0, 256, (6, 17)).astype(np.int32)
+    batches = rd.from_numpy(rows, column="tokens").iter_jax_batches(
+        batch_size=2)
+    first = next(batches)["tokens"]
+    params, opt_state, _ = step(params, opt_state, first)  # compiles
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for batch in batches:
+            params, opt_state, loss = step(params, opt_state,
+                                           batch["tokens"])
+        float(loss)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    events = [(event.name, dict(event.stats))
+              for plane in jax.profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for event in line.events]
+    steps = [s["step_num"] for name, s in events if name == "train_step"]
+    assert steps == [1, 2]
+    names = [name for name, _ in events]
+    # The iterator is one batch ahead: two waits are left (the last finds
+    # the end) and one put.
+    assert names.count("data.next_batch") == 2
+    assert names.count("data.device_put") == 1
+
+
 @pytest.mark.slow
 def test_compiled_step_donation_off():
     cfg = _tiny(depth=2, scan_layers=True, scan_chunk=2)
