@@ -18,15 +18,8 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.paged_attention import decode_attention
 from .llama import LlamaConfig, rms_norm, rope
-
-# Paged decode attention implementation choice, read ONCE at import (it
-# is baked into the traced program — flipping the env after the first
-# compile has no effect): default is the XLA gather path, which measured
-# faster in the full decode step on a v5e (before PR 1).
-import os as _os
-
-_USE_PAGED_KERNEL = _os.environ.get("RAY_TPU_PAGED_KERNEL") == "1"
 
 
 class KVCache(NamedTuple):
@@ -179,12 +172,9 @@ class PagedKVCache(NamedTuple):
     of ``max_batch * max_len`` each, so one long-context request coexists
     with many short ones; pages recycle the moment a request finishes.
     All shapes static for XLA. The pool is HEAD-MAJOR
-    ([L, Hkv, P_total, page, Dh]) so the Pallas page-walk kernel blocks
-    on (head, page) without a per-step transpose. Decode attention
-    gathers each slot's pages (``jnp.take(ck, page_table, axis=1)``)
-    into a window bounded by B * Pmax * page tokens — independent of
-    pool size — and masks by length (see _attend_paged for the measured
-    kernel-vs-gather tradeoff)."""
+    ([L, Hkv, P_total, page, Dh]): one copy brings a page of every KV
+    head to the decode attention (ops/paged_attention.py), which reads a
+    slot's own pages where they lie."""
 
     k: jax.Array            # [L, Hkv, P_total, page, Dh] shared pool
     v: jax.Array            # [L, Hkv, P_total, page, Dh]
@@ -198,9 +188,6 @@ class PagedKVCache(NamedTuple):
     @staticmethod
     def create(cfg: LlamaConfig, batch: int, total_pages: int,
                page_size: int, max_pages_per_seq: int) -> "PagedKVCache":
-        # Head-major pool: the Pallas page-walk kernel blocks on
-        # (head, page) directly — no per-step pool transpose (which
-        # would scale with POOL size and defeat paging).
         shape = (cfg.num_layers, cfg.num_kv_heads, total_pages,
                  page_size, cfg.dh)
         return PagedKVCache(
@@ -212,62 +199,18 @@ class PagedKVCache(NamedTuple):
         )
 
 
-def _attend_paged_xla(q, ck, cv, page_table, lengths, cfg):
-    """XLA fallback: gather each slot's pages into its logical
-    [T, Hkv, Dh] view and attend densely (the gather output is small —
-    only the slots' windows, bounded by B * Pmax * page tokens
-    regardless of pool size; the Pallas kernel avoids even that)."""
-    B = q.shape[0]
-    q_pos = lengths[:, None]
-    kp = jnp.take(ck, page_table, axis=1)  # [Hkv, B, Pmax, page, Dh]
-    vp = jnp.take(cv, page_table, axis=1)
-    Hkv, _, Pmax, page, Dh = kp.shape
-    kp = kp.transpose(1, 2, 3, 0, 4).reshape(B, Pmax * page, Hkv, Dh)
-    vp = vp.transpose(1, 2, 3, 0, 4).reshape(B, Pmax * page, Hkv, Dh)
-    return _attend_cached(q, kp, vp, q_pos, lengths + 1, cfg)
-
-
-def _attend_paged(q, ck, cv, page_table, lengths, cfg):
-    """Single-token decode over the paged pool. Default: the XLA gather
-    path — measured on a v5e (before PR 1) its cost is bounded
-    by the attention WINDOW (B * Pmax * page tokens), independent of
-    pool size, and it edges out the Pallas page-walk kernel in the full
-    decode step (2.04 vs 2.35 ms at pool=256 pages). The kernel
-    (ops/paged_attention.py) stays available via
-    RAY_TPU_PAGED_KERNEL=1 for shapes where the gather's window copy
-    dominates (very long windows / tiny batch)."""
-    from ..ops import paged_attention as pa
-
-    page = ck.shape[2]
-    if (
-        _USE_PAGED_KERNEL
-        and cfg.use_flash
-        and pa.on_tpu()
-        and pa.pageable(page, q.shape[-1])
-    ):
-        out = pa.paged_decode_attention(
-            q[:, 0], ck, cv, page_table, lengths
-        )
-        return out[:, None]
-    return _attend_paged_xla(q, ck, cv, page_table, lengths, cfg)
-
-
 def _layer_paged_decode(cfg, lp, x, ck, cv, page_table, lengths,
                         page_ids, offsets, active):
     """One block, single-token decode against the paged pool. x [B,1,M];
     ck/cv [Hkv, P, page, Dh] (this layer's pool slice, carried by the
     layer scan); page_ids/offsets [B] name each slot's write cell for
-    this token (inactive slots scatter to id -1 → dropped).
+    this token (inactive slots scatter past the pool → dropped).
 
-    Measured design note (before PR 1): three structures were benchmarked
-    on a v5e for the step's pool traffic — (a) this scan over
-    per-layer slices, (b) an unrolled layer loop scattering/gathering
-    the full [L, ...] pool with static layer indices + donation, and
-    (c) the pre-head-major layout with a per-step pool transpose. (a)
-    wins by 10x+ over (b) (XLA lowers the separated-advanced-index
-    full-pool scatters and full-pool custom-call operands poorly) and
-    strictly dominates (c). The residual pool-size dependence of (a) is
-    the scan re-stacking its ys (one pool-sized copy per k/v per step)."""
+    The layer scan slices the pool as its xs and re-stacks it as its ys:
+    pool-sized copies every step, whatever the load (ROADMAP S5; in the
+    chat cell's trace ``constant_dynamic-slice_fusion``,
+    ``copy_dynamic-update-slice_fusion`` and ``copy_bf16_16_8_2048_16_128``,
+    ~18 ms a step at a 2048-page pool: ledger, PR 25)."""
     B = x.shape[0]
     page = ck.shape[2]
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
@@ -290,7 +233,8 @@ def _layer_paged_decode(cfg, lp, x, ck, cv, page_table, lengths,
         k[:, 0].astype(ck.dtype).transpose(1, 0, 2), mode="drop")
     cv = cv.at[:, drop, offsets].set(
         v[:, 0].astype(cv.dtype).transpose(1, 0, 2), mode="drop")
-    attn = _attend_paged(q, ck, cv, page_table, lengths, cfg)
+    attn = decode_attention(
+        q[:, 0], ck, cv, page_table, lengths, active)[:, None]
     x = x + jnp.einsum("bshd,hdm->bsm", attn.astype(x.dtype), lp["wo"])
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if cfg.n_experts > 0:
@@ -318,8 +262,8 @@ def paged_decode(
     active: jax.Array,          # [B] bool
 ) -> Tuple[jax.Array, PagedKVCache]:
     """One decode step over the paged pool: write each slot's token into
-    its current page cell, attend over its gathered pages, return [B, V]
-    logits and the updated cache."""
+    its current page cell, attend over its pages, return [B, V] logits
+    and the updated cache."""
     B = tokens.shape[0]
     page = cache.page_size
     page_ids = cache.page_table[jnp.arange(B), cache.lengths // page]

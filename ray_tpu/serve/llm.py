@@ -121,6 +121,7 @@ class LLMEngine:
             paged_prefill,
             sample_logits,
         )
+        from ..ops.paged_attention import decode_attention_path
 
         self.cfg = cfg
         self.params = params
@@ -136,6 +137,9 @@ class LLMEngine:
                 f"({page_size})"
             )
         self.page_size = page_size
+        # What the decode program below is built with: the same call
+        # paged_decode's attention makes when the program is traced.
+        self._decode_attention = decode_attention_path(page_size, cfg.dh)
         self.max_pages_per_seq = math.ceil(max_len / page_size)
         # Default pool: enough for every slot at max_len (same worst case
         # as a dense cache); pass a smaller total_pages to oversubscribe.
@@ -241,7 +245,9 @@ class LLMEngine:
 
         Gauges: ``active_slots``, ``free_slots``, ``free_pages``,
         ``queued`` (submitted, not yet admitted), beside the constants
-        ``platform``, ``device_kind``, ``total_pages``, ``page_size``.
+        ``platform``, ``device_kind``, ``total_pages``, ``page_size`` and
+        ``decode_attention`` (``"page_walk"`` or ``"gather"``: the path of
+        ops/paged_attention.py the decode program was built with).
 
         Counts: ``decode_steps``; ``decode_slot_steps`` (sequences, summed
         over decode steps) and ``decode_kv_tokens`` (their cached tokens,
@@ -283,6 +289,7 @@ class LLMEngine:
                 "free_pages": len(self._free_pages),
                 "total_pages": self.total_pages,
                 "page_size": self.page_size,
+                "decode_attention": self._decode_attention,
             }
 
     def shutdown(self):

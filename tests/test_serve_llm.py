@@ -32,6 +32,42 @@ def test_engine_single_request_matches_generate(tiny_model):
         engine.shutdown()
 
 
+def test_engine_paged_decode_agrees_with_dense_generate(tiny_model):
+    """The engine's paged decode against ``generate()``'s dense cache,
+    greedy token for token: prompts of mixed lengths over page edges,
+    one request that ends at ``max_len``, slots idle beside busy ones,
+    and a slot taken again after its request finished (its table row
+    and length are the last request's until then)."""
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, max_batch=3, max_len=64, page_size=16)
+    try:
+        assert engine.stats()["decode_attention"] == "gather"  # a CPU
+        rng = np.random.RandomState(7)
+        # (prompt length, new tokens): 20 + 44 fills max_len.
+        shapes = [(5, 10), (20, 44), (17, 3), (9, 6)]
+        prompts = [list(rng.randint(0, 256, n)) for n, _ in shapes]
+        expected = [
+            np.asarray(generate(params, jnp.asarray([p]), cfg,
+                                max_new_tokens=n))[0].tolist()
+            for p, (_, n) in zip(prompts, shapes)
+        ]
+        first = [engine.submit(p, n)
+                 for p, (_, n) in zip(prompts[:3], shapes[:3])]
+        assert first[2].result(timeout=180) == expected[2]
+        # Every slot has been taken once: this one gets a used slot,
+        # while the long request is still decoding.
+        again = engine.submit(prompts[3], shapes[3][1])
+        assert again.result(timeout=180) == expected[3]
+        assert first[0].result(timeout=180) == expected[0]
+        assert first[1].result(timeout=180) == expected[1]
+        stats = engine.stats()
+        assert stats["admitted"] == stats["finished"] == 4
+        # Slots idled: fewer sequences decoded than 3 a step.
+        assert stats["decode_slot_steps"] < 3 * stats["decode_steps"]
+    finally:
+        engine.shutdown()
+
+
 @pytest.mark.slow
 def test_engine_concurrent_requests_continuous_batching(tiny_model):
     cfg, params = tiny_model

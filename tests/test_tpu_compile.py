@@ -37,6 +37,13 @@ flash_mod = importlib.import_module("ray_tpu.ops.flash_attention")
 
 B, S, H, HKV, D = 8, 2048, 32, 8, 128
 PAGE, POOL_PAGES, PAGES_PER_SEQ = 16, 4096, 64
+# The chat cell's engine: 32 slots of 128 pages over a 2048-page pool.
+CHAT_CELL, CHAT_POOL_PAGES = (32, 128), 2048
+decode_shapes = pytest.mark.parametrize(
+    "batch,pages_per_seq,pool_pages",
+    [(B, PAGES_PER_SEQ, POOL_PAGES), (*CHAT_CELL, CHAT_POOL_PAGES)],
+    ids=["b8", "chat-cell"],
+)
 BUCKET = 512
 
 
@@ -84,13 +91,14 @@ def _serve_cfg():
     )
 
 
-def _serve_shapes(cfg, sharding):
+def _serve_shapes(cfg, sharding, batch=B, pool_pages=POOL_PAGES,
+                  pages_per_seq=PAGES_PER_SEQ):
     params = jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0))
     )
     cache = jax.eval_shape(
         lambda: generation.PagedKVCache.create(
-            cfg, B, POOL_PAGES, PAGE, PAGES_PER_SEQ
+            cfg, batch, pool_pages, PAGE, pages_per_seq
         )
     )
     return _shapes(params, sharding), _shapes(cache, sharding)
@@ -120,21 +128,30 @@ def test_flash_backward_compiles_for_v5e(v5e, as_tpu):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
-def test_paged_decode_kernel_compiles_for_v5e(v5e):
-    """GQA rep = 4 page walk over a 4,096-page pool."""
+@decode_shapes
+def test_paged_decode_kernel_compiles_for_v5e(v5e, batch, pages_per_seq,
+                                              pool_pages):
+    """All 8 KV heads' 32 query rows of a slot in one program."""
     compiled = jax.jit(paged_attention.paged_decode_attention).lower(
-        _arr(v5e, (B, H, D)),
-        _arr(v5e, (HKV, POOL_PAGES, PAGE, D)),
-        _arr(v5e, (HKV, POOL_PAGES, PAGE, D)),
-        _arr(v5e, (B, PAGES_PER_SEQ), jnp.int32),
-        _arr(v5e, (B,), jnp.int32),
+        _arr(v5e, (batch, H, D)),
+        _arr(v5e, (HKV, pool_pages, PAGE, D)),
+        _arr(v5e, (HKV, pool_pages, PAGE, D)),
+        _arr(v5e, (batch, pages_per_seq), jnp.int32),
+        _arr(v5e, (batch,), jnp.int32),
+        _arr(v5e, (batch,), jnp.bool_),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_paged_decode_program_compiles_for_v5e(v5e, as_tpu):
+@decode_shapes
+def test_paged_decode_program_compiles_for_v5e(v5e, as_tpu, batch,
+                                               pages_per_seq, pool_pages):
+    """The decode program as the code builds it for a TPU: the page walk
+    inside the layer scan, chosen from platform and shape."""
     cfg = _serve_cfg()
-    params, cache = _serve_shapes(cfg, v5e)
+    params, cache = _serve_shapes(cfg, v5e, batch, pool_pages,
+                                  pages_per_seq)
+    assert paged_attention.decode_attention_path(PAGE, D) == "page_walk"
 
     def decode(params, cache, tok, active):
         return generation.paged_decode(
@@ -142,30 +159,11 @@ def test_paged_decode_program_compiles_for_v5e(v5e, as_tpu):
         )
 
     compiled = jax.jit(decode, donate_argnums=(1,)).lower(
-        params, cache, _arr(v5e, (B,), jnp.int32),
-        _arr(v5e, (B,), jnp.bool_),
+        params, cache, _arr(v5e, (batch,), jnp.int32),
+        _arr(v5e, (batch,), jnp.bool_),
     ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
-
-
-def test_paged_decode_program_with_kernel_compiles_for_v5e(
-        v5e, as_tpu, monkeypatch):
-    """The page-walk kernel inside the layer scan (the path
-    RAY_TPU_PAGED_KERNEL=1 selects at import)."""
-    monkeypatch.setattr(generation, "_USE_PAGED_KERNEL", True)
-    cfg = _serve_cfg()
-    params, cache = _serve_shapes(cfg, v5e)
-
-    def decode(params, cache, tok, active):
-        return generation.paged_decode(
-            params, tok, cache, cfg, active=active
-        )
-
-    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
-        params, cache, _arr(v5e, (B,), jnp.int32),
-        _arr(v5e, (B,), jnp.bool_),
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_paged_prefill_program_compiles_for_v5e(v5e, as_tpu):
