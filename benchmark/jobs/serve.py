@@ -17,7 +17,7 @@ def _deployment():
     must not import the serving stack."""
     from ray_tpu.serve.llm import LLMDeployment
 
-    from .. import reference, worker
+    from .. import arch, worker
 
     class BenchLLM(LLMDeployment):
         """``LLMDeployment`` on weights made in one jitted call from the
@@ -33,8 +33,9 @@ def _deployment():
             started = time.time()
             self._compiles = worker.CompileLog()  # before the first compile
             self._device = worker.device_facts(chips)
-            self._dtype = config["dtype"]
-            cfg = worker.llama_config(config)
+            self._config = config
+            self._reference = arch.reference(config)
+            cfg = arch.program_config(config)
             params = jax.block_until_ready(
                 jax.jit(lambda key: init_params(cfg, key))(
                     worker.prng_key(seed)))
@@ -58,7 +59,8 @@ def _deployment():
                     "phases": self._phases,
                     "entered": dict(self._entered),
                     "memory_peak_bytes": worker.memory_peak_bytes(),
-                    "margin_tol": reference.LOGIT_MARGIN_TOL[self._dtype],
+                    "margin_tol": self._reference.LOGIT_MARGIN_TOL[
+                        self._config["dtype"]],
                     **self._compiles.facts()}
 
         def trace_start(self) -> None:
@@ -76,13 +78,13 @@ def _deployment():
             import numpy as np
 
             eng = self.engine
-            fn = jax.jit(reference.logit_margins, static_argnums=(2, 3))
+            margins, config = self._reference.logit_margins, self._config
+            fn = jax.jit(lambda p, t: margins(p, t, config))
             out = []
             for prompt, output in pairs:
                 seq = np.zeros((1, eng.max_len + 1), np.int32)
                 seq[0, :len(prompt) + len(output)] = prompt + output
-                row = np.asarray(fn(eng.params, seq, eng.cfg.rope_theta,
-                                    eng.cfg.rms_eps))[0]
+                row = np.asarray(fn(eng.params, seq))[0]
                 start = len(prompt) - 1
                 out.append(row[start:start + len(output)].tolist())
             return out

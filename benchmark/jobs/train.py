@@ -27,13 +27,14 @@ def _loop(c: Mapping) -> None:
     from ray_tpu.data.context import DataContext
     from ray_tpu.train.compiled_step import CompiledTrainStep
 
-    from .. import reference, worker
+    from .. import arch, worker
 
     phases = {"worker_started": time.time()}
     compiles = worker.CompileLog()  # before the first compile
     device = worker.device_facts(c["chips"])
     config, traffic = c["config"], c["traffic"]
-    cfg = worker.llama_config(config)
+    cfg = arch.program_config(config)
+    reference = arch.reference(config)
     mesh = None
     if config.get("mesh"):
         from ray_tpu.parallel import make_mesh
@@ -59,8 +60,8 @@ def _loop(c: Mapping) -> None:
     first = tokens[warmup * batch:(warmup + 1) * batch]
     k = traffic["check_sequences"]
     rows = np.random.default_rng(c["seed"]).choice(batch, k, replace=False)
-    ref_loss = float(jax.jit(reference.loss, static_argnums=(2, 3))(
-        params, first[rows], cfg.rope_theta, cfg.rms_eps))
+    ref_loss = float(jax.jit(lambda p, t: reference.loss(p, t, config))(
+        params, first[rows]))
     jax.clear_caches()  # unload the reference; it keeps scratch reserved
     gc.collect()
     phases["reference_done"] = time.time()

@@ -10,7 +10,7 @@ Every reader returns None where ``stats()`` lacks what it reads (an
 engine from before PR 25), and never raises for that.
 """
 
-from .. import flops, stats
+from .. import arch, flops, stats
 from .trace import decode_step_device_s_p50
 
 # The loop thread's time, cut without overlap: ``admit_stalling`` is a
@@ -100,9 +100,10 @@ def decode_step_roofline_counted(record):
     if not step or sequences is None or tokens is None:
         return None
     config = record["config"]
+    counts = arch.counts(config)
     least = flops.roofline_s(
-        flops.decode_step_flops(config, sequences, tokens),
-        flops.decode_step_bytes(config, sequences, tokens),
+        counts.decode_step_flops(config, sequences, tokens),
+        counts.decode_step_bytes(config, sequences, tokens),
         flops.peaks(record["worker"]["device"]["kind"]))
     return 100.0 * least / step
 

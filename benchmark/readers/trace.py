@@ -1,7 +1,7 @@
 """Readers of the reduced profiler trace (``trace_reduce.reduce_trace``).
 Each returns None when the run was not traced."""
 
-from .. import flops, stats
+from .. import arch, flops, stats
 
 
 def _peak(record):
@@ -37,9 +37,10 @@ def flash_roofline(record):
     traffic, config = record["traffic"], record["config"]
     steps = len(trace["modules"].get("train_step", ()))
     chips = trace["devices"]
+    counts = arch.counts(config)
     args = (config, traffic["batch"], traffic["seqlen"])
-    least = flops.roofline_s(flops.flash_train_flops(*args) * steps / chips,
-                             flops.flash_train_bytes(*args) * steps / chips,
+    least = flops.roofline_s(counts.flash_train_flops(*args) * steps / chips,
+                             counts.flash_train_bytes(*args) * steps / chips,
                              _peak(record))
     return 100.0 * least / _pallas_s(trace)
 
@@ -101,7 +102,8 @@ def decode_step_roofline(record):
         return None
     sequences, tokens = _traced_load(record)
     config = record["config"]
+    counts = arch.counts(config)
     least = flops.roofline_s(
-        flops.decode_step_flops(config, sequences, tokens),
-        flops.decode_step_bytes(config, sequences, tokens), _peak(record))
+        counts.decode_step_flops(config, sequences, tokens),
+        counts.decode_step_bytes(config, sequences, tokens), _peak(record))
     return 100.0 * least / step

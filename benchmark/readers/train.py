@@ -1,6 +1,6 @@
 """Readers of the training job's own samples."""
 
-from .. import flops, stats
+from .. import arch, flops, stats
 
 
 def _summary(record):
@@ -30,8 +30,9 @@ def step_stall_share(record):
 def step_mfu(record):
     """Required operations per token times the median reading's tokens
     per second (the step's own speed), over the chips' bf16 peak."""
-    per_token = flops.train_flops_per_token(
-        record["config"], record["traffic"]["seqlen"])
+    config = record["config"]
+    per_token = arch.counts(config).train_flops_per_token(
+        config, record["traffic"]["seqlen"])
     device = record["worker"]["device"]
     peak = flops.peaks(device["kind"])["bf16_flops_per_s"] * device["count"]
     return 100.0 * train_tokens_per_s_median(record) * per_token / peak

@@ -81,9 +81,12 @@ def _attention(q, k, v):
     return jnp.moveaxis(out, 0, 1).reshape(b, s, h, d)
 
 
-def hidden(params, tokens, theta: float, eps: float):
+def hidden(params, tokens, config):
     """Final-norm hidden states [B, S, M] for tokens [B, S]; S must be a
-    multiple of ``Q_BLOCK`` or smaller than it."""
+    multiple of ``Q_BLOCK`` or smaller than it. ``config`` is the
+    configuration file's dict, read for ``rope_theta`` and
+    ``rms_norm_eps`` (Python floats: close over it before ``jax.jit``)."""
+    theta, eps = config["rope_theta"], config["rms_norm_eps"]
     x = _f32(params["embed"][tokens])
 
     def layer(x, w):
@@ -105,10 +108,10 @@ def hidden(params, tokens, theta: float, eps: float):
     return _rms_norm(x, _f32(params["final_norm"]), eps)
 
 
-def _per_block(params, tokens, theta, eps, reduce_logits):
+def _per_block(params, tokens, config, reduce_logits):
     """``reduce_logits(logits [B, block, V], targets [B, block])`` over
     blocks of positions, so [B, S, V] never exists at once."""
-    x = hidden(params, tokens[:, :-1], theta, eps)
+    x = hidden(params, tokens[:, :-1], config)
     targets = tokens[:, 1:]
     b, s, m = x.shape
     block = min(Q_BLOCK, s)
@@ -125,21 +128,21 @@ def _per_block(params, tokens, theta, eps, reduce_logits):
     return jnp.moveaxis(out, 0, 1).reshape(b, s)
 
 
-def token_nll(params, tokens, theta: float, eps: float):
+def token_nll(params, tokens, config):
     """Next-token negative log likelihood [B, S] for tokens [B, S+1]."""
     def nll(logits, targets):
         logp = jax.nn.log_softmax(logits, -1)
         return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
 
-    return _per_block(params, tokens, theta, eps, nll)
+    return _per_block(params, tokens, config, nll)
 
 
-def loss(params, tokens, theta: float, eps: float):
+def loss(params, tokens, config):
     """Mean next-token cross entropy of tokens [B, S+1]."""
-    return token_nll(params, tokens, theta, eps).mean()
+    return token_nll(params, tokens, config).mean()
 
 
-def logit_margins(params, tokens, theta: float, eps: float):
+def logit_margins(params, tokens, config):
     """For tokens [B, S+1]: at each position, how far the logit of the
     token that follows trails the best logit (0 where it is the
     argmax). Teacher-forced: one full forward, no cache."""
@@ -147,4 +150,4 @@ def logit_margins(params, tokens, theta: float, eps: float):
         chosen = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
         return logits.max(-1) - chosen
 
-    return _per_block(params, tokens, theta, eps, margin)
+    return _per_block(params, tokens, config, margin)

@@ -14,7 +14,7 @@ import shutil
 import tempfile
 from typing import Dict, Mapping, Optional
 
-from . import flops
+from . import arch
 
 
 def llama_config(config: Mapping):
@@ -31,7 +31,7 @@ def llama_config(config: Mapping):
         num_layers=config["num_hidden_layers"],
         num_heads=config["num_attention_heads"],
         num_kv_heads=config["num_key_value_heads"],
-        head_dim=flops.head_dim(config),
+        head_dim=arch.counts(config).head_dim(config),
         rope_theta=config["rope_theta"],
         rms_eps=config["rms_norm_eps"],
         dtype=jnp.dtype(config["dtype"]),

@@ -12,7 +12,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from benchmark import flops, loadgen, stats  # noqa: E402
+from benchmark import arch, flops, loadgen, stats  # noqa: E402
 from benchmark.readers import serve as serve_readers  # noqa: E402
 from benchmark.readers import train as train_readers  # noqa: E402
 
@@ -86,24 +86,27 @@ def test_train_readers_on_a_hand_made_record():
 def test_flops_against_hand_worked_counts(name, layer, matmul, total,
                                           kv_bytes, train_flops):
     config = _config(name)
-    counts = flops.param_counts(config)
+    # Through the resolver, as the readers reach them.
+    module = arch.counts(config)
+    counts = module.param_counts(config)
     assert counts["layer"] == layer
     assert counts["matmul"] == matmul
     assert counts["total"] == total
-    assert flops.kv_bytes_per_token(config) == kv_bytes
-    assert flops.train_flops_per_token(config, 2048) == train_flops
+    assert module.kv_bytes_per_token(config) == kv_bytes
+    assert module.train_flops_per_token(config, 2048) == train_flops
     # The attention kernels' operations are the formula's second term.
-    assert flops.flash_train_flops(config, 8, 2048) == (
+    assert module.flash_train_flops(config, 8, 2048) == (
         train_flops - 6 * matmul) * 8 * 2048
 
 
 def test_decode_step_is_bound_by_weight_bytes():
     config = _config("mistral-7b-v0.3-L16")
+    counts = arch.counts(config)
     peak = flops.peaks("TPU v5 lite")
-    nbytes = flops.decode_step_bytes(config, 20, 8000)
+    nbytes = counts.decode_step_bytes(config, 20, 8000)
     assert nbytes == pytest.approx(
         2 * (3_623_878_656 + 135_168) + 8000 * 65_536 + 20 * 4096 * 2)
-    least = flops.roofline_s(flops.decode_step_flops(config, 20, 8000),
+    least = flops.roofline_s(counts.decode_step_flops(config, 20, 8000),
                              nbytes, peak)
     assert least == pytest.approx(nbytes / 819e9)
 

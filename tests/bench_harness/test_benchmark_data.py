@@ -14,6 +14,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+from benchmark import arch  # noqa: E402
 from benchmark import run as bench_run  # noqa: E402
 
 BENCH = bench_run.load_benchmark()
@@ -53,6 +54,72 @@ def test_config_file_is_the_source_with_only_depth_cut(entry):
     # No width may be cut: the reduced keys are depth alone.
     assert entry["reduced"] == ["num_hidden_layers"]
     assert any(c["config"] == entry["name"] for c in BENCH["workloads"])
+
+
+REFERENCE_OFFERS = ("loss", "logit_margins", "LOSS_ATOL", "LOGIT_MARGIN_TOL")
+COUNTS_OFFER = ("train_flops_per_token", "flash_train_flops",
+                "flash_train_bytes", "kv_bytes_per_token", "decode_step_flops",
+                "decode_step_bytes", "param_counts", "head_dim")
+
+
+def _config_file(entry):
+    with open(os.path.join(REPO, entry["file"])) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda e: e["name"])
+def test_config_names_its_architecture_under_paths(entry):
+    config = _config_file(entry)
+    assert set(config["arch"]) == set(arch.ROLES)
+    roots = tuple(os.path.join(REPO, p) + os.sep for p in BENCH["paths"])
+    reference, counts = arch.reference(config), arch.counts(config)
+    assert reference.__file__.startswith(roots)
+    assert counts.__file__.startswith(roots)
+    assert reference.__name__ == config["arch"]["reference"]
+    assert counts.__name__ == config["arch"]["counts"]
+    assert all(callable(getattr(reference, n)) for n in REFERENCE_OFFERS[:2])
+    assert all(config["dtype"] in getattr(reference, n)
+               for n in REFERENCE_OFFERS[2:])
+    assert all(callable(getattr(counts, n)) for n in COUNTS_OFFER)
+    # The program's config comes from the function the file names, and
+    # carries the file's sizes.
+    cfg = arch.program_config(config)
+    assert (cfg.hidden_size, cfg.num_layers, cfg.dh) == (
+        config["hidden_size"], config["num_hidden_layers"],
+        counts.head_dim(config))
+    module, _, function = config["arch"]["program_config"].rpartition(".")
+    assert sys.modules[module].__file__.startswith(roots)
+    assert callable(getattr(sys.modules[module], function))
+
+
+@pytest.mark.parametrize("change,says", [
+    (lambda c: c.pop("arch"), "no arch.counts"),
+    (lambda c: c.update(arch=None), "no arch.counts"),
+    (lambda c: c["arch"].pop("counts"), "no arch.counts"),
+    (lambda c: c["arch"].update(counts=["benchmark.flops"]), "no arch.counts"),
+    (lambda c: c["arch"].update(counts="ray_tpu.models.llama"), "outside"),
+    (lambda c: c["arch"].update(counts="json"), "outside"),
+    (lambda c: c["arch"].update(counts="tests.conftest"), "outside"),
+    (lambda c: c["arch"].update(counts="benchmark...ray_tpu.models.llama"),
+     "outside"),
+], ids=["no-arch", "arch-null", "no-role", "not-a-name", "program-module",
+        "stdlib", "beside-paths", "dots-leading-out"])
+def test_a_config_without_arch_or_naming_outside_paths_is_an_error(change, says):
+    config = _config_file(BENCH["configs"][0])
+    assert arch.counts(config).__name__ == "benchmark.flops"
+    change(config)
+    with pytest.raises(ValueError, match=says):
+        arch.counts(config)
+
+
+def test_a_name_under_paths_with_no_file_is_an_import_error():
+    config = _config_file(BENCH["configs"][0])
+    config["arch"]["reference"] = "benchmark.no_such_reference"
+    with pytest.raises(ImportError, match="no_such_reference"):
+        arch.reference(config)
+    config["arch"]["program_config"] = "llama_config"  # no module named
+    with pytest.raises(ValueError, match="outside"):
+        arch.program_config(config)
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda c: c["name"])

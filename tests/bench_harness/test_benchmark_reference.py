@@ -28,15 +28,17 @@ def tiny():
     params = init_params(cfg, jax.random.PRNGKey(3))
     tokens = jnp.asarray(
         np.random.RandomState(0).randint(0, 256, (2, 1025)), jnp.int32)
-    return cfg, params, tokens
+    # What the reference reads of a configuration file's dict.
+    config = {"rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.rms_eps}
+    return cfg, params, tokens, config
 
 
 def test_reference_loss_equals_the_programs(tiny):
     from benchmark import reference
     from ray_tpu.models.llama import causal_lm_loss
 
-    cfg, params, tokens = tiny
-    ours = float(reference.loss(params, tokens, cfg.rope_theta, cfg.rms_eps))
+    cfg, params, tokens, config = tiny
+    ours = float(reference.loss(params, tokens, config))
     theirs = float(causal_lm_loss(params, tokens, cfg))
     assert abs(ours - theirs) <= reference.LOSS_ATOL["float32"]
 
@@ -47,27 +49,26 @@ def test_reference_margins_equal_the_programs_logits(tiny):
     from benchmark import reference
     from ray_tpu.models.llama import forward
 
-    cfg, params, tokens = tiny
+    cfg, params, tokens, config = tiny
     logits, _ = forward(params, tokens[:, :-1], cfg)
     chosen = jnp.take_along_axis(logits, tokens[:, 1:, None], -1)[..., 0]
     theirs = logits.max(-1) - chosen
-    ours = reference.logit_margins(params, tokens, cfg.rope_theta, cfg.rms_eps)
+    ours = reference.logit_margins(params, tokens, config)
     assert ours.shape == (2, 1024)
     assert float(jnp.abs(ours - theirs).max()) <= reference.LOGIT_MARGIN_TOL["float32"]
     # Blocks of queries change nothing: a short sequence is one block.
-    short = reference.logit_margins(params, tokens[:, :101], cfg.rope_theta,
-                                    cfg.rms_eps)
+    short = reference.logit_margins(params, tokens[:, :101], config)
     assert float(jnp.abs(short - theirs[:, :100]).max()) <= 1e-4
 
 
 def test_llama_config_from_a_configuration_file():
     import json
 
-    from benchmark import worker
+    from benchmark import arch, worker
 
     with open(os.path.join(REPO, "benchmark/configs/mistral-nemo-12b-L8.json")) as f:
         config = json.load(f)
-    cfg = worker.llama_config(config)
+    cfg = arch.program_config(config)
     assert (cfg.hidden_size, cfg.dh, cfg.num_heads, cfg.num_kv_heads) == (5120, 128, 32, 8)
     assert (cfg.vocab_size, cfg.num_layers, cfg.rope_theta) == (131072, 8, 1e6)
     assert (cfg.remat_policy, cfg.scan_chunk) == ("dots", 4)
@@ -91,6 +92,7 @@ def test_run_refuses_to_measure_without_a_chip():
 def test_the_driver_side_never_imports_jax():
     code = ("import sys, benchmark.run, benchmark.driver, benchmark.loadgen, "
             "benchmark.jobs.train, benchmark.jobs.serve, benchmark.flops, "
+            "benchmark.arch, benchmark.readers.engine, "
             "benchmark.trace_reduce, benchmark.readers.trace, "
             "benchmark.readers.serve, benchmark.readers.train; "
             "sys.exit('jax' in sys.modules)")
