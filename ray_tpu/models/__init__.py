@@ -1,5 +1,6 @@
 """ray_tpu.models: TPU-first model zoo for the benchmark configs
-(BASELINE.json): Llama-3 family (+ Mixtral MoE via n_experts), ResNet/CIFAR,
+(BASELINE.json): Llama-3 family (+ a mixture of experts via n_experts, QK-norm via
+qk_norm: OLMoE's block), ResNet/CIFAR,
 ViT for image pipelines."""
 
 from .llama import (  # noqa: F401

@@ -19,7 +19,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.paged_attention import decode_attention
-from .llama import LlamaConfig, rms_norm, rope
+from .llama import (
+    LlamaConfig, ffn, qkv_proj, rms_norm, rope, split_expert_stack,
+)
 
 
 class KVCache(NamedTuple):
@@ -63,15 +65,15 @@ def _attend_cached(q, ck, cv, q_pos, lengths, cfg):
 
 
 def _layer_cached(cfg, lp, x, cache_k, cache_v, start_pos, q_pos,
-                  active=None):
+                  token_mask=None, expert_stack=None):
     """One block over cached KV. x [B,S,M]; start_pos [B] write offset;
-    ``active`` [B] masks rows out of MoE routing (inactive decode slots
-    must not claim expert capacity)."""
+    ``token_mask`` [B,S] keeps rows (inactive decode slots, a bucket's
+    padding) out of MoE routing: they reach no expert. Returns the
+    tokens assigned to each expert beside x and the cache (None for a
+    dense model)."""
     B, S, M = x.shape
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
-    k = jnp.einsum("bsm,mhd->bshd", h, lp["wk"])
-    v = jnp.einsum("bsm,mhd->bshd", h, lp["wv"])
+    q, k, v = qkv_proj(cfg, lp, x)
+
     # Rotary with per-slot positions.
     def rope_rows(x_b, pos_b):
         return rope(x_b[None], pos_b, cfg.rope_theta)[0]
@@ -90,30 +92,26 @@ def _layer_cached(cfg, lp, x, cache_k, cache_v, start_pos, q_pos,
     attn = _attend_cached(q, cache_k, cache_v, q_pos,
                           start_pos + S, cfg)
     x = x + jnp.einsum("bshd,hdm->bsm", attn.astype(x.dtype), lp["wo"])
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    if cfg.n_experts > 0:
-        # MoE cached decode: the same static-capacity expert dispatch as
-        # training (parallel/moe.py); the aux load-balancing loss is a
-        # training-only term and is discarded here.
-        from ..parallel.moe import moe_ffn
+    # The load-balancing loss is a training-only term: dropped here.
+    x, _aux, expert_tokens = ffn(cfg, lp, x, token_mask=token_mask,
+                                 expert_stack=expert_stack)
+    return x, cache_k, cache_v, expert_tokens
 
-        token_mask = None
-        if active is not None:
-            token_mask = jnp.broadcast_to(
-                active[:, None], h.shape[:2]
-            )
-        out, _aux = moe_ffn(
-            h, lp["router"], lp["w_up"], lp["w_down"],
-            k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-            w_gate=lp["w_gate"], token_mask=token_mask,
-        )
-        x = x + out
-        return x, cache_k, cache_v
-    up = jnp.einsum("bsm,mf->bsf", h, lp["w_up"])
-    gate = jnp.einsum("bsm,mf->bsf", h, lp["w_gate"])
-    h2 = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
-    x = x + jnp.einsum("bsf,fm->bsm", h2, lp["w_down"])
-    return x, cache_k, cache_v
+
+class MoeLoad(NamedTuple):
+    """What the experts of one program run were given, summed over its
+    layers: the serving engine's expert-load counters."""
+
+    expert_tokens: jax.Array    # [E] int32 (token, expert) assignments
+    experts_reached: jax.Array  # [] int32 (layer, expert) pairs with any
+
+    @staticmethod
+    def of_layers(expert_tokens) -> Optional["MoeLoad"]:
+        """From the layer scan's stacked [L, E] counts; None for None."""
+        if expert_tokens is None:
+            return None
+        return MoeLoad(expert_tokens.sum(axis=0),
+                       (expert_tokens > 0).sum().astype(jnp.int32))
 
 
 def forward_with_cache(
@@ -133,21 +131,43 @@ def forward_with_cache(
     ``last_index``/``append_len`` support BUCKETED prefill: tokens padded
     to a bucket length S still produce logits at the true final position
     and advance each slot's length by its true prompt length (padded cache
-    rows beyond the length are never attended — masking is by length)."""
+    rows beyond the length are never attended — masking is by length;
+    padded rows and inactive slots reach no expert of a MoE model)."""
+    logits, cache, _load = _forward_with_cache(
+        params, tokens, cache, cfg, active=active, last_index=last_index,
+        append_len=append_len)
+    return logits, cache
+
+
+def _forward_with_cache(params, tokens, cache, cfg, *, active=None,
+                        last_index=None, append_len=None):
+    """``forward_with_cache`` plus the run's ``MoeLoad`` (None for a
+    dense model)."""
     B, S = tokens.shape
     start = cache.lengths
     q_pos = start[:, None] + jnp.arange(S)[None, :]
     x = params["embed"][tokens].astype(cfg.dtype)
+    token_mask = None
+    if cfg.n_experts > 0 and (active is not None or append_len is not None):
+        token_mask = jnp.ones((B, S), bool)
+        if active is not None:
+            token_mask &= active[:, None]
+        if append_len is not None:
+            token_mask &= (jnp.arange(S)[None, :]
+                           < jnp.reshape(append_len, (-1, 1)))
+
+    layers, expert_stack = split_expert_stack(cfg, params["layers"])
 
     def body(carry, layer_in):
         x = carry
         lp, ck, cv = layer_in
-        x, ck, cv = _layer_cached(cfg, lp, x, ck, cv, start, q_pos,
-                                  active=active)
-        return x, (ck, cv)
+        x, ck, cv, expert_tokens = _layer_cached(
+            cfg, lp, x, ck, cv, start, q_pos, token_mask=token_mask,
+            expert_stack=expert_stack)
+        return x, (ck, cv, expert_tokens)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], cache.k, cache.v)
+    x, (new_k, new_v, expert_tokens) = jax.lax.scan(
+        body, x, (layers, cache.k, cache.v)
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     if last_index is None:
@@ -161,7 +181,8 @@ def forward_with_cache(
     keep = active[:, None, None, None]
     new_k = jnp.where(keep[None], new_k, cache.k)
     new_v = jnp.where(keep[None], new_v, cache.v)
-    return logits.astype(jnp.float32), KVCache(new_k, new_v, lengths)
+    return (logits.astype(jnp.float32), KVCache(new_k, new_v, lengths),
+            MoeLoad.of_layers(expert_tokens))
 
 
 class PagedKVCache(NamedTuple):
@@ -200,23 +221,20 @@ class PagedKVCache(NamedTuple):
 
 
 def _layer_paged_decode(cfg, lp, x, ck, cv, page_table, lengths,
-                        page_ids, offsets, active):
+                        page_ids, offsets, active, expert_stack=None):
     """One block, single-token decode against the paged pool. x [B,1,M];
     ck/cv [Hkv, P, page, Dh] (this layer's pool slice, carried by the
     layer scan); page_ids/offsets [B] name each slot's write cell for
-    this token (inactive slots scatter past the pool → dropped).
+    this token (inactive slots scatter past the pool → dropped). Returns
+    the tokens assigned to each expert beside x and the pool slice (None
+    for a dense model).
 
     The layer scan slices the pool as its xs and re-stacks it as its ys:
     pool-sized copies every step, whatever the load (ROADMAP S5; in the
     chat cell's trace ``constant_dynamic-slice_fusion``,
     ``copy_dynamic-update-slice_fusion`` and ``copy_bf16_16_8_2048_16_128``,
     ~18 ms a step at a 2048-page pool: ledger, PR 25)."""
-    B = x.shape[0]
-    page = ck.shape[2]
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
-    k = jnp.einsum("bsm,mhd->bshd", h, lp["wk"])
-    v = jnp.einsum("bsm,mhd->bshd", h, lp["wv"])
+    q, k, v = qkv_proj(cfg, lp, x)
     q_pos = lengths[:, None]
 
     def rope_rows(x_b, pos_b):
@@ -236,21 +254,11 @@ def _layer_paged_decode(cfg, lp, x, ck, cv, page_table, lengths,
     attn = decode_attention(
         q[:, 0], ck, cv, page_table, lengths, active)[:, None]
     x = x + jnp.einsum("bshd,hdm->bsm", attn.astype(x.dtype), lp["wo"])
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    if cfg.n_experts > 0:
-        from ..parallel.moe import moe_ffn
-
-        token_mask = jnp.broadcast_to(active[:, None], h.shape[:2])
-        out, _aux = moe_ffn(
-            h, lp["router"], lp["w_up"], lp["w_down"],
-            k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-            w_gate=lp["w_gate"], token_mask=token_mask,
-        )
-        return x + out, ck, cv
-    up = jnp.einsum("bsm,mf->bsf", h, lp["w_up"])
-    gate = jnp.einsum("bsm,mf->bsf", h, lp["w_gate"])
-    h2 = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
-    return x + jnp.einsum("bsf,fm->bsm", h2, lp["w_down"]), ck, cv
+    # Inactive slots reach no expert: the experts a step reads follow
+    # the live sequences.
+    x, _aux, expert_tokens = ffn(cfg, lp, x, token_mask=active[:, None],
+                                 expert_stack=expert_stack)
+    return x, ck, cv, expert_tokens
 
 
 def paged_decode(
@@ -260,34 +268,36 @@ def paged_decode(
     cfg: LlamaConfig,
     *,
     active: jax.Array,          # [B] bool
-) -> Tuple[jax.Array, PagedKVCache]:
+) -> Tuple[jax.Array, PagedKVCache, Optional[MoeLoad]]:
     """One decode step over the paged pool: write each slot's token into
-    its current page cell, attend over its pages, return [B, V] logits
-    and the updated cache."""
+    its current page cell, attend over its pages, return [B, V] logits,
+    the updated cache and the step's ``MoeLoad`` (None for a dense
+    model)."""
     B = tokens.shape[0]
     page = cache.page_size
     page_ids = cache.page_table[jnp.arange(B), cache.lengths // page]
     offsets = cache.lengths % page
     x = params["embed"][tokens][:, None].astype(cfg.dtype)
+    layers, expert_stack = split_expert_stack(cfg, params["layers"])
 
     def body(carry, layer_in):
         x = carry
         lp, ck, cv = layer_in
-        x, ck, cv = _layer_paged_decode(
+        x, ck, cv, expert_tokens = _layer_paged_decode(
             cfg, lp, x, ck, cv, cache.page_table, cache.lengths,
-            page_ids, offsets, active,
+            page_ids, offsets, active, expert_stack=expert_stack,
         )
-        return x, (ck, cv)
+        return x, (ck, cv, expert_tokens)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], cache.k, cache.v)
+    x, (new_k, new_v, expert_tokens) = jax.lax.scan(
+        body, x, (layers, cache.k, cache.v)
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bm,mv->bv", x[:, 0], params["lm_head"])
     lengths = jnp.where(active, cache.lengths + 1, cache.lengths)
     return logits.astype(jnp.float32), PagedKVCache(
         new_k, new_v, cache.page_table, lengths
-    )
+    ), MoeLoad.of_layers(expert_tokens)
 
 
 def paged_prefill(
@@ -298,14 +308,15 @@ def paged_prefill(
     cfg: LlamaConfig,
     slot: int | jax.Array,
     pages: jax.Array,           # [S_bucket // page] page ids for this slot
-) -> Tuple[jax.Array, PagedKVCache]:
+) -> Tuple[jax.Array, PagedKVCache, Optional[MoeLoad]]:
     """Prefill one request through the dense single-row path, then scatter
     the resulting rows into the slot's pool pages. The bucket length must
-    be a multiple of the page size (buckets are powers of two >= page)."""
+    be a multiple of the page size (buckets are powers of two >= page).
+    Returns the run's ``MoeLoad`` too (None for a dense model)."""
     S = tokens.shape[1]
     page = cache.page_size
     small = KVCache.create(cfg, 1, S)
-    logits, small = forward_with_cache(
+    logits, small, load = _forward_with_cache(
         params, tokens, small, cfg,
         last_index=real_len[None] - 1, append_len=real_len[None],
     )
@@ -320,7 +331,7 @@ def paged_prefill(
     k = cache.k.at[:, :, pages].set(k_pages.astype(cache.k.dtype))
     v = cache.v.at[:, :, pages].set(v_pages.astype(cache.v.dtype))
     lengths = cache.lengths.at[slot].set(real_len)
-    return logits, PagedKVCache(k, v, cache.page_table, lengths)
+    return logits, PagedKVCache(k, v, cache.page_table, lengths), load
 
 
 def sample_logits(logits: jax.Array, rng: jax.Array, *,

@@ -1,7 +1,8 @@
 """Llama-family transformer, TPU-first.
 
-The flagship model (BASELINE.json configs: Llama-3 8B/70B, Mixtral 8x7B via
-``n_experts``). Design choices for TPU/XLA:
+The flagship model (BASELINE.json configs: Llama-3 8B/70B; a mixture of
+experts via ``n_experts``, routed as OLMoE routes: parallel/moe.py).
+Design choices for TPU/XLA:
 
 - Pure-functional: params are a pytree of arrays; sharding is declared as a
   matching pytree of logical axes (parallel/sharding.py rules) — pjit/GSPMD
@@ -48,10 +49,17 @@ class LlamaConfig:
     rope_theta: float = 500_000.0
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # MoE (Mixtral-style) when n_experts > 0.
+    # MoE when n_experts > 0: softmax over all experts, top_k, gates not
+    # renormalised over the chosen ones (parallel/moe.py; dropless).
     n_experts: int = 0
     top_k: int = 2
+    # Read by nothing since the dropless dispatch (PR 28). It stays only
+    # because tests/bench_harness/moe_tiny pins it and a model_config PR
+    # may not edit the benchmark's files; ROADMAP D5 removes both.
     capacity_factor: float = 1.25
+    # RMSNorm over the whole q and the whole k projection, before the
+    # split into heads and before rotary (OLMoE's q_norm / k_norm).
+    qk_norm: bool = False
     remat: bool = True
     # "full" (save only layer inputs), "dots" (save matmul outputs,
     # recompute elementwise), or "save_all" (save every intermediate —
@@ -103,13 +111,6 @@ class LlamaConfig:
         )
 
     @staticmethod
-    def mixtral_8x7b() -> "LlamaConfig":
-        return LlamaConfig(
-            hidden_size=4096, intermediate_size=14_336, num_layers=32,
-            num_heads=32, num_kv_heads=8, n_experts=8, top_k=2,
-        )
-
-    @staticmethod
     def tiny(vocab: int = 256, moe: bool = False) -> "LlamaConfig":
         return LlamaConfig(
             vocab_size=vocab, hidden_size=64, intermediate_size=128,
@@ -129,6 +130,8 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         "wo": ("layers", "heads", "head_dim", "embed"),
         "mlp_norm": ("layers", "norm"),
     }
+    if cfg.qk_norm:
+        layer.update(q_norm=("layers", "norm"), k_norm=("layers", "norm"))
     if cfg.n_experts > 0:
         layer.update(
             router=("layers", "embed", None),
@@ -171,6 +174,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "wo": winit(next(k), (L, H, Dh, M), H * Dh),
         "mlp_norm": norm_init((L, M)),
     }
+    if cfg.qk_norm:
+        layers.update(q_norm=norm_init((L, H * Dh)),
+                      k_norm=norm_init((L, Hkv * Dh)))
     if cfg.n_experts > 0:
         E = cfg.n_experts
         layers.update(
@@ -234,28 +240,57 @@ def _attention(cfg: LlamaConfig, mesh, q, k, v):
     return mha_attention(q, k, v, causal=True)
 
 
-def _layer(cfg: LlamaConfig, mesh, positions, x, lp):
-    """One transformer block. x [B, S, M]."""
+def qkv_proj(cfg: LlamaConfig, lp, x):
+    """The block's first half up to rotary, shared by the training,
+    cached and paged blocks: attention norm, then q [B,S,H,Dh] and k, v
+    [B,S,Hkv,Dh], q and k normed over their whole projection where the
+    model has a QK-norm."""
+    B, S, _ = x.shape
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
-    kk = jnp.einsum("bsm,mhd->bshd", h, lp["wk"])
-    vv = jnp.einsum("bsm,mhd->bshd", h, lp["wv"])
-    q = rope(q, positions, cfg.rope_theta)
-    kk = rope(kk, positions, cfg.rope_theta)
-    q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"),
-                                mesh=mesh)
-    attn = _attention(cfg, mesh, q, kk, vv)
-    x = x + jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
+    k = jnp.einsum("bsm,mhd->bshd", h, lp["wk"])
+    v = jnp.einsum("bsm,mhd->bshd", h, lp["wv"])
+    if cfg.qk_norm:
+        q = rms_norm(q.reshape(B, S, -1), lp["q_norm"],
+                     cfg.rms_eps).reshape(q.shape)
+        k = rms_norm(k.reshape(B, S, -1), lp["k_norm"],
+                     cfg.rms_eps).reshape(k.shape)
+    return q, k, v
 
+
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def split_expert_stack(cfg: LlamaConfig, layers):
+    """(what a layer scan slices, what it must read in place): a MoE
+    model's expert weights stay whole beside the scan, with the layer's
+    index among the scanned (``ffn``'s ``expert_stack``; why:
+    parallel/moe.py). A dense model's layers come back as they are."""
+    if cfg.n_experts == 0:
+        return layers, None
+    scanned = {k: v for k, v in layers.items() if k not in EXPERT_WEIGHTS}
+    scanned["index"] = jnp.arange(cfg.num_layers)
+    return scanned, {k: layers[k] for k in EXPERT_WEIGHTS}
+
+
+def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
+        expert_stack=None):
+    """The block's second half, shared likewise: MLP norm, then the
+    SiLU-gated MLP or the mixture of experts, added to x [B,S,M].
+    Returns (x, load-balancing loss, tokens assigned to each expert [E]
+    or None for a dense model). ``token_mask`` [B,S] keeps rows (inactive
+    decode slots, bucket padding) away from every expert. The experts'
+    weights are ``lp``'s own, or with ``expert_stack`` (the second half
+    of ``split_expert_stack``) all layers', read at ``lp["index"]``."""
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if cfg.n_experts > 0:
-        out, aux = moe_ffn(
-            h, lp["router"], lp["w_up"], lp["w_down"],
-            k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-            w_gate=lp["w_gate"],
+        w = lp if expert_stack is None else expert_stack
+        out, aux, expert_tokens = moe_ffn(
+            h, lp["router"], w["w_up"], w["w_down"], k=cfg.top_k,
+            w_gate=w["w_gate"], token_mask=token_mask,
+            layer=None if expert_stack is None else lp["index"],
         )
-        x = x + out
-        return x, aux
+        return x + out, aux, expert_tokens
     up = jnp.einsum("bsm,mf->bsf", h, lp["w_up"])
     gate = jnp.einsum("bsm,mf->bsf", h, lp["w_gate"])
     # Named for the selective "mlp" remat policy: saving these two
@@ -267,7 +302,20 @@ def _layer(cfg: LlamaConfig, mesh, positions, x, lp):
     h = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
     h = with_logical_constraint(h, ("batch", "seq", "mlp"), mesh=mesh)
     x = x + jnp.einsum("bsf,fm->bsm", h, lp["w_down"])
-    return x, jnp.zeros((), dtype=jnp.float32)
+    return x, jnp.zeros((), dtype=jnp.float32), None
+
+
+def _layer(cfg: LlamaConfig, mesh, positions, x, lp):
+    """One transformer block. x [B, S, M]."""
+    q, kk, vv = qkv_proj(cfg, lp, x)
+    q = rope(q, positions, cfg.rope_theta)
+    kk = rope(kk, positions, cfg.rope_theta)
+    q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"),
+                                mesh=mesh)
+    attn = _attention(cfg, mesh, q, kk, vv)
+    x = x + jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
+    x, aux, _ = ffn(cfg, lp, x, mesh=mesh)
+    return x, aux
 
 
 def forward(

@@ -1,11 +1,19 @@
-"""Mixture-of-experts with expert parallelism.
+"""Mixture-of-experts FFN: dropless, sorted, grouped.
 
-Absent from the reference (SURVEY.md §2.5 — no EP/MoE in Ray); built
-TPU-native: Switch/Top-k routing with *static capacity* (XLA needs static
-shapes — no ragged dispatch), experts sharded over the "ep" mesh axis via
-logical axis "expert". The dispatch/combine einsums carry sharding
-constraints so XLA emits the all-to-alls over ICI (the reference-world
-equivalent would be NCCL all-to-all in e.g. DeepSpeed-MoE).
+Absent from the reference (SURVEY.md §2.5 — no EP/MoE in Ray). Routing
+is softmax over all experts, top-k, gates NOT renormalised over the
+chosen k (OLMoE's ``norm_topk_prob=false``; Mixtral renormalises, and a
+model that needs that divides its gates by their sum before calling).
+
+One path, for training, prefill and decode: the ``k * T`` (token, expert)
+assignments are sorted by expert, the tokens' rows gathered in that
+order, and the three expert matmuls run as grouped matmuls over
+``group_sizes`` (``jax.lax.ragged_dot``), so every assignment is
+computed, none is dropped, and nothing has a capacity axis. Rows of a
+``token_mask`` (inactive decode slots, a prefill bucket's padding) sort
+behind the last group and belong to no expert. Experts are sharded over
+the "ep" mesh axis by their weights' logical axis "expert"; GSPMD
+partitions the grouped matmuls (an all-to-all layout is ROADMAP R3's).
 """
 
 from __future__ import annotations
@@ -15,85 +23,85 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .sharding import with_logical_constraint
-
-
-def top_k_routing(
-    router_logits: jax.Array,  # [tokens, E]
-    k: int,
-    capacity: int,
-    token_mask: Optional[jax.Array] = None,  # [T] 1=route, 0=ignore
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Compute dispatch/combine tensors for top-k token→expert routing with
-    per-expert capacity. Returns (dispatch [T,E,C] bool-ish, combine
-    [T,E,C] float weights, aux_loss scalar: Switch load-balancing loss).
-
-    ``token_mask`` removes tokens from routing entirely — they claim no
-    expert capacity and produce zero output (the decode-engine case:
-    inactive batch slots must not steal capacity from live requests)."""
-    T, E = router_logits.shape
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)            # [T,k]
-    # One-hot per choice: [k, T, E]
-    onehot = jax.nn.one_hot(expert_idx.T, E, dtype=jnp.float32)
-    if token_mask is not None:
-        onehot = onehot * token_mask.astype(jnp.float32)[None, :, None]
-    # Position of each token within its expert's queue, counted over the
-    # flattened (choice-major, then token) order so earlier choices win.
-    flat = onehot.reshape(k * T, E)
-    pos = jnp.cumsum(flat, axis=0) - flat                       # [k*T, E]
-    within_cap = (pos < capacity) * flat
-    pos_clamped = jnp.minimum(pos, capacity - 1).astype(jnp.int32)
-    cap_onehot = jax.nn.one_hot(pos_clamped, capacity, dtype=jnp.float32)
-    disp_flat = within_cap[..., None] * cap_onehot              # [k*T, E, C]
-    dispatch = disp_flat.reshape(k, T, E, capacity).sum(axis=0)  # [T,E,C]
-    gates = (onehot * gate_vals.T[..., None]).reshape(k * T, E)
-    combine_flat = (gates * within_cap)[..., None] * cap_onehot
-    combine = combine_flat.reshape(k, T, E, capacity).sum(axis=0)
-    # Switch aux loss: E * sum_e f_e * p_e (fraction routed × mean prob).
-    frac = onehot[0].mean(axis=0) if k == 1 else onehot.sum(0).mean(0) / k
-    mean_prob = probs.mean(axis=0)
-    aux_loss = E * jnp.sum(frac * mean_prob)
-    return dispatch, combine, aux_loss
-
 
 def moe_ffn(
     x: jax.Array,           # [B, S, M]
     router_w: jax.Array,    # [M, E]
-    w_in: jax.Array,        # [E, M, F]   (gate/up fused optional: see w_gate)
+    w_in: jax.Array,        # [E, M, F]
     w_out: jax.Array,       # [E, F, M]
     *,
     k: int = 2,
-    capacity_factor: float = 1.25,
     w_gate: Optional[jax.Array] = None,  # [E, M, F] for gated (SwiGLU) experts
     activation=jax.nn.silu,
     token_mask: Optional[jax.Array] = None,  # [B, S] 1=route, 0=ignore
-) -> Tuple[jax.Array, jax.Array]:
-    """Expert-parallel FFN block (Mixtral-style when w_gate given).
-    Returns (output [B,S,M], aux_loss)."""
+    layer: Optional[jax.Array] = None,  # [] int32: the weights are stacks
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Returns (output [B,S,M], Switch load-balancing loss, tokens
+    assigned to each expert [E] int32). Masked rows give zero, reach no
+    expert and are not counted.
+
+    With ``layer``, ``w_in``, ``w_gate`` and ``w_out`` are all layers'
+    experts ``[L, E, ...]`` and this layer's are read where they lie, as
+    groups ``layer * E ..`` of ``L * E`` with every other group empty. A
+    layer scan that slices its layer's experts out first copies them
+    (805 MB a layer at OLMoE's widths, a third of the decode step: chip
+    run, PR 28): the grouped matmul is a custom call and cannot read a
+    slice in place. The serving programs pass the stack; training
+    slices, where a stack's gradient would be summed whole per layer."""
     B, S, M = x.shape
     E = router_w.shape[1]
     T = B * S
-    capacity = max(1, int(capacity_factor * k * T / E))
     xt = x.reshape(T, M)
-    router_logits = jnp.einsum(
-        "tm,me->te", xt.astype(jnp.float32), router_w.astype(jnp.float32)
-    )
-    dispatch, combine, aux = top_k_routing(
-        router_logits, k, capacity,
-        token_mask=(token_mask.reshape(T) if token_mask is not None
-                    else None),
-    )
-    # Dispatch tokens to expert buffers: [E, C, M]; "expert" shards over ep.
-    expert_in = jnp.einsum("tec,tm->ecm", dispatch.astype(x.dtype), xt)
-    expert_in = with_logical_constraint(expert_in, ("expert", None, None))
-    h = jnp.einsum("ecm,emf->ecf", expert_in, w_in)
-    if w_gate is not None:
-        g = jnp.einsum("ecm,emf->ecf", expert_in, w_gate)
-        h = activation(g) * h
-    else:
-        h = activation(h)
-    expert_out = jnp.einsum("ecf,efm->ecm", h, w_out)
-    expert_out = with_logical_constraint(expert_out, ("expert", None, None))
-    out = jnp.einsum("tec,ecm->tm", combine.astype(x.dtype), expert_out)
-    return out.reshape(B, S, M), aux
+    with jax.named_scope("moe.route"):
+        router_logits = jnp.einsum(
+            "tm,me->te", xt.astype(jnp.float32), router_w.astype(jnp.float32)
+        )
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        gates, experts = jax.lax.top_k(probs, k)               # [T, k]
+        chosen = jax.nn.one_hot(experts, E, dtype=jnp.float32).sum(axis=1)
+        if token_mask is not None:
+            live = token_mask.reshape(T).astype(bool)
+            chosen = chosen * live[:, None]
+            # Expert E does not exist: the masked rows sort last, behind
+            # every group.
+            experts = jnp.where(live[:, None], experts, E)
+            gates = gates * live[:, None]
+        expert_tokens = chosen.sum(axis=0)                     # [E]
+        # Switch aux loss: E * sum_e f_e * p_e (share routed x mean prob).
+        aux = E * jnp.sum(expert_tokens / (k * T) * probs.mean(axis=0))
+        group_sizes = expert_tokens.astype(jnp.int32)
+        order = jnp.argsort(experts.reshape(T * k))            # stable
+        rows = xt[order // k]                                  # [k*T, M]
+        if token_mask is not None:
+            # Rows behind the last group: a grouped matmul leaves there
+            # whatever the backend does (zeros on the CPU, not on the
+            # TPU: chip run, PR 28), forward and backward, so they are
+            # cut off on the way in and on the way out.
+            routed = (jnp.arange(T * k) < group_sizes.sum())[:, None]
+            rows = jnp.where(routed, rows, 0)
+    with jax.named_scope("moe.experts"):
+        groups = group_sizes
+        if layer is not None:
+            L = w_in.shape[0]
+            groups = jnp.zeros((L, E), jnp.int32).at[layer].set(
+                group_sizes).reshape(L * E)
+            w_in, w_out, w_gate = (
+                None if w is None else w.reshape((L * E,) + w.shape[2:])
+                for w in (w_in, w_out, w_gate))
+        h = jax.lax.ragged_dot(rows, w_in, groups)
+        if w_gate is not None:
+            g = jax.lax.ragged_dot(rows, w_gate, groups)
+            h = activation(g.astype(jnp.float32)).astype(h.dtype) * h
+        else:
+            h = activation(h)
+        y = jax.lax.ragged_dot(h, w_out, groups)               # [k*T, M]
+        if token_mask is not None:
+            y = jnp.where(routed, y, 0)
+    with jax.named_scope("moe.combine"):
+        # Back to token order (a gather by the inverse permutation, no
+        # scatter-add), then the k results of a token weighted by its
+        # gates and summed in float32.
+        back = jnp.argsort(order)
+        out = jnp.einsum("tkm,tk->tm", y[back].reshape(T, k, M), gates,
+                         preferred_element_type=jnp.float32)
+    return out.astype(x.dtype).reshape(B, S, M), aux, group_sizes

@@ -174,13 +174,31 @@ class LLMEngine:
         self._phase_s = dict.fromkeys(_PHASES, 0.0)
         self._finished_rows: "collections.deque[List]" = collections.deque(
             maxlen=_REQUEST_ROWS)
+        # Expert load of a MoE model, read back behind each program's
+        # tokens (``with_load`` below); None for a dense one.
+        self._moe: Optional[Dict[str, Any]] = None
+        if cfg.n_experts > 0:
+            self._moe = {"assignments": 0, "decode_assignments": 0,
+                         "experts_reached": 0, "layer_steps": 0,
+                         "prefill_experts_reached": 0,
+                         "expert_tokens": np.zeros(cfg.n_experts, np.int64)}
+
+        def with_load(tokens, load):
+            """The tokens a program sampled and, for a MoE model, its
+            expert load behind them in the same int32 vector: one
+            read-back a step, as for a dense model (whose programs
+            return the tokens alone)."""
+            if load is None:
+                return tokens
+            return jnp.concatenate([
+                tokens, load.expert_tokens, load.experts_reached[None]])
 
         def decode_step(params, cache, last_tok, active, key):
-            logits, cache = paged_decode(
+            logits, cache, load = paged_decode(
                 params, last_tok, cache, cfg, active=active
             )
             nxt = sample_logits(logits, key, temperature=temperature)
-            return nxt, cache
+            return with_load(nxt, load), cache
 
         from ..util.device_metrics import instrumented_jit
 
@@ -198,12 +216,12 @@ class LLMEngine:
                                         tap_stride=64)
 
         def prefill(params, cache, tokens, real_len, slot, pages):
-            logits, cache = paged_prefill(
+            logits, cache, load = paged_prefill(
                 params, tokens, real_len, cache, cfg, slot, pages
             )
             nxt = sample_logits(logits, jax.random.PRNGKey(0),
                                 temperature=temperature)
-            return cache, nxt[0]
+            return cache, (nxt[0] if load is None else with_load(nxt, load))
 
         self._prefill = instrumented_jit(prefill, donate_argnums=(1,))
         self._rng = jax.random.PRNGKey(0)
@@ -266,6 +284,16 @@ class LLMEngine:
         through), ``inputs``, ``decode`` (the dispatch), ``readback`` (the
         host waiting for the device), ``emit``, ``idle`` (the 2 ms poll).
 
+        ``moe``, for a model with experts only: ``expert_tokens`` (a list
+        of E: (token, expert) assignments each expert was given, prefills
+        and decode steps, summed over layers), ``assignments`` (their
+        sum) and ``decode_assignments`` (the decode steps' part of it);
+        ``experts_reached`` ((layer, expert) pairs that a decode
+        step gave at least one token, summed over decode steps) over
+        ``layer_steps`` (decode steps x layers) is the experts a layer of
+        a decode step read; ``prefill_experts_reached`` is the same count
+        over prefills.
+
         ``requests``: the newest requests that have finished and those now
         decoding, each ``[t_submit, t_admit, t_first, t_done or None,
         prompt_len, bucket]`` in ``time.time()`` seconds and tokens."""
@@ -290,6 +318,9 @@ class LLMEngine:
                 "total_pages": self.total_pages,
                 "page_size": self.page_size,
                 "decode_attention": self._decode_attention,
+                **({"moe": {**self._moe, "expert_tokens":
+                            self._moe["expert_tokens"].tolist()}}
+                   if self._moe else {}),
             }
 
     def shutdown(self):
@@ -404,7 +435,8 @@ class LLMEngine:
                         jnp.asarray(slot, dtype=jnp.int32),
                         jnp.asarray(prefill_pages, dtype=jnp.int32),
                     )
-                    first = int(first)
+                    first = int(self._tokens(
+                        np.asarray(first).reshape(-1), 1, decode=False)[0])
             except Exception as e:  # noqa: BLE001
                 self._close(req, e)
                 self._release_slot(slot)
@@ -424,6 +456,26 @@ class LLMEngine:
                 self._slot_req[slot] = req
             self._last_tok[slot] = first
             self._finish_if_done(slot, req, first)
+
+    def _tokens(self, out: np.ndarray, n: int, decode: bool) -> np.ndarray:
+        """The ``n`` tokens at the head of a program's read-back; what a
+        MoE model's program packed behind them (``with_load``) goes to
+        the expert-load counters, a decode step's apart from a
+        prefill's where ``stats()`` tells them apart."""
+        moe = self._moe
+        if moe is not None:
+            expert_tokens = out[n:-1]
+            with self._lock:
+                assignments = int(expert_tokens.sum())
+                moe["expert_tokens"] += expert_tokens
+                moe["assignments"] += assignments
+                if decode:
+                    moe["decode_assignments"] += assignments
+                    moe["experts_reached"] += int(out[-1])
+                    moe["layer_steps"] += self.cfg.num_layers
+                else:
+                    moe["prefill_experts_reached"] += int(out[-1])
+        return out[:n]
 
     def _finish_if_done(self, slot: int, req: _Request, tok: int):
         if (len(req.output) >= req.max_new_tokens
@@ -495,6 +547,7 @@ class LLMEngine:
             lap("readback")
             with span("engine.emit"):
                 self._step_count += 1
+                nxt = self._tokens(nxt, self.max_batch, decode=True)
                 counts["decode_slot_steps"] += len(active_slots)
                 # The step attended to each prompt and every token
                 # generated before this one.

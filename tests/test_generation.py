@@ -68,16 +68,10 @@ def test_decode_respects_active_mask():
 
 @pytest.mark.slow
 def test_moe_cached_decode_matches_naive():
-    """MoE (Mixtral-style) models decode through the KV cache (r1 gap:
-    generation.py raised NotImplementedError for MoE)."""
-    import dataclasses
-
-    # capacity_factor high enough that no token is dropped: with drops,
-    # full-sequence and incremental eval legitimately group tokens
-    # differently and exact equality is not defined.
-    cfg = dataclasses.replace(
-        LlamaConfig.tiny(moe=True), capacity_factor=8.0
-    )
+    """MoE models decode through the KV cache (r1 gap: generation.py
+    raised NotImplementedError for MoE). The dispatch drops no token,
+    so full-sequence and incremental evaluation agree."""
+    cfg = LlamaConfig.tiny(moe=True)
     params = init_params(cfg, jax.random.PRNGKey(1))
     prompt = jnp.asarray(np.random.RandomState(1).randint(0, 256, (2, 6)))
     naive = _naive_greedy(params, prompt, cfg, 5)
@@ -208,3 +202,115 @@ def test_decode_attention_path_follows_platform_and_shape(monkeypatch):
     assert pa.decode_attention_path(16, 128) == "page_walk"
     assert pa.decode_attention_path(16, 64) == "gather"
     assert pa.decode_attention_path(8, 128) == "gather"
+
+
+def _tiny_olmoe():
+    """(the configuration dict, the program's cfg, seeded float32
+    weights): OLMoE's block at a tiny size, 8 experts top-2, QK-norm,
+    built by the benchmark's own builder."""
+    import json
+    import os
+
+    from benchmark import arch
+
+    with open(os.path.join(os.path.dirname(__file__), "bench_harness",
+                           "olmoe_tiny", "config.json")) as f:
+        config = json.load(f)
+    cfg = arch.program_config(config)
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    # Norm weights off one, so that a norm left out cannot pass.
+    rng = np.random.RandomState(4)
+    for name in ("attn_norm", "mlp_norm", "q_norm", "k_norm"):
+        leaf = params["layers"][name]
+        params["layers"][name] = leaf + jnp.asarray(
+            rng.uniform(-0.3, 0.3, leaf.shape), leaf.dtype)
+    return config, cfg, params
+
+
+def _reference_logits(config, params, tokens):
+    from benchmark import olmoe_reference as reference
+
+    x, _ = reference.hidden(params, tokens, config)
+    return jnp.einsum("bsm,mv->bsv", x, params["lm_head"],
+                      precision="highest")
+
+
+def test_tiny_olmoe_has_the_qk_norm_and_the_training_block_uses_it():
+    config, cfg, params = _tiny_olmoe()
+    assert cfg.qk_norm and cfg.n_experts == 8 and cfg.top_k == 2
+    assert params["layers"]["q_norm"].shape == (2, 64)
+    assert params["layers"]["k_norm"].shape == (2, 64)
+    tokens = jnp.asarray(np.random.RandomState(5).randint(0, 256, (2, 24)))
+    logits, _ = forward(params, tokens, cfg)
+    expected = _reference_logits(config, params, tokens)
+    # float32 on both sides: the order of sums alone differs.
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(expected),
+                               atol=1e-4, rtol=1e-4)
+    flat = dict(params, layers=dict(
+        params["layers"], q_norm=jnp.ones_like(params["layers"]["q_norm"])))
+    off, _ = forward(flat, tokens, cfg)
+    assert float(jnp.abs(off - expected).max()) > 1e-2
+
+
+@pytest.mark.parametrize("real_len", [5, 16, 27])
+def test_tiny_olmoe_paged_prefill_then_decode_equals_the_reference(real_len):
+    """A prompt padded to its bucket through ``paged_prefill``, then
+    tokens one at a time through ``paged_decode`` beside idle slots,
+    against the float32 reference's FULL forward pass of the same
+    sequence: logits compared. Tolerance 1e-4: float32 on both sides,
+    the sums in another order; a bucket's padding or an idle slot
+    reaching an expert, a norm left out or a dropped assignment is off
+    by more than 1e-2."""
+    from ray_tpu.models.generation import (
+        PagedKVCache, paged_decode, paged_prefill)
+
+    config, cfg, params = _tiny_olmoe()
+    rng = np.random.RandomState(real_len)
+    steps, page, slots, slot = 4, 16, 3, 1
+    seq = rng.randint(0, 256, real_len + steps)
+    bucket = 16 if real_len <= 16 else 32
+    expected = np.asarray(_reference_logits(
+        config, params, jnp.asarray(seq[None])))[0]
+
+    cache = PagedKVCache.create(cfg, slots, 8, page, 4)
+    pages = [5, 2, 7]                      # the slot's pages, out of order
+    table = np.zeros((slots, 4), np.int32)
+    table[slot, :3] = pages
+    cache = cache._replace(page_table=jnp.asarray(table))
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :real_len] = seq[:real_len]
+    logits, cache, load = paged_prefill(
+        params, jnp.asarray(padded), jnp.asarray(real_len, jnp.int32),
+        cache, cfg, slot, jnp.asarray(pages[:bucket // page], jnp.int32))
+    np.testing.assert_allclose(np.asarray(logits)[0], expected[real_len - 1],
+                               atol=1e-4, rtol=1e-4)
+    # The bucket's padding reached no expert: real tokens x k x layers.
+    assert int(load.expert_tokens.sum()) == real_len * 2 * 2
+    # (layer, expert) pairs: at least the experts seen in any layer.
+    assert int((np.asarray(load.expert_tokens) > 0).sum()) \
+        <= int(load.experts_reached) <= min(16, real_len * 2 * 2)
+
+    active = jnp.asarray(np.arange(slots) == slot)
+    for i in range(steps):
+        last = np.zeros(slots, np.int32)
+        last[slot] = seq[real_len + i]
+        logits, cache, load = paged_decode(
+            params, jnp.asarray(last), cache, cfg, active=active)
+        np.testing.assert_allclose(
+            np.asarray(logits)[slot], expected[real_len + i],
+            atol=1e-4, rtol=1e-4)
+        # One live slot: 2 experts in each of 2 layers, whatever idles.
+        assert int(load.expert_tokens.sum()) == 4
+        assert int(load.experts_reached) == 4
+    assert int(cache.lengths[slot]) == real_len + steps
+
+
+def test_dense_programs_return_no_expert_load():
+    from ray_tpu.models.generation import PagedKVCache, paged_decode
+
+    cfg = LlamaConfig.tiny()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    cache = PagedKVCache.create(cfg, 2, 4, 16, 2)
+    _, _, load = paged_decode(params, jnp.zeros(2, jnp.int32), cache, cfg,
+                              active=jnp.asarray([True, False]))
+    assert load is None

@@ -236,3 +236,48 @@ def test_llm_serve_sse_streaming(ray_tpu_start):
     finally:
         stop_proxy()
         serve.shutdown()
+
+
+def test_engine_counts_expert_load_for_a_moe_model_only(tiny_model):
+    """``stats()["moe"]``: every real token of a prefill and every live
+    slot of a decode step is given ``top_k`` experts in each layer, and
+    nothing else is (bucket padding, idle slots); absent for a dense
+    model."""
+    cfg, params = tiny_model
+    dense = LLMEngine(cfg, params, max_batch=2, max_len=64)
+    try:
+        dense.generate([1, 2, 3], max_new_tokens=2)
+        assert "moe" not in dense.stats()
+    finally:
+        dense.shutdown()
+
+    cfg = LlamaConfig.tiny(moe=True)
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    engine = LLMEngine(cfg, params, max_batch=4, max_len=64)
+    try:
+        prompts = [list(np.random.RandomState(i).randint(0, 256, n))
+                   for i, n in enumerate((5, 17, 9))]
+        reqs = [engine.submit(p, 6) for p in prompts]
+        outs = [r.result(timeout=180) for r in reqs]
+        expected = [np.asarray(generate(
+            params, jnp.asarray([p]), cfg, max_new_tokens=6))[0].tolist()
+            for p in prompts]
+        assert outs == expected
+        stats = engine.stats()
+        moe = stats["moe"]
+        per_token = cfg.top_k * cfg.num_layers
+        assert moe["decode_assignments"] == \
+            stats["decode_slot_steps"] * per_token
+        assert moe["assignments"] == (
+            stats["prefill_tokens"] + stats["decode_slot_steps"]) * per_token
+        assert sum(moe["expert_tokens"]) == moe["assignments"]
+        assert len(moe["expert_tokens"]) == cfg.n_experts
+        assert moe["layer_steps"] == stats["decode_steps"] * cfg.num_layers
+        # A layer of a decode step reaches between top_k experts and all.
+        assert cfg.top_k * moe["layer_steps"] <= moe["experts_reached"] \
+            <= cfg.n_experts * moe["layer_steps"]
+        assert cfg.top_k * cfg.num_layers * 3 \
+            <= moe["prefill_experts_reached"] \
+            <= cfg.n_experts * cfg.num_layers * 3
+    finally:
+        engine.shutdown()

@@ -196,3 +196,62 @@ def test_flash_falls_back_off_tpu():
         lambda q, k, v: flash_mod.flash_attention(q, k, v, causal=True)
     ).lower(q, k, k).as_text()
     assert "tpu_custom_call" not in text
+
+
+def _olmoe_cfg():
+    """OLMoE-1B-7B at its published widths and the benchmark's depth of
+    8: the cell ``serve-olmoe-chat``'s model."""
+    return LlamaConfig(
+        vocab_size=50_304, hidden_size=2048, intermediate_size=1024,
+        num_layers=8, num_heads=16, num_kv_heads=16, head_dim=128,
+        rope_theta=10_000.0, dtype=jnp.bfloat16, n_experts=64, top_k=8,
+        qk_norm=True,
+    )
+
+
+def _fits_one_chip(compiled):
+    memory = compiled.memory_analysis()
+    return (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < 15.75 * 2**30
+
+
+def test_olmoe_decode_program_compiles_for_v5e(v5e, as_tpu):
+    """The chat cell's decode step with the grouped expert matmuls in
+    the layer scan: 32 slots x 8 experts a token are 256 rows."""
+    cfg = _olmoe_cfg()
+    params, cache = _serve_shapes(cfg, v5e, CHAT_CELL[0], CHAT_POOL_PAGES,
+                                  CHAT_CELL[1])
+
+    def decode(params, cache, tok, active):
+        return generation.paged_decode(
+            params, tok, cache, cfg, active=active
+        )
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (CHAT_CELL[0],), jnp.int32),
+        _arr(v5e, (CHAT_CELL[0],), jnp.bool_),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the page walk
+    assert _fits_one_chip(compiled)
+
+
+def test_olmoe_prefill_program_compiles_for_v5e(v5e, as_tpu):
+    """The largest bucket, 2048 tokens: 16,384 rows through the grouped
+    matmuls beside 7.1 GB of weights and the 2.1 GB pool."""
+    cfg = _olmoe_cfg()
+    params, cache = _serve_shapes(cfg, v5e, CHAT_CELL[0], CHAT_POOL_PAGES,
+                                  CHAT_CELL[1])
+
+    def prefill(params, cache, tokens, real_len, slot, pages):
+        return generation.paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages
+        )
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (1, 2048), jnp.int32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
+        _arr(v5e, (2048 // PAGE,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # flash prefill
+    assert _fits_one_chip(compiled)
