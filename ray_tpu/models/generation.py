@@ -195,7 +195,14 @@ class PagedKVCache(NamedTuple):
     All shapes static for XLA. The pool is HEAD-MAJOR
     ([L, Hkv, P_total, page, Dh]): one copy brings a page of every KV
     head to the decode attention (ops/paged_attention.py), which reads a
-    slot's own pages where they lie."""
+    slot's own pages where they lie and, on a TPU, is also what writes a
+    decode step's token: one page a slot a layer, through an output
+    aliased to the pool. A decode step never slices, re-stacks or
+    re-lays the pool. XLA cannot write one token's row in place (a row
+    is a sixteenth of a bf16 tile; a scatter or ``dynamic_update_slice``
+    of it compiles to pool-sized re-layouts, and the pool as the layer
+    scan's xs/ys to a slice and a re-stack a layer: 28 ms of a 39 ms
+    step before PR 29, PERF.md §6)."""
 
     k: jax.Array            # [L, Hkv, P_total, page, Dh] shared pool
     v: jax.Array            # [L, Hkv, P_total, page, Dh]
@@ -220,20 +227,16 @@ class PagedKVCache(NamedTuple):
         )
 
 
-def _layer_paged_decode(cfg, lp, x, ck, cv, page_table, lengths,
-                        page_ids, offsets, active, expert_stack=None):
+def _layer_paged_decode(cfg, lp, x, k_pool, v_pool, page_table, lengths,
+                        active, expert_stack=None):
     """One block, single-token decode against the paged pool. x [B,1,M];
-    ck/cv [Hkv, P, page, Dh] (this layer's pool slice, carried by the
-    layer scan); page_ids/offsets [B] name each slot's write cell for
-    this token (inactive slots scatter past the pool → dropped). Returns
-    the tokens assigned to each expert beside x and the pool slice (None
-    for a dense model).
-
-    The layer scan slices the pool as its xs and re-stacks it as its ys:
-    pool-sized copies every step, whatever the load (ROADMAP S5; in the
-    chat cell's trace ``constant_dynamic-slice_fusion``,
-    ``copy_dynamic-update-slice_fusion`` and ``copy_bf16_16_8_2048_16_128``,
-    ~18 ms a step at a 2048-page pool: ledger, PR 25)."""
+    k_pool/v_pool the WHOLE pools [L, Hkv, P, page, Dh], carried by the
+    layer scan and used at ``lp["index"]``. The token's K/V row goes to
+    ``decode_attention``, which writes it at position ``lengths[b]`` of
+    each active slot and attends; nothing else here reads or writes the
+    pools (a second reader of what goes into the kernel's aliased call
+    would make XLA copy them). Returns x, the pools and the tokens
+    assigned to each expert (None for a dense model)."""
     q, k, v = qkv_proj(cfg, lp, x)
     q_pos = lengths[:, None]
 
@@ -242,23 +245,16 @@ def _layer_paged_decode(cfg, lp, x, ck, cv, page_table, lengths,
 
     q = jax.vmap(rope_rows)(q, q_pos)
     k = jax.vmap(rope_rows)(k, q_pos)
-    # Scatter this token's KV into each active slot's current page cell.
-    # Inactive slots aim past the pool: -1 would WRAP to the last page
-    # (NumPy semantics) and corrupt it; only >= n is truly dropped.
-    n_pages = ck.shape[1]
-    drop = jnp.where(active, page_ids, n_pages)
-    ck = ck.at[:, drop, offsets].set(
-        k[:, 0].astype(ck.dtype).transpose(1, 0, 2), mode="drop")
-    cv = cv.at[:, drop, offsets].set(
-        v[:, 0].astype(cv.dtype).transpose(1, 0, 2), mode="drop")
-    attn = decode_attention(
-        q[:, 0], ck, cv, page_table, lengths, active)[:, None]
-    x = x + jnp.einsum("bshd,hdm->bsm", attn.astype(x.dtype), lp["wo"])
+    attn, k_pool, v_pool = decode_attention(
+        q[:, 0], k[:, 0], v[:, 0], k_pool, v_pool, lp["index"],
+        page_table, lengths, active)
+    x = x + jnp.einsum("bshd,hdm->bsm", attn[:, None].astype(x.dtype),
+                       lp["wo"])
     # Inactive slots reach no expert: the experts a step reads follow
     # the live sequences.
     x, _aux, expert_tokens = ffn(cfg, lp, x, token_mask=active[:, None],
                                  expert_stack=expert_stack)
-    return x, ck, cv, expert_tokens
+    return x, k_pool, v_pool, expert_tokens
 
 
 def paged_decode(
@@ -269,28 +265,25 @@ def paged_decode(
     *,
     active: jax.Array,          # [B] bool
 ) -> Tuple[jax.Array, PagedKVCache, Optional[MoeLoad]]:
-    """One decode step over the paged pool: write each slot's token into
-    its current page cell, attend over its pages, return [B, V] logits,
-    the updated cache and the step's ``MoeLoad`` (None for a dense
-    model)."""
-    B = tokens.shape[0]
-    page = cache.page_size
-    page_ids = cache.page_table[jnp.arange(B), cache.lengths // page]
-    offsets = cache.lengths % page
+    """One decode step over the paged pool: write each active slot's
+    token into its current page cell, attend over its pages, return
+    [B, V] logits, the updated cache and the step's ``MoeLoad`` (None
+    for a dense model). The layer scan CARRIES the pools whole: as its
+    xs and ys they would be sliced and re-stacked, pool-sized copies
+    every step (PagedKVCache)."""
     x = params["embed"][tokens][:, None].astype(cfg.dtype)
     layers, expert_stack = split_expert_stack(cfg, params["layers"])
 
-    def body(carry, layer_in):
-        x = carry
-        lp, ck, cv = layer_in
-        x, ck, cv, expert_tokens = _layer_paged_decode(
-            cfg, lp, x, ck, cv, cache.page_table, cache.lengths,
-            page_ids, offsets, active, expert_stack=expert_stack,
+    def body(carry, lp):
+        x, k_pool, v_pool = carry
+        x, k_pool, v_pool, expert_tokens = _layer_paged_decode(
+            cfg, lp, x, k_pool, v_pool, cache.page_table, cache.lengths,
+            active, expert_stack=expert_stack,
         )
-        return x, (ck, cv, expert_tokens)
+        return (x, k_pool, v_pool), expert_tokens
 
-    x, (new_k, new_v, expert_tokens) = jax.lax.scan(
-        body, x, (layers, cache.k, cache.v)
+    (x, new_k, new_v), expert_tokens = jax.lax.scan(
+        body, (x, cache.k, cache.v), layers
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bm,mv->bv", x[:, 0], params["lm_head"])

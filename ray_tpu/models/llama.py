@@ -262,15 +262,15 @@ EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
 def split_expert_stack(cfg: LlamaConfig, layers):
-    """(what a layer scan slices, what it must read in place): a MoE
-    model's expert weights stay whole beside the scan, with the layer's
-    index among the scanned (``ffn``'s ``expert_stack``; why:
-    parallel/moe.py). A dense model's layers come back as they are."""
-    if cfg.n_experts == 0:
-        return layers, None
-    scanned = {k: v for k, v in layers.items() if k not in EXPERT_WEIGHTS}
+    """(what a layer scan slices, what it must read in place): the
+    scanned layers carry their ``index``, and a MoE model's expert
+    weights stay whole beside the scan, read at that index (``ffn``'s
+    ``expert_stack``; why: parallel/moe.py); None for a dense model.
+    ``paged_decode`` reads the KV pool at the same index."""
+    experts = EXPERT_WEIGHTS if cfg.n_experts > 0 else ()
+    scanned = {k: v for k, v in layers.items() if k not in experts}
     scanned["index"] = jnp.arange(cfg.num_layers)
-    return scanned, {k: layers[k] for k in EXPERT_WEIGHTS}
+    return scanned, ({k: layers[k] for k in experts} or None)
 
 
 def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,

@@ -1,29 +1,45 @@
-"""Single-token decode attention over a paged KV pool.
+"""Single-token decode over a paged KV pool: write the token, attend.
 
-The pool (models/generation.py PagedKVCache) is head-major,
-``[Hkv, P, page, Dh]`` a layer; a slot's tokens live in the pages its
-row of ``page_table`` names, position ``lengths[b]`` holding the token
-being decoded (the caller has scattered it in, so it attends).
+The pool (models/generation.py PagedKVCache) is head-major and whole,
+``[L, Hkv, P, page, Dh]``; a slot's tokens live in the pages its row of
+``page_table`` names. :func:`decode_attention` is the one place a decode
+step touches it: at layer ``layer`` it puts each active slot's new K/V
+row at position ``lengths[b]`` (page ``page_table[b, lengths[b] //
+page]``, row ``lengths[b] % page``), attends over positions ``0 ..
+lengths[b]`` and hands both pools on.
 
 Two implementations, one chosen by :func:`decode_attention_path` from
 what the code can see (platform and shape), never by a user:
 
-``page_walk`` — a Pallas TPU kernel, one program a slot. The pool stays
-in HBM; the program copies the slot's own pages, ``_BLOCK_TOKENS`` at a
-time and double-buffered, into VMEM and runs an online softmax over
-them. Its reads and arithmetic follow ``lengths``: a slot walks
+``page_walk`` — a Pallas TPU kernel, one program a slot. Both pools stay
+in HBM and come back as outputs aliased to the inputs; the program
+copies the slot's own pages at ``layer``, ``_BLOCK_TOKENS`` at a time
+and double-buffered, into VMEM and runs an online softmax over them.
+Its reads and arithmetic follow ``lengths``: a slot walks
 ``lengths[b] // page + 1`` pages (rounded up to a block), an inactive
-slot none. All KV heads of a slot are served by one program from the
-slot's ``[H, Dh]`` queries: per KV head one bf16 matmul of all H query
-rows against that head's block, of which the rows of its own GQA group
-are kept — so K and V are never repeated, and no operand is narrower
-than a tile. Operands go to the MXU in the pool's dtype with float32
-scores; running max, denominator and accumulator are float32.
+slot none. The last of those pages is the one the new row belongs to:
+the program sets the row in VMEM before the scores and copies that one
+page (``[Hkv, page, Dh]``, a whole tile a head) back to the pool. That
+is a decode step's only write to the pool, and an inactive slot makes
+none. It is made here because no XLA write of one row leaves the pool
+where it is: a row is a sixteenth of a bf16 ``(16, 128)`` tile, and for
+a scatter or a ``dynamic_update_slice`` of it XLA re-lays the whole
+pool so that a row is a tile, and back (compiled for a v5e: four
+pool-sized copies a step; with the pool sliced by the layer scan, 70%
+of the step: PERF.md §6, PR 29). Slots own disjoint pages and the grid
+runs in order, so one slot's write meets no other slot's reads. All KV
+heads of a slot are served by one program from the slot's ``[H, Dh]``
+queries: per KV head one bf16 matmul of all H query rows against that
+head's block, of which the rows of its own GQA group are kept — so K
+and V are never repeated, and no operand is narrower than a tile.
+Operands go to the MXU in the pool's dtype with float32 scores; running
+max, denominator and accumulator are float32.
 
 ``gather`` — plain XLA, for any platform and shape (tier-1 runs it on
-CPU): gather every slot's ``Pmax`` pages, attend densely with the GQA
-group as a dimension of the queries, mask by length. Its work is in
-proportion to ``B * Pmax * page`` whatever ``lengths`` says.
+CPU): scatter the rows into the whole pool, gather every slot's
+``Pmax`` pages of the layer, attend densely with the GQA group as a
+dimension of the queries, mask by length. It copies the pool, and its
+work is in proportion to ``B * Pmax * page`` whatever ``lengths`` says.
 """
 
 from __future__ import annotations
@@ -40,20 +56,24 @@ _NEG_INF = -1e30
 _BLOCK_TOKENS = 128
 
 
-def _page_walk_kernel(pt_ref, np_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+def _page_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, kn_ref,
+                      vn_ref, k_hbm, v_hbm, o_ref, k_out, v_out,
                       k_buf, v_buf, sems, *, pmax: int, scale: float):
     """Grid (B,). pt_ref [B * Pmax], np_ref [B] (pages to walk), len_ref
-    [B] in SMEM; q_ref/o_ref [H, D] this slot's rows; k_hbm/v_hbm the
-    pool [Hkv, P, page, D] left in HBM; k_buf/v_buf [2, Hkv, block, D]
-    VMEM; sems [2, 2] DMA (k/v, buffer)."""
+    [B], layer_ref [1] in SMEM; q_ref/o_ref [H, D] this slot's rows;
+    kn_ref/vn_ref [Hkv, 1, D] its new K/V row; k_hbm/v_hbm the pools
+    [L, Hkv, P, page, D] left in HBM and k_out/v_out the same buffers as
+    outputs; k_buf/v_buf [2, Hkv, block, D] VMEM; sems [3, 2] DMA (k
+    and v in by buffer, then k and v back)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
     H, D = q_ref.shape
     _, Hkv, block, _ = k_buf.shape
-    page = k_hbm.shape[2]
+    page = k_hbm.shape[3]
     pages_per_block = block // page
+    layer = layer_ref[0]
     n_pages = np_ref[b]
     n_blocks = (n_pages + pages_per_block - 1) // pages_per_block
     length = len_ref[b]
@@ -68,12 +88,30 @@ def _page_walk_kernel(pt_ref, np_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             pid = pt_ref[b * pmax + p]
             rows = pl.ds(j * page, page)
             out.append(pltpu.make_async_copy(
-                k_hbm.at[:, pid], k_buf.at[buf, :, rows, :],
+                k_hbm.at[layer, :, pid], k_buf.at[buf, :, rows, :],
                 sems.at[0, buf]))
             out.append(pltpu.make_async_copy(
-                v_hbm.at[:, pid], v_buf.at[buf, :, rows, :],
+                v_hbm.at[layer, :, pid], v_buf.at[buf, :, rows, :],
                 sems.at[1, buf]))
         return out
+
+    # The page that takes the new row is the walk's last, in the last
+    # block's buffer.
+    last = n_blocks - 1
+    pid_new = pt_ref[b * pmax + jnp.maximum(n_pages - 1, 0)]
+    rows_new = pl.ds(pl.multiple_of(
+        (n_pages - 1 - last * pages_per_block) * page, page), page)
+
+    def write_back():
+        buf = last % 2
+        return [
+            pltpu.make_async_copy(k_buf.at[buf, :, rows_new, :],
+                                  k_out.at[layer, :, pid_new],
+                                  sems.at[2, 0]),
+            pltpu.make_async_copy(v_buf.at[buf, :, rows_new, :],
+                                  v_out.at[layer, :, pid_new],
+                                  sems.at[2, 1]),
+        ]
 
     @pl.when(n_blocks > 0)
     def _first():
@@ -96,6 +134,17 @@ def _page_walk_kernel(pt_ref, np_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         for c in copies(i, buf):
             c.wait()
+
+        @pl.when(i == last)
+        def _new_row():
+            is_new = jax.lax.broadcasted_iota(
+                jnp.int32, (Hkv, page, D), 1) == length % page
+            for ref, new in ((k_buf, kn_ref), (v_buf, vn_ref)):
+                rows = ref[buf, :, rows_new, :]
+                ref[buf, :, rows_new, :] = jnp.where(is_new, new[...], rows)
+            for c in write_back():
+                c.start()
+
         k = k_buf[buf]                                # [Hkv, block, D]
         v = v_buf[buf]
         s = jnp.zeros((H, block), jnp.float32)
@@ -125,69 +174,94 @@ def _page_walk_kernel(pt_ref, np_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     # A slot that walked nothing (inactive) writes zeros.
     o_ref[...] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
+    @pl.when(n_blocks > 0)
+    def _written():
+        for c in write_back():
+            c.wait()
+
 
 def paged_decode_attention(
     q: jax.Array,           # [B, H, D] one query row per slot
-    k_pool: jax.Array,      # [Hkv, P, page, D]
+    k_new: jax.Array,       # [B, Hkv, D] the token's K row per slot
+    v_new: jax.Array,
+    k_pool: jax.Array,      # [L, Hkv, P, page, D]
     v_pool: jax.Array,
+    layer: jax.Array,       # [] int32 — the pools' layer to use
     page_table: jax.Array,  # [B, Pmax] int32
-    lengths: jax.Array,     # [B] int32 — key positions <= lengths[b] attend
+    lengths: jax.Array,     # [B] int32 — the new row's position
     active: jax.Array,      # [B] bool — an inactive slot walks no page
     *,
     interpret: bool = False,
-) -> jax.Array:
-    """The page-walk kernel. Returns [B, H, D]; rows of inactive slots
-    are zeros."""
+):
+    """The page-walk kernel. Returns ([B, H, D], k_pool, v_pool): rows
+    of inactive slots are zeros, the pools are the arguments' buffers
+    with the active slots' rows written."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
-    Hkv, _, page, _ = k_pool.shape
+    _, Hkv, _, page, _ = k_pool.shape
     Pmax = page_table.shape[1]
     pages_per_block = max(1, _BLOCK_TOKENS // page)
     n_pages = jnp.where(
         active, jnp.minimum(lengths // page + 1, Pmax), 0
     ).astype(jnp.int32)
     slot_rows = pl.BlockSpec((None, H, D), lambda b, *_: (b, 0, 0))
-    kv_buf = pltpu.VMEM((2, Hkv, pages_per_block * page, D), k_pool.dtype)
+    new_row = pl.BlockSpec((None, Hkv, 1, D), lambda b, *_: (b, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    dtype = k_pool.dtype
+    kv_buf = pltpu.VMEM((2, Hkv, pages_per_block * page, D), dtype)
     kernel = functools.partial(
         _page_walk_kernel, pmax=Pmax, scale=D ** -0.5)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B,),
-            in_specs=[
-                slot_rows,
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=slot_rows,
-            scratch_shapes=[kv_buf, kv_buf, pltpu.SemaphoreType.DMA((2, 2))],
+            in_specs=[slot_rows, new_row, new_row, hbm, hbm],
+            out_specs=[slot_rows, hbm, hbm],
+            scratch_shapes=[kv_buf, kv_buf, pltpu.SemaphoreType.DMA((3, 2))],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, H, D), q.dtype),
+                   jax.ShapeDtypeStruct(k_pool.shape, dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, dtype)],
+        # Operands count the prefetched scalars: the pools are 7 and 8.
+        input_output_aliases={7: 1, 8: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(page_table.reshape(-1).astype(jnp.int32), n_pages,
-      lengths.astype(jnp.int32), q.astype(k_pool.dtype), k_pool, v_pool)
+      lengths.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q.astype(dtype), k_new.astype(dtype)[:, :, None],
+      v_new.astype(dtype)[:, :, None], k_pool, v_pool)
 
 
-def gather_decode_attention(q, k_pool, v_pool, page_table, lengths):
-    """The XLA path. Same arguments and result as the kernel, bar
-    ``active`` (an inactive slot's row is computed and discarded)."""
+def gather_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
+                            page_table, lengths, active):
+    """The XLA path: the kernel's arguments and results, bar that an
+    inactive slot's row of the attention is computed (and discarded by
+    the caller)."""
     B, H, D = q.shape
-    Hkv, _, page, _ = k_pool.shape
+    _, Hkv, n_pool, page, _ = k_pool.shape
     T = page_table.shape[1] * page
-    k = jnp.take(k_pool, page_table, axis=1).reshape(Hkv, B, T, D)
-    v = jnp.take(v_pool, page_table, axis=1).reshape(Hkv, B, T, D)
+    # Inactive slots aim past the pool: -1 would WRAP to the last page
+    # (NumPy semantics) and corrupt it; only >= n is truly dropped.
+    drop = jnp.where(
+        active, page_table[jnp.arange(B), lengths // page], n_pool)
+    # A scalar beside index arrays across a slice: the cells are [B, Hkv, D].
+    at = (layer, slice(None), drop, lengths % page)
+    k_pool = k_pool.at[at].set(k_new.astype(k_pool.dtype), mode="drop")
+    v_pool = v_pool.at[at].set(v_new.astype(v_pool.dtype), mode="drop")
+    k = jnp.take(k_pool[layer], page_table, axis=1).reshape(Hkv, B, T, D)
+    v = jnp.take(v_pool[layer], page_table, axis=1).reshape(Hkv, B, T, D)
     qg = q.reshape(B, Hkv, H // Hkv, D)
     s = jnp.einsum("bhgd,hbtd->bhgt", qg, k,
                    preferred_element_type=jnp.float32) * (D ** -0.5)
     attends = jnp.arange(T)[None, :] <= lengths[:, None]      # [B, T]
     s = jnp.where(attends[:, None, None], s, -jnp.inf)
     prob = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhgt,hbtd->bhgd", prob, v).reshape(B, H, D)
+    attn = jnp.einsum("bhgt,hbtd->bhgd", prob, v).reshape(B, H, D)
+    return attn, k_pool, v_pool
 
 
 def pageable(page: int, head_dim: int) -> bool:
@@ -206,11 +280,16 @@ def decode_attention_path(page: int, head_dim: int) -> str:
         else "gather"
 
 
-def decode_attention(q, k_pool, v_pool, page_table, lengths, active):
-    """[B, H, D] queries against the paged pool, by the path
+def decode_attention(q, k_new, v_new, k_pool, v_pool, layer, page_table,
+                     lengths, active):
+    """Write each active slot's ``k_new``/``v_new`` row [B, Hkv, D] into
+    the pools [L, Hkv, P, page, D] at ``layer`` and position
+    ``lengths[b]``, and attend the queries [B, H, D] over positions ``0
+    .. lengths[b]``: (attention [B, H, D], k_pool, v_pool), by the path
     :func:`decode_attention_path` names."""
-    _, _, page, D = k_pool.shape
-    if decode_attention_path(page, D) == "page_walk":
-        return paged_decode_attention(
-            q, k_pool, v_pool, page_table, lengths, active)
-    return gather_decode_attention(q, k_pool, v_pool, page_table, lengths)
+    page, D = k_pool.shape[3:]
+    path = (paged_decode_attention
+            if decode_attention_path(page, D) == "page_walk"
+            else gather_decode_attention)
+    return path(q, k_new, v_new, k_pool, v_pool, layer, page_table,
+                lengths, active)

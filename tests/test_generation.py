@@ -113,8 +113,9 @@ def test_bucketed_prefill_matches_exact():
 
 
 def _reference_decode_attention(q, ck, cv, page_table, lengths):
-    """Plain float32: a slot's real tokens, positions 0..lengths[b],
-    sliced out of its pages in table order; one softmax a query row."""
+    """Plain float32 over one layer's pool [Hkv, P, page, D]: a slot's
+    real tokens, positions 0..lengths[b], sliced out of its pages in
+    table order; one softmax a query row."""
     q, ck, cv = (jnp.asarray(x, jnp.float32) for x in (q, ck, cv))
     B, H, D = q.shape
     rep = H // ck.shape[0]
@@ -131,21 +132,32 @@ def _reference_decode_attention(q, ck, cv, page_table, lengths):
     return np.asarray(jnp.stack(rows))
 
 
-# name: (H, Hkv, pool dtype, lengths, active, pages a slot). Pages are
-# 16 tokens and the kernel's block 8 pages, so 10 pages a slot make a
-# second, partly filled block.
+# name: (H, Hkv, pool dtype, lengths, active, pages a slot, layers, the
+# layer decoded). Pages are 16 tokens and the kernel's block 8 pages, so
+# 10 pages a slot make a second, partly filled block. ``lengths[b]`` is
+# where the new row goes: 16 is the first row of a fresh page, 128 and
+# 256 the first of a fresh block, 159 of 10 pages the last cell of the
+# slot's last page.
 _PAGED_CASES = {
-    "length_0": (4, 2, "float32", [0], [True], 4),
-    "length_15_page_end": (4, 2, "float32", [15], [True], 4),
-    "length_16_page_start": (4, 2, "float32", [16], [True], 4),
-    "length_17": (4, 2, "float32", [17], [True], 4),
+    "length_0": (4, 2, "float32", [0], [True], 4, 2, 1),
+    "length_15_page_end": (4, 2, "float32", [15], [True], 4, 2, 0),
+    "length_16_page_start": (4, 2, "float32", [16], [True], 4, 2, 1),
+    "length_17": (4, 2, "float32", [17], [True], 4, 2, 0),
     "last_cell_of_last_page": (4, 2, "float32", [159, 127, 128], [True] * 3,
-                               10),
+                               10, 2, 1),
     "inactive_slot_stale_row": (4, 2, "float32", [40, 150, 3],
-                                [True, False, True], 10),
-    "gqa_rep4_hkv8": (32, 8, "float32", [5, 131], [True, True], 10),
-    "mha_rep1": (4, 4, "float32", [33, 64], [True, True], 10),
-    "bf16_pool": (32, 8, "bfloat16", [0, 100, 159], [True] * 3, 10),
+                                [True, False, True], 10, 2, 0),
+    "gqa_rep4_hkv8": (32, 8, "float32", [5, 131], [True, True], 10, 2, 1),
+    "mha_rep1": (4, 4, "float32", [33, 64], [True, True], 10, 2, 0),
+    "bf16_pool": (32, 8, "bfloat16", [0, 100, 159], [True] * 3, 10, 2, 1),
+    "block_boundaries": (4, 2, "float32", [127, 128, 256, 255], [True] * 4,
+                         17, 1, 0),
+    "all_slots_inactive": (4, 2, "float32", [16, 130], [False, False], 10,
+                           2, 1),
+    "layer_0_of_3": (4, 2, "float32", [20, 143], [True, True], 10, 3, 0),
+    "layer_last_of_3": (4, 2, "float32", [20, 143], [True, True], 10, 3, 2),
+    "mha_hkv16_bf16": (16, 16, "bfloat16", [31, 144, 7],
+                       [True, True, False], 10, 2, 1),
 }
 
 
@@ -153,36 +165,51 @@ _PAGED_CASES = {
 @pytest.mark.parametrize("path", ["page_walk", "gather"])
 def test_paged_decode_attention_matches_reference(path, case):
     """Both decode attentions (the Pallas page walk in interpret mode,
-    the XLA gather) against the float32 reference above. Every case
-    walks pages out of order; a slot's unused table cells hold 0, the
-    id of a page another slot uses; an inactive slot keeps the row and
-    the length its last request left."""
+    the XLA gather): the new K/V row of each active slot lands in
+    ``[layer, :, page_table[b, len // page], len % page]`` and every
+    other cell of both pools is bit-identical (an inactive slot writes
+    nothing); the attention equals the float32 reference above over the
+    pool so written. Every case walks pages out of order; a slot's
+    unused table cells hold 0, the id of a page another slot uses; an
+    inactive slot keeps the row and the length its last request left."""
     from ray_tpu.ops import paged_attention as pa
 
-    H, Hkv, dtype, lengths, active, pmax = _PAGED_CASES[case]
+    H, Hkv, dtype, lengths, active, pmax, n_layers, layer = \
+        _PAGED_CASES[case]
     B, D, page = len(lengths), 128, 16
     n_pool = B * pmax
     rng = np.random.RandomState(len(case))
     q = jnp.asarray(rng.randn(B, H, D), dtype)
-    ck = jnp.asarray(rng.randn(Hkv, n_pool, page, D), dtype)
-    cv = jnp.asarray(rng.randn(Hkv, n_pool, page, D), dtype)
+    k_new = jnp.asarray(rng.randn(B, Hkv, D), dtype)
+    v_new = jnp.asarray(rng.randn(B, Hkv, D), dtype)
+    ck = jnp.asarray(rng.randn(n_layers, Hkv, n_pool, page, D), dtype)
+    cv = jnp.asarray(rng.randn(n_layers, Hkv, n_pool, page, D), dtype)
     order = rng.permutation(n_pool)
     order[np.argmin(order)], order[0] = order[0], 0  # slot 0 owns page 0
     table = np.zeros((B, pmax), np.int32)
     for b, n in enumerate(lengths):
         used = n // page + 1
         table[b, :used] = order[b * pmax:b * pmax + used]
-    lengths = jnp.asarray(lengths, jnp.int32)
     active = np.asarray(active)
-    args = (q, ck, cv, jnp.asarray(table), lengths)
+    want_k, want_v = np.array(ck), np.array(cv)
+    for b in np.flatnonzero(active):
+        cell = (layer, slice(None), table[b, lengths[b] // page],
+                lengths[b] % page)
+        want_k[cell], want_v[cell] = k_new[b], v_new[b]
+    lengths = jnp.asarray(lengths, jnp.int32)
+    args = (q, k_new, v_new, ck, cv, jnp.asarray(layer, jnp.int32),
+            jnp.asarray(table), lengths, jnp.asarray(active))
     if path == "page_walk":
-        out = pa.paged_decode_attention(*args, jnp.asarray(active),
-                                        interpret=True)
+        out, got_k, got_v = pa.paged_decode_attention(*args, interpret=True)
         assert not np.asarray(out, np.float32)[~active].any()
     else:
-        out = pa.gather_decode_attention(*args)
+        out, got_k, got_v = pa.gather_decode_attention(*args)
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    np.testing.assert_array_equal(np.asarray(got_v), want_v)
+    assert got_k.dtype == ck.dtype and got_v.dtype == cv.dtype
     assert out.shape == q.shape and out.dtype == q.dtype
-    ref = _reference_decode_attention(q, ck, cv, table, lengths)
+    ref = _reference_decode_attention(q, want_k[layer], want_v[layer], table,
+                                      lengths)
     tol = 2e-5 if dtype == "float32" else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32)[active],
                                ref[active], atol=tol, rtol=tol)

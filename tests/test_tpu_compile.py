@@ -14,7 +14,9 @@ page 16 for serving.
 
 import dataclasses
 import importlib
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -128,42 +130,87 @@ def test_flash_backward_compiles_for_v5e(v5e, as_tpu):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+# What may have a pool-shaped result in the decode program: the pool on
+# its way into, round and out of the layer scan, and the kernel call
+# whose aliased outputs carry it on.
+_POOL_CARRIERS = {"parameter", "get-tuple-element", "tuple", "bitcast",
+                  "while"}
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.-]+ = (?P<result>.*?) (?P<op>[\w-]+)\((?P<rest>.*)$",
+    re.MULTILINE)
+
+
+def _assert_pool_stays_in_place(compiled, pool_shape):
+    """The guard against pool-sized copies in a decode step (ROADMAP
+    S5; 70% of the step before PR 29): the program's temporaries are
+    under one layer's slice of one pool, and no instruction of the
+    optimized HLO but the pool's carriers and the kernel call has a
+    result of the pool's or a layer slice's shape."""
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * math.prod(pool_shape[1:]), f"{temp} B of temporaries"
+    shapes = ["bf16[%s]" % ",".join(map(str, dims))
+              for dims in (pool_shape, pool_shape[1:], (1, *pool_shape[1:]))]
+    offenders = []
+    for m in _HLO_INSTRUCTION.finditer(compiled.as_text()):
+        if m["op"] in _POOL_CARRIERS or not any(
+                shape in m["result"] for shape in shapes):
+            continue
+        if m["op"] == "custom-call" and "tpu_custom_call" in m["rest"]:
+            continue
+        offenders.append(m[0].strip()[:160])
+    assert not offenders, offenders
+
+
 @decode_shapes
 def test_paged_decode_kernel_compiles_for_v5e(v5e, batch, pages_per_seq,
                                               pool_pages):
-    """All 8 KV heads' 32 query rows of a slot in one program."""
-    compiled = jax.jit(paged_attention.paged_decode_attention).lower(
+    """All 8 KV heads' 32 query rows of a slot in one program, which
+    also writes the slot's new row: the pools go in whole and come back
+    through aliased outputs."""
+    pool = (4, HKV, pool_pages, PAGE, D)
+    compiled = jax.jit(
+        paged_attention.paged_decode_attention, donate_argnums=(3, 4)
+    ).lower(
         _arr(v5e, (batch, H, D)),
-        _arr(v5e, (HKV, pool_pages, PAGE, D)),
-        _arr(v5e, (HKV, pool_pages, PAGE, D)),
+        _arr(v5e, (batch, HKV, D)), _arr(v5e, (batch, HKV, D)),
+        _arr(v5e, pool), _arr(v5e, pool), _arr(v5e, (), jnp.int32),
         _arr(v5e, (batch, pages_per_seq), jnp.int32),
         _arr(v5e, (batch,), jnp.int32),
         _arr(v5e, (batch,), jnp.bool_),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_pool_stays_in_place(compiled, pool)
 
 
-@decode_shapes
-def test_paged_decode_program_compiles_for_v5e(v5e, as_tpu, batch,
-                                               pages_per_seq, pool_pages):
-    """The decode program as the code builds it for a TPU: the page walk
-    inside the layer scan, chosen from platform and shape."""
-    cfg = _serve_cfg()
+def _decode_program(cfg, v5e, batch, pool_pages, pages_per_seq):
     params, cache = _serve_shapes(cfg, v5e, batch, pool_pages,
                                   pages_per_seq)
-    assert paged_attention.decode_attention_path(PAGE, D) == "page_walk"
 
     def decode(params, cache, tok, active):
         return generation.paged_decode(
             params, tok, cache, cfg, active=active
         )
 
-    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+    return jax.jit(decode, donate_argnums=(1,)).lower(
         params, cache, _arr(v5e, (batch,), jnp.int32),
         _arr(v5e, (batch,), jnp.bool_),
-    ).compile()
+    ).compile(), cache.k.shape
+
+
+@decode_shapes
+def test_paged_decode_program_compiles_for_v5e(v5e, as_tpu, batch,
+                                               pages_per_seq, pool_pages):
+    """The decode program as the code builds it for a TPU: the page walk
+    inside the layer scan, chosen from platform and shape; the pool in
+    the scan's carry, never sliced, re-stacked or re-laid."""
+    assert paged_attention.decode_attention_path(PAGE, D) == "page_walk"
+    compiled, pool = _decode_program(_serve_cfg(), v5e, batch, pool_pages,
+                                     pages_per_seq)
     assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+    _assert_pool_stays_in_place(compiled, pool)
+    # The donated pools are the outputs: both aliased at the entry.
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * 2 * math.prod(pool)
 
 
 def test_paged_prefill_program_compiles_for_v5e(v5e, as_tpu):
@@ -218,22 +265,13 @@ def _fits_one_chip(compiled):
 
 def test_olmoe_decode_program_compiles_for_v5e(v5e, as_tpu):
     """The chat cell's decode step with the grouped expert matmuls in
-    the layer scan: 32 slots x 8 experts a token are 256 rows."""
-    cfg = _olmoe_cfg()
-    params, cache = _serve_shapes(cfg, v5e, CHAT_CELL[0], CHAT_POOL_PAGES,
-                                  CHAT_CELL[1])
-
-    def decode(params, cache, tok, active):
-        return generation.paged_decode(
-            params, tok, cache, cfg, active=active
-        )
-
-    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
-        params, cache, _arr(v5e, (CHAT_CELL[0],), jnp.int32),
-        _arr(v5e, (CHAT_CELL[0],), jnp.bool_),
-    ).compile()
+    the layer scan: 32 slots x 8 experts a token are 256 rows. The page
+    walk at ``Hkv`` 16 leaves this pool in place too."""
+    compiled, pool = _decode_program(_olmoe_cfg(), v5e, CHAT_CELL[0],
+                                     CHAT_POOL_PAGES, CHAT_CELL[1])
     assert "tpu_custom_call" in compiled.as_text()  # the page walk
     assert _fits_one_chip(compiled)
+    _assert_pool_stays_in_place(compiled, pool)
 
 
 def test_olmoe_prefill_program_compiles_for_v5e(v5e, as_tpu):
