@@ -13,7 +13,7 @@ EXT       := ray_tpu/_native/_rtstore.so
 PUMP_SRC  := src/pump/rts_pump.cc
 PUMP_EXT  := ray_tpu/_native/_rtpump.so
 
-.PHONY: native native-test native-ubsan cpp-client clean check check-slow check-obs check-metrics rtlint perf-transfer perf-actor perf-native perf-dispatch perf-train train-smoke train-chaos chaos overload
+.PHONY: native native-test native-ubsan cpp-client clean check check-slow check-obs check-metrics rtlint perf-transfer perf-actor perf-native perf-dispatch train-smoke train-chaos chaos overload
 
 # Static analysis: the rtlint distributed-invariant analyzer (pass
 # catalog: python -m tools.rtlint --list). Exits non-zero on any
@@ -34,17 +34,9 @@ check-metrics: check-obs
 
 # Fast CPU smoke of the compiled training step (2-layer, chunk=1, one
 # fused pjit step with donation): a pjit/scan regression fails here in
-# seconds, before any TPU bench run sees it.
+# seconds, before any run on the chip sees it.
 train-smoke:
 	JAX_PLATFORMS=cpu $(PY) tools/run_train_smoke.py
-
-# Training-step A/B matrix: sweeps scan x chunk x remat x donation x
-# depth through bench.py's worker gang (fresh chip state per row) and
-# writes per-config rows + the machine-picked winners to BENCH_AB.json
-# (tokens/s, MFU, peak HBM, allocator fragmentation). Needs the chip:
-# bench.py has no CPU mode.
-perf-train:
-	RAY_TPU_BENCH_AB=1 $(PY) bench.py
 
 # CI umbrella: the full static-analysis plane + the sanitized native
 # build/tests + the compiled-train-step smoke. Tier-1 docs point here.

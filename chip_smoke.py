@@ -17,9 +17,8 @@ One chip (what the driver runs):
   chip, per-node HTTP proxy, unary requests and one SSE stream with
   prompts of several hundred tokens (the flash prefill bucket) and 48
   new tokens each. Every generated token is checked against a plain
-  full-sequence ``forward()`` on the same parameters (within a stated
-  bf16 margin of its best logit), and the outputs are compared with
-  greedy ``generate()``, the dense-cache XLA path.
+  full-sequence ``forward()`` on the same parameters: within a stated
+  bf16 margin of its best logit, and for a float32 model the best one.
 
 Four chips (run by the builder, never by the driver): one gang worker
 holding ``TPU: 4`` takes the same train steps on one of its devices
@@ -56,9 +55,9 @@ if _REPO not in sys.path:
 
 SEED = 0
 
-# bench.py's model: Llama-3-8B layer geometry, vocabulary 32,768, depth
-# cut to 4. A plain dict — a LlamaConfig holds a jax dtype, and this
-# process must not import jax; the workers build the config from it.
+# Llama-3-8B layer geometry, vocabulary 32,768, depth cut to 4. A plain
+# dict — a LlamaConfig holds a jax dtype, and this process must not
+# import jax; the workers build the config from it.
 MODEL_8B_SHAPED = {
     "vocab_size": 32_768,
     "hidden_size": 4096,
@@ -81,17 +80,18 @@ MODEL_8B_SHAPED = {
 LEARNING_RATE_8B_SHAPED = 1e-5
 
 # How far the logit of a token the engine chose may trail the best logit
-# of the plain full-sequence forward on the same context. In float32 the
-# engine also has to equal greedy generate() token for token. In
-# bfloat16 it cannot: on the v5e 4 of 5 requests left generate() after
-# 5 to 43 equal tokens, the flash-prefill + paged-decode path and the
-# dense XLA path rounding differently where random weights put two of
-# 32,768 logits within rounding of each other. The engine picks from a
-# bf16 product, so logits closer than a bf16 ulp look the same to it:
-# logits here reach ~4, where an ulp is 2**-6, and the bound is two of
-# them. Measured: 238 of 240 tokens are the reference's argmax, the
-# other two trail it by at most 0.0065 (v5e, PR 21).
-LOGIT_MARGIN_TOL = {"float32": 1e-4, "bfloat16": 2.0 ** -5}
+# of the plain full-sequence forward on the same context. In float32 not
+# at all: every token has to be the reference's argmax, which is greedy
+# decoding token for token. In bfloat16 it cannot be: random weights put
+# two of 32,768 logits within rounding of each other, and two paths
+# that round differently then part for good (on the v5e 4 of 5 requests
+# left a dense-cache XLA decode after 5 to 43 equal tokens; PR 21). The
+# engine picks from a bf16 product, so logits closer than a bf16 ulp
+# look the same to it: logits here reach ~4, where an ulp is 2**-6, and
+# the bound is two of them. Measured: 238 of 240 tokens are the
+# reference's argmax, the other two trail it by at most 0.0065 (v5e,
+# PR 21).
+LOGIT_MARGIN_TOL = {"float32": 0.0, "bfloat16": 2.0 ** -5}
 
 # Sharded vs one-device loss, relative. Both runs share seed, data and
 # program; they differ in where bf16 rounding falls (tp splits the wo
@@ -376,20 +376,6 @@ def _llm_deployment():
             self._device = _device_facts(platform)
             super().__init__(_llama_config(model), **engine_kwargs)
 
-        def reference(self, prompts, max_new_tokens: int):
-            """Greedy ``generate()`` on the engine's own parameters:
-            dense KV cache, XLA attention, one batch."""
-            import jax.numpy as jnp
-            import numpy as np
-
-            from ray_tpu.models.generation import generate
-
-            out = generate(
-                self.engine.params, jnp.asarray(prompts, jnp.int32),
-                self.engine.cfg, max_new_tokens=max_new_tokens,
-            )
-            return np.asarray(out).tolist()
-
         def margins(self, prompts, outputs):
             """Teacher-forced check of every generated token: one plain
             full-sequence forward (XLA attention, no cache) over prompt
@@ -659,9 +645,6 @@ def serve_phase(model: dict, *, platform: str, prompt_len: int,
             calls.append(pool.submit(_sse, url, body[-1]))
             outputs += [c.result() for c in calls]
 
-        reference = handle.options(method="reference").remote(
-            prompts, max_new_tokens
-        ).result(timeout=900)
         margins = handle.options(method="margins").remote(
             prompts, outputs
         ).result(timeout=900)
@@ -675,14 +658,9 @@ def serve_phase(model: dict, *, platform: str, prompt_len: int,
         serve.shutdown()
     facts["released_s"] = _wait_chip_released(facts["pid"])
     tol = LOGIT_MARGIN_TOL[model["dtype"]]
-    first_diff = [
-        next((i for i, (a, b) in enumerate(zip(o, r)) if a != b), None)
-        for o, r in zip(outputs, reference)
-    ]
     log("serve", requests={"handle": 1, "http_unary": n_unary, "sse": 1},
         prompt_len=prompt_len,
         new_tokens=[len(o) for o in outputs], warmup_s=warmup_s,
-        first_diff_from_generate=first_diff,
         argmax_agreement=[sum(m == 0 for m in row) for row in margins],
         max_logit_margin=max(max(row) for row in margins),
         logit_margin_tol=tol, **facts)
@@ -694,11 +672,6 @@ def serve_phase(model: dict, *, platform: str, prompt_len: int,
         raise RuntimeError(
             f"an engine token trails the reference's best logit by "
             f"{worst:.4f} (> {tol}): margins {margins}"
-        )
-    if model["dtype"] == "float32" and outputs != reference:
-        raise RuntimeError(
-            "engine output differs from greedy generate():\n"
-            f"engine    {outputs}\nreference {reference}"
         )
     if platform == "tpu":
         if not facts["hbm"]:

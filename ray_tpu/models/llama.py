@@ -66,11 +66,9 @@ class LlamaConfig:
     # no backward recompute). "dots"/"save_all" trade HBM for less
     # backward recompute where memory allows.
     remat_policy: str = "full"
-    # Pallas flash attention kernel on TPU (ops/flash_attention.py);
-    # automatically the XLA einsum path off-TPU or for odd shapes.
-    # On by default: with the fused Pallas backward (KV-head-grid dK/dV,
-    # GQA reduced in-kernel) flash beats the XLA path for training too —
-    # 0.596 vs 0.532 MFU on the 8B-shaped bench (v5e A/B, before PR 1).
+    # Pallas flash attention kernel on TPU (ops/flash_attention.py, which
+    # also says for which shapes); the XLA einsum path off-TPU and for
+    # the other shapes. Every cell of the benchmark runs it on.
     use_flash: bool = True
     # Cross-entropy sequence chunk: the loss streams over S/chunk slices
     # so the [B, S, V] float32 logits (4.3 GB at B=16, S=2k, V=32k — and
@@ -206,25 +204,30 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding; x [B, S, H, D], positions [S] (global indices so
-    sequence-sharded blocks stay correct)."""
+    """Rotary embedding; x [B, S, H, D]; positions [S], the same for every
+    row (global indices, so sequence-sharded blocks stay correct), or
+    [B, S], each row's own (serving slots at different lengths)."""
     D = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, D/2]
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = jnp.sin(angles)[None, :, None, :]
+    angles = positions[..., None].astype(jnp.float32) * freqs  # [(B,) S, D/2]
+    cos = jnp.cos(angles)[..., None, :]
+    sin = jnp.sin(angles)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
 
-def _attention(cfg: LlamaConfig, mesh, q, k, v):
+def causal_attention(cfg: LlamaConfig, mesh, q, k, v):
+    """Causal self-attention of q [B,S,H,Dh] over k, v [B,S,Hkv,Dh] of
+    the same S tokens: training's, and a prefill's. The one place that
+    chooses among ring attention (an ``sp`` axis), the flash kernel and
+    the XLA einsum; for which platform and shapes the kernel itself runs
+    is ops/flash_attention.py's to say."""
     if mesh is not None and mesh_axis_size(mesh, "sp") > 1:
         return ring_attention(q, k, v, mesh, causal=True)
     if cfg.use_flash:
         from ..ops.flash_attention import flash_attention
 
-        # Pallas kernel on TPU; transparently the XLA path elsewhere.
         attend = functools.partial(flash_attention, causal=True)
         if mesh is not None:
             # GSPMD cannot partition a Mosaic kernel ("wrap the call in
@@ -241,10 +244,9 @@ def _attention(cfg: LlamaConfig, mesh, q, k, v):
 
 
 def qkv_proj(cfg: LlamaConfig, lp, x):
-    """The block's first half up to rotary, shared by the training,
-    cached and paged blocks: attention norm, then q [B,S,H,Dh] and k, v
-    [B,S,Hkv,Dh], q and k normed over their whole projection where the
-    model has a QK-norm."""
+    """The block's first half up to rotary: attention norm, then q
+    [B,S,H,Dh] and k, v [B,S,Hkv,Dh], q and k normed over their whole
+    projection where the model has a QK-norm."""
     B, S, _ = x.shape
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
@@ -275,7 +277,7 @@ def split_expert_stack(cfg: LlamaConfig, layers):
 
 def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
         expert_stack=None):
-    """The block's second half, shared likewise: MLP norm, then the
+    """The block's second half: MLP norm, then the
     SiLU-gated MLP or the mixture of experts, added to x [B,S,M].
     Returns (x, load-balancing loss, tokens assigned to each expert [E]
     or None for a dense model). ``token_mask`` [B,S] keeps rows (inactive
@@ -305,17 +307,33 @@ def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
     return x, jnp.zeros((), dtype=jnp.float32), None
 
 
-def _layer(cfg: LlamaConfig, mesh, positions, x, lp):
-    """One transformer block. x [B, S, M]."""
-    q, kk, vv = qkv_proj(cfg, lp, x)
+def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
+          token_mask=None, expert_stack=None):
+    """One transformer block over x [B,S,M]: the only place where
+    projections, rotary, attention, ``wo`` and the FFN are put in order.
+    Training, prefill and decode differ in what they pass:
+
+    ``positions`` [S] or [B,S], each row's place in its sequence (``rope``).
+    ``attend(q, k, v)`` owns the attention and whatever state it keeps,
+    and returns (output [B,S,H,Dh], that state): training attends
+    causally and keeps nothing, a prefill does the same and keeps k and
+    v, a decode step writes them into the KV pool it carries and attends
+    over the pool. The block hands the state on unread, so another
+    attention (a window, a latent cache) is another ``attend``.
+    ``mesh``, ``token_mask``, ``expert_stack``: ``ffn``'s.
+
+    Returns (x, attend's state, load-balancing loss, tokens assigned to
+    each expert or None)."""
+    q, k, v = qkv_proj(cfg, lp, x)
     q = rope(q, positions, cfg.rope_theta)
-    kk = rope(kk, positions, cfg.rope_theta)
+    k = rope(k, positions, cfg.rope_theta)
     q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"),
                                 mesh=mesh)
-    attn = _attention(cfg, mesh, q, kk, vv)
+    attn, state = attend(q, k, v)
     x = x + jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
-    x, aux, _ = ffn(cfg, lp, x, mesh=mesh)
-    return x, aux
+    x, aux, expert_tokens = ffn(cfg, lp, x, mesh=mesh, token_mask=token_mask,
+                                expert_stack=expert_stack)
+    return x, state, aux, expert_tokens
 
 
 def forward(
@@ -375,15 +393,20 @@ def hidden_forward(
     positions = jnp.arange(S)
     policy = remat_policy(cfg)
 
+    def attend(q, k, v):
+        return causal_attention(cfg, mesh, q, k, v), None
+
+    def layer(x, lp):
+        # A layer's own slice of the experts (expert_stack=None): why,
+        # parallel/moe.py.
+        x, _, aux, _ = block(cfg, lp, x, positions, attend, mesh=mesh)
+        return x, aux
+
     def body(x, lp):
         if cfg.remat:
-            fn = jax.checkpoint(
-                lambda x_, lp_: _layer(cfg, mesh, positions, x_, lp_),
-                policy=policy,
-            )
-            out, aux = fn(x, lp)
+            out, aux = jax.checkpoint(layer, policy=policy)(x, lp)
         else:
-            out, aux = _layer(cfg, mesh, positions, x, lp)
+            out, aux = layer(x, lp)
         out = with_logical_constraint(out, ("batch", "seq", "embed"), mesh=mesh)
         return out, aux
 
@@ -406,7 +429,7 @@ def hidden_forward(
                 aux = jnp.zeros((), dtype=jnp.float32)
                 for k in range(K):
                     lp = jax.tree.map(lambda p: p[k], cp)
-                    x_, a = _layer(cfg, mesh, positions, x_, lp)
+                    x_, a = layer(x_, lp)
                     aux = aux + a
                 return x_, aux
 

@@ -461,15 +461,16 @@ def flash_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Flash attention with GQA and global-coordinate causal masking
-    (same signature as ops.attention.mha_attention). Dispatches to the
-    Pallas kernel when running on TPU with tileable shapes, else to the
-    XLA einsum path."""
+    (same signature as ops.attention.mha_attention). The one place that
+    says when the Pallas kernel runs: on a TPU, for sequences that are
+    whole blocks (128 rows unless the caller names another size: a
+    serving bucket of 16, 32 or 64 tokens is no block, and no shorter
+    one has run on the chip), heads of at most 256 and whole GQA
+    groups. Everything else takes the XLA einsum path."""
     B, Sq, H, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     scale = scale if scale is not None else D ** -0.5
 
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Skv)
     tileable = (
         Sq % block_q == 0
         and Skv % block_k == 0

@@ -1,12 +1,14 @@
 """Single-token decode over a paged KV pool: write the token, attend.
 
-The pool (models/generation.py PagedKVCache) is head-major and whole,
-``[L, Hkv, P, page, Dh]``; a slot's tokens live in the pages its row of
-``page_table`` names. :func:`decode_attention` is the one place a decode
-step touches it: at layer ``layer`` it puts each active slot's new K/V
-row at position ``lengths[b]`` (page ``page_table[b, lengths[b] //
-page]``, row ``lengths[b] % page``), attends over positions ``0 ..
-lengths[b]`` and hands both pools on.
+The pool (models/generation.py PagedKVCache, the one KV cache) is
+head-major and whole, ``[L, Hkv, P, page, Dh]``; a slot's tokens live in
+the pages its row of ``page_table`` names. :func:`decode_attention` is
+what ``paged_decode`` hands the one transformer block (``llama.block``)
+as its ``attend``, and the one place a decode step touches the pool:
+at layer ``layer`` it puts each active slot's new K/V row at position
+``lengths[b]`` (page ``page_table[b, lengths[b] // page]``, row
+``lengths[b] % page``), attends over positions ``0 .. lengths[b]`` and
+hands both pools on.
 
 Two implementations, one chosen by :func:`decode_attention_path` from
 what the code can see (platform and shape), never by a user:

@@ -3,8 +3,8 @@
 Ref analogue: python/ray/_private/ray_perf.py (task/actor-call/put
 throughput) with the timeit runner of ray_microbenchmark_helpers.py:14.
 Run as ``python -m ray_tpu.perf`` for the full table, or call
-``run_microbenchmarks`` programmatically (bench.py and tests use reduced
-iteration counts).
+``run_microbenchmarks`` programmatically (tests use reduced iteration
+counts).
 
 Each entry reports ops/s (mean of ``repeat`` timed windows). The suite
 exercises the real control plane: driver puts/gets through the shm arena,

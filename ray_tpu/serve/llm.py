@@ -209,9 +209,8 @@ class LLMEngine:
         # surface as ray_tpu_device_jit_* series instead of silent
         # latency spikes. The per-token tap rides a ring flushed once
         # every 64 steps (and at every burst boundary — see _loop /
-        # stats), not per token: polling the executable cache around
-        # every [B,1] decode step was the remaining slice of the
-        # 695→652 tok/s regression (PERF_r06, partially recovered).
+        # stats), not per token, so the executable cache is not polled
+        # around every [B,1] decode step.
         self._decode = instrumented_jit(decode_step, donate_argnums=(1,),
                                         tap_stride=64)
 

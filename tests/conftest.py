@@ -42,3 +42,35 @@ def ray_tpu_start():
     )
     yield rt
     ray_tpu.shutdown()
+
+
+@pytest.fixture(scope="session")
+def naive_greedy():
+    """``naive_greedy(params, prompt, cfg, n) -> n`` token ids: greedy
+    decoding the plain way, the model's full ``forward`` over the whole
+    sequence for every token and nothing cached: the reference the
+    serving programs and the engine are compared with. The sequence
+    stands in a row of ``pad_to`` tokens: causal attention keeps what
+    lies to the right of a position from it, and one shape is one
+    compile a model."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import forward
+
+    @functools.lru_cache(maxsize=None)
+    def next_token(cfg):
+        return jax.jit(lambda params, row, n: jnp.argmax(
+            forward(params, row[None], cfg)[0][0, n - 1]))
+
+    def greedy(params, prompt, cfg, n, pad_to=64):
+        row = np.zeros(pad_to, np.int32)
+        row[:len(prompt)] = prompt
+        for at in range(len(prompt), len(prompt) + n):
+            row[at] = next_token(cfg)(params, jnp.asarray(row), at)
+        return row[len(prompt):len(prompt) + n].tolist()
+
+    return greedy

@@ -77,10 +77,10 @@ def test_serve_phase_tiny_on_cpu(one_tpu_node, capsys):
     assert device["platform"] == "cpu"
     (line,) = capsys.readouterr().out.splitlines()
     facts = json.loads(line)
-    # float32: token for token equal to generate(), and every token the
-    # argmax of the plain forward().
-    assert facts["first_diff_from_generate"] == [None] * 5
+    # float32: every token the argmax of the plain forward(), which is
+    # greedy decoding token for token.
     assert facts["argmax_agreement"] == [8] * 5
+    assert facts["max_logit_margin"] == facts["logit_margin_tol"] == 0
     assert facts["new_tokens"] == [8] * 5
     assert facts["prefill_bucket"] == 32
     assert facts["engine"]["platform"] == "cpu"

@@ -1,5 +1,5 @@
-"""KV-cache decode correctness: cached generation must match the naive
-full-recompute argmax loop exactly (greedy)."""
+"""The serving programs over the paged KV cache against the plain full
+forward pass, and the decode attention against a reference of its own."""
 
 import numpy as np
 import pytest
@@ -9,107 +9,10 @@ import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.models import LlamaConfig, forward, init_params  # noqa: E402
 from ray_tpu.models.generation import (  # noqa: E402
-    KVCache,
-    forward_with_cache,
-    generate,
+    PagedKVCache,
+    paged_decode,
+    paged_prefill,
 )
-
-
-def _naive_greedy(params, prompt, cfg, n):
-    seq = prompt
-    out = []
-    for _ in range(n):
-        logits, _ = forward(params, seq, cfg)
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        out.append(nxt)
-        seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
-    return jnp.stack(out, axis=1)
-
-
-def test_cached_prefill_matches_forward():
-    cfg = LlamaConfig.tiny()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    prompt = jnp.asarray(np.random.RandomState(0).randint(0, 256, (2, 8)))
-    full_logits, _ = forward(params, prompt, cfg)
-    cache = KVCache.create(cfg, 2, 32)
-    cached_logits, cache = forward_with_cache(params, prompt, cache, cfg)
-    np.testing.assert_allclose(
-        np.asarray(cached_logits), np.asarray(full_logits[:, -1]),
-        atol=1e-4, rtol=1e-4,
-    )
-    assert list(np.asarray(cache.lengths)) == [8, 8]
-
-
-@pytest.mark.slow
-def test_generate_matches_naive():
-    cfg = LlamaConfig.tiny()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    prompt = jnp.asarray(np.random.RandomState(1).randint(0, 256, (2, 6)))
-    expected = _naive_greedy(params, prompt, cfg, 5)
-    got = generate(params, prompt, cfg, max_new_tokens=5)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(expected))
-
-
-def test_decode_respects_active_mask():
-    cfg = LlamaConfig.tiny()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    cache = KVCache.create(cfg, 2, 16)
-    prompt = jnp.asarray(np.random.RandomState(2).randint(0, 256, (2, 4)))
-    _, cache = forward_with_cache(params, prompt, cache, cfg)
-    tok = jnp.asarray([[5], [9]], dtype=jnp.int32)
-    active = jnp.asarray([True, False])
-    _, cache2 = forward_with_cache(params, tok, cache, cfg, active=active)
-    assert list(np.asarray(cache2.lengths)) == [5, 4]
-    # Inactive slot's cache rows untouched.
-    np.testing.assert_array_equal(
-        np.asarray(cache2.k[:, 1]), np.asarray(cache.k[:, 1])
-    )
-
-
-@pytest.mark.slow
-def test_moe_cached_decode_matches_naive():
-    """MoE models decode through the KV cache (r1 gap: generation.py
-    raised NotImplementedError for MoE). The dispatch drops no token,
-    so full-sequence and incremental evaluation agree."""
-    cfg = LlamaConfig.tiny(moe=True)
-    params = init_params(cfg, jax.random.PRNGKey(1))
-    prompt = jnp.asarray(np.random.RandomState(1).randint(0, 256, (2, 6)))
-    naive = _naive_greedy(params, prompt, cfg, 5)
-    out = generate(params, prompt, cfg, max_new_tokens=5)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(naive))
-
-
-def test_bucketed_prefill_matches_exact():
-    """Padded (bucketed) prefill with last_index/append_len produces the
-    same logits and cache lengths as exact-length prefill."""
-    cfg = LlamaConfig.tiny()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    rs = np.random.RandomState(2)
-    real_len = 5
-    prompt = jnp.asarray(rs.randint(0, 256, (1, real_len)))
-    exact_logits, exact_cache = forward_with_cache(
-        params, prompt, KVCache.create(cfg, 1, 32), cfg
-    )
-    bucket = 8
-    padded = jnp.concatenate(
-        [prompt, jnp.zeros((1, bucket - real_len), jnp.int32)], axis=1
-    )
-    padded_logits, padded_cache = forward_with_cache(
-        params, padded, KVCache.create(cfg, 1, 32), cfg,
-        last_index=jnp.asarray([real_len - 1]),
-        append_len=jnp.asarray(real_len),
-    )
-    np.testing.assert_allclose(
-        np.asarray(padded_logits), np.asarray(exact_logits),
-        atol=1e-4, rtol=1e-4,
-    )
-    assert int(padded_cache.lengths[0]) == real_len
-    # Decode continues identically from either cache.
-    nxt = jnp.argmax(exact_logits, -1).astype(jnp.int32)[:, None]
-    l1, _ = forward_with_cache(params, nxt, exact_cache, cfg)
-    l2, _ = forward_with_cache(params, nxt, padded_cache, cfg)
-    np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
-                               atol=1e-4, rtol=1e-4)
 
 
 def _reference_decode_attention(q, ck, cv, page_table, lengths):
@@ -279,25 +182,37 @@ def test_tiny_olmoe_has_the_qk_norm_and_the_training_block_uses_it():
     assert float(jnp.abs(off - expected).max()) > 1e-2
 
 
+def _model(name):
+    """(cfg, seeded float32 weights, tokens [B, S] -> the logits of a
+    full forward pass that caches nothing): the tiny dense model and the
+    tiny mixture of experts against the program's own ``forward``, the
+    tiny OLMoE against the benchmark's reference, which shares no code
+    with the program."""
+    if name == "olmoe":
+        config, cfg, params = _tiny_olmoe()
+        return cfg, params, lambda t: _reference_logits(config, params, t)
+    cfg = LlamaConfig.tiny(moe=name == "moe")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    return cfg, params, lambda t: forward(params, t, cfg)[0]
+
+
 @pytest.mark.parametrize("real_len", [5, 16, 27])
-def test_tiny_olmoe_paged_prefill_then_decode_equals_the_reference(real_len):
+@pytest.mark.parametrize("model", ["dense", "moe", "olmoe"])
+def test_paged_prefill_then_decode_equals_the_full_forward(model, real_len):
     """A prompt padded to its bucket through ``paged_prefill``, then
     tokens one at a time through ``paged_decode`` beside idle slots,
-    against the float32 reference's FULL forward pass of the same
-    sequence: logits compared. Tolerance 1e-4: float32 on both sides,
-    the sums in another order; a bucket's padding or an idle slot
-    reaching an expert, a norm left out or a dropped assignment is off
-    by more than 1e-2."""
-    from ray_tpu.models.generation import (
-        PagedKVCache, paged_decode, paged_prefill)
-
-    config, cfg, params = _tiny_olmoe()
+    against a FULL forward pass of the same sequence at its exact
+    length: logits compared, so a padded prefill equals an exact one at
+    the last real position and decode continues from what it cached.
+    Tolerance 1e-4: float32 on both sides, the sums in another order; a
+    bucket's padding or an idle slot reaching an expert, a norm left
+    out or a dropped assignment is off by more than 1e-2."""
+    cfg, params, full_forward = _model(model)
     rng = np.random.RandomState(real_len)
     steps, page, slots, slot = 4, 16, 3, 1
     seq = rng.randint(0, 256, real_len + steps)
     bucket = 16 if real_len <= 16 else 32
-    expected = np.asarray(_reference_logits(
-        config, params, jnp.asarray(seq[None])))[0]
+    expected = np.asarray(full_forward(jnp.asarray(seq[None])))[0]
 
     cache = PagedKVCache.create(cfg, slots, 8, page, 4)
     pages = [5, 2, 7]                      # the slot's pages, out of order
@@ -311,11 +226,17 @@ def test_tiny_olmoe_paged_prefill_then_decode_equals_the_reference(real_len):
         cache, cfg, slot, jnp.asarray(pages[:bucket // page], jnp.int32))
     np.testing.assert_allclose(np.asarray(logits)[0], expected[real_len - 1],
                                atol=1e-4, rtol=1e-4)
-    # The bucket's padding reached no expert: real tokens x k x layers.
-    assert int(load.expert_tokens.sum()) == real_len * 2 * 2
-    # (layer, expert) pairs: at least the experts seen in any layer.
-    assert int((np.asarray(load.expert_tokens) > 0).sum()) \
-        <= int(load.experts_reached) <= min(16, real_len * 2 * 2)
+    assert list(np.asarray(cache.lengths)) == [0, real_len, 0]
+    per_token = cfg.top_k * cfg.num_layers
+    if cfg.n_experts == 0:
+        assert load is None
+    else:
+        # The bucket's padding reached no expert: real tokens x k x layers.
+        assert int(load.expert_tokens.sum()) == real_len * per_token
+        # (layer, expert) pairs: at least the experts seen in any layer.
+        assert int((np.asarray(load.expert_tokens) > 0).sum()) \
+            <= int(load.experts_reached) \
+            <= min(cfg.n_experts * cfg.num_layers, real_len * per_token)
 
     active = jnp.asarray(np.arange(slots) == slot)
     for i in range(steps):
@@ -326,15 +247,44 @@ def test_tiny_olmoe_paged_prefill_then_decode_equals_the_reference(real_len):
         np.testing.assert_allclose(
             np.asarray(logits)[slot], expected[real_len + i],
             atol=1e-4, rtol=1e-4)
-        # One live slot: 2 experts in each of 2 layers, whatever idles.
-        assert int(load.expert_tokens.sum()) == 4
-        assert int(load.experts_reached) == 4
-    assert int(cache.lengths[slot]) == real_len + steps
+        if cfg.n_experts > 0:
+            # One live slot: k experts in each layer, whatever idles.
+            assert int(load.expert_tokens.sum()) == per_token
+            assert int(load.experts_reached) == per_token
+    assert list(np.asarray(cache.lengths)) == [0, real_len + steps, 0]
+
+
+def test_paged_decode_leaves_an_inactive_slot_as_it_was():
+    """Two prefilled slots, one decode step with the second inactive:
+    its length and every cell of its pages are what they were, the
+    first slot's length is one more and its new row is the only cell of
+    either pool that changed."""
+    cfg = LlamaConfig.tiny()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    page, lens, pages = 16, (5, 9), ([3], [1])
+    cache = PagedKVCache.create(cfg, 2, 4, page, 2)
+    table = np.zeros((2, 2), np.int32)
+    table[0, 0], table[1, 0] = pages[0][0], pages[1][0]
+    cache = cache._replace(page_table=jnp.asarray(table))
+    rng = np.random.RandomState(0)
+    for slot, n in enumerate(lens):
+        prompt = np.zeros((1, page), np.int32)
+        prompt[0, :n] = rng.randint(0, 256, n)
+        _, cache, _ = paged_prefill(
+            params, jnp.asarray(prompt), jnp.asarray(n, jnp.int32), cache,
+            cfg, slot, jnp.asarray(pages[slot], jnp.int32))
+    _, after, _ = paged_decode(
+        params, jnp.asarray([5, 9], jnp.int32), cache, cfg,
+        active=jnp.asarray([True, False]))
+    assert list(np.asarray(after.lengths)) == [lens[0] + 1, lens[1]]
+    for old, new in ((cache.k, after.k), (cache.v, after.v)):
+        changed = np.argwhere((np.asarray(old) != np.asarray(new)).any(-1))
+        # [layer, kv head, page, row]: slot 0's row 5 of page 3 alone.
+        assert {tuple(c[2:]) for c in changed} == {(pages[0][0], lens[0])}
+        assert len(changed) == cfg.num_layers * cfg.num_kv_heads
 
 
 def test_dense_programs_return_no_expert_load():
-    from ray_tpu.models.generation import PagedKVCache, paged_decode
-
     cfg = LlamaConfig.tiny()
     params = init_params(cfg, jax.random.PRNGKey(0))
     cache = PagedKVCache.create(cfg, 2, 4, 16, 2)

@@ -4,10 +4,8 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.models import LlamaConfig, init_params  # noqa: E402
-from ray_tpu.models.generation import generate  # noqa: E402
 from ray_tpu.serve.llm import LLMEngine  # noqa: E402
 
 
@@ -18,26 +16,26 @@ def tiny_model():
     return cfg, params
 
 
-def test_engine_single_request_matches_generate(tiny_model):
+def test_engine_single_request_matches_naive_greedy(tiny_model, naive_greedy):
     cfg, params = tiny_model
     engine = LLMEngine(cfg, params, max_batch=4, max_len=64)
     try:
         prompt = list(np.random.RandomState(0).randint(0, 256, 6))
-        expected = np.asarray(
-            generate(params, jnp.asarray([prompt]), cfg, max_new_tokens=8)
-        )[0].tolist()
+        expected = naive_greedy(params, prompt, cfg, 8)
         got = engine.generate(prompt, max_new_tokens=8)
         assert got == expected
     finally:
         engine.shutdown()
 
 
-def test_engine_paged_decode_agrees_with_dense_generate(tiny_model):
-    """The engine's paged decode against ``generate()``'s dense cache,
-    greedy token for token: prompts of mixed lengths over page edges,
-    one request that ends at ``max_len``, slots idle beside busy ones,
-    and a slot taken again after its request finished (its table row
-    and length are the last request's until then)."""
+def test_engine_paged_decode_agrees_with_naive_greedy(tiny_model,
+                                                     naive_greedy):
+    """The engine's paged decode against greedy decoding by the full
+    forward pass with nothing cached, token for token: prompts of mixed
+    lengths over page edges, one request that ends at ``max_len``,
+    slots idle beside busy ones, and a slot taken again after its
+    request finished (its table row and length are the last request's
+    until then)."""
     cfg, params = tiny_model
     engine = LLMEngine(cfg, params, max_batch=3, max_len=64, page_size=16)
     try:
@@ -46,11 +44,8 @@ def test_engine_paged_decode_agrees_with_dense_generate(tiny_model):
         # (prompt length, new tokens): 20 + 44 fills max_len.
         shapes = [(5, 10), (20, 44), (17, 3), (9, 6)]
         prompts = [list(rng.randint(0, 256, n)) for n, _ in shapes]
-        expected = [
-            np.asarray(generate(params, jnp.asarray([p]), cfg,
-                                max_new_tokens=n))[0].tolist()
-            for p, (_, n) in zip(prompts, shapes)
-        ]
+        expected = [naive_greedy(params, p, cfg, n)
+                    for p, (_, n) in zip(prompts, shapes)]
         first = [engine.submit(p, n)
                  for p, (_, n) in zip(prompts[:3], shapes[:3])]
         assert first[2].result(timeout=180) == expected[2]
@@ -69,19 +64,16 @@ def test_engine_paged_decode_agrees_with_dense_generate(tiny_model):
 
 
 @pytest.mark.slow
-def test_engine_concurrent_requests_continuous_batching(tiny_model):
+def test_engine_concurrent_requests_continuous_batching(tiny_model,
+                                                        naive_greedy):
     cfg, params = tiny_model
     engine = LLMEngine(cfg, params, max_batch=4, max_len=64)
     try:
         rng = np.random.RandomState(1)
         prompts = [list(rng.randint(0, 256, int(n))) for n in (4, 6, 5, 7)]
         lens = [10, 3, 7, 5]
-        expected = [
-            np.asarray(
-                generate(params, jnp.asarray([p]), cfg, max_new_tokens=n)
-            )[0].tolist()
-            for p, n in zip(prompts, lens)
-        ]
+        expected = [naive_greedy(params, p, cfg, n)
+                    for p, n in zip(prompts, lens)]
         # Submit all concurrently: they share the decode loop.
         reqs = [engine.submit(p, n) for p, n in zip(prompts, lens)]
         results = [r.result(timeout=120) for r in reqs]
@@ -238,7 +230,8 @@ def test_llm_serve_sse_streaming(ray_tpu_start):
         serve.shutdown()
 
 
-def test_engine_counts_expert_load_for_a_moe_model_only(tiny_model):
+def test_engine_counts_expert_load_for_a_moe_model_only(tiny_model,
+                                                        naive_greedy):
     """``stats()["moe"]``: every real token of a prefill and every live
     slot of a decode step is given ``top_k`` experts in each layer, and
     nothing else is (bucket padding, idle slots); absent for a dense
@@ -259,9 +252,7 @@ def test_engine_counts_expert_load_for_a_moe_model_only(tiny_model):
                    for i, n in enumerate((5, 17, 9))]
         reqs = [engine.submit(p, 6) for p in prompts]
         outs = [r.result(timeout=180) for r in reqs]
-        expected = [np.asarray(generate(
-            params, jnp.asarray([p]), cfg, max_new_tokens=6))[0].tolist()
-            for p in prompts]
+        expected = [naive_greedy(params, p, cfg, 6) for p in prompts]
         assert outs == expected
         stats = engine.stats()
         moe = stats["moe"]

@@ -7,7 +7,7 @@ machine (a slice not aligned to the tiling, too much VMEM, a program over
 HBM) fails here first, at no chip time. Nothing executes: these tests
 say nothing about results or speed.
 
-Shapes are the 8B-shaped config ``chip_smoke.py`` and ``bench.py`` run:
+Shapes are the 8B-shaped config ``chip_smoke.py`` runs:
 32 query / 8 KV heads of dim 128, hidden 4096, b8 x 2048 for training,
 page 16 for serving.
 """
@@ -213,9 +213,13 @@ def test_paged_decode_program_compiles_for_v5e(v5e, as_tpu, batch,
         >= 2 * 2 * math.prod(pool)
 
 
-def test_paged_prefill_program_compiles_for_v5e(v5e, as_tpu):
-    """A several-hundred-token prompt lands in the 512 bucket, whose
-    fresh-prefill attention is the flash kernel."""
+@pytest.mark.parametrize("bucket,flash", [(64, False), (BUCKET, True)])
+def test_paged_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket, flash):
+    """Which causal attention a prefill bucket runs on the chip
+    (``ops/flash_attention.py`` owns the rule): a several-hundred-token
+    prompt lands in the 512 bucket, whole 128-row blocks, and runs the
+    flash kernel; a bucket under a block (16, 32, 64) runs the XLA
+    einsum. A dense model's prefill holds no other custom call."""
     cfg = _serve_cfg()
     params, cache = _serve_shapes(cfg, v5e)
 
@@ -225,11 +229,11 @@ def test_paged_prefill_program_compiles_for_v5e(v5e, as_tpu):
         )
 
     compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
-        params, cache, _arr(v5e, (1, BUCKET), jnp.int32),
+        params, cache, _arr(v5e, (1, bucket), jnp.int32),
         _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
-        _arr(v5e, (BUCKET // PAGE,), jnp.int32),
+        _arr(v5e, (bucket // PAGE,), jnp.int32),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert ("tpu_custom_call" in compiled.as_text()) is flash
 
 
 def test_flash_falls_back_off_tpu():

@@ -3,7 +3,7 @@
 One tiny pjit'd step through the full fused path — chunked-scan
 schedule, donated params + optimizer state, compiled init — so a
 pjit/scan/donation regression fails in CI seconds instead of surfacing
-as a broken TPU bench run. Mirrors what bench.py's worker does, minus
+as a broken run on the chip. Mirrors what a train worker does, minus
 the cluster (this must stay cheap enough for every `make check`).
 """
 
