@@ -69,7 +69,9 @@ class ActorMethod:
         if streaming:
             from .streaming import ObjectRefGenerator
 
-            return ObjectRefGenerator(spec.task_id, refs[0])
+            return ObjectRefGenerator(
+                spec.task_id, refs[0], retriable=spec.max_retries > 0
+            )
         return refs[0] if num_returns == 1 else refs
 
     def __call__(self, *args, **kwargs):

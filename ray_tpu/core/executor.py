@@ -9,9 +9,6 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-# Sentinel passed to stream_item after the last yielded value.
-_STREAM_END = object()
-
 from .config import get_config
 from .exceptions import TaskError
 from .ids import ObjectID
@@ -215,7 +212,6 @@ def execute_task(
             elif value is not None:
                 stream_item(0, value)
                 count = 1
-            stream_item(count, _STREAM_END)
             value = count
         results, nested = package_results(spec, value, store_large)
         return results, False, nested, None

@@ -279,6 +279,12 @@ class NodeEntry:
         }
 
 
+class _LocateEvent(asyncio.Event):
+    """An object's "published" event, with the long-polls parked on it."""
+
+    waiters = 0
+
+
 class GcsService:
     """The control-plane tables + TCP server. Lives on the head node
     manager's asyncio loop; every public coroutine is loop-thread-only."""
@@ -301,7 +307,7 @@ class GcsService:
         # GC-ing its pulled replica cannot delete the producer's entry (ref
         # analogue: ObjectDirectory's per-object node sets).
         self._object_nodes: Dict[ObjectID, set] = {}
-        self._object_events: Dict[ObjectID, asyncio.Event] = {}
+        self._object_events: Dict[ObjectID, _LocateEvent] = {}
         self._job_counter = 0
         # Placement groups (ref analogue: GcsPlacementGroupManager +
         # GcsPlacementGroupScheduler 2PC across raylets).
@@ -1795,11 +1801,20 @@ class GcsService:
         nid = self._pick_object_node(object_id)
         if nid is not None or timeout <= 0:
             return nid
-        ev = self._object_events.setdefault(object_id, asyncio.Event())
+        ev = self._object_events.setdefault(object_id, _LocateEvent())
+        ev.waiters += 1
         try:
             await asyncio.wait_for(ev.wait(), timeout)
         except asyncio.TimeoutError:
             return None
+        finally:
+            # The last waiter of an object that was never published (a
+            # stream's item past its end, a lost object) takes the event
+            # with it, timed out or cancelled.
+            ev.waiters -= 1
+            if (not ev.waiters and not ev.is_set()
+                    and self._object_events.get(object_id) is ev):
+                del self._object_events[object_id]
         return self._pick_object_node(object_id)
 
     def nodes_view(self) -> List[Dict[str, Any]]:

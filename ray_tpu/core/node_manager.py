@@ -379,6 +379,8 @@ class NodeManager:
 
         self._sealed: Set[ObjectID] = set()
         self._seal_events: Dict[ObjectID, asyncio.Event] = {}
+        # wait_objects calls parked on each unsealed object's event.
+        self._parked_waits: Dict[ObjectID, int] = {}
         self._pending_procs: Dict[WorkerID, subprocess.Popen] = {}
 
         # Cluster plane.
@@ -3074,15 +3076,14 @@ class NodeManager:
             return False
         if nid is None or nid == self.node_id:
             return False
-        self._seal_object(oid, RemoteLocation(nid.hex(), 0))
-        # Any entry for a remotely-owned object is a borrow this node
-        # must register with the owner (owner already resolved — pass it
-        # through instead of repeating the locate RPC). The holder's +1
-        # delta lands BEFORE the blocking lookup that triggered this
-        # (runtimes flush ref deltas ahead of blocking requests on the
-        # same connection), so the count here is already the holder's —
-        # no compensating pin (the old interim scheme's) is needed.
-        self._borrow_stubs.add(oid)
+        # The borrow is registered with the owner here (owner already
+        # resolved — pass it through instead of repeating the locate
+        # RPC). The holder's +1 delta lands BEFORE the blocking lookup
+        # that triggered this (runtimes flush ref deltas ahead of
+        # blocking requests on the same connection), so the count here is
+        # already the holder's — no compensating pin (the old interim
+        # scheme's) is needed.
+        self._adopt_remote_object(oid, nid)
         await self._register_borrow(oid, owner_hex=nid.hex())
         return True
 
@@ -4486,31 +4487,98 @@ class NodeManager:
         timeout: Optional[float],
     ) -> List[ObjectID]:
         deadline = None if timeout is None else time.monotonic() + timeout
-        while True:
-            ready = [oid for oid in object_ids if oid in self._sealed]
-            if len(ready) >= num_returns:
-                return ready
-            remaining = None
-            if deadline is not None:
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
+        parked: Set[ObjectID] = set()
+        locating: List[asyncio.Future] = []
+        try:
+            while True:
+                ready = [oid for oid in object_ids if oid in self._sealed]
+                if len(ready) >= num_returns:
                     return ready
-            # Event-driven: wake when any unsealed object seals.
-            pending = [
-                self._seal_events.setdefault(oid, asyncio.Event())
-                for oid in object_ids
-                if oid not in self._sealed
-            ]
-            tasks = [asyncio.ensure_future(ev.wait()) for ev in pending]
+                remaining = None
+                if deadline is not None:
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0:
+                        return ready
+                # Event-driven: wake when any unsealed object seals.
+                pending = []
+                for oid in object_ids:
+                    if oid in self._sealed:
+                        continue
+                    pending.append(
+                        self._seal_events.setdefault(oid, asyncio.Event())
+                    )
+                    if oid in parked:
+                        continue
+                    parked.add(oid)
+                    self._parked_waits[oid] = (
+                        self._parked_waits.get(oid, 0) + 1
+                    )
+                    if (self._gcs is not None and self._multi_node
+                            and (not self.directory.has_entry(oid)
+                                 or oid in self._borrow_stubs)):
+                        # Nothing on this node will ever seal it (a
+                        # streamed item of a producer elsewhere, a ref
+                        # borrowed unsealed): its publication in the GCS
+                        # object directory has to.
+                        locating.append(asyncio.ensure_future(
+                            self._seal_when_published(oid)
+                        ))
+                tasks = [asyncio.ensure_future(ev.wait()) for ev in pending]
+                try:
+                    await asyncio.wait(
+                        tasks,
+                        timeout=remaining,
+                        return_when=asyncio.FIRST_COMPLETED,
+                    )
+                finally:
+                    for t in tasks:
+                        t.cancel()
+        finally:
+            for t in locating:
+                t.cancel()
+            for oid in parked:
+                left = self._parked_waits[oid] - 1
+                if left:
+                    self._parked_waits[oid] = left
+                    continue
+                del self._parked_waits[oid]
+                if (oid not in self._sealed
+                        and not self.directory.has_entry(oid)):
+                    # An object that never came to be (a stream's item
+                    # past its end): with no entry, nothing but this
+                    # would ever take its event away.
+                    self._seal_events.pop(oid, None)
+
+    async def _seal_when_published(self, oid: ObjectID):
+        """``wait_objects``' arm for an object another node will seal:
+        park on the GCS object directory's long-poll and seal the object
+        here, as a remote location, the moment its node publishes it. The
+        long-poll is re-armed when it runs out; nothing waits for that."""
+        while oid not in self._sealed:
+            t0 = time.monotonic()
             try:
-                await asyncio.wait(
-                    tasks,
-                    timeout=remaining,
-                    return_when=asyncio.FIRST_COMPLETED,
+                nid = await self._gcs.locate_object(
+                    oid, timeout=self.config.object_locate_timeout_s
                 )
-            finally:
-                for t in tasks:
-                    t.cancel()
+            # An unreachable GCS reads as "not published yet".
+            except Exception:  # rtlint: disable=swallowed-failure
+                nid = None
+            if nid is not None and nid != self.node_id:
+                self._adopt_remote_object(oid, nid)
+                # Not awaited: the waiter this seal wakes cancels us.
+                self._spawn_bg(self._register_borrow(oid, owner_hex=nid.hex()))
+                return
+            if time.monotonic() - t0 < 1.0:
+                # Came back empty at once (GCS down, a stale claim of
+                # this very node): do not spin on it.
+                await asyncio.sleep(1.0)
+
+    def _adopt_remote_object(self, oid: ObjectID, nid: NodeID):
+        """Seal ``oid`` here as living on node ``nid``. Any entry for a
+        remotely-owned object is a borrow this node must register with
+        the owner (the caller does, by its own discipline)."""
+        self._seal_object(oid, RemoteLocation(nid.hex(), 0))
+        self._borrow_stubs.add(oid)
 
     def _remove_ref(self, object_id: ObjectID, count: int = 1):
         self.directory.remove_ref(object_id, count)

@@ -6,23 +6,39 @@ into the object store AS IT IS PRODUCED (index-derived ObjectIDs), so the
 consumer iterates results while the producer is still running —
 backpressure-free pipelining for long producers.
 
-Protocol: the producing worker seals item i as
-``ObjectID.from_index(task_id, STREAM_BASE | (i+1))`` with one pinned
-ref, then writes a small KV record ``__stream__/<task>/<i>``; generator
-exhaustion writes an ``end`` record. The consumer polls the KV (cheap:
-single control-plane lookup), adopts each item ref (its +1 cancels the
-producer's pin via coalesced delta flushing), and raises StopIteration at
-the end marker. Works cross-node: item locations ride the GCS object
-directory like any sealed object.
+Protocol. An item's id is derived, not announced: item i of a task is
+``ObjectID.from_index(task_id, STREAM_BASE | (i+1))``, so the consumer
+knows it before the item exists.
+
+- The producer publishes nothing but the objects themselves: the worker
+  seals item i with one pinned ref (a ``put`` to its node manager), and
+  when the generator is exhausted the task's one return slot, the
+  completion ref, seals with the item count (or with the task's error).
+- The consumer blocks on a seal: one ``wait([item_i, completion])`` on
+  its node manager, which the producer's seal wakes (``_seal_events``;
+  an item sealed on another node wakes it through the GCS object
+  directory's long-poll). There is no timer on the way: a token reaches
+  ``next()`` when it is sealed. Item sealed: adopt it (the consumer's +1
+  cancels the producer's pin via coalesced delta flushing). Completion
+  sealed and item not: end of stream, or the task's error. Items of one
+  node are sealed before their completion, in order; an item still on its
+  way from another node when the count arrives is waited for alone, and
+  one still on its way when an ERROR arrives is cut off by that error.
+- The retry record ``__stream__/<task>`` holds the consumer's position
+  and exists only for a producer that can be retried (``max_retries``):
+  a retried attempt re-runs the generator from the start and must not
+  re-seal, and so re-pin, what the consumer already took and dropped.
+  The consumer writes its position as it adopts an item; only a retried
+  attempt reads it; it is deleted with the stream.
 """
 
 from __future__ import annotations
 
+import threading
 import time
-from typing import Any, Optional
+from typing import Optional
 
-import cloudpickle
-
+from ..util.metrics import Counter, Histogram
 from .ids import ObjectID, TaskID
 from .reference import ObjectRef
 
@@ -30,29 +46,62 @@ from .reference import ObjectRef
 # (0x8000_0000 block).
 STREAM_BASE = 0x4000_0000
 
-POLL_INTERVAL_S = 0.02
+# Consumer-side delivery accounting. A blocked share near 100% with waits
+# near the producer's cadence is a consumer that keeps up; a low blocked
+# share is a consumer that lags (items were waiting for it).
+STREAM_ITEMS = Counter(
+    "ray_tpu_stream_items_total",
+    "Streaming-generator items handed to a consumer.",
+)
+STREAM_ITEMS_BLOCKED = Counter(
+    "ray_tpu_stream_item_blocked_total",
+    "Items the consumer had to block for (not yet sealed when it asked).",
+)
+STREAM_ITEM_WAIT = Histogram(
+    "ray_tpu_stream_item_wait_seconds",
+    "Time a consumer spent blocked until the item it asked for sealed.",
+    boundaries=[0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+                1.0, 2.5, 5.0, 10.0],
+)
+
+# How many item ids an abandoned stream's release asks about at once.
+_RELEASE_WINDOW = 64
 
 
 def stream_item_id(task_id: TaskID, index: int) -> ObjectID:
     return ObjectID.from_index(task_id, STREAM_BASE | (index + 1))
 
 
-def stream_key(task_id: TaskID, index: int) -> str:
-    return f"__stream__/{task_id.hex()}/{index}"
+def stream_key(task_id: TaskID) -> str:
+    """KV key of a retriable stream's retry record (consumer position)."""
+    return f"__stream__/{task_id.hex()}"
+
+
+def consumed_upto(rt, task_id: TaskID) -> int:
+    """Producer side, retried attempts only: how many leading items the
+    consumer has already taken (0 when it took none)."""
+    blob = rt.kv_get(stream_key(task_id))
+    return int(blob) if blob else 0
 
 
 class ObjectRefGenerator:
     """Iterator over a streaming task's yielded ObjectRefs (ref:
-    ObjectRefGenerator). ``next()`` returns the NEXT item's ObjectRef as
-    soon as the producer sealed it; iteration ends when the producer's
-    generator is exhausted. The completion ref resolves to the item count
-    (and surfaces the task's exception, if any)."""
+    ObjectRefGenerator). ``next()`` blocks on the node manager until the
+    producer SEALS the next item (or the task completes) and returns the
+    item's ObjectRef; nothing on the way sleeps or polls. Iteration ends
+    when the producer's generator is exhausted. The completion ref
+    resolves to the item count (and surfaces the task's exception, if
+    any). ``retriable`` says the producing task may be re-run after a
+    crash: only then is the retry record (module docstring) kept."""
 
-    def __init__(self, task_id: TaskID, completion_ref: ObjectRef):
+    def __init__(self, task_id: TaskID, completion_ref: ObjectRef,
+                 retriable: bool = False):
         self._task_id = task_id
         self._completion_ref = completion_ref
+        self._retriable = retriable
         self._next = 0
         self._count: Optional[int] = None
+        self._released = False
         # Optional per-item production deadline (serve SSE guard).
         self.item_timeout_s = None
 
@@ -70,103 +119,82 @@ class ObjectRefGenerator:
         rt = current_runtime()
         if self._count is not None and self._next >= self._count:
             raise StopIteration
-        key = stream_key(self._task_id, self._next)
-        deadline = (
-            None if self.item_timeout_s is None
-            else time.monotonic() + self.item_timeout_s
-        )
-        while True:
-            blob = rt.kv_get(key)
-            if blob is not None:
-                break
-            if deadline is not None and time.monotonic() > deadline:
-                # A wedged producer must not hold consumers (serve proxy
-                # threads) forever — surface a timeout instead.
-                from .exceptions import GetTimeoutError
-
-                raise GetTimeoutError(
-                    f"stream item {self._next} not produced within "
-                    f"{self.item_timeout_s}s"
-                )
-            # Surface producer failure instead of hanging: the completion
-            # slot seals (with the error) when the task dies.
-            import ray_tpu
-
-            done, _ = ray_tpu.wait(
-                [self._completion_ref], num_returns=1, timeout=0
-            )
-            if done:
-                # Either finished (end marker imminent/count known) or
-                # failed (get raises the task error).
-                count = ray_tpu.get(self._completion_ref)
-                blob = rt.kv_get(key)
-                if blob is None:
-                    self._count = count
-                    raise StopIteration
-                break
-            time.sleep(POLL_INTERVAL_S)
-        payload = cloudpickle.loads(blob)
-        if "end" in payload:
-            self._count = payload["end"]
-            self._drop_all_kv()
+        item = stream_item_id(self._task_id, self._next)
+        if not self._await_item(rt, item):
+            if self._retriable:
+                _drop_retry_record(rt, self._task_id)
             raise StopIteration
-        idx = self._next
         self._next += 1
-        oid = ObjectID.from_hex(payload["oid"])
-        ref = ObjectRef(oid, _register=True)
+        ref = ObjectRef(item, _register=True)
         # Cancel the producer-side pin: the +1 just registered and this -1
         # coalesce locally, leaving the seal-time pin as the user ref's
         # count until the ref is dropped.
-        rt.refs.decr(oid)
-        # TOMBSTONE rather than delete: a retried producer checks this key
-        # to decide whether an index was already pinned — deleting it would
-        # make the retry re-pin consumed items (leak).
-        try:
-            rt.kv_put(stream_key(self._task_id, idx),
-                      cloudpickle.dumps({"consumed": True}))
-        except Exception:
-            pass
+        rt.refs.decr(item)
+        if self._retriable:
+            _write_retry_record(rt, self._task_id, self._next)
         return ref
 
-    def _drop_all_kv(self) -> None:
-        """Stream finished: progress records (incl. tombstones) go away."""
-        from .runtime_context import current_runtime_or_none
+    def _await_item(self, rt, item: ObjectID) -> bool:
+        """Block until ``item`` is sealed (True) or the stream ended
+        before it (False); raises the task's error, or GetTimeoutError
+        after ``item_timeout_s`` without either."""
+        import ray_tpu
 
-        rt = current_runtime_or_none()
-        if rt is None:
-            return
-        try:
-            prefix = f"__stream__/{self._task_id.hex()}/"
-            for key in rt.kv_keys(prefix):
-                try:
-                    rt.kv_del(key)
-                except Exception:
-                    pass
-        except Exception:
-            pass
+        ids = [item, self._completion_ref.id()]
+        while True:
+            ready = rt._wait(ids, 1, 0)
+            if not ready:
+                t0 = time.monotonic()
+                ready = rt._wait(ids, 1, self.item_timeout_s)
+                if not ready:
+                    # A wedged producer must not hold consumers (serve
+                    # proxy threads) forever — surface a timeout instead.
+                    from .exceptions import GetTimeoutError
+
+                    raise GetTimeoutError(
+                        f"stream item {self._next} not produced within "
+                        f"{self.item_timeout_s}s"
+                    )
+                if item in ready:
+                    STREAM_ITEMS_BLOCKED.inc()
+                    STREAM_ITEM_WAIT.observe(time.monotonic() - t0)
+            if item in ready:
+                STREAM_ITEMS.inc()
+                return True
+            # The task is over and this item is not sealed here: finished
+            # (the count says whether the item exists) or failed (get
+            # raises the task's error).
+            self._count = ray_tpu.get(self._completion_ref)
+            if self._next >= self._count:
+                return False
+            # It exists, and is only still on its way from the producer's
+            # node: wait for it alone.
+            ids = [item]
 
     def __del__(self):
         """Abandoned mid-stream: release the producer pins of every
-        unconsumed item and drop all progress records, so a consumer that
-        stops early doesn't leak object-store memory.
+        unconsumed item sealed so far and drop the retry record, so a
+        consumer that stops early doesn't leak object-store memory.
 
         The cleanup does BLOCKING control-plane calls, and __del__ can
         fire on ANY thread the garbage collector happens to run on —
         including the node-manager event loop itself (observed: gc
-        during frame pickling on the NM loop → kv_keys → call_sync onto
-        the same loop → the whole runtime deadlocks). So the work is
-        handed to a short-lived daemon thread, never run inline."""
+        during frame pickling on the NM loop → a call_sync onto the same
+        loop → the whole runtime deadlocks). So the work is handed to a
+        short-lived daemon thread, never run inline."""
         try:
-            import threading
-
             from .runtime_context import current_runtime_or_none
 
             rt = current_runtime_or_none()
             if rt is None:
                 return
+            if self._released or (
+                    self._count is not None and self._next >= self._count):
+                return  # released already, or consumed to its end
+            self._released = True
             threading.Thread(
                 target=_release_abandoned_stream,
-                args=(rt, self._task_id, self._next),
+                args=(rt, self._task_id, self._next, self._retriable),
                 name="stream-gc",
                 daemon=True,
             ).start()
@@ -178,23 +206,38 @@ class ObjectRefGenerator:
                 f"next={self._next})")
 
 
-def _release_abandoned_stream(rt, task_id, next_idx: int) -> None:
-    """Off-thread body of ObjectRefGenerator.__del__ (see there)."""
+def _write_retry_record(rt, task_id: TaskID, position: int) -> None:
+    # The record guards a retry against a leak; a stream must not fail
+    # over it.
     try:
-        prefix = f"__stream__/{task_id.hex()}/"
-        for key in rt.kv_keys(prefix):
-            try:
-                idx = int(key.rsplit("/", 1)[1])
-            except ValueError:
-                continue
-            blob = rt.kv_get(key)
-            if blob and idx >= next_idx:
-                payload = cloudpickle.loads(blob)
-                if "oid" in payload:
-                    rt.refs.decr(ObjectID.from_hex(payload["oid"]))
-            try:
-                rt.kv_del(key)
-            except Exception:
-                pass
+        rt.kv_put(stream_key(task_id), str(position).encode())
+    except Exception:  # rtlint: disable=swallowed-failure
+        pass
+
+
+def _drop_retry_record(rt, task_id: TaskID) -> None:
+    try:
+        rt.kv_del(stream_key(task_id))
+    except Exception:  # rtlint: disable=swallowed-failure
+        pass
+
+
+def _release_abandoned_stream(rt, task_id, next_idx: int,
+                              retriable: bool) -> None:
+    """Off-thread body of ObjectRefGenerator.__del__ (see there): the
+    sealed items from ``next_idx`` on, asked for a window at a time."""
+    try:
+        while True:
+            window = [stream_item_id(task_id, next_idx + k)
+                      for k in range(_RELEASE_WINDOW)]
+            sealed = set(rt._wait(window, len(window), 0))
+            for oid in window:
+                if oid in sealed:
+                    rt.refs.decr(oid)
+            if len(sealed) < len(window):
+                break
+            next_idx += len(window)
+        if retriable:
+            _drop_retry_record(rt, task_id)
     except Exception:
         pass

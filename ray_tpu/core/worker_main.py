@@ -1051,33 +1051,19 @@ class Worker:
             return rt.store.put_serialized(oid, sobj)
 
         def stream_item(index: int, value):
-            """Seal one streamed yield + publish its KV progress record
-            (see core/streaming.py for the protocol)."""
-            import cloudpickle
-
-            from .executor import _STREAM_END
-            from .serialization import serialize as _ser
-            from .streaming import stream_item_id, stream_key
-
-            key = stream_key(spec.task_id, index)
-            if value is _STREAM_END:
-                rt.kv_put(key, cloudpickle.dumps({"end": index}))
-                return
-            # Retry of an index the consumer already consumed (tombstone
-            # record): nothing to re-seal — and the tombstone must survive
-            # so a THIRD attempt stays a no-op too.
-            prior = rt.kv_get(key)
-            if prior is not None:
-                try:
-                    if cloudpickle.loads(prior).get("consumed"):
-                        return
-                # Unreadable tombstone: treat as not-consumed and
-                # re-seal below — idempotent either way.
-                except Exception:  # rtlint: disable=swallowed-failure
-                    pass
-            oid = stream_item_id(spec.task_id, index)
+            """Seal one streamed yield: the seal is what wakes the
+            consumer (see core/streaming.py for the protocol)."""
             from .serialization import serialize_with_refs as _ser_refs
+            from .streaming import consumed_upto, stream_item_id
 
+            # A retried attempt re-runs the generator from its start:
+            # what the consumer already took is not sealed, and so not
+            # pinned, a second time (the retry record). A first attempt
+            # asks nothing.
+            if (spec.retries_left < spec.max_retries
+                    and index < consumed_upto(rt, spec.task_id)):
+                return
+            oid = stream_item_id(spec.task_id, index)
             sobj, nested = _ser_refs(value)
             loc = rt.store.put_serialized(oid, sobj)
             # Seal with one pinned ref (consumed by the reader's adopt).
@@ -1091,7 +1077,6 @@ class Worker:
             if nested:
                 msg["nested"] = nested
             self.conn.send(msg)
-            rt.kv_put(key, cloudpickle.dumps({"oid": oid.hex()}))
 
         rt.current_task_id = spec.task_id
         if spec.task_type in (TaskType.ACTOR_CREATION_TASK, TaskType.ACTOR_TASK):

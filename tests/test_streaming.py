@@ -118,3 +118,301 @@ def test_generator_del_on_node_manager_loop_does_not_deadlock(
         return 41
 
     assert rt.get(ping.remote(), timeout=30) == 41
+
+
+# ---- delivery: a consumer blocks on the producer's seal (PR 31) ----------
+
+
+def _stream_counters():
+    """(items, blocked, waits observed, seconds waited) of this process's
+    stream-delivery counters."""
+    from ray_tpu.util.metrics import local_snapshot
+
+    snap = local_snapshot()
+
+    def series(name):
+        return snap.get(name, ("", {}, ""))[1].get((), None)
+
+    wait = series("ray_tpu_stream_item_wait_seconds") or {
+        "count": 0, "sum": 0.0}
+    return (series("ray_tpu_stream_items_total") or 0.0,
+            series("ray_tpu_stream_item_blocked_total") or 0.0,
+            wait["count"], wait["sum"])
+
+
+def test_item_is_delivered_at_its_seal_not_at_a_tick(ray_tpu_start,
+                                                     monkeypatch):
+    """A producer 5 ms an item is consumed 5 ms an item: no sleep is on
+    the delivery path (the 20 ms KV poll made every gap 20+ ms)."""
+    import statistics
+    import types
+
+    from ray_tpu.core import streaming
+
+    def no_sleep(_):
+        raise AssertionError("the delivery path slept")
+
+    monkeypatch.setattr(streaming, "time", types.SimpleNamespace(
+        monotonic=time.monotonic, sleep=no_sleep))
+
+    @ray_tpu.remote(num_returns="streaming")
+    def paced(n):
+        for i in range(n):
+            time.sleep(0.005)
+            yield i
+
+    stamps, values = [], []
+    for ref in paced.remote(40):
+        values.append(ray_tpu.get(ref))
+        stamps.append(time.monotonic())
+    assert values == list(range(40))
+    gaps = [b - a for a, b in zip(stamps, stamps[1:])]
+    assert statistics.median(gaps) < 0.015, sorted(gaps)
+
+
+def test_slow_producer_costs_blocked_waits_not_polls(ray_tpu_start,
+                                                     monkeypatch):
+    """1.5 s between two items is one parked wait, not ~75 polls."""
+    from ray_tpu.core.runtime_context import current_runtime
+
+    rt = current_runtime()
+    calls = {"kv_get": 0, "_wait": 0}
+
+    def counted(name):
+        real = getattr(rt, name)
+
+        def wrapper(*a, **kw):
+            calls[name] += 1
+            return real(*a, **kw)
+
+        return wrapper
+
+    for name in calls:
+        monkeypatch.setattr(rt, name, counted(name))
+
+    @ray_tpu.remote(num_returns="streaming")
+    def slow():
+        yield "a"
+        time.sleep(1.5)
+        yield "b"
+
+    t0 = time.monotonic()
+    assert [ray_tpu.get(r) for r in slow.remote()] == ["a", "b"]
+    assert time.monotonic() - t0 >= 1.5
+    # Two items and the end, each at most a look and a parked wait.
+    assert calls["_wait"] <= 6 and calls["kv_get"] == 0, calls
+
+
+@pytest.mark.parametrize("pace", ["consumer_keeps_up", "consumer_lags"])
+def test_stream_counters_follow_the_pace(ray_tpu_start, pace):
+    n = 12
+
+    @ray_tpu.remote(num_returns="streaming")
+    def produce(gap_s):
+        for i in range(n):
+            time.sleep(gap_s)
+            yield i
+
+    before = _stream_counters()
+    if pace == "consumer_keeps_up":
+        gen = produce.remote(0.05)
+    else:
+        gen = produce.remote(0.0)
+        ray_tpu.get(gen.completed, timeout=60)  # every item sealed first
+    assert [ray_tpu.get(r) for r in gen] == list(range(n))
+    items, blocked, waits, waited_s = (
+        a - b for a, b in zip(_stream_counters(), before))
+    assert items == n
+    assert waits == blocked
+    if pace == "consumer_keeps_up":
+        # It blocked for (nearly) every item, about the producer's gap.
+        assert blocked >= n - 2, blocked
+        assert 0.03 * blocked < waited_s < 0.2 * blocked, waited_s
+    else:
+        assert blocked == 0 and waited_s == 0
+
+
+@ray_tpu.remote
+class _StreamActor:
+    def produce(self, k, fail):
+        for i in range(k):
+            yield i
+        if fail:
+            raise ValueError("stream broke")
+
+
+@ray_tpu.remote(num_returns="streaming")
+def _stream_task(k, fail):
+    for i in range(k):
+        yield i
+    if fail:
+        raise ValueError("stream broke")
+
+
+@pytest.mark.parametrize("kind", ["task", "actor_method"])
+@pytest.mark.parametrize("k,fail", [(0, False), (3, True)],
+                         ids=["empty", "error_after_3"])
+def test_stream_ends_at_once(ray_tpu_start, kind, k, fail):
+    """The end of a stream and a producer's error are seals like any
+    other: neither waits for ``item_timeout_s``."""
+    if kind == "task":
+        gen = _stream_task.remote(k, fail)
+    else:
+        actor = _StreamActor.remote()
+        gen = actor.produce.options(num_returns="streaming").remote(k, fail)
+    gen.item_timeout_s = 120.0
+    t0 = time.monotonic()
+    got = []
+    if fail:
+        with pytest.raises(ValueError, match="stream broke"):
+            for r in gen:
+                got.append(ray_tpu.get(r))
+    else:
+        got = [ray_tpu.get(r) for r in gen]
+        with pytest.raises(StopIteration):
+            next(gen)
+    assert got == list(range(k))
+    assert time.monotonic() - t0 < 30
+
+
+def test_item_timeout_on_a_wedged_producer(ray_tpu_start):
+    from ray_tpu.core.exceptions import GetTimeoutError
+
+    @ray_tpu.remote(num_returns="streaming")
+    def wedge():
+        yield 0
+        time.sleep(30)
+        yield 1
+
+    gen = wedge.remote()
+    assert ray_tpu.get(next(gen)) == 0
+    gen.item_timeout_s = 0.5
+    t0 = time.monotonic()
+    with pytest.raises(GetTimeoutError):
+        next(gen)
+    assert 0.5 <= time.monotonic() - t0 < 10
+
+
+def test_retried_producer_skips_what_the_consumer_took(ray_tpu_start,
+                                                       tmp_path):
+    """A crashed producer is re-run from its start; the consumer goes on
+    where it was, and the retry record (the consumer's position) keeps
+    the second attempt from re-sealing what was already taken."""
+    from ray_tpu.core.runtime_context import current_runtime
+    from ray_tpu.core.streaming import stream_item_id, stream_key
+
+    marker = str(tmp_path / "attempted")
+
+    @ray_tpu.remote(num_returns="streaming", max_retries=2)
+    def flaky(marker):
+        import os
+
+        first = not os.path.exists(marker)
+        for i in range(6):
+            if first and i == 3:
+                open(marker, "w").close()
+                time.sleep(0.5)  # let the consumer take items 0..2
+                os._exit(1)
+            yield i
+
+    rt = current_runtime()
+    gen = flaky.remote(marker)
+    task_id = gen._task_id
+    got = []
+    for ref in gen:
+        got.append(ray_tpu.get(ref))
+        if len(got) == 3:
+            assert rt.kv_get(stream_key(task_id)) == b"3"
+            del ref  # taken AND dropped: only the record remembers it
+    assert got == list(range(6))
+    assert rt.kv_get(stream_key(task_id)) is None  # gone with the stream
+    # The second attempt did not seal items 0..2 again: past the grace
+    # period nothing holds them.
+    deadline = time.monotonic() + 20
+    first3 = [stream_item_id(task_id, i) for i in range(3)]
+    while rt._wait(first3, 3, 0) and time.monotonic() < deadline:
+        time.sleep(0.2)
+    assert rt._wait(first3, 3, 0) == []
+
+
+def test_abandoned_stream_releases_its_sealed_items(ray_tpu_start):
+    from ray_tpu.core.runtime_context import current_runtime
+    from ray_tpu.core.streaming import stream_item_id
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen():
+        for i in range(5):
+            yield i
+
+    rt = current_runtime()
+    g = gen.remote()
+    task_id = g._task_id
+    assert ray_tpu.get(next(g)) == 0
+    ray_tpu.get(g.completed, timeout=60)
+    rest = [stream_item_id(task_id, i) for i in range(1, 5)]
+    assert len(rt._wait(rest, 4, 0)) == 4  # sealed, pinned, untaken
+    del g
+    deadline = time.monotonic() + 20
+    while rt._wait(rest, 4, 0) and time.monotonic() < deadline:
+        time.sleep(0.2)
+    assert rt._wait(rest, 4, 0) == []
+
+
+def test_finished_streams_leave_no_event_behind(ray_tpu_start):
+    """Every stream's last wait names an item that never comes to be;
+    its seal event leaves with the wait."""
+    from ray_tpu.core.runtime_context import current_runtime
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen(n):
+        for i in range(n):
+            yield i
+
+    nm = current_runtime()._nm
+    for _ in range(5):
+        assert [ray_tpu.get(r) for r in gen.remote(3)] == [0, 1, 2]
+    assert not nm._parked_waits
+    assert not nm._seal_events, list(nm._seal_events)
+
+
+def test_stream_from_another_node_wakes_on_its_seal():
+    """A producer on another node: its seal reaches the consumer's wait
+    through the GCS object directory's long-poll, not through a timer;
+    the end of the stream and an error do too."""
+    import statistics
+
+    from ray_tpu.cluster_utils import Cluster
+
+    c = Cluster(head_resources={"CPU": 2},
+                system_config={"log_to_driver": False})
+    try:
+        c.add_node(num_cpus=2, resources={"gadget": 1})
+        c.wait_for_nodes(2)
+
+        @ray_tpu.remote(num_returns="streaming", resources={"gadget": 1})
+        def far(n, gap_s, fail):
+            for i in range(n):
+                time.sleep(gap_s)
+                yield i
+            if fail:
+                raise ValueError("stream broke")
+
+        assert [ray_tpu.get(r) for r in far.remote(2, 0.0, False)] == [0, 1]
+        stamps, values = [], []
+        for ref in far.remote(20, 0.02, False):
+            values.append(ray_tpu.get(ref))
+            stamps.append(time.monotonic())
+        assert values == list(range(20))
+        gaps = [b - a for a, b in zip(stamps, stamps[1:])]
+        assert statistics.median(gaps) < 0.1, sorted(gaps)
+
+        t0 = time.monotonic()
+        assert list(far.remote(0, 0.0, False)) == []
+        got = []
+        with pytest.raises(ValueError, match="stream broke"):
+            for r in far.remote(3, 0.05, True):
+                got.append(ray_tpu.get(r))
+        assert got == [0, 1, 2][:len(got)]
+        assert time.monotonic() - t0 < 20
+    finally:
+        c.shutdown()
