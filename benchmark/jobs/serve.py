@@ -1,5 +1,7 @@
 """Job kind ``serve``: an ``LLMDeployment`` replica on a ``tpu`` worker
-behind the per-node HTTP proxy, driven by the open-loop load generator.
+behind the per-node HTTP proxy, driven by the load generator: its open
+loop for a traffic file with ``rate_per_s``, its closed loop for one
+with ``concurrency``.
 
 From chip_smoke.py's ``_llm_deployment``/``serve_phase``/``_sse`` (PR 21).
 """
@@ -7,9 +9,15 @@ From chip_smoke.py's ``_llm_deployment``/``serve_phase``/``_sse`` (PR 21).
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 import time
 from typing import Dict, List, Mapping
+
+# What the harness gives each of its own calls into the replica
+# (``facts``, ``trace_start``, ``trace_stop``, ``margins``): stopping a
+# trace of some hundred decode steps alone can take over a minute.
+CONTROL_TIMEOUT_S = 900.0
 
 
 def _deployment():
@@ -112,6 +120,17 @@ def warmup_requests(requests: List[Mapping], engine: Mapping) -> List[Dict]:
             for i, (_, r) in enumerate(sorted(by_bucket.items()))]
 
 
+def control_call(handle, method: str, *args):
+    """One of the harness's own calls into the replica, under the
+    harness's deadline and not the one serve gives a user's request
+    that brings none (``serve_default_request_timeout_s``)."""
+    from ray_tpu.util import overload
+
+    with overload.deadline_scope(time.time() + CONTROL_TIMEOUT_S):
+        reply = handle.options(method=method).remote(*args)
+    return reply.result(timeout=CONTROL_TIMEOUT_S)
+
+
 @contextlib.contextmanager
 def deployed(cell: Mapping, config: Mapping, traffic: Mapping, seed: int):
     """The system up with one replica behind the proxy; yields
@@ -133,10 +152,7 @@ def deployed(cell: Mapping, config: Mapping, traffic: Mapping, seed: int):
             handle = serve.run(dep.bind(config, config["engine"], seed,
                                         cell["chips"]), name="llm")
 
-            def call(method, *args):
-                return handle.options(method=method).remote(*args).result(
-                    timeout=900)
-
+            call = functools.partial(control_call, handle)
             pid = call("facts")["pid"]
             proxies = http_proxy.start_per_node_proxies(port=0)
             (_, port), = proxies.values()
@@ -161,12 +177,47 @@ def warm_up(port: int, requests: List[Mapping], engine: Mapping) -> None:
             raise RuntimeError(f"warm-up failed: {warm['error']}")
 
 
+def offer_load(port: int, requests: List[Mapping], traffic: Mapping,
+               seconds: float) -> Dict:
+    """The window's load: a closed loop for a traffic file that holds
+    ``concurrency`` streams open, an open loop for one with a rate."""
+    from .. import loadgen
+
+    if "concurrency" in traffic:
+        return loadgen.run_closed_loop(
+            "127.0.0.1", port, "/llm/stream", requests,
+            traffic["concurrency"], seconds, traffic["grace_s"])
+    return loadgen.run_open_loop(
+        "127.0.0.1", port, "/llm/stream", requests, traffic["clients"],
+        seconds, traffic["grace_s"])
+
+
+def judge(samples: List[Mapping], margins: List[List[float]],
+          programs_in_window: int, tol: float):
+    """``(correct, check)``: no request failed, nothing compiled or
+    loaded inside the window, and no served token of the checked sample
+    trails the reference's best logit at its position by more than
+    ``tol``. ``check`` holds each number compared and its limit."""
+    failed = [s for s in samples if s["error"] is not None]
+    worst = max((m for row in margins for m in row), default=float("inf"))
+    correct = (not failed and bool(margins) and programs_in_window == 0
+               and worst <= tol)
+    return correct, {"worst_margin": worst, "tol": tol,
+                     "tokens": sum(len(r) for r in margins),
+                     "argmax": sum(m == 0 for r in margins for m in r),
+                     "failed": len(failed),
+                     "programs_in_window": programs_in_window,
+                     "errors": [s["error"] for s in failed][:3]}
+
+
 def run(cell: Mapping, config: Mapping, traffic: Mapping, seed: int,
         seconds: float, trace: bool) -> Dict:
     import random
 
     from .. import loadgen
 
+    # A closed loop's schedule is its whole list: the warm-up then loads
+    # every prefill bucket a window can meet, whichever requests it takes.
     requests = loadgen.schedule(traffic, seed, seconds, config["vocab_size"])
     traced: Dict = {}
     with deployed(cell, config, traffic, seed) as (port, call):
@@ -183,9 +234,7 @@ def run(cell: Mapping, config: Mapping, traffic: Mapping, seed: int,
         tracer = threading.Thread(target=trace_part) if trace else None
         if tracer:
             tracer.start()
-        load = loadgen.run_open_loop("127.0.0.1", port, "/llm/stream",
-                                     requests, traffic["clients"],
-                                     seconds, traffic["grace_s"])
+        load = offer_load(port, requests, traffic, seconds)
         if tracer:
             tracer.join(timeout=300)
         after = call("facts")
@@ -199,22 +248,16 @@ def run(cell: Mapping, config: Mapping, traffic: Mapping, seed: int,
 
     for s in load["samples"]:
         del s["tokens"]
-    failed = [s for s in load["samples"] if s["error"] is not None]
-    worst = max((m for row in margins for m in row), default=float("inf"))
     programs_in_window = after["programs"] - before["programs"]
-    correct = (not failed and bool(margins) and programs_in_window == 0
-               and worst <= after["margin_tol"])
+    correct, check = judge(load["samples"], margins, programs_in_window,
+                           after["margin_tol"])
     return {
-        "correct": correct, "attempted": len(requests),
-        "failed": len(failed),
+        "correct": correct, "attempted": len(load["samples"]),
+        "failed": check["failed"],
         "worker": {**after, "window_start": load["t0_wall"],
                    "programs_in_window": programs_in_window,
-                   "check": {"worst_margin": worst,
-                             "tol": after["margin_tol"],
-                             "tokens": sum(len(r) for r in margins),
-                             "argmax": sum(m == 0 for r in margins
-                                           for m in r),
-                             "errors": [s["error"] for s in failed][:3]},
+                   "check": check,
+                   "stop_trace_s": traced.get("stop_trace_s"),
                    "engine_before": before["engine"],
                    "phases": {**before["phases"],
                               "deployed": deployed_at}},

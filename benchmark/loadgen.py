@@ -1,17 +1,23 @@
-"""Open-loop load from a seed: the schedule, and the clients that keep it.
+"""Load from a seed: the schedule, and the clients that keep it.
 
-A traffic file fixes a rate and two length distributions. From them comes
-one fixed multiset of prompt lengths, answer lengths and gaps between
-arrivals (the distributions' quantiles, evenly spaced), so that every
-seed offers the same work; the seed shuffles the three lists and draws
-the token ids. Requests go out when they are due whether or not earlier
-ones have finished, and each is timed from when it was due.
+A traffic file fixes two length distributions and either a rate (an
+open loop) or a number of streams (a closed loop). From them comes one
+fixed multiset of prompt lengths, answer lengths and, for a rate, gaps
+between arrivals (the distributions' quantiles, evenly spaced), so that
+every seed offers the same work; the seed shuffles the lists and draws
+the token ids. In an open loop requests go out when they are due whether
+or not earlier ones have finished, and each is timed from when it was
+due. In a closed loop ``concurrency`` clients each send the list's next
+request the moment their last stream ends, and each is timed from when
+it was sent.
 
 Parameters a traffic file gives, so that a new mix of these is a data
-file: ``rate_per_s`` (Poisson arrivals), and ``prompt`` and ``output`` as
-``lognormal`` (``median``, ``sigma``) or ``loguniform``, clipped to
-``min`` and ``max``. Bursts, shared prefixes or two classes of request
-come with the cell that needs them, as a generator of its own.
+file: ``rate_per_s`` (Poisson arrivals) or ``concurrency`` with
+``requests`` (the length of the list the clients draw from), and
+``prompt`` and ``output`` as ``lognormal`` (``median``, ``sigma``) or
+``loguniform``, clipped to ``min`` and ``max``. Bursts, shared prefixes
+or two classes of request come with the cell that needs them, as a
+generator of its own.
 """
 
 from __future__ import annotations
@@ -58,13 +64,19 @@ def arrival_gaps(n: int, seconds: float) -> List[float]:
 
 def schedule(traffic: Mapping, seed: int, seconds: float, vocab: int
              ) -> List[Dict]:
-    """The requests of one run, a pure function of its arguments."""
-    n = max(1, round(traffic["rate_per_s"] * seconds))
+    """The requests of one run, a pure function of its arguments. A
+    file with ``concurrency`` gives its whole list whatever ``seconds``
+    is, in the order the clients take it, and no request is due at a
+    time of its own (``due_s`` None: ``run_closed_loop`` stamps it)."""
+    closed = "concurrency" in traffic
+    n = (traffic["requests"] if closed
+         else max(1, round(traffic["rate_per_s"] * seconds)))
     rng = np.random.default_rng(seed)
     prompts = rng.permutation(lengths(traffic["prompt"], n))
     outputs = rng.permutation(lengths(traffic["output"], n))
-    due = np.cumsum(rng.permutation(arrival_gaps(n, seconds)))
-    return [{"id": i, "due_s": float(due[i]),
+    due = ([None] * n if closed else
+           np.cumsum(rng.permutation(arrival_gaps(n, seconds))).tolist())
+    return [{"id": i, "due_s": due[i],
              "prompt": rng.integers(0, vocab, int(prompts[i])).tolist(),
              "max_new_tokens": int(outputs[i])}
             for i in range(n)]
@@ -108,6 +120,8 @@ def _stream(host: str, port: int, path: str, request: Mapping, t0: float,
         sock = conn.sock
         with cut.lock:
             cut.open.add(sock)
+            if cut.done:  # closed since a closed loop's client looked
+                sock.shutdown(socket.SHUT_RDWR)
         conn.request("POST", path, body,
                      {"Content-Type": "application/json"})
         resp = conn.getresponse()
@@ -169,4 +183,46 @@ def run_open_loop(host: str, port: int, path: str, requests: List[Mapping],
         cut.close_all()
         samples = [f.result() for f in futures]
     return {"t0_wall": t0_wall, "samples": samples,
+            "closed_s": time.perf_counter() - t0}
+
+
+def run_closed_loop(host: str, port: int, path: str, requests: List[Mapping],
+                    concurrency: int, seconds: float, grace_s: float,
+                    timeout: float = 300.0) -> Dict:
+    """``concurrency`` clients, all started at the window's start; each
+    sends the list's next request the moment its last stream ends, until
+    the streams are closed ``grace_s`` after the window's end: as many
+    streams are open in every gap that is measured. A request is due
+    when it is sent. A list that runs dry before the close is an error:
+    fewer streams would be open than the cell states."""
+    t0, t0_wall = time.perf_counter(), time.time()
+    cut, lock = _Cut(), threading.Lock()
+    waiting, samples = iter(requests), []
+
+    def client() -> None:
+        while not cut.done:
+            with lock:
+                request = next(waiting, None)
+            if request is None:
+                raise RuntimeError(
+                    f"the list of {len(requests)} requests ran dry "
+                    f"{time.perf_counter() - t0:.1f} s into a run that "
+                    f"closes at {seconds + grace_s:.1f} s")
+            sample = _stream(
+                host, port, path,
+                {**request, "due_s": time.perf_counter() - t0}, t0, timeout,
+                cut)
+            with lock:
+                samples.append(sample)
+
+    with ThreadPoolExecutor(max_workers=concurrency) as pool:
+        clients = [pool.submit(client) for _ in range(concurrency)]
+        wait = t0 + seconds + grace_s - time.perf_counter()
+        if wait > 0:
+            time.sleep(wait)
+        cut.close_all()
+        for done in clients:
+            done.result()
+    return {"t0_wall": t0_wall,
+            "samples": sorted(samples, key=lambda s: s["id"]),
             "closed_s": time.perf_counter() - t0}

@@ -43,6 +43,17 @@ def gap_p99_s(record):
     return stats.percentile(_gaps(record), 99)
 
 
+def gap_slow_share(record):
+    """Share of the gaps over 1.5 times the median gap: the gaps that
+    hold more than a decode step's cadence (a prefill, an admission, a
+    stall). Where it nears 10%, ``gap_p90_s`` sits on their edge."""
+    gaps = _gaps(record)
+    if not gaps:
+        return None
+    limit = 1.5 * stats.percentile(gaps, 50)
+    return 100.0 * sum(g > limit for g in gaps) / len(gaps)
+
+
 def loadgen_late_s_max(record):
     """How late the generator sent its latest request."""
     return max(s["sent_s"] - s["due_s"] for s in _samples(record))

@@ -105,7 +105,6 @@ def main(argv=None) -> int:
         "metrics": read_metrics(cell_metrics(bench, cell["name"], group),
                                 record),
         "device": device,
-        "check": worker["check"],
         "programs_in_window": worker["programs_in_window"],
         # Seconds from this process's start to each phase of set-up.
         "setup": {k: t - process_start
@@ -124,6 +123,14 @@ def main(argv=None) -> int:
             raise SystemExit("--trace 1 produced no trace")
         device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
         result["breakdown"] = trace_reduce.breakdown(trace)
+        # What jax.profiler.stop_trace() took in the worker (a serve
+        # job's; it grows with the steps in the traced window).
+        result["stop_trace_s"] = worker.get("stop_trace_s")
+    # Each number compared beside its limit: last in the line, and the
+    # last line of standard error.
+    result["check"] = worker["check"]
+    print("check: " + json.dumps(worker["check"]), file=sys.stderr,
+          flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -33,6 +33,11 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     cell, config, traffic = bench_run.load_cell(
         bench_run.load_benchmark(), args.workload)
+    if "rate_per_s" not in traffic:
+        raise SystemExit(
+            f"traffic {cell['traffic']!r} offers no rate to sweep: it holds "
+            f"{traffic.get('concurrency')} streams open (a closed loop), and "
+            f"a closed loop has no knee to find")
     try:
         driver.require_chips(cell["chips"])
     except driver.NoChip as e:

@@ -12,6 +12,7 @@ import glob
 import os
 import shutil
 import tempfile
+import time
 from typing import Dict, Mapping, Optional
 
 from . import arch
@@ -124,7 +125,9 @@ def prng_key(seed: int):
 class Tracer:
     """One profiler trace of this process, reduced as soon as it stops.
     The Python tracer is off: it records every function call and slows
-    the host loop it is meant to observe."""
+    the host loop it is meant to observe. ``stop_trace_s`` is what
+    ``jax.profiler.stop_trace()`` itself took: it grows with the events
+    in the window, so with the steps a faster loop puts there."""
 
     def __init__(self):
         self.dir: Optional[str] = None
@@ -142,11 +145,14 @@ class Tracer:
 
         from . import trace_reduce
 
+        started = time.perf_counter()
         jax.profiler.stop_trace()
+        stop_trace_s = time.perf_counter() - started
         try:
             path, = glob.glob(os.path.join(
                 self.dir, "plugins", "profile", "*", "*.xplane.pb"))
-            return trace_reduce.reduce_trace(trace_reduce.load(path))
+            return {**trace_reduce.reduce_trace(trace_reduce.load(path)),
+                    "stop_trace_s": stop_trace_s}
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
             self.dir = None

@@ -116,20 +116,30 @@ def test_unknown_device_kind_is_an_error():
         flops.peaks("TPU v9 imaginary")
 
 
-@pytest.mark.parametrize("name,config", [("chat-open", "mistral-7b-v0.3-L16")])
+@pytest.mark.parametrize("name,config", [
+    ("chat-open", "mistral-7b-v0.3-L16"),
+    ("chat-closed-c16", "olmoe-1b-7b-0125-L8")])
 def test_schedule_is_a_pure_function_of_the_seed(name, config):
     traffic = _traffic(name)
     a = loadgen.schedule(traffic, 2 ** 31 + 5, 40, 32768)
     b = loadgen.schedule(traffic, 2 ** 31 + 5, 40, 32768)
     c = loadgen.schedule(traffic, 7, 40, 32768)
     assert a == b and a != c
-    assert len(a) == round(traffic["rate_per_s"] * 40)
+    assert [r["id"] for r in a] == list(range(len(a)))
     # Every seed offers the same work, in another order.
     for key in (lambda r: len(r["prompt"]), lambda r: r["max_new_tokens"]):
         assert sorted(map(key, a)) == sorted(map(key, c))
     assert sum(len(r["prompt"]) for r in a) == sum(len(r["prompt"]) for r in c)
     due = [r["due_s"] for r in a]
-    assert due == sorted(due) and 0 < due[0] and due[-1] < 40
+    if "concurrency" in traffic:
+        # A closed loop's list is its own length whatever the window's,
+        # and a request is due when a client is free to send it.
+        assert len(a) == traffic["requests"] == len(
+            loadgen.schedule(traffic, 7, 51, 32768))
+        assert due == [None] * len(a)
+    else:
+        assert len(a) == round(traffic["rate_per_s"] * 40)
+        assert due == sorted(due) and 0 < due[0] and due[-1] < 40
     engine = _config(config)["engine"]
     for r in a:
         assert traffic["prompt"]["min"] <= len(r["prompt"]) <= traffic["prompt"]["max"]
@@ -177,6 +187,8 @@ def test_serve_readers_on_hand_made_samples():
     # Gaps pooled: 0.1 x3, 0.2 x2, 1.0.
     assert serve_readers.gap_p50_s(record) == pytest.approx(0.15)
     assert serve_readers.gap_p99_s(record) == pytest.approx(0.96)
+    # Over 1.5 x 0.15: the gap of 1.0 alone, one of six (0.2 is under).
+    assert serve_readers.gap_slow_share(record) == pytest.approx(100 / 6)
     assert serve_readers.loadgen_late_s_max(record) == pytest.approx(0.004)
     assert serve_readers.ingress_s_p50(record) == pytest.approx(0.004)
     # 6 decoded tokens (firsts come from prefill) over 10 steps x 2 slots.

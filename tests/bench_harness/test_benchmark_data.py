@@ -133,7 +133,14 @@ def test_cell_resolves_to_config_traffic_and_job(cell):
     if traffic["kind"] == "train":
         assert config["trainer"] and traffic["batch"] % traffic["check_sequences"] == 0
     else:
-        assert isinstance(traffic["rate_per_s"], (int, float))
+        # An open loop states its rate, a closed loop its streams.
+        assert ("rate_per_s" in traffic) != ("concurrency" in traffic)
+        if "concurrency" in traffic:
+            assert 1 <= traffic["concurrency"] <= traffic["clients"]
+            assert traffic["concurrency"] <= config["engine"]["max_batch"]
+            assert traffic["requests"] >= 4 * traffic["concurrency"]
+        else:
+            assert isinstance(traffic["rate_per_s"], (int, float))
         assert traffic["prompt"]["max"] + traffic["output"]["max"] \
             <= config["engine"]["max_len"]
     reported = {g: {m["name"] for m in bench_run.cell_metrics(BENCH, cell["name"], g)}

@@ -96,7 +96,7 @@ METRICS = [m for m in bench_run.load_benchmark()["per_layer"]
 
 def test_the_nine_metrics_are_the_ones_checked_here():
     assert {m["name"] for m in METRICS} == set(EXPECTED)
-    assert all(m["workloads"] == ["serve-mistral7b-chat"]
+    assert all(m["workloads"] == ["serve-mistral7b-chat", "serve-olmoe-c16"]
                and m["moves"] == "gap_p90_s" for m in METRICS)
 
 
