@@ -428,7 +428,8 @@ def _llm_deployment():
             prefill = eng._prefill.__wrapped_jit__.lower(
                 eng.params, eng.cache, shape((1, bucket), i32),
                 shape((), i32), shape((), i32),
-                shape((bucket // eng.page_size,), i32),
+                {kind: shape((min(bucket // eng.page_size, columns),), i32)
+                 for kind, (_, _, columns) in eng._pools.items()},
             ).compile().as_text()
             return {
                 "pid": os.getpid(),

@@ -1,9 +1,12 @@
 """The serving programs of the Llama family over the paged KV cache.
 
 The engine's cache is the paged one (``PagedKVCache``): a shared pool of
-token pages and a page table a slot. Two jitted programs use it, both
+token pages and a page table a slot, for each attention kind the model
+has. Two jitted programs use it, both
 built on the one transformer block (``llama.block``) with an attention
-of their own, and all their shapes are static:
+of their own, both a layer scan for each run of alike layers
+(``llama.layer_runs``: one run for a uniform model), and all their
+shapes are static:
 
 ``paged_prefill`` — one request's prompt, padded to a bucket. A fresh
 prompt attends to nothing but itself, so this is training's causal
@@ -28,9 +31,10 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.paged_attention import decode_attention
+from ..ops.paged_attention import decode_attention, ring_pages
 from .llama import (
-    LlamaConfig, block, causal_attention, rms_norm, split_expert_stack,
+    LlamaConfig, block, causal_attention, embed_tokens, kv_layers,
+    layer_runs, layer_stacks, rms_norm, split_expert_stack,
 )
 
 
@@ -43,11 +47,13 @@ class MoeLoad(NamedTuple):
 
     @staticmethod
     def of_layers(expert_tokens) -> Optional["MoeLoad"]:
-        """From the layer scan's stacked [L, E] counts; None for None."""
-        if expert_tokens is None:
+        """From the layer scans' stacked [n, E] counts, one entry a run
+        with experts; None for none."""
+        if not expert_tokens:
             return None
-        return MoeLoad(expert_tokens.sum(axis=0),
-                       (expert_tokens > 0).sum().astype(jnp.int32))
+        counts = jnp.concatenate(expert_tokens)
+        return MoeLoad(counts.sum(axis=0),
+                       (counts > 0).sum().astype(jnp.int32))
 
 
 class PagedKVCache(NamedTuple):
@@ -57,8 +63,20 @@ class PagedKVCache(NamedTuple):
     bounded by ``total_pages * page_size`` tokens ACROSS requests instead
     of ``max_batch * max_len`` each, so one long-context request coexists
     with many short ones; pages recycle the moment a request finishes.
-    All shapes static for XLA. The pool is HEAD-MAJOR
-    ([L, Hkv, P_total, page, Dh]): one copy brings a page of every KV
+    All shapes static for XLA.
+
+    One manager, a pool and a page table for each attention KIND the
+    model has (``llama.kv_layers``): ``k``, ``v`` and ``page_table`` are
+    dicts by kind, ``{"full": ...}`` alone for a model without window
+    layers. A "full" layer keeps every token, so its table has a column
+    for every page of the longest sequence and its pool as many pages as
+    the caller gives it. A "window" layer keeps the last
+    ``sliding_window`` tokens: its table's row is a ring of
+    ``ring_pages`` columns (ops/paged_attention.py) and its pool holds a
+    ring for every slot, whatever the context lengths.
+
+    A pool is HEAD-MAJOR
+    ([L_kind, Hkv, P_kind, page, Dh]): one copy brings a page of every KV
     head to the decode attention (ops/paged_attention.py), which reads a
     slot's own pages where they lie and, on a TPU, is also what writes a
     decode step's token: one page a slot a layer, through an output
@@ -69,25 +87,47 @@ class PagedKVCache(NamedTuple):
     scan's xs/ys to a slice and a re-stack a layer: 28 ms of a 39 ms
     step before PR 29, PERF.md §6)."""
 
-    k: jax.Array            # [L, Hkv, P_total, page, Dh] shared pool
-    v: jax.Array            # [L, Hkv, P_total, page, Dh]
-    page_table: jax.Array   # [B, P_max] int32 page ids per slot
-    lengths: jax.Array      # [B] int32 valid tokens per slot
+    k: Dict[str, jax.Array]            # kind -> [L_kind, Hkv, P, page, Dh]
+    v: Dict[str, jax.Array]
+    page_table: Dict[str, jax.Array]   # kind -> [B, columns] int32 page ids
+    lengths: jax.Array                 # [B] int32 valid tokens per slot
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[3]
+        return next(iter(self.k.values())).shape[3]
+
+    @staticmethod
+    def sizes(cfg: LlamaConfig, batch: int, total_pages: int,
+              page_size: int, max_pages_per_seq: int) -> Dict[str, Tuple]:
+        """{kind: (layers, pool pages, table columns)}: what ``create``
+        builds, and what the engine's allocator counts in."""
+        out = {}
+        for kind, layers in kv_layers(cfg).items():
+            if kind == "window":
+                ring = ring_pages(cfg.sliding_window, page_size,
+                                  max_pages_per_seq)
+                out[kind] = (layers, batch * ring, ring)
+            else:
+                out[kind] = (layers, total_pages, max_pages_per_seq)
+        return out
 
     @staticmethod
     def create(cfg: LlamaConfig, batch: int, total_pages: int,
                page_size: int, max_pages_per_seq: int) -> "PagedKVCache":
-        shape = (cfg.num_layers, cfg.num_kv_heads, total_pages,
-                 page_size, cfg.dh)
+        """``total_pages`` and ``max_pages_per_seq`` are the "full"
+        pool's; a window pool's size follows from the window."""
+        sizes = PagedKVCache.sizes(cfg, batch, total_pages, page_size,
+                                   max_pages_per_seq)
+
+        def pools():
+            return {kind: jnp.zeros(
+                (layers, cfg.num_kv_heads, pages, page_size, cfg.dh),
+                dtype=cfg.dtype) for kind, (layers, pages, _) in sizes.items()}
+
         return PagedKVCache(
-            k=jnp.zeros(shape, dtype=cfg.dtype),
-            v=jnp.zeros(shape, dtype=cfg.dtype),
-            page_table=jnp.zeros((batch, max_pages_per_seq),
-                                 dtype=jnp.int32),
+            k=pools(), v=pools(),
+            page_table={kind: jnp.zeros((batch, columns), dtype=jnp.int32)
+                        for kind, (_, _, columns) in sizes.items()},
             lengths=jnp.zeros((batch,), dtype=jnp.int32),
         )
 
@@ -100,46 +140,56 @@ def paged_decode(
     *,
     active: jax.Array,          # [B] bool
 ) -> Tuple[jax.Array, PagedKVCache, Optional[MoeLoad]]:
-    """One decode step over the paged pool: write each active slot's
+    """One decode step over the paged pools: write each active slot's
     token into its current page cell, attend over its pages, return
     [B, V] logits, the updated cache and the step's ``MoeLoad`` (None
-    for a dense model). The layer scan CARRIES the pools whole: as its
-    xs and ys they would be sliced and re-stacked, pool-sized copies
+    for a dense model). One layer scan a run of alike layers
+    (``llama.layer_runs``), each over the pool of its attention kind,
+    which it CARRIES whole: as its
+    xs and ys a pool would be sliced and re-stacked, pool-sized copies
     every step (PagedKVCache). An inactive slot's pages and length stay
     as they are, and it reaches no expert: the experts a step reads
     follow the live sequences."""
-    x = params["embed"][tokens][:, None].astype(cfg.dtype)
-    layers, expert_stack = split_expert_stack(cfg, params["layers"])
+    x = embed_tokens(params, tokens, cfg)[:, None]
+    k_pools, v_pools = dict(cache.k), dict(cache.v)
+    expert_tokens = []
+    for run, stack in zip(layer_runs(cfg), layer_stacks(params)):
+        layers, expert_stack = split_expert_stack(stack)
+        kind, table = run.kind, cache.page_table[run.kind]
 
-    def body(carry, lp):
-        x, k_pool, v_pool = carry
+        def body(carry, lp):
+            x, k_pool, v_pool = carry
 
-        def attend(q, k, v):
-            # The token's K/V row goes to ``decode_attention``, which
-            # writes it at position ``lengths[b]`` of each active slot
-            # and attends. Nothing else in the step reads or writes the
-            # pools (a second reader of what goes into the kernel's
-            # aliased call would make XLA copy them): the block never
-            # sees them.
-            out, k_new, v_new = decode_attention(
-                q[:, 0], k[:, 0], v[:, 0], k_pool, v_pool, lp["index"],
-                cache.page_table, cache.lengths, active)
-            return out[:, None], (k_new, v_new)
+            def attend(q, k, v):
+                # The token's K/V row goes to ``decode_attention``, which
+                # writes it at position ``lengths[b]`` of each active slot
+                # and attends. Nothing else in the step reads or writes
+                # the pools (a second reader of what goes into the
+                # kernel's aliased call would make XLA copy them): the
+                # block never sees them.
+                with jax.named_scope(f"attn.{kind}"):
+                    out, k_new, v_new = decode_attention(
+                        q[:, 0], k[:, 0], v[:, 0], k_pool, v_pool,
+                        lp["index"] + run.kv_offset, table, cache.lengths,
+                        active, window=cfg.window(kind))
+                return out[:, None], (k_new, v_new)
 
-        # The load-balancing loss is a training-only term: dropped.
-        x, (k_pool, v_pool), _aux, expert_tokens = block(
-            cfg, lp, x, cache.lengths[:, None], attend,
-            token_mask=active[:, None], expert_stack=expert_stack)
-        return (x, k_pool, v_pool), expert_tokens
+            # The load-balancing loss is a training-only term: dropped.
+            x, (k_pool, v_pool), _aux, load = block(
+                cfg, lp, x, cache.lengths[:, None], attend,
+                token_mask=active[:, None], expert_stack=expert_stack,
+                kind=kind)
+            return (x, k_pool, v_pool), load
 
-    (x, new_k, new_v), expert_tokens = jax.lax.scan(
-        body, (x, cache.k, cache.v), layers
-    )
+        (x, k_pools[kind], v_pools[kind]), load = jax.lax.scan(
+            body, (x, k_pools[kind], v_pools[kind]), layers)
+        if load is not None:
+            expert_tokens.append(load)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bm,mv->bv", x[:, 0], params["lm_head"])
     lengths = jnp.where(active, cache.lengths + 1, cache.lengths)
     return logits.astype(jnp.float32), PagedKVCache(
-        new_k, new_v, cache.page_table, lengths
+        k_pools, v_pools, cache.page_table, lengths
     ), MoeLoad.of_layers(expert_tokens)
 
 
@@ -150,48 +200,77 @@ def paged_prefill(
     cache: PagedKVCache,
     cfg: LlamaConfig,
     slot: int | jax.Array,
-    pages: jax.Array,           # [S_bucket // page] page ids for this slot
+    pages: Dict[str, jax.Array],  # kind -> page ids for this slot
 ) -> Tuple[jax.Array, PagedKVCache, Optional[MoeLoad]]:
     """Prefill one request: causal self-attention over the padded prompt,
     logits [1, V] at its last real token, each layer's k and v laid
-    into the slot's pool pages, the slot's length set to ``real_len``.
+    into the slot's pages of the layer's pool, the slot's length set to
+    ``real_len``.
     Rows behind ``real_len`` are the bucket's padding: causal masking
     keeps them from the real rows, they reach no expert of a MoE model,
     and what they leave in the pages lies behind the slot's length,
     where decode writes before it reads. The bucket length must be a
     multiple of the page size (buckets are powers of two >= page).
+
+    ``pages``: for "full" the ``S_bucket // page`` pages that take the
+    bucket; for "window" the first ``min(S_bucket // page, columns)``
+    columns of the slot's ring, into which go only the pages a later
+    token can still attend to, the last of them the one that holds
+    ``real_len - 1``.
     Returns the run's ``MoeLoad`` too (None for a dense model)."""
     S = tokens.shape[1]
     page = cache.page_size
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = embed_tokens(params, tokens, cfg)
     positions = jnp.arange(S)
     token_mask = positions[None] < real_len if cfg.n_experts > 0 else None
-    layers, expert_stack = split_expert_stack(cfg, params["layers"])
+    k_pools, v_pools = dict(cache.k), dict(cache.v)
+    expert_tokens = []
 
-    def attend(q, k, v):
-        return causal_attention(cfg, None, q, k, v), (k, v)
+    def to_pages(rows, pool, run):
+        """[n, 1, S, Hkv, Dh] -> [n, Hkv, S // page, page, Dh], the
+        pool's layout, set at the run's layers and the slot's page ids."""
+        paged = rows[:, 0].reshape(
+            run.n, S // page, page, cfg.num_kv_heads, cfg.dh
+        ).transpose(0, 3, 1, 2, 4)
+        ids = pages[run.kind]
+        whole = run.n == pool.shape[0]
+        at = slice(None) if whole else slice(run.kv_offset,
+                                             run.kv_offset + run.n)
+        if run.kind == "window":
+            # The newest ``len(ids)`` pages up to the last real token's
+            # (all of a bucket that fits the ring), each to its column.
+            first = jnp.clip((real_len - 1) // page + 1 - len(ids), 0,
+                             S // page - len(ids))
+            paged = jax.lax.dynamic_slice_in_dim(paged, first, len(ids), 2)
+            ids = ids[(first + jnp.arange(len(ids))) % len(ids)]
+        return pool.at[at, :, ids].set(paged.astype(pool.dtype))
 
-    def body(x, lp):
-        x, kv, _aux, expert_tokens = block(
-            cfg, lp, x, positions, attend, token_mask=token_mask,
-            expert_stack=expert_stack)
-        return x, (kv, expert_tokens)
+    for run, stack in zip(layer_runs(cfg), layer_stacks(params)):
+        layers, expert_stack = split_expert_stack(stack)
+        kind = run.kind
 
-    x, ((k, v), expert_tokens) = jax.lax.scan(body, x, layers)
+        def attend(q, k, v):
+            with jax.named_scope(f"attn.{kind}"):
+                out = causal_attention(cfg, None, q, k, v,
+                                       window=cfg.window(kind))
+            return out, (k, v)
+
+        def body(x, lp):
+            x, kv, _aux, load = block(
+                cfg, lp, x, positions, attend, token_mask=token_mask,
+                expert_stack=expert_stack, kind=kind)
+            return x, (kv, load)
+
+        x, ((k, v), load) = jax.lax.scan(body, x, layers)
+        k_pools[kind] = to_pages(k, k_pools[kind], run)
+        v_pools[kind] = to_pages(v, v_pools[kind], run)
+        if load is not None:
+            expert_tokens.append(load)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bm,mv->bv", x[:, real_len - 1], params["lm_head"])
-
-    def to_pages(rows, pool):
-        """[L, 1, S, Hkv, Dh] -> [L, Hkv, S // page, page, Dh], the
-        pool's layout, set at the slot's page ids."""
-        paged = rows[:, 0].reshape(
-            cfg.num_layers, S // page, page, cfg.num_kv_heads, cfg.dh
-        ).transpose(0, 3, 1, 2, 4)
-        return pool.at[:, :, pages].set(paged.astype(pool.dtype))
-
     lengths = cache.lengths.at[slot].set(real_len)
     return logits.astype(jnp.float32), PagedKVCache(
-        to_pages(k, cache.k), to_pages(v, cache.v), cache.page_table, lengths
+        k_pools, v_pools, cache.page_table, lengths
     ), MoeLoad.of_layers(expert_tokens)
 
 

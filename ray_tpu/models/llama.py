@@ -10,7 +10,10 @@ Design choices for TPU/XLA:
   shard_map island inside the jitted program.
 - Layers are *stacked* ([L, ...] leaves) and applied with lax.scan: one
   layer gets compiled once regardless of depth (compile-time O(1) in L),
-  and the "layers" leading axis is what pipeline parallelism shards.
+  and the "layers" leading axis is what pipeline parallelism shards. A
+  model whose layers are not all alike is an ordered list of such stacks,
+  one a run of alike layers (``layer_runs``), each scanned by the one
+  block; what kind a layer is, is known when a program is traced.
 - bfloat16 activations/weights with float32 RMSNorm/softmax/rope, the
   standard TPU mixed-precision recipe (MXU eats bf16; norms need f32).
 - jax.checkpoint around each layer body for rematerialization.
@@ -23,7 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +63,38 @@ class LlamaConfig:
     # RMSNorm over the whole q and the whole k projection, before the
     # split into heads and before rotary (OLMoE's q_norm / k_norm).
     qk_norm: bool = False
+    # What an architecture states beyond Llama's block, each off by
+    # default; with all of them off the programs are what they were.
+    # A stack that is not uniform: the first ``num_dense_layers`` layers
+    # of a model with experts have a dense FFN of ``intermediate_size``,
+    # the others experts of ``moe_intermediate_size`` (None: an expert is
+    # ``intermediate_size`` wide) beside ``n_shared_experts`` dense ones
+    # of the same width, always on.
+    num_dense_layers: int = 0
+    moe_intermediate_size: Optional[int] = None
+    n_shared_experts: int = 0
+    # The router (parallel/moe.route): "softmax" | "sigmoid" scores, a
+    # learned selection bias that is not in the gate, the chosen gates
+    # renormalised, and a scale.
+    router_score: str = "softmax"
+    router_bias: bool = False
+    route_norm: bool = False
+    route_scale: float = 1.0
+    # A layer's attention kind, "window" or "full" (None: all full). A
+    # window layer's token t attends to t - sliding_window < j <= t.
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: Optional[int] = None
+    # Rotary on every layer, or on window layers alone.
+    rope_full_layers: bool = True
+    # ``qk_norm`` over each head's ``dh`` instead of the whole projection.
+    qk_norm_per_head: bool = False
+    # The attention output times sigmoid(h wg), before ``wo``.
+    attn_gate: bool = False
+    # A norm behind the attention and one behind the FFN, on what each
+    # adds to the residual stream (four norms a layer).
+    post_norms: bool = False
+    # The embedding rows times this (muP: sqrt(hidden_size)).
+    embed_scale: float = 1.0
     remat: bool = True
     # "full" (save only layer inputs), "dots" (save matmul outputs,
     # recompute elementwise), or "save_all" (save every intermediate —
@@ -97,6 +132,14 @@ class LlamaConfig:
     def dh(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    @property
+    def expert_size(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    def window(self, kind: str) -> Optional[int]:
+        """The attention window of a layer of ``kind``; None for none."""
+        return self.sliding_window if kind == "window" else None
+
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
         return LlamaConfig()
@@ -117,9 +160,75 @@ class LlamaConfig:
         )
 
 
+class LayerRun(NamedTuple):
+    """Consecutive layers that are alike, so that one body scans their
+    stacked weights: the same FFN (dense or experts) and the same
+    attention kind. ``kv_offset`` is where the run's layers begin in
+    the KV pool of their kind (generation.PagedKVCache)."""
+
+    start: int
+    n: int
+    moe: bool
+    kind: str       # "full" | "window"
+    kv_offset: int
+
+
+def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
+    """The model's layers as an ordered list of uniform runs, from what
+    the config states; everything about a layer that a program needs to
+    know when it is traced. Llama, Mistral, OLMoE: one run, "full"."""
+    kinds = cfg.layer_types or ("full",) * cfg.num_layers
+    if len(kinds) != cfg.num_layers or set(kinds) - {"full", "window"}:
+        raise ValueError(
+            f"layer_types {kinds} must name 'full' or 'window' for each "
+            f"of {cfg.num_layers} layers")
+    if "window" in kinds and not cfg.sliding_window:
+        raise ValueError("window layers need a sliding_window")
+    alike = [(cfg.n_experts > 0 and i >= cfg.num_dense_layers, kind)
+             for i, kind in enumerate(kinds)]
+    runs, seen = [], {"full": 0, "window": 0}
+    for i, (moe, kind) in enumerate(alike):
+        if runs and alike[i - 1] == (moe, kind):
+            runs[-1] = runs[-1]._replace(n=runs[-1].n + 1)
+        else:
+            runs.append(LayerRun(i, 1, moe, kind, seen[kind]))
+        seen[kind] += 1
+    return tuple(runs)
+
+
+def kv_layers(cfg: LlamaConfig) -> Dict[str, int]:
+    """Layers of each attention kind present, {kind: count}: the KV
+    pools a model needs, in the order of first use."""
+    out: Dict[str, int] = {}
+    for run in layer_runs(cfg):
+        out[run.kind] = out.get(run.kind, 0) + run.n
+    return out
+
+
+def layer_stacks(params) -> Tuple[Dict[str, Any], ...]:
+    """``params["layers"]`` as one stacked tree a run: a uniform model
+    keeps the single ``[L, ...]`` tree it always had, another holds a
+    tuple of them in ``layer_runs``' order."""
+    layers = params["layers"]
+    return tuple(layers) if isinstance(layers, (tuple, list)) else (layers,)
+
+
+def require_uniform(cfg: LlamaConfig, what: str) -> None:
+    """Training scans ONE stack with ONE causal attention, and the flash
+    backward kernels take no window: a stack in runs or a window layer
+    trains nowhere yet (ROADMAP R3), and says so by name."""
+    if len(layer_runs(cfg)) > 1 or "window" in kv_layers(cfg):
+        raise NotImplementedError(
+            f"{what}: training a model whose layer stack is not uniform "
+            f"(dense layers before expert layers, window beside full "
+            f"attention) is not implemented; it is served only "
+            f"(models/generation.py)")
+
+
 # Logical axes for each parameter leaf (maps through DEFAULT_RULES:
 # embed→fsdp, heads/mlp/vocab→tp, expert→ep, layers→pp-or-scan).
 def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
+    require_uniform(cfg, "param_logical_axes")
     layer = {
         "attn_norm": ("layers", "norm"),
         "wq": ("layers", "embed", "heads", "head_dim"),
@@ -130,6 +239,11 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     }
     if cfg.qk_norm:
         layer.update(q_norm=("layers", "norm"), k_norm=("layers", "norm"))
+    if cfg.attn_gate:
+        layer["wg"] = ("layers", "embed", "heads", "head_dim")
+    if cfg.post_norms:
+        layer.update(post_attn_norm=("layers", "norm"),
+                     post_mlp_norm=("layers", "norm"))
     if cfg.n_experts > 0:
         layer.update(
             router=("layers", "embed", None),
@@ -137,6 +251,12 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             w_up=("layers", "expert", "embed", "mlp"),
             w_down=("layers", "expert", "mlp", "embed"),
         )
+        if cfg.n_shared_experts:
+            layer.update(ws_gate=("layers", "embed", "mlp"),
+                         ws_up=("layers", "embed", "mlp"),
+                         ws_down=("layers", "mlp", "embed"))
+        if cfg.router_bias:
+            layer["expert_bias"] = ("layers", None)
     else:
         layer.update(
             w_gate=("layers", "embed", "mlp"),
@@ -151,11 +271,13 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     }
 
 
-def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
-    k = iter(jax.random.split(key, 16))
-    M, F, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
-    H, Hkv, Dh, V = cfg.num_heads, cfg.num_kv_heads, cfg.dh, cfg.vocab_size
-    dt = cfg.dtype
+def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool) -> Dict[str, Any]:
+    """``n`` alike layers' weights, stacked ``[n, ...]``, drawing keys
+    from the iterator ``k`` in an order that never changes for a leaf
+    that is there (new leaves draw last): a seed's weights stay what
+    they were."""
+    M, H, Hkv, Dh, dt = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+                         cfg.dh, cfg.dtype)
 
     def norm_init(shape):
         return jnp.ones(shape, dtype=jnp.float32)
@@ -165,35 +287,72 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
                 * (fan_in ** -0.5)).astype(dt)
 
     layers: Dict[str, Any] = {
-        "attn_norm": norm_init((L, M)),
-        "wq": winit(next(k), (L, M, H, Dh), M),
-        "wk": winit(next(k), (L, M, Hkv, Dh), M),
-        "wv": winit(next(k), (L, M, Hkv, Dh), M),
-        "wo": winit(next(k), (L, H, Dh, M), H * Dh),
-        "mlp_norm": norm_init((L, M)),
+        "attn_norm": norm_init((n, M)),
+        "wq": winit(next(k), (n, M, H, Dh), M),
+        "wk": winit(next(k), (n, M, Hkv, Dh), M),
+        "wv": winit(next(k), (n, M, Hkv, Dh), M),
+        "wo": winit(next(k), (n, H, Dh, M), H * Dh),
+        "mlp_norm": norm_init((n, M)),
     }
     if cfg.qk_norm:
-        layers.update(q_norm=norm_init((L, H * Dh)),
-                      k_norm=norm_init((L, Hkv * Dh)))
-    if cfg.n_experts > 0:
-        E = cfg.n_experts
+        per_head = cfg.qk_norm_per_head
+        layers.update(q_norm=norm_init((n, Dh if per_head else H * Dh)),
+                      k_norm=norm_init((n, Dh if per_head else Hkv * Dh)))
+    if moe:
+        E, F = cfg.n_experts, cfg.expert_size
         layers.update(
-            router=winit(next(k), (L, M, E), M).astype(jnp.float32),
-            w_gate=winit(next(k), (L, E, M, F), M),
-            w_up=winit(next(k), (L, E, M, F), M),
-            w_down=winit(next(k), (L, E, F, M), F),
+            router=winit(next(k), (n, M, E), M).astype(jnp.float32),
+            w_gate=winit(next(k), (n, E, M, F), M),
+            w_up=winit(next(k), (n, E, M, F), M),
+            w_down=winit(next(k), (n, E, F, M), F),
         )
+        if cfg.n_shared_experts:
+            Fs = F * cfg.n_shared_experts
+            layers.update(ws_gate=winit(next(k), (n, M, Fs), M),
+                          ws_up=winit(next(k), (n, M, Fs), M),
+                          ws_down=winit(next(k), (n, Fs, M), Fs))
+        if cfg.router_bias:
+            # Nonzero, so that "selects, but is not in the gate" shows.
+            layers["expert_bias"] = 0.05 * jax.random.normal(
+                next(k), (n, E), dtype=jnp.float32)
     else:
+        F = cfg.intermediate_size
         layers.update(
-            w_gate=winit(next(k), (L, M, F), M),
-            w_up=winit(next(k), (L, M, F), M),
-            w_down=winit(next(k), (L, F, M), F),
+            w_gate=winit(next(k), (n, M, F), M),
+            w_up=winit(next(k), (n, M, F), M),
+            w_down=winit(next(k), (n, F, M), F),
         )
+    if cfg.attn_gate:
+        layers["wg"] = winit(next(k), (n, M, H, Dh), M)
+    if cfg.post_norms:
+        layers.update(post_attn_norm=norm_init((n, M)),
+                      post_mlp_norm=norm_init((n, M)))
+    return layers
+
+
+def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
+    """``layers`` is one stacked tree for a uniform model and a tuple of
+    them, one a run (``layer_runs``), for any other."""
+    M, V = cfg.hidden_size, cfg.vocab_size
+    runs = layer_runs(cfg)
+    k = iter(jax.random.split(key, 16))
+    if len(runs) == 1:
+        layers = _init_stack(cfg, k, cfg.num_layers, runs[0].moe)
+    else:
+        layers = tuple(
+            _init_stack(cfg, iter(jax.random.split(
+                jax.random.fold_in(key, run.start), 16)), run.n, run.moe)
+            for run in runs)
+
+    def winit(key, shape):
+        return (jax.random.normal(key, shape, dtype=jnp.float32)
+                * (M ** -0.5)).astype(cfg.dtype)
+
     return {
-        "embed": winit(next(k), (V, M), M),
+        "embed": winit(next(k), (V, M)),
         "layers": layers,
-        "final_norm": norm_init((M,)),
-        "lm_head": winit(next(k), (M, V), M),
+        "final_norm": jnp.ones((M,), dtype=jnp.float32),
+        "lm_head": winit(next(k), (M, V)),
     }
 
 
@@ -217,18 +376,23 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def causal_attention(cfg: LlamaConfig, mesh, q, k, v):
+def causal_attention(cfg: LlamaConfig, mesh, q, k, v, window=None):
     """Causal self-attention of q [B,S,H,Dh] over k, v [B,S,Hkv,Dh] of
-    the same S tokens: training's, and a prefill's. The one place that
+    the same S tokens: training's, and a prefill's; with ``window``, of
+    each token over the last ``window`` tokens, itself among them (a
+    prefill's only). The one place that
     chooses among ring attention (an ``sp`` axis), the flash kernel and
     the XLA einsum; for which platform and shapes the kernel itself runs
     is ops/flash_attention.py's to say."""
     if mesh is not None and mesh_axis_size(mesh, "sp") > 1:
+        if window is not None:
+            raise NotImplementedError("ring attention takes no window")
         return ring_attention(q, k, v, mesh, causal=True)
     if cfg.use_flash:
         from ..ops.flash_attention import flash_attention
 
-        attend = functools.partial(flash_attention, causal=True)
+        attend = functools.partial(flash_attention, causal=True,
+                                   window=window)
         if mesh is not None:
             # GSPMD cannot partition a Mosaic kernel ("wrap the call in
             # a shard_map"): hand each device its (batch, heads) shard.
@@ -240,61 +404,57 @@ def causal_attention(cfg: LlamaConfig, mesh, q, k, v):
                 out_specs=spec, check_vma=False,
             )
         return attend(q, k, v)
-    return mha_attention(q, k, v, causal=True)
+    return mha_attention(q, k, v, causal=True, window=window)
 
 
 def qkv_proj(cfg: LlamaConfig, lp, x):
     """The block's first half up to rotary: attention norm, then q
-    [B,S,H,Dh] and k, v [B,S,Hkv,Dh], q and k normed over their whole
-    projection where the model has a QK-norm."""
+    [B,S,H,Dh] and k, v [B,S,Hkv,Dh], q and k normed where the model has
+    a QK-norm (over their whole projection, or over each head's ``dh``),
+    and the output gate sigmoid(h wg) [B,S,H,Dh] where it has one (else
+    None)."""
     B, S, _ = x.shape
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
     k = jnp.einsum("bsm,mhd->bshd", h, lp["wk"])
     v = jnp.einsum("bsm,mhd->bshd", h, lp["wv"])
-    if cfg.qk_norm:
+    if cfg.qk_norm and cfg.qk_norm_per_head:
+        q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
+    elif cfg.qk_norm:
         q = rms_norm(q.reshape(B, S, -1), lp["q_norm"],
                      cfg.rms_eps).reshape(q.shape)
         k = rms_norm(k.reshape(B, S, -1), lp["k_norm"],
                      cfg.rms_eps).reshape(k.shape)
-    return q, k, v
+    gate = None
+    if cfg.attn_gate:
+        g = jnp.einsum("bsm,mhd->bshd", h, lp["wg"])
+        gate = jax.nn.sigmoid(g.astype(jnp.float32)).astype(g.dtype)
+    return q, k, v, gate
 
 
 EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
-def split_expert_stack(cfg: LlamaConfig, layers):
-    """(what a layer scan slices, what it must read in place): the
-    scanned layers carry their ``index``, and a MoE model's expert
-    weights stay whole beside the scan, read at that index (``ffn``'s
-    ``expert_stack``; why: parallel/moe.py); None for a dense model.
-    ``paged_decode`` reads the KV pool at the same index."""
-    experts = EXPERT_WEIGHTS if cfg.n_experts > 0 else ()
+def split_expert_stack(layers):
+    """(what a layer scan slices, what it must read in place) of one
+    run's stacked layers: the scanned layers carry their ``index`` in
+    the stack, and the expert weights of layers that have experts stay
+    whole beside the scan, read at that index (``ffn``'s
+    ``expert_stack``; why: parallel/moe.py); None for dense layers.
+    ``paged_decode`` reads the KV pool at ``LayerRun.kv_offset`` plus
+    the same index."""
+    experts = EXPERT_WEIGHTS if "router" in layers else ()
     scanned = {k: v for k, v in layers.items() if k not in experts}
-    scanned["index"] = jnp.arange(cfg.num_layers)
+    scanned["index"] = jnp.arange(layers["attn_norm"].shape[0])
     return scanned, ({k: layers[k] for k in experts} or None)
 
 
-def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
-        expert_stack=None):
-    """The block's second half: MLP norm, then the
-    SiLU-gated MLP or the mixture of experts, added to x [B,S,M].
-    Returns (x, load-balancing loss, tokens assigned to each expert [E]
-    or None for a dense model). ``token_mask`` [B,S] keeps rows (inactive
-    decode slots, bucket padding) away from every expert. The experts'
-    weights are ``lp``'s own, or with ``expert_stack`` (the second half
-    of ``split_expert_stack``) all layers', read at ``lp["index"]``."""
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    if cfg.n_experts > 0:
-        w = lp if expert_stack is None else expert_stack
-        out, aux, expert_tokens = moe_ffn(
-            h, lp["router"], w["w_up"], w["w_down"], k=cfg.top_k,
-            w_gate=w["w_gate"], token_mask=token_mask,
-            layer=None if expert_stack is None else lp["index"],
-        )
-        return x + out, aux, expert_tokens
-    up = jnp.einsum("bsm,mf->bsf", h, lp["w_up"])
-    gate = jnp.einsum("bsm,mf->bsf", h, lp["w_gate"])
+def swiglu(h, w_gate, w_up, w_down, *, mesh=None):
+    """The SiLU-gated MLP of h [B,S,M]: a dense layer's, a shared
+    expert's."""
+    up = jnp.einsum("bsm,mf->bsf", h, w_up)
+    gate = jnp.einsum("bsm,mf->bsf", h, w_gate)
     # Named for the selective "mlp" remat policy: saving these two
     # outputs (the widest matmuls — ~45% of a layer's forward FLOPs)
     # removes their backward recompute at a fraction of checkpoint_dots'
@@ -303,12 +463,44 @@ def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
     gate = checkpoint_name(gate, "mlp_gate")
     h = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
     h = with_logical_constraint(h, ("batch", "seq", "mlp"), mesh=mesh)
-    x = x + jnp.einsum("bsf,fm->bsm", h, lp["w_down"])
-    return x, jnp.zeros((), dtype=jnp.float32), None
+    return jnp.einsum("bsf,fm->bsm", h, w_down)
+
+
+def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
+        expert_stack=None):
+    """The block's second half: MLP norm, then the
+    SiLU-gated MLP or, for a layer with a ``router``, the mixture of
+    experts (and the shared expert beside it), normed again where the
+    model has post-norms, added to x [B,S,M].
+    Returns (x, load-balancing loss, tokens assigned to each expert [E]
+    or None for a dense layer). ``token_mask`` [B,S] keeps rows (inactive
+    decode slots, bucket padding) away from every expert. The experts'
+    weights are ``lp``'s own, or with ``expert_stack`` (the second half
+    of ``split_expert_stack``) the whole run's, read at ``lp["index"]``."""
+    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+    if "router" in lp:
+        w = lp if expert_stack is None else expert_stack
+        out, aux, expert_tokens = moe_ffn(
+            h, lp["router"], w["w_up"], w["w_down"], k=cfg.top_k,
+            w_gate=w["w_gate"], token_mask=token_mask,
+            layer=None if expert_stack is None else lp["index"],
+            score=cfg.router_score, select_bias=lp.get("expert_bias"),
+            renormalize=cfg.route_norm, scale=cfg.route_scale,
+        )
+        if "ws_up" in lp:
+            with jax.named_scope("moe.shared"):
+                out = out + swiglu(h, lp["ws_gate"], lp["ws_up"],
+                                   lp["ws_down"], mesh=mesh)
+    else:
+        out = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], mesh=mesh)
+        aux, expert_tokens = jnp.zeros((), dtype=jnp.float32), None
+    if cfg.post_norms:
+        out = rms_norm(out, lp["post_mlp_norm"], cfg.rms_eps)
+    return x + out, aux, expert_tokens
 
 
 def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
-          token_mask=None, expert_stack=None):
+          token_mask=None, expert_stack=None, kind: str = "full"):
     """One transformer block over x [B,S,M]: the only place where
     projections, rotary, attention, ``wo`` and the FFN are put in order.
     Training, prefill and decode differ in what they pass:
@@ -320,20 +512,37 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
     v, a decode step writes them into the KV pool it carries and attends
     over the pool. The block hands the state on unread, so another
     attention (a window, a latent cache) is another ``attend``.
-    ``mesh``, ``token_mask``, ``expert_stack``: ``ffn``'s.
+    ``mesh``, ``token_mask``, ``expert_stack``: ``ffn``'s. ``kind``,
+    the layer's attention kind, is the caller's ``attend`` to honour;
+    here it decides only whether q and k are rotated.
 
     Returns (x, attend's state, load-balancing loss, tokens assigned to
     each expert or None)."""
-    q, k, v = qkv_proj(cfg, lp, x)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q, k, v, gate = qkv_proj(cfg, lp, x)
+    if cfg.rope_full_layers or kind != "full":
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"),
                                 mesh=mesh)
     attn, state = attend(q, k, v)
-    x = x + jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
+    if gate is not None:
+        attn = attn * gate
+    attn = jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
+    if cfg.post_norms:
+        attn = rms_norm(attn, lp["post_attn_norm"], cfg.rms_eps)
+    x = x + attn
     x, aux, expert_tokens = ffn(cfg, lp, x, mesh=mesh, token_mask=token_mask,
                                 expert_stack=expert_stack)
     return x, state, aux, expert_tokens
+
+
+def embed_tokens(params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    """The residual stream's first value: the tokens' embedding rows in
+    the model's dtype, scaled where the model scales them."""
+    x = params["embed"][tokens].astype(cfg.dtype)
+    if cfg.embed_scale != 1.0:
+        x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
+    return x
 
 
 def forward(
@@ -387,8 +596,9 @@ def hidden_forward(
 ) -> Tuple[jax.Array, jax.Array]:
     """Transformer trunk WITHOUT the lm_head projection: returns
     (hidden [B, S, M] after final_norm, moe_aux_loss scalar)."""
+    require_uniform(cfg, "hidden_forward")
     B, S = tokens.shape
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = embed_tokens(params, tokens, cfg)
     x = with_logical_constraint(x, ("batch", "seq", "embed"), mesh=mesh)
     positions = jnp.arange(S)
     policy = remat_policy(cfg)

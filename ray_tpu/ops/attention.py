@@ -27,11 +27,16 @@ def mha_attention(
     q_offset: int = 0,
     kv_offset: int = 0,
     bias: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Multi-head attention with optional GQA (Hkv divides H) and causal
     masking in *global* coordinates: query position i is q_offset + i,
     key position j is kv_offset + j — offsets make the same kernel correct
-    for sharded sequence blocks (ring attention) and decode steps."""
+    for sharded sequence blocks (ring attention) and decode steps. With
+    ``window`` (causal only) a query attends to the last ``window``
+    positions, its own among them: q_pos - window < k_pos <= q_pos."""
+    if window is not None and not causal:
+        raise ValueError("a window is a causal attention's lower bound")
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     scale = scale if scale is not None else D ** -0.5
@@ -49,6 +54,8 @@ def mha_attention(
         q_pos = q_offset + jnp.arange(Sq)[:, None]
         k_pos = kv_offset + jnp.arange(k.shape[1])[None, :]
         mask = k_pos <= q_pos  # [Sq, Skv]
+        if window is not None:
+            mask = mask & (k_pos > q_pos - window)
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     out = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", out, v)

@@ -39,7 +39,7 @@ _NEG_INF = -1e30
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
                       causal: bool, q_offset: int, kv_offset: int,
-                      block_k: int):
+                      block_k: int, window: Optional[int] = None):
     from jax.experimental import pallas as pl
 
     block_q = q_ref.shape[1]
@@ -59,6 +59,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
         hi = jnp.clip((last_q - kv_offset) // block_k + 1, 0, nk)
     else:
         hi = nk
+    # With a window, KV blocks whose last position lies before the first
+    # query's window are fully masked too: the loop gets a lower bound.
+    lo = 0 if window is None else jnp.clip(
+        (q_start - window + 1 - kv_offset) // block_k, 0, nk)
 
     def body(j, carry):
         m, l, acc = carry
@@ -72,7 +76,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
             k_pos = kv_offset + j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1
             )
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+            attends = k_pos <= q_pos
+            if window is not None:
+                attends = attends & (k_pos > q_pos - window)
+            s = jnp.where(attends, s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=1))
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m - m_new)
@@ -83,7 +90,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
     m0 = jnp.full((block_q,), _NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((block_q,), dtype=jnp.float32)
     acc0 = jnp.zeros((block_q, head_dim), dtype=jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, acc0))
     # Guard the all-masked case (possible when kv_offset > q positions).
     l_safe = jnp.where(l == 0.0, 1.0, l)
     out = acc / l_safe[:, None]
@@ -94,7 +101,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
 
 def _flash_fwd(q3, k3, v3, *, heads: int, kv_heads: int, scale: float,
                causal: bool, q_offset: int, kv_offset: int,
-               block_q: int, block_k: int, interpret: bool = False):
+               block_q: int, block_k: int, interpret: bool = False,
+               window: Optional[int] = None):
     """q3: [B*H, Sq, D]; k3/v3: [B*Hkv, Skv, D] → [B*H, Sq, D]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -113,6 +121,7 @@ def _flash_fwd(q3, k3, v3, *, heads: int, kv_heads: int, scale: float,
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal,
         q_offset=q_offset, kv_offset=kv_offset, block_k=block_k,
+        window=window,
     )
     return pl.pallas_call(
         kernel,
@@ -378,11 +387,12 @@ def _flash_bwd(q3, k3, v3, do3, lse, delta, *, heads: int, kv_heads: int,
     return dq3, dk3, dv3
 
 
-def _reference(q, k, v, *, causal, scale, q_offset, kv_offset):
+def _reference(q, k, v, *, causal, scale, q_offset, kv_offset, window=None):
     from .attention import mha_attention
 
     return mha_attention(q, k, v, causal=causal, scale=scale,
-                         q_offset=q_offset, kv_offset=kv_offset)
+                         q_offset=q_offset, kv_offset=kv_offset,
+                         window=window)
 
 
 def _to_heads3(x):
@@ -392,10 +402,10 @@ def _to_heads3(x):
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10)
 )
 def _flash_attention_core(q, k, v, causal, scale, q_offset, kv_offset,
-                          block_q, block_k, interpret=False):
+                          block_q, block_k, interpret=False, window=None):
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     o3, _lse = _flash_fwd(
@@ -403,12 +413,19 @@ def _flash_attention_core(q, k, v, causal, scale, q_offset, kv_offset,
         heads=H, kv_heads=Hkv, scale=scale, causal=causal,
         q_offset=q_offset, kv_offset=kv_offset,
         block_q=block_q, block_k=block_k, interpret=interpret,
+        window=window,
     )
     return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
 
 
 def _core_fwd(q, k, v, causal, scale, q_offset, kv_offset, block_q,
-              block_k, interpret=False):
+              block_k, interpret=False, window=None):
+    if window is not None:
+        # The two backward kernels mask causally and no further: a
+        # window's gradient through them would be wrong without a word.
+        raise NotImplementedError(
+            "flash_attention(window=...) has no backward kernel: the "
+            "forward kernel alone takes a window (serving's prefill)")
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     q3, k3, v3 = _to_heads3(q), _to_heads3(k), _to_heads3(v)
@@ -422,10 +439,10 @@ def _core_fwd(q, k, v, causal, scale, q_offset, kv_offset, block_q,
 
 
 def _core_bwd(causal, scale, q_offset, kv_offset, block_q, block_k,
-              interpret, res, g):
+              interpret, window, res, g):
     """Fused flash backward: P recomputed block-wise in VMEM from the
     saved logsumexp; dK/dV reduced over each GQA group inside the kernel
-    (KV-head grid)."""
+    (KV-head grid). ``_core_fwd`` has refused a window."""
     q3, k3, v3, o3, lse, B, H, Hkv = res
     Sq, D = q3.shape[1], q3.shape[2]
     do3 = _to_heads3(g)
@@ -459,9 +476,12 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention with GQA and global-coordinate causal masking
-    (same signature as ops.attention.mha_attention). The one place that
+    (same signature as ops.attention.mha_attention), of the last
+    ``window`` positions where one is given (causal only; forward only:
+    under ``jax.grad`` it raises). The one place that
     says when the Pallas kernel runs: on a TPU, for sequences that are
     whole blocks (128 rows unless the caller names another size: a
     serving bucket of 16, 32 or 64 tokens is no block, and no shorter
@@ -479,10 +499,13 @@ def flash_attention(
     )
     if not tileable or (not _on_tpu() and not interpret):
         return _reference(q, k, v, causal=causal, scale=scale,
-                          q_offset=q_offset, kv_offset=kv_offset)
+                          q_offset=q_offset, kv_offset=kv_offset,
+                          window=window)
+    if window is not None and not causal:
+        raise ValueError("a window is a causal attention's lower bound")
     return _flash_attention_core(
         q, k, v, causal, scale, q_offset, kv_offset, block_q, block_k,
-        interpret,
+        interpret, window,
     )
 
 

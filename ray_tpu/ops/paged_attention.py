@@ -10,6 +10,15 @@ at layer ``layer`` it puts each active slot's new K/V row at position
 ``lengths[b] % page``), attends over positions ``0 .. lengths[b]`` and
 hands both pools on.
 
+A layer with an attention ``window`` keeps no more than the window: a
+slot's row of that pool's ``page_table`` is a RING of
+:func:`ring_pages` columns, page ``p`` of the sequence lying in column
+``p % columns``, so the page a new row opens is the one whose rows all
+left the window. Such a step attends over positions ``lengths[b] + 1 -
+window .. lengths[b]``: it starts at the page that holds the first of
+them, and masks a row by its position in the sequence. The host never
+touches the ring while a slot decodes.
+
 Two implementations, one chosen by :func:`decode_attention_path` from
 what the code can see (platform and shape), never by a user:
 
@@ -47,6 +56,7 @@ work is in proportion to ``B * Pmax * page`` whatever ``lengths`` says.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,11 +68,11 @@ _NEG_INF = -1e30
 _BLOCK_TOKENS = 128
 
 
-def _page_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, kn_ref,
-                      vn_ref, k_hbm, v_hbm, o_ref, k_out, v_out,
-                      k_buf, v_buf, sems, *, pmax: int, scale: float):
+def _page_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, *refs, pmax: int,
+                      scale: float, window=None):
     """Grid (B,). pt_ref [B * Pmax], np_ref [B] (pages to walk), len_ref
-    [B], layer_ref [1] in SMEM; q_ref/o_ref [H, D] this slot's rows;
+    [B], layer_ref [1] and, with a ``window``, first_ref [B] (the page
+    the walk starts at) in SMEM; q_ref/o_ref [H, D] this slot's rows;
     kn_ref/vn_ref [Hkv, 1, D] its new K/V row; k_hbm/v_hbm the pools
     [L, Hkv, P, page, D] left in HBM and k_out/v_out the same buffers as
     outputs; k_buf/v_buf [2, Hkv, block, D] VMEM; sems [3, 2] DMA (k
@@ -71,6 +81,22 @@ def _page_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, kn_ref,
     from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
+    if window is None:
+        first = 0
+
+        def column(p):
+            return p
+    else:
+        # The slot's row of the table is a ring: page p of the sequence
+        # lies in column p % Pmax, and the walk starts at the page that
+        # holds the oldest position the new token still attends to.
+        first_ref, *refs = refs
+        first = first_ref[b]
+
+        def column(p):
+            return jax.lax.rem(first + p, pmax)
+    (q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref, k_out, v_out,
+     k_buf, v_buf, sems) = refs
     H, D = q_ref.shape
     _, Hkv, block, _ = k_buf.shape
     page = k_hbm.shape[3]
@@ -87,7 +113,7 @@ def _page_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, kn_ref,
         out = []
         for j in range(pages_per_block):
             p = jnp.minimum(i * pages_per_block + j, n_pages - 1)
-            pid = pt_ref[b * pmax + p]
+            pid = pt_ref[b * pmax + column(p)]
             rows = pl.ds(j * page, page)
             out.append(pltpu.make_async_copy(
                 k_hbm.at[layer, :, pid], k_buf.at[buf, :, rows, :],
@@ -100,7 +126,7 @@ def _page_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, kn_ref,
     # The page that takes the new row is the walk's last, in the last
     # block's buffer.
     last = n_blocks - 1
-    pid_new = pt_ref[b * pmax + jnp.maximum(n_pages - 1, 0)]
+    pid_new = pt_ref[b * pmax + column(jnp.maximum(n_pages - 1, 0))]
     rows_new = pl.ds(pl.multiple_of(
         (n_pages - 1 - last * pages_per_block) * page, page), page)
 
@@ -156,7 +182,12 @@ def _page_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, kn_ref,
                 preferred_element_type=jnp.float32)   # [H, block]
             s = jnp.where(own[h], s_h, s)
         t = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(t <= length, s * scale, _NEG_INF)
+        if window is None:
+            attends = t <= length
+        else:
+            t = first * page + t
+            attends = (t <= length) & (t > length - window)
+        s = jnp.where(attends, s * scale, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         prob = jnp.exp(s - m_new)
@@ -193,11 +224,15 @@ def paged_decode_attention(
     lengths: jax.Array,     # [B] int32 — the new row's position
     active: jax.Array,      # [B] bool — an inactive slot walks no page
     *,
+    window: Optional[int] = None,
     interpret: bool = False,
 ):
     """The page-walk kernel. Returns ([B, H, D], k_pool, v_pool): rows
     of inactive slots are zeros, the pools are the arguments' buffers
-    with the active slots' rows written."""
+    with the active slots' rows written. With ``window`` a slot's row of
+    the table is a ring (module docstring) and the walk starts at the
+    page of position ``lengths[b] + 1 - window``, masking the rows
+    before it; ``window=None`` is the walk over everything."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -205,20 +240,26 @@ def paged_decode_attention(
     _, Hkv, _, page, _ = k_pool.shape
     Pmax = page_table.shape[1]
     pages_per_block = max(1, _BLOCK_TOKENS // page)
-    n_pages = jnp.where(
-        active, jnp.minimum(lengths // page + 1, Pmax), 0
-    ).astype(jnp.int32)
+    n_pages = jnp.minimum(lengths // page + 1, Pmax)
+    scalars = [lengths.astype(jnp.int32),
+               jnp.reshape(layer, (1,)).astype(jnp.int32)]
+    if window is not None:
+        first = jnp.maximum(lengths + 1 - window, 0) // page
+        n_pages = lengths // page + 1 - first
+        scalars.append(first.astype(jnp.int32))
+    n_pages = jnp.where(active, n_pages, 0).astype(jnp.int32)
     slot_rows = pl.BlockSpec((None, H, D), lambda b, *_: (b, 0, 0))
     new_row = pl.BlockSpec((None, Hkv, 1, D), lambda b, *_: (b, 0, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     dtype = k_pool.dtype
     kv_buf = pltpu.VMEM((2, Hkv, pages_per_block * page, D), dtype)
     kernel = functools.partial(
-        _page_walk_kernel, pmax=Pmax, scale=D ** -0.5)
+        _page_walk_kernel, pmax=Pmax, scale=D ** -0.5, window=window)
+    n_scalars = 2 + len(scalars)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=n_scalars,
             grid=(B,),
             in_specs=[slot_rows, new_row, new_row, hbm, hbm],
             out_specs=[slot_rows, hbm, hbm],
@@ -227,29 +268,39 @@ def paged_decode_attention(
         out_shape=[jax.ShapeDtypeStruct((B, H, D), q.dtype),
                    jax.ShapeDtypeStruct(k_pool.shape, dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, dtype)],
-        # Operands count the prefetched scalars: the pools are 7 and 8.
-        input_output_aliases={7: 1, 8: 2},
+        # Operands count the prefetched scalars: with four of them the
+        # pools are 7 and 8.
+        input_output_aliases={n_scalars + 3: 1, n_scalars + 4: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table.reshape(-1).astype(jnp.int32), n_pages,
-      lengths.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+    )(page_table.reshape(-1).astype(jnp.int32), n_pages, *scalars,
       q.astype(dtype), k_new.astype(dtype)[:, :, None],
       v_new.astype(dtype)[:, :, None], k_pool, v_pool)
 
 
 def gather_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
-                            page_table, lengths, active):
+                            page_table, lengths, active, *, window=None):
     """The XLA path: the kernel's arguments and results, bar that an
     inactive slot's row of the attention is computed (and discarded by
     the caller)."""
     B, H, D = q.shape
     _, Hkv, n_pool, page, _ = k_pool.shape
-    T = page_table.shape[1] * page
+    Pmax = page_table.shape[1]
+    T = Pmax * page
+    last = lengths // page                     # the new row's page
+    if window is None:
+        column, position = last, jnp.arange(T)[None, :]
+    else:
+        # The ring: column c holds the newest page p <= last with
+        # p % Pmax == c (negative: never written).
+        column = last % Pmax
+        held = last[:, None] - (last[:, None] - jnp.arange(Pmax)) % Pmax
+        position = (held[:, :, None] * page
+                    + jnp.arange(page)).reshape(B, T)
     # Inactive slots aim past the pool: -1 would WRAP to the last page
     # (NumPy semantics) and corrupt it; only >= n is truly dropped.
-    drop = jnp.where(
-        active, page_table[jnp.arange(B), lengths // page], n_pool)
+    drop = jnp.where(active, page_table[jnp.arange(B), column], n_pool)
     # A scalar beside index arrays across a slice: the cells are [B, Hkv, D].
     at = (layer, slice(None), drop, lengths % page)
     k_pool = k_pool.at[at].set(k_new.astype(k_pool.dtype), mode="drop")
@@ -259,7 +310,9 @@ def gather_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
     qg = q.reshape(B, Hkv, H // Hkv, D)
     s = jnp.einsum("bhgd,hbtd->bhgt", qg, k,
                    preferred_element_type=jnp.float32) * (D ** -0.5)
-    attends = jnp.arange(T)[None, :] <= lengths[:, None]      # [B, T]
+    attends = position <= lengths[:, None]                    # [B, T]
+    if window is not None:
+        attends &= (position > lengths[:, None] - window) & (position >= 0)
     s = jnp.where(attends[:, None, None], s, -jnp.inf)
     prob = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     attn = jnp.einsum("bhgt,hbtd->bhgd", prob, v).reshape(B, H, D)
@@ -282,16 +335,25 @@ def decode_attention_path(page: int, head_dim: int) -> str:
         else "gather"
 
 
+def ring_pages(window: int, page: int, max_pages: int) -> int:
+    """Pages a slot holds in a window layer's pool, the columns of that
+    pool's page table: the ``window`` positions a token attends to span
+    at most ``ceil(window / page) + 1`` pages, and never more than the
+    ``max_pages`` of the longest sequence."""
+    return min(-(-window // page) + 1, max_pages)
+
+
 def decode_attention(q, k_new, v_new, k_pool, v_pool, layer, page_table,
-                     lengths, active):
+                     lengths, active, *, window=None):
     """Write each active slot's ``k_new``/``v_new`` row [B, Hkv, D] into
     the pools [L, Hkv, P, page, D] at ``layer`` and position
     ``lengths[b]``, and attend the queries [B, H, D] over positions ``0
-    .. lengths[b]``: (attention [B, H, D], k_pool, v_pool), by the path
+    .. lengths[b]``, or with ``window`` over the last ``window`` of
+    them: (attention [B, H, D], k_pool, v_pool), by the path
     :func:`decode_attention_path` names."""
     page, D = k_pool.shape[3:]
     path = (paged_decode_attention
             if decode_attention_path(page, D) == "page_walk"
             else gather_decode_attention)
     return path(q, k_new, v_new, k_pool, v_pool, layer, page_table,
-                lengths, active)
+                lengths, active, window=window)

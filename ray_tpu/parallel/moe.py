@@ -1,9 +1,11 @@
 """Mixture-of-experts FFN: dropless, sorted, grouped.
 
-Absent from the reference (SURVEY.md §2.5 — no EP/MoE in Ray). Routing
-is softmax over all experts, top-k, gates NOT renormalised over the
-chosen k (OLMoE's ``norm_topk_prob=false``; Mixtral renormalises, and a
-model that needs that divides its gates by their sum before calling).
+Absent from the reference (SURVEY.md §2.5 — no EP/MoE in Ray). The
+router (:func:`route`, float32) is the model's to state: scores by
+softmax over all experts or by a sigmoid each, the top k chosen by score
+plus an optional selection bias that is not in the gate, the chosen
+gates renormalised to sum to one or left as they fall, and a scale. The
+default is OLMoE's: softmax, no bias, ``norm_topk_prob=false``, scale 1.
 
 One path, for training, prefill and decode: the ``k * T`` (token, expert)
 assignments are sorted by expert, the tokens' rows gathered in that
@@ -24,6 +26,31 @@ import jax
 import jax.numpy as jnp
 
 
+def route(logits: jax.Array, k: int, *, score: str = "softmax",
+          select_bias: Optional[jax.Array] = None,
+          renormalize: bool = False, scale: float = 1.0):
+    """(scores [T, E], gates [T, k], experts [T, k]) from float32 router
+    logits [T, E]. ``score``: "softmax" over the experts or "sigmoid" of
+    each. With ``select_bias`` [E] the k experts are those of the
+    largest score + bias, and a gate is the score alone. ``renormalize``
+    divides a token's k gates by their sum; ``scale`` multiplies them."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"router score {score!r}: softmax or sigmoid")
+    scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+              else jax.nn.sigmoid(logits))
+    if select_bias is None:
+        gates, experts = jax.lax.top_k(scores, k)              # [T, k]
+    else:
+        _, experts = jax.lax.top_k(
+            scores + select_bias.astype(jnp.float32), k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+    if renormalize:
+        gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        gates = gates * scale
+    return scores, gates, experts
+
+
 def moe_ffn(
     x: jax.Array,           # [B, S, M]
     router_w: jax.Array,    # [M, E]
@@ -35,6 +62,7 @@ def moe_ffn(
     activation=jax.nn.silu,
     token_mask: Optional[jax.Array] = None,  # [B, S] 1=route, 0=ignore
     layer: Optional[jax.Array] = None,  # [] int32: the weights are stacks
+    **routing,              # route()'s: score, select_bias, renormalize, scale
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (output [B,S,M], Switch load-balancing loss, tokens
     assigned to each expert [E] int32). Masked rows give zero, reach no
@@ -56,8 +84,7 @@ def moe_ffn(
         router_logits = jnp.einsum(
             "tm,me->te", xt.astype(jnp.float32), router_w.astype(jnp.float32)
         )
-        probs = jax.nn.softmax(router_logits, axis=-1)
-        gates, experts = jax.lax.top_k(probs, k)               # [T, k]
+        probs, gates, experts = route(router_logits, k, **routing)
         chosen = jax.nn.one_hot(experts, E, dtype=jnp.float32).sum(axis=1)
         if token_mask is not None:
             live = token_mask.reshape(T).astype(bool)

@@ -13,6 +13,13 @@ the pages its prompt + max_new_tokens need — not a dense max_len row — so
 total KV is bounded by actual demand, long-context requests coexist with
 short ones, and pages recycle the moment a request finishes. Admission
 waits for pages instead of OOMing. All shapes stay static for XLA.
+
+A model with window layers has a pool and a page table for each
+attention kind, under the one allocator: a request reserves, at
+admission and per kind, every page of its context in the "full" pool and
+at most a ring of ``window / page + 1`` in the "window" pool, which the
+decode programs then turn through by themselves. The loop allocates and
+frees nothing between admission and finish.
 """
 
 from __future__ import annotations
@@ -98,7 +105,8 @@ class _Request:
 
 # LLMEngine.stats(): the monotonic counts, the loop's phases, and how many
 # finished requests' rows it keeps.
-_COUNTERS = ("decode_slot_steps", "decode_kv_tokens", "prefills",
+_COUNTERS = ("decode_slot_steps", "decode_kv_tokens", "decode_kv_rows_read",
+             "kv_page_steps_held", "kv_page_steps_one_table", "prefills",
              "prefill_tokens", "prefill_bucket_tokens", "submitted",
              "admitted", "finished", "failed", "cache_resets", "page_waits")
 _PHASES = ("admit", "admit_stalling", "inputs", "decode", "readback",
@@ -121,6 +129,7 @@ class LLMEngine:
             paged_prefill,
             sample_logits,
         )
+        from ..models.llama import layer_runs
         from ..ops.paged_attention import decode_attention_path
 
         self.cfg = cfg
@@ -154,13 +163,21 @@ class LLMEngine:
             cfg, max_batch, self.total_pages, page_size,
             self.max_pages_per_seq,
         )
-        self._free_pages: List[int] = list(range(self.total_pages))
-        self._table = np.zeros(
-            (max_batch, self.max_pages_per_seq), dtype=np.int32
-        )
+        # The allocator's books, one entry a KV pool (an attention kind
+        # the model has): {kind: (layers, pool pages, table columns)}.
+        self._pools = PagedKVCache.sizes(
+            cfg, max_batch, self.total_pages, page_size,
+            self.max_pages_per_seq)
+        self._free_pages: Dict[str, List[int]] = {}
+        self._table: Dict[str, np.ndarray] = {}
+        self._new_books()
         self._slot_free = list(range(max_batch))
         self._slot_req: Dict[int, _Request] = {}
-        self._slot_pages: Dict[int, List[int]] = {}
+        self._slot_pages: Dict[int, Dict[str, List[int]]] = {}
+        # Per slot, fixed from admission to finish so that a decode step
+        # only adds them up: (pages held, each times its pool's layers;
+        # what one table for every layer would hold).
+        self._slot_held: Dict[int, tuple] = {}
         self._last_tok = np.zeros((max_batch,), dtype=np.int32)
         self._queue: "queue.Queue[_Request]" = queue.Queue()
         self._waiting: List[_Request] = []  # admitted-but-no-pages
@@ -177,6 +194,8 @@ class LLMEngine:
         # Expert load of a MoE model, read back behind each program's
         # tokens (``with_load`` below); None for a dense one.
         self._moe: Optional[Dict[str, Any]] = None
+        self._expert_layers = sum(
+            run.n for run in layer_runs(cfg) if run.moe)
         if cfg.n_experts > 0:
             self._moe = {"assignments": 0, "decode_assignments": 0,
                          "experts_reached": 0, "layer_steps": 0,
@@ -238,13 +257,14 @@ class LLMEngine:
             )
         req = _Request(prompt, max_new_tokens, eos_token)
         need = self._pages_needed(req, self._bucket(len(prompt)))
-        if need > self.total_pages:
-            # Unsatisfiable EVER: waiting would head-of-line block the
-            # admission queue forever.
-            raise ValueError(
-                f"request needs {need} pages but the pool has only "
-                f"{self.total_pages} (page_size={self.page_size})"
-            )
+        for kind, (_, pages, _) in self._pools.items():
+            if need[kind] > pages:
+                # Unsatisfiable EVER: waiting would head-of-line block
+                # the admission queue forever.
+                raise ValueError(
+                    f"request needs {need[kind]} pages but the {kind} "
+                    f"pool has only {pages} (page_size={self.page_size})"
+                )
         with self._lock:
             self._counts["submitted"] += 1
         req.t_submit = time.time()
@@ -260,7 +280,9 @@ class LLMEngine:
         """Gauges of the engine now, and monotonic counts since it
         started; take two readings and subtract for a window.
 
-        Gauges: ``active_slots``, ``free_slots``, ``free_pages``,
+        Gauges: ``active_slots``, ``free_slots``, ``free_pages`` (of the
+        pool that keeps everything, "full", or of the only pool),
+        ``pages`` (``{kind: {"layers", "total", "free"}}``, every pool),
         ``queued`` (submitted, not yet admitted), beside the constants
         ``platform``, ``device_kind``, ``total_pages``, ``page_size`` and
         ``decode_attention`` (``"page_walk"`` or ``"gather"``: the path of
@@ -270,6 +292,14 @@ class LLMEngine:
         over decode steps) and ``decode_kv_tokens`` (their cached tokens,
         prompt and generated so far, summed likewise), so that the mean
         batch and context of a step are quotients of differences;
+        ``decode_kv_rows_read`` (the rows the steps' attention read,
+        summed over steps, sequences and layers: a sequence's cached
+        tokens, on a window layer at most the window; without window
+        layers ``decode_kv_tokens`` times the layers);
+        ``kv_page_steps_held`` (pages the live sequences held, each
+        times its pool's layers, summed over decode steps) and
+        ``kv_page_steps_one_table`` (what they would have held with one
+        table for every layer: the same number without window layers);
         ``prefills``, ``prefill_tokens`` (real) and
         ``prefill_bucket_tokens`` (padded to the bucket); ``submitted``,
         ``admitted``, ``finished``, ``failed`` (requests); ``cache_resets``;
@@ -289,9 +319,9 @@ class LLMEngine:
         sum) and ``decode_assignments`` (the decode steps' part of it);
         ``experts_reached`` ((layer, expert) pairs that a decode
         step gave at least one token, summed over decode steps) over
-        ``layer_steps`` (decode steps x layers) is the experts a layer of
-        a decode step read; ``prefill_experts_reached`` is the same count
-        over prefills.
+        ``layer_steps`` (decode steps x layers that have experts) is the
+        experts such a layer of a decode step read;
+        ``prefill_experts_reached`` is the same count over prefills.
 
         ``requests``: the newest requests that have finished and those now
         decoding, each ``[t_submit, t_admit, t_first, t_done or None,
@@ -313,7 +343,12 @@ class LLMEngine:
                 "active_slots": len(self._slot_req),
                 "free_slots": len(self._slot_free),
                 "decode_steps": self._step_count,
-                "free_pages": len(self._free_pages),
+                "free_pages": len(self._free_pages.get(
+                    "full", next(iter(self._free_pages.values())))),
+                "pages": {kind: {"layers": layers, "total": total,
+                                 "free": len(self._free_pages[kind])}
+                          for kind, (layers, total, _) in
+                          self._pools.items()},
                 "total_pages": self.total_pages,
                 "page_size": self.page_size,
                 "decode_attention": self._decode_attention,
@@ -332,11 +367,23 @@ class LLMEngine:
 
     # ---- page accounting ---------------------------------------------------
 
-    def _pages_needed(self, req: _Request, bucket: int) -> int:
+    def _new_books(self):
+        """Every page free, every table zero."""
+        for kind, (_, pages, columns) in self._pools.items():
+            self._free_pages[kind] = list(range(pages))
+            self._table[kind] = np.zeros((self.max_batch, columns),
+                                         dtype=np.int32)
+
+    def _pages_needed(self, req: _Request, bucket: int) -> Dict[str, int]:
+        """Pages of each pool the request holds from admission to its
+        end: its bucket's or its whole context's, whichever is more, and
+        in a window pool no more than the ring (the table's columns)."""
         decode_span = math.ceil(
             (len(req.prompt) + req.max_new_tokens) / self.page_size
         )
-        return max(bucket // self.page_size, decode_span)
+        span = max(bucket // self.page_size, decode_span)
+        return {kind: min(span, columns)
+                for kind, (_, _, columns) in self._pools.items()}
 
     def _reset_cache(self, cause: Exception):
         """Recover from a failed donated call: the old pool's buffers
@@ -348,9 +395,9 @@ class LLMEngine:
             victims = list(self._slot_req.items())
             self._slot_req.clear()
             self._slot_free = list(range(self.max_batch))
-            self._free_pages = list(range(self.total_pages))
             self._slot_pages.clear()
-            self._table[:] = 0
+            self._slot_held.clear()
+            self._new_books()
         self._counts["cache_resets"] += 1
         for _slot, req in victims:
             if not req.done.is_set():
@@ -374,9 +421,11 @@ class LLMEngine:
         req._live.put(None)
 
     def _release_slot(self, slot: int):
-        pages = self._slot_pages.pop(slot, [])
-        self._free_pages.extend(pages)
-        self._table[slot, :] = 0
+        self._slot_held.pop(slot, None)
+        held = self._slot_pages.pop(slot, {})
+        for kind, pages in held.items():
+            self._free_pages[kind].extend(pages)
+            self._table[kind][slot, :] = 0
         self._slot_free.append(slot)
 
     # ---- engine loop -------------------------------------------------------
@@ -403,14 +452,17 @@ class LLMEngine:
             real_len = req.prompt_len
             bucket = self._bucket(real_len)
             need = self._pages_needed(req, bucket)
-            if need > len(self._free_pages):
-                # Paged admission control: wait for pages to recycle
-                # instead of OOMing or over-reserving a dense max_len row.
+            if any(n > len(self._free_pages[kind])
+                   for kind, n in need.items()):
+                # Paged admission control: wait for pages to recycle, in
+                # whichever pool is short, instead of OOMing or
+                # over-reserving a dense max_len row.
                 self._waiting.insert(0, req)
                 counts["page_waits"] += 1
                 return
             slot = self._slot_free.pop()
-            pages = [self._free_pages.pop() for _ in range(need)]
+            pages = {kind: [self._free_pages[kind].pop() for _ in range(n)]
+                     for kind, n in need.items()}
             req.bucket = bucket
             req.t_admit = time.time()
             counts["admitted"] += 1
@@ -420,19 +472,30 @@ class LLMEngine:
                 with self._jax.profiler.TraceAnnotation(
                         "engine.prefill", bucket=bucket, slot=slot):
                     self._slot_pages[slot] = pages
-                    self._table[slot, :] = 0
-                    self._table[slot, :need] = pages
-                    prefill_pages = pages[: bucket // self.page_size]
+                    self._slot_held[slot] = (
+                        sum(self._pools[kind][0] * n
+                            for kind, n in need.items()),
+                        self.cfg.num_layers * max(need.values()))
+                    for kind, ids in pages.items():
+                        self._table[kind][slot, :] = 0
+                        self._table[kind][slot, :len(ids)] = ids
+                    # What paged_prefill lays the bucket into: its pages
+                    # of a pool that keeps everything, of a ring no more
+                    # than the ring has.
+                    prefill_pages = {
+                        kind: jnp.asarray(
+                            ids[: bucket // self.page_size], dtype=jnp.int32)
+                        for kind, ids in pages.items()}
                     self.cache = self.cache._replace(
-                        page_table=jnp.asarray(self._table)
-                    )
+                        page_table={kind: jnp.asarray(table) for kind, table
+                                    in self._table.items()})
                     padded = req.prompt + [0] * (bucket - real_len)
                     tokens = jnp.asarray([padded], dtype=jnp.int32)
                     self.cache, first = self._prefill(
                         self.params, self.cache, tokens,
                         jnp.asarray(real_len, dtype=jnp.int32),
                         jnp.asarray(slot, dtype=jnp.int32),
-                        jnp.asarray(prefill_pages, dtype=jnp.int32),
+                        prefill_pages,
                     )
                     first = int(self._tokens(
                         np.asarray(first).reshape(-1), 1, decode=False)[0])
@@ -471,7 +534,7 @@ class LLMEngine:
                 if decode:
                     moe["decode_assignments"] += assignments
                     moe["experts_reached"] += int(out[-1])
-                    moe["layer_steps"] += self.cfg.num_layers
+                    moe["layer_steps"] += self._expert_layers
                 else:
                     moe["prefill_experts_reached"] += int(out[-1])
         return out[:n]
@@ -549,11 +612,20 @@ class LLMEngine:
                 nxt = self._tokens(nxt, self.max_batch, decode=True)
                 counts["decode_slot_steps"] += len(active_slots)
                 # The step attended to each prompt and every token
-                # generated before this one.
-                counts["decode_kv_tokens"] += sum(
-                    req.prompt_len + len(req.output)
-                    for req in active_slots.values())
+                # generated before this one: in a window layer to no
+                # more of them than the window.
+                contexts = [req.prompt_len + len(req.output)
+                            for req in active_slots.values()]
+                tokens = sum(contexts)
+                counts["decode_kv_tokens"] += tokens
+                counts["decode_kv_rows_read"] += sum(
+                    layers * (tokens if kind != "window" else sum(
+                        min(c, self.cfg.sliding_window) for c in contexts))
+                    for kind, (layers, _, _) in self._pools.items())
                 for slot, req in active_slots.items():
+                    held, one_table = self._slot_held[slot]
+                    counts["kv_page_steps_held"] += held
+                    counts["kv_page_steps_one_table"] += one_table
                     tok = int(nxt[slot])
                     req.output.append(tok)
                     req._live.put(tok)

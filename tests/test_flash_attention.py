@@ -148,3 +148,53 @@ def test_flash_gqa_gradients_perhead_fallback(monkeypatch):
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("window", [1, 100, 128, 300, 4096])
+def test_flash_window_matches_the_masked_einsum(window):
+    """The forward kernel with a lower bound: token t attends to
+    ``t - window < j <= t``, for windows under a block, of a block, over
+    several and over the whole sequence, with GQA; against the XLA
+    einsum with the same mask, which itself is checked by hand."""
+    B, S, H, Hkv, D = 1, 512, 4, 2, 64
+    q = _rand((B, S, H, D), 0)
+    k = _rand((B, S, Hkv, D), 1)
+    v = _rand((B, S, Hkv, D), 2)
+    ref = mha_attention(q, k, v, causal=True, window=window)
+    out = flash_attention(q, k, v, causal=True, window=window,
+                          interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    # The einsum's mask by hand, at one query row.
+    t = 400
+    lo = max(0, t + 1 - window)
+    s = np.einsum("d,td->t", np.asarray(q)[0, t, 3],
+                  np.asarray(k)[0, lo:t + 1, 1]) * D ** -0.5
+    p = np.exp(s - s.max())
+    want = (p / p.sum()) @ np.asarray(v)[0, lo:t + 1, 1]
+    np.testing.assert_allclose(np.asarray(ref)[0, t, 3], want, atol=2e-5,
+                               rtol=2e-5)
+
+
+def test_flash_window_none_is_todays_kernel_and_a_window_has_no_grad():
+    """``window=None`` traces the program it always did; under
+    ``jax.grad`` a window raises by name instead of giving the causal
+    kernels' gradient."""
+    B, S, H, D = 1, 256, 2, 64
+    q, k, v = (_rand((B, S, H, D), i) for i in range(3))
+
+    def flash(window, **named):
+        return lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=True, **named,
+            **({"window": window} if window != "unnamed" else {}))
+
+    assert str(jax.make_jaxpr(flash("unnamed"))(q, k, v)) == \
+        str(jax.make_jaxpr(flash(None))(q, k, v))
+    np.testing.assert_array_equal(
+        np.asarray(flash(None)(q, k, v)), np.asarray(flash("unnamed")(q, k, v)))
+    with pytest.raises(NotImplementedError, match="no backward kernel"):
+        jax.grad(lambda q: flash(64)(q, k, v).sum())(q)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=64, interpret=True)
+    with pytest.raises(ValueError, match="causal"):
+        mha_attention(q, k, v, causal=False, window=64)

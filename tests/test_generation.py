@@ -1,6 +1,8 @@
 """The serving programs over the paged KV cache against the plain full
 forward pass, and the decode attention against a reference of its own."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -118,6 +120,110 @@ def test_paged_decode_attention_matches_reference(path, case):
                                ref[active], atol=tol, rtol=tol)
 
 
+_WINDOW_CASES = {
+    # lengths (position of the new row), window; page 16
+    "under_the_window": ([5, 20], 32),
+    "crossing_it": ([31, 32, 33], 32),
+    "far_over_wrapping": ([47, 48, 200, 1000], 32),
+    "window_no_page_multiple": ([70, 129, 7], 40),
+    "an_idle_slot": ([300, 90], 64),
+}
+
+
+@pytest.mark.parametrize("case", list(_WINDOW_CASES))
+@pytest.mark.parametrize("path", ["page_walk", "gather"])
+def test_window_decode_attention_matches_the_masked_einsum(path, case):
+    """A window layer's decode attention over a RING of ``ring_pages``
+    columns, on both paths: each slot's whole history is laid into its
+    ring the way prefill and earlier steps would have left it (position t
+    in column ``(t // page) % columns``, later pages over earlier ones);
+    the new row lands in the ring's cell for ``lengths[b]`` and nowhere
+    else, and the output equals a plain softmax over positions
+    ``lengths[b] - window < t <= lengths[b]`` of the history, on float32
+    values, whether the slot is under the window, crosses it or has
+    wrapped its ring many times. A slot's unused columns hold page 0."""
+    from ray_tpu.ops import paged_attention as pa
+
+    lengths, window = _WINDOW_CASES[case]
+    B, H, Hkv, D, page, layer = len(lengths), 4, 2, 128, 16, 1
+    columns = pa.ring_pages(window, page, 4096)
+    assert columns == -(-window // page) + 1
+    active = np.ones(B, bool)
+    if case == "an_idle_slot":
+        active[1] = False
+    rng = np.random.RandomState(len(case))
+    n_pool = B * columns + 1
+    q = rng.randn(B, H, D).astype(np.float32)
+    hist_k = [rng.randn(n + 1, Hkv, D).astype(np.float32) for n in lengths]
+    hist_v = [rng.randn(n + 1, Hkv, D).astype(np.float32) for n in lengths]
+    ck = rng.randn(2, Hkv, n_pool, page, D).astype(np.float32)
+    cv = rng.randn(2, Hkv, n_pool, page, D).astype(np.float32)
+    order = 1 + rng.permutation(B * columns)          # page 0 is no one's
+    table = np.zeros((B, columns), np.int32)
+    for b, n in enumerate(lengths):
+        used = min(n // page + 1, columns)
+        table[b, :used] = order[b * columns:b * columns + used]
+        for t in range(n):                             # the rows before
+            cell = (layer, slice(None), table[b, (t // page) % columns],
+                    t % page)
+            ck[cell], cv[cell] = hist_k[b][t], hist_v[b][t]
+    want_k, want_v = ck.copy(), cv.copy()
+    for b in np.flatnonzero(active):
+        n = lengths[b]
+        cell = (layer, slice(None), table[b, (n // page) % columns], n % page)
+        want_k[cell], want_v[cell] = hist_k[b][n], hist_v[b][n]
+    args = (jnp.asarray(q), jnp.asarray(np.stack([h[-1] for h in hist_k])),
+            jnp.asarray(np.stack([h[-1] for h in hist_v])), jnp.asarray(ck),
+            jnp.asarray(cv), jnp.asarray(layer, jnp.int32),
+            jnp.asarray(table), jnp.asarray(lengths, jnp.int32),
+            jnp.asarray(active))
+    if path == "page_walk":
+        out, got_k, got_v = pa.paged_decode_attention(
+            *args, window=window, interpret=True)
+    else:
+        out, got_k, got_v = pa.gather_decode_attention(*args, window=window)
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    np.testing.assert_array_equal(np.asarray(got_v), want_v)
+    for b in np.flatnonzero(active):
+        n = lengths[b]
+        lo = max(0, n + 1 - window)
+        k, v = hist_k[b][lo:n + 1], hist_v[b][lo:n + 1]   # [T, Hkv, D]
+        qg = q[b].reshape(Hkv, H // Hkv, D)
+        s = np.einsum("hgd,thd->hgt", qg, k) * D ** -0.5
+        prob = np.exp(s - s.max(-1, keepdims=True))
+        prob /= prob.sum(-1, keepdims=True)
+        ref = np.einsum("hgt,thd->hgd", prob, v).reshape(H, D)
+        np.testing.assert_allclose(np.asarray(out)[b], ref, atol=2e-5,
+                                   rtol=2e-5)
+
+
+def test_a_window_of_none_is_the_walk_over_everything():
+    """``window=None`` changes nothing: the same jaxpr as a call that
+    does not name it, on both paths, and a window wider than the
+    context gives the same numbers over a table that holds it all."""
+    from ray_tpu.ops import paged_attention as pa
+
+    B, H, Hkv, D, page, pmax = 2, 4, 2, 128, 16, 4
+    rng = np.random.RandomState(0)
+    args = (jnp.asarray(rng.randn(B, H, D), jnp.float32),
+            jnp.asarray(rng.randn(B, Hkv, D), jnp.float32),
+            jnp.asarray(rng.randn(B, Hkv, D), jnp.float32),
+            jnp.asarray(rng.randn(1, Hkv, B * pmax, page, D), jnp.float32),
+            jnp.asarray(rng.randn(1, Hkv, B * pmax, page, D), jnp.float32),
+            jnp.asarray(0, jnp.int32),
+            jnp.asarray(rng.permutation(B * pmax).reshape(B, pmax), jnp.int32),
+            jnp.asarray([37, 9], jnp.int32), jnp.asarray([True, True]))
+    for fn in (functools.partial(pa.paged_decode_attention, interpret=True),
+               pa.gather_decode_attention):
+        plain = jax.make_jaxpr(fn)(*args)
+        named = jax.make_jaxpr(functools.partial(fn, window=None))(*args)
+        assert str(plain) == str(named)
+        wide = fn(*args, window=4096)
+        for a, b in zip(fn(*args), wide):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-6, rtol=1e-6)
+
+
 def test_decode_attention_path_follows_platform_and_shape(monkeypatch):
     """One choice, from what the code can see: the page walk on a TPU
     for shapes it tiles, the gather everywhere else."""
@@ -218,12 +324,13 @@ def test_paged_prefill_then_decode_equals_the_full_forward(model, real_len):
     pages = [5, 2, 7]                      # the slot's pages, out of order
     table = np.zeros((slots, 4), np.int32)
     table[slot, :3] = pages
-    cache = cache._replace(page_table=jnp.asarray(table))
+    cache = cache._replace(page_table={"full": jnp.asarray(table)})
     padded = np.zeros((1, bucket), np.int32)
     padded[0, :real_len] = seq[:real_len]
     logits, cache, load = paged_prefill(
         params, jnp.asarray(padded), jnp.asarray(real_len, jnp.int32),
-        cache, cfg, slot, jnp.asarray(pages[:bucket // page], jnp.int32))
+        cache, cfg, slot,
+        {"full": jnp.asarray(pages[:bucket // page], jnp.int32)})
     np.testing.assert_allclose(np.asarray(logits)[0], expected[real_len - 1],
                                atol=1e-4, rtol=1e-4)
     assert list(np.asarray(cache.lengths)) == [0, real_len, 0]
@@ -265,19 +372,20 @@ def test_paged_decode_leaves_an_inactive_slot_as_it_was():
     cache = PagedKVCache.create(cfg, 2, 4, page, 2)
     table = np.zeros((2, 2), np.int32)
     table[0, 0], table[1, 0] = pages[0][0], pages[1][0]
-    cache = cache._replace(page_table=jnp.asarray(table))
+    cache = cache._replace(page_table={"full": jnp.asarray(table)})
     rng = np.random.RandomState(0)
     for slot, n in enumerate(lens):
         prompt = np.zeros((1, page), np.int32)
         prompt[0, :n] = rng.randint(0, 256, n)
         _, cache, _ = paged_prefill(
             params, jnp.asarray(prompt), jnp.asarray(n, jnp.int32), cache,
-            cfg, slot, jnp.asarray(pages[slot], jnp.int32))
+            cfg, slot, {"full": jnp.asarray(pages[slot], jnp.int32)})
     _, after, _ = paged_decode(
         params, jnp.asarray([5, 9], jnp.int32), cache, cfg,
         active=jnp.asarray([True, False]))
     assert list(np.asarray(after.lengths)) == [lens[0] + 1, lens[1]]
-    for old, new in ((cache.k, after.k), (cache.v, after.v)):
+    for old, new in ((cache.k["full"], after.k["full"]),
+                     (cache.v["full"], after.v["full"])):
         changed = np.argwhere((np.asarray(old) != np.asarray(new)).any(-1))
         # [layer, kv head, page, row]: slot 0's row 5 of page 3 alone.
         assert {tuple(c[2:]) for c in changed} == {(pages[0][0], lens[0])}

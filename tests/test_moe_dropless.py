@@ -199,3 +199,59 @@ def test_nothing_has_a_capacity_axis():
     assert max(sizes(jaxpr.jaxpr)) <= k * T * max(M, F, E)
     assert any(e.primitive.name.startswith("ragged_dot")
                for e in jaxpr.jaxpr.eqns)
+
+
+# ---- the router's other arguments (PR 38) -----------------------------------
+
+@pytest.mark.parametrize("E,k,T", CASES[:3], ids=IDS[:3])
+def test_sigmoid_routing_with_a_selection_bias_equals_the_per_token_loop(
+        E, k, T):
+    """Sigmoid scores, the k experts of the largest score + bias, each
+    gated by its score alone, renormalised and scaled: the same sorted
+    grouped path as ever, against a per-token loop that selects and
+    weights by NumPy. The bias is large enough to change who is chosen."""
+    router, w_up, w_gate, w_down = _weights(E, 11)
+    x = _tokens(T, 12)
+    bias = jnp.asarray(np.random.RandomState(13).randn(E) * 0.5, jnp.float32)
+    scale = 2.826
+    out, _, expert_tokens = moe_ffn(
+        x, router, w_up, w_down, k=k, w_gate=w_gate, score="sigmoid",
+        select_bias=bias, renormalize=True, scale=scale)
+    xt = np.asarray(x, np.float64).reshape(-1, M)
+    scores = 1 / (1 + np.exp(-(xt @ np.asarray(router, np.float64))))
+    chosen = np.argsort(-(scores + np.asarray(bias, np.float64)), axis=-1,
+                        kind="stable")[:, :k]
+    unbiased = np.argsort(-scores, axis=-1, kind="stable")[:, :k]
+    assert (np.sort(chosen) != np.sort(unbiased)).any()
+    rows = []
+    for t in range(T):
+        gates = scores[t, chosen[t]]
+        gates = gates / gates.sum() * scale
+        total = np.zeros(M)
+        for gate, e in zip(gates, chosen[t]):
+            a = xt[t] @ np.asarray(w_gate[e], np.float64)
+            h = a / (1 + np.exp(-a)) * (xt[t] @ np.asarray(w_up[e], np.float64))
+            total += gate * (h @ np.asarray(w_down[e], np.float64))
+        rows.append(total)
+    np.testing.assert_allclose(np.asarray(out)[0], np.stack(rows),
+                               atol=2e-5, rtol=2e-5)
+    assert list(np.asarray(expert_tokens)) == list(
+        np.bincount(chosen.reshape(-1), minlength=E))
+
+
+def test_the_default_routing_is_the_program_it_was():
+    """Softmax, no bias, gates as they fall, scale 1: naming the
+    defaults traces the same program as not naming them, so OLMoE's
+    compiled programs do not change."""
+    router, w_up, w_gate, w_down = _weights(8, 1)
+    x = _tokens(5, 2)
+
+    def plain(x):
+        return moe_ffn(x, router, w_up, w_down, k=2, w_gate=w_gate)
+
+    def named(x):
+        return moe_ffn(x, router, w_up, w_down, k=2, w_gate=w_gate,
+                       score="softmax", select_bias=None, renormalize=False,
+                       scale=1.0)
+
+    assert str(jax.make_jaxpr(plain)(x)) == str(jax.make_jaxpr(named)(x))

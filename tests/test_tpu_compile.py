@@ -194,7 +194,7 @@ def _decode_program(cfg, v5e, batch, pool_pages, pages_per_seq):
     return jax.jit(decode, donate_argnums=(1,)).lower(
         params, cache, _arr(v5e, (batch,), jnp.int32),
         _arr(v5e, (batch,), jnp.bool_),
-    ).compile(), cache.k.shape
+    ).compile(), cache.k["full"].shape
 
 
 @decode_shapes
@@ -231,7 +231,7 @@ def test_paged_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket, flash):
     compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
         params, cache, _arr(v5e, (1, bucket), jnp.int32),
         _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
-        _arr(v5e, (bucket // PAGE,), jnp.int32),
+        {"full": _arr(v5e, (bucket // PAGE,), jnp.int32)},
     ).compile()
     assert ("tpu_custom_call" in compiled.as_text()) is flash
 
@@ -251,7 +251,7 @@ def test_flash_falls_back_off_tpu():
 
 def _olmoe_cfg():
     """OLMoE-1B-7B at its published widths and the benchmark's depth of
-    8: the cell ``serve-olmoe-chat``'s model."""
+    8: the configuration ``olmoe-1b-7b-0125-L8``."""
     return LlamaConfig(
         vocab_size=50_304, hidden_size=2048, intermediate_size=1024,
         num_layers=8, num_heads=16, num_kv_heads=16, head_dim=128,
@@ -293,7 +293,76 @@ def test_olmoe_prefill_program_compiles_for_v5e(v5e, as_tpu):
     compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
         params, cache, _arr(v5e, (1, 2048), jnp.int32),
         _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
-        _arr(v5e, (2048 // PAGE,), jnp.int32),
+        {"full": _arr(v5e, (2048 // PAGE,), jnp.int32)},
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()  # flash prefill
     assert _fits_one_chip(compiled)
+
+
+def _trinity():
+    """``trinity-mini-L6`` as the benchmark builds it, and its engine."""
+    import json
+
+    from benchmark import arch
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "trinity-mini-L6.json")) as f:
+        config = json.load(f)
+    return arch.program_config(config), config["engine"]
+
+
+def _trinity_shapes(v5e):
+    cfg, engine = _trinity()
+    params, cache = _serve_shapes(
+        cfg, v5e, engine["max_batch"], engine["total_pages"],
+        engine["max_len"] // PAGE)
+    return cfg, engine, params, cache
+
+
+def test_trinity_decode_program_compiles_for_v5e(v5e, as_tpu):
+    """Two pools, five scans: 5 window layers over rings of 129 pages a
+    slot and one full layer over the 8192-page pool, 128 experts read in
+    place. Neither pool is copied, sliced or re-stacked, and the weights
+    of a run's layers are read where they lie."""
+    cfg, engine, params, cache = _trinity_shapes(v5e)
+    assert {k: v.shape for k, v in cache.k.items()} == {
+        "window": (5, 4, 32 * 129, PAGE, 128), "full": (1, 4, 8192, PAGE, 128)}
+    batch = engine["max_batch"]
+
+    def decode(params, cache, tok, active):
+        return generation.paged_decode(params, tok, cache, cfg, active=active)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (batch,), jnp.int32),
+        _arr(v5e, (batch,), jnp.bool_)).compile()
+    assert _fits_one_chip(compiled)
+    # The window pool is the larger: the temporaries' bound is its slice.
+    _assert_pool_stays_in_place(compiled, cache.k["window"].shape)
+    _assert_pool_stays_in_place(compiled, cache.k["full"].shape)
+    pools = sum(2 * 2 * math.prod(p.shape) for p in cache.k.values())
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools
+
+
+@pytest.mark.parametrize("bucket", [4096, 8192])
+def test_trinity_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket):
+    """The two buckets no cell had before: the flash kernel with a whole
+    4096- or 8192-row K and V of a head in VMEM, with and without the
+    window's lower bound, 8 x bucket rows through the grouped matmuls,
+    beside 8.6 GB of weights and both pools."""
+    cfg, engine, params, cache = _trinity_shapes(v5e)
+
+    def prefill(params, cache, tokens, real_len, slot, pages):
+        return generation.paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages)
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (1, bucket), jnp.int32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
+        {"full": _arr(v5e, (bucket // PAGE,), jnp.int32),
+         "window": _arr(v5e, (129,), jnp.int32)},
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert _fits_one_chip(compiled)
+    memory = compiled.memory_analysis()
+    print(bucket, memory.temp_size_in_bytes / 2**30, "GiB of temporaries")
