@@ -426,8 +426,8 @@ def _llm_deployment():
             i32 = jax.numpy.int32
             shape = jax.ShapeDtypeStruct
             prefill = eng._prefill.__wrapped_jit__.lower(
-                eng.params, eng.cache, shape((1, bucket), i32),
-                shape((), i32), shape((), i32),
+                eng.params, eng.cache, shape((eng.max_batch,), i32),
+                shape((1, bucket), i32), shape((), i32), shape((), i32),
                 {kind: shape((min(bucket // eng.page_size, columns),), i32)
                  for kind, (_, _, columns) in eng._pools.items()},
             ).compile().as_text()

@@ -20,6 +20,30 @@ admission and per kind, every page of its context in the "full" pool and
 at most a ring of ``window / page + 1`` in the "window" pool, which the
 decode programs then turn through by themselves. The loop allocates and
 frees nothing between admission and finish.
+
+The loop runs ONE decode step ahead of the one it reads. What a step
+needs lies on the device: the last tokens (a step's output is the next
+one's input; a prefill sets its slot's), the PRNG key (split inside the
+program) and the active mask (sent again only when membership changes).
+One turn of ``LLMEngine._loop``:
+
+1. admit: prefill queued requests into free slots, blocking for each
+   first token (the prefill queues behind the step in flight);
+2. inputs: who decodes in the step queued next: every open slot that
+   its token count, the token in flight included, has not ended;
+3. decode: dispatch step k+1, start its read-back's copy to the host;
+4. readback: block on step k's tokens while step k+1 runs;
+5. emit: step k's tokens to their requests, the counters, the finishes.
+
+So no step writes a K/V row past the pages its slot holds: a slot that
+ends by its count is left out of the next step before its last token is
+read. Only ``eos_token`` is known at the read-back alone: the one step
+already queued for that slot is a wasted row inside its own pages, its
+token is dropped, and slot and pages return to the allocator when that
+step is read, so a prefill that reuses them queues behind the stray
+write. A slot freed at step k's read-back is therefore taken by a
+waiting request one step later than a loop that read each step before
+the next would give it.
 """
 
 from __future__ import annotations
@@ -108,10 +132,70 @@ class _Request:
 _COUNTERS = ("decode_slot_steps", "decode_kv_tokens", "decode_kv_rows_read",
              "kv_page_steps_held", "kv_page_steps_one_table", "prefills",
              "prefill_tokens", "prefill_bucket_tokens", "submitted",
-             "admitted", "finished", "failed", "cache_resets", "page_waits")
+             "admitted", "finished", "failed", "cache_resets", "page_waits",
+             "decode_steps_ahead", "decode_slot_steps_discarded")
 _PHASES = ("admit", "admit_stalling", "inputs", "decode", "readback",
            "emit", "idle")
 _REQUEST_ROWS = 1024
+
+
+class _Step:
+    """A decode step dispatched and not yet read."""
+
+    __slots__ = ("out", "slots", "ahead", "dropped")
+
+    def __init__(self, out, slots: Dict[int, _Request], ahead: bool):
+        self.out = out        # the packed read-back, on its way to the host
+        self.slots = slots    # who decodes in it
+        self.ahead = ahead    # dispatched while the step before was unread
+        # Slots that ended on ``eos_token`` in the step before: their
+        # token is dropped, slot and pages released when this one is read.
+        self.dropped: List[int] = []
+
+
+def serving_programs(cfg, temperature: float):
+    """``(decode_step, prefill)``, the two functions the engine jits.
+
+    ``decode_step(params, cache, last_tok, active, rng)`` returns
+    ``(read-back, cache, last_tok, rng)``: what the next call takes, so
+    that nothing of a step's inputs passes through the host.
+    ``prefill(params, cache, last_tok, tokens, real_len, slot, pages)``
+    returns ``(cache, last_tok, read-back)``, its first token set at
+    ``slot`` (traced: one program a bucket, whatever the slot). A
+    read-back is the sampled tokens ([B], of a prefill one) and, for a
+    MoE model, the program's expert load behind them in the same int32
+    vector: one read a step, as for a dense model."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..models.generation import paged_decode, paged_prefill, sample_logits
+
+    def with_load(tokens, load):
+        if load is None:
+            return tokens
+        return jnp.concatenate([
+            tokens, load.expert_tokens, load.experts_reached[None]])
+
+    def decode_step(params, cache, last_tok, active, rng):
+        # The chain a loop that split on the host would draw: the same
+        # seed, the same tokens.
+        rng, key = jax.random.split(rng)
+        logits, cache, load = paged_decode(
+            params, last_tok, cache, cfg, active=active
+        )
+        nxt = sample_logits(logits, key, temperature=temperature)
+        return with_load(nxt, load), cache, nxt, rng
+
+    def prefill(params, cache, last_tok, tokens, real_len, slot, pages):
+        logits, cache, load = paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages
+        )
+        nxt = sample_logits(logits, jax.random.PRNGKey(0),
+                            temperature=temperature)
+        return (cache, last_tok.at[slot].set(nxt[0]),
+                nxt[0] if load is None else with_load(nxt, load))
+
+    return decode_step, prefill
 
 
 class LLMEngine:
@@ -123,12 +207,7 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..models.generation import (
-            PagedKVCache,
-            paged_decode,
-            paged_prefill,
-            sample_logits,
-        )
+        from ..models.generation import PagedKVCache
         from ..models.llama import layer_runs
         from ..ops.paged_attention import decode_attention_path
 
@@ -178,7 +257,6 @@ class LLMEngine:
         # only adds them up: (pages held, each times its pool's layers;
         # what one table for every layer would hold).
         self._slot_held: Dict[int, tuple] = {}
-        self._last_tok = np.zeros((max_batch,), dtype=np.int32)
         self._queue: "queue.Queue[_Request]" = queue.Queue()
         self._waiting: List[_Request] = []  # admitted-but-no-pages
         self._lock = threading.Lock()
@@ -192,7 +270,7 @@ class LLMEngine:
         self._finished_rows: "collections.deque[List]" = collections.deque(
             maxlen=_REQUEST_ROWS)
         # Expert load of a MoE model, read back behind each program's
-        # tokens (``with_load`` below); None for a dense one.
+        # tokens (``serving_programs``); None for a dense one.
         self._moe: Optional[Dict[str, Any]] = None
         self._expert_layers = sum(
             run.n for run in layer_runs(cfg) if run.moe)
@@ -201,23 +279,6 @@ class LLMEngine:
                          "experts_reached": 0, "layer_steps": 0,
                          "prefill_experts_reached": 0,
                          "expert_tokens": np.zeros(cfg.n_experts, np.int64)}
-
-        def with_load(tokens, load):
-            """The tokens a program sampled and, for a MoE model, its
-            expert load behind them in the same int32 vector: one
-            read-back a step, as for a dense model (whose programs
-            return the tokens alone)."""
-            if load is None:
-                return tokens
-            return jnp.concatenate([
-                tokens, load.expert_tokens, load.experts_reached[None]])
-
-        def decode_step(params, cache, last_tok, active, key):
-            logits, cache, load = paged_decode(
-                params, last_tok, cache, cfg, active=active
-            )
-            nxt = sample_logits(logits, key, temperature=temperature)
-            return with_load(nxt, load), cache
 
         from ..util.device_metrics import instrumented_jit
 
@@ -230,19 +291,11 @@ class LLMEngine:
         # every 64 steps (and at every burst boundary — see _loop /
         # stats), not per token, so the executable cache is not polled
         # around every [B,1] decode step.
+        decode_step, prefill = serving_programs(cfg, temperature)
         self._decode = instrumented_jit(decode_step, donate_argnums=(1,),
                                         tap_stride=64)
-
-        def prefill(params, cache, tokens, real_len, slot, pages):
-            logits, cache, load = paged_prefill(
-                params, tokens, real_len, cache, cfg, slot, pages
-            )
-            nxt = sample_logits(logits, jax.random.PRNGKey(0),
-                                temperature=temperature)
-            return cache, (nxt[0] if load is None else with_load(nxt, load))
-
         self._prefill = instrumented_jit(prefill, donate_argnums=(1,))
-        self._rng = jax.random.PRNGKey(0)
+        self._new_carry()
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
@@ -303,15 +356,28 @@ class LLMEngine:
         ``prefills``, ``prefill_tokens`` (real) and
         ``prefill_bucket_tokens`` (padded to the bucket); ``submitted``,
         ``admitted``, ``finished``, ``failed`` (requests); ``cache_resets``;
-        ``page_waits`` (admission rounds that stopped for want of pages).
+        ``page_waits`` (admission rounds that stopped for want of pages);
+        ``decode_steps_ahead`` (decode steps dispatched while the step
+        before was still unread: over ``decode_steps``, how often the
+        loop ran ahead of its read-back; the first step after a lull or
+        a reset does not) and ``decode_slot_steps_discarded`` (the
+        part of ``decode_slot_steps`` thrown away: the one step already
+        queued for a slot when its ``eos_token`` was read). Every count
+        of a decode step advances when the step is read and emitted,
+        never at its dispatch: a reading taken with a step in flight
+        does not hold that step.
 
         ``phase_s``: seconds the loop thread has spent in each phase, from
         the ``perf_counter()`` boundaries that also delimit its
         ``engine.*`` profiler annotations: ``admit`` (admission rounds,
         prefills included), ``admit_stalling`` (the part of ``admit`` in
         rounds entered with a stream open, which all of them wait
-        through), ``inputs``, ``decode`` (the dispatch), ``readback`` (the
-        host waiting for the device), ``emit``, ``idle`` (the 2 ms poll).
+        through), ``inputs`` (who decodes next; the mask, when it
+        changed), ``decode`` (the dispatch of the next step),
+        ``readback`` (the host waiting for the step before it: the
+        device was given the next step first, so this is DEVICE-BOUND
+        waiting, about a step's time where the device sets the pace,
+        and no sign of a slow host), ``emit``, ``idle`` (the 2 ms poll).
 
         ``moe``, for a model with experts only: ``expert_tokens`` (a list
         of E: (token, expert) assignments each expert was given, prefills
@@ -374,6 +440,18 @@ class LLMEngine:
             self._table[kind] = np.zeros((self.max_batch, columns),
                                          dtype=np.int32)
 
+    def _new_carry(self):
+        """What a decode step takes from the one before, on the device,
+        as at the engine's start: last tokens, the PRNG key (a reset
+        draws the seed's stream again), nobody active, no step in
+        flight."""
+        jnp = self._jnp
+        self._last_tok = jnp.zeros((self.max_batch,), dtype=jnp.int32)
+        self._rng = self._jax.random.PRNGKey(0)
+        self._active = jnp.zeros((self.max_batch,), dtype=bool)
+        self._active_slots: frozenset = frozenset()
+        self._flying: Optional[_Step] = None
+
     def _pages_needed(self, req: _Request, bucket: int) -> Dict[str, int]:
         """Pages of each pool the request holds from admission to its
         end: its bucket's or its whole context's, whichever is more, and
@@ -388,7 +466,9 @@ class LLMEngine:
     def _reset_cache(self, cause: Exception):
         """Recover from a failed donated call: the old pool's buffers
         are gone, so rebuild a fresh cache and fail in-flight requests
-        with the root cause (they cannot be resumed without their KV)."""
+        with the root cause (they cannot be resumed without their KV).
+        A step in flight goes with them: what it returns may be the
+        failed call's."""
         from ..models.generation import PagedKVCache
 
         with self._lock:
@@ -408,6 +488,7 @@ class LLMEngine:
             self.cfg, self.max_batch, self.total_pages, self.page_size,
             self.max_pages_per_seq,
         )
+        self._new_carry()
 
     def _close(self, req: _Request, error: Optional[BaseException] = None):
         """End of a request, finished or failed: wake its waiters and
@@ -438,7 +519,9 @@ class LLMEngine:
 
     def _admit(self):
         """One admission round: prefill queued requests into free slots
-        until slots, pages or the queue run out."""
+        until slots, pages or the queue run out. A prefill queues
+        behind the decode step in flight and this thread waits for its
+        first token, so that step's tokens are emitted after it."""
         jnp = self._jnp
         counts = self._counts
         while self._slot_free:
@@ -491,8 +574,8 @@ class LLMEngine:
                                     in self._table.items()})
                     padded = req.prompt + [0] * (bucket - real_len)
                     tokens = jnp.asarray([padded], dtype=jnp.int32)
-                    self.cache, first = self._prefill(
-                        self.params, self.cache, tokens,
+                    self.cache, self._last_tok, first = self._prefill(
+                        self.params, self.cache, self._last_tok, tokens,
                         jnp.asarray(real_len, dtype=jnp.int32),
                         jnp.asarray(slot, dtype=jnp.int32),
                         prefill_pages,
@@ -516,8 +599,10 @@ class LLMEngine:
             req._live.put(first)
             with self._lock:
                 self._slot_req[slot] = req
-            self._last_tok[slot] = first
-            self._finish_if_done(slot, req, first)
+            # No step in flight decodes for a slot admitted after it.
+            if self._ended(req, first):
+                self._finish(slot, req)
+                self._release_slot(slot)
 
     def _tokens(self, out: np.ndarray, n: int, decode: bool) -> np.ndarray:
         """The ``n`` tokens at the head of a program's read-back; what a
@@ -539,25 +624,68 @@ class LLMEngine:
                     moe["prefill_experts_reached"] += int(out[-1])
         return out[:n]
 
-    def _finish_if_done(self, slot: int, req: _Request, tok: int):
-        if (len(req.output) >= req.max_new_tokens
-                or (req.eos_token is not None and tok == req.eos_token)):
-            with self._lock:
-                self._slot_req.pop(slot, None)
-            self._release_slot(slot)
-            self._close(req)
+    @staticmethod
+    def _ended(req: _Request, tok: int) -> bool:
+        return (len(req.output) >= req.max_new_tokens
+                or (req.eos_token is not None and tok == req.eos_token))
+
+    def _finish(self, slot: int, req: _Request):
+        """The request's end. Its slot and pages go back apart from it
+        (``_release_slot``): at once, or when a step in flight that
+        still writes into them has been read."""
+        with self._lock:
+            self._slot_req.pop(slot, None)
+        self._close(req)
+
+    def _emit(self, step: _Step, out: np.ndarray, queued: Optional[_Step]):
+        """A step's tokens to their requests, once read: the counters,
+        the finishes. ``queued`` is the step dispatched after it."""
+        counts = self._counts
+        self._step_count += 1
+        counts["decode_steps_ahead"] += step.ahead
+        counts["decode_slot_steps_discarded"] += len(step.dropped)
+        nxt = self._tokens(out, self.max_batch, decode=True)
+        counts["decode_slot_steps"] += len(step.slots)
+        # The step attended to each prompt and every token generated
+        # before this one: in a window layer to no more of them than
+        # the window.
+        contexts = [req.prompt_len + len(req.output)
+                    for req in step.slots.values()]
+        tokens = sum(contexts)
+        counts["decode_kv_tokens"] += tokens
+        counts["decode_kv_rows_read"] += sum(
+            layers * (tokens if kind != "window" else sum(
+                min(c, self.cfg.sliding_window) for c in contexts))
+            for kind, (layers, _, _) in self._pools.items())
+        for slot, req in step.slots.items():
+            held, one_table = self._slot_held[slot]
+            counts["kv_page_steps_held"] += held
+            counts["kv_page_steps_one_table"] += one_table
+            if slot in step.dropped:
+                self._release_slot(slot)
+                continue
+            tok = int(nxt[slot])
+            req.output.append(tok)
+            req._live.put(tok)
+            if self._ended(req, tok):
+                self._finish(slot, req)
+                if queued is not None and slot in queued.slots:
+                    # It ended on its eos_token, which no count foretold.
+                    queued.dropped.append(slot)
+                else:
+                    self._release_slot(slot)
 
     def _loop(self):
-        """Admit, then one decode step for every active slot. Each phase
-        is a profiler annotation (inert unless a ``jax.profiler`` trace
-        is open; then it lands in the trace's host plane, on the device
-        trace's clock) and, from the same ``perf_counter()`` boundaries,
-        a running total in ``phase_s``."""
+        """Admit, dispatch the next decode step, then read and emit the
+        one before it (the module docstring has the order and why it is
+        safe). Each phase is a profiler annotation (inert unless a
+        ``jax.profiler`` trace is open; then it lands in the trace's
+        host plane, on the device trace's clock) and, from the same
+        ``perf_counter()`` boundaries, a running total in ``phase_s``."""
         jnp = self._jnp
-        jax = self._jax
-        span = jax.profiler.TraceAnnotation
+        span = self._jax.profiler.TraceAnnotation
         clock = time.perf_counter
-        counts, phase_s = self._counts, self._phase_s
+        phase_s = self._phase_s
         t = clock()
 
         def lap(phase: str) -> float:
@@ -576,62 +704,53 @@ class LLMEngine:
             dt = lap("admit")
             if stalling:
                 phase_s["admit_stalling"] += dt
-            with self._lock:
-                active_slots = dict(self._slot_req)
-            if not active_slots:
-                # Burst boundary: the decode loop went idle — flush the
-                # batched metric taps accumulated over the burst.
-                self._decode.flush_taps()
-                time.sleep(0.002)
-                lap("idle")
-                continue
-            with span("engine.inputs"):
-                active = np.zeros((self.max_batch,), dtype=bool)
-                for s in active_slots:
-                    active[s] = True
-                self._rng, key = jax.random.split(self._rng)
-                last_tok = jnp.asarray(self._last_tok)
-                active = jnp.asarray(active)
-            lap("inputs")
+            flying = self._flying
+            # Who decodes next: every open slot short of its count, the
+            # token in flight included.
+            pending = flying.slots if flying else ()
+            slots = {slot: req for slot, req in self._slot_req.items()
+                     if len(req.output) + (slot in pending)
+                     < req.max_new_tokens}
+            queued = None
             try:
-                with span("engine.decode"):
-                    nxt, self.cache = self._decode(
-                        self.params, self.cache, last_tok, active, key)
+                if slots:
+                    with span("engine.inputs"):
+                        if slots.keys() != self._active_slots:
+                            active = np.zeros((self.max_batch,), dtype=bool)
+                            active[list(slots)] = True
+                            self._active = jnp.asarray(active)
+                            self._active_slots = frozenset(slots)
+                    lap("inputs")
+                    with span("engine.decode"):
+                        out, self.cache, self._last_tok, self._rng = \
+                            self._decode(self.params, self.cache,
+                                         self._last_tok, self._active,
+                                         self._rng)
+                        out.copy_to_host_async()
+                    lap("decode")
+                    queued = _Step(out, slots, ahead=flying is not None)
+                if flying is not None:
+                    with span("engine.readback"):
+                        out = np.asarray(flying.out)
+                    lap("readback")
             except Exception as e:  # noqa: BLE001
                 # The cache was donated into the failed call — recover
                 # like the prefill path: rebuild the pool, fail in-flight
                 # requests cleanly, keep the loop alive for new work.
                 self._reset_cache(e)
                 continue
-            lap("decode")
-            with span("engine.readback"):
-                nxt = np.asarray(nxt)
-            lap("readback")
-            with span("engine.emit"):
-                self._step_count += 1
-                nxt = self._tokens(nxt, self.max_batch, decode=True)
-                counts["decode_slot_steps"] += len(active_slots)
-                # The step attended to each prompt and every token
-                # generated before this one: in a window layer to no
-                # more of them than the window.
-                contexts = [req.prompt_len + len(req.output)
-                            for req in active_slots.values()]
-                tokens = sum(contexts)
-                counts["decode_kv_tokens"] += tokens
-                counts["decode_kv_rows_read"] += sum(
-                    layers * (tokens if kind != "window" else sum(
-                        min(c, self.cfg.sliding_window) for c in contexts))
-                    for kind, (layers, _, _) in self._pools.items())
-                for slot, req in active_slots.items():
-                    held, one_table = self._slot_held[slot]
-                    counts["kv_page_steps_held"] += held
-                    counts["kv_page_steps_one_table"] += one_table
-                    tok = int(nxt[slot])
-                    req.output.append(tok)
-                    req._live.put(tok)
-                    self._last_tok[slot] = tok
-                    self._finish_if_done(slot, req, tok)
-            lap("emit")
+            self._flying = queued
+            if flying is not None:
+                with span("engine.emit"):
+                    self._emit(flying, out, queued)
+                lap("emit")
+            elif queued is None:
+                # Burst boundary: the decode loop went idle, the last
+                # step read — flush the batched metric taps accumulated
+                # over the burst.
+                self._decode.flush_taps()
+                time.sleep(0.002)
+                lap("idle")
 
 
 class LLMDeployment:

@@ -274,6 +274,268 @@ def test_engine_counts_expert_load_for_a_moe_model_only(tiny_model,
         engine.shutdown()
 
 
+# ---- the loop one decode step ahead of its read-back (PR 39) ----------------
+
+def _hold_admission(engine):
+    """Stop the loop at the top of its next turn; ``.set()`` what is
+    returned to let it go. Requests submitted meanwhile are all in the
+    queue when admission next runs: a schedule that does not depend on
+    when each ``submit`` returned."""
+    import threading
+
+    entered, gate = threading.Event(), threading.Event()
+    admit = engine._admit
+
+    def gated():
+        entered.set()
+        gate.wait()
+        return admit()
+
+    engine._admit = gated
+    assert entered.wait(60)
+    return gate
+
+
+def _four_requests():
+    """(prompts, new tokens): lengths on both sides of a page edge, four
+    different counts, more requests than the engine below has slots."""
+    rng = np.random.RandomState(11)
+    news = [10, 14, 3, 6]
+    return [list(rng.randint(0, 256, n)) for n in (5, 20, 17, 9)], news
+
+
+def test_engine_a_step_ahead_serves_naive_greedys_tokens(tiny_model,
+                                                         naive_greedy):
+    """Step k+1 is queued before step k's tokens are read, in most
+    steps, and nobody's tokens change: a slot that ends by its count is
+    out of the step queued next, a waiting request takes it after."""
+    cfg, params = tiny_model
+    prompts, news = _four_requests()
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=64)
+    try:
+        gate = _hold_admission(engine)
+        reqs = [engine.submit(p, n) for p, n in zip(prompts, news)]
+        gate.set()
+        outs = [r.result(timeout=180) for r in reqs]
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    assert outs == [naive_greedy(params, p, cfg, n)
+                    for p, n in zip(prompts, news)]
+    assert stats["decode_slot_steps"] == sum(news) - 4
+    assert stats["decode_steps_ahead"] / stats["decode_steps"] > 0.5
+    assert stats["decode_slot_steps_discarded"] == 0
+
+
+def _host_split_chain(params, cfg, prompts, news, slots, temperature):
+    """What a loop that reads every step before the next, and splits
+    ``PRNGKey(0)`` on the host once a step, serves at ``temperature``:
+    in a straight line, every token from the full ``forward`` with
+    nothing cached. One rule is the engine's own: a slot freed by step
+    j's tokens is taken in the turn after step j+1 was formed."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import forward
+    from ray_tpu.models.generation import sample_logits
+
+    last_logits = jax.jit(lambda params, row, n: forward(
+        params, row[None], cfg)[0][0, n - 1])
+
+    def logits(seq):
+        row = np.zeros(64, np.int32)
+        row[:len(seq)] = seq
+        return last_logits(params, jnp.asarray(row), len(seq))
+
+    outs = [[] for _ in prompts]
+    queue, free, held, freed = list(range(len(prompts))), \
+        list(range(slots)), {}, []
+    rng = jax.random.PRNGKey(0)
+    while queue or held:
+        while free and queue:
+            i, slot = queue.pop(0), free.pop()
+            outs[i].append(int(sample_logits(
+                logits(prompts[i])[None], jax.random.PRNGKey(0),
+                temperature=temperature)[0]))
+            held[slot] = i
+        free, freed = free + freed, []
+        if not held:
+            continue
+        rng, key = jax.random.split(rng)
+        batch = jnp.zeros((slots, cfg.vocab_size), jnp.float32)
+        for slot, i in held.items():
+            batch = batch.at[slot].set(logits(prompts[i] + outs[i]))
+        nxt = sample_logits(batch, key, temperature=temperature)
+        for slot, i in list(held.items()):
+            outs[i].append(int(nxt[slot]))
+            if len(outs[i]) == news[i]:
+                freed.append(slot)
+                del held[slot]
+    return outs
+
+
+def test_sampling_engine_draws_the_host_split_chain(tiny_model):
+    """The key is split inside the decode program and stays on the
+    device; the stream is the one the host-side split drew."""
+    cfg, params = tiny_model
+    prompts, news = _four_requests()
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=64,
+                       temperature=0.7)
+    try:
+        gate = _hold_admission(engine)
+        reqs = [engine.submit(p, n) for p, n in zip(prompts, news)]
+        gate.set()
+        outs = [r.result(timeout=180) for r in reqs]
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    expected = _host_split_chain(params, cfg, prompts, news, 2, 0.7)
+    assert outs == expected
+    greedy = _host_split_chain(params, cfg, prompts, news, 2, 0.0)
+    assert outs != greedy            # it did sample
+    assert stats["decode_steps_ahead"] / stats["decode_steps"] > 0.5
+
+
+def test_eos_drops_the_step_in_flight_and_the_next_tenant_decodes_right(
+        tiny_model, naive_greedy):
+    """An ``eos_token`` is known only at the read-back: the step
+    already queued for the slot is thrown away (counted), nothing is
+    emitted after the token, and slot and pages come back behind that
+    step, to a tenant that decodes correctly from them."""
+    cfg, params = tiny_model
+    for seed in range(20):
+        prompt = list(np.random.RandomState(seed).randint(0, 256, 7))
+        expected = naive_greedy(params, prompt, cfg, 8)
+        if expected[2] not in expected[:2]:
+            break
+    engine = LLMEngine(cfg, params, max_batch=1, max_len=32, total_pages=2)
+    try:
+        req = engine.submit(prompt, 8, eos_token=expected[2])
+        assert list(req.tokens(timeout=120)) == expected[:3]
+        assert req.result(timeout=1) == expected[:3]
+        _wait_until(lambda: engine.stats()["free_pages"] == 2)
+        stats = engine.stats()
+        assert stats["decode_slot_steps_discarded"] == 1
+        # Two served, one thrown away: the device ran all three.
+        assert stats["decode_slot_steps"] == stats["decode_steps"] == 3
+        assert (stats["finished"], stats["active_slots"],
+                stats["free_slots"]) == (1, 0, 1)
+        # Both pages again: 20 + 12 fill them to the last row.
+        tenant = list(np.random.RandomState(99).randint(0, 256, 20))
+        assert engine.generate(tenant, 12, timeout=120) == \
+            naive_greedy(params, tenant, cfg, 12)
+        assert engine.stats()["decode_slot_steps_discarded"] == 1
+    finally:
+        engine.shutdown()
+
+
+def test_no_step_writes_past_the_pages_a_slot_holds(tiny_model,
+                                                    naive_greedy):
+    """A request whose prompt and answer end exactly on a page edge
+    ends by its count while the other slot holds page 0, which is what
+    a column past a slot's pages reads: it is out of the step queued
+    behind its last one, its length stops a row short of the edge, and
+    the other slot's tokens are its solo run's."""
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=32, page_size=16,
+                       total_pages=3)
+    try:
+        # Pages are handed out from the list's end: the first request
+        # gets page 2, the second pages 1 and 0.
+        assert engine._free_pages["full"] == [0, 1, 2]
+        rng = np.random.RandomState(5)
+        edge, other = list(rng.randint(0, 256, 10)), \
+            list(rng.randint(0, 256, 20))
+        gate = _hold_admission(engine)
+        reqs = [engine.submit(edge, 6), engine.submit(other, 12)]
+        gate.set()
+        outs = [r.result(timeout=180) for r in reqs]
+        _wait_until(lambda: engine.stats()["free_pages"] == 3)
+        assert outs == [naive_greedy(params, edge, cfg, 6),
+                        naive_greedy(params, other, cfg, 12)]
+        # Rows written: the prompt's and every token's but the last.
+        assert sorted(np.asarray(engine.cache.lengths).tolist()) == [
+            10 + 5, 20 + 11]
+        assert engine.stats()["decode_slot_steps"] == 5 + 11
+    finally:
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("at", ["lull", "shutdown"])
+def test_moe_counters_are_whole_with_a_step_in_flight(tiny_model, at):
+    """The expert load is read a step behind the dispatch: at a lull
+    the last step has been read, at a shutdown the step in flight is in
+    no counter, so the counters agree with each other whenever read."""
+    cfg = LlamaConfig.tiny(moe=True)
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    engine = LLMEngine(cfg, params, max_batch=4, max_len=64)
+    try:
+        reqs = [engine.submit([1, 2, 3 + i], 50 if at == "shutdown" else 7)
+                for i in range(3)]
+        if at == "lull":
+            for r in reqs:
+                r.result(timeout=180)
+            _wait_until(lambda: engine.stats()["active_slots"] == 0)
+        else:
+            _wait_until(lambda: engine.stats()["decode_steps"] >= 5)
+    finally:
+        engine.shutdown()
+    stats = engine.stats()
+    moe = stats["moe"]
+    assert stats["decode_steps_ahead"] > 0
+    if at == "lull":
+        assert stats["decode_slot_steps"] == 3 * 6
+    assert moe["layer_steps"] == stats["decode_steps"] * cfg.num_layers
+    assert moe["decode_assignments"] == \
+        stats["decode_slot_steps"] * cfg.top_k * cfg.num_layers
+    assert moe["assignments"] == (
+        stats["prefill_tokens"] + stats["decode_slot_steps"]
+    ) * cfg.top_k * cfg.num_layers
+
+
+@pytest.mark.parametrize("failing", ["dispatch", "readback"])
+def test_a_failed_decode_with_a_step_in_flight_fails_each_request_once(
+        tiny_model, naive_greedy, failing):
+    """The third decode step fails, at its dispatch or when its tokens
+    are read, with the second in flight or read: one cache reset, each
+    open request failed once, and the engine serves the next one."""
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=64)
+    real, calls = engine._decode, []
+
+    class Unreadable:
+        def copy_to_host_async(self):
+            pass
+
+        def __array__(self, *args, **kwargs):
+            raise RuntimeError("decode fell over")
+
+    def flaky(*args):
+        calls.append(1)
+        if len(calls) == 3 and failing == "dispatch":
+            raise RuntimeError("decode fell over")
+        out, *carry = real(*args)
+        return (Unreadable() if len(calls) == 3 else out, *carry)
+
+    flaky.flush_taps = real.flush_taps
+    try:
+        engine._decode = flaky
+        gate = _hold_admission(engine)
+        reqs = [engine.submit([1, 2, 3], 20), engine.submit([4, 5], 20)]
+        gate.set()
+        for req in reqs:
+            with pytest.raises(RuntimeError, match="fell over"):
+                req.result(timeout=120)
+        stats = engine.stats()
+        assert (stats["failed"], stats["cache_resets"]) == (2, 1)
+        assert stats["active_slots"] == 0 and stats["free_slots"] == 2
+        assert stats["free_pages"] == stats["total_pages"]
+        assert engine.generate([7, 8, 9], 8, timeout=120) == \
+            naive_greedy(params, [7, 8, 9], cfg, 8)
+        assert engine.stats()["finished"] == 1
+    finally:
+        engine.shutdown()
+
+
 # ---- a model with window layers: one allocator, a pool a kind (PR 38) ------
 
 @pytest.fixture(scope="module")

@@ -86,8 +86,11 @@ def test_late_requests_wait_for_a_slot_and_admission_stalls_streams(
         stats = engine.stats()
     finally:
         engine.shutdown()
-    assert before["phase_s"]["admit_stalling"] == 0.0
-    assert stats["phase_s"]["admit_stalling"] > 0.0
+    # One stream alone stalls behind no prefill: only the empty round
+    # entered while its last token was in flight.
+    assert before["phase_s"]["admit_stalling"] < 1e-3
+    assert stats["phase_s"]["admit_stalling"] \
+        > 2 * before["phase_s"]["admit_stalling"]
     waits = _admit_waits(stats)[-4:]
     assert min(waits[2:]) > max(waits[:2])
 

@@ -268,14 +268,56 @@ def _fits_one_chip(compiled):
 
 
 def test_olmoe_decode_program_compiles_for_v5e(v5e, as_tpu):
-    """The chat cell's decode step with the grouped expert matmuls in
-    the layer scan: 32 slots x 8 experts a token are 256 rows. The page
-    walk at ``Hkv`` 16 leaves this pool in place too."""
+    """``serve-olmoe-c16``'s decode step with the grouped expert matmuls
+    in the layer scan: 32 slots x 8 experts a token are 256 rows. The
+    page walk at ``Hkv`` 16 leaves this pool in place too."""
     compiled, pool = _decode_program(_olmoe_cfg(), v5e, CHAT_CELL[0],
                                      CHAT_POOL_PAGES, CHAT_CELL[1])
     assert "tpu_custom_call" in compiled.as_text()  # the page walk
     assert _fits_one_chip(compiled)
     _assert_pool_stays_in_place(compiled, pool)
+
+
+# What would take a step's inputs or outputs through the host.
+_HOST_OPS = {"send", "send-done", "recv", "recv-done", "infeed", "outfeed"}
+
+
+@pytest.mark.parametrize("model,temperature", [
+    ("dense", 0.0), ("olmoe", 0.0), ("dense", 0.7)])
+def test_engine_decode_program_carries_tokens_and_key_on_the_device(
+        v5e, as_tpu, model, temperature):
+    """The program the engine jits (``serve/llm.py:serving_programs``)
+    at the chat cells' engine shapes: it takes the last tokens, the
+    active mask and the PRNG key and returns the last tokens and the
+    key for the call after it beside the read-back (for a MoE model the
+    expert load behind the tokens), so that the loop can queue step k+1
+    before it has read step k. Around ``paged_decode`` the pool still
+    stays in place (the PR 29 guard), and nothing in it calls the host."""
+    from ray_tpu.serve.llm import serving_programs
+
+    cfg = _olmoe_cfg() if model == "olmoe" else _serve_cfg()
+    batch, pages_per_seq = CHAT_CELL
+    params, cache = _serve_shapes(cfg, v5e, batch, CHAT_POOL_PAGES,
+                                  pages_per_seq)
+    decode_step, _ = serving_programs(cfg, temperature)
+    args = (params, cache, _arr(v5e, (batch,), jnp.int32),
+            _arr(v5e, (batch,), jnp.bool_), _arr(v5e, (2,), jnp.uint32))
+    readback, _, last_tok, rng = jax.eval_shape(decode_step, *args)
+    extra = cfg.n_experts + 1 if cfg.n_experts else 0
+    assert (readback.shape, readback.dtype) == ((batch + extra,), jnp.int32)
+    assert (last_tok.shape, last_tok.dtype) == ((batch,), jnp.int32)
+    assert (rng.shape, rng.dtype) == ((2,), jnp.uint32)
+
+    compiled = jax.jit(decode_step, donate_argnums=(1,)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the page walk
+    pool = cache.k["full"].shape
+    _assert_pool_stays_in_place(compiled, pool)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * 2 * math.prod(pool)
+    assert "callback" not in text.lower()
+    assert not _HOST_OPS & {m["op"] for m in _HLO_INSTRUCTION.finditer(text)}
 
 
 def test_olmoe_prefill_program_compiles_for_v5e(v5e, as_tpu):
