@@ -36,9 +36,9 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Optional
+from typing import List, Optional
 
-from ..util.metrics import Counter, Histogram
+from ..util.metrics import Counter, Histogram, ItemTally
 from .ids import ObjectID, TaskID
 from .reference import ObjectRef
 
@@ -46,9 +46,25 @@ from .reference import ObjectRef
 # (0x8000_0000 block).
 STREAM_BASE = 0x4000_0000
 
-# Consumer-side delivery accounting. A blocked share near 100% with waits
-# near the producer's cadence is a consumer that keeps up; a low blocked
-# share is a consumer that lags (items were waiting for it).
+# Delivery accounting at both ends of a stream, in the process of each.
+# No item's path calls the metrics registry: a stream adds up its own
+# counts and seconds and records them every ``ItemTally.FLUSH_ITEMS``
+# items and when it ends (or is abandoned).
+#
+# Producer side: what a seal costs the producing thread (serialize,
+# store put, the ``put`` frame to the node manager).
+STREAM_ITEMS_SEALED = Counter(
+    "ray_tpu_stream_items_sealed_total",
+    "Streaming-generator items sealed by their producer.",
+)
+STREAM_ITEM_SEAL_S = Counter(
+    "ray_tpu_stream_item_seal_seconds_total",
+    "Time producers spent sealing items (serialize, store put, send).",
+)
+# Consumer side. A blocked share near 100% with waits near the producer's
+# cadence is a consumer that keeps up; a low blocked share is a consumer
+# that lags (items were waiting for it), and what an item then costs it
+# is the probe (a node-manager round trip that finds the item sealed).
 STREAM_ITEMS = Counter(
     "ray_tpu_stream_items_total",
     "Streaming-generator items handed to a consumer.",
@@ -62,6 +78,12 @@ STREAM_ITEM_WAIT = Histogram(
     "Time a consumer spent blocked until the item it asked for sealed.",
     boundaries=[0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
                 1.0, 2.5, 5.0, 10.0],
+)
+
+STREAM_ITEM_PROBE_S = Counter(
+    "ray_tpu_stream_item_probe_seconds_total",
+    "Time consumers spent asking whether the next item is sealed yet "
+    "(the non-blocking look before a parked wait).",
 )
 
 # How many item ids an abandoned stream's release asks about at once.
@@ -104,6 +126,10 @@ class ObjectRefGenerator:
         self._released = False
         # Optional per-item production deadline (serve SSE guard).
         self.item_timeout_s = None
+        # Items handed over and the probes' seconds, and the blocked
+        # waits, since they were last recorded (``_record_delivery``).
+        self._tally = ItemTally(STREAM_ITEMS, STREAM_ITEM_PROBE_S)
+        self._waits: List[float] = []
 
     @property
     def completed(self) -> ObjectRef:
@@ -118,9 +144,16 @@ class ObjectRefGenerator:
 
         rt = current_runtime()
         if self._count is not None and self._next >= self._count:
+            _record_delivery(self._tally, self._waits)
             raise StopIteration
         item = stream_item_id(self._task_id, self._next)
-        if not self._await_item(rt, item):
+        try:
+            sealed = self._await_item(rt, item)
+        except BaseException:
+            _record_delivery(self._tally, self._waits)
+            raise
+        if not sealed:
+            _record_delivery(self._tally, self._waits)
             if self._retriable:
                 _drop_retry_record(rt, self._task_id)
             raise StopIteration
@@ -140,11 +173,15 @@ class ObjectRefGenerator:
         after ``item_timeout_s`` without either."""
         import ray_tpu
 
+        clock = time.perf_counter
         ids = [item, self._completion_ref.id()]
+        probe_s = 0.0
         while True:
+            t0 = clock()
             ready = rt._wait(ids, 1, 0)
+            t1 = clock()
+            probe_s += t1 - t0
             if not ready:
-                t0 = time.monotonic()
                 ready = rt._wait(ids, 1, self.item_timeout_s)
                 if not ready:
                     # A wedged producer must not hold consumers (serve
@@ -156,10 +193,10 @@ class ObjectRefGenerator:
                         f"{self.item_timeout_s}s"
                     )
                 if item in ready:
-                    STREAM_ITEMS_BLOCKED.inc()
-                    STREAM_ITEM_WAIT.observe(time.monotonic() - t0)
+                    self._waits.append(clock() - t1)
             if item in ready:
-                STREAM_ITEMS.inc()
+                if self._tally.item(probe_s):
+                    _record_delivery(self._tally, self._waits)
                 return True
             # The task is over and this item is not sealed here: finished
             # (the count says whether the item exists) or failed (get
@@ -194,7 +231,8 @@ class ObjectRefGenerator:
             self._released = True
             threading.Thread(
                 target=_release_abandoned_stream,
-                args=(rt, self._task_id, self._next, self._retriable),
+                args=(rt, self._task_id, self._next, self._retriable,
+                      self._tally, self._waits),
                 name="stream-gc",
                 daemon=True,
             ).start()
@@ -204,6 +242,16 @@ class ObjectRefGenerator:
     def __repr__(self):
         return (f"ObjectRefGenerator(task={self._task_id.hex()[:8]}, "
                 f"next={self._next})")
+
+
+def _record_delivery(tally: ItemTally, waits: List[float]) -> None:
+    """A consumer's counts since they were last recorded, to the metrics
+    registry: items and probe seconds, and the blocked waits."""
+    tally.flush()
+    if waits:
+        STREAM_ITEMS_BLOCKED.inc(len(waits))
+        STREAM_ITEM_WAIT.observe_many(waits)
+        waits.clear()
 
 
 def _write_retry_record(rt, task_id: TaskID, position: int) -> None:
@@ -222,11 +270,13 @@ def _drop_retry_record(rt, task_id: TaskID) -> None:
         pass
 
 
-def _release_abandoned_stream(rt, task_id, next_idx: int,
-                              retriable: bool) -> None:
-    """Off-thread body of ObjectRefGenerator.__del__ (see there): the
-    sealed items from ``next_idx`` on, asked for a window at a time."""
+def _release_abandoned_stream(rt, task_id, next_idx: int, retriable: bool,
+                              tally: ItemTally, waits: List[float]) -> None:
+    """Off-thread body of ObjectRefGenerator.__del__ (see there): what
+    the consumer counted and had not recorded, then the sealed items
+    from ``next_idx`` on, asked for a window at a time."""
     try:
+        _record_delivery(tally, waits)
         while True:
             window = [stream_item_id(task_id, next_idx + k)
                       for k in range(_RELEASE_WINDOW)]

@@ -1050,12 +1050,25 @@ class Worker:
         def store_large(oid: ObjectID, sobj: SerializedObject) -> Location:
             return rt.store.put_serialized(oid, sobj)
 
+        import time as _time
+
+        seals = None
+        if spec.streaming:
+            from ..util.metrics import ItemTally
+            from .streaming import STREAM_ITEM_SEAL_S, STREAM_ITEMS_SEALED
+
+            # Items this task seals and the seconds that takes its
+            # thread, recorded every ItemTally.FLUSH_ITEMS items and
+            # when the task ends.
+            seals = ItemTally(STREAM_ITEMS_SEALED, STREAM_ITEM_SEAL_S)
+
         def stream_item(index: int, value):
             """Seal one streamed yield: the seal is what wakes the
             consumer (see core/streaming.py for the protocol)."""
             from .serialization import serialize_with_refs as _ser_refs
             from .streaming import consumed_upto, stream_item_id
 
+            sealing = _time.perf_counter()
             # A retried attempt re-runs the generator from its start:
             # what the consumer already took is not sealed, and so not
             # pinned, a second time (the retry record). A first attempt
@@ -1077,12 +1090,11 @@ class Worker:
             if nested:
                 msg["nested"] = nested
             self.conn.send(msg)
+            seals.item(_time.perf_counter() - sealing)
 
         rt.current_task_id = spec.task_id
         if spec.task_type in (TaskType.ACTOR_CREATION_TASK, TaskType.ACTOR_TASK):
             rt.current_actor_id = spec.actor_id
-        import time as _time
-
         from .timeline import enter_span, exit_span, new_span_id
 
         ctx = getattr(spec, "trace_ctx", None)
@@ -1111,6 +1123,8 @@ class Worker:
         finally:
             rt.current_task_id = None
             exit_span(prev_span)
+            if seals is not None:
+                seals.flush()
             try:
                 from .timeline import get_buffer
 

@@ -17,7 +17,13 @@ KV pipeline — ``util/prometheus.render()`` then exposes them unchanged:
   wait measured at the replica;
 - ``ray_tpu_serve_replica_processing_seconds{deployment,method}`` user
   code execution time, and
-  ``ray_tpu_serve_replica_ongoing_requests{deployment}``.
+  ``ray_tpu_serve_replica_ongoing_requests{deployment}``;
+- ``ray_tpu_serve_stream_items_total{deployment,hop}`` and
+  ``ray_tpu_serve_stream_item_seconds_total{deployment,hop}`` the items
+  of streamed replies and what each cost the consumer's thread at
+  ``hop`` ``fetch`` (the handle's ``get`` of a sealed item) and
+  ``write`` (the proxy's SSE write and flush), recorded in batches by
+  ``stream_tally`` and never a call an item.
 """
 
 from __future__ import annotations
@@ -25,7 +31,7 @@ from __future__ import annotations
 import time
 from typing import Optional
 
-from ..util.metrics import Counter, Gauge, Histogram
+from ..util.metrics import Counter, Gauge, Histogram, ItemTally
 
 # Prometheus' default latency buckets: sub-5ms cache hits through
 # multi-second LLM generations land in distinct buckets.
@@ -107,6 +113,25 @@ RETRIES_TOTAL = Counter(
     "Handle-level request retries spent from the retry budget.",
     tag_keys=("deployment",),
 )
+STREAM_ITEMS = Counter(
+    "ray_tpu_serve_stream_items_total",
+    "Items of streamed replies that passed one hop of the consumer's "
+    "side: `fetch` (handle) or `write` (proxy SSE).",
+    tag_keys=("deployment", "hop"),
+)
+STREAM_ITEM_SECONDS = Counter(
+    "ray_tpu_serve_stream_item_seconds_total",
+    "Time the consumer's thread spent on streamed items at one hop.",
+    tag_keys=("deployment", "hop"),
+)
+
+
+def stream_tally(deployment: str, hop: str) -> ItemTally:
+    """The account of one streamed reply at ``hop``: its thread adds
+    each item's seconds to it and flushes it when the stream ends."""
+    tags = {"deployment": deployment or "anonymous", "hop": hop}
+    return ItemTally(STREAM_ITEMS.with_tags(**tags),
+                     STREAM_ITEM_SECONDS.with_tags(**tags))
 
 
 def observe_ingress(deployment: str, protocol: str, code,

@@ -668,11 +668,23 @@ class DeploymentHandle:
         Routing (p2c, model affinity, dead-replica retry) happens on the
         first item; once a replica has started yielding, a mid-stream
         death surfaces to the caller rather than silently replaying
-        side effects."""
+        side effects.
+
+        Accounted on the consumer's thread, off the metrics registry
+        until the stream ends (``_telemetry.stream_tally``): what
+        fetching each sealed item cost. And one ``core/timeline`` span,
+        ``stream.deliver``, under the consumer's active span: from the
+        first item handed over to the consumer coming back from the
+        last (it has then written it). Beside the replica's ``engine.*``
+        spans of the same trace it shows how long delivery went on after
+        the engine was done (one host's ``time.time()``; a consumer on
+        another node adds that node's clock skew)."""
         import ray_tpu
         from ray_tpu.core.exceptions import OverloadedError
 
+        from ..core.timeline import record_span
         from ..util import overload
+        from . import _telemetry
 
         model_id = self._model_id
         state = self._state
@@ -687,6 +699,9 @@ class DeploymentHandle:
         # Mirror of _route_with_retry: only post-submit retries charge
         # the budget; empty-set refreshes are free.
         needs_budget = False
+        clock = time.perf_counter
+        fetches = _telemetry.stream_tally(state.deployment_name, "fetch")
+        first_at = last_at = None
         while attempt <= MAX_DEATH_RETRIES:
             if needs_budget and not _spend_retry(state, deadline_ts):
                 break
@@ -696,8 +711,6 @@ class DeploymentHandle:
                 )
             except (RuntimeError, OverloadedError) as e:
                 if isinstance(e, OverloadedError):
-                    from . import _telemetry
-
                     _telemetry.observe_shed(
                         state.deployment_name, "router"
                     )
@@ -726,9 +739,14 @@ class DeploymentHandle:
                             item_timeout,
                             max(0.0, deadline_ts - time.time()) + 1.0,
                         )
+                    fetching = clock()
                     value = ray_tpu.get(ref, timeout=item_timeout)
+                    fetches.item(clock() - fetching)
                     started = True
+                    if first_at is None:
+                        first_at = last_at = time.time()
                     yield value
+                    last_at = time.time()
                 state.record_result(replica, True,
                                     time.monotonic() - t0)
                 return
@@ -771,6 +789,9 @@ class DeploymentHandle:
                 raise
             finally:
                 state.end(replica)
+                fetches.flush()
+                if first_at is not None:
+                    record_span("stream.deliver", first_at, last_at)
         raise last_err if last_err is not None else RuntimeError(
             f"deployment {state.deployment_name!r}: streaming retries "
             f"exhausted"

@@ -175,7 +175,13 @@ class _Handler(BaseHTTPRequestHandler):
     def _stream_reply(self, handle, arg):
         """Server-sent events: one `data:` frame per item the replica's
         generator yields, flushed as produced (ref analogue: proxy.py
-        RESPONSE_STREAMING over ASGI; `curl -N` shows tokens live)."""
+        RESPONSE_STREAMING over ASGI; `curl -N` shows tokens live).
+        What the frames cost this thread is added up here and recorded
+        when the stream ends (``_telemetry.stream_tally``)."""
+        from . import _telemetry
+
+        clock = time.perf_counter
+        writes = _telemetry.stream_tally(handle.deployment_name, "write")
         self.send_response(200)
         self.send_header("Content-Type", "text/event-stream")
         self.send_header("Cache-Control", "no-cache")
@@ -183,10 +189,12 @@ class _Handler(BaseHTTPRequestHandler):
         self.end_headers()
         try:
             for item in handle.stream(arg):
+                writing = clock()
                 self.wfile.write(
                     f"data: {json.dumps(item)}\n\n".encode()
                 )
                 self.wfile.flush()
+                writes.item(clock() - writing)
             self.wfile.write(b"event: end\ndata: null\n\n")
             self.wfile.flush()
         except BrokenPipeError:
@@ -199,6 +207,8 @@ class _Handler(BaseHTTPRequestHandler):
                 self.wfile.flush()
             except Exception:
                 pass
+        finally:
+            writes.flush()
 
     def do_OPTIONS(self):  # noqa: N802 — stdlib API
         self.do_POST()

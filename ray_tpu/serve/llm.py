@@ -58,12 +58,55 @@ from typing import Any, Dict, Iterator, List, Optional
 import numpy as np
 
 
+class _Streams:
+    """Who takes tokens from the engine's requests, for
+    ``LLMEngine.stats()["stream"]``: the ``_Request.tokens()`` iterators
+    now running, and the sums of those that have ended. A request's own
+    account is written by its taking thread alone; the lock is taken
+    when an iterator starts, when it ends, and by a reading."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.running: set = set()
+        self.ended = {"tokens_taken": 0, "taken_lag_s": 0.0, "held_s": 0.0}
+
+    def begin(self, req: "_Request") -> None:
+        with self.lock:
+            self.running.add(req)
+
+    def end(self, req: "_Request") -> None:
+        with self.lock:
+            if req in self.running:
+                self.running.remove(req)
+                for key, value in req.taken_account().items():
+                    self.ended[key] += value
+
+    def read(self) -> Dict[str, Any]:
+        """The sums over ended and running iterators, and ``backlog``:
+        tokens emitted to a running iterator that it has not taken."""
+        with self.lock:
+            out = dict(self.ended)
+            backlog = 0
+            for req in self.running:
+                account = req.taken_account()
+                for key, value in account.items():
+                    out[key] += value
+                backlog += max(
+                    0, len(req.output) - account["tokens_taken"])
+        out["backlog"] = backlog
+        return out
+
+
 class _Request:
     def __init__(self, prompt: List[int], max_new_tokens: int,
-                 eos_token: Optional[int]):
+                 eos_token: Optional[int], request_id: Any,
+                 streams: _Streams):
         self.prompt = list(prompt)
         self.max_new_tokens = max_new_tokens
         self.eos_token = eos_token
+        # The caller's name for the request (an operator's request id),
+        # carried to its ``stats()["requests"]`` row.
+        self.id = request_id
         self.output: List[int] = []
         self.done = threading.Event()
         self.error: Optional[BaseException] = None
@@ -76,9 +119,22 @@ class _Request:
         self.t_done: Optional[float] = None
         self.prompt_len = len(self.prompt)
         self.bucket: Optional[int] = None
-        # Incremental consumers (token streaming) read from here; None is
-        # the end-of-stream sentinel.
-        self._live: "queue.Queue[Optional[int]]" = queue.Queue()
+        # Incremental consumers (token streaming) read from here: each
+        # token with the ``time.time()`` at which the loop put it; None
+        # is the end-of-stream sentinel.
+        self._live: "queue.Queue[Optional[tuple]]" = queue.Queue()
+        # The way back, accounted by the thread that runs ``tokens()``
+        # and written by it alone: tokens taken, seconds from emitted to
+        # taken, seconds from handing a token over to being asked for
+        # the next, and when the consumer came back after the last one.
+        self._streams = streams
+        self.taken = 0
+        self.taken_lag_s = 0.0
+        self.held_s = 0.0
+        self.t_last_put: Optional[float] = None
+        # The row ``_close`` kept for stats(): ``t_last_put`` is known
+        # only later, and is written into it in place.
+        self._row: Optional[List] = None
 
     @property
     def ttft_s(self) -> Optional[float]:
@@ -90,7 +146,11 @@ class _Request:
     def row(self) -> List:
         """This request as ``LLMEngine.stats()["requests"]`` shows it."""
         return [self.t_submit, self.t_admit, self.t_first, self.t_done,
-                self.prompt_len, self.bucket]
+                self.prompt_len, self.bucket, self.id, self.t_last_put]
+
+    def taken_account(self) -> Dict[str, Any]:
+        return {"tokens_taken": self.taken, "taken_lag_s": self.taken_lag_s,
+                "held_s": self.held_s}
 
     def record_spans(self, parent: Optional[tuple] = None) -> None:
         """The engine's part of this request as ``core/timeline`` spans
@@ -117,14 +177,33 @@ class _Request:
         return self.output
 
     def tokens(self, timeout: Optional[float] = None) -> Iterator[int]:
-        """Yield tokens as the decode loop produces them."""
-        while True:
-            tok = self._live.get(timeout=timeout)
-            if tok is None:
-                if self.error:
-                    raise self.error
-                return
-            yield tok
+        """Yield tokens as the decode loop produces them, and account
+        for the way back on the calling thread (``stats()["stream"]``):
+        three clock reads and four adds a token, no lock."""
+        wall, clock = time.time, time.perf_counter
+        self._streams.begin(self)
+        back = None  # perf_counter() when the consumer last came back
+        try:
+            while True:
+                item = self._live.get(timeout=timeout)
+                if item is None:
+                    if back is not None:
+                        # On time.time(), by way of the lap since.
+                        self.t_last_put = wall() - (clock() - back)
+                        # ``_close`` kept the row before it put the None.
+                        self._row[_LAST_PUT] = self.t_last_put
+                    if self.error:
+                        raise self.error
+                    return
+                tok, emitted = item
+                self.taken_lag_s += wall() - emitted
+                self.taken += 1
+                handed = clock()
+                yield tok
+                back = clock()
+                self.held_s += back - handed
+        finally:
+            self._streams.end(self)
 
 
 # LLMEngine.stats(): the monotonic counts, the loop's phases, and how many
@@ -137,6 +216,8 @@ _COUNTERS = ("decode_slot_steps", "decode_kv_tokens", "decode_kv_rows_read",
 _PHASES = ("admit", "admit_stalling", "inputs", "decode", "readback",
            "emit", "idle")
 _REQUEST_ROWS = 1024
+# Where a ``requests`` row keeps ``t_last_put``.
+_LAST_PUT = 7
 
 
 class _Step:
@@ -269,6 +350,10 @@ class LLMEngine:
         self._phase_s = dict.fromkeys(_PHASES, 0.0)
         self._finished_rows: "collections.deque[List]" = collections.deque(
             maxlen=_REQUEST_ROWS)
+        # The way back (stats()["stream"]): tokens put on a request's
+        # ``_live``, counted by the loop thread, and who has taken them.
+        self._tokens_emitted = 0
+        self._streams = _Streams()
         # Expert load of a MoE model, read back behind each program's
         # tokens (``serving_programs``); None for a dense one.
         self._moe: Optional[Dict[str, Any]] = None
@@ -302,13 +387,15 @@ class LLMEngine:
     # ---- public API --------------------------------------------------------
 
     def submit(self, prompt: List[int], max_new_tokens: int = 32,
-               eos_token: Optional[int] = None) -> _Request:
+               eos_token: Optional[int] = None,
+               request_id: Any = None) -> _Request:
         if len(prompt) + max_new_tokens > self.max_len:
             raise ValueError(
                 f"prompt({len(prompt)}) + max_new({max_new_tokens}) exceeds "
                 f"engine max_len({self.max_len})"
             )
-        req = _Request(prompt, max_new_tokens, eos_token)
+        req = _Request(prompt, max_new_tokens, eos_token, request_id,
+                       self._streams)
         need = self._pages_needed(req, self._bucket(len(prompt)))
         for kind, (_, pages, _) in self._pools.items():
             if need[kind] > pages:
@@ -391,13 +478,39 @@ class LLMEngine:
 
         ``requests``: the newest requests that have finished and those now
         decoding, each ``[t_submit, t_admit, t_first, t_done or None,
-        prompt_len, bucket]`` in ``time.time()`` seconds and tokens."""
+        prompt_len, bucket, id, t_last_put or None]`` in ``time.time()``
+        seconds and tokens: ``id`` is what the caller passed as
+        ``request_id`` (None without one), ``t_last_put`` when the
+        consumer of ``tokens()`` came back after the request's last
+        token (that token's seal was then on its way to the node
+        manager), so ``t_last_put - t_done`` is how far the replica's
+        own part of the way back trailed the engine.
+
+        ``t``: ``time.time()`` of this reading, so that a rate between
+        two readings needs no outside clock.
+
+        ``stream``, the way back from the loop to whoever iterates
+        ``_Request.tokens()``: monotonic ``tokens_emitted`` (tokens the
+        loop put on a request's live queue: prefills that produced one
+        plus ``decode_slot_steps`` less ``decode_slot_steps_discarded``),
+        ``tokens_taken`` (by a ``tokens()`` iterator; a request read by
+        ``result()`` alone is never taken), ``taken_lag_s`` (emitted to
+        taken, summed over taken tokens: the iterator's wake-up and what
+        queued in front of it) and ``held_s`` (from a token's ``yield``
+        to the consumer asking for the next: what ``LLMDeployment.stream``,
+        the executor and the seal cost on that thread), and the gauge
+        ``backlog`` (emitted to a running iterator, not yet taken).
+        Stamps are ``time.time()`` of one process; a token's is its
+        step's, one clock read a step."""
         # Telemetry read: publish whatever the decode tap ring has
         # accumulated so /metrics never lags a long burst.
         self._decode.flush_taps()
+        stream = self._streams.read()
         with self._lock:
             return {
                 **self._counts,
+                "t": time.time(),
+                "stream": {"tokens_emitted": self._tokens_emitted, **stream},
                 "queued": self._queue.qsize() + len(self._waiting),
                 "phase_s": dict(self._phase_s),
                 "requests": list(self._finished_rows) + [
@@ -496,8 +609,9 @@ class LLMEngine:
         req.t_done = time.time()
         req.error = error
         self._counts["failed" if error else "finished"] += 1
+        req._row = req.row()
         with self._lock:
-            self._finished_rows.append(req.row())
+            self._finished_rows.append(req._row)
         req.done.set()
         req._live.put(None)
 
@@ -596,7 +710,8 @@ class LLMEngine:
             counts["prefill_tokens"] += real_len
             counts["prefill_bucket_tokens"] += bucket
             req.output.append(first)
-            req._live.put(first)
+            req._live.put((first, req.t_first))
+            self._tokens_emitted += 1
             with self._lock:
                 self._slot_req[slot] = req
             # No step in flight decodes for a slot admitted after it.
@@ -641,11 +756,13 @@ class LLMEngine:
         """A step's tokens to their requests, once read: the counters,
         the finishes. ``queued`` is the step dispatched after it."""
         counts = self._counts
+        now = time.time()  # the step's tokens are emitted now
         self._step_count += 1
         counts["decode_steps_ahead"] += step.ahead
         counts["decode_slot_steps_discarded"] += len(step.dropped)
         nxt = self._tokens(out, self.max_batch, decode=True)
         counts["decode_slot_steps"] += len(step.slots)
+        self._tokens_emitted += len(step.slots) - len(step.dropped)
         # The step attended to each prompt and every token generated
         # before this one: in a window layer to no more of them than
         # the window.
@@ -666,7 +783,7 @@ class LLMEngine:
                 continue
             tok = int(nxt[slot])
             req.output.append(tok)
-            req._live.put(tok)
+            req._live.put((tok, now))
             if self._ended(req, tok):
                 self._finish(slot, req)
                 if queued is not None and slot in queued.slots:
@@ -787,6 +904,7 @@ class LLMDeployment:
             list(request["prompt"]),
             int(request.get("max_new_tokens", 32)),
             request.get("eos_token"),
+            request.get("id"),
         )
 
     def __call__(self, request: Dict[str, Any]) -> Dict[str, Any]:

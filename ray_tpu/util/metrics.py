@@ -193,7 +193,7 @@ class _BoundHistogram:
         self._bounds = bounds
 
     def observe(self, value: float, exemplar: Optional[str] = None):
-        Histogram._observe(self._name, self._bounds, self._key, value,
+        Histogram._observe(self._name, self._bounds, self._key, (value,),
                            exemplar)
 
 
@@ -244,7 +244,15 @@ class Histogram(_Metric):
         lands in (OpenMetrics exemplar: latest observation wins) — the
         one-hop link from a latency bucket to a recorded waterfall."""
         self._observe(self._name, self._boundaries, self._key(tags),
-                      value, exemplar)
+                      (value,), exemplar)
+
+    def observe_many(self, values,
+                     tags: Optional[Dict[str, str]] = None):
+        """Every one of ``values`` in ONE registry call: for a path
+        that keeps its observations and records them in batches."""
+        if values:
+            self._observe(self._name, self._boundaries, self._key(tags),
+                          values)
 
     def with_tags(self, **tags) -> _BoundHistogram:
         """Pre-resolved handle; see ``Counter.with_tags``."""
@@ -252,7 +260,7 @@ class Histogram(_Metric):
                                self._boundaries)
 
     @staticmethod
-    def _observe(name: str, bounds: List[float], key: tuple, value: float,
+    def _observe(name: str, bounds: List[float], key: tuple, values,
                  exemplar: Optional[str] = None):
         ex_ts = time.time() if exemplar else 0.0
 
@@ -260,15 +268,17 @@ class Histogram(_Metric):
             cur = cur or {"count": 0, "sum": 0.0, "bounds": list(bounds),
                           "buckets": [0] * (len(bounds) + 1)}
             le: Any = "+Inf"
-            for i, b in enumerate(bounds):
-                if value <= b:
-                    cur["buckets"][i] += 1
-                    le = b
-                    break
-            else:
-                cur["buckets"][-1] += 1
-            cur["count"] += 1
-            cur["sum"] += value
+            for value in values:
+                le = "+Inf"
+                for i, b in enumerate(bounds):
+                    if value <= b:
+                        cur["buckets"][i] += 1
+                        le = b
+                        break
+                else:
+                    cur["buckets"][-1] += 1
+                cur["count"] += 1
+                cur["sum"] += value
             if exemplar:
                 cur.setdefault("exemplars", {})[le] = {
                     "trace_id": exemplar, "value": value, "ts": ex_ts,
@@ -276,6 +286,41 @@ class Histogram(_Metric):
             return cur
 
         _registry.record(name, "histogram", key, update)
+
+
+class ItemTally:
+    """The items of one stream that passed one hop and the seconds the
+    hop took them, summed here and recorded every ``FLUSH_ITEMS`` items
+    and at ``flush()``: ``Counter.inc`` takes the registry's lock and
+    builds a closure, which a per-item path cannot pay several times
+    over. One thread writes a tally; ``items_to`` and ``seconds_to``
+    are counters, or ``with_tags`` handles of counters."""
+
+    FLUSH_ITEMS = 64
+
+    __slots__ = ("_items_to", "_seconds_to", "items", "seconds")
+
+    def __init__(self, items_to, seconds_to):
+        self._items_to = items_to
+        self._seconds_to = seconds_to
+        self.items = 0
+        self.seconds = 0.0
+
+    def item(self, seconds: float) -> bool:
+        """One more item; True if that recorded the sums so far."""
+        self.items += 1
+        self.seconds += seconds
+        if self.items >= self.FLUSH_ITEMS:
+            self.flush()
+            return True
+        return False
+
+    def flush(self) -> None:
+        if self.items:
+            self._items_to.inc(self.items)
+            self._seconds_to.inc(self.seconds)
+            self.items = 0
+            self.seconds = 0.0
 
 
 # Per-process resource series, recorded by the flusher's periodic

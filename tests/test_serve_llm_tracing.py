@@ -59,7 +59,7 @@ def test_stats_conserve_requests_tokens_and_time(tiny_model):
     assert stats["prefill_bucket_tokens"] == 16 * n
     assert len(stats["requests"]) == n
     for row, req in zip(sorted(stats["requests"]), reqs):
-        t_submit, t_admit, t_first, t_done, prompt_len, bucket = row
+        t_submit, t_admit, t_first, t_done, prompt_len, bucket = row[:6]
         assert t_submit <= t_admit <= t_first <= t_done
         assert (prompt_len, bucket) == (4, 16)
         assert req.ttft_s == t_first - t_submit
@@ -130,9 +130,140 @@ def test_a_failed_prefill_is_counted_and_its_row_kept(tiny_model):
         engine.shutdown()
     assert (stats["admitted"], stats["failed"], stats["finished"]) == (1, 1, 0)
     assert stats["prefills"] == 0 and stats["cache_resets"] == 1
-    (t_submit, t_admit, t_first, t_done, _, bucket), = stats["requests"]
+    (t_submit, t_admit, t_first, t_done, _, bucket, rid, t_last_put), = \
+        stats["requests"]
     assert t_submit <= t_admit <= t_done and t_first is None and bucket == 16
     assert req.ttft_s is None
+    # No token was made: none emitted, none taken, nothing put.
+    assert (rid, t_last_put) == (None, None)
+    assert stats["stream"] == {"tokens_emitted": 0, "tokens_taken": 0,
+                               "taken_lag_s": 0.0, "held_s": 0.0,
+                               "backlog": 0}
+
+
+# ---- the way back: stats()["stream"], "t", a row's id and t_last_put ------
+
+
+def _emitted_by_the_counts(stats):
+    """Tokens the loop made, from the step and prefill counts: every
+    prefill here produces one, every slot of a step one, less those
+    thrown away behind an ``eos_token``."""
+    return (stats["prefills"] + stats["decode_slot_steps"]
+            - stats["decode_slot_steps_discarded"])
+
+
+def _take(req, pause_s=0.0):
+    tokens = []
+    for tok in req.tokens(timeout=120):
+        tokens.append(tok)
+        time.sleep(pause_s)
+    return tokens
+
+
+def test_stream_counts_conserve_against_steps_and_prefills(tiny_model):
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=64)
+    try:
+        first = engine.stats()
+        assert first["stream"] == {"tokens_emitted": 0, "tokens_taken": 0,
+                                   "taken_lag_s": 0.0, "held_s": 0.0,
+                                   "backlog": 0}
+        news = [3, 7, 5, 4]
+        reqs = [engine.submit([1, 2, 3, 4 + i], n, request_id=f"r{i}")
+                for i, n in enumerate(news)]
+        # Two are streamed, one of them stopped by its eos_token (a
+        # discarded slot-step); two are read by result() alone.
+        eos = engine.generate([1, 2, 3, 4], 3)[1]
+        before = engine.stats()
+        stopped = engine.submit([1, 2, 3, 4], 3, eos_token=eos)
+        streamed = [_take(r) for r in (reqs[0], reqs[1], stopped)]
+        for r in reqs:
+            r.result(timeout=120)
+        # The step queued behind the eos_token is counted when read.
+        deadline = time.monotonic() + 60
+        while engine.stats()["free_slots"] < 2:
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        after = engine.stats()
+    finally:
+        engine.shutdown()
+    assert [len(t) for t in streamed] == [3, 7, 2]
+    for stats in (before, after):
+        assert stats["stream"]["tokens_emitted"] == \
+            _emitted_by_the_counts(stats)
+    assert after["decode_slot_steps_discarded"] == 1
+    stream = after["stream"]
+    # Taken: what the three iterators took, and never more than made.
+    assert stream["tokens_taken"] == 3 + 7 + 2
+    assert stream["tokens_taken"] < stream["tokens_emitted"] \
+        == sum(news) + 3 + 2
+    assert stream["backlog"] == 0
+    assert stream["taken_lag_s"] > 0 and stream["held_s"] > 0
+    # A reading carries its own time, on the rows' clock.
+    assert first["t"] < before["t"] < after["t"] <= time.time()
+    assert after["requests"][0][0] < after["t"]
+
+
+def test_a_slow_consumer_shows_as_lag_and_backlog_not_as_other_tokens(
+        tiny_model):
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=64)
+    try:
+        engine.generate([9, 9, 9], 2, timeout=120)  # programs compiled
+        base = engine.stats()["stream"]
+        quick = engine.submit([1, 2, 3], 12)
+        quick_tokens = _take(quick)
+        fast = engine.stats()["stream"]
+        slow = engine.submit([1, 2, 3], 12)
+        seen = {}
+
+        def consume():
+            seen["tokens"] = _take(slow, pause_s=0.05)
+
+        consumer = threading.Thread(target=consume)
+        consumer.start()
+        slow.result(timeout=120)  # the engine is done with it ...
+        mid = engine.stats()["stream"]
+        consumer.join(timeout=120)
+        lagged = engine.stats()["stream"]
+    finally:
+        engine.shutdown()
+    # ... while its consumer still has most of the tokens in front of it.
+    assert mid["backlog"] >= 6
+    assert mid["tokens_emitted"] - mid["tokens_taken"] >= mid["backlog"]
+    assert lagged["backlog"] == 0
+    assert lagged["tokens_taken"] - fast["tokens_taken"] == 12 \
+        == fast["tokens_taken"] - base["tokens_taken"]
+    # 0.05 s between two takes: the queue's wait is in taken_lag_s, the
+    # consumer's own time in held_s; a consumer that keeps up has neither.
+    assert lagged["held_s"] - fast["held_s"] > 11 * 0.045
+    assert lagged["taken_lag_s"] - fast["taken_lag_s"] > 1.0
+    assert fast["held_s"] - base["held_s"] < 0.1
+    assert fast["taken_lag_s"] - base["taken_lag_s"] < 0.5
+    assert seen["tokens"] == quick_tokens == quick.result(timeout=1)
+
+
+def test_rows_keep_six_fields_and_gain_the_callers_id_and_the_last_put(
+        tiny_model):
+    cfg, params = tiny_model
+    dep = LLMDeployment(cfg, params, max_batch=2, max_len=64)
+    try:
+        request = {"prompt": [1, 2, 3], "max_new_tokens": 4}
+        named = [item["token"] for item in dep.stream({**request, "id": 17})]
+        anonymous = dep(request)["tokens"]
+        rows = sorted(dep.stats()["requests"])
+    finally:
+        dep.engine.shutdown()
+    assert named == anonymous and len(rows) == 2
+    for row in rows:
+        t_submit, t_admit, t_first, t_done, prompt_len, bucket = row[:6]
+        assert t_submit <= t_admit <= t_first <= t_done
+        assert (prompt_len, bucket, len(row)) == (3, 16, 8)
+    (_, _, _, t_done, _, _, rid, t_last_put), by_call = rows
+    # Streamed: the caller's id, and the consumer was back for more
+    # after the engine was done. Read by __call__: no id, never put.
+    assert rid == 17 and t_done <= t_last_put <= time.time()
+    assert by_call[6:] == [None, None]
 
 
 @pytest.mark.parametrize("entry", ["stream", "call"])

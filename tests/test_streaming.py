@@ -153,7 +153,7 @@ def test_item_is_delivered_at_its_seal_not_at_a_tick(ray_tpu_start,
         raise AssertionError("the delivery path slept")
 
     monkeypatch.setattr(streaming, "time", types.SimpleNamespace(
-        monotonic=time.monotonic, sleep=no_sleep))
+        perf_counter=time.perf_counter, sleep=no_sleep))
 
     @ray_tpu.remote(num_returns="streaming")
     def paced(n):
@@ -416,3 +416,201 @@ def test_stream_from_another_node_wakes_on_its_seal():
         assert time.monotonic() - t0 < 20
     finally:
         c.shutdown()
+
+
+# ---- both ends of a stream, hop by hop, off the registry (PR 40) ----------
+
+
+def _series(snapshot_or_report, name, **tags):
+    """One series of a ``local_snapshot()`` or a
+    ``get_metrics_report()``, by its tags; 0.0 where it is not there."""
+    entry = snapshot_or_report.get(name)
+    if entry is None:
+        return 0.0
+    series = entry["series"] if isinstance(entry, dict) else entry[1]
+    return series.get(tuple(sorted(tags.items())), 0.0)
+
+
+def _sealed():
+    """(items, seconds) the producers of this cluster sealed, as their
+    processes last flushed them to the KV."""
+    from ray_tpu.util.metrics import get_metrics_report
+
+    report = get_metrics_report()
+    return (_series(report, "ray_tpu_stream_items_sealed_total"),
+            _series(report, "ray_tpu_stream_item_seal_seconds_total"))
+
+
+def _probe_s():
+    from ray_tpu.util.metrics import local_snapshot
+
+    return _series(local_snapshot(),
+                   "ray_tpu_stream_item_probe_seconds_total")
+
+
+def _wait_for(read, want, timeout=20.0):
+    deadline = time.monotonic() + timeout
+    while True:
+        got = read()
+        if want(got) or time.monotonic() > deadline:
+            return got
+        time.sleep(0.1)
+
+
+@pytest.mark.parametrize("paced", ["producer", "consumer"])
+def test_both_ends_count_items_and_seconds_whoever_sets_the_pace(
+        ray_tpu_start, paced):
+    n = 12
+
+    @ray_tpu.remote(num_returns="streaming")
+    def produce(gap_s):
+        for i in range(n):
+            time.sleep(gap_s)
+            yield i
+
+    sealed0, seal_s0 = _sealed()
+    before, probe0 = _stream_counters(), _probe_s()
+    got = []
+    for ref in produce.remote(0.03 if paced == "producer" else 0.0):
+        got.append(ray_tpu.get(ref))
+        if paced == "consumer":
+            time.sleep(0.03)
+    items, blocked, waits, waited_s = (
+        a - b for a, b in zip(_stream_counters(), before))
+    probe_s = _probe_s() - probe0
+    assert got == list(range(n)) and items == n and waits == blocked
+    # One look a hand-over (a second where the end came first), each a
+    # round trip to the node manager: never free, never a wait.
+    assert 0 < probe_s < 0.05 * n
+    if paced == "producer":
+        assert blocked >= n - 2 and waited_s > 0.02 * blocked
+    else:
+        # What a consumer finds sealed it does not block for (the first
+        # item waits for the task to start).
+        assert blocked <= 2
+    # The producer's side, from its own process: every item sealed, in
+    # seconds that do not hold the generator's own sleeps.
+    sealed, seal_s = _wait_for(_sealed, lambda s: s[0] - sealed0 >= n)
+    assert sealed - sealed0 == n
+    assert 0 < seal_s - seal_s0 < 0.02 * n
+
+
+def test_an_abandoned_stream_still_records_what_it_counted(ray_tpu_start):
+    @ray_tpu.remote(num_returns="streaming")
+    def produce():
+        for i in range(10):
+            yield i
+
+    before, probe0 = _stream_counters(), _probe_s()
+    gen = produce.remote()
+    assert [ray_tpu.get(next(gen)) for _ in range(3)] == [0, 1, 2]
+    # Nothing is recorded an item: three are under the flush's 64.
+    assert _stream_counters() == before and _probe_s() == probe0
+    del gen
+    got = _wait_for(_stream_counters, lambda c: c[0] - before[0] >= 3)
+    assert got[0] - before[0] == 3 and got[2] == got[1]
+    assert _probe_s() > probe0
+
+
+def test_the_consumers_item_path_makes_no_registry_call(ray_tpu_start,
+                                                        monkeypatch):
+    """200 items cost the consumer's process a flush every 64 and one at
+    the end, not three registry calls an item."""
+    from ray_tpu.util import metrics
+
+    n = 200
+
+    @ray_tpu.remote(num_returns="streaming")
+    def produce():
+        for i in range(n):
+            yield i
+
+    calls = []
+    real = metrics._registry.record
+
+    def counted(name, kind, tags_key, update):
+        if name.startswith("ray_tpu_stream"):
+            calls.append(name)
+        return real(name, kind, tags_key, update)
+
+    monkeypatch.setattr(metrics._registry, "record", counted)
+    before = _stream_counters()
+    assert [ray_tpu.get(r) for r in produce.remote()] == list(range(n))
+    items, blocked, waits, _ = (
+        a - b for a, b in zip(_stream_counters(), before))
+    assert items == n and waits == blocked
+    # Four flushes (64, 128, 192, the end) of at most four series.
+    assert 2 <= len(calls) <= 16, calls
+    assert calls.count("ray_tpu_stream_items_total") == 4
+
+
+def test_a_served_stream_counts_its_fetches_and_writes_and_records_one_span(
+        ray_tpu_start):
+    """Through ``handle.stream`` and the proxy's SSE reply: items and
+    seconds at the ``fetch`` and ``write`` hops, tagged by deployment,
+    and one ``stream.deliver`` span under the consumer's own span."""
+    import json
+    import sys
+    import urllib.request
+
+    import ray_tpu.core.timeline  # noqa: F401
+    from ray_tpu import serve
+    from ray_tpu.serve.http_proxy import start_proxy, stop_proxy
+    from ray_tpu.util.metrics import local_snapshot
+
+    timeline = sys.modules["ray_tpu.core.timeline"]
+    n = 5
+
+    @serve.deployment(num_replicas=1)
+    class Tokens:
+        def stream(self, _):
+            for i in range(n):
+                time.sleep(0.01)
+                yield {"token": i}
+
+    def hop(name):
+        snap = local_snapshot()
+        return tuple(_series(snap, series, deployment="toks", hop=name)
+                     for series in (
+                         "ray_tpu_serve_stream_items_total",
+                         "ray_tpu_serve_stream_item_seconds_total"))
+
+    handle = serve.run(Tokens.bind(), name="toks")
+    try:
+        trace_id, span_id = "ab" * 16, "cd" * 8
+        prev = timeline.enter_span(trace_id, span_id)
+        started = time.time()
+        try:
+            got = [item["token"] for item
+                   in handle.options(method="stream").stream(None)]
+        finally:
+            timeline.exit_span(prev)
+        ended = time.time()
+        assert got == list(range(n))
+        items, seconds = hop("fetch")
+        assert items == n and 0 < seconds < ended - started
+        assert hop("write") == (0.0, 0.0)
+        spans = [e for e in timeline.get_buffer()._events
+                 if e["name"] == "stream.deliver"
+                 and e["trace_id"] == trace_id]
+        assert len(spans) == 1 and spans[0]["parent_id"] == span_id
+        # First item handed over to the consumer back from the last.
+        assert started < spans[0]["ts"] <= spans[0]["ts"] + spans[0]["dur"] \
+            <= ended
+        assert spans[0]["dur"] >= 0.01 * (n - 2)
+
+        port = start_proxy(0)
+        request = urllib.request.Request(
+            f"http://127.0.0.1:{port}/toks/stream", data=b"null",
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(request, timeout=60) as reply:
+            lines = [raw.decode().strip() for raw in reply]
+        tokens = [json.loads(line[5:])["token"] for line in lines
+                  if line.startswith("data:") and "token" in line]
+        assert tokens == list(range(n)), lines
+        assert _wait_for(lambda: hop("write")[0], lambda w: w >= n) == n
+        assert 0 < hop("write")[1] < 1.0
+        assert hop("fetch")[0] == 2 * n
+    finally:
+        stop_proxy()
+        serve.shutdown()
