@@ -103,6 +103,12 @@ def _free_location(loc) -> None:
             pass
 
 
+def _resolve(fut: "asyncio.Future") -> None:
+    """Wake what awaits ``fut``, unless it was woken or cancelled."""
+    if not fut.done():
+        fut.set_result(None)
+
+
 def _read_text_tail(path: str, nbytes: int) -> str:
     """Last ``nbytes`` of a text file via seek (bounded read — never the
     whole file). Executor-thread helper for crash diagnosis; '' on any
@@ -379,8 +385,9 @@ class NodeManager:
 
         self._sealed: Set[ObjectID] = set()
         self._seal_events: Dict[ObjectID, asyncio.Event] = {}
-        # wait_objects calls parked on each unsealed object's event.
-        self._parked_waits: Dict[ObjectID, int] = {}
+        # The futures of the wait_objects calls parked on each unsealed
+        # object: one a call, on every object it waits for.
+        self._parked_waits: Dict[ObjectID, List[asyncio.Future]] = {}
         self._pending_procs: Dict[WorkerID, subprocess.Popen] = {}
 
         # Cluster plane.
@@ -1530,7 +1537,16 @@ class NodeManager:
         elif mtype == "get_locations":
             self._bg_op(clock, self._reply_locations(w, msg))
         elif mtype == "wait":
-            self._bg_op(clock, self._reply_wait(w, msg))
+            # A stream consumer's wait sends no ``blocked`` / ``unblocked``
+            # frame: the book is kept here, round the request, and only
+            # if it parks. Blocked at the frame's own place in the
+            # worker's order, as that frame was: the producer a task
+            # submitted just before must not be queued behind it.
+            parks = bool(msg.get("carry")) and self._would_park(
+                msg["object_ids"], msg.get("timeout"))
+            if parks:
+                self._on_worker_blocked(w)
+            self._bg_op(clock, self._reply_wait(w, msg, parks))
         elif mtype == "put":
             await self.put_object(
                 msg["object_id"], msg["loc"], msg.get("refs", 1),
@@ -2464,7 +2480,8 @@ class NodeManager:
         for oid in self.directory.remote_entries(node_hex):
             if oid in self._lineage:
                 self._sealed.discard(oid)
-                if oid in self._dep_index or oid in self._seal_events:
+                if (oid in self._dep_index or oid in self._seal_events
+                        or oid in self._parked_waits):
                     # Consumers are already parked on this object: kick the
                     # re-execution now, their seal waits stay valid.
                     self._spawn_bg(self._reconstruct_object(oid))
@@ -3664,6 +3681,8 @@ class NodeManager:
         ev = self._seal_events.pop(oid, None)
         if ev is not None:
             ev.set()
+        for fut in self._parked_waits.pop(oid, ()):
+            _resolve(fut)
         waiters = self._dep_index.pop(oid, None)
         if waiters:
             for tid in waiters:
@@ -4486,68 +4505,91 @@ class NodeManager:
         num_returns: int,
         timeout: Optional[float],
     ) -> List[ObjectID]:
+        """The sealed ones of ``object_ids``, once ``num_returns`` of
+        them are or ``timeout`` has run out. A call that has to wait
+        parks ONE future on every object it still waits for
+        (``_parked_waits``); ``_seal_object`` resolves it."""
+        sealed = self._sealed
+        ready = [oid for oid in object_ids if oid in sealed]
+        if len(ready) >= num_returns or (timeout is not None
+                                         and timeout <= 0):
+            return ready
         deadline = None if timeout is None else time.monotonic() + timeout
-        parked: Set[ObjectID] = set()
+        waits = self._parked_waits
         locating: List[asyncio.Future] = []
+        if self._gcs is not None and self._multi_node:
+            for oid in object_ids:
+                if oid not in sealed and (
+                        not self.directory.has_entry(oid)
+                        or oid in self._borrow_stubs):
+                    # Nothing on this node will ever seal it (a streamed
+                    # item of a producer elsewhere, a ref borrowed
+                    # unsealed): its publication in the GCS object
+                    # directory has to.
+                    locating.append(asyncio.ensure_future(
+                        self._seal_when_published(oid)
+                    ))
         try:
             while True:
-                ready = [oid for oid in object_ids if oid in self._sealed]
-                if len(ready) >= num_returns:
-                    return ready
-                remaining = None
+                fut = self._loop.create_future()
+                parked = [oid for oid in object_ids if oid not in sealed]
+                for oid in parked:
+                    waits.setdefault(oid, []).append(fut)
+                timer = None
                 if deadline is not None:
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        return ready
-                # Event-driven: wake when any unsealed object seals.
-                pending = []
-                for oid in object_ids:
-                    if oid in self._sealed:
-                        continue
-                    pending.append(
-                        self._seal_events.setdefault(oid, asyncio.Event())
-                    )
-                    if oid in parked:
-                        continue
-                    parked.add(oid)
-                    self._parked_waits[oid] = (
-                        self._parked_waits.get(oid, 0) + 1
-                    )
-                    if (self._gcs is not None and self._multi_node
-                            and (not self.directory.has_entry(oid)
-                                 or oid in self._borrow_stubs)):
-                        # Nothing on this node will ever seal it (a
-                        # streamed item of a producer elsewhere, a ref
-                        # borrowed unsealed): its publication in the GCS
-                        # object directory has to.
-                        locating.append(asyncio.ensure_future(
-                            self._seal_when_published(oid)
-                        ))
-                tasks = [asyncio.ensure_future(ev.wait()) for ev in pending]
+                    timer = self._loop.call_later(
+                        deadline - time.monotonic(), _resolve, fut)
                 try:
-                    await asyncio.wait(
-                        tasks,
-                        timeout=remaining,
-                        return_when=asyncio.FIRST_COMPLETED,
-                    )
+                    await fut
                 finally:
-                    for t in tasks:
-                        t.cancel()
+                    if timer is not None:
+                        timer.cancel()
+                    for oid in parked:
+                        futs = waits.get(oid)
+                        if futs is None or fut not in futs:
+                            continue  # sealed: the seal took the list
+                        futs.remove(fut)
+                        if not futs:
+                            # The last wait on an object that has not
+                            # come to be (a stream's item past its end)
+                            # leaves nothing of it behind.
+                            del waits[oid]
+                ready = [oid for oid in object_ids if oid in sealed]
+                if len(ready) >= num_returns or (
+                        deadline is not None
+                        and time.monotonic() >= deadline):
+                    return ready
         finally:
             for t in locating:
                 t.cancel()
-            for oid in parked:
-                left = self._parked_waits[oid] - 1
-                if left:
-                    self._parked_waits[oid] = left
-                    continue
-                del self._parked_waits[oid]
-                if (oid not in self._sealed
-                        and not self.directory.has_entry(oid)):
-                    # An object that never came to be (a stream's item
-                    # past its end): with no entry, nothing but this
-                    # would ever take its event away.
-                    self._seal_events.pop(oid, None)
+
+    def _would_park(self, object_ids: List[ObjectID],
+                    timeout: Optional[float]) -> bool:
+        """Whether a wait for the first of ``object_ids`` has to wait."""
+        sealed = self._sealed
+        return (timeout is None or timeout > 0) and not any(
+            oid in sealed for oid in object_ids)
+
+    async def wait_carrying(
+        self, object_ids: List[ObjectID], timeout: Optional[float],
+    ) -> Tuple[List[ObjectID], Dict[ObjectID, Location], bool]:
+        """A stream consumer's one request an item: park until the first
+        of ``object_ids`` is sealed (the item, or its stream's
+        completion) and bring back what a ``get_locations`` for it would
+        say. ``(ready, locations, parked)``: ``locations`` holds every
+        ready id whose entry a process of this node can read as it
+        stands (inline bytes, which so ride along, or this node's
+        store); a remote or spilled one is left out and its reader asks
+        ``get_locations``, which pulls or restores. ``parked`` says the
+        call had to wait."""
+        parked = self._would_park(object_ids, timeout)
+        ready = await self.wait_objects(object_ids, 1, timeout)
+        locations = {}
+        for oid in ready:
+            loc = self.directory.lookup(oid)
+            if isinstance(loc, (InlineLocation, ShmLocation, ArenaLocation)):
+                locations[oid] = loc
+        return ready, locations, parked
 
     async def _seal_when_published(self, oid: ObjectID):
         """``wait_objects``' arm for an object another node will seal:
@@ -4758,11 +4800,21 @@ class NodeManager:
             except Exception:  # rtlint: disable=swallowed-failure
                 pass
 
-    async def _reply_wait(self, w: WorkerHandle, msg):
-        ready = await self.wait_objects(
-            msg["object_ids"], msg["num_returns"], msg.get("timeout")
-        )
-        await w.writer.send({"type": "reply", "msg_id": msg["msg_id"], "ready": ready})
+    async def _reply_wait(self, w: WorkerHandle, msg, parks: bool):
+        reply = {"type": "reply", "msg_id": msg["msg_id"]}
+        try:
+            if msg.get("carry"):
+                reply["ready"], reply["locations"], reply["parked"] = (
+                    await self.wait_carrying(
+                        msg["object_ids"], msg.get("timeout")))
+            else:
+                reply["ready"] = await self.wait_objects(
+                    msg["object_ids"], msg["num_returns"],
+                    msg.get("timeout"))
+        finally:
+            if parks:
+                self._on_worker_unblocked(w)
+        await w.writer.send(reply)
 
     # --------------------------------------------------------------------- kv
 
@@ -5725,14 +5777,19 @@ class NodeManager:
                 # after them on the socket or it misses frames still in
                 # our buffer (the worker only scans its own queue).
                 self._flush_worker_exec_buf(w)
-                ids = [r.spec.task_id for r in w.pending]
-                try:
-                    w.writer.send_nowait(
-                        {"type": "reclaim", "task_ids": ids}
-                    )
-                except Exception:
-                    asyncio.ensure_future(self._on_worker_death(w))
+                asyncio.ensure_future(self._send_reclaim(
+                    w, [r.spec.task_id for r in w.pending]))
             self._schedule()
+
+    async def _send_reclaim(self, w: WorkerHandle, task_ids: List[TaskID]):
+        # Behind every execute frame still being written (a blob fetch
+        # in flight holds ``send_lock``): one that reached the worker
+        # after the reclaim would stay queued behind the blocked task.
+        async with w.send_lock:
+            try:
+                w.writer.send_nowait({"type": "reclaim", "task_ids": task_ids})
+            except Exception:
+                await self._on_worker_death(w)
 
     def _on_tasks_reclaimed(self, w: WorkerHandle, msg: Dict[str, Any]):
         """Worker returned pipelined frames it had not started: requeue

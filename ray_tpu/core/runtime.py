@@ -88,7 +88,11 @@ class RefCountTable:
     def __init__(self, flush_fn, on_zero=None):
         self._local: Dict[ObjectID, int] = {}
         self._deltas: Dict[ObjectID, int] = {}
-        self._lock = threading.Lock()
+        # Re-entrant: the collector can run between two bytecodes of a
+        # locked region, and an ObjectRef's __del__ then calls ``decr``
+        # on the thread that holds the lock (seen: a driver's ref
+        # flusher stuck on itself, every other thread behind it).
+        self._lock = threading.RLock()
         self._flush_fn = flush_fn
         # Called (outside the lock) when this process's last local ref
         # to an object drops — the runtime invalidates its location
@@ -113,9 +117,7 @@ class RefCountTable:
             self._on_zero(oid)
 
     def flush(self):
-        with self._lock:
-            deltas = {k: v for k, v in self._deltas.items() if v != 0}
-            self._deltas.clear()
+        deltas = self.drain()
         if deltas:
             self._flush_fn(deltas)
 
@@ -123,10 +125,12 @@ class RefCountTable:
         """Take the pending deltas WITHOUT flushing them — they ride an
         outbound completion frame instead, so the control plane applies
         them before dropping the completing task's pins."""
+        # Swapped, not read, under the lock: a ``decr`` that re-enters
+        # (see ``__init__``) must not change a dict being iterated.
+        fresh: Dict[ObjectID, int] = {}
         with self._lock:
-            deltas = {k: v for k, v in self._deltas.items() if v != 0}
-            self._deltas.clear()
-        return deltas
+            taken, self._deltas = self._deltas, fresh
+        return {k: v for k, v in taken.items() if v != 0}
 
 
 class BaseRuntime:
@@ -203,6 +207,15 @@ class BaseRuntime:
     def _wait(
         self, ids: List[ObjectID], num_returns: int, timeout: Optional[float]
     ) -> List[ObjectID]:
+        raise NotImplementedError
+
+    def _wait_carrying(
+        self, ids: List[ObjectID], timeout: Optional[float]
+    ) -> Tuple[List[ObjectID], Dict[ObjectID, Location], bool]:
+        """A stream consumer's one request an item
+        (``NodeManager.wait_carrying``): ``(ready, locations, parked)``
+        once one of ``ids`` is sealed, ``ready`` empty after
+        ``timeout``."""
         raise NotImplementedError
 
     def _register_put(self, oid: ObjectID, loc: Location,
@@ -444,6 +457,16 @@ class BaseRuntime:
         else:
             fetched = {}
         return [(i, hits.get(i, fetched.get(i))) for i in ids]
+
+    def _carry_location(self, oid: ObjectID, loc: Location):
+        """Keep a location that came with another reply (a streamed
+        item's, with the wait for its seal), so that the ``get`` of a
+        ref this process holds makes no request. Whatever its size: it
+        leaves with the process's last local ref, like any entry."""
+        cache = self._loc_cache
+        if len(cache) >= self._LOC_CACHE_CAP:
+            cache.clear()
+        cache[oid] = loc
 
     def wait(
         self,
@@ -1779,6 +1802,9 @@ class DriverRuntime(BaseRuntime):
     def _wait(self, ids, num_returns, timeout):
         return self._nm.call_sync(self._nm.wait_objects(ids, num_returns, timeout))
 
+    def _wait_carrying(self, ids, timeout):
+        return self._nm.call_sync(self._nm.wait_carrying(ids, timeout))
+
     def _register_put(self, oid: ObjectID, loc: Location,
                       nested: Optional[List[ObjectID]] = None):
         self._post(self._nm.put_object(oid, loc, refs=0, nested=nested))
@@ -2142,6 +2168,16 @@ class WorkerRuntime(BaseRuntime):
             except Exception:
                 pass
         return reply["ready"]
+
+    def _wait_carrying(self, ids, timeout):
+        # No ``blocked`` / ``unblocked`` frame round it: the node
+        # manager keeps that book itself, and only if the wait parks.
+        reply = self.request(
+            {"type": "wait", "object_ids": ids, "num_returns": 1,
+             "timeout": timeout, "carry": True},
+            timeout=timeout,
+        )
+        return reply["ready"], reply["locations"], reply["parked"]
 
     def _register_put(self, oid: ObjectID, loc: Location,
                       nested: Optional[List[ObjectID]] = None):

@@ -14,16 +14,27 @@ knows it before the item exists.
   seals item i with one pinned ref (a ``put`` to its node manager), and
   when the generator is exhausted the task's one return slot, the
   completion ref, seals with the item count (or with the task's error).
-- The consumer blocks on a seal: one ``wait([item_i, completion])`` on
-  its node manager, which the producer's seal wakes (``_seal_events``;
-  an item sealed on another node wakes it through the GCS object
-  directory's long-poll). There is no timer on the way: a token reaches
-  ``next()`` when it is sealed. Item sealed: adopt it (the consumer's +1
-  cancels the producer's pin via coalesced delta flushing). Completion
-  sealed and item not: end of stream, or the task's error. Items of one
-  node are sealed before their completion, in order; an item still on its
-  way from another node when the count arrives is waited for alone, and
-  one still on its way when an ERROR arrives is cut off by that error.
+- The consumer makes one request an item: a ``wait([item_i,
+  completion])`` on its node manager that parks until the producer's
+  seal wakes it (``_parked_waits``; an item sealed on another node wakes
+  it through the GCS object directory's long-poll). No look comes before
+  it and no timer is on the way: a token reaches ``next()`` when it is
+  sealed. The reply carries what ``get`` would have asked for, the
+  location of each ready id that a process of this node can read as it
+  stands (inline bytes, which so ride in the reply, or this node's
+  store); the consumer keeps it for the ref it hands out, and
+  ``get(ref)`` asks nothing. An item of a producer on another node, or a
+  spilled one, comes without, and its ``get`` asks ``get_locations``,
+  which pulls or restores. The reply also says whether the wait
+  ``parked``: then, and only then, the node manager kept the blocked
+  book of a consumer task round it (the CPU it holds is free for its
+  producer meanwhile); the consumer sends no frame for that.
+  Item sealed: adopt it (the consumer's +1 cancels the producer's pin
+  via coalesced delta flushing). Completion sealed and item not: end of
+  stream, or the task's error. Items of one node are sealed before their
+  completion, in order; an item still on its way from another node when
+  the count arrives is waited for alone, and one still on its way when
+  an ERROR arrives is cut off by that error.
 - The retry record ``__stream__/<task>`` holds the consumer's position
   and exists only for a producer that can be retried (``max_retries``):
   a retried attempt re-runs the generator from the start and must not
@@ -63,8 +74,9 @@ STREAM_ITEM_SEAL_S = Counter(
 )
 # Consumer side. A blocked share near 100% with waits near the producer's
 # cadence is a consumer that keeps up; a low blocked share is a consumer
-# that lags (items were waiting for it), and what an item then costs it
-# is the probe (a node-manager round trip that finds the item sealed).
+# that lags (items were waiting for it). Blocked is what the node
+# manager says of the item's one request: it ``parked``. The carried
+# share is ~1 for a stream of this node and 0 for one from another.
 STREAM_ITEMS = Counter(
     "ray_tpu_stream_items_total",
     "Streaming-generator items handed to a consumer.",
@@ -73,17 +85,16 @@ STREAM_ITEMS_BLOCKED = Counter(
     "ray_tpu_stream_item_blocked_total",
     "Items the consumer had to block for (not yet sealed when it asked).",
 )
+STREAM_ITEMS_CARRIED = Counter(
+    "ray_tpu_stream_items_carried_total",
+    "Items whose location came with the reply of the wait for their seal "
+    "(their get makes no request).",
+)
 STREAM_ITEM_WAIT = Histogram(
     "ray_tpu_stream_item_wait_seconds",
     "Time a consumer spent blocked until the item it asked for sealed.",
     boundaries=[0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
                 1.0, 2.5, 5.0, 10.0],
-)
-
-STREAM_ITEM_PROBE_S = Counter(
-    "ray_tpu_stream_item_probe_seconds_total",
-    "Time consumers spent asking whether the next item is sealed yet "
-    "(the non-blocking look before a parked wait).",
 )
 
 # How many item ids an abandoned stream's release asks about at once.
@@ -126,9 +137,10 @@ class ObjectRefGenerator:
         self._released = False
         # Optional per-item production deadline (serve SSE guard).
         self.item_timeout_s = None
-        # Items handed over and the probes' seconds, and the blocked
-        # waits, since they were last recorded (``_record_delivery``).
-        self._tally = ItemTally(STREAM_ITEMS, STREAM_ITEM_PROBE_S)
+        # Items handed over and how many of them were carried, and the
+        # blocked waits, since they were last recorded
+        # (``_record_delivery``).
+        self._tally = ItemTally(STREAM_ITEMS, STREAM_ITEMS_CARRIED)
         self._waits: List[float] = []
 
     @property
@@ -148,7 +160,7 @@ class ObjectRefGenerator:
             raise StopIteration
         item = stream_item_id(self._task_id, self._next)
         try:
-            sealed = self._await_item(rt, item)
+            sealed, loc = self._await_item(rt, item)
         except BaseException:
             _record_delivery(self._tally, self._waits)
             raise
@@ -163,47 +175,51 @@ class ObjectRefGenerator:
         # coalesce locally, leaving the seal-time pin as the user ref's
         # count until the ref is dropped.
         rt.refs.decr(item)
+        if loc is not None:
+            # After the decr, which drops what the process knew of the id.
+            rt._carry_location(item, loc)
         if self._retriable:
             _write_retry_record(rt, self._task_id, self._next)
         return ref
 
-    def _await_item(self, rt, item: ObjectID) -> bool:
-        """Block until ``item`` is sealed (True) or the stream ended
-        before it (False); raises the task's error, or GetTimeoutError
-        after ``item_timeout_s`` without either."""
+    def _await_item(self, rt, item: ObjectID):
+        """Block until ``item`` is sealed or the stream ended before it:
+        ``(sealed, the item's location if it came with the reply)``;
+        raises the task's error, or GetTimeoutError after
+        ``item_timeout_s`` without either."""
         import ray_tpu
 
         clock = time.perf_counter
-        ids = [item, self._completion_ref.id()]
-        probe_s = 0.0
+        completion = self._completion_ref.id()
+        ids = [item, completion]
         while True:
             t0 = clock()
-            ready = rt._wait(ids, 1, 0)
-            t1 = clock()
-            probe_s += t1 - t0
+            ready, locations, parked = rt._wait_carrying(
+                ids, self.item_timeout_s)
             if not ready:
-                ready = rt._wait(ids, 1, self.item_timeout_s)
-                if not ready:
-                    # A wedged producer must not hold consumers (serve
-                    # proxy threads) forever — surface a timeout instead.
-                    from .exceptions import GetTimeoutError
+                # A wedged producer must not hold consumers (serve
+                # proxy threads) forever — surface a timeout instead.
+                from .exceptions import GetTimeoutError
 
-                    raise GetTimeoutError(
-                        f"stream item {self._next} not produced within "
-                        f"{self.item_timeout_s}s"
-                    )
-                if item in ready:
-                    self._waits.append(clock() - t1)
+                raise GetTimeoutError(
+                    f"stream item {self._next} not produced within "
+                    f"{self.item_timeout_s}s"
+                )
             if item in ready:
-                if self._tally.item(probe_s):
+                if parked:
+                    self._waits.append(clock() - t0)
+                loc = locations.get(item)
+                if self._tally.item(loc is not None):
                     _record_delivery(self._tally, self._waits)
-                return True
+                return True, loc
             # The task is over and this item is not sealed here: finished
             # (the count says whether the item exists) or failed (get
             # raises the task's error).
+            if completion in locations:
+                rt._carry_location(completion, locations[completion])
             self._count = ray_tpu.get(self._completion_ref)
             if self._next >= self._count:
-                return False
+                return False, None
             # It exists, and is only still on its way from the producer's
             # node: wait for it alone.
             ids = [item]
@@ -246,7 +262,7 @@ class ObjectRefGenerator:
 
 def _record_delivery(tally: ItemTally, waits: List[float]) -> None:
     """A consumer's counts since they were last recorded, to the metrics
-    registry: items and probe seconds, and the blocked waits."""
+    registry: items and how many were carried, and the blocked waits."""
     tally.flush()
     if waits:
         STREAM_ITEMS_BLOCKED.inc(len(waits))

@@ -290,11 +290,12 @@ class Histogram(_Metric):
 
 class ItemTally:
     """The items of one stream that passed one hop and the seconds the
-    hop took them, summed here and recorded every ``FLUSH_ITEMS`` items
-    and at ``flush()``: ``Counter.inc`` takes the registry's lock and
-    builds a closure, which a per-item path cannot pay several times
-    over. One thread writes a tally; ``items_to`` and ``seconds_to``
-    are counters, or ``with_tags`` handles of counters."""
+    hop took them (or, for a hop that counts a kind of item beside all
+    of them, 1 for each of that kind), summed here and recorded every
+    ``FLUSH_ITEMS`` items and at ``flush()``: ``Counter.inc`` takes the
+    registry's lock and builds a closure, which a per-item path cannot
+    pay several times over. One thread writes a tally; ``items_to`` and
+    ``seconds_to`` are counters, or ``with_tags`` handles of counters."""
 
     FLUSH_ITEMS = 64
 

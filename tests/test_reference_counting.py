@@ -185,3 +185,58 @@ def test_borrow_then_owner_node_dies():
         assert time.monotonic() - t0 < 60  # failed, not hung
     finally:
         cluster.shutdown()
+
+
+@pytest.mark.parametrize("where", ["flush", "drain", "incr", "decr"])
+def test_a_ref_dropped_inside_the_tables_lock_does_not_deadlock(where):
+    """The collector can run an ObjectRef's ``__del__`` between two
+    bytecodes of the thread that holds the ref table's lock (seen: a
+    driver's ref flusher stuck on itself in ``flush``, every thread of
+    the process behind it). Stood in for here by a dict that drops a ref
+    when the table reads it."""
+    import threading
+
+    from ray_tpu.core.ids import ObjectID
+    from ray_tpu.core.runtime import RefCountTable
+
+    flushed = []
+    table = RefCountTable(flushed.append)
+    held, other = ObjectID.from_random(), ObjectID.from_random()
+
+    class Dropping(dict):
+        armed = True
+
+        def _drop(self):
+            if Dropping.armed:
+                Dropping.armed = False
+                table.decr(other)
+
+        def items(self):
+            self._drop()
+            return super().items()
+
+        def get(self, *a):
+            self._drop()
+            return super().get(*a)
+
+    table.incr(held)
+    table.incr(other)
+    if where in ("flush", "drain"):
+        table._deltas = Dropping(table._deltas)
+    else:
+        table._local = Dropping(table._local)
+    call = {"flush": table.flush,
+            "drain": lambda: flushed.append(table.drain()),
+            "incr": lambda: table.incr(held),
+            "decr": lambda: table.decr(held)}[where]
+    thread = threading.Thread(target=call, daemon=True)
+    thread.start()
+    thread.join(timeout=10)
+    assert not thread.is_alive(), f"{where} deadlocked on its own lock"
+    assert not Dropping.armed
+    # Nothing was lost: what was taken and what is left add up.
+    total = {held: 0, other: 0}
+    for deltas in flushed + [table.drain()]:
+        for oid, d in deltas.items():
+            total[oid] += d
+    assert total == {held: {"incr": 2, "decr": 0}.get(where, 1), other: 0}

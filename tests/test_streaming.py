@@ -176,7 +176,7 @@ def test_slow_producer_costs_blocked_waits_not_polls(ray_tpu_start,
     from ray_tpu.core.runtime_context import current_runtime
 
     rt = current_runtime()
-    calls = {"kv_get": 0, "_wait": 0}
+    calls = {"kv_get": 0, "_wait": 0, "_wait_carrying": 0}
 
     def counted(name):
         real = getattr(rt, name)
@@ -199,8 +199,8 @@ def test_slow_producer_costs_blocked_waits_not_polls(ray_tpu_start,
     t0 = time.monotonic()
     assert [ray_tpu.get(r) for r in slow.remote()] == ["a", "b"]
     assert time.monotonic() - t0 >= 1.5
-    # Two items and the end, each at most a look and a parked wait.
-    assert calls["_wait"] <= 6 and calls["kv_get"] == 0, calls
+    # Two items and the end, each one parked wait and no look before it.
+    assert calls == {"kv_get": 0, "_wait": 0, "_wait_carrying": 3}, calls
 
 
 @pytest.mark.parametrize("pace", ["consumer_keeps_up", "consumer_lags"])
@@ -441,11 +441,12 @@ def _sealed():
             _series(report, "ray_tpu_stream_item_seal_seconds_total"))
 
 
-def _probe_s():
+def _carried():
+    """Items of this process's streams whose location came with the
+    reply of the wait for their seal."""
     from ray_tpu.util.metrics import local_snapshot
 
-    return _series(local_snapshot(),
-                   "ray_tpu_stream_item_probe_seconds_total")
+    return _series(local_snapshot(), "ray_tpu_stream_items_carried_total")
 
 
 def _wait_for(read, want, timeout=20.0):
@@ -469,7 +470,7 @@ def test_both_ends_count_items_and_seconds_whoever_sets_the_pace(
             yield i
 
     sealed0, seal_s0 = _sealed()
-    before, probe0 = _stream_counters(), _probe_s()
+    before, carried0 = _stream_counters(), _carried()
     got = []
     for ref in produce.remote(0.03 if paced == "producer" else 0.0):
         got.append(ray_tpu.get(ref))
@@ -477,11 +478,10 @@ def test_both_ends_count_items_and_seconds_whoever_sets_the_pace(
             time.sleep(0.03)
     items, blocked, waits, waited_s = (
         a - b for a, b in zip(_stream_counters(), before))
-    probe_s = _probe_s() - probe0
     assert got == list(range(n)) and items == n and waits == blocked
-    # One look a hand-over (a second where the end came first), each a
-    # round trip to the node manager: never free, never a wait.
-    assert 0 < probe_s < 0.05 * n
+    # One request a hand-over, and its reply brought the item's location
+    # whether it had to park for the seal or found it.
+    assert _carried() - carried0 == n
     if paced == "producer":
         assert blocked >= n - 2 and waited_s > 0.02 * blocked
     else:
@@ -501,15 +501,15 @@ def test_an_abandoned_stream_still_records_what_it_counted(ray_tpu_start):
         for i in range(10):
             yield i
 
-    before, probe0 = _stream_counters(), _probe_s()
+    before, carried0 = _stream_counters(), _carried()
     gen = produce.remote()
     assert [ray_tpu.get(next(gen)) for _ in range(3)] == [0, 1, 2]
     # Nothing is recorded an item: three are under the flush's 64.
-    assert _stream_counters() == before and _probe_s() == probe0
+    assert _stream_counters() == before and _carried() == carried0
     del gen
     got = _wait_for(_stream_counters, lambda c: c[0] - before[0] >= 3)
     assert got[0] - before[0] == 3 and got[2] == got[1]
-    assert _probe_s() > probe0
+    assert _carried() - carried0 == 3
 
 
 def test_the_consumers_item_path_makes_no_registry_call(ray_tpu_start,
@@ -541,6 +541,7 @@ def test_the_consumers_item_path_makes_no_registry_call(ray_tpu_start,
     assert items == n and waits == blocked
     # Four flushes (64, 128, 192, the end) of at most four series.
     assert 2 <= len(calls) <= 16, calls
+    assert calls.count("ray_tpu_stream_items_carried_total") == 4
     assert calls.count("ray_tpu_stream_items_total") == 4
 
 
@@ -613,4 +614,290 @@ def test_a_served_stream_counts_its_fetches_and_writes_and_records_one_span(
         assert hop("fetch")[0] == 2 * n
     finally:
         stop_proxy()
+        serve.shutdown()
+
+
+# ---- one node-manager request an item (PR 41) ------------------------------
+
+
+def _paced_items():
+    """A streaming task ``(n, gap_s)``, and ``counts()`` = this
+    process's (items, blocked, carried); both made here so that a worker
+    gets them by value (it cannot import this module)."""
+
+    @ray_tpu.remote(num_returns="streaming")
+    def paced_items(n, gap_s):
+        for i in range(n):
+            time.sleep(gap_s)
+            yield i
+
+    def counts():
+        from ray_tpu.util.metrics import local_snapshot
+
+        snap = local_snapshot()
+        return tuple(
+            snap.get(f"ray_tpu_stream_{name}_total", ("", {}, ""))[1].get(
+                (), 0.0)
+            for name in ("items", "item_blocked", "items_carried"))
+
+    return paced_items, counts
+
+
+def _consumer_actor():
+    paced_items, counts = _paced_items()
+
+    @ray_tpu.remote
+    class Consumer:
+        """A consumer in a worker's process: what it asks of the node
+        manager crosses a socket, frame by frame."""
+
+        def open(self, n, gap_s, sealed_first):
+            from ray_tpu.core.runtime_context import current_runtime
+
+            self.gen = paced_items.remote(n, gap_s)
+            if sealed_first:
+                ray_tpu.get(self.gen.completed, timeout=60)
+            return current_runtime().worker_id
+
+        def drain(self):
+            before = counts()
+            got = [ray_tpu.get(ref) for ref in self.gen]
+            return got, tuple(a - b for a, b in zip(counts(), before))
+
+    return Consumer.remote()
+
+
+def _count_frames(nm, monkeypatch):
+    """``{(worker id, frame type): frames}`` as the node manager's
+    dispatch sees them from now on."""
+    import collections
+
+    frames = collections.Counter()
+    real = nm._dispatch_message_op
+
+    async def counted(w, msg, clock=None):
+        frames[w.worker_id, msg["type"]] += 1
+        return await real(w, msg, clock)
+
+    monkeypatch.setattr(nm, "_dispatch_message_op", counted)
+    return frames
+
+
+@pytest.mark.parametrize("pace", ["sealed_first", "paced_producer"])
+def test_a_local_item_costs_its_consumer_one_wait_and_nothing_else(
+        ray_tpu_start, monkeypatch, pace):
+    """N items are N + 1 ``wait`` requests (the last finds the end), no
+    ``get_locations`` and no ``blocked`` / ``unblocked`` frame: sealed
+    before they are asked for none parks, behind a paced producer every
+    one does, and the node manager keeps that book itself."""
+    from ray_tpu.core.runtime_context import current_runtime
+
+    n = 10
+    consumer = _consumer_actor()
+    sealed_first = pace == "sealed_first"
+    worker = ray_tpu.get(consumer.open.remote(
+        n, 0.0 if sealed_first else 0.03, sealed_first), timeout=60)
+    frames = _count_frames(current_runtime()._nm, monkeypatch)
+    got, (items, blocked, carried) = ray_tpu.get(consumer.drain.remote(),
+                                                 timeout=60)
+    assert got == list(range(n)) and items == n and carried == n
+    assert frames[worker, "wait"] == n + 1, frames
+    for kind in ("get_locations", "blocked", "unblocked"):
+        assert frames[worker, kind] == 0, frames
+    if sealed_first:
+        assert blocked == 0
+    else:
+        assert blocked >= n - 1, blocked  # item 0 may be sealed by now
+
+
+def test_a_consumer_on_the_only_cpu_gives_it_up_to_its_producer():
+    """The consumer task holds the node's one CPU and its producer needs
+    it: the parked wait releases it at the node manager, with no frame
+    from the worker, and takes it back with the reply."""
+    ray_tpu.init(num_cpus=1, system_config={"num_prestart_workers": 1})
+    try:
+        paced_items, counts = _paced_items()
+
+        @ray_tpu.remote(num_cpus=1)
+        def consume(n):
+            before = counts()
+            got = [ray_tpu.get(ref) for ref in paced_items.remote(n, 0.01)]
+            return got, tuple(a - b for a, b in zip(counts(), before))
+
+        got, (items, blocked, carried) = ray_tpu.get(consume.remote(8),
+                                                     timeout=120)
+        assert got == list(range(8))
+        assert (items, blocked, carried) == (8, 8, 8)
+    finally:
+        ray_tpu.shutdown()
+
+
+def _no_request_from_this_thread(rt, monkeypatch):
+    """Calls of ``rt._get_locations`` made by the calling thread, as a
+    list that the wrapper it installs appends to."""
+    import threading
+
+    me, asked = threading.get_ident(), []
+    real = rt._get_locations
+
+    def counted(ids, timeout):
+        if threading.get_ident() == me:
+            asked.append(list(ids))
+        return real(ids, timeout)
+
+    monkeypatch.setattr(rt, "_get_locations", counted)
+    return asked
+
+
+def test_an_item_over_the_inline_limit_is_carried_as_its_store_location(
+        ray_tpu_start, monkeypatch):
+    import numpy as np
+
+    from ray_tpu.core.config import get_config
+    from ray_tpu.core.object_store import InlineLocation
+    from ray_tpu.core.runtime_context import current_runtime
+
+    n, words = 4, get_config().max_inline_object_size // 4
+
+    @ray_tpu.remote(num_returns="streaming")
+    def big(n):
+        for i in range(n):
+            yield np.full(words, i, dtype=np.int64)  # twice the limit
+
+    rt = current_runtime()
+    asked = _no_request_from_this_thread(rt, monkeypatch)
+    carried0 = _carried()
+    for i, ref in enumerate(big.remote(n)):
+        loc = rt._loc_cache[ref.id()]
+        assert not isinstance(loc, InlineLocation), loc
+        value = ray_tpu.get(ref)
+        assert value.shape == (words,) and (value == i).all()
+    assert i == n - 1 and _carried() - carried0 == n
+    assert asked == []
+
+
+def test_an_item_from_another_node_is_not_carried_and_is_pulled():
+    """The fall-through: the reply of the wait names a remote item ready
+    and brings no location; ``get`` asks ``get_locations``, which pulls
+    it. Same values, same order."""
+    from ray_tpu.cluster_utils import Cluster
+
+    c = Cluster(head_resources={"CPU": 2},
+                system_config={"log_to_driver": False})
+    try:
+        c.add_node(num_cpus=2, resources={"gadget": 1})
+        c.wait_for_nodes(2)
+
+        @ray_tpu.remote(num_returns="streaming", resources={"gadget": 1})
+        def far(n):
+            for i in range(n):
+                yield {"item": i}
+
+        n = 6
+        before, carried0 = _stream_counters(), _carried()
+        got = [ray_tpu.get(r)["item"] for r in far.remote(n)]
+        assert got == list(range(n))
+        assert _stream_counters()[0] - before[0] == n
+        assert _carried() == carried0
+    finally:
+        c.shutdown()
+
+
+@pytest.mark.parametrize("kind,carried", [
+    ("inline", True), ("store", True), ("remote", False),
+    ("spilled", False)])
+def test_the_waits_reply_carries_what_a_process_of_the_node_can_read(
+        ray_tpu_start, kind, carried):
+    """The node manager chooses by the entry it finds: no flag of the
+    caller's. What it leaves out is ready all the same."""
+    from ray_tpu.core.ids import ObjectID
+    from ray_tpu.core.object_store import (InlineLocation, RemoteLocation,
+                                           ShmLocation, SpilledLocation)
+    from ray_tpu.core.runtime_context import current_runtime
+
+    nm = current_runtime()._nm
+    loc = {"inline": InlineLocation(b"x" * 8192),
+           "store": ShmLocation("rtpu-test-none", 1 << 20),
+           "remote": RemoteLocation("ab" * 16, 64),
+           "spilled": SpilledLocation("/nonexistent/spill", 64)}[kind]
+    oid, unsealed = ObjectID.from_random(), ObjectID.from_random()
+
+    async def seal_and_wait():
+        nm.directory.add(oid, loc, initial_refs=1)
+        nm._sealed.add(oid)
+        try:
+            return await nm.wait_carrying([oid, unsealed], 5.0)
+        finally:
+            nm._sealed.discard(oid)
+            nm.directory._entries.pop(oid, None)
+
+    ready, locations, parked = nm.call_sync(seal_and_wait())
+    assert ready == [oid] and not parked
+    assert locations == ({oid: loc} if carried else {})
+    assert not nm._parked_waits
+
+
+@pytest.mark.parametrize("ending", ["error", "timeout"])
+def test_a_stream_that_ends_badly_leaves_nothing_pinned_or_kept(
+        ray_tpu_start, ending):
+    """After a producer's error or a consumer's timeout, with the refs
+    dropped: no item stays sealed at the node manager, and none of the
+    carried locations stays in the consumer's process."""
+    from ray_tpu.core.exceptions import GetTimeoutError
+    from ray_tpu.core.runtime_context import current_runtime
+    from ray_tpu.core.streaming import stream_item_id
+
+    @ray_tpu.remote(num_returns="streaming")
+    def produce(ending):
+        yield 0
+        yield 1
+        if ending == "error":
+            raise ValueError("stream broke")
+        time.sleep(60)
+        yield 2
+
+    rt = current_runtime()
+    gen = produce.remote(ending)
+    gen.item_timeout_s = 1.0 if ending == "timeout" else None
+    ids = [stream_item_id(gen._task_id, i) for i in range(2)]
+    refs = [next(gen), next(gen)]
+    assert all(oid in rt._loc_cache for oid in ids)
+    assert [ray_tpu.get(r) for r in refs] == [0, 1]
+    with pytest.raises(ValueError if ending == "error" else GetTimeoutError):
+        next(gen)
+    del refs, gen
+    assert not any(oid in rt._loc_cache for oid in ids)
+    deadline = time.monotonic() + 20
+    while rt._wait(ids, 2, 0) and time.monotonic() < deadline:
+        time.sleep(0.2)
+    assert rt._wait(ids, 2, 0) == []
+
+
+def test_a_served_streams_fetch_asks_the_node_manager_nothing(
+        ray_tpu_start, monkeypatch):
+    """``handle.stream``: the ``fetch`` hop, ``ray_tpu.get(ref)``, reads
+    what the wait's reply brought; the stream's end does too."""
+    from ray_tpu import serve
+    from ray_tpu.core.runtime_context import current_runtime
+
+    n = 6
+
+    @serve.deployment(num_replicas=1)
+    class Tokens:
+        def stream(self, _):
+            for i in range(n):
+                time.sleep(0.01)
+                yield {"token": i}
+
+    handle = serve.run(Tokens.bind(), name="toks41")
+    try:
+        stream = handle.options(method="stream")
+        assert [item["token"] for item in stream.stream(None)] == list(
+            range(n))  # routes resolved, replica warm
+        asked = _no_request_from_this_thread(current_runtime(), monkeypatch)
+        carried0 = _carried()
+        got = [item["token"] for item in stream.stream(None)]
+        assert got == list(range(n))
+        assert asked == [] and _carried() - carried0 == n
+    finally:
         serve.shutdown()
