@@ -19,6 +19,15 @@ pool whole; ``attend`` is ``ops/paged_attention.decode_attention``,
 which writes the token's k and v into the slot's current page and
 attends over the slot's pages.
 
+A latent-attention layer (``llama.latent_proj``) caches one row a token,
+the latent and the shared rotary key, in a pool of its own kind and has
+two attends: the prefill rebuilds k and v of every head from the rows
+and attends causally as any other (q and k 192 wide beside a v of 128
+through the flash kernel), the decode step absorbs the up-projections
+into the query and behind the attention and attends every head over the
+rows themselves (``ops/paged_attention.latent_decode_attention``). The
+same function of the same weights, at the cost each phase can bear.
+
 No reference counterpart — Ray delegates model serving compute to user
 code; this framework owns it (continuous batching sits on top in
 ray_tpu.serve.llm).
@@ -31,10 +40,13 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.paged_attention import decode_attention, ring_pages
+from ..ops.paged_attention import (
+    decode_attention, latent_decode_attention, ring_pages,
+)
 from .llama import (
     LlamaConfig, block, causal_attention, embed_tokens, kv_layers,
-    layer_runs, layer_stacks, rms_norm, split_expert_stack,
+    latent_absorb_out, latent_absorb_q, latent_kv, layer_runs, layer_stacks,
+    rms_norm, split_expert_stack,
 )
 
 
@@ -73,9 +85,12 @@ class PagedKVCache(NamedTuple):
     the caller gives it. A "window" layer keeps the last
     ``sliding_window`` tokens: its table's row is a ring of
     ``ring_pages`` columns (ops/paged_attention.py) and its pool holds a
-    ring for every slot, whatever the context lengths.
+    ring for every slot, whatever the context lengths. A "latent" layer
+    keeps every token as a "full" one does, but ONE row for all heads
+    (``llama.latent_proj``): its pool is ``k["latent"]``,
+    [L, P, page, ``cfg.latent_row``], and ``v`` has no such entry.
 
-    A pool is HEAD-MAJOR
+    A k/v pool is HEAD-MAJOR
     ([L_kind, Hkv, P_kind, page, Dh]): one copy brings a page of every KV
     head to the decode attention (ops/paged_attention.py), which reads a
     slot's own pages where they lie and, on a TPU, is also what writes a
@@ -88,13 +103,17 @@ class PagedKVCache(NamedTuple):
     step before PR 29, PERF.md §6)."""
 
     k: Dict[str, jax.Array]            # kind -> [L_kind, Hkv, P, page, Dh]
-    v: Dict[str, jax.Array]
+    v: Dict[str, jax.Array]            # ("latent": k alone, [L, P, page, W])
     page_table: Dict[str, jax.Array]   # kind -> [B, columns] int32 page ids
     lengths: jax.Array                 # [B] int32 valid tokens per slot
 
     @property
     def page_size(self) -> int:
-        return next(iter(self.k.values())).shape[3]
+        return next(iter(self.k.values())).shape[-2]
+
+    def pools(self, kind: str) -> Tuple[jax.Array, ...]:
+        """The pools of ``kind``: (k, v), or the one of latent rows."""
+        return tuple(d[kind] for d in (self.k, self.v) if kind in d)
 
     @staticmethod
     def sizes(cfg: LlamaConfig, batch: int, total_pages: int,
@@ -119,17 +138,29 @@ class PagedKVCache(NamedTuple):
         sizes = PagedKVCache.sizes(cfg, batch, total_pages, page_size,
                                    max_pages_per_seq)
 
-        def pools():
+        def pools(latent):
             return {kind: jnp.zeros(
+                (layers, pages, page_size, cfg.latent_row)
+                if kind == "latent" else
                 (layers, cfg.num_kv_heads, pages, page_size, cfg.dh),
-                dtype=cfg.dtype) for kind, (layers, pages, _) in sizes.items()}
+                dtype=cfg.dtype) for kind, (layers, pages, _) in sizes.items()
+                if latent or kind != "latent"}
 
         return PagedKVCache(
-            k=pools(), v=pools(),
+            k=pools(True), v=pools(False),
             page_table={kind: jnp.zeros((batch, columns), dtype=jnp.int32)
                         for kind, (_, _, columns) in sizes.items()},
             lengths=jnp.zeros((batch,), dtype=jnp.int32),
         )
+
+
+def _with_pools(cache: PagedKVCache, pools, lengths) -> PagedKVCache:
+    """``cache`` with each kind's pools (``PagedKVCache.pools``' tuples)
+    and the slots' lengths replaced."""
+    return PagedKVCache(
+        {kind: held[0] for kind, held in pools.items()},
+        {kind: held[1] for kind, held in pools.items() if len(held) > 1},
+        cache.page_table, lengths)
 
 
 def paged_decode(
@@ -151,14 +182,14 @@ def paged_decode(
     as they are, and it reaches no expert: the experts a step reads
     follow the live sequences."""
     x = embed_tokens(params, tokens, cfg)[:, None]
-    k_pools, v_pools = dict(cache.k), dict(cache.v)
+    pools = {kind: cache.pools(kind) for kind in cache.k}
     expert_tokens = []
     for run, stack in zip(layer_runs(cfg), layer_stacks(params)):
         layers, expert_stack = split_expert_stack(stack)
         kind, table = run.kind, cache.page_table[run.kind]
 
         def body(carry, lp):
-            x, k_pool, v_pool = carry
+            x, held = carry
 
             def attend(q, k, v):
                 # The token's K/V row goes to ``decode_attention``, which
@@ -168,29 +199,40 @@ def paged_decode(
                 # kernel's aliased call would make XLA copy them): the
                 # block never sees them.
                 with jax.named_scope(f"attn.{kind}"):
-                    out, k_new, v_new = decode_attention(
-                        q[:, 0], k[:, 0], v[:, 0], k_pool, v_pool,
+                    out, *new = decode_attention(
+                        q[:, 0], k[:, 0], v[:, 0], *held,
                         lp["index"] + run.kv_offset, table, cache.lengths,
                         active, window=cfg.window(kind))
-                return out[:, None], (k_new, v_new)
+                return out[:, None], tuple(new)
+
+            def attend_latent(q, row, _):
+                # Absorbed: every head's query against the rows as they
+                # are cached, the values the rows' own latent part.
+                q_lat = latent_absorb_q(cfg, lp, q)
+                with jax.named_scope("attn.latent"):
+                    out, pool = latent_decode_attention(
+                        q_lat[:, 0], row[:, 0], *held,
+                        lp["index"] + run.kv_offset, table, cache.lengths,
+                        active, scale=cfg.dh ** -0.5,
+                        values=cfg.kv_lora_rank)
+                return latent_absorb_out(cfg, lp, out[:, None]), (pool,)
 
             # The load-balancing loss is a training-only term: dropped.
-            x, (k_pool, v_pool), _aux, load = block(
-                cfg, lp, x, cache.lengths[:, None], attend,
+            x, held, _aux, load = block(
+                cfg, lp, x, cache.lengths[:, None],
+                attend_latent if kind == "latent" else attend,
                 token_mask=active[:, None], expert_stack=expert_stack,
                 kind=kind)
-            return (x, k_pool, v_pool), load
+            return (x, held), load
 
-        (x, k_pools[kind], v_pools[kind]), load = jax.lax.scan(
-            body, (x, k_pools[kind], v_pools[kind]), layers)
+        (x, pools[kind]), load = jax.lax.scan(body, (x, pools[kind]), layers)
         if load is not None:
             expert_tokens.append(load)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bm,mv->bv", x[:, 0], params["lm_head"])
     lengths = jnp.where(active, cache.lengths + 1, cache.lengths)
-    return logits.astype(jnp.float32), PagedKVCache(
-        k_pools, v_pools, cache.page_table, lengths
-    ), MoeLoad.of_layers(expert_tokens)
+    return logits.astype(jnp.float32), _with_pools(
+        cache, pools, lengths), MoeLoad.of_layers(expert_tokens)
 
 
 def paged_prefill(
@@ -223,19 +265,23 @@ def paged_prefill(
     x = embed_tokens(params, tokens, cfg)
     positions = jnp.arange(S)
     token_mask = positions[None] < real_len if cfg.n_experts > 0 else None
-    k_pools, v_pools = dict(cache.k), dict(cache.v)
+    pools = {kind: cache.pools(kind) for kind in cache.k}
     expert_tokens = []
 
     def to_pages(rows, pool, run):
         """[n, 1, S, Hkv, Dh] -> [n, Hkv, S // page, page, Dh], the
-        pool's layout, set at the run's layers and the slot's page ids."""
-        paged = rows[:, 0].reshape(
-            run.n, S // page, page, cfg.num_kv_heads, cfg.dh
-        ).transpose(0, 3, 1, 2, 4)
+        pool's layout, set at the run's layers and the slot's page ids;
+        latent rows [n, 1, S, W] -> [n, S // page, page, W] likewise."""
         ids = pages[run.kind]
         whole = run.n == pool.shape[0]
         at = slice(None) if whole else slice(run.kv_offset,
                                              run.kv_offset + run.n)
+        if run.kind == "latent":
+            paged = rows[:, 0].reshape(run.n, S // page, page, -1)
+            return pool.at[at, ids].set(paged.astype(pool.dtype))
+        paged = rows[:, 0].reshape(
+            run.n, S // page, page, cfg.num_kv_heads, cfg.dh
+        ).transpose(0, 3, 1, 2, 4)
         if run.kind == "window":
             # The newest ``len(ids)`` pages up to the last real token's
             # (all of a bucket that fits the ring), each to its column.
@@ -249,29 +295,37 @@ def paged_prefill(
         layers, expert_stack = split_expert_stack(stack)
         kind = run.kind
 
-        def attend(q, k, v):
-            with jax.named_scope(f"attn.{kind}"):
-                out = causal_attention(cfg, None, q, k, v,
-                                       window=cfg.window(kind))
-            return out, (k, v)
-
         def body(x, lp):
-            x, kv, _aux, load = block(
-                cfg, lp, x, positions, attend, token_mask=token_mask,
-                expert_stack=expert_stack, kind=kind)
-            return x, (kv, load)
+            def attend(q, k, v):
+                with jax.named_scope(f"attn.{kind}"):
+                    out = causal_attention(cfg, None, q, k, v,
+                                           window=cfg.window(kind))
+                return out, (k, v)
 
-        x, ((k, v), load) = jax.lax.scan(body, x, layers)
-        k_pools[kind] = to_pages(k, k_pools[kind], run)
-        v_pools[kind] = to_pages(v, v_pools[kind], run)
+            def attend_latent(q, row, _):
+                # Rebuilt: k and v of every head from the rows, for this
+                # attention alone; what is kept is the rows.
+                k, v = latent_kv(cfg, lp, row)
+                with jax.named_scope("attn.latent"):
+                    out = causal_attention(cfg, None, q, k, v)
+                return out, (row,)
+
+            x, kept, _aux, load = block(
+                cfg, lp, x, positions,
+                attend_latent if kind == "latent" else attend,
+                token_mask=token_mask, expert_stack=expert_stack, kind=kind)
+            return x, (kept, load)
+
+        x, (kept, load) = jax.lax.scan(body, x, layers)
+        pools[kind] = tuple(to_pages(rows, pool, run)
+                            for rows, pool in zip(kept, pools[kind]))
         if load is not None:
             expert_tokens.append(load)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bm,mv->bv", x[:, real_len - 1], params["lm_head"])
     lengths = cache.lengths.at[slot].set(real_len)
-    return logits.astype(jnp.float32), PagedKVCache(
-        k_pools, v_pools, cache.page_table, lengths
-    ), MoeLoad.of_layers(expert_tokens)
+    return logits.astype(jnp.float32), _with_pools(
+        cache, pools, lengths), MoeLoad.of_layers(expert_tokens)
 
 
 def sample_logits(logits: jax.Array, rng: jax.Array, *,

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -95,6 +96,21 @@ class LlamaConfig:
     post_norms: bool = False
     # The embedding rows times this (muP: sqrt(hidden_size)).
     embed_scale: float = 1.0
+    # Latent attention (MLA) when ``kv_lora_rank`` > 0, every layer of
+    # kind "latent": q through a bottleneck of ``q_lora_rank`` (normed),
+    # k and v rebuilt from one latent row of ``kv_lora_rank`` a token
+    # (normed) and one rotary key of ``qk_rope_head_dim`` shared by all
+    # heads. A head's q and k are ``qk_nope_head_dim`` + ``qk_rope_head_dim``
+    # wide (that sum is ``dh``; only the second part is rotated), its v
+    # ``v_head_dim``. What is cached is the latent row and the rotary key
+    # (generation.PagedKVCache's "latent" pool); ``num_kv_heads`` is unread.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Rotary over adjacent pairs (2i, 2i+1) instead of the two halves.
+    rope_interleave: bool = False
     remat: bool = True
     # "full" (save only layer inputs), "dots" (save matmul outputs,
     # recompute elementwise), or "save_all" (save every intermediate —
@@ -136,6 +152,17 @@ class LlamaConfig:
     def expert_size(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
 
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_row(self) -> int:
+        """Values a token's row of the latent pool holds: the latent and
+        the rotary key, the key on a lane tile (128) of its own so that
+        the latent is whole tiles and both are read by one copy."""
+        return self.kv_lora_rank + -(-self.qk_rope_head_dim // 128) * 128
+
     def window(self, kind: str) -> Optional[int]:
         """The attention window of a layer of ``kind``; None for none."""
         return self.sliding_window if kind == "window" else None
@@ -169,24 +196,34 @@ class LayerRun(NamedTuple):
     start: int
     n: int
     moe: bool
-    kind: str       # "full" | "window"
+    kind: str       # "full" | "window" | "latent"
     kv_offset: int
 
 
 def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
     """The model's layers as an ordered list of uniform runs, from what
     the config states; everything about a layer that a program needs to
-    know when it is traced. Llama, Mistral, OLMoE: one run, "full"."""
-    kinds = cfg.layer_types or ("full",) * cfg.num_layers
-    if len(kinds) != cfg.num_layers or set(kinds) - {"full", "window"}:
-        raise ValueError(
-            f"layer_types {kinds} must name 'full' or 'window' for each "
-            f"of {cfg.num_layers} layers")
+    know when it is traced. Llama, Mistral, OLMoE: one run, "full". A
+    model with latent attention: every layer "latent"."""
+    if cfg.latent:
+        if cfg.layer_types or cfg.dh != (cfg.qk_nope_head_dim
+                                          + cfg.qk_rope_head_dim):
+            raise ValueError(
+                f"latent attention: every layer is of the one kind (no "
+                f"layer_types) and head_dim ({cfg.dh}) is the q.k width, "
+                f"qk_nope_head_dim + qk_rope_head_dim")
+        kinds = ("latent",) * cfg.num_layers
+    else:
+        kinds = cfg.layer_types or ("full",) * cfg.num_layers
+        if len(kinds) != cfg.num_layers or set(kinds) - {"full", "window"}:
+            raise ValueError(
+                f"layer_types {kinds} must name 'full' or 'window' for each "
+                f"of {cfg.num_layers} layers")
     if "window" in kinds and not cfg.sliding_window:
         raise ValueError("window layers need a sliding_window")
     alike = [(cfg.n_experts > 0 and i >= cfg.num_dense_layers, kind)
              for i, kind in enumerate(kinds)]
-    runs, seen = [], {"full": 0, "window": 0}
+    runs, seen = [], {"full": 0, "window": 0, "latent": 0}
     for i, (moe, kind) in enumerate(alike):
         if runs and alike[i - 1] == (moe, kind):
             runs[-1] = runs[-1]._replace(n=runs[-1].n + 1)
@@ -215,13 +252,15 @@ def layer_stacks(params) -> Tuple[Dict[str, Any], ...]:
 
 def require_uniform(cfg: LlamaConfig, what: str) -> None:
     """Training scans ONE stack with ONE causal attention, and the flash
-    backward kernels take no window: a stack in runs or a window layer
-    trains nowhere yet (ROADMAP R3), and says so by name."""
-    if len(layer_runs(cfg)) > 1 or "window" in kv_layers(cfg):
+    backward kernels take no window and one width for q, k and v: a
+    stack in runs, a window layer or a latent-attention layer trains
+    nowhere yet (ROADMAP R3, R5), and says so by name."""
+    if len(layer_runs(cfg)) > 1 or set(kv_layers(cfg)) - {"full"}:
         raise NotImplementedError(
             f"{what}: training a model whose layer stack is not uniform "
             f"(dense layers before expert layers, window beside full "
-            f"attention) is not implemented; it is served only "
+            f"attention) or whose attention is latent (q.k and v of "
+            f"unequal widths) is not implemented; it is served only "
             f"(models/generation.py)")
 
 
@@ -271,6 +310,30 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     }
 
 
+# A leaf whose float32 form is larger than this is drawn a slice of its
+# leading axis at a time (``_normal``): 2**30 elements, the largest leaf
+# any configuration had before there was a larger one (OLMoE's experts,
+# [8, 64, 2048, 1024]), which are therefore drawn whole as ever.
+_WHOLE_LEAF_ELEMENTS = 2 ** 30
+
+
+def _normal(key, shape, scale: float, dtype):
+    """A leaf of normal values times ``scale`` in ``dtype``, drawn in
+    float32. A leaf is drawn whole, as it always was, unless it is over
+    ``_WHOLE_LEAF_ELEMENTS``: then a slice of its leading axis at a
+    time, each from a key of its own, so that the float32 temporaries
+    are a slice's (256 experts of a layer, 1.6 GB, where four layers'
+    whole would be 6.4 GB beside 11 GB of weights)."""
+    def draw(key, shape):
+        return (jax.random.normal(key, shape, dtype=jnp.float32)
+                * scale).astype(dtype)
+
+    if math.prod(shape) <= _WHOLE_LEAF_ELEMENTS:
+        return draw(key, shape)
+    return jax.lax.map(lambda k: draw(k, shape[1:]),
+                       jax.random.split(key, shape[0]))
+
+
 def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool) -> Dict[str, Any]:
     """``n`` alike layers' weights, stacked ``[n, ...]``, drawing keys
     from the iterator ``k`` in an order that never changes for a leaf
@@ -283,17 +346,35 @@ def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool) -> Dict[str, Any]:
         return jnp.ones(shape, dtype=jnp.float32)
 
     def winit(key, shape, fan_in):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * (fan_in ** -0.5)).astype(dt)
+        return _normal(key, shape, fan_in ** -0.5, dt)
 
-    layers: Dict[str, Any] = {
-        "attn_norm": norm_init((n, M)),
-        "wq": winit(next(k), (n, M, H, Dh), M),
-        "wk": winit(next(k), (n, M, Hkv, Dh), M),
-        "wv": winit(next(k), (n, M, Hkv, Dh), M),
-        "wo": winit(next(k), (n, H, Dh, M), H * Dh),
-        "mlp_norm": norm_init((n, M)),
-    }
+    if cfg.latent:
+        # The down-projections and their norms, the up-projections a
+        # head (``wk_b`` and ``wv_b`` are the two halves of the
+        # published kv_b_proj, W_UK and W_UV), the output projection.
+        Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        layers: Dict[str, Any] = {
+            "attn_norm": norm_init((n, M)),
+            "wq_a": winit(next(k), (n, M, Rq), M),
+            "q_a_norm": norm_init((n, Rq)),
+            "wq_b": winit(next(k), (n, Rq, H, Dh), Rq),
+            "wkv_a": winit(next(k), (n, M, Rkv + cfg.qk_rope_head_dim), M),
+            "kv_a_norm": norm_init((n, Rkv)),
+            "wk_b": winit(next(k), (n, Rkv, H, cfg.qk_nope_head_dim), Rkv),
+            "wv_b": winit(next(k), (n, Rkv, H, cfg.v_head_dim), Rkv),
+            "wo": winit(next(k), (n, H, cfg.v_head_dim, M),
+                        H * cfg.v_head_dim),
+            "mlp_norm": norm_init((n, M)),
+        }
+    else:
+        layers = {
+            "attn_norm": norm_init((n, M)),
+            "wq": winit(next(k), (n, M, H, Dh), M),
+            "wk": winit(next(k), (n, M, Hkv, Dh), M),
+            "wv": winit(next(k), (n, M, Hkv, Dh), M),
+            "wo": winit(next(k), (n, H, Dh, M), H * Dh),
+            "mlp_norm": norm_init((n, M)),
+        }
     if cfg.qk_norm:
         per_head = cfg.qk_norm_per_head
         layers.update(q_norm=norm_init((n, Dh if per_head else H * Dh)),
@@ -345,8 +426,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             for run in runs)
 
     def winit(key, shape):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * (M ** -0.5)).astype(cfg.dtype)
+        return _normal(key, shape, M ** -0.5, cfg.dtype)
 
     return {
         "embed": winit(next(k), (V, M)),
@@ -362,17 +442,28 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (xf * scale * w).astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         interleave: bool = False) -> jax.Array:
     """Rotary embedding; x [B, S, H, D]; positions [S], the same for every
     row (global indices, so sequence-sharded blocks stay correct), or
-    [B, S], each row's own (serving slots at different lengths)."""
+    [B, S], each row's own (serving slots at different lengths). Pair i
+    of a head, turned by ``positions * theta ** (-i / (D/2))``, is
+    dimensions (i, i + D/2), or with ``interleave`` (2i, 2i + 1)."""
     D = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [(B,) S, D/2]
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleave:
+        pairs = xf.reshape(x.shape[:-1] + (D // 2, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                        axis=-1).reshape(x.shape)
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                              axis=-1)
     return out.astype(x.dtype)
 
 
@@ -431,6 +522,74 @@ def qkv_proj(cfg: LlamaConfig, lp, x):
         g = jnp.einsum("bsm,mhd->bshd", h, lp["wg"])
         gate = jax.nn.sigmoid(g.astype(jnp.float32)).astype(g.dtype)
     return q, k, v, gate
+
+
+def _latent_row(cfg: LlamaConfig, latent, rotary):
+    """[.., kv_lora_rank] ‖ [.., qk_rope_head_dim] ‖ zeros up to
+    ``cfg.latent_row``: how a cached row is laid, and a query against it."""
+    pad = jnp.zeros(latent.shape[:-1] + (
+        cfg.latent_row - latent.shape[-1] - rotary.shape[-1],), latent.dtype)
+    return jnp.concatenate([latent, rotary, pad], axis=-1)
+
+
+def latent_proj(cfg: LlamaConfig, lp, x, positions):
+    """A latent layer's first half: attention norm, then the rotated
+    queries [B,S,H,dh] (each head ``qk_nope_head_dim`` unrotated values
+    and ``qk_rope_head_dim`` rotated ones) through the normed bottleneck
+    of ``q_lora_rank``, and the token's latent row [B,S,``latent_row``]:
+    the normed latent of ``kv_lora_rank``, then the one rotated key all
+    heads share, then zeros up to the lane tile. The row is what a cache
+    keeps (generation.PagedKVCache) and all that a later token needs."""
+    nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+    with jax.named_scope("mla.q"):
+        c_q = rms_norm(jnp.einsum("bsm,mr->bsr", h, lp["wq_a"]),
+                       lp["q_a_norm"], cfg.rms_eps)
+        q = jnp.einsum("bsr,rhd->bshd", c_q, lp["wq_b"])
+        q = jnp.concatenate(
+            [q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta,
+                                 cfg.rope_interleave)], axis=-1)
+    with jax.named_scope("mla.kv"):
+        kv = jnp.einsum("bsm,mr->bsr", h, lp["wkv_a"])
+        c = rms_norm(kv[..., :rank], lp["kv_a_norm"], cfg.rms_eps)
+        k_rope = rope(kv[..., None, rank:], positions, cfg.rope_theta,
+                      cfg.rope_interleave)[..., 0, :]
+        row = _latent_row(cfg, c, k_rope)
+    return q, row
+
+
+def latent_kv(cfg: LlamaConfig, lp, row):
+    """k [B,S,H,dh] and v [B,S,H,``v_head_dim``] rebuilt from the latent
+    rows [B,S,``latent_row``]: what a prefill attends with. A head's k
+    is its own up-projection of the latent beside the shared rotary
+    key."""
+    rank, rot = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    with jax.named_scope("mla.kv"):
+        c = row[..., :rank]
+        k_nope = jnp.einsum("bsr,rhd->bshd", c, lp["wk_b"])
+        v = jnp.einsum("bsr,rhd->bshd", c, lp["wv_b"])
+        k_rope = jnp.broadcast_to(
+            row[..., None, rank:rank + rot],
+            k_nope.shape[:-1] + (rot,))
+        return jnp.concatenate([k_nope, k_rope], axis=-1), v
+
+
+def latent_absorb_q(cfg: LlamaConfig, lp, q):
+    """Queries [B,S,H,dh] against latent rows instead of rebuilt keys:
+    W_UK goes into the query, ``q_nope_h wk_b_h^T`` [kv_lora_rank], so
+    that ``q . row`` is ``q_nope . k_nope + q_rope . k_rope`` without a
+    k_nope ever made. Returns [B,S,H,``latent_row``], laid as a row."""
+    nope = cfg.qk_nope_head_dim
+    with jax.named_scope("mla.absorb"):
+        q_lat = jnp.einsum("bshd,rhd->bshr", q[..., :nope], lp["wk_b"])
+        return _latent_row(cfg, q_lat, q[..., nope:])
+
+
+def latent_absorb_out(cfg: LlamaConfig, lp, attn):
+    """W_UV behind the attention: probabilities times latent rows
+    [B,S,H,kv_lora_rank] to a head's [B,S,H,``v_head_dim``]."""
+    with jax.named_scope("mla.absorb"):
+        return jnp.einsum("bshr,rhd->bshd", attn, lp["wv_b"])
 
 
 EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
@@ -514,14 +673,22 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
     attention (a window, a latent cache) is another ``attend``.
     ``mesh``, ``token_mask``, ``expert_stack``: ``ffn``'s. ``kind``,
     the layer's attention kind, is the caller's ``attend`` to honour;
-    here it decides only whether q and k are rotated.
+    here it decides whether q and k are rotated, and for "latent" what
+    the projections are: ``attend`` is then given the rotated queries,
+    the tokens' latent rows in k's place and no v (``latent_proj``), and
+    returns [B,S,H,``v_head_dim``]; whether it rebuilds k and v
+    (``latent_kv``) or absorbs the up-projections (``latent_absorb_q``,
+    ``latent_absorb_out``) is its own to choose.
 
     Returns (x, attend's state, load-balancing loss, tokens assigned to
     each expert or None)."""
-    q, k, v, gate = qkv_proj(cfg, lp, x)
-    if cfg.rope_full_layers or kind != "full":
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    if kind == "latent":
+        (q, k), v, gate = latent_proj(cfg, lp, x, positions), None, None
+    else:
+        q, k, v, gate = qkv_proj(cfg, lp, x)
+        if cfg.rope_full_layers or kind != "full":
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
     q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"),
                                 mesh=mesh)
     attn, state = attend(q, k, v)

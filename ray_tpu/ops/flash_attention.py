@@ -43,7 +43,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
     from jax.experimental import pallas as pl
 
     block_q = q_ref.shape[1]
-    head_dim = q_ref.shape[2]
+    head_dim = v_ref.shape[2]  # the output's width is v's
     skv = k_ref.shape[1]
     nk = skv // block_k
     qi = pl.program_id(1)
@@ -103,12 +103,13 @@ def _flash_fwd(q3, k3, v3, *, heads: int, kv_heads: int, scale: float,
                causal: bool, q_offset: int, kv_offset: int,
                block_q: int, block_k: int, interpret: bool = False,
                window: Optional[int] = None):
-    """q3: [B*H, Sq, D]; k3/v3: [B*Hkv, Skv, D] → [B*H, Sq, D]."""
+    """q3: [B*H, Sq, D]; k3: [B*Hkv, Skv, D]; v3: [B*Hkv, Skv, Dv] →
+    [B*H, Sq, Dv]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, sq, d = q3.shape
-    skv = k3.shape[1]
+    skv, dv = k3.shape[1], v3.shape[2]
     rep = heads // kv_heads
     grid = (bh, sq // block_q)
 
@@ -126,7 +127,7 @@ def _flash_fwd(q3, k3, v3, *, heads: int, kv_heads: int, scale: float,
     return pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), q3.dtype),
             # [bh, 1, sq]: a (1, 1, block) tile satisfies the TPU
             # (8, 128)-divisible-or-full block rule; flat [bh, sq] can't.
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
@@ -137,11 +138,11 @@ def _flash_fwd(q3, k3, v3, *, heads: int, kv_heads: int, scale: float,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, skv, d), kv_index,
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, skv, d), kv_index,
+            pl.BlockSpec((1, skv, dv), kv_index,
                          memory_space=pltpu.VMEM),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
+            pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j),
                          memory_space=pltpu.VMEM),
@@ -415,7 +416,7 @@ def _flash_attention_core(q, k, v, causal, scale, q_offset, kv_offset,
         block_q=block_q, block_k=block_k, interpret=interpret,
         window=window,
     )
-    return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    return o3.reshape(B, H, Sq, v.shape[3]).transpose(0, 2, 1, 3)
 
 
 def _core_fwd(q, k, v, causal, scale, q_offset, kv_offset, block_q,
@@ -426,6 +427,11 @@ def _core_fwd(q, k, v, causal, scale, q_offset, kv_offset, block_q,
         raise NotImplementedError(
             "flash_attention(window=...) has no backward kernel: the "
             "forward kernel alone takes a window (serving's prefill)")
+    if v.shape[3] != q.shape[3]:
+        raise NotImplementedError(
+            "flash_attention with a v narrower than q and k (latent "
+            "attention's 192/128) has no backward kernel: the forward "
+            "kernel alone takes unequal widths (serving's prefill)")
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     q3, k3, v3 = _to_heads3(q), _to_heads3(k), _to_heads3(v)
@@ -481,7 +487,9 @@ def flash_attention(
     """Flash attention with GQA and global-coordinate causal masking
     (same signature as ops.attention.mha_attention), of the last
     ``window`` positions where one is given (causal only; forward only:
-    under ``jax.grad`` it raises). The one place that
+    under ``jax.grad`` it raises). v may have a width of its own, [B,
+    Skv, Hkv, Dv] (latent attention rebuilt: q and k 192, v 128), which
+    is then the output's; forward only likewise. The one place that
     says when the Pallas kernel runs: on a TPU, for sequences that are
     whole blocks (128 rows unless the caller names another size: a
     serving bucket of 16, 32 or 64 tokens is no block, and no shorter

@@ -51,6 +51,20 @@ CPU): scatter the rows into the whole pool, gather every slot's
 ``Pmax`` pages of the layer, attend densely with the GQA group as a
 dimension of the queries, mask by length. It copies the pool, and its
 work is in proportion to ``B * Pmax * page`` whatever ``lengths`` says.
+
+A LATENT pool (latent attention, ``llama.latent_proj``) holds one row a
+token for all heads, ``[L, P, page, W]``: the latent, the shared rotary
+key, zeros up to whole lane tiles. :func:`latent_decode_attention`
+attends every head's absorbed query, laid as such a row, over the
+slot's rows; the scores are ``q . row`` over all W and the values the
+rows' first ``values``, so a row is read once for both. ``latent_walk``
+is the page walk's scheme over such a pool (one program a slot, one
+copy a page with every "head" in it, the new row set in VMEM and its
+page copied back through the aliased output); per block of
+``_LATENT_BLOCK_TOKENS`` it is two matmuls of all H query rows, no GQA
+group to pick: 2 H (W + values) operations a row of 2 W bytes, some 60
+a byte at 32 heads over 576 + 512, where a k/v walk does 2. ``gather``
+is its XLA path.
 """
 
 from __future__ import annotations
@@ -279,6 +293,186 @@ def paged_decode_attention(
       v_new.astype(dtype)[:, :, None], k_pool, v_pool)
 
 
+# Tokens a compute step of the latent walk covers: the rows are narrow
+# (one for all heads), so a block is made long enough that a step's
+# fixed costs are spread over some hundred kilobytes of them; contexts
+# here are thousands of tokens.
+_LATENT_BLOCK_TOKENS = 256
+
+
+def _latent_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, new_ref,
+                        pool_hbm, o_ref, pool_out, buf, sems, *, pmax: int,
+                        scale: float):
+    """Grid (B,). pt_ref [B * Pmax], np_ref [B] (pages to walk), len_ref
+    [B], layer_ref [1] in SMEM; q_ref [H, W] this slot's absorbed
+    queries, laid as rows; new_ref [1, W] its new row; pool_hbm the pool
+    [L, P, page, W] left in HBM and pool_out the same buffer as an
+    output; o_ref [H, values]; buf [2, block, W] VMEM; sems [2, 2] DMA
+    (rows in by buffer, then the new row's page back)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    values = o_ref.shape[1]
+    _, block, _ = buf.shape
+    page = pool_hbm.shape[2]
+    pages_per_block = block // page
+    layer = layer_ref[0]
+    n_pages = np_ref[b]
+    n_blocks = (n_pages + pages_per_block - 1) // pages_per_block
+    length = len_ref[b]
+
+    def copies(i, at):
+        """As the page walk's: past the slot's last page the last one is
+        read again, so the buffer holds nothing but pool rows."""
+        out = []
+        for j in range(pages_per_block):
+            p = jnp.minimum(i * pages_per_block + j, n_pages - 1)
+            out.append(pltpu.make_async_copy(
+                pool_hbm.at[layer, pt_ref[b * pmax + p]],
+                buf.at[at, pl.ds(j * page, page), :], sems.at[0, at]))
+        return out
+
+    last = n_blocks - 1
+    pid_new = pt_ref[b * pmax + jnp.maximum(n_pages - 1, 0)]
+    rows_new = pl.ds(pl.multiple_of(
+        (n_pages - 1 - last * pages_per_block) * page, page), page)
+
+    def write_back():
+        return pltpu.make_async_copy(buf.at[last % 2, rows_new, :],
+                                     pool_out.at[layer, pid_new],
+                                     sems.at[1, 0])
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    q = q_ref[...]
+
+    def body(i, carry):
+        m, l, acc = carry
+        at = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            for c in copies(i + 1, 1 - at):
+                c.start()
+
+        for c in copies(i, at):
+            c.wait()
+
+        @pl.when(i == last)
+        def _new_row():
+            held = buf[at, rows_new, :]
+            is_new = jax.lax.broadcasted_iota(
+                jnp.int32, held.shape, 0) == length % page
+            buf[at, rows_new, :] = jnp.where(is_new, new_ref[...], held)
+            write_back().start()
+
+        rows = buf[at]                                    # [block, W]
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [H, block]
+        t = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(t <= length, s * scale, _NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        prob = jnp.exp(s - m_new)
+        l = alpha * l + prob.sum(axis=1, keepdims=True)
+        pv = jnp.dot(prob.astype(rows.dtype), rows[:, :values],
+                     preferred_element_type=jnp.float32)  # [H, values]
+        return m_new, l, acc * alpha + pv
+
+    H = q.shape[0]
+    m0 = jnp.full((H, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((H, 1), jnp.float32)
+    acc0 = jnp.zeros((H, values), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
+    # A slot that walked nothing (inactive) writes zeros.
+    o_ref[...] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    @pl.when(n_blocks > 0)
+    def _written():
+        write_back().wait()
+
+
+def paged_latent_decode_attention(
+    q: jax.Array,           # [B, H, W] absorbed queries, laid as rows
+    row_new: jax.Array,     # [B, W] the token's row per slot
+    pool: jax.Array,        # [L, P, page, W]
+    layer: jax.Array,       # [] int32
+    page_table: jax.Array,  # [B, Pmax] int32
+    lengths: jax.Array,     # [B] int32: the new row's position
+    active: jax.Array,      # [B] bool: an inactive slot walks no page
+    *,
+    scale: float,
+    values: int,
+    interpret: bool = False,
+):
+    """The latent walk. Returns ([B, H, values], pool): rows of inactive
+    slots are zeros, the pool is the argument's buffer with the active
+    slots' rows written."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, W = q.shape
+    page = pool.shape[2]
+    Pmax = page_table.shape[1]
+    pages_per_block = max(1, _LATENT_BLOCK_TOKENS // page)
+    n_pages = jnp.where(active, jnp.minimum(lengths // page + 1, Pmax),
+                        0).astype(jnp.int32)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    dtype = pool.dtype
+    kernel = functools.partial(_latent_walk_kernel, pmax=Pmax, scale=scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((None, H, W), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec((None, 1, W), lambda b, *_: (b, 0, 0)),
+                      hbm],
+            out_specs=[pl.BlockSpec((None, H, values),
+                                    lambda b, *_: (b, 0, 0)), hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, pages_per_block * page, W), dtype),
+                pltpu.SemaphoreType.DMA((2, 2))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, H, values), q.dtype),
+                   jax.ShapeDtypeStruct(pool.shape, dtype)],
+        # Operands count the four prefetched scalars: the pool is 6.
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(page_table.reshape(-1).astype(jnp.int32), n_pages,
+      lengths.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q.astype(dtype), row_new.astype(dtype)[:, None], pool)
+
+
+def gather_latent_decode_attention(q, row_new, pool, layer, page_table,
+                                   lengths, active, *, scale, values):
+    """The XLA path: the kernel's arguments and results, bar that an
+    inactive slot's row of the attention is computed (and discarded by
+    the caller)."""
+    B, _, W = q.shape
+    _, n_pool, page, _ = pool.shape
+    T = page_table.shape[1] * page
+    # Inactive slots aim past the pool (``gather_decode_attention``).
+    drop = jnp.where(active, page_table[jnp.arange(B), lengths // page],
+                     n_pool)
+    pool = pool.at[layer, drop, lengths % page].set(
+        row_new.astype(pool.dtype), mode="drop")
+    rows = jnp.take(pool[layer], page_table, axis=0).reshape(B, T, W)
+    s = jnp.einsum("bhw,btw->bht", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    attends = jnp.arange(T)[None, :] <= lengths[:, None]
+    s = jnp.where(attends[:, None], s, -jnp.inf)
+    prob = jax.nn.softmax(s, axis=-1).astype(rows.dtype)
+    return jnp.einsum("bht,btr->bhr", prob, rows[..., :values]), pool
+
+
 def gather_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
                             page_table, lengths, active, *, window=None):
     """The XLA path: the kernel's arguments and results, bar that an
@@ -326,13 +520,20 @@ def pageable(page: int, head_dim: int) -> bool:
     return head_dim % 128 == 0 and page % 16 == 0
 
 
-def decode_attention_path(page: int, head_dim: int) -> str:
+def decode_attention_path(page: int, head_dim: int,
+                          values: Optional[int] = None) -> str:
     """``"page_walk"`` or ``"gather"``: what :func:`decode_attention`
-    runs for this pool here. ``LLMEngine.stats()`` reports it."""
+    runs for this pool here; for a latent pool, whose rows are
+    ``head_dim`` wide and hold ``values`` of latent, ``"latent_walk"``
+    or ``"gather"``: what :func:`latent_decode_attention` runs.
+    ``LLMEngine.stats()`` reports it."""
     from .flash_attention import _on_tpu
 
-    return "page_walk" if _on_tpu() and pageable(page, head_dim) \
-        else "gather"
+    if not (_on_tpu() and pageable(page, head_dim)):
+        return "gather"
+    if values is None:
+        return "page_walk"
+    return "latent_walk" if values % 128 == 0 else "gather"
 
 
 def ring_pages(window: int, page: int, max_pages: int) -> int:
@@ -357,3 +558,19 @@ def decode_attention(q, k_new, v_new, k_pool, v_pool, layer, page_table,
             else gather_decode_attention)
     return path(q, k_new, v_new, k_pool, v_pool, layer, page_table,
                 lengths, active, window=window)
+
+
+def latent_decode_attention(q, row_new, pool, layer, page_table, lengths,
+                            active, *, scale: float, values: int):
+    """Write each active slot's ``row_new`` [B, W] into the latent pool
+    [L, P, page, W] at ``layer`` and position ``lengths[b]``, and attend
+    the absorbed queries [B, H, W] over rows ``0 .. lengths[b]``: scores
+    ``scale * q . row``, values the rows' first ``values``. Returns
+    (attention [B, H, values], pool), by the path
+    :func:`decode_attention_path` names."""
+    page, W = pool.shape[2:]
+    path = (paged_latent_decode_attention
+            if decode_attention_path(page, W, values) == "latent_walk"
+            else gather_latent_decode_attention)
+    return path(q, row_new, pool, layer, page_table, lengths, active,
+                scale=scale, values=values)

@@ -308,7 +308,10 @@ class LLMEngine:
         self.page_size = page_size
         # What the decode program below is built with: the same call
         # paged_decode's attention makes when the program is traced.
-        self._decode_attention = decode_attention_path(page_size, cfg.dh)
+        self._decode_attention = (
+            decode_attention_path(page_size, cfg.latent_row,
+                                  cfg.kv_lora_rank)
+            if cfg.latent else decode_attention_path(page_size, cfg.dh))
         self.max_pages_per_seq = math.ceil(max_len / page_size)
         # Default pool: enough for every slot at max_len (same worst case
         # as a dense cache); pass a smaller total_pages to oversubscribe.
@@ -328,6 +331,11 @@ class LLMEngine:
         self._pools = PagedKVCache.sizes(
             cfg, max_batch, self.total_pages, page_size,
             self.max_pages_per_seq)
+        # What a token holds in one layer of each pool, as allocated.
+        self._row_bytes = {
+            kind: sum(pool.nbytes for pool in self.cache.pools(kind))
+            // (layers * pages * page_size)
+            for kind, (layers, pages, _) in self._pools.items()}
         self._free_pages: Dict[str, List[int]] = {}
         self._table: Dict[str, np.ndarray] = {}
         self._new_books()
@@ -423,9 +431,14 @@ class LLMEngine:
         Gauges: ``active_slots``, ``free_slots``, ``free_pages`` (of the
         pool that keeps everything, "full", or of the only pool),
         ``pages`` (``{kind: {"layers", "total", "free"}}``, every pool),
+        ``kv_row_bytes`` (``{kind: bytes}``: what a token holds in one
+        layer of that pool, the pool's bytes over its tokens and layers:
+        k and v of every KV head, or a latent pool's one row, padding
+        and all),
         ``queued`` (submitted, not yet admitted), beside the constants
         ``platform``, ``device_kind``, ``total_pages``, ``page_size`` and
-        ``decode_attention`` (``"page_walk"`` or ``"gather"``: the path of
+        ``decode_attention`` (``"page_walk"``, for a latent pool
+        ``"latent_walk"``, or ``"gather"``: the path of
         ops/paged_attention.py the decode program was built with).
 
         Counts: ``decode_steps``; ``decode_slot_steps`` (sequences, summed
@@ -435,7 +448,8 @@ class LLMEngine:
         ``decode_kv_rows_read`` (the rows the steps' attention read,
         summed over steps, sequences and layers: a sequence's cached
         tokens, on a window layer at most the window; without window
-        layers ``decode_kv_tokens`` times the layers);
+        layers ``decode_kv_tokens`` times the layers; a latent layer's
+        rows are one a token, whatever the heads);
         ``kv_page_steps_held`` (pages the live sequences held, each
         times its pool's layers, summed over decode steps) and
         ``kv_page_steps_one_table`` (what they would have held with one
@@ -528,6 +542,7 @@ class LLMEngine:
                                  "free": len(self._free_pages[kind])}
                           for kind, (layers, total, _) in
                           self._pools.items()},
+                "kv_row_bytes": dict(self._row_bytes),
                 "total_pages": self.total_pages,
                 "page_size": self.page_size,
                 "decode_attention": self._decode_attention,

@@ -198,3 +198,21 @@ def test_flash_window_none_is_todays_kernel_and_a_window_has_no_grad():
         flash_attention(q, k, v, causal=False, window=64, interpret=True)
     with pytest.raises(ValueError, match="causal"):
         mha_attention(q, k, v, causal=False, window=64)
+
+
+@pytest.mark.parametrize("S", [128, 512])
+def test_flash_forward_takes_a_v_narrower_than_q_and_k(S):
+    """Latent attention rebuilt: q and k 192 wide, v 128, the scale
+    192 ** -0.5; the output is v's width. Against the XLA einsum. The
+    backward kernels take one width: a gradient raises by name."""
+    B, H = 1, 4
+    q, k = _rand((B, S, H, 192), 0), _rand((B, S, H, 192), 1)
+    v = _rand((B, S, H, 128), 2)
+    ref = mha_attention(q, k, v, causal=True)
+    out = flash_attention(q, k, v, causal=True, interpret=True)
+    assert out.shape == (B, S, H, 128)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    with pytest.raises(NotImplementedError, match="narrower than q and k"):
+        jax.grad(lambda q: flash_attention(
+            q, k, v, causal=True, interpret=True).sum())(q)

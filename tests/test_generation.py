@@ -399,3 +399,129 @@ def test_dense_programs_return_no_expert_load():
     _, _, load = paged_decode(params, jnp.zeros(2, jnp.int32), cache, cfg,
                               active=jnp.asarray([True, False]))
     assert load is None
+
+
+# ---- latent attention: one row a token for all heads (PR 42) ---------------
+
+_LATENT_CASES = {
+    # lengths, active, pages a slot, layers, layer
+    "mid_page_and_page_ends": ([37, 0, 255, 16], [True, True, True, True],
+                               16, 2, 1),
+    "an_inactive_slot": ([37, 200, 90], [True, False, True], 16, 3, 0),
+    "all_inactive": ([5, 70], [False, False], 8, 1, 0),
+    "over_a_block": ([300, 511, 256], [True, True, True], 32, 2, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(_LATENT_CASES))
+@pytest.mark.parametrize("path", ["latent_walk", "gather"])
+def test_latent_decode_attention_matches_reference(path, case):
+    """Both decode attentions over a latent pool (the Pallas latent walk
+    in interpret mode, the XLA gather): the new row of each active slot
+    lands in ``[layer, page_table[b, len // page], len % page]`` and
+    every other cell of the pool is bit-identical (an inactive slot
+    writes nothing); the attention is, by hand, every head's softmax of
+    ``scale * q . row`` over rows ``0 .. len`` times the rows' first
+    ``values``. Contexts end mid-page, on a page's last row and past a
+    block of 256; pages are walked out of order."""
+    from ray_tpu.ops import paged_attention as pa
+
+    lengths, active, pmax, n_layers, layer = _LATENT_CASES[case]
+    B, H, W, values, page, scale = len(lengths), 8, 256, 128, 16, 0.07
+    n_pool = B * pmax
+    rng = np.random.RandomState(len(case))
+    q = jnp.asarray(rng.randn(B, H, W), jnp.float32)
+    new = jnp.asarray(rng.randn(B, W), jnp.float32)
+    pool = jnp.asarray(rng.randn(n_layers, n_pool, page, W), jnp.float32)
+    table = rng.permutation(n_pool).reshape(B, pmax).astype(np.int32)
+    active = np.asarray(active)
+    want = np.array(pool)
+    for b in np.flatnonzero(active):
+        want[layer, table[b, lengths[b] // page], lengths[b] % page] = new[b]
+    args = (q, new, pool, jnp.asarray(layer, jnp.int32), jnp.asarray(table),
+            jnp.asarray(lengths, jnp.int32), jnp.asarray(active))
+    if path == "latent_walk":
+        out, got = pa.paged_latent_decode_attention(
+            *args, scale=scale, values=values, interpret=True)
+        assert not np.asarray(out)[~active].any()
+    else:
+        out, got = pa.gather_latent_decode_attention(
+            *args, scale=scale, values=values)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert out.shape == (B, H, values) and got.dtype == pool.dtype
+    for b in np.flatnonzero(active):
+        rows = want[layer][table[b]].reshape(pmax * page, W)[:lengths[b] + 1]
+        s = np.asarray(q)[b] @ rows.T * scale
+        p = np.exp(s - s.max(-1, keepdims=True))
+        ref = (p / p.sum(-1, keepdims=True)) @ rows[:, :values]
+        np.testing.assert_allclose(np.asarray(out)[b], ref, atol=2e-5,
+                                   rtol=2e-5)
+
+
+def _latent_cfg(**changes):
+    return LlamaConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=4, num_kv_heads=4, head_dim=24, rope_theta=10_000.0,
+        dtype=jnp.float32, q_lora_rank=24, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=12,
+        rope_interleave=True, **changes)
+
+
+def test_absorbed_attention_is_the_rebuilt_one_on_the_same_weights():
+    """One latent layer's weights, 50 tokens. Rebuilt: k and v of every
+    head from the rows, causal attention (a prefill's). Absorbed: W_UK
+    into the query, every head over the rows themselves, W_UV behind (a
+    decode step's), here for the last token over a pool that holds the
+    49 before it. The same [H, v_head_dim], to float32's rounding."""
+    from ray_tpu.models import llama
+    from ray_tpu.ops import paged_attention as pa
+    from ray_tpu.ops.attention import mha_attention
+
+    cfg = _latent_cfg()
+    assert cfg.latent and cfg.latent_row == 32 + 128
+    lp = jax.tree.map(lambda p: p[1],
+                      init_params(cfg, jax.random.PRNGKey(2))["layers"])
+    T, page = 50, 16
+    x = jnp.asarray(np.random.RandomState(0).randn(1, T, 32), jnp.float32)
+    q, rows = llama.latent_proj(cfg, lp, x, jnp.arange(T))
+    assert q.shape == (1, T, 4, 24) and rows.shape == (1, T, 160)
+    assert not np.asarray(rows)[..., 40:].any()          # the lane padding
+    k, v = llama.latent_kv(cfg, lp, rows)
+    assert k.shape == (1, T, 4, 24) and v.shape == (1, T, 4, 12)
+    rebuilt = mha_attention(q, k, v, causal=True)[0, -1]
+    # The pool: the first 49 rows in pages 3, 1, 0, 2; the 50th is new.
+    table = jnp.asarray([[3, 1, 0, 2]], jnp.int32)
+    held = jnp.zeros((64, 160)).at[:T - 1].set(rows[0, :T - 1])
+    pool = jnp.zeros((1, 4, page, 160)).at[0, table[0]].set(
+        held.reshape(4, page, 160))
+    q_lat = llama.latent_absorb_q(cfg, lp, q[:, -1:])
+    assert q_lat.shape == (1, 1, 4, 160)
+    out, pool = pa.gather_latent_decode_attention(
+        q_lat[:, 0], rows[:, -1], pool, jnp.asarray(0), table,
+        jnp.asarray([T - 1]), jnp.asarray([True]), scale=24 ** -0.5,
+        values=32)
+    absorbed = llama.latent_absorb_out(cfg, lp, out[:, None])[0, 0]
+    np.testing.assert_allclose(np.asarray(absorbed), np.asarray(rebuilt),
+                               atol=2e-6, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(pool[0, 2, 1]),  # 49 = 3 * 16 + 1
+                                  np.asarray(rows[0, -1]))
+
+
+def test_latent_cache_is_one_pool_of_rows_and_training_raises_by_name():
+    from ray_tpu.models import causal_lm_loss
+    from ray_tpu.models.llama import kv_layers, layer_runs
+
+    cfg = _latent_cfg()
+    assert [tuple(r) for r in layer_runs(cfg)] == [(0, 2, False, "latent", 0)]
+    assert kv_layers(cfg) == {"latent": 2}
+    cache = PagedKVCache.create(cfg, 3, 12, 16, 4)
+    assert {k: v.shape for k, v in cache.k.items()} == {
+        "latent": (2, 12, 16, 160)}
+    assert cache.v == {} and cache.page_size == 16
+    assert cache.page_table["latent"].shape == (3, 4)
+    assert PagedKVCache.sizes(cfg, 3, 12, 16, 4) == {"latent": (2, 12, 4)}
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    with pytest.raises(NotImplementedError, match="attention is latent"):
+        causal_lm_loss(params, jnp.zeros((1, 9), jnp.int32), cfg)
+    with pytest.raises(ValueError, match="q.k width"):
+        layer_runs(_latent_cfg(layer_types=("full", "full")))

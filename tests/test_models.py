@@ -104,3 +104,27 @@ def test_resnet18_forward_and_grad():
     loss, g = jax.value_and_grad(loss_fn)(variables["params"])
     assert np.isfinite(float(loss))
     assert all(np.isfinite(np.asarray(l)).all() for l in jax.tree.leaves(g))
+
+
+def test_rope_interleave_turns_adjacent_pairs():
+    """A hand-made case: 4 dims, theta 100, position 3. Pair 0 turns by
+    3 rad, pair 1 by 3 / 10 rad; interleaved the pairs are dims (0, 1)
+    and (2, 3), by halves they are (0, 2) and (1, 3). Position 0 turns
+    nothing."""
+    import numpy as np
+
+    from ray_tpu.models.llama import rope
+
+    x = jnp.asarray([[[[1.0, 2.0, 3.0, 4.0]]] * 2])       # [1, 2, 1, 4]
+    got = np.asarray(rope(x, jnp.asarray([0, 3]), 100.0, interleave=True))
+    np.testing.assert_allclose(got[0, 0, 0], [1, 2, 3, 4], atol=1e-6)
+    c0, s0, c1, s1 = np.cos(3.0), np.sin(3.0), np.cos(0.3), np.sin(0.3)
+    np.testing.assert_allclose(
+        got[0, 1, 0],
+        [1 * c0 - 2 * s0, 1 * s0 + 2 * c0, 3 * c1 - 4 * s1, 3 * s1 + 4 * c1],
+        rtol=1e-5)
+    halves = np.asarray(rope(x, jnp.asarray([0, 3]), 100.0))
+    np.testing.assert_allclose(
+        halves[0, 1, 0],
+        [1 * c0 - 3 * s0, 2 * c1 - 4 * s1, 1 * s0 + 3 * c0, 2 * s1 + 4 * c1],
+        rtol=1e-5)

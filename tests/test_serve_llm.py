@@ -691,3 +691,96 @@ def test_admission_waits_on_whichever_pool_is_short(window_model, short):
         assert stats["pages"][short]["free"] == (5 if short == "full" else 3)
     finally:
         engine.shutdown()
+
+
+# ---- a model with latent attention: one pool of rows (PR 42) ---------------
+
+@pytest.fixture(scope="module")
+def latent_model():
+    """1 dense + 3 expert layers, q.k 24 beside v 12, ranks 24 and 32:
+    the benchmark's tiny JoyAI (tests/bench_harness/joyai_tiny)."""
+    import json
+    import os
+
+    from benchmark import arch
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "bench_harness", "joyai_tiny",
+                           "config.json")) as f:
+        config = json.load(f)
+    cfg = arch.program_config(config)
+    return config, cfg, jax.jit(lambda key: init_params(cfg, key))(
+        jax.random.PRNGKey(3))
+
+
+def test_latent_engine_serves_within_tolerance_of_the_reference(latent_model):
+    """Through the engine, four streams at once at different lengths, 60
+    tokens each: prefill rebuilds k and v, decode attends absorbed over
+    the latent pool. Every served token's logit lies within 1e-4 of the
+    plain reference's best at its position (teacher-forced, one full
+    forward, no cache, no absorption)."""
+    import jax.numpy as jnp
+
+    from benchmark import arch
+
+    config, cfg, params = latent_model
+    reference = arch.reference(config)
+    engine = LLMEngine(cfg, params, max_batch=4, max_len=256, page_size=16,
+                       total_pages=48)
+    try:
+        rng = np.random.RandomState(0)
+        prompts = [list(rng.randint(0, 256, n)) for n in (10, 25, 40, 100)]
+        reqs = [engine.submit(p, 60) for p in prompts]
+        outs = [r.result(timeout=300) for r in reqs]
+    finally:
+        engine.shutdown()
+    seqs = np.zeros((4, max(len(p) for p in prompts) + 60), np.int32)
+    for row, prompt, out in zip(seqs, prompts, outs):
+        row[:len(prompt) + 60] = prompt + out
+    margins = np.asarray(jax.jit(
+        lambda params, seqs: reference.logit_margins(params, seqs, config))(
+            params, jnp.asarray(seqs)))
+    for row, prompt in zip(margins, prompts):
+        assert row[len(prompt) - 1:len(prompt) + 59].max() <= 1e-4
+
+
+def test_latent_pool_pages_are_held_from_admission_to_finish(latent_model):
+    """A 100-token context (60 + 40) holds 7 pages of the one pool, of
+    kind "latent", from admission to its end; they return on finish.
+    The counters: a step at context c reads c rows in each of 4 layers;
+    a row is 32 + 128 float32 values."""
+    _, cfg, params = latent_model
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=256, page_size=16,
+                       total_pages=20)
+    try:
+        stats = engine.stats()
+        assert stats["pages"] == {
+            "latent": {"layers": 4, "total": 20, "free": 20}}
+        assert stats["kv_row_bytes"] == {"latent": 160 * 4}
+        assert stats["decode_attention"] == "gather"          # on the CPU
+        req = engine.submit(list(range(60)), max_new_tokens=40)
+        _wait_until(lambda: engine.stats()["active_slots"] == 1)
+        held = engine.stats()
+        assert held["pages"]["latent"]["free"] == held["free_pages"] == 20 - 7
+        assert len(req.result(timeout=300)) == 40
+        stats = engine.stats()
+        assert stats["pages"]["latent"]["free"] == stats["free_pages"] == 20
+        contexts = range(61, 100)        # 39 decode steps after the prefill
+        assert stats["decode_steps"] == 39
+        assert stats["decode_kv_tokens"] == sum(contexts)
+        assert stats["decode_kv_rows_read"] == 4 * sum(contexts)
+        assert stats["kv_page_steps_held"] == \
+            stats["kv_page_steps_one_table"] == 39 * 4 * 7
+        assert stats["moe"]["layer_steps"] == 39 * 3
+    finally:
+        engine.shutdown()
+
+
+def test_a_k_and_v_pool_says_what_its_rows_hold(tiny_model):
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=64, page_size=16)
+    try:
+        # k and v, 2 KV heads of 16 float32 values each.
+        assert engine.stats()["kv_row_bytes"] == {"full": 2 * 2 * 16 * 4}
+    finally:
+        engine.shutdown()

@@ -13,6 +13,7 @@ page 16 for serving.
 """
 
 import dataclasses
+import functools
 import importlib
 import math
 import os
@@ -408,3 +409,105 @@ def test_trinity_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket):
     assert _fits_one_chip(compiled)
     memory = compiled.memory_analysis()
     print(bucket, memory.temp_size_in_bytes / 2**30, "GiB of temporaries")
+
+
+def _joyai():
+    """``joyai-llm-flash-L5`` as the benchmark builds it, and its engine."""
+    import json
+
+    from benchmark import arch
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "joyai-llm-flash-L5.json")) as f:
+        config = json.load(f)
+    return arch.program_config(config), config["engine"]
+
+
+def _joyai_shapes(v5e):
+    cfg, engine = _joyai()
+    params, cache = _serve_shapes(
+        cfg, v5e, engine["max_batch"], engine["total_pages"],
+        engine["max_len"] // PAGE)
+    return cfg, engine, params, cache
+
+
+LATENT_POOL = (5, 8192, PAGE, 640)
+
+
+def test_latent_walk_kernel_compiles_for_v5e(v5e):
+    """32 absorbed query rows of a slot against its rows of 640 (the
+    latent's 512, the rotary key's 64 on a lane tile of its own), the
+    values the rows' first 512: the pool goes in whole and comes back
+    through the aliased output."""
+    batch, pages_per_seq = 32, 8192 // PAGE
+    compiled = jax.jit(
+        functools.partial(paged_attention.paged_latent_decode_attention,
+                          scale=192 ** -0.5, values=512),
+        donate_argnums=(2,),
+    ).lower(
+        _arr(v5e, (batch, 32, 640)), _arr(v5e, (batch, 640)),
+        _arr(v5e, LATENT_POOL), _arr(v5e, (), jnp.int32),
+        _arr(v5e, (batch, pages_per_seq), jnp.int32),
+        _arr(v5e, (batch,), jnp.int32), _arr(v5e, (batch,), jnp.bool_),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_pool_stays_in_place(compiled, LATENT_POOL)
+
+
+def test_joyai_decode_program_compiles_for_v5e(v5e, as_tpu):
+    """Two scans (the dense layer, four expert layers) over the one
+    latent pool, 256 experts a layer read in place: the pool is neither
+    copied, sliced nor re-stacked, and no k or v pool exists."""
+    cfg, engine, params, cache = _joyai_shapes(v5e)
+    assert {k: v.shape for k, v in cache.k.items()} == {"latent": LATENT_POOL}
+    assert cache.v == {}
+    batch = engine["max_batch"]
+
+    def decode(params, cache, tok, active):
+        return generation.paged_decode(params, tok, cache, cfg, active=active)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (batch,), jnp.int32),
+        _arr(v5e, (batch,), jnp.bool_)).compile()
+    assert _fits_one_chip(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_pool_stays_in_place(compiled, LATENT_POOL)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * math.prod(LATENT_POOL)
+
+
+@pytest.mark.parametrize("bucket", [2048, 8192])
+def test_joyai_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket):
+    """The cell's smallest and largest bucket: the flash kernel with a
+    head's whole K (192 wide) and V (128 wide) in VMEM and no
+    [32, bucket, bucket] of scores anywhere, 8 x bucket rows through the
+    grouped matmuls of 1024 groups, beside 11.1 GB of weights."""
+    cfg, engine, params, cache = _joyai_shapes(v5e)
+
+    def prefill(params, cache, tokens, real_len, slot, pages):
+        return generation.paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages)
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (1, bucket), jnp.int32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
+        {"latent": _arr(v5e, (bucket // PAGE,), jnp.int32)},
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"f32[32,{bucket},{bucket}]" not in text
+    assert f"f32[1,32,{bucket},{bucket}]" not in text
+    assert _fits_one_chip(compiled)
+    memory = compiled.memory_analysis()
+    print(bucket, memory.temp_size_in_bytes / 2**30, "GiB of temporaries")
+
+
+def test_joyai_weights_are_made_within_one_chip(v5e):
+    """``init_params`` as the benchmark jits it: the experts' leaves are
+    drawn a layer at a time, so the float32 temporaries beside 11.1 GB
+    of weights stay under the chip's 16 GB."""
+    cfg, _ = _joyai()
+    compiled = jax.jit(lambda key: init_params(cfg, key)).lower(
+        _arr(v5e, (2,), jnp.uint32)).compile()
+    assert _fits_one_chip(compiled)
