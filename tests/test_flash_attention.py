@@ -1,6 +1,9 @@
 """Pallas flash attention kernel tests (interpret mode on the CPU mesh;
 the same kernel compiles for real on TPU)."""
 
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -126,11 +129,11 @@ def test_flash_decode_offset_gradients():
 def test_flash_gqa_gradients_perhead_fallback(monkeypatch):
     """Shapes whose grouped [rep, Sq, D] Q/dO block would overflow VMEM
     use the per-query-head dkv kernel + external group sum; force that
-    path by zeroing the VMEM budget and check grads still match XLA."""
+    path and check grads still match XLA."""
     import importlib
 
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
-    monkeypatch.setattr(fa, "_DKV_GROUP_VMEM_BUDGET", 0)
+    monkeypatch.setattr(fa, "_dkv_grouped", lambda *shape: False)
     B, S, H, Hkv, D = 1, 128, 4, 2, 32
     q = _rand((B, S, H, D), 0)
     k = _rand((B, S, Hkv, D), 1)
@@ -216,3 +219,197 @@ def test_flash_forward_takes_a_v_narrower_than_q_and_k(S):
     with pytest.raises(NotImplementedError, match="narrower than q and k"):
         jax.grad(lambda q: flash_attention(
             q, k, v, causal=True, interpret=True).sum())(q)
+
+
+# -- PR 44: bfloat16 operands on the MXU, float32 accumulation -----------
+
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
+
+# name: (Sq, Skv, H, Hkv, D, Dv, named blocks, q_offset, kv_offset,
+# window, grouped dK/dV kernel)
+_BF16_CASES = {
+    "one_block": (128, 128, 2, 2, 128, 128, None, 0, 0, None, True),
+    "s384_only_128_divides": (384, 384, 2, 2, 128, 128, None, 0, 0, None,
+                              True),
+    "block_q_256_block_k_128": (512, 512, 2, 2, 128, 128, (256, 128), 0, 0,
+                                None, True),
+    "block_q_128_block_k_256": (512, 512, 2, 2, 128, 128, (128, 256), 0, 0,
+                                None, True),
+    "offsets_move_the_diagonal": (256, 512, 2, 2, 128, 128, (128, 256),
+                                  320, 64, None, True),
+    "window_edge_inside_a_block": (512, 512, 4, 2, 128, 128, (256, 128), 0,
+                                   0, 200, True),
+    "widths_192_128": (256, 256, 2, 2, 192, 128, None, 0, 0, None, True),
+    "gqa_grouped": (256, 256, 4, 2, 128, 128, None, 0, 0, None, True),
+    "gqa_per_head_fallback": (256, 256, 4, 2, 128, 128, None, 0, 0, None,
+                              False),
+}
+# dQ, dK and dV exist where the backward kernels do: no window, one width.
+_BF16_PARAMS = [
+    (name, out) for name, case in _BF16_CASES.items()
+    for out in (("o",) if case[9] is not None or case[4] != case[5]
+                else ("o", "dq", "dk", "dv"))
+]
+
+
+@functools.lru_cache(maxsize=None)
+def _bf16_case(name):
+    """(the kernel's, the float32 einsum's, the bfloat16 einsum's) o, dq,
+    dk, dv on the same bfloat16 inputs, each as float32 arrays."""
+    (Sq, Skv, H, Hkv, D, Dv, named, q_off, kv_off, window,
+     grouped) = _BF16_CASES[name]
+    bf16 = jnp.bfloat16
+    q = _rand((1, Sq, H, D), 0).astype(bf16)
+    k = _rand((1, Skv, Hkv, D), 1).astype(bf16)
+    v = _rand((1, Skv, Hkv, Dv), 2).astype(bf16)
+    w = _rand((1, Sq, H, Dv), 3)  # the loss weighs every output
+    kw = dict(causal=True, q_offset=q_off, kv_offset=kv_off, window=window)
+    named = {} if named is None else dict(block_q=named[0],
+                                          block_k=named[1])
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, interpret=True, **named, **kw)
+
+    def einsum(dtype):
+        return lambda q, k, v: mha_attention(
+            q.astype(dtype), k.astype(dtype), v.astype(dtype), **kw)
+
+    dkv_grouped = fa._dkv_grouped
+    if not grouped:
+        fa._dkv_grouped = lambda *shape: False
+    try:
+        results = []
+        for fn in (flash, einsum(jnp.float32), einsum(bf16)):
+            out = {"o": fn(q, k, v)}
+            if window is None and D == Dv:
+                grads = jax.grad(
+                    lambda q, k, v: (fn(q, k, v).astype(jnp.float32)
+                                     * w).sum(), argnums=(0, 1, 2))(q, k, v)
+                out.update(zip(("dq", "dk", "dv"), grads))
+            results.append({n: np.asarray(a.astype(jnp.float32))
+                            for n, a in out.items()})
+    finally:
+        fa._dkv_grouped = dkv_grouped
+    assert results[0]["o"].dtype == np.float32
+    return results
+
+
+@pytest.mark.parametrize("name,out", _BF16_PARAMS,
+                         ids=[f"{n}-{o}" for n, o in _BF16_PARAMS])
+def test_flash_bf16_operands_match_the_float32_einsum(name, out):
+    """bfloat16 q, k, v (and dO) go to the matmuls as they are and are
+    accumulated in float32: against the float32 einsum of the same
+    bfloat16 inputs the kernel stays within bfloat16's tolerance, one
+    rounding of the result (2**-8 of its size) and one of the
+    probabilities, and is no further off than the repo's own bfloat16
+    einsum, the path the same prefill takes at buckets under a block."""
+    kernel, exact, einsum = (r[out] for r in _bf16_case(name))
+    size = np.abs(exact).max()
+    np.testing.assert_allclose(kernel, exact, atol=2 ** -6 * size, rtol=0)
+    err = np.linalg.norm(kernel - exact) / np.linalg.norm(exact)
+    einsum_err = np.linalg.norm(einsum - exact) / np.linalg.norm(exact)
+    assert err < 2 ** -7, err
+    assert err < 1.5 * einsum_err + 1e-4, (err, einsum_err)
+
+
+def test_flash_float32_caller_is_computed_in_float32():
+    """A float32 caller reads what it read: float32's tolerance against
+    the einsum at the chosen blocks (256 here) with GQA and offsets,
+    forward and all three gradients, and no bfloat16 anywhere in the
+    traced program."""
+    B, Sq, Skv, H, Hkv, D = 1, 256, 512, 4, 2, 64
+    q, k, v = (_rand((B, Sq, H, D), 0), _rand((B, Skv, Hkv, D), 1),
+               _rand((B, Skv, Hkv, D), 2))
+    assert fa.choose_blocks(Sq, Skv, D, D, 2, 4).fwd == (256, 512)
+    kw = dict(causal=True, q_offset=200, kv_offset=8)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, interpret=True, **kw)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    ref = functools.partial(mha_attention, **kw)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=3e-5, rtol=3e-5)
+    text = str(jax.make_jaxpr(jax.grad(loss(flash), argnums=(0, 1, 2)))(
+        q, k, v))
+    assert "bf16" not in text
+
+
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation under ``jaxpr``, in order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_pallas_calls(sub))
+    return found
+
+
+@pytest.mark.parametrize("S,H,Hkv,D,Dv,window", [
+    (2048, 32, 8, 128, 128, None),     # Mistral's prefill, training's S
+    (8192, 32, 4, 128, 128, 2048),     # Trinity's window layers
+    (4096, 32, 32, 192, 128, None),    # JoyAI: q and k 192, v 128
+])
+def test_flash_forward_call_writes_what_the_benchmark_reads(
+        S, H, Hkv, D, Dv, window):
+    """``benchmark/readers/window.py:FLASH`` knows a prefill's forward
+    kernel by its outputs: ``pallas_<dtype>_<B·H>_<S>_<Dv>_f32_<B·H>_1_<S>``
+    is o [B·H, S, Dv] in the input's dtype, then the float32 lse
+    [B·H, 1, S], and nothing else. A third output, another order or a
+    flat lse would silence ``prefill_flash_roofline.chat``."""
+    from benchmark.readers.window import FLASH
+
+    def arr(heads, width):
+        return jax.ShapeDtypeStruct((1, S, heads, width), jnp.bfloat16)
+
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, interpret=True))(
+            arr(H, D), arr(Hkv, D), arr(Hkv, Dv))
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    outs = [(v.aval.shape, str(v.aval.dtype)) for v in call.outvars]
+    assert outs == [((H, S, Dv), "bfloat16"), ((H, 1, S), "float32")]
+    # XLA names the custom call by its results: dtype, then dimensions.
+    name = "pallas_" + "_".join(
+        "_".join([{"bfloat16": "bf16", "float32": "f32"}[dtype],
+                  *map(str, shape)]) for shape, dtype in outs)
+    match = FLASH.match(name)
+    assert match and int(match.group(2)) == S, name
+
+
+def test_flash_block_choice_is_a_pure_function_of_the_shape():
+    """The shape-to-block table of every caller there is: a later edit
+    that sends a bucket to the einsum (None), or that lets the choice
+    depend on anything but these arguments, fails here. Training is
+    Mistral's and Nemo's b8 x 2048 (groups of 4: Q and dO of the four
+    heads stay in VMEM, so dK/dV's grid block is the smaller); the
+    buckets are every power of two a serving cell pads a prompt to."""
+    choose = fa.choose_blocks
+    assert choose(2048, 2048, 128, 128, 4, 2) == fa.Blocks(
+        fwd=(512, 512), dq=(512, 512), dkv=(512, 256))
+    assert fa._dkv_grouped(4, 2048, 128, 2)
+    assert not fa._dkv_grouped(4, 4096, 128, 2)  # 16 MB of Q and dO
+    for bucket in (128, 256, 512, 1024, 2048, 4096, 8192):
+        for rep, d, dv in ((4, 128, 128),    # Mistral
+                           (1, 128, 128),    # OLMoE
+                           (8, 128, 128),    # Trinity
+                           (1, 192, 128)):   # JoyAI
+            got = choose(bucket, bucket, d, dv, rep, 2)
+            # K and V at 192/128 of 8192 tokens are 12 MB of the 16.
+            want = ((256, 512) if (bucket, d) == (8192, 192)
+                    else (min(bucket, 512),) * 2)
+            assert got is not None and got.fwd == want, (bucket, d, got)
+    # Under a block, or where 128 does not divide: the einsum's.
+    for s in (16, 32, 64, 100, 192):
+        assert choose(s, s, 128, 128, 4, 2) is None
+    # float32 callers and unequal lengths are tiled by the same rule.
+    assert choose(256, 512, 64, 64, 2, 4).fwd == (256, 512)
+    assert choose(384, 384, 128, 128, 1, 2).fwd == (128, 128)
