@@ -1,8 +1,10 @@
 """The serving programs of the Llama family over the paged KV cache.
 
-The engine's cache is the paged one (``PagedKVCache``): a shared pool of
-token pages and a page table a slot, for each attention kind the model
-has. Two jitted programs use it, both
+The engine's cache is the paged one (``PagedKVCache``): for each of the
+four attention kinds a model may have ("full", "window", "latent",
+"state") a pool, and for the three that keep a row a token a shared
+pool of token pages and a page table a slot. Two jitted programs use
+it, both
 built on the one transformer block (``llama.block``) with an attention
 of their own, both a layer scan for each run of alike layers
 (``llama.layer_runs``: one run for a uniform model), and all their
@@ -28,6 +30,13 @@ into the query and behind the attention and attends every head over the
 rows themselves (``ops/paged_attention.latent_decode_attention``). The
 same function of the same weights, at the cost each phase can bear.
 
+A retention layer (kind "state", ops/retention.py) keeps no row a token
+but a state of fixed size a slot, in a pool that has no pages: the
+prefill runs the chunked scan from an empty state, with the bucket's
+padding masked out of it (a gate of 1, a key of 0), and lays the state
+it is left with into the slot; the decode step updates every active
+slot's state in place and reads the token's output from it.
+
 No reference counterpart — Ray delegates model serving compute to user
 code; this framework owns it (continuous batching sits on top in
 ray_tpu.serve.llm).
@@ -43,6 +52,7 @@ import jax.numpy as jnp
 from ..ops.paged_attention import (
     decode_attention, latent_decode_attention, ring_pages,
 )
+from ..ops.retention import retention_decode, retention_prefill, state_shape
 from .llama import (
     LlamaConfig, block, causal_attention, embed_tokens, kv_layers,
     latent_absorb_out, latent_absorb_q, latent_kv, layer_runs, layer_stacks,
@@ -89,6 +99,13 @@ class PagedKVCache(NamedTuple):
     keeps every token as a "full" one does, but ONE row for all heads
     (``llama.latent_proj``): its pool is ``k["latent"]``,
     [L, P, page, ``cfg.latent_row``], and ``v`` has no such entry.
+    A "state" layer (power retention) keeps no token at all but a state
+    of fixed size a slot: its pool is ``k["state"]``,
+    ``ops/retention.state_shape`` [L, B, Hkv, T, R, Dh] float32, again
+    without a ``v``; it has NO pages (``sizes`` gives it none, its page
+    table has no column), a slot is all a request needs of it, a prefill
+    overwrites the slot's state whole and nothing is zeroed at release.
+    The class keeps its name though such a model pages nothing.
 
     A k/v pool is HEAD-MAJOR
     ([L_kind, Hkv, P_kind, page, Dh]): one copy brings a page of every KV
@@ -103,16 +120,19 @@ class PagedKVCache(NamedTuple):
     step before PR 29, PERF.md §6)."""
 
     k: Dict[str, jax.Array]            # kind -> [L_kind, Hkv, P, page, Dh]
-    v: Dict[str, jax.Array]            # ("latent": k alone, [L, P, page, W])
+    v: Dict[str, jax.Array]            # ("latent", "state": k alone)
     page_table: Dict[str, jax.Array]   # kind -> [B, columns] int32 page ids
     lengths: jax.Array                 # [B] int32 valid tokens per slot
 
     @property
-    def page_size(self) -> int:
-        return next(iter(self.k.values())).shape[-2]
+    def page_size(self) -> Optional[int]:
+        """Tokens a page holds; None for a model that pages nothing."""
+        return next((pool.shape[-2] for kind, pool in self.k.items()
+                     if kind != "state"), None)
 
     def pools(self, kind: str) -> Tuple[jax.Array, ...]:
-        """The pools of ``kind``: (k, v), or the one of latent rows."""
+        """The pools of ``kind``: (k, v), or the one of latent rows, or
+        the one of states."""
         return tuple(d[kind] for d in (self.k, self.v) if kind in d)
 
     @staticmethod
@@ -126,6 +146,8 @@ class PagedKVCache(NamedTuple):
                 ring = ring_pages(cfg.sliding_window, page_size,
                                   max_pages_per_seq)
                 out[kind] = (layers, batch * ring, ring)
+            elif kind == "state":
+                out[kind] = (layers, 0, 0)
             else:
                 out[kind] = (layers, total_pages, max_pages_per_seq)
         return out
@@ -138,13 +160,21 @@ class PagedKVCache(NamedTuple):
         sizes = PagedKVCache.sizes(cfg, batch, total_pages, page_size,
                                    max_pages_per_seq)
 
-        def pools(latent):
-            return {kind: jnp.zeros(
-                (layers, pages, page_size, cfg.latent_row)
-                if kind == "latent" else
+        def pool(kind, layers, pages):
+            if kind == "state":
+                return jnp.zeros(state_shape(layers, batch, cfg.num_kv_heads,
+                                             cfg.dh), dtype=jnp.float32)
+            if kind == "latent":
+                return jnp.zeros((layers, pages, page_size, cfg.latent_row),
+                                 dtype=cfg.dtype)
+            return jnp.zeros(
                 (layers, cfg.num_kv_heads, pages, page_size, cfg.dh),
-                dtype=cfg.dtype) for kind, (layers, pages, _) in sizes.items()
-                if latent or kind != "latent"}
+                dtype=cfg.dtype)
+
+        def pools(one_only):
+            return {kind: pool(kind, layers, pages)
+                    for kind, (layers, pages, _) in sizes.items()
+                    if one_only or kind not in ("latent", "state")}
 
         return PagedKVCache(
             k=pools(True), v=pools(False),
@@ -180,7 +210,9 @@ def paged_decode(
     xs and ys a pool would be sliced and re-stacked, pool-sized copies
     every step (PagedKVCache). An inactive slot's pages and length stay
     as they are, and it reaches no expert: the experts a step reads
-    follow the live sequences."""
+    follow the live sequences. A "state" layer's pool is carried the
+    same way; its step updates each active slot's state and leaves an
+    inactive slot's as it is."""
     x = embed_tokens(params, tokens, cfg)[:, None]
     pools = {kind: cache.pools(kind) for kind in cache.k}
     expert_tokens = []
@@ -217,10 +249,19 @@ def paged_decode(
                         values=cfg.kv_lora_rank)
                 return latent_absorb_out(cfg, lp, out[:, None]), (pool,)
 
+            def attend_state(q, k, v_gate):
+                v, log_g = v_gate
+                with jax.named_scope("attn.state"):
+                    out, pool = retention_decode(
+                        q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], *held,
+                        lp["index"] + run.kv_offset, active)
+                return out[:, None], (pool,)
+
             # The load-balancing loss is a training-only term: dropped.
             x, held, _aux, load = block(
                 cfg, lp, x, cache.lengths[:, None],
-                attend_latent if kind == "latent" else attend,
+                {"latent": attend_latent, "state": attend_state}.get(
+                    kind, attend),
                 token_mask=active[:, None], expert_stack=expert_stack,
                 kind=kind)
             return (x, held), load
@@ -258,7 +299,10 @@ def paged_prefill(
     bucket; for "window" the first ``min(S_bucket // page, columns)``
     columns of the slot's ring, into which go only the pages a later
     token can still attend to, the last of them the one that holds
-    ``real_len - 1``.
+    ``real_len - 1``; for "state" none: what a retention layer keeps
+    is the state after token ``real_len - 1``, laid into the slot whole,
+    and the padding never reaches it (a padded token's gate is 1 and
+    its key 0), so that a prompt leaves the same state in any bucket.
     Returns the run's ``MoeLoad`` too (None for a dense model)."""
     S = tokens.shape[1]
     page = cache.page_size
@@ -272,6 +316,10 @@ def paged_prefill(
         """[n, 1, S, Hkv, Dh] -> [n, Hkv, S // page, page, Dh], the
         pool's layout, set at the run's layers and the slot's page ids;
         latent rows [n, 1, S, W] -> [n, S // page, page, W] likewise."""
+        if run.kind == "state":
+            # [n, Hkv, T, R, Dh]: the slot's states of the run's layers.
+            return jax.lax.dynamic_update_slice(
+                pool, rows[:, None], (run.kv_offset, slot, 0, 0, 0, 0))
         ids = pages[run.kind]
         whole = run.n == pool.shape[0]
         at = slice(None) if whole else slice(run.kv_offset,
@@ -310,9 +358,19 @@ def paged_prefill(
                     out = causal_attention(cfg, None, q, k, v)
                 return out, (row,)
 
+            def attend_state(q, k, v_gate):
+                v, log_g = v_gate
+                real = positions < real_len
+                with jax.named_scope("attn.state"):
+                    out, state = retention_prefill(
+                        q[0], jnp.where(real[:, None, None], k[0], 0),
+                        v[0], jnp.where(real[:, None], log_g[0], 0.0))
+                return out[None], (state,)
+
             x, kept, _aux, load = block(
                 cfg, lp, x, positions,
-                attend_latent if kind == "latent" else attend,
+                {"latent": attend_latent, "state": attend_state}.get(
+                    kind, attend),
                 token_mask=token_mask, expert_stack=expert_stack, kind=kind)
             return x, (kept, load)
 

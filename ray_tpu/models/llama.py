@@ -83,6 +83,12 @@ class LlamaConfig:
     route_scale: float = 1.0
     # A layer's attention kind, "window" or "full" (None: all full). A
     # window layer's token t attends to t - sliding_window < j <= t.
+    # Or "state" for every layer: power retention of degree 2
+    # (ops/retention.py), a gated linear attention whose weights are
+    # (q.k)^2 / dh, decayed by a gate a KV head a token,
+    # log g = log_sigmoid(h wg + bg) (``wg`` [hidden, Hkv], ``bg`` [Hkv]
+    # float32). What is kept is a state of fixed size a slot and no row
+    # a token (generation.PagedKVCache's "state" pool).
     layer_types: Optional[Tuple[str, ...]] = None
     sliding_window: Optional[int] = None
     # Rotary on every layer, or on window layers alone.
@@ -163,6 +169,10 @@ class LlamaConfig:
         the latent is whole tiles and both are read by one copy."""
         return self.kv_lora_rank + -(-self.qk_rope_head_dim // 128) * 128
 
+    @property
+    def retention(self) -> bool:
+        return bool(self.layer_types) and "state" in self.layer_types
+
     def window(self, kind: str) -> Optional[int]:
         """The attention window of a layer of ``kind``; None for none."""
         return self.sliding_window if kind == "window" else None
@@ -196,7 +206,7 @@ class LayerRun(NamedTuple):
     start: int
     n: int
     moe: bool
-    kind: str       # "full" | "window" | "latent"
+    kind: str       # "full" | "window" | "latent" | "state"
     kv_offset: int
 
 
@@ -204,7 +214,8 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
     """The model's layers as an ordered list of uniform runs, from what
     the config states; everything about a layer that a program needs to
     know when it is traced. Llama, Mistral, OLMoE: one run, "full". A
-    model with latent attention: every layer "latent"."""
+    model with latent attention: every layer "latent"; one with power
+    retention: every layer "state"."""
     if cfg.latent:
         if cfg.layer_types or cfg.dh != (cfg.qk_nope_head_dim
                                           + cfg.qk_rope_head_dim):
@@ -213,6 +224,16 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
                 f"layer_types) and head_dim ({cfg.dh}) is the q.k width, "
                 f"qk_nope_head_dim + qk_rope_head_dim")
         kinds = ("latent",) * cfg.num_layers
+    elif cfg.retention:
+        kinds = cfg.layer_types
+        if (set(kinds) != {"state"} or len(kinds) != cfg.num_layers
+                or cfg.attn_gate or cfg.num_heads % cfg.num_kv_heads):
+            raise ValueError(
+                f"power retention: every one of {cfg.num_layers} layers is "
+                f"of kind 'state' (a state beside a KV cache in one model "
+                f"is not implemented: ROADMAP R7), whole groups of query "
+                f"heads a KV head, and no attn_gate (its wg is the "
+                f"retention gate's name); got {kinds}")
     else:
         kinds = cfg.layer_types or ("full",) * cfg.num_layers
         if len(kinds) != cfg.num_layers or set(kinds) - {"full", "window"}:
@@ -223,7 +244,7 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
         raise ValueError("window layers need a sliding_window")
     alike = [(cfg.n_experts > 0 and i >= cfg.num_dense_layers, kind)
              for i, kind in enumerate(kinds)]
-    runs, seen = [], {"full": 0, "window": 0, "latent": 0}
+    runs, seen = [], dict.fromkeys(("full", "window", "latent", "state"), 0)
     for i, (moe, kind) in enumerate(alike):
         if runs and alike[i - 1] == (moe, kind):
             runs[-1] = runs[-1]._replace(n=runs[-1].n + 1)
@@ -253,15 +274,17 @@ def layer_stacks(params) -> Tuple[Dict[str, Any], ...]:
 def require_uniform(cfg: LlamaConfig, what: str) -> None:
     """Training scans ONE stack with ONE causal attention, and the flash
     backward kernels take no window and one width for q, k and v: a
-    stack in runs, a window layer or a latent-attention layer trains
-    nowhere yet (ROADMAP R3, R5), and says so by name."""
+    stack in runs, a window layer, a latent-attention layer or a
+    retention layer (kind "state": its chunked scan has no backward)
+    trains nowhere yet (ROADMAP R3, R5, R7), and says so by name."""
     if len(layer_runs(cfg)) > 1 or set(kv_layers(cfg)) - {"full"}:
         raise NotImplementedError(
             f"{what}: training a model whose layer stack is not uniform "
             f"(dense layers before expert layers, window beside full "
-            f"attention) or whose attention is latent (q.k and v of "
-            f"unequal widths) is not implemented; it is served only "
-            f"(models/generation.py)")
+            f"attention), whose attention is latent (q.k and v of "
+            f"unequal widths) or whose layers are of kind 'state' (power "
+            f"retention: the chunked scan has no backward pass) is not "
+            f"implemented; it is served only (models/generation.py)")
 
 
 # Logical axes for each parameter leaf (maps through DEFAULT_RULES:
@@ -334,7 +357,19 @@ def _normal(key, shape, scale: float, dtype):
                        jax.random.split(key, shape[0]))
 
 
-def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool) -> Dict[str, Any]:
+def _retention_gate_bias(n: int, kv_heads: int) -> jax.Array:
+    """``bg`` [n, Hkv] float32: KV head i's gate remembers about
+    ``tau_i`` tokens, 32 to 4,096 in equal ratios over the heads, so
+    sigmoid(bg) = 1 - 1/tau. A gate without a bias is 1/2 in the median
+    for any weights drawn around zero, a memory of two tokens: neither
+    a long context nor the state's precision would be exercised."""
+    span = jnp.arange(kv_heads, dtype=jnp.float32) / max(kv_heads - 1, 1)
+    tau = 32.0 * 128.0 ** span
+    return jnp.broadcast_to(jnp.log(tau - 1.0), (n, kv_heads))
+
+
+def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
+                kind: str = "full") -> Dict[str, Any]:
     """``n`` alike layers' weights, stacked ``[n, ...]``, drawing keys
     from the iterator ``k`` in an order that never changes for a leaf
     that is there (new leaves draw last): a seed's weights stay what
@@ -408,6 +443,9 @@ def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool) -> Dict[str, Any]:
     if cfg.post_norms:
         layers.update(post_attn_norm=norm_init((n, M)),
                       post_mlp_norm=norm_init((n, M)))
+    if kind == "state":
+        layers.update(wg=winit(next(k), (n, M, Hkv), M),
+                      bg=_retention_gate_bias(n, Hkv))
     return layers
 
 
@@ -418,11 +456,13 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     runs = layer_runs(cfg)
     k = iter(jax.random.split(key, 16))
     if len(runs) == 1:
-        layers = _init_stack(cfg, k, cfg.num_layers, runs[0].moe)
+        layers = _init_stack(cfg, k, cfg.num_layers, runs[0].moe,
+                             runs[0].kind)
     else:
         layers = tuple(
             _init_stack(cfg, iter(jax.random.split(
-                jax.random.fold_in(key, run.start), 16)), run.n, run.moe)
+                jax.random.fold_in(key, run.start), 16)), run.n, run.moe,
+                run.kind)
             for run in runs)
 
     def winit(key, shape):
@@ -503,7 +543,9 @@ def qkv_proj(cfg: LlamaConfig, lp, x):
     [B,S,H,Dh] and k, v [B,S,Hkv,Dh], q and k normed where the model has
     a QK-norm (over their whole projection, or over each head's ``dh``),
     and the output gate sigmoid(h wg) [B,S,H,Dh] where it has one (else
-    None)."""
+    None); for a retention layer the fourth is the tokens' log gates
+    log_sigmoid(h wg + bg) [B,S,Hkv] float32, which go to its attention
+    and not behind it."""
     B, S, _ = x.shape
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
@@ -521,6 +563,10 @@ def qkv_proj(cfg: LlamaConfig, lp, x):
     if cfg.attn_gate:
         g = jnp.einsum("bsm,mhd->bshd", h, lp["wg"])
         gate = jax.nn.sigmoid(g.astype(jnp.float32)).astype(g.dtype)
+    elif cfg.retention:
+        with jax.named_scope("ret.gate"):
+            g = jnp.einsum("bsm,mn->bsn", h, lp["wg"])
+            gate = jax.nn.log_sigmoid(g.astype(jnp.float32) + lp["bg"])
     return q, k, v, gate
 
 
@@ -678,7 +724,8 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
     the tokens' latent rows in k's place and no v (``latent_proj``), and
     returns [B,S,H,``v_head_dim``]; whether it rebuilds k and v
     (``latent_kv``) or absorbs the up-projections (``latent_absorb_q``,
-    ``latent_absorb_out``) is its own to choose.
+    ``latent_absorb_out``) is its own to choose. For "state" ``attend``
+    is given ``(v, log gates)`` in v's place (``qkv_proj``).
 
     Returns (x, attend's state, load-balancing loss, tokens assigned to
     each expert or None)."""
@@ -691,9 +738,12 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
             k = rope(k, positions, cfg.rope_theta)
     q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"),
                                 mesh=mesh)
-    attn, state = attend(q, k, v)
-    if gate is not None:
-        attn = attn * gate
+    if kind == "state":
+        attn, state = attend(q, k, (v, gate))
+    else:
+        attn, state = attend(q, k, v)
+        if gate is not None:
+            attn = attn * gate
     attn = jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
     if cfg.post_norms:
         attn = rms_norm(attn, lp["post_attn_norm"], cfg.rms_eps)

@@ -21,6 +21,11 @@ at most a ring of ``window / page + 1`` in the "window" pool, which the
 decode programs then turn through by themselves. The loop allocates and
 frees nothing between admission and finish.
 
+A model of retention layers (kind "state") holds a state of fixed size
+a slot and no pages: its pool has none, a request needs none, and
+admission is by slot alone. ``max_len`` then bounds the rotary
+positions and the prefill bucket, not memory.
+
 The loop runs ONE decode step ahead of the one it reads. What a step
 needs lies on the device: the last tokens (a step's output is the next
 one's input; a prefill sets its slot's), the PRNG key (split inside the
@@ -212,7 +217,8 @@ _COUNTERS = ("decode_slot_steps", "decode_kv_tokens", "decode_kv_rows_read",
              "kv_page_steps_held", "kv_page_steps_one_table", "prefills",
              "prefill_tokens", "prefill_bucket_tokens", "submitted",
              "admitted", "finished", "failed", "cache_resets", "page_waits",
-             "decode_steps_ahead", "decode_slot_steps_discarded")
+             "decode_steps_ahead", "decode_slot_steps_discarded",
+             "decode_state_slot_layers")
 _PHASES = ("admit", "admit_stalling", "inputs", "decode", "readback",
            "emit", "idle")
 _REQUEST_ROWS = 1024
@@ -291,6 +297,7 @@ class LLMEngine:
         from ..models.generation import PagedKVCache
         from ..models.llama import layer_runs
         from ..ops.paged_attention import decode_attention_path
+        from ..ops.retention import retention_path
 
         self.cfg = cfg
         self.params = params
@@ -308,10 +315,13 @@ class LLMEngine:
         self.page_size = page_size
         # What the decode program below is built with: the same call
         # paged_decode's attention makes when the program is traced.
-        self._decode_attention = (
-            decode_attention_path(page_size, cfg.latent_row,
-                                  cfg.kv_lora_rank)
-            if cfg.latent else decode_attention_path(page_size, cfg.dh))
+        if cfg.retention:
+            self._decode_attention = retention_path(cfg.dh)
+        elif cfg.latent:
+            self._decode_attention = decode_attention_path(
+                page_size, cfg.latent_row, cfg.kv_lora_rank)
+        else:
+            self._decode_attention = decode_attention_path(page_size, cfg.dh)
         self.max_pages_per_seq = math.ceil(max_len / page_size)
         # Default pool: enough for every slot at max_len (same worst case
         # as a dense cache); pass a smaller total_pages to oversubscribe.
@@ -331,11 +341,18 @@ class LLMEngine:
         self._pools = PagedKVCache.sizes(
             cfg, max_batch, self.total_pages, page_size,
             self.max_pages_per_seq)
-        # What a token holds in one layer of each pool, as allocated.
+        # What a token holds in one layer of each pool that has pages,
+        # and what a slot holds in one layer of one that has none, as
+        # allocated.
+        held = {kind: sum(pool.nbytes for pool in self.cache.pools(kind))
+                for kind in self._pools}
         self._row_bytes = {
-            kind: sum(pool.nbytes for pool in self.cache.pools(kind))
-            // (layers * pages * page_size)
-            for kind, (layers, pages, _) in self._pools.items()}
+            kind: held[kind] // (layers * pages * page_size)
+            for kind, (layers, pages, _) in self._pools.items() if pages}
+        self._slot_bytes = {
+            kind: held[kind] // (layers * max_batch)
+            for kind, (layers, pages, _) in self._pools.items() if not pages}
+        self._state_layers = self._pools.get("state", (0,))[0]
         self._free_pages: Dict[str, List[int]] = {}
         self._table: Dict[str, np.ndarray] = {}
         self._new_books()
@@ -434,12 +451,17 @@ class LLMEngine:
         ``kv_row_bytes`` (``{kind: bytes}``: what a token holds in one
         layer of that pool, the pool's bytes over its tokens and layers:
         k and v of every KV head, or a latent pool's one row, padding
-        and all),
+        and all; a pool without pages has no entry),
+        ``state_slot_bytes`` (``{kind: bytes}``: what a slot holds in one
+        layer of a pool that has no pages, a retention layer's state and
+        normaliser, padding and all; empty for a model that has none),
         ``queued`` (submitted, not yet admitted), beside the constants
         ``platform``, ``device_kind``, ``total_pages``, ``page_size`` and
         ``decode_attention`` (``"page_walk"``, for a latent pool
         ``"latent_walk"``, or ``"gather"``: the path of
-        ops/paged_attention.py the decode program was built with).
+        ops/paged_attention.py the decode program was built with; for a
+        model of retention layers ops/retention.py's ``"state_kernel"``
+        or ``"xla"``).
 
         Counts: ``decode_steps``; ``decode_slot_steps`` (sequences, summed
         over decode steps) and ``decode_kv_tokens`` (their cached tokens,
@@ -449,7 +471,11 @@ class LLMEngine:
         summed over steps, sequences and layers: a sequence's cached
         tokens, on a window layer at most the window; without window
         layers ``decode_kv_tokens`` times the layers; a latent layer's
-        rows are one a token, whatever the heads);
+        rows are one a token, whatever the heads; a retention layer
+        reads none); ``decode_state_slot_layers`` (the states the steps
+        read and wrote: sequences times retention layers, summed over
+        decode steps; times ``state_slot_bytes``, the bytes of state a
+        step moved each way);
         ``kv_page_steps_held`` (pages the live sequences held, each
         times its pool's layers, summed over decode steps) and
         ``kv_page_steps_one_table`` (what they would have held with one
@@ -543,6 +569,7 @@ class LLMEngine:
                           for kind, (layers, total, _) in
                           self._pools.items()},
                 "kv_row_bytes": dict(self._row_bytes),
+                "state_slot_bytes": dict(self._slot_bytes),
                 "total_pages": self.total_pages,
                 "page_size": self.page_size,
                 "decode_attention": self._decode_attention,
@@ -582,8 +609,9 @@ class LLMEngine:
 
     def _pages_needed(self, req: _Request, bucket: int) -> Dict[str, int]:
         """Pages of each pool the request holds from admission to its
-        end: its bucket's or its whole context's, whichever is more, and
-        in a window pool no more than the ring (the table's columns)."""
+        end: its bucket's or its whole context's, whichever is more, in
+        a window pool no more than the ring (the table's columns), and
+        of a pool of states, whose table has no column, none."""
         decode_span = math.ceil(
             (len(req.prompt) + req.max_new_tokens) / self.page_size
         )
@@ -788,7 +816,9 @@ class LLMEngine:
         counts["decode_kv_rows_read"] += sum(
             layers * (tokens if kind != "window" else sum(
                 min(c, self.cfg.sliding_window) for c in contexts))
-            for kind, (layers, _, _) in self._pools.items())
+            for kind, (layers, pages, _) in self._pools.items() if pages)
+        counts["decode_state_slot_layers"] += (
+            len(step.slots) * self._state_layers)
         for slot, req in step.slots.items():
             held, one_table = self._slot_held[slot]
             counts["kv_page_steps_held"] += held

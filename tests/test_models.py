@@ -128,3 +128,120 @@ def test_rope_interleave_turns_adjacent_pairs():
         halves[0, 1, 0],
         [1 * c0 - 3 * s0, 2 * c1 - 4 * s1, 1 * s0 + 3 * c0, 2 * s1 + 4 * c1],
         rtol=1e-5)
+
+
+# ---- power retention (ops/retention.py) ------------------------------------
+
+
+def _retention_attention_form(q, k, v, log_g):
+    """The layer as a sum over the past, nothing kept: q [S, H, d]; k, v
+    [S, Hkv, d]; log_g [S, Hkv]."""
+    S, H, d = q.shape
+    Hkv = k.shape[1]
+    cum = jnp.cumsum(log_g, axis=0).T                      # [Hkv, S]
+    s = jnp.einsum("tngd,jnd->ngtj", q.reshape(S, Hkv, H // Hkv, d), k,
+                   precision="highest")
+    past = jnp.tril(jnp.ones((S, S), bool))
+    decay = jnp.where(past, jnp.exp(jnp.where(
+        past, cum[:, :, None] - cum[:, None, :], 0.0)), 0.0)
+    a = s * s / d * decay[:, None]
+    out = jnp.einsum("ngtj,jnd->tngd", a, v, precision="highest")
+    total = a.sum(-1).transpose(2, 0, 1)[..., None]
+    return (out / (total + 1e-6)).reshape(S, H, d)
+
+
+def _retention_inputs(gate_bias, S=96, H=4, Hkv=2, d=16, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (S, H, d))
+    k = jax.random.normal(ks[1], (S, Hkv, d))
+    v = jax.random.normal(ks[2], (S, Hkv, d))
+    log_g = jax.nn.log_sigmoid(
+        jax.random.normal(ks[3], (S, Hkv)) + jnp.asarray(gate_bias))
+    return q, k, v, log_g
+
+
+@pytest.mark.parametrize("gate_bias,tol", [
+    (-6.0, 2e-3), (8.0, 2e-5), ((-3.0, 6.0), 2e-4)],
+    ids=["gates-near-0", "gates-near-1", "mixed"])
+def test_retention_chunked_recurrent_and_attention_forms_agree(gate_bias,
+                                                               tol):
+    """One layer three ways: the chunked scan (chunks of 32 and the
+    sequence whole), the recurrence a token at a time through the
+    decode step, and the sum over the past. With gates near 0 a token
+    sees little but itself and the normaliser can be as small as its
+    own (q.k)^2 / d: the quotient is then less well conditioned."""
+    from ray_tpu.ops import retention
+
+    q, k, v, log_g = _retention_inputs(gate_bias)
+    want = np.asarray(_retention_attention_form(q, k, v, log_g))
+    scale = tol / 2e-5 * np.abs(want).max()
+    chunked, state = retention.xla_retention_prefill(q, k, v, log_g,
+                                                     chunk=32)
+    whole, state_whole = retention.xla_retention_prefill(q, k, v, log_g,
+                                                         chunk=96)
+    assert np.abs(np.asarray(chunked) - want).max() < 2e-5 * scale
+    assert np.abs(np.asarray(whole) - want).max() < 2e-5 * scale
+    assert np.allclose(state, state_whole, rtol=1e-4,
+                       atol=1e-5 * float(jnp.abs(state).max()))
+    # The recurrence, from an empty state in slot 1 of 2; slot 0 idle.
+    pool = jnp.zeros(retention.state_shape(1, 2, 2, 16))
+    active = jnp.asarray([False, True])
+    step = jax.jit(retention.xla_retention_decode)
+    rows = []
+    for t in range(q.shape[0]):
+        out, pool = step(*(jnp.stack([x[t] * 0, x[t]])
+                           for x in (q, k, v, log_g)), pool, 0, active)
+        rows.append(out[1])
+    assert np.abs(np.stack(rows) - want).max() < 2e-5 * scale
+    assert np.allclose(pool[0, 1], state, rtol=1e-4,
+                       atol=1e-5 * float(jnp.abs(state).max()))
+    assert not np.asarray(pool[0, 0]).any()
+
+
+@pytest.mark.parametrize("d", [16, 128])
+def test_the_symmetric_state_is_the_square_of_the_dot_product(d):
+    """phi(x).phi(y) = (x.y)^2 / d against the full d^2 outer product,
+    with every unordered pair held once: d/2 + 1 turns of d, where turn
+    d/2 holds its d/2 pairs twice at half the weight."""
+    from ray_tpu.ops import retention
+
+    x, y = jax.random.normal(jax.random.PRNGKey(d), (2, 7, d))
+    px, py = retention.phi(x), retention.phi(y)
+    assert px.shape == (7, d // 2 + 1, d)
+    got = jnp.einsum("nta,nta->n", px, py, precision="highest")
+    full = jnp.einsum("na,nb,na,nb->n", x, x, y, y,
+                      precision="highest") / d
+    assert np.allclose(got, full, rtol=1e-4, atol=1e-6)
+    assert np.allclose(got, jnp.einsum("na,na->n", x, y) ** 2 / d,
+                       rtol=1e-4, atol=1e-6)
+    # Every unordered pair (a, b) once: turn t pairs a with a - t.
+    pairs = {}
+    for t in range(d // 2 + 1):
+        for a in range(d):
+            pair = frozenset((a, (a - t) % d))
+            pairs[pair] = pairs.get(pair, 0) + 1
+    assert len(pairs) == d * (d + 1) // 2
+    assert {n for p, n in pairs.items()
+            if len(p) == 2 and max(p) - min(p) == d // 2} == {2}
+    assert {n for p, n in pairs.items()
+            if len(p) == 1 or max(p) - min(p) != d // 2} == {1}
+
+
+def test_a_retention_stack_is_one_run_of_kind_state_and_inits_its_gate():
+    from ray_tpu.models.llama import kv_layers, layer_runs
+
+    cfg = LlamaConfig(vocab_size=64, hidden_size=32, intermediate_size=48,
+                      num_layers=3, num_heads=4, num_kv_heads=2, head_dim=8,
+                      dtype=jnp.float32, layer_types=("state",) * 3,
+                      qk_norm=True, qk_norm_per_head=True)
+    (run,) = layer_runs(cfg)
+    assert (run.n, run.kind, run.moe, run.kv_offset) == (3, "state", False, 0)
+    assert kv_layers(cfg) == {"state": 3}
+    layers = init_params(cfg, jax.random.PRNGKey(0))["layers"]
+    assert layers["wg"].shape == (3, 32, 2) and layers["bg"].shape == (3, 2)
+    # The gates remember 32 and 4,096 tokens: sigmoid(bg) = 1 - 1/tau.
+    assert np.allclose(1 / (1 - jax.nn.sigmoid(layers["bg"][0])),
+                       [32.0, 4096.0], rtol=1e-3)
+    with pytest.raises(ValueError, match="every one of 3 layers"):
+        layer_runs(LlamaConfig(num_layers=3,
+                               layer_types=("state", "full", "state")))

@@ -511,3 +511,110 @@ def test_joyai_weights_are_made_within_one_chip(v5e):
     compiled = jax.jit(lambda key: init_params(cfg, key)).lower(
         _arr(v5e, (2,), jnp.uint32)).compile()
     assert _fits_one_chip(compiled)
+
+
+def _brumby():
+    """``brumby-14b-base-L6`` as the benchmark builds it, and its engine."""
+    import json
+
+    from benchmark import arch
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "brumby-14b-base-L6.json")) as f:
+        config = json.load(f)
+    return arch.program_config(config), config["engine"]
+
+
+def _brumby_shapes(v5e):
+    cfg, engine = _brumby()
+    params, cache = _serve_shapes(
+        cfg, v5e, engine["max_batch"], engine["total_pages"],
+        engine["max_len"] // PAGE)
+    return cfg, engine, params, cache
+
+
+# 6 layers, 16 slots, 8 KV heads, 65 turns of 136 rows of 128: 3.48 GB.
+STATE_POOL = (6, 16, 8, 65, 136, 128)
+
+
+def test_state_step_kernel_compiles_for_v5e(v5e):
+    """The decode retention kernel at the published shapes: 40 query
+    heads on 8 KV heads of 128, 16 slots; a (slot, KV head)'s state
+    block of 4.5 MB goes through VMEM and comes back through the output
+    aliased to the pool."""
+    from ray_tpu.ops import retention
+
+    assert retention.state_shape(6, 16, 8, 128) == STATE_POOL
+    compiled = jax.jit(retention.state_step, donate_argnums=(4,)).lower(
+        _arr(v5e, (16, 40, 128)), _arr(v5e, (16, 8, 128)),
+        _arr(v5e, (16, 8, 128)), _arr(v5e, (16, 8), jnp.float32),
+        _arr(v5e, STATE_POOL, jnp.float32), _arr(v5e, (), jnp.int32),
+        _arr(v5e, (16,), jnp.bool_),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4 * math.prod(STATE_POOL)
+    # Beside the pool: phi(q), phi(k) and the like, no second pool.
+    assert memory.temp_size_in_bytes < 4 * math.prod(STATE_POOL[1:])
+
+
+@pytest.mark.parametrize("bucket", [4096, 16384])
+def test_chunk_scan_kernel_compiles_for_v5e(v5e, bucket):
+    """The chunked prefill kernel at the cell's smallest and largest
+    bucket: a KV head's state stays in VMEM over its chunks."""
+    from ray_tpu.ops import retention
+
+    compiled = jax.jit(retention.chunk_scan).lower(
+        _arr(v5e, (bucket, 40, 128)), _arr(v5e, (bucket, 8, 128)),
+        _arr(v5e, (bucket, 8, 128)), _arr(v5e, (bucket, 8), jnp.float32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_brumby_decode_program_compiles_for_v5e(v5e, as_tpu):
+    """One scan of six retention layers over the one pool of states: the
+    pool is carried whole and updated in place, no KV pool exists, and
+    7.08 GB of weights beside 3.48 GB of state fit the chip."""
+    cfg, engine, params, cache = _brumby_shapes(v5e)
+    assert {k: v.shape for k, v in cache.k.items()} == {"state": STATE_POOL}
+    assert cache.v == {} and cache.page_table["state"].shape == (16, 0)
+    batch = engine["max_batch"]
+
+    def decode(params, cache, tok, active):
+        return generation.paged_decode(params, tok, cache, cfg, active=active)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (batch,), jnp.int32),
+        _arr(v5e, (batch,), jnp.bool_)).compile()
+    assert _fits_one_chip(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4 * math.prod(STATE_POOL)
+    # Nothing pool-sized beside the pool: a copy would be 3.48 GB.
+    assert memory.temp_size_in_bytes < 2 * math.prod(STATE_POOL)
+    print("decode", memory.temp_size_in_bytes / 2**30, "GiB of temporaries",
+          memory.argument_size_in_bytes / 2**30, "GiB of arguments")
+
+
+@pytest.mark.parametrize("bucket", [4096, 16384])
+def test_brumby_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket):
+    """The cell's smallest and largest bucket: the chunked scan a layer,
+    the slot's states laid into the pool in place, beside 10.6 GB of
+    weights and state."""
+    cfg, engine, params, cache = _brumby_shapes(v5e)
+
+    def prefill(params, cache, tokens, real_len, slot, pages):
+        return generation.paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages)
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (1, bucket), jnp.int32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
+        {"state": _arr(v5e, (0,), jnp.int32)},
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _fits_one_chip(compiled)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4 * math.prod(STATE_POOL)
+    print(bucket, memory.temp_size_in_bytes / 2**30, "GiB of temporaries")
