@@ -688,7 +688,7 @@ def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
         out, aux, expert_tokens = moe_ffn(
             h, lp["router"], w["w_up"], w["w_down"], k=cfg.top_k,
             w_gate=w["w_gate"], token_mask=token_mask,
-            layer=None if expert_stack is None else lp["index"],
+            layer=None if expert_stack is None else lp["index"], mesh=mesh,
             score=cfg.router_score, select_bias=lp.get("expert_bias"),
             renormalize=cfg.route_norm, scale=cfg.route_scale,
         )

@@ -10,12 +10,23 @@ default is OLMoE's: softmax, no bias, ``norm_topk_prob=false``, scale 1.
 One path, for training, prefill and decode: the ``k * T`` (token, expert)
 assignments are sorted by expert, the tokens' rows gathered in that
 order, and the three expert matmuls run as grouped matmuls over
-``group_sizes`` (``jax.lax.ragged_dot``), so every assignment is
-computed, none is dropped, and nothing has a capacity axis. Rows of a
-``token_mask`` (inactive decode slots, a prefill bucket's padding) sort
-behind the last group and belong to no expert. Experts are sharded over
-the "ep" mesh axis by their weights' logical axis "expert"; GSPMD
-partitions the grouped matmuls (an all-to-all layout is ROADMAP R3's).
+``group_sizes``, so every assignment is computed, none is dropped, and
+nothing has a capacity axis. Rows of a ``token_mask`` (inactive decode
+slots, a prefill bucket's padding) sort behind the last group and belong
+to no expert. Experts are sharded over the "ep" mesh axis by their
+weights' logical axis "expert"; GSPMD partitions the grouped matmuls (an
+all-to-all layout is ROADMAP R3's).
+
+What multiplies the groups is ops/grouped_matmul.py's to choose, from
+what it can see and nothing a caller sets
+(``grouped_matmul.grouped_path``): on a TPU, outside any mesh of more
+than one device, a call of few rows a group (a decode step's 256 rows
+over 64 to 256 experts, a prefill's shortest buckets) runs the Pallas
+kernel sized to those rows, gate, up and the activation in one call and
+down in a second; every other call (the CPU, a mesh, a prefill or a
+training step of thousands of rows) runs ``jax.lax.ragged_dot`` three
+times, operation for operation what it was. A gradient through the
+kernel is ``ragged_dot``'s (a ``custom_vjp``).
 """
 
 from __future__ import annotations
@@ -24,6 +35,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ..ops.grouped_matmul import grouped_matmul
+from .sharding import _current_mesh
 
 
 def route(logits: jax.Array, k: int, *, score: str = "softmax",
@@ -62,6 +76,7 @@ def moe_ffn(
     activation=jax.nn.silu,
     token_mask: Optional[jax.Array] = None,  # [B, S] 1=route, 0=ignore
     layer: Optional[jax.Array] = None,  # [] int32: the weights are stacks
+    mesh=None,              # the mesh the program is partitioned over
     **routing,              # route()'s: score, select_bias, renormalize, scale
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (output [B,S,M], Switch load-balancing loss, tokens
@@ -75,7 +90,11 @@ def moe_ffn(
     (805 MB a layer at OLMoE's widths, a third of the decode step: chip
     run, PR 28): the grouped matmul is a custom call and cannot read a
     slice in place. The serving programs pass the stack; training
-    slices, where a stack's gradient would be summed whole per layer."""
+    slices, where a stack's gradient would be summed whole per layer.
+
+    ``mesh``: the mesh the caller's program is partitioned over (``ffn``
+    passes its own; else the context's, as ``with_logical_constraint``):
+    one of the things ``grouped_path`` chooses the grouped matmul by."""
     B, S, M = x.shape
     E = router_w.shape[1]
     T = B * S
@@ -115,13 +134,17 @@ def moe_ffn(
             w_in, w_out, w_gate = (
                 None if w is None else w.reshape((L * E,) + w.shape[2:])
                 for w in (w_in, w_out, w_gate))
-        h = jax.lax.ragged_dot(rows, w_in, groups)
-        if w_gate is not None:
-            g = jax.lax.ragged_dot(rows, w_gate, groups)
-            h = activation(g.astype(jnp.float32)).astype(h.dtype) * h
-        else:
-            h = activation(h)
-        y = jax.lax.ragged_dot(h, w_out, groups)               # [k*T, M]
+
+        def activate(h, g=None):
+            if g is None:
+                return activation(h)
+            return activation(g.astype(jnp.float32)).astype(h.dtype) * h
+
+        matmul = grouped_matmul(T * k, groups, experts=E,
+                                mesh=mesh or _current_mesh())
+        h = matmul(rows, (w_in,) if w_gate is None else (w_in, w_gate),
+                   activate)
+        y = matmul(h, (w_out,))                                # [k*T, M]
         if token_mask is not None:
             y = jnp.where(routed, y, 0)
     with jax.named_scope("moe.combine"):

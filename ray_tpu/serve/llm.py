@@ -388,6 +388,7 @@ class LLMEngine:
             self._moe = {"assignments": 0, "decode_assignments": 0,
                          "experts_reached": 0, "layer_steps": 0,
                          "prefill_experts_reached": 0,
+                         "layer_calls": 0, "small_rows_layer_calls": 0,
                          "expert_tokens": np.zeros(cfg.n_experts, np.int64)}
 
         from ..util.device_metrics import instrumented_jit
@@ -514,7 +515,11 @@ class LLMEngine:
         step gave at least one token, summed over decode steps) over
         ``layer_steps`` (decode steps x layers that have experts) is the
         experts such a layer of a decode step read;
-        ``prefill_experts_reached`` is the same count over prefills.
+        ``prefill_experts_reached`` is the same count over prefills;
+        ``layer_calls`` (expert layers x programs run, decode steps and
+        prefills) and ``small_rows_layer_calls``, those whose program
+        was built with the grouped matmul for few rows a group
+        (ops/grouped_matmul.py: the same rule, by the program's rows).
 
         ``requests``: the newest requests that have finished and those now
         decoding, each ``[t_submit, t_admit, t_first, t_done or None,
@@ -738,7 +743,7 @@ class LLMEngine:
                         prefill_pages,
                     )
                     first = int(self._tokens(
-                        np.asarray(first).reshape(-1), 1, decode=False)[0])
+                        np.asarray(first).reshape(-1), 1, bucket)[0])
             except Exception as e:  # noqa: BLE001
                 self._close(req, e)
                 self._release_slot(slot)
@@ -762,19 +767,30 @@ class LLMEngine:
                 self._finish(slot, req)
                 self._release_slot(slot)
 
-    def _tokens(self, out: np.ndarray, n: int, decode: bool) -> np.ndarray:
-        """The ``n`` tokens at the head of a program's read-back; what a
+    def _tokens(self, out: np.ndarray, n: int,
+                bucket: Optional[int] = None) -> np.ndarray:
+        """The ``n`` tokens at the head of a program's read-back, a
+        decode step's or with ``bucket`` that bucket's prefill's; what a
         MoE model's program packed behind them (``with_load``) goes to
         the expert-load counters, a decode step's apart from a
         prefill's where ``stats()`` tells them apart."""
         moe = self._moe
         if moe is not None:
+            from ..ops.grouped_matmul import grouped_path
+
             expert_tokens = out[n:-1]
+            # What moe_ffn asked when the program was traced.
+            small_rows = grouped_path(
+                (bucket or self.max_batch) * self.cfg.top_k,
+                self.cfg.n_experts) == "small_rows"
             with self._lock:
                 assignments = int(expert_tokens.sum())
                 moe["expert_tokens"] += expert_tokens
                 moe["assignments"] += assignments
-                if decode:
+                moe["layer_calls"] += self._expert_layers
+                moe["small_rows_layer_calls"] += (
+                    self._expert_layers * small_rows)
+                if bucket is None:
                     moe["decode_assignments"] += assignments
                     moe["experts_reached"] += int(out[-1])
                     moe["layer_steps"] += self._expert_layers
@@ -803,7 +819,7 @@ class LLMEngine:
         self._step_count += 1
         counts["decode_steps_ahead"] += step.ahead
         counts["decode_slot_steps_discarded"] += len(step.dropped)
-        nxt = self._tokens(out, self.max_batch, decode=True)
+        nxt = self._tokens(out, self.max_batch)
         counts["decode_slot_steps"] += len(step.slots)
         self._tokens_emitted += len(step.slots) - len(step.dropped)
         # The step attended to each prompt and every token generated

@@ -274,6 +274,57 @@ def test_engine_counts_expert_load_for_a_moe_model_only(tiny_model,
         engine.shutdown()
 
 
+def test_engine_counts_the_programs_built_with_the_small_rows_kernel(
+        monkeypatch, naive_greedy):
+    """``stats()["moe"]``'s ``layer_calls`` is expert layers x programs
+    run, and ``small_rows_layer_calls`` those whose program the rule of
+    ops/grouped_matmul.py gave the kernel for few rows a group: none on
+    the CPU; with a rule that gives it to the decode program alone (and
+    the kernel interpreted), every decode step and no prefill, and the
+    tokens are still a plain greedy decode's."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    cfg = LlamaConfig.tiny(moe=True)
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    prompts = [list(np.random.RandomState(i).randint(0, 256, n))
+               for i, n in enumerate((5, 17, 9))]
+
+    def serve():
+        engine = LLMEngine(cfg, params, max_batch=8, max_len=64)
+        try:
+            reqs = [engine.submit(p, 4) for p in prompts]
+            assert [r.result(timeout=180) for r in reqs] == [
+                naive_greedy(params, p, cfg, 4) for p in prompts]
+            return engine.stats()
+        finally:
+            engine.shutdown()
+
+    stats = serve()
+    programs = stats["decode_steps"] + stats["prefills"]
+    assert stats["moe"]["layer_calls"] == cfg.num_layers * programs
+    assert stats["moe"]["small_rows_layer_calls"] == 0
+
+    decode_rows = 8 * cfg.top_k
+    kernel, traced = gm.small_rows_grouped_matmul, []
+    monkeypatch.setattr(
+        gm, "grouped_path", lambda rows, experts, mesh=None:
+        "small_rows" if rows == decode_rows else "ragged_dot")
+    monkeypatch.setattr(
+        gm, "small_rows_grouped_matmul",
+        lambda rows, weights, group_sizes, walk, epilogue: traced.append(
+            rows.shape) or kernel(rows, weights, group_sizes, None, epilogue,
+                                  None, True))
+    stats = serve()
+    # Gate and up in one call, down in a second, of one traced layer.
+    assert traced == [(decode_rows, cfg.hidden_size),
+                      (decode_rows, cfg.intermediate_size)]
+    assert stats["prefills"] == 3 and stats["decode_steps"] >= 3
+    assert stats["moe"]["layer_calls"] == cfg.num_layers * (
+        stats["decode_steps"] + stats["prefills"])
+    assert stats["moe"]["small_rows_layer_calls"] \
+        == cfg.num_layers * stats["decode_steps"]
+
+
 # ---- the loop one decode step ahead of its read-back (PR 39) ----------------
 
 def _hold_admission(engine):

@@ -51,8 +51,8 @@ BUCKET = 512
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """Sharding on one device of a described v5e 2x2 host."""
+def v5e_host():
+    """The four devices of a described v5e 2x2 host."""
     try:
         topo = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
@@ -64,9 +64,15 @@ def v5e():
     # recompiles); keep these out of it.
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_host):
+    """Sharding on one device of that host."""
+    return SingleDeviceSharding(v5e_host[0])
 
 
 @pytest.fixture
@@ -279,6 +285,38 @@ def test_olmoe_decode_program_compiles_for_v5e(v5e, as_tpu):
     _assert_pool_stays_in_place(compiled, pool)
 
 
+@pytest.mark.parametrize("groups,K,N,stacks", [
+    (512, 2048, 1024, 2), (512, 1024, 2048, 1),
+    (1024, 2048, 768, 2), (1024, 768, 2048, 1)],
+    ids=["olmoe-trinity-in", "olmoe-trinity-down", "joyai-in", "joyai-down"])
+def test_grouped_matmul_kernels_compile_for_v5e(v5e, groups, K, N, stacks):
+    """The two calls a decode step's expert layer makes, at the three
+    MoE cells' shapes: 256 rows against a stack of ``L * E`` experts,
+    gate and up with the activation in one call and down in a second.
+    Each is ONE custom call that writes one ``[256, N]`` array (the
+    benchmark's readers find the grouped matmuls by that), beside the
+    walk's scalar kernel; no operation has a result as large as one
+    expert's weights, so the stack is read where it lies."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    def gated(h, g):
+        return jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * h
+
+    def call(rows, group_sizes, *weights):
+        return gm.small_rows_grouped_matmul(
+            rows, weights, group_sizes, None, gated if stacks == 2 else None)
+
+    compiled = jax.jit(call).lower(
+        _arr(v5e, (256, K)), _arr(v5e, (groups,), jnp.int32),
+        *[_arr(v5e, (groups, K, N))] * stacks).compile()
+    calls = [m for m in _HLO_INSTRUCTION.finditer(compiled.as_text())
+             if m["op"] == "custom-call" and "tpu_custom_call" in m["rest"]]
+    wide = [m["result"] for m in calls if "bf16" in m["result"]]
+    assert len(calls) == 2 and len(wide) == 1
+    assert wide[0].startswith(f"bf16[256,{N}]")
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * K * N
+
+
 # What would take a step's inputs or outputs through the host.
 _HOST_OPS = {"send", "send-done", "recv", "recv-done", "infeed", "outfeed"}
 
@@ -340,6 +378,97 @@ def test_olmoe_prefill_program_compiles_for_v5e(v5e, as_tpu):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()  # flash prefill
     assert _fits_one_chip(compiled)
+
+
+@pytest.mark.parametrize("bucket,kernel", [(16, True), (512, True),
+                                           (1024, False)])
+def test_olmoe_prefill_buckets_choose_their_grouped_matmul(v5e, as_tpu,
+                                                           bucket, kernel):
+    """8 x bucket rows over 64 experts: up to 64 rows an expert (the
+    512-token bucket's 4096 rows) a prefill runs the kernel for few rows
+    a group, as the decode step does; past it ``ragged_dot``'s own
+    custom calls. Either way the grouped matmuls are the program's
+    two-dimensional custom calls, rows by the expert's or the model's
+    width."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    cfg = _olmoe_cfg()
+    assert (gm.grouped_path(8 * bucket, cfg.n_experts) == "small_rows") \
+        is kernel
+    params, cache = _serve_shapes(cfg, v5e, CHAT_CELL[0], CHAT_POOL_PAGES,
+                                  CHAT_CELL[1])
+
+    def prefill(params, cache, tokens, real_len, slot, pages):
+        return generation.paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages
+        )
+
+    text = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (1, bucket), jnp.int32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
+        {"full": _arr(v5e, (bucket // PAGE,), jnp.int32)},
+    ).compile().as_text()
+    calls = [m["result"] for m in _HLO_INSTRUCTION.finditer(text)
+             if m["op"] == "custom-call" and "tpu_custom_call" in m["rest"]]
+    grouped = [r for r in calls if r.startswith(f"bf16[{8 * bucket},")]
+    assert len(grouped) == (2 if kernel else 3), calls
+    assert ("ragged-dot" in text) is not kernel
+
+
+def _dense_programs(v5e, mesh):
+    """The texts of a dense model's train step under ``mesh`` (the Nemo
+    cell's trainer settings at tiny widths, b4 x 512) and of its decode
+    step on one device, each traced anew."""
+    from ray_tpu.train.compiled_step import CompiledTrainStep
+
+    cfg = LlamaConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_layers=4, num_heads=2, num_kv_heads=2, head_dim=128,
+        dtype=jnp.bfloat16, remat_policy="dots", scan_layers=True,
+        scan_chunk=2, loss_chunk=256,
+    )
+    step = CompiledTrainStep(cfg, mesh=mesh, learning_rate=1e-5)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    with jax.threefry_partitionable(True):
+        state = jax.eval_shape(step._init, key)
+        shardings = step._init.lower(key).compile().output_shardings
+    params, opt_state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        state, shardings)
+    tokens = jax.ShapeDtypeStruct((4, 513), jnp.int32,
+                                  sharding=step.token_sharding())
+    train = step._step.__wrapped_jit__.lower(params, opt_state, tokens)
+    decode, _ = _decode_program(
+        dataclasses.replace(cfg, remat_policy="none", scan_chunk=0), v5e,
+        B, POOL_PAGES, PAGES_PER_SEQ)
+    return train.compile().as_text(), decode.as_text()
+
+
+def test_programs_without_experts_are_the_same_either_way(
+        v5e, v5e_host, as_tpu, monkeypatch):
+    """``train-nemo12b-4chip``, ``train-mistral7b-1chip``,
+    ``serve-mistral7b-chat`` and ``serve-brumby-c16-8k`` run no expert
+    layer, so nothing asks ops/grouped_matmul.py for a path: a dense
+    model's train step under an ``fsdp=2 x tp=2`` mesh of a described
+    v5e host, as the Nemo cell's, and its decode step compile to the
+    same text whichever answer ``grouped_path`` would give. Those cells
+    cannot tell a tree with the kernel from one without."""
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.parallel import make_mesh
+
+    mesh = make_mesh(devices=v5e_host, dp=1, fsdp=2, tp=2)
+    asked, texts = [], []
+    for answer in ("small_rows", "ragged_dot"):
+        monkeypatch.setattr(
+            gm, "grouped_path",
+            lambda *a, answer=answer, **k: asked.append(a) or answer)
+        texts.append(_dense_programs(v5e, mesh))
+    assert not asked
+    assert texts[0] == texts[1]
+    train, decode = texts[0]
+    assert "all-reduce" in train or "all-gather" in train
+    assert "tpu_custom_call" in train             # the flash kernels
+    assert "ragged" not in train + decode
 
 
 def _trinity():
