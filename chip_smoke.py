@@ -422,14 +422,14 @@ def _llm_deployment():
             from ray_tpu.util.device_metrics import hbm_snapshot
 
             eng = self.engine
-            bucket = eng._bucket(prompt_len)
+            bucket = eng.scheduler.bucket(prompt_len)
             i32 = jax.numpy.int32
             shape = jax.ShapeDtypeStruct
-            prefill = eng._prefill.__wrapped_jit__.lower(
-                eng.params, eng.cache, shape((eng.max_batch,), i32),
+            prefill = eng.runner.prefill.__wrapped_jit__.lower(
+                eng.params, eng.runner.cache, shape((eng.max_batch,), i32),
                 shape((1, bucket), i32), shape((), i32), shape((), i32),
                 {kind: shape((min(bucket // eng.page_size, columns),), i32)
-                 for kind, (_, _, columns) in eng._pools.items()},
+                 for kind, (_, _, columns) in eng.books.pools.items()},
             ).compile().as_text()
             return {
                 "pid": os.getpid(),
@@ -449,12 +449,17 @@ def _llm_deployment():
 
 
 def _exited(pid: int) -> bool:
+    """Gone, or a zombie with no thread left (``benchmark/driver.py``'s
+    rule, PR 51): a zombie leader whose other threads live has not
+    exited, they still hold its chips."""
     try:
         with open(f"/proc/{pid}/stat") as f:
-            # "pid (comm) state ...": a zombie has released its devices.
-            return f.read().rsplit(")", 1)[1].split()[0] == "Z"
-    except FileNotFoundError:
+            # "pid (comm) state ...": the state is field 3 and
+            # num_threads field 20.
+            fields = f.read().rsplit(")", 1)[1].split()
+    except OSError:
         return True
+    return fields[0] == "Z" and int(fields[17]) <= 1
 
 
 def _wait_chip_released(pid: int, timeout: float = 60.0) -> float:

@@ -37,6 +37,9 @@ padding masked out of it (a gate of 1, a key of 0), and lays the state
 it is left with into the slot; the decode step updates every active
 slot's state in place and reads the token's output from it.
 
+What the pools hold for whom is kept on the host by ``KVBooks``; the
+serving engine reserves and releases through it and names no kind.
+
 No reference counterpart — Ray delegates model serving compute to user
 code; this framework owns it (continuous batching sits on top in
 ray_tpu.serve.llm).
@@ -44,15 +47,19 @@ ray_tpu.serve.llm).
 
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.paged_attention import (
-    decode_attention, latent_decode_attention, ring_pages,
+    decode_attention, decode_attention_path, latent_decode_attention,
+    ring_pages,
 )
-from ..ops.retention import retention_decode, retention_prefill, state_shape
+from ..ops.retention import (
+    retention_decode, retention_path, retention_prefill, state_shape,
+)
 from .llama import (
     LlamaConfig, block, causal_attention, embed_tokens, kv_layers,
     latent_absorb_out, latent_absorb_q, latent_kv, layer_runs, layer_stacks,
@@ -182,6 +189,162 @@ class PagedKVCache(NamedTuple):
                         for kind, (_, _, columns) in sizes.items()},
             lengths=jnp.zeros((batch,), dtype=jnp.int32),
         )
+
+
+class KVBooks:
+    """The accounts of one ``PagedKVCache``, on the host: numpy and
+    ints, no request and no program. For every pool the free pages and
+    the host's copy of the page table, for every slot what it holds: a
+    request reserves at admission, in every pool, all it holds to its
+    end (every page of its context, of a ring at most the table's
+    columns, of a pool of states nothing: admission is then by slot
+    alone), and nothing is allocated or freed in between. Built from the
+    five arguments ``cache`` was created with, and ``cache`` for what
+    its pools weigh. One thread writes, the engine's loop; a ``reading``
+    on another holds the engine's lock."""
+
+    def __init__(self, cfg: LlamaConfig, batch: int, total_pages: int,
+                 page_size: int, max_pages_per_seq: int,
+                 cache: PagedKVCache):
+        self.page_size, self.total_pages = page_size, total_pages
+        self._batch, self._layers = batch, cfg.num_layers
+        # {kind: (layers, pool pages, table columns)}
+        self.pools = PagedKVCache.sizes(cfg, batch, total_pages, page_size,
+                                        max_pages_per_seq)
+        held = {kind: sum(pool.nbytes for pool in cache.pools(kind))
+                for kind in self.pools}
+        # What a token holds in one layer of each pool that has pages,
+        # and what a slot holds in one layer of one that has none, as
+        # allocated.
+        self._row_bytes = {
+            kind: held[kind] // (layers * pages * page_size)
+            for kind, (layers, pages, _) in self.pools.items() if pages}
+        self._slot_bytes = {
+            kind: held[kind] // (layers * batch)
+            for kind, (layers, pages, _) in self.pools.items() if not pages}
+        # What a decode step reads of a sequence in each pool of rows:
+        # (layers, the window or None for every token).
+        self._reads = [(layers, cfg.window(kind))
+                       for kind, (layers, pages, _) in self.pools.items()
+                       if pages]
+        self._state_layers = sum(
+            layers for layers, pages, _ in self.pools.values() if not pages)
+        # ``free_pages``: of the pool that keeps everything, or the only.
+        self._gauge = "full" if "full" in self.pools else next(
+            iter(self.pools))
+        # What the decode program is built with: the same call
+        # paged_decode's attention makes when the program is traced.
+        if cfg.retention:
+            self.decode_attention = retention_path(cfg.dh)
+        elif cfg.latent:
+            self.decode_attention = decode_attention_path(
+                page_size, cfg.latent_row, cfg.kv_lora_rank)
+        else:
+            self.decode_attention = decode_attention_path(page_size, cfg.dh)
+        # What the decode steps read and held (LLMEngine.stats() says
+        # what each means), summed as the steps are read.
+        self.counts = dict.fromkeys((
+            "decode_kv_tokens", "decode_kv_rows_read",
+            "decode_state_slot_layers", "kv_page_steps_held",
+            "kv_page_steps_one_table"), 0)
+        self.reset()
+
+    def reset(self) -> None:
+        """Every page free, every table zero, no slot holding."""
+        self.free: Dict[str, List[int]] = {
+            kind: list(range(pages))
+            for kind, (_, pages, _) in self.pools.items()}
+        self.tables: Dict[str, np.ndarray] = {
+            kind: np.zeros((self._batch, columns), dtype=np.int32)
+            for kind, (_, _, columns) in self.pools.items()}
+        self._pages: Dict[int, Dict[str, List[int]]] = {}
+        # Per slot, fixed from ``reserve`` to ``release`` so that a
+        # decode step only adds them up: pages held, each times its
+        # pool's layers; what one table for every layer would hold.
+        self._held: Dict[int, int] = {}
+        self._one_table: Dict[int, int] = {}
+
+    def _need(self, tokens: int, bucket: int) -> Dict[str, int]:
+        """Pages of each pool a context of ``tokens``, prefilled in
+        ``bucket``, holds to its end: the bucket's or the context's,
+        whichever is more, of a ring no more than the ring, and of a
+        pool of states, whose table has no column, none."""
+        span = max(bucket // self.page_size, -(-tokens // self.page_size))
+        return {kind: min(span, columns)
+                for kind, (_, _, columns) in self.pools.items()}
+
+    def refusal(self, tokens: int, bucket: int) -> Optional[str]:
+        """Why such a context could never be held, whatever is released;
+        None where it could."""
+        for kind, need in self._need(tokens, bucket).items():
+            if need > self.pools[kind][1]:
+                return (f"request needs {need} pages but the {kind} pool "
+                        f"has only {self.pools[kind][1]} "
+                        f"(page_size={self.page_size})")
+        return None
+
+    def reserve(self, slot: int, tokens: int,
+                bucket: int) -> Optional[tuple]:
+        """Hold for ``slot`` what ``_need`` says, or None where a pool
+        is short (nothing is then taken). What a prefill gets: the
+        slot's pages of each pool that take the bucket (of a ring no
+        more than the ring has), and every table whole, to upload."""
+        need = self._need(tokens, bucket)
+        if any(n > len(self.free[kind]) for kind, n in need.items()):
+            return None
+        pages = {kind: [self.free[kind].pop() for _ in range(n)]
+                 for kind, n in need.items()}
+        self._pages[slot] = pages
+        self._held[slot] = sum(
+            self.pools[kind][0] * n for kind, n in need.items())
+        self._one_table[slot] = self._layers * max(need.values())
+        for kind, ids in pages.items():
+            self.tables[kind][slot, :] = 0
+            self.tables[kind][slot, :len(ids)] = ids
+        return ({kind: ids[: bucket // self.page_size]
+                 for kind, ids in pages.items()}, self.tables)
+
+    def release(self, slot: int) -> None:
+        self._held.pop(slot, None)
+        self._one_table.pop(slot, None)
+        for kind, ids in self._pages.pop(slot, {}).items():
+            self.free[kind].extend(ids)
+            self.tables[kind][slot, :] = 0
+
+    def account(self, slots: Iterable[int], contexts: List[int]) -> None:
+        """A decode step, once read: ``slots`` decoded in it, at
+        ``contexts`` (each the prompt and every token generated before
+        this one). The step attended to all of them, in a window layer
+        to no more than the window."""
+        counts = self.counts
+        tokens = sum(contexts)
+        counts["decode_kv_tokens"] += tokens
+        for layers, window in self._reads:
+            counts["decode_kv_rows_read"] += layers * (
+                tokens if window is None
+                else sum(min(c, window) for c in contexts))
+        counts["decode_state_slot_layers"] += (
+            len(contexts) * self._state_layers)
+        counts["kv_page_steps_held"] += sum(
+            map(self._held.__getitem__, slots))
+        counts["kv_page_steps_one_table"] += sum(
+            map(self._one_table.__getitem__, slots))
+
+    def reading(self) -> Dict[str, Any]:
+        """The counts and the gauges, as ``LLMEngine.stats()`` shows
+        and documents them."""
+        return {
+            **self.counts,
+            "free_pages": len(self.free[self._gauge]),
+            "pages": {kind: {"layers": layers, "total": total,
+                             "free": len(self.free[kind])}
+                      for kind, (layers, total, _) in self.pools.items()},
+            "kv_row_bytes": dict(self._row_bytes),
+            "state_slot_bytes": dict(self._slot_bytes),
+            "total_pages": self.total_pages,
+            "page_size": self.page_size,
+            "decode_attention": self.decode_attention,
+        }
 
 
 def _with_pools(cache: PagedKVCache, pools, lengths) -> PagedKVCache:

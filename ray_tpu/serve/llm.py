@@ -7,24 +7,22 @@ running decode loop — each decode step batches every active slot into one
 [B, 1] forward pass (HBM-bandwidth bound; batching amortizes the weight
 reads), while prefill runs per admission into power-of-two length buckets.
 
-KV memory is PAGED (models/generation.py PagedKVCache): a shared pool of
-fixed-size token pages with a per-slot page table. A request reserves only
-the pages its prompt + max_new_tokens need — not a dense max_len row — so
-total KV is bounded by actual demand, long-context requests coexist with
-short ones, and pages recycle the moment a request finishes. Admission
-waits for pages instead of OOMing. All shapes stay static for XLA.
+KV memory is PAGED (models/generation.py, which says what each kind of
+attention layer keeps): a request reserves at admission only what its
+prompt + max_new_tokens need — not a dense max_len row — and holds it to
+its end, so admission waits for pages instead of OOMing. All shapes stay
+static for XLA.
 
-A model with window layers has a pool and a page table for each
-attention kind, under the one allocator: a request reserves, at
-admission and per kind, every page of its context in the "full" pool and
-at most a ring of ``window / page + 1`` in the "window" pool, which the
-decode programs then turn through by themselves. The loop allocates and
-frees nothing between admission and finish.
+The engine is a loop over three owners, and the arrows point one way
+(each class says what it hides):
 
-A model of retention layers (kind "state") holds a state of fixed size
-a slot and no pages: its pool has none, a request needs none, and
-admission is by slot alone. ``max_len`` then bounds the rotary
-positions and the prefill bucket, not memory.
+    LLMEngine        the thread, the loop and its phases, stats(), reset
+       |-- _Scheduler   who decides. Requests only: no page, no kind,
+       |       v        no jax; it asks the books, never the runner.
+       |-- KVBooks      (models/generation.py) who keeps the cache's
+       |                books. Pages and holdings: no request, no program.
+       '-- _Runner      who runs the programs and what they carry on the
+                        device. Slots, tokens, page ids: no request.
 
 The loop runs ONE decode step ahead of the one it reads. What a step
 needs lies on the device: the last tokens (a step's output is the next
@@ -32,8 +30,10 @@ one's input; a prefill sets its slot's), the PRNG key (split inside the
 program) and the active mask (sent again only when membership changes).
 One turn of ``LLMEngine._loop``:
 
-1. admit: prefill queued requests into free slots, blocking for each
-   first token (the prefill queues behind the step in flight);
+1. admit: the scheduler picks a queued request for a free slot, the
+   books reserve its pages (or it waits, first in line), the runner
+   prefills, blocking for the first token (the prefill queues behind
+   the step in flight), and the scheduler takes the token;
 2. inputs: who decodes in the step queued next: every open slot that
    its token count, the token in flight included, has not ended;
 3. decode: dispatch step k+1, start its read-back's copy to the host;
@@ -44,7 +44,7 @@ So no step writes a K/V row past the pages its slot holds: a slot that
 ends by its count is left out of the next step before its last token is
 read. Only ``eos_token`` is known at the read-back alone: the one step
 already queued for that slot is a wasted row inside its own pages, its
-token is dropped, and slot and pages return to the allocator when that
+token is dropped, and slot and pages return to the books when that
 step is read, so a prefill that reuses them queues behind the stray
 write. A slot freed at step k's read-back is therefore taken by a
 waiting request one step later than a loop that read each step before
@@ -211,14 +211,14 @@ class _Request:
             self._streams.end(self)
 
 
-# LLMEngine.stats(): the monotonic counts, the loop's phases, and how many
-# finished requests' rows it keeps.
-_COUNTERS = ("decode_slot_steps", "decode_kv_tokens", "decode_kv_rows_read",
-             "kv_page_steps_held", "kv_page_steps_one_table", "prefills",
-             "prefill_tokens", "prefill_bucket_tokens", "submitted",
-             "admitted", "finished", "failed", "cache_resets", "page_waits",
-             "decode_steps_ahead", "decode_slot_steps_discarded",
-             "decode_state_slot_layers")
+
+# LLMEngine.stats(): the scheduler's monotonic counts (the books and
+# the runner keep their own), the loop's phases, and how many finished
+# requests' rows are kept.
+_COUNTERS = ("decode_slot_steps", "prefills", "prefill_tokens",
+             "prefill_bucket_tokens", "submitted", "admitted", "finished",
+             "failed", "page_waits", "decode_steps_ahead",
+             "decode_slot_steps_discarded")
 _PHASES = ("admit", "admit_stalling", "inputs", "decode", "readback",
            "emit", "idle")
 _REQUEST_ROWS = 1024
@@ -231,8 +231,8 @@ class _Step:
 
     __slots__ = ("out", "slots", "ahead", "dropped")
 
-    def __init__(self, out, slots: Dict[int, _Request], ahead: bool):
-        self.out = out        # the packed read-back, on its way to the host
+    def __init__(self, slots: Dict[int, _Request], ahead: bool):
+        self.out = None       # the runner's read-back, on its way to the host
         self.slots = slots    # who decodes in it
         self.ahead = ahead    # dispatched while the step before was unread
         # Slots that ended on ``eos_token`` in the step before: their
@@ -285,19 +285,381 @@ def serving_programs(cfg, temperature: float):
     return decode_step, prefill
 
 
+class _Scheduler:
+    """Who decides: which queued request takes a free slot, who decodes
+    in the step queued next, when a request has ended and when its slot
+    goes back. Requests only: no page, no kind, no jax. ``books`` is
+    asked one question at submit (``refusal``: could such a context ever
+    be held) and one at admission (``reserve``: a reservation or
+    nothing), and told a slot's ``release``, a ``reset`` and what each
+    step read (``account``). The loop thread calls everything but
+    ``submit`` and ``reading``; ``lock`` is the engine's, taken where
+    another thread's ``reading`` sees what is written."""
+
+    def __init__(self, books, lock: threading.Lock, *, max_batch: int,
+                 max_len: int, min_bucket: int):
+        self._books, self._lock = books, lock
+        self._max_batch, self._max_len = max_batch, max_len
+        self._min_bucket = min_bucket
+        self._queue: "queue.Queue[_Request]" = queue.Queue()
+        self._waiting: List[_Request] = []  # picked, but the books refused
+        self._slot_free = list(range(max_batch))
+        self._slot_req: Dict[int, _Request] = {}
+        # Picked, its prefill not yet answered: (slot, request).
+        self._admitting: Optional[tuple] = None
+        # Written by the loop thread alone (plain adds, no lock) except
+        # ``submitted``, which callers' threads bump under the lock.
+        self.counts = dict.fromkeys(_COUNTERS, 0)
+        self._steps = 0
+        self._finished_rows: "collections.deque[List]" = collections.deque(
+            maxlen=_REQUEST_ROWS)
+        # The way back (stats()["stream"]): tokens put on a request's
+        # ``_live``, counted by the loop thread, and who has taken them.
+        self._tokens_emitted = 0
+        self.streams = _Streams()
+
+    def bucket(self, n: int) -> int:
+        bucket = self._min_bucket
+        while bucket < n:
+            bucket *= 2
+        return min(bucket, self._max_len)
+
+    def submit(self, prompt: List[int], max_new_tokens: int,
+               eos_token: Optional[int], request_id: Any) -> _Request:
+        if len(prompt) + max_new_tokens > self._max_len:
+            raise ValueError(
+                f"prompt({len(prompt)}) + max_new({max_new_tokens}) exceeds "
+                f"engine max_len({self._max_len})"
+            )
+        # Unsatisfiable EVER: waiting would head-of-line block the
+        # admission queue forever.
+        refusal = self._books.refusal(len(prompt) + max_new_tokens,
+                                      self.bucket(len(prompt)))
+        if refusal:
+            raise ValueError(refusal)
+        req = _Request(prompt, max_new_tokens, eos_token, request_id,
+                       self.streams)
+        with self._lock:
+            self.counts["submitted"] += 1
+        req.t_submit = time.time()
+        self._queue.put(req)
+        return req
+
+    def streaming(self) -> bool:
+        """A slot is open. Only the loop thread adds slots, so it looks
+        without the lock."""
+        return bool(self._slot_req)
+
+    def pick(self) -> Optional[tuple]:
+        """The next request into a free slot: ``(slot, prompt, bucket,
+        reservation)`` for its prefill, which ``first_token`` or
+        ``prefill_failed`` answers. None without a slot or a request,
+        and where the books refuse (paged admission control: wait for
+        pages to recycle instead of OOMing or over-reserving): the
+        request is then the first to be picked again."""
+        if not self._slot_free:
+            return None
+        if self._waiting:
+            req = self._waiting.pop(0)
+        else:
+            try:
+                req = self._queue.get_nowait()
+            except queue.Empty:
+                return None
+        bucket = self.bucket(req.prompt_len)
+        slot = self._slot_free[-1]
+        held = self._books.reserve(
+            slot, req.prompt_len + req.max_new_tokens, bucket)
+        if held is None:
+            self._waiting.insert(0, req)
+            self.counts["page_waits"] += 1
+            return None
+        self._slot_free.pop()
+        req.bucket = bucket
+        req.t_admit = time.time()
+        self.counts["admitted"] += 1
+        self._admitting = (slot, req)
+        return slot, req.prompt, bucket, held
+
+    def first_token(self, first: int) -> None:
+        """The picked request's prefill gave its first token."""
+        slot, req = self._admitting
+        counts = self.counts
+        req.t_first = time.time()
+        counts["prefills"] += 1
+        counts["prefill_tokens"] += req.prompt_len
+        counts["prefill_bucket_tokens"] += req.bucket
+        req.output.append(first)
+        req._live.put((first, req.t_first))
+        self._tokens_emitted += 1
+        with self._lock:
+            self._slot_req[slot] = req
+        # No step in flight decodes for a slot admitted after it.
+        if self._ended(req, first):
+            self._finish(slot, req)
+            self._release(slot)
+
+    def prefill_failed(self, error: BaseException) -> None:
+        slot, req = self._admitting
+        self._close(req, error)
+        self._release(slot)
+
+    def next_step(self, flying: Optional[_Step]) -> Optional[_Step]:
+        """Who decodes in the step queued behind ``flying``: every open
+        slot short of its count, the token in flight included. None for
+        nobody."""
+        pending = flying.slots if flying else ()
+        slots = {slot: req for slot, req in self._slot_req.items()
+                 if len(req.output) + (slot in pending)
+                 < req.max_new_tokens}
+        return _Step(slots, ahead=flying is not None) if slots else None
+
+    def emit(self, step: _Step, nxt, queued: Optional[_Step]) -> None:
+        """A step's tokens ``nxt`` (one a slot) to their requests, once
+        read: the counters, the finishes. ``queued`` is the step
+        dispatched after it."""
+        counts = self.counts
+        now = time.time()  # the step's tokens are emitted now
+        self._steps += 1
+        counts["decode_steps_ahead"] += step.ahead
+        counts["decode_slot_steps_discarded"] += len(step.dropped)
+        counts["decode_slot_steps"] += len(step.slots)
+        self._tokens_emitted += len(step.slots) - len(step.dropped)
+        self._books.account(
+            step.slots.keys(), [req.prompt_len + len(req.output)
+                                for req in step.slots.values()])
+        for slot, req in step.slots.items():
+            if slot in step.dropped:
+                self._release(slot)
+                continue
+            tok = int(nxt[slot])
+            req.output.append(tok)
+            req._live.put((tok, now))
+            if self._ended(req, tok):
+                self._finish(slot, req)
+                if queued is not None and slot in queued.slots:
+                    # It ended on its eos_token, which no count foretold.
+                    queued.dropped.append(slot)
+                else:
+                    self._release(slot)
+
+    def reset(self, cause: Exception) -> None:
+        """After a failed donated call: every slot free, the books new,
+        and the open requests failed with the root cause (they cannot
+        be resumed without their KV)."""
+        with self._lock:
+            victims = list(self._slot_req.values())
+            self._slot_req.clear()
+            self._slot_free = list(range(self._max_batch))
+            self._books.reset()
+        for req in victims:
+            if not req.done.is_set():
+                self._close(req, RuntimeError(
+                    f"engine cache reset after runtime failure: {cause!r}"
+                ))
+
+    def reading(self, taken: Dict[str, Any]) -> Dict[str, Any]:
+        """``taken``: ``streams.read()``, which has a lock of its own,
+        read before the engine's is taken for this."""
+        return {
+            **self.counts,
+            "decode_steps": self._steps,
+            "stream": {"tokens_emitted": self._tokens_emitted, **taken},
+            "queued": self._queue.qsize() + len(self._waiting),
+            "requests": list(self._finished_rows) + [
+                req.row() for req in self._slot_req.values()],
+            "active_slots": len(self._slot_req),
+            "free_slots": len(self._slot_free),
+        }
+
+    @staticmethod
+    def _ended(req: _Request, tok: int) -> bool:
+        return (len(req.output) >= req.max_new_tokens
+                or (req.eos_token is not None and tok == req.eos_token))
+
+    def _finish(self, slot: int, req: _Request):
+        """The request's end. Its slot and pages go back apart from it
+        (``_release``): at once, or when a step in flight that still
+        writes into them has been read."""
+        with self._lock:
+            self._slot_req.pop(slot, None)
+        self._close(req)
+
+    def _close(self, req: _Request, error: Optional[BaseException] = None):
+        """End of a request, finished or failed: wake its waiters and
+        keep its row for stats()."""
+        req.t_done = time.time()
+        req.error = error
+        self.counts["failed" if error else "finished"] += 1
+        req._row = req.row()
+        with self._lock:
+            self._finished_rows.append(req._row)
+        req.done.set()
+        req._live.put(None)
+
+    def _release(self, slot: int):
+        self._books.release(slot)
+        self._slot_free.append(slot)
+
+
+class _Runner:
+    """Who runs the programs: the two jitted functions of
+    ``serving_programs`` (``decode_step``, ``prefill``), what a decode
+    step takes from the one before on the device (the cache, the last
+    tokens, the PRNG key, the active mask), donation and the rebuild
+    after a donated call failed, and the read-back's format, which
+    ``with_load`` packs and ``unpack`` alone takes apart, with the MoE
+    counters it feeds. Slots, token lists and page ids: no request."""
+
+    def __init__(self, cfg, params, temperature: float, max_batch: int,
+                 new_cache, lock: threading.Lock):
+        import jax
+
+        from ..models.llama import layer_runs
+        from ..ops.grouped_matmul import grouped_path
+        from ..util.device_metrics import instrumented_jit
+
+        self.params = params
+        self._max_batch = max_batch
+        self._new_cache = new_cache
+        self._lock = lock
+        self._device = jax.devices()[0]
+        # Expert load of a MoE model, read back behind each program's
+        # tokens; None for a dense one.
+        self._moe: Optional[Dict[str, Any]] = None
+        self._expert_layers = sum(
+            run.n for run in layer_runs(cfg) if run.moe)
+        if cfg.n_experts > 0:
+            self._moe = {"assignments": 0, "decode_assignments": 0,
+                         "experts_reached": 0, "layer_steps": 0,
+                         "prefill_experts_reached": 0,
+                         "layer_calls": 0, "small_rows_layer_calls": 0,
+                         "expert_tokens": np.zeros(cfg.n_experts, np.int64)}
+        # What moe_ffn asked when a program of so many tokens was traced.
+        self._small_rows = lambda tokens: grouped_path(
+            tokens * cfg.top_k, cfg.n_experts) == "small_rows"
+        # Donate the cache: the paged pool updates IN PLACE instead of
+        # being copied every step (a pool-sized copy per step would make
+        # paging cost scale with pool size). Jit through the instrumented
+        # compile path: serving recompiles (shape changes, evictions)
+        # surface as ray_tpu_device_jit_* series instead of silent
+        # latency spikes. The per-token tap rides a ring flushed once
+        # every 64 steps (and at every burst boundary: LLMEngine._loop,
+        # stats), not per token, so the executable cache is not polled
+        # around every [B,1] decode step.
+        decode_step, prefill = serving_programs(cfg, temperature)
+        self.decode_step = instrumented_jit(
+            decode_step, donate_argnums=(1,), tap_stride=64)
+        self.prefill = instrumented_jit(prefill, donate_argnums=(1,))
+        self.reset()
+
+    def reset(self):
+        """A new cache and, as at the engine's start, what a decode step
+        takes from the one before: last tokens, the PRNG key (a reset
+        draws the seed's stream again), nobody active. After a failed
+        donated call the old pool's buffers are gone, and what a step in
+        flight returns may be the failed call's."""
+        import jax
+        import jax.numpy as jnp
+
+        self.cache = self._new_cache()
+        self._last_tok = jnp.zeros((self._max_batch,), dtype=jnp.int32)
+        self._rng = jax.random.PRNGKey(0)
+        self._active = jnp.zeros((self._max_batch,), dtype=bool)
+        self._active_slots: frozenset = frozenset()
+
+    def run_prefill(self, slot: int, prompt: List[int], bucket: int,
+                    pages: Dict[str, List[int]], tables) -> int:
+        """Prefill ``prompt``, padded to ``bucket``, into ``slot`` and
+        its ``pages`` (of each pool, those that take the bucket), the
+        host's ``tables`` uploaded whole, and wait for the token."""
+        import jax.numpy as jnp
+
+        self.cache = self.cache._replace(
+            page_table={kind: jnp.asarray(table)
+                        for kind, table in tables.items()})
+        padded = prompt + [0] * (bucket - len(prompt))
+        self.cache, self._last_tok, first = self.prefill(
+            self.params, self.cache, self._last_tok,
+            jnp.asarray([padded], dtype=jnp.int32),
+            jnp.asarray(len(prompt), dtype=jnp.int32),
+            jnp.asarray(slot, dtype=jnp.int32),
+            {kind: jnp.asarray(ids, dtype=jnp.int32)
+             for kind, ids in pages.items()},
+        )
+        return int(self.unpack(np.asarray(first).reshape(-1), 1, bucket)[0])
+
+    def activate(self, slots) -> None:
+        """``slots`` (a set of them) decode in the next step: the mask
+        goes to the device only when the set changed."""
+        if slots != self._active_slots:
+            import jax.numpy as jnp
+
+            active = np.zeros((self._max_batch,), dtype=bool)
+            active[list(slots)] = True
+            self._active = jnp.asarray(active)
+            self._active_slots = frozenset(slots)
+
+    def step(self):
+        """Dispatch a decode step; its read-back, on its way to the
+        host."""
+        out, self.cache, self._last_tok, self._rng = self.decode_step(
+            self.params, self.cache, self._last_tok, self._active,
+            self._rng)
+        out.copy_to_host_async()
+        return out
+
+    def fetch(self, out) -> np.ndarray:
+        """Block for a step's read-back."""
+        return np.asarray(out)
+
+    def unpack(self, out: np.ndarray, n: int,
+               bucket: Optional[int] = None) -> np.ndarray:
+        """The ``n`` tokens at the head of a program's read-back, a
+        decode step's or with ``bucket`` that bucket's prefill's; what a
+        MoE model's program packed behind them (``with_load``) goes to
+        the expert-load counters, a decode step's apart from a
+        prefill's where ``stats()`` tells them apart."""
+        moe = self._moe
+        if moe is not None:
+            expert_tokens = out[n:-1]
+            small_rows = self._small_rows(bucket or self._max_batch)
+            with self._lock:
+                assignments = int(expert_tokens.sum())
+                moe["expert_tokens"] += expert_tokens
+                moe["assignments"] += assignments
+                moe["layer_calls"] += self._expert_layers
+                moe["small_rows_layer_calls"] += (
+                    self._expert_layers * small_rows)
+                if bucket is None:
+                    moe["decode_assignments"] += assignments
+                    moe["experts_reached"] += int(out[-1])
+                    moe["layer_steps"] += self._expert_layers
+                else:
+                    moe["prefill_experts_reached"] += int(out[-1])
+        return out[:n]
+
+    def reading(self) -> Dict[str, Any]:
+        return {
+            # Where the engine's programs run: a rate read from these
+            # stats is a device number only on a "tpu".
+            "platform": self._device.platform,
+            "device_kind": self._device.device_kind,
+            **({"moe": {**self._moe, "expert_tokens":
+                        self._moe["expert_tokens"].tolist()}}
+               if self._moe else {}),
+        }
+
+
 class LLMEngine:
-    """Paged continuous-batching decode engine over the Llama family."""
+    """Paged continuous-batching decode engine over the Llama family:
+    the thread and the loop over ``scheduler``, ``books`` and ``runner``
+    (the module docstring says who owns what)."""
 
     def __init__(self, cfg, params, *, max_batch: int = 8,
                  max_len: int = 512, temperature: float = 0.0,
                  page_size: int = 16, total_pages: Optional[int] = None):
-        import jax
-        import jax.numpy as jnp
-
-        from ..models.generation import PagedKVCache
-        from ..models.llama import layer_runs
-        from ..ops.paged_attention import decode_attention_path
-        from ..ops.retention import retention_path
+        from ..models.generation import KVBooks, PagedKVCache
 
         self.cfg = cfg
         self.params = params
@@ -313,100 +675,24 @@ class LLMEngine:
                 f"({page_size})"
             )
         self.page_size = page_size
-        # What the decode program below is built with: the same call
-        # paged_decode's attention makes when the program is traced.
-        if cfg.retention:
-            self._decode_attention = retention_path(cfg.dh)
-        elif cfg.latent:
-            self._decode_attention = decode_attention_path(
-                page_size, cfg.latent_row, cfg.kv_lora_rank)
-        else:
-            self._decode_attention = decode_attention_path(page_size, cfg.dh)
-        self.max_pages_per_seq = math.ceil(max_len / page_size)
+        max_pages_per_seq = math.ceil(max_len / page_size)
         # Default pool: enough for every slot at max_len (same worst case
         # as a dense cache); pass a smaller total_pages to oversubscribe.
-        self.total_pages = total_pages or (
-            max_batch * self.max_pages_per_seq
-        )
-        self._jnp = jnp
-        self._jax = jax
-        self._device = jax.devices()[0]
-
-        self.cache = PagedKVCache.create(
-            cfg, max_batch, self.total_pages, page_size,
-            self.max_pages_per_seq,
-        )
-        # The allocator's books, one entry a KV pool (an attention kind
-        # the model has): {kind: (layers, pool pages, table columns)}.
-        self._pools = PagedKVCache.sizes(
-            cfg, max_batch, self.total_pages, page_size,
-            self.max_pages_per_seq)
-        # What a token holds in one layer of each pool that has pages,
-        # and what a slot holds in one layer of one that has none, as
-        # allocated.
-        held = {kind: sum(pool.nbytes for pool in self.cache.pools(kind))
-                for kind in self._pools}
-        self._row_bytes = {
-            kind: held[kind] // (layers * pages * page_size)
-            for kind, (layers, pages, _) in self._pools.items() if pages}
-        self._slot_bytes = {
-            kind: held[kind] // (layers * max_batch)
-            for kind, (layers, pages, _) in self._pools.items() if not pages}
-        self._state_layers = self._pools.get("state", (0,))[0]
-        self._free_pages: Dict[str, List[int]] = {}
-        self._table: Dict[str, np.ndarray] = {}
-        self._new_books()
-        self._slot_free = list(range(max_batch))
-        self._slot_req: Dict[int, _Request] = {}
-        self._slot_pages: Dict[int, Dict[str, List[int]]] = {}
-        # Per slot, fixed from admission to finish so that a decode step
-        # only adds them up: (pages held, each times its pool's layers;
-        # what one table for every layer would hold).
-        self._slot_held: Dict[int, tuple] = {}
-        self._queue: "queue.Queue[_Request]" = queue.Queue()
-        self._waiting: List[_Request] = []  # admitted-but-no-pages
+        self.total_pages = total_pages or max_batch * max_pages_per_seq
+        geometry = (cfg, max_batch, self.total_pages, page_size,
+                    max_pages_per_seq)
         self._lock = threading.Lock()
+        self.runner = _Runner(cfg, params, temperature, max_batch,
+                              lambda: PagedKVCache.create(*geometry),
+                              self._lock)
+        self.books = KVBooks(*geometry, self.runner.cache)
+        self.scheduler = _Scheduler(
+            self.books, self._lock, max_batch=max_batch, max_len=max_len,
+            min_bucket=page_size)
         self._stop = False
-        self._step_count = 0
-        # What stats() reports beside the gauges. Written by the loop
-        # thread alone (plain adds, no lock) except ``submitted``, which
-        # callers' threads bump under the lock.
-        self._counts = dict.fromkeys(_COUNTERS, 0)
+        self._flying: Optional[_Step] = None
         self._phase_s = dict.fromkeys(_PHASES, 0.0)
-        self._finished_rows: "collections.deque[List]" = collections.deque(
-            maxlen=_REQUEST_ROWS)
-        # The way back (stats()["stream"]): tokens put on a request's
-        # ``_live``, counted by the loop thread, and who has taken them.
-        self._tokens_emitted = 0
-        self._streams = _Streams()
-        # Expert load of a MoE model, read back behind each program's
-        # tokens (``serving_programs``); None for a dense one.
-        self._moe: Optional[Dict[str, Any]] = None
-        self._expert_layers = sum(
-            run.n for run in layer_runs(cfg) if run.moe)
-        if cfg.n_experts > 0:
-            self._moe = {"assignments": 0, "decode_assignments": 0,
-                         "experts_reached": 0, "layer_steps": 0,
-                         "prefill_experts_reached": 0,
-                         "layer_calls": 0, "small_rows_layer_calls": 0,
-                         "expert_tokens": np.zeros(cfg.n_experts, np.int64)}
-
-        from ..util.device_metrics import instrumented_jit
-
-        # Donate the cache: the paged pool updates IN PLACE instead of
-        # being copied every step (a pool-sized copy per step would make
-        # paging cost scale with pool size). Jit through the instrumented
-        # compile path: serving recompiles (shape changes, evictions)
-        # surface as ray_tpu_device_jit_* series instead of silent
-        # latency spikes. The per-token tap rides a ring flushed once
-        # every 64 steps (and at every burst boundary — see _loop /
-        # stats), not per token, so the executable cache is not polled
-        # around every [B,1] decode step.
-        decode_step, prefill = serving_programs(cfg, temperature)
-        self._decode = instrumented_jit(decode_step, donate_argnums=(1,),
-                                        tap_stride=64)
-        self._prefill = instrumented_jit(prefill, donate_argnums=(1,))
-        self._new_carry()
+        self._cache_resets = 0
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
@@ -415,27 +701,8 @@ class LLMEngine:
     def submit(self, prompt: List[int], max_new_tokens: int = 32,
                eos_token: Optional[int] = None,
                request_id: Any = None) -> _Request:
-        if len(prompt) + max_new_tokens > self.max_len:
-            raise ValueError(
-                f"prompt({len(prompt)}) + max_new({max_new_tokens}) exceeds "
-                f"engine max_len({self.max_len})"
-            )
-        req = _Request(prompt, max_new_tokens, eos_token, request_id,
-                       self._streams)
-        need = self._pages_needed(req, self._bucket(len(prompt)))
-        for kind, (_, pages, _) in self._pools.items():
-            if need[kind] > pages:
-                # Unsatisfiable EVER: waiting would head-of-line block
-                # the admission queue forever.
-                raise ValueError(
-                    f"request needs {need[kind]} pages but the {kind} "
-                    f"pool has only {pages} (page_size={self.page_size})"
-                )
-        with self._lock:
-            self._counts["submitted"] += 1
-        req.t_submit = time.time()
-        self._queue.put(req)
-        return req
+        return self.scheduler.submit(prompt, max_new_tokens, eos_token,
+                                     request_id)
 
     def generate(self, prompt: List[int], max_new_tokens: int = 32,
                  eos_token: Optional[int] = None,
@@ -549,309 +816,64 @@ class LLMEngine:
         step's, one clock read a step."""
         # Telemetry read: publish whatever the decode tap ring has
         # accumulated so /metrics never lags a long burst.
-        self._decode.flush_taps()
-        stream = self._streams.read()
+        self.runner.decode_step.flush_taps()
+        taken = self.scheduler.streams.read()
         with self._lock:
             return {
-                **self._counts,
+                **self.scheduler.reading(taken),
+                **self.books.reading(),
+                **self.runner.reading(),
                 "t": time.time(),
-                "stream": {"tokens_emitted": self._tokens_emitted, **stream},
-                "queued": self._queue.qsize() + len(self._waiting),
                 "phase_s": dict(self._phase_s),
-                "requests": list(self._finished_rows) + [
-                    req.row() for req in self._slot_req.values()],
-                # Where the engine's programs run: a rate read from
-                # these stats is a device number only on a "tpu".
-                "platform": self._device.platform,
-                "device_kind": self._device.device_kind,
-                "active_slots": len(self._slot_req),
-                "free_slots": len(self._slot_free),
-                "decode_steps": self._step_count,
-                "free_pages": len(self._free_pages.get(
-                    "full", next(iter(self._free_pages.values())))),
-                "pages": {kind: {"layers": layers, "total": total,
-                                 "free": len(self._free_pages[kind])}
-                          for kind, (layers, total, _) in
-                          self._pools.items()},
-                "kv_row_bytes": dict(self._row_bytes),
-                "state_slot_bytes": dict(self._slot_bytes),
-                "total_pages": self.total_pages,
-                "page_size": self.page_size,
-                "decode_attention": self._decode_attention,
-                **({"moe": {**self._moe, "expert_tokens":
-                            self._moe["expert_tokens"].tolist()}}
-                   if self._moe else {}),
+                "cache_resets": self._cache_resets,
             }
 
     def shutdown(self):
         self._stop = True
         self._thread.join(timeout=5)
         try:
-            self._decode.flush_taps()
+            self.runner.decode_step.flush_taps()
         except Exception:
             pass
 
-    # ---- page accounting ---------------------------------------------------
-
-    def _new_books(self):
-        """Every page free, every table zero."""
-        for kind, (_, pages, columns) in self._pools.items():
-            self._free_pages[kind] = list(range(pages))
-            self._table[kind] = np.zeros((self.max_batch, columns),
-                                         dtype=np.int32)
-
-    def _new_carry(self):
-        """What a decode step takes from the one before, on the device,
-        as at the engine's start: last tokens, the PRNG key (a reset
-        draws the seed's stream again), nobody active, no step in
-        flight."""
-        jnp = self._jnp
-        self._last_tok = jnp.zeros((self.max_batch,), dtype=jnp.int32)
-        self._rng = self._jax.random.PRNGKey(0)
-        self._active = jnp.zeros((self.max_batch,), dtype=bool)
-        self._active_slots: frozenset = frozenset()
-        self._flying: Optional[_Step] = None
-
-    def _pages_needed(self, req: _Request, bucket: int) -> Dict[str, int]:
-        """Pages of each pool the request holds from admission to its
-        end: its bucket's or its whole context's, whichever is more, in
-        a window pool no more than the ring (the table's columns), and
-        of a pool of states, whose table has no column, none."""
-        decode_span = math.ceil(
-            (len(req.prompt) + req.max_new_tokens) / self.page_size
-        )
-        span = max(bucket // self.page_size, decode_span)
-        return {kind: min(span, columns)
-                for kind, (_, _, columns) in self._pools.items()}
-
-    def _reset_cache(self, cause: Exception):
-        """Recover from a failed donated call: the old pool's buffers
-        are gone, so rebuild a fresh cache and fail in-flight requests
-        with the root cause (they cannot be resumed without their KV).
-        A step in flight goes with them: what it returns may be the
-        failed call's."""
-        from ..models.generation import PagedKVCache
-
-        with self._lock:
-            victims = list(self._slot_req.items())
-            self._slot_req.clear()
-            self._slot_free = list(range(self.max_batch))
-            self._slot_pages.clear()
-            self._slot_held.clear()
-            self._new_books()
-        self._counts["cache_resets"] += 1
-        for _slot, req in victims:
-            if not req.done.is_set():
-                self._close(req, RuntimeError(
-                    f"engine cache reset after runtime failure: {cause!r}"
-                ))
-        self.cache = PagedKVCache.create(
-            self.cfg, self.max_batch, self.total_pages, self.page_size,
-            self.max_pages_per_seq,
-        )
-        self._new_carry()
-
-    def _close(self, req: _Request, error: Optional[BaseException] = None):
-        """End of a request, finished or failed: wake its waiters and
-        keep its row for stats()."""
-        req.t_done = time.time()
-        req.error = error
-        self._counts["failed" if error else "finished"] += 1
-        req._row = req.row()
-        with self._lock:
-            self._finished_rows.append(req._row)
-        req.done.set()
-        req._live.put(None)
-
-    def _release_slot(self, slot: int):
-        self._slot_held.pop(slot, None)
-        held = self._slot_pages.pop(slot, {})
-        for kind, pages in held.items():
-            self._free_pages[kind].extend(pages)
-            self._table[kind][slot, :] = 0
-        self._slot_free.append(slot)
-
     # ---- engine loop -------------------------------------------------------
 
-    def _bucket(self, n: int) -> int:
-        bucket = self.page_size
-        while bucket < n:
-            bucket *= 2
-        return min(bucket, self.max_len)
+    def _reset(self, cause: Exception):
+        """Recover from a failed donated call: the cache's buffers may
+        already be invalid, so rather than serve from dead buffers fail
+        every open request with the root cause (the scheduler, which
+        renews the books under the same lock), rebuild cache and carry
+        (the runner), and forget the step in flight."""
+        self._cache_resets += 1
+        self.scheduler.reset(cause)
+        self.runner.reset()
+        self._flying = None
 
     def _admit(self):
         """One admission round: prefill queued requests into free slots
         until slots, pages or the queue run out. A prefill queues
         behind the decode step in flight and this thread waits for its
         first token, so that step's tokens are emitted after it."""
-        jnp = self._jnp
-        counts = self._counts
-        while self._slot_free:
-            if self._waiting:
-                req = self._waiting.pop(0)
-            else:
-                try:
-                    req = self._queue.get_nowait()
-                except queue.Empty:
-                    return
-            real_len = req.prompt_len
-            bucket = self._bucket(real_len)
-            need = self._pages_needed(req, bucket)
-            if any(n > len(self._free_pages[kind])
-                   for kind, n in need.items()):
-                # Paged admission control: wait for pages to recycle, in
-                # whichever pool is short, instead of OOMing or
-                # over-reserving a dense max_len row.
-                self._waiting.insert(0, req)
-                counts["page_waits"] += 1
+        scheduler, runner = self.scheduler, self.runner
+        while True:
+            picked = scheduler.pick()
+            if picked is None:
                 return
-            slot = self._slot_free.pop()
-            pages = {kind: [self._free_pages[kind].pop() for _ in range(n)]
-                     for kind, n in need.items()}
-            req.bucket = bucket
-            req.t_admit = time.time()
-            counts["admitted"] += 1
+            import jax
+
+            slot, prompt, bucket, (pages, tables) = picked
             try:
                 # Table upload, padding, dispatch and the wait for the
                 # first token: what every open stream stalls through.
-                with self._jax.profiler.TraceAnnotation(
+                with jax.profiler.TraceAnnotation(
                         "engine.prefill", bucket=bucket, slot=slot):
-                    self._slot_pages[slot] = pages
-                    self._slot_held[slot] = (
-                        sum(self._pools[kind][0] * n
-                            for kind, n in need.items()),
-                        self.cfg.num_layers * max(need.values()))
-                    for kind, ids in pages.items():
-                        self._table[kind][slot, :] = 0
-                        self._table[kind][slot, :len(ids)] = ids
-                    # What paged_prefill lays the bucket into: its pages
-                    # of a pool that keeps everything, of a ring no more
-                    # than the ring has.
-                    prefill_pages = {
-                        kind: jnp.asarray(
-                            ids[: bucket // self.page_size], dtype=jnp.int32)
-                        for kind, ids in pages.items()}
-                    self.cache = self.cache._replace(
-                        page_table={kind: jnp.asarray(table) for kind, table
-                                    in self._table.items()})
-                    padded = req.prompt + [0] * (bucket - real_len)
-                    tokens = jnp.asarray([padded], dtype=jnp.int32)
-                    self.cache, self._last_tok, first = self._prefill(
-                        self.params, self.cache, self._last_tok, tokens,
-                        jnp.asarray(real_len, dtype=jnp.int32),
-                        jnp.asarray(slot, dtype=jnp.int32),
-                        prefill_pages,
-                    )
-                    first = int(self._tokens(
-                        np.asarray(first).reshape(-1), 1, bucket)[0])
+                    first = runner.run_prefill(slot, prompt, bucket, pages,
+                                               tables)
             except Exception as e:  # noqa: BLE001
-                self._close(req, e)
-                self._release_slot(slot)
-                # The cache was DONATED into the failed call — its
-                # buffers may already be invalid. Rebuild the pool and
-                # fail every in-flight request rather than serving from
-                # dead buffers (engine reset; callers see clean errors).
-                self._reset_cache(e)
+                scheduler.prefill_failed(e)
+                self._reset(e)
                 continue
-            req.t_first = time.time()
-            counts["prefills"] += 1
-            counts["prefill_tokens"] += real_len
-            counts["prefill_bucket_tokens"] += bucket
-            req.output.append(first)
-            req._live.put((first, req.t_first))
-            self._tokens_emitted += 1
-            with self._lock:
-                self._slot_req[slot] = req
-            # No step in flight decodes for a slot admitted after it.
-            if self._ended(req, first):
-                self._finish(slot, req)
-                self._release_slot(slot)
-
-    def _tokens(self, out: np.ndarray, n: int,
-                bucket: Optional[int] = None) -> np.ndarray:
-        """The ``n`` tokens at the head of a program's read-back, a
-        decode step's or with ``bucket`` that bucket's prefill's; what a
-        MoE model's program packed behind them (``with_load``) goes to
-        the expert-load counters, a decode step's apart from a
-        prefill's where ``stats()`` tells them apart."""
-        moe = self._moe
-        if moe is not None:
-            from ..ops.grouped_matmul import grouped_path
-
-            expert_tokens = out[n:-1]
-            # What moe_ffn asked when the program was traced.
-            small_rows = grouped_path(
-                (bucket or self.max_batch) * self.cfg.top_k,
-                self.cfg.n_experts) == "small_rows"
-            with self._lock:
-                assignments = int(expert_tokens.sum())
-                moe["expert_tokens"] += expert_tokens
-                moe["assignments"] += assignments
-                moe["layer_calls"] += self._expert_layers
-                moe["small_rows_layer_calls"] += (
-                    self._expert_layers * small_rows)
-                if bucket is None:
-                    moe["decode_assignments"] += assignments
-                    moe["experts_reached"] += int(out[-1])
-                    moe["layer_steps"] += self._expert_layers
-                else:
-                    moe["prefill_experts_reached"] += int(out[-1])
-        return out[:n]
-
-    @staticmethod
-    def _ended(req: _Request, tok: int) -> bool:
-        return (len(req.output) >= req.max_new_tokens
-                or (req.eos_token is not None and tok == req.eos_token))
-
-    def _finish(self, slot: int, req: _Request):
-        """The request's end. Its slot and pages go back apart from it
-        (``_release_slot``): at once, or when a step in flight that
-        still writes into them has been read."""
-        with self._lock:
-            self._slot_req.pop(slot, None)
-        self._close(req)
-
-    def _emit(self, step: _Step, out: np.ndarray, queued: Optional[_Step]):
-        """A step's tokens to their requests, once read: the counters,
-        the finishes. ``queued`` is the step dispatched after it."""
-        counts = self._counts
-        now = time.time()  # the step's tokens are emitted now
-        self._step_count += 1
-        counts["decode_steps_ahead"] += step.ahead
-        counts["decode_slot_steps_discarded"] += len(step.dropped)
-        nxt = self._tokens(out, self.max_batch)
-        counts["decode_slot_steps"] += len(step.slots)
-        self._tokens_emitted += len(step.slots) - len(step.dropped)
-        # The step attended to each prompt and every token generated
-        # before this one: in a window layer to no more of them than
-        # the window.
-        contexts = [req.prompt_len + len(req.output)
-                    for req in step.slots.values()]
-        tokens = sum(contexts)
-        counts["decode_kv_tokens"] += tokens
-        counts["decode_kv_rows_read"] += sum(
-            layers * (tokens if kind != "window" else sum(
-                min(c, self.cfg.sliding_window) for c in contexts))
-            for kind, (layers, pages, _) in self._pools.items() if pages)
-        counts["decode_state_slot_layers"] += (
-            len(step.slots) * self._state_layers)
-        for slot, req in step.slots.items():
-            held, one_table = self._slot_held[slot]
-            counts["kv_page_steps_held"] += held
-            counts["kv_page_steps_one_table"] += one_table
-            if slot in step.dropped:
-                self._release_slot(slot)
-                continue
-            tok = int(nxt[slot])
-            req.output.append(tok)
-            req._live.put((tok, now))
-            if self._ended(req, tok):
-                self._finish(slot, req)
-                if queued is not None and slot in queued.slots:
-                    # It ended on its eos_token, which no count foretold.
-                    queued.dropped.append(slot)
-                else:
-                    self._release_slot(slot)
+            scheduler.first_token(first)
 
     def _loop(self):
         """Admit, dispatch the next decode step, then read and emit the
@@ -860,8 +882,10 @@ class LLMEngine:
         ``jax.profiler`` trace is open; then it lands in the trace's
         host plane, on the device trace's clock) and, from the same
         ``perf_counter()`` boundaries, a running total in ``phase_s``."""
-        jnp = self._jnp
-        span = self._jax.profiler.TraceAnnotation
+        import jax
+
+        scheduler, runner = self.scheduler, self.runner
+        span = jax.profiler.TraceAnnotation
         clock = time.perf_counter
         phase_s = self._phase_s
         t = clock()
@@ -875,58 +899,42 @@ class LLMEngine:
             return dt
 
         while not self._stop:
-            # Only this thread adds slots, so no lock to look.
-            stalling = bool(self._slot_req)
+            stalling = scheduler.streaming()
             with span("engine.admit"):
                 self._admit()
             dt = lap("admit")
             if stalling:
                 phase_s["admit_stalling"] += dt
             flying = self._flying
-            # Who decodes next: every open slot short of its count, the
-            # token in flight included.
-            pending = flying.slots if flying else ()
-            slots = {slot: req for slot, req in self._slot_req.items()
-                     if len(req.output) + (slot in pending)
-                     < req.max_new_tokens}
-            queued = None
+            queued = scheduler.next_step(flying)
             try:
-                if slots:
+                if queued is not None:
                     with span("engine.inputs"):
-                        if slots.keys() != self._active_slots:
-                            active = np.zeros((self.max_batch,), dtype=bool)
-                            active[list(slots)] = True
-                            self._active = jnp.asarray(active)
-                            self._active_slots = frozenset(slots)
+                        runner.activate(queued.slots.keys())
                     lap("inputs")
                     with span("engine.decode"):
-                        out, self.cache, self._last_tok, self._rng = \
-                            self._decode(self.params, self.cache,
-                                         self._last_tok, self._active,
-                                         self._rng)
-                        out.copy_to_host_async()
+                        queued.out = runner.step()
                     lap("decode")
-                    queued = _Step(out, slots, ahead=flying is not None)
                 if flying is not None:
                     with span("engine.readback"):
-                        out = np.asarray(flying.out)
+                        out = runner.fetch(flying.out)
                     lap("readback")
             except Exception as e:  # noqa: BLE001
-                # The cache was donated into the failed call — recover
-                # like the prefill path: rebuild the pool, fail in-flight
-                # requests cleanly, keep the loop alive for new work.
-                self._reset_cache(e)
+                # The cache was donated into the failed call: recover
+                # like the prefill path, keep the loop alive for new work.
+                self._reset(e)
                 continue
             self._flying = queued
             if flying is not None:
                 with span("engine.emit"):
-                    self._emit(flying, out, queued)
+                    scheduler.emit(
+                        flying, runner.unpack(out, self.max_batch), queued)
                 lap("emit")
             elif queued is None:
                 # Burst boundary: the decode loop went idle, the last
                 # step read — flush the batched metric taps accumulated
                 # over the burst.
-                self._decode.flush_taps()
+                runner.decode_step.flush_taps()
                 time.sleep(0.002)
                 lap("idle")
 

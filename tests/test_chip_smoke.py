@@ -121,3 +121,59 @@ def test_main_fails_fast_without_a_chip():
     assert proc.returncode != 0
     assert '"ok": true' not in proc.stdout
     assert "expected a 'tpu' device" in proc.stderr
+
+
+# The main thread leaves by the raw ``exit`` system call, which ends one
+# thread and not the group: the leader turns zombie and its other thread
+# runs on until its stdin closes (tests/bench_harness/test_benchmark_chips.py
+# has the same child for ``benchmark/driver.py``'s copy of the rule).
+_ZOMBIE_LEADER = r"""
+import ctypes, os, platform, sys, threading
+
+def stay():
+    os.write(1, b"ready\n")
+    os.read(0, 1)
+    os._exit(0)
+
+number = {"x86_64": 60, "aarch64": 93}.get(platform.machine())
+if number is None:
+    sys.exit(77)
+threading.Thread(target=stay).start()
+ctypes.CDLL(None).syscall(number, 0)
+"""
+
+
+def test_a_zombie_leader_with_a_thread_left_has_not_exited():
+    """What ``_wait_chip_released`` waits for: a worker whose leader is
+    a zombie while a thread of it lives still holds its chips."""
+    import time
+
+    def until(condition, seconds=10.0):
+        deadline = time.monotonic() + seconds
+        while time.monotonic() < deadline and not condition():
+            time.sleep(0.02)
+        return condition()
+
+    def state(pid):
+        with open(f"/proc/{pid}/stat") as f:
+            return f.read().rsplit(")", 1)[1].split()[0]
+
+    assert not chip_smoke._exited(os.getpid())
+    child = subprocess.Popen([sys.executable, "-c", _ZOMBIE_LEADER],
+                             stdin=subprocess.PIPE, stdout=subprocess.PIPE)
+    try:
+        if child.stdout.readline() != b"ready\n":
+            pytest.skip("no raw exit system call known for this machine")
+        if not until(lambda: state(child.pid) == "Z"):
+            pytest.skip("this platform shows no zombie leader with a live "
+                        "thread")
+        assert not chip_smoke._exited(child.pid)
+        child.stdin.close()
+        # Not reaped yet: a zombie still, but with no thread left.
+        assert until(lambda: chip_smoke._exited(child.pid))
+        assert state(child.pid) == "Z"
+    finally:
+        child.stdin.close()
+        child.wait(timeout=10)
+        child.stdout.close()
+    assert chip_smoke._exited(child.pid)  # reaped: gone

@@ -492,7 +492,7 @@ def test_no_step_writes_past_the_pages_a_slot_holds(tiny_model,
     try:
         # Pages are handed out from the list's end: the first request
         # gets page 2, the second pages 1 and 0.
-        assert engine._free_pages["full"] == [0, 1, 2]
+        assert engine.books.free["full"] == [0, 1, 2]
         rng = np.random.RandomState(5)
         edge, other = list(rng.randint(0, 256, 10)), \
             list(rng.randint(0, 256, 20))
@@ -504,7 +504,7 @@ def test_no_step_writes_past_the_pages_a_slot_holds(tiny_model,
         assert outs == [naive_greedy(params, edge, cfg, 6),
                         naive_greedy(params, other, cfg, 12)]
         # Rows written: the prompt's and every token's but the last.
-        assert sorted(np.asarray(engine.cache.lengths).tolist()) == [
+        assert sorted(np.asarray(engine.runner.cache.lengths).tolist()) == [
             10 + 5, 20 + 11]
         assert engine.stats()["decode_slot_steps"] == 5 + 11
     finally:
@@ -551,7 +551,7 @@ def test_a_failed_decode_with_a_step_in_flight_fails_each_request_once(
     open request failed once, and the engine serves the next one."""
     cfg, params = tiny_model
     engine = LLMEngine(cfg, params, max_batch=2, max_len=64)
-    real, calls = engine._decode, []
+    real, calls = engine.runner.decode_step, []
 
     class Unreadable:
         def copy_to_host_async(self):
@@ -569,7 +569,7 @@ def test_a_failed_decode_with_a_step_in_flight_fails_each_request_once(
 
     flaky.flush_taps = real.flush_taps
     try:
-        engine._decode = flaky
+        engine.runner.decode_step = flaky
         gate = _hold_admission(engine)
         reqs = [engine.submit([1, 2, 3], 20), engine.submit([4, 5], 20)]
         gate.set()
@@ -732,7 +732,7 @@ def test_admission_waits_on_whichever_pool_is_short(window_model, short):
                        total_pages=5 if short == "full" else 16)
     try:
         if short == "window":
-            del engine._free_pages["window"][3:]     # one ring is left
+            del engine.books.free["window"][3:]     # one ring is left
         a = engine.submit(list(range(40)), max_new_tokens=30)   # 5 pages
         b = engine.submit(list(range(40, 80)), max_new_tokens=30)
         assert len(a.result(timeout=300)) == 30
@@ -930,3 +930,68 @@ def test_a_paged_engine_has_no_state_gauge_and_counts_no_states(tiny_model):
         assert stats["decode_state_slot_layers"] == 0
     finally:
         engine.shutdown()
+
+
+# ---- stats()'s key tree: what benchmark/readers/*.py were written against --
+
+# Written from the output of the engine as one class (PR 51's tree).
+_STATS_KEYS = [
+    "active_slots", "admitted", "cache_resets", "decode_attention",
+    "decode_kv_rows_read", "decode_kv_tokens", "decode_slot_steps",
+    "decode_slot_steps_discarded", "decode_state_slot_layers",
+    "decode_steps", "decode_steps_ahead", "device_kind", "failed",
+    "finished", "free_pages", "free_slots", "kv_page_steps_held",
+    "kv_page_steps_one_table", "kv_row_bytes", "page_size", "page_waits",
+    "pages", "phase_s", "platform", "prefill_bucket_tokens",
+    "prefill_tokens", "prefills", "queued", "requests", "state_slot_bytes",
+    "stream", "submitted", "t", "total_pages"]
+_NESTED_KEYS = {
+    "phase_s": ["admit", "admit_stalling", "decode", "emit", "idle",
+                "inputs", "readback"],
+    "stream": ["backlog", "held_s", "taken_lag_s", "tokens_emitted",
+               "tokens_taken"],
+    "moe": ["assignments", "decode_assignments", "expert_tokens",
+            "experts_reached", "layer_calls", "layer_steps",
+            "prefill_experts_reached", "small_rows_layer_calls"]}
+_NOT_INT = {"decode_attention": str, "device_kind": str, "platform": str,
+            "t": float, "requests": list, "kv_row_bytes": dict,
+            "pages": dict, "phase_s": dict, "state_slot_bytes": dict,
+            "stream": dict, "moe": dict}
+
+
+@pytest.mark.parametrize("model, pools, slot_pools, moe", [
+    ("tiny_model", ["full"], [], False),
+    ("tiny_moe", ["full"], [], True),
+    ("window_model", ["full", "window"], [], True),
+    ("latent_model", ["latent"], [], True),
+    ("state_model", [], ["state"], False)])
+def test_stats_keys_and_types_are_the_one_class_engines(
+        request, model, pools, slot_pools, moe):
+    """Every key, nested key and type of ``stats()``, after one served
+    request, against a literal list: the three owners' readings merge to
+    what the readers of ``benchmark/readers`` index."""
+    if model == "tiny_moe":
+        cfg = LlamaConfig.tiny(moe=True)
+        params = init_params(cfg, jax.random.PRNGKey(1))
+    else:
+        cfg, params = request.getfixturevalue(model)[-2:]
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=64, page_size=16)
+    try:
+        assert len(engine.generate([1, 2, 3], max_new_tokens=4)) == 4
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    assert sorted(stats) == sorted(_STATS_KEYS + ["moe"] * moe)
+    assert {key: type(value) for key, value in stats.items()} == {
+        key: _NOT_INT.get(key, int) for key in stats}
+    for key, nested in _NESTED_KEYS.items():
+        assert sorted(stats.get(key, nested)) == nested
+    assert isinstance(stats.get("moe", {}).get("expert_tokens", []), list)
+    assert sorted(stats["pages"]) == sorted(pools + slot_pools)
+    assert all(sorted(pool) == ["free", "layers", "total"]
+               for pool in stats["pages"].values())
+    assert sorted(stats["kv_row_bytes"]) == pools
+    assert sorted(stats["state_slot_bytes"]) == slot_pools
+    (row,) = stats["requests"]
+    assert [type(field) for field in row] == [
+        float, float, float, float, int, int, type(None), type(None)]

@@ -121,7 +121,7 @@ def test_a_failed_prefill_is_counted_and_its_row_kept(tiny_model):
         raise RuntimeError("prefill fell over")
 
     try:
-        engine._prefill = broken
+        engine.runner.prefill = broken
         req = engine.submit([1, 2, 3], 4)
         with pytest.raises(RuntimeError, match="fell over"):
             req.result(timeout=120)
