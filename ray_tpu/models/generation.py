@@ -30,6 +30,16 @@ into the query and behind the attention and attends every head over the
 rows themselves (``ops/paged_attention.latent_decode_attention``). The
 same function of the same weights, at the cost each phase can bear.
 
+A latent layer may attend over a learned SELECTION of the cached tokens
+(``cfg.index_topk``; ops/sparse_attention.py): an indexing layer (kind
+"latent_index") caches one more key a token, in the "index" pool that
+rides on the latent pool's page table, scores the slot's keys with it,
+keeps the best ``index_topk`` and attends over those rows alone; the
+layers behind it of kind "latent_shared" attend over the same selection,
+which the layer loop hands on from run to run and from layer to layer
+(the block passes nothing but the residual). A context no longer than
+``index_topk`` selects everything, and is the latent layer's attention.
+
 A retention layer (kind "state", ops/retention.py) keeps no row a token
 but a state of fixed size a slot, in a pool that has no pages: the
 prefill runs the chunked scan from an empty state, with the bucket's
@@ -60,10 +70,14 @@ from ..ops.paged_attention import (
 from ..ops.retention import (
     retention_decode, retention_path, retention_prefill, state_shape,
 )
+from ..ops.sparse_attention import (
+    empty_selection, index_select_decode, prefill_select,
+    sparse_prefill_attention,
+)
 from .llama import (
-    LlamaConfig, block, causal_attention, embed_tokens, kv_layers,
-    latent_absorb_out, latent_absorb_q, latent_kv, layer_runs, layer_stacks,
-    rms_norm, split_expert_stack,
+    LlamaConfig, block, causal_attention, embed_tokens, index_offsets,
+    kv_layers, latent_absorb_out, latent_absorb_q, latent_kv, layer_runs,
+    layer_stacks, pool_kind, rms_norm, split_expert_stack,
 )
 
 
@@ -73,16 +87,24 @@ class MoeLoad(NamedTuple):
 
     expert_tokens: jax.Array    # [E] int32 (token, expert) assignments
     experts_reached: jax.Array  # [] int32 (layer, expert) pairs with any
+    # [] int32 assignments to experts that other chips hold; None for a
+    # model that holds all of its experts.
+    elsewhere: Optional[jax.Array] = None
 
     @staticmethod
-    def of_layers(expert_tokens) -> Optional["MoeLoad"]:
+    def of_layers(expert_tokens, share: bool = False) -> Optional["MoeLoad"]:
         """From the layer scans' stacked [n, E] counts, one entry a run
-        with experts; None for none."""
+        with experts; None for none. Of a model that holds a ``share``
+        of its experts the counts are [n, held + 1], the last column
+        the assignments that went elsewhere (``moe_ffn``)."""
         if not expert_tokens:
             return None
         counts = jnp.concatenate(expert_tokens)
+        elsewhere = None
+        if share:
+            counts, elsewhere = counts[:, :-1], counts[:, -1].sum()
         return MoeLoad(counts.sum(axis=0),
-                       (counts > 0).sum().astype(jnp.int32))
+                       (counts > 0).sum().astype(jnp.int32), elsewhere)
 
 
 class PagedKVCache(NamedTuple):
@@ -106,6 +128,12 @@ class PagedKVCache(NamedTuple):
     keeps every token as a "full" one does, but ONE row for all heads
     (``llama.latent_proj``): its pool is ``k["latent"]``,
     [L, P, page, ``cfg.latent_row``], and ``v`` has no such entry.
+    A latent model with a learned selection (``cfg.index_topk``) keeps
+    besides one indexer key a token an indexing layer, in ``k["index"]``,
+    [Li, P, page, ``cfg.index_head_dim``]: a pool that RIDES on the
+    latent pool's page table (``RIDES``), page for page, so that one
+    reservation a slot covers both and there is no second allocator: it
+    has no table and no free list of its own.
     A "state" layer (power retention) keeps no token at all but a state
     of fixed size a slot: its pool is ``k["state"]``,
     ``ops/retention.state_shape`` [L, B, Hkv, T, R, Dh] float32, again
@@ -127,9 +155,12 @@ class PagedKVCache(NamedTuple):
     step before PR 29, PERF.md §6)."""
 
     k: Dict[str, jax.Array]            # kind -> [L_kind, Hkv, P, page, Dh]
-    v: Dict[str, jax.Array]            # ("latent", "state": k alone)
+    v: Dict[str, jax.Array]            # ("latent", "index", "state": k alone)
     page_table: Dict[str, jax.Array]   # kind -> [B, columns] int32 page ids
     lengths: jax.Array                 # [B] int32 valid tokens per slot
+
+    # A pool whose pages are another pool's, at the same ids.
+    RIDES = {"index": "latent"}
 
     @property
     def page_size(self) -> Optional[int]:
@@ -155,6 +186,8 @@ class PagedKVCache(NamedTuple):
                 out[kind] = (layers, batch * ring, ring)
             elif kind == "state":
                 out[kind] = (layers, 0, 0)
+            elif kind in PagedKVCache.RIDES:
+                out[kind] = (layers, total_pages, 0)
             else:
                 out[kind] = (layers, total_pages, max_pages_per_seq)
         return out
@@ -174,6 +207,10 @@ class PagedKVCache(NamedTuple):
             if kind == "latent":
                 return jnp.zeros((layers, pages, page_size, cfg.latent_row),
                                  dtype=cfg.dtype)
+            if kind == "index":
+                return jnp.zeros(
+                    (layers, pages, page_size, cfg.index_head_dim),
+                    dtype=cfg.dtype)
             return jnp.zeros(
                 (layers, cfg.num_kv_heads, pages, page_size, cfg.dh),
                 dtype=cfg.dtype)
@@ -181,12 +218,13 @@ class PagedKVCache(NamedTuple):
         def pools(one_only):
             return {kind: pool(kind, layers, pages)
                     for kind, (layers, pages, _) in sizes.items()
-                    if one_only or kind not in ("latent", "state")}
+                    if one_only or kind not in ("latent", "index", "state")}
 
         return PagedKVCache(
             k=pools(True), v=pools(False),
             page_table={kind: jnp.zeros((batch, columns), dtype=jnp.int32)
-                        for kind, (_, _, columns) in sizes.items()},
+                        for kind, (_, _, columns) in sizes.items()
+                        if kind not in PagedKVCache.RIDES},
             lengths=jnp.zeros((batch,), dtype=jnp.int32),
         )
 
@@ -198,7 +236,10 @@ class KVBooks:
     request reserves at admission, in every pool, all it holds to its
     end (every page of its context, of a ring at most the table's
     columns, of a pool of states nothing: admission is then by slot
-    alone), and nothing is allocated or freed in between. Built from the
+    alone), and nothing is allocated or freed in between. A pool that
+    rides on another's pages (``PagedKVCache.RIDES``) has no account of
+    its own: what is reserved in the pool it rides on is its too. Built
+    from the
     five arguments ``cache`` was created with, and ``cache`` for what
     its pools weigh. One thread writes, the engine's loop; a ``reading``
     on another holds the engine's lock."""
@@ -211,6 +252,15 @@ class KVBooks:
         # {kind: (layers, pool pages, table columns)}
         self.pools = PagedKVCache.sizes(cfg, batch, total_pages, page_size,
                                         max_pages_per_seq)
+        rides = PagedKVCache.RIDES
+        # The pools that have a free list and a table of their own, and
+        # the layers that hold a page of each (its riders' too).
+        self._own = {kind: sizes for kind, sizes in self.pools.items()
+                     if kind not in rides}
+        self._page_layers = {
+            kind: layers + sum(self.pools[r][0] for r, on in rides.items()
+                               if on == kind and r in self.pools)
+            for kind, (layers, _, _) in self._own.items()}
         held = {kind: sum(pool.nbytes for pool in cache.pools(kind))
                 for kind in self.pools}
         # What a token holds in one layer of each pool that has pages,
@@ -222,11 +272,18 @@ class KVBooks:
         self._slot_bytes = {
             kind: held[kind] // (layers * batch)
             for kind, (layers, pages, _) in self.pools.items() if not pages}
-        # What a decode step reads of a sequence in each pool of rows:
-        # (layers, the window or None for every token).
-        self._reads = [(layers, cfg.window(kind))
-                       for kind, (layers, pages, _) in self.pools.items()
+        # What a decode step reads of a sequence in each pool of rows,
+        # and what it attends to of that: (layers, the window or None
+        # for every token, the most a selection keeps or None).
+        selecting = sum(run.n for run in layer_runs(cfg)
+                        if run.kind in ("latent_index", "latent_shared"))
+        self._reads = [(layers, cfg.window(kind), None)
+                       for kind, (layers, pages, _) in self._own.items()
                        if pages]
+        if selecting:
+            (layers, _, _), = self._reads
+            self._reads = [(layers - selecting, None, None),
+                           (selecting, None, cfg.index_topk)]
         self._state_layers = sum(
             layers for layers, pages, _ in self.pools.values() if not pages)
         # ``free_pages``: of the pool that keeps everything, or the only.
@@ -239,13 +296,16 @@ class KVBooks:
         elif cfg.latent:
             self.decode_attention = decode_attention_path(
                 page_size, cfg.latent_row, cfg.kv_lora_rank)
+            if selecting and self.decode_attention == "latent_walk":
+                self.decode_attention = "sparse_walk"
         else:
             self.decode_attention = decode_attention_path(page_size, cfg.dh)
         # What the decode steps read and held (LLMEngine.stats() says
         # what each means), summed as the steps are read.
         self.counts = dict.fromkeys((
             "decode_kv_tokens", "decode_kv_rows_read",
-            "decode_state_slot_layers", "kv_page_steps_held",
+            "decode_kv_rows_selected", "decode_state_slot_layers",
+            "kv_page_steps_held",
             "kv_page_steps_one_table"), 0)
         self.reset()
 
@@ -253,10 +313,10 @@ class KVBooks:
         """Every page free, every table zero, no slot holding."""
         self.free: Dict[str, List[int]] = {
             kind: list(range(pages))
-            for kind, (_, pages, _) in self.pools.items()}
+            for kind, (_, pages, _) in self._own.items()}
         self.tables: Dict[str, np.ndarray] = {
             kind: np.zeros((self._batch, columns), dtype=np.int32)
-            for kind, (_, _, columns) in self.pools.items()}
+            for kind, (_, _, columns) in self._own.items()}
         self._pages: Dict[int, Dict[str, List[int]]] = {}
         # Per slot, fixed from ``reserve`` to ``release`` so that a
         # decode step only adds them up: pages held, each times its
@@ -271,7 +331,7 @@ class KVBooks:
         pool of states, whose table has no column, none."""
         span = max(bucket // self.page_size, -(-tokens // self.page_size))
         return {kind: min(span, columns)
-                for kind, (_, _, columns) in self.pools.items()}
+                for kind, (_, _, columns) in self._own.items()}
 
     def refusal(self, tokens: int, bucket: int) -> Optional[str]:
         """Why such a context could never be held, whatever is released;
@@ -296,7 +356,7 @@ class KVBooks:
                  for kind, n in need.items()}
         self._pages[slot] = pages
         self._held[slot] = sum(
-            self.pools[kind][0] * n for kind, n in need.items())
+            self._page_layers[kind] * n for kind, n in need.items())
         self._one_table[slot] = self._layers * max(need.values())
         for kind, ids in pages.items():
             self.tables[kind][slot, :] = 0
@@ -319,10 +379,13 @@ class KVBooks:
         counts = self.counts
         tokens = sum(contexts)
         counts["decode_kv_tokens"] += tokens
-        for layers, window in self._reads:
-            counts["decode_kv_rows_read"] += layers * (
-                tokens if window is None
-                else sum(min(c, window) for c in contexts))
+        for layers, window, most in self._reads:
+            read = (tokens if window is None
+                    else sum(min(c, window) for c in contexts))
+            counts["decode_kv_rows_read"] += layers * read
+            counts["decode_kv_rows_selected"] += layers * (
+                read if most is None
+                else sum(min(c, most) for c in contexts))
         counts["decode_state_slot_layers"] += (
             len(contexts) * self._state_layers)
         counts["kv_page_steps_held"] += sum(
@@ -337,7 +400,8 @@ class KVBooks:
             **self.counts,
             "free_pages": len(self.free[self._gauge]),
             "pages": {kind: {"layers": layers, "total": total,
-                             "free": len(self.free[kind])}
+                             "free": len(self.free[
+                                 PagedKVCache.RIDES.get(kind, kind)])}
                       for kind, (layers, total, _) in self.pools.items()},
             "kv_row_bytes": dict(self._row_bytes),
             "state_slot_bytes": dict(self._slot_bytes),
@@ -379,12 +443,22 @@ def paged_decode(
     x = embed_tokens(params, tokens, cfg)[:, None]
     pools = {kind: cache.pools(kind) for kind in cache.k}
     expert_tokens = []
-    for run, stack in zip(layer_runs(cfg), layer_stacks(params)):
+    # The last selection made, [B, T] float32 (``index_select_decode``):
+    # an indexing layer replaces it, the layers that share it take it
+    # as the layer loop hands it on.
+    selected = None
+    for run, stack, index_at in zip(layer_runs(cfg), layer_stacks(params),
+                                    index_offsets(cfg)):
         layers, expert_stack = split_expert_stack(stack)
-        kind, table = run.kind, cache.page_table[run.kind]
+        kind, pool = run.kind, pool_kind(run.kind)
+        table = cache.page_table[pool]
+        if kind == "latent_index" and selected is None:
+            selected = jnp.zeros(
+                (tokens.shape[0], table.shape[1] * cache.page_size),
+                jnp.float32)
 
         def body(carry, lp):
-            x, held = carry
+            x, held, keys, selected = carry
 
             def attend(q, k, v):
                 # The token's K/V row goes to ``decode_attention``, which
@@ -398,19 +472,32 @@ def paged_decode(
                         q[:, 0], k[:, 0], v[:, 0], *held,
                         lp["index"] + run.kv_offset, table, cache.lengths,
                         active, window=cfg.window(kind))
-                return out[:, None], tuple(new)
+                return out[:, None], (tuple(new), keys, selected)
 
-            def attend_latent(q, row, _):
+            def attend_latent(q, row, index, selected=selected, keys=keys):
                 # Absorbed: every head's query against the rows as they
-                # are cached, the values the rows' own latent part.
+                # are cached, the values the rows' own latent part; of a
+                # layer with a selection, against the selected rows.
+                if index is not None:
+                    q_i, k_i, w_i = index
+                    with jax.named_scope("index.score"):
+                        selected, key_pool = index_select_decode(
+                            q_i[:, 0], w_i[:, 0], k_i[:, 0], *keys,
+                            lp["index"] + index_at, table, cache.lengths,
+                            active, topk=cfg.index_topk)
+                    keys = (key_pool,)
+                sparse = kind != "latent"
                 q_lat = latent_absorb_q(cfg, lp, q)
-                with jax.named_scope("attn.latent"):
+                with jax.named_scope(
+                        "attn.sparse" if sparse else "attn.latent"):
                     out, pool = latent_decode_attention(
                         q_lat[:, 0], row[:, 0], *held,
                         lp["index"] + run.kv_offset, table, cache.lengths,
                         active, scale=cfg.dh ** -0.5,
-                        values=cfg.kv_lora_rank)
-                return latent_absorb_out(cfg, lp, out[:, None]), (pool,)
+                        values=cfg.kv_lora_rank,
+                        selected=selected if sparse else None)
+                return (latent_absorb_out(cfg, lp, out[:, None]),
+                        ((pool,), keys, selected))
 
             def attend_state(q, k, v_gate):
                 v, log_g = v_gate
@@ -418,25 +505,29 @@ def paged_decode(
                     out, pool = retention_decode(
                         q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], *held,
                         lp["index"] + run.kv_offset, active)
-                return out[:, None], (pool,)
+                return out[:, None], ((pool,), keys, selected)
 
             # The load-balancing loss is a training-only term: dropped.
-            x, held, _aux, load = block(
+            x, kept, _aux, load = block(
                 cfg, lp, x, cache.lengths[:, None],
                 {"latent": attend_latent, "state": attend_state}.get(
-                    kind, attend),
+                    pool, attend),
                 token_mask=active[:, None], expert_stack=expert_stack,
                 kind=kind)
-            return (x, held), load
+            return (x,) + kept, load
 
-        (x, pools[kind]), load = jax.lax.scan(body, (x, pools[kind]), layers)
+        (x, pools[pool], keys, selected), load = jax.lax.scan(
+            body, (x, pools[pool], pools.get("index", ()), selected), layers)
+        if keys:
+            pools["index"] = keys
         if load is not None:
             expert_tokens.append(load)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bm,mv->bv", x[:, 0], params["lm_head"])
     lengths = jnp.where(active, cache.lengths + 1, cache.lengths)
     return logits.astype(jnp.float32), _with_pools(
-        cache, pools, lengths), MoeLoad.of_layers(expert_tokens)
+        cache, pools, lengths), MoeLoad.of_layers(
+            expert_tokens, cfg.experts_held is not None)
 
 
 def paged_prefill(
@@ -475,19 +566,20 @@ def paged_prefill(
     pools = {kind: cache.pools(kind) for kind in cache.k}
     expert_tokens = []
 
-    def to_pages(rows, pool, run):
+    def to_pages(rows, pool, run, offset):
         """[n, 1, S, Hkv, Dh] -> [n, Hkv, S // page, page, Dh], the
-        pool's layout, set at the run's layers and the slot's page ids;
-        latent rows [n, 1, S, W] -> [n, S // page, page, W] likewise."""
+        pool's layout, set at the run's layers (from ``offset`` of the
+        pool's) and the slot's page ids; latent rows and indexer keys
+        [n, 1, S, W] -> [n, S // page, page, W] likewise."""
         if run.kind == "state":
             # [n, Hkv, T, R, Dh]: the slot's states of the run's layers.
             return jax.lax.dynamic_update_slice(
-                pool, rows[:, None], (run.kv_offset, slot, 0, 0, 0, 0))
-        ids = pages[run.kind]
+                pool, rows[:, None], (offset, slot, 0, 0, 0, 0))
+        ids = pages[pool_kind(run.kind)]
         whole = run.n == pool.shape[0]
-        at = slice(None) if whole else slice(run.kv_offset,
-                                             run.kv_offset + run.n)
-        if run.kind == "latent":
+        at = slice(None) if whole else slice(offset, offset + run.n)
+        if rows.ndim == 4:
+            # One row a token for all heads: latent rows, indexer keys.
             paged = rows[:, 0].reshape(run.n, S // page, page, -1)
             return pool.at[at, ids].set(paged.astype(pool.dtype))
         paged = rows[:, 0].reshape(
@@ -502,24 +594,47 @@ def paged_prefill(
             ids = ids[(first + jnp.arange(len(ids))) % len(ids)]
         return pool.at[at, :, ids].set(paged.astype(pool.dtype))
 
-    for run, stack in zip(layer_runs(cfg), layer_stacks(params)):
+    # A prompt no longer than the selection keeps selects every token
+    # before it: None, and the attention is the causal one of a latent
+    # layer without an indexer. Else the last selection made
+    # (``prefill_select``'s), handed on by the layer loop.
+    selects = cfg.index_topk and S > cfg.index_topk
+    selected = (empty_selection(S, cfg.index_head_dim) if selects else None)
+    for run, stack, index_at in zip(layer_runs(cfg), layer_stacks(params),
+                                    index_offsets(cfg)):
         layers, expert_stack = split_expert_stack(stack)
-        kind = run.kind
+        kind, pool = run.kind, pool_kind(run.kind)
 
-        def body(x, lp):
+        def body(carry, lp):
+            x, selected = carry
+
             def attend(q, k, v):
                 with jax.named_scope(f"attn.{kind}"):
                     out = causal_attention(cfg, None, q, k, v,
                                            window=cfg.window(kind))
-                return out, (k, v)
+                return out, ((k, v), selected)
 
-            def attend_latent(q, row, _):
+            def attend_latent(q, row, index, selected=selected):
                 # Rebuilt: k and v of every head from the rows, for this
-                # attention alone; what is kept is the rows.
+                # attention alone; what is kept is the rows, and of an
+                # indexing layer its keys.
+                kept = (row,)
+                if index is not None:
+                    q_i, k_i, w_i = index
+                    kept = (row, k_i)
+                    if selects:
+                        selected = prefill_select(
+                            q_i[0], k_i[0], w_i[0], topk=cfg.index_topk)
                 k, v = latent_kv(cfg, lp, row)
-                with jax.named_scope("attn.latent"):
-                    out = causal_attention(cfg, None, q, k, v)
-                return out, (row,)
+                if kind == "latent" or not selects:
+                    with jax.named_scope("attn.latent"):
+                        out = causal_attention(cfg, None, q, k, v)
+                else:
+                    with jax.named_scope("attn.sparse"):
+                        out = sparse_prefill_attention(
+                            q[0], k[0], v[0], selected,
+                            scale=cfg.dh ** -0.5)[None]
+                return out, (kept, selected)
 
             def attend_state(q, k, v_gate):
                 v, log_g = v_gate
@@ -528,25 +643,31 @@ def paged_prefill(
                     out, state = retention_prefill(
                         q[0], jnp.where(real[:, None, None], k[0], 0),
                         v[0], jnp.where(real[:, None], log_g[0], 0.0))
-                return out[None], (state,)
+                return out[None], ((state,), selected)
 
-            x, kept, _aux, load = block(
+            x, (kept, selected), _aux, load = block(
                 cfg, lp, x, positions,
                 {"latent": attend_latent, "state": attend_state}.get(
-                    kind, attend),
+                    pool, attend),
                 token_mask=token_mask, expert_stack=expert_stack, kind=kind)
-            return x, (kept, load)
+            return (x, selected), (kept, load)
 
-        x, (kept, load) = jax.lax.scan(body, x, layers)
-        pools[kind] = tuple(to_pages(rows, pool, run)
-                            for rows, pool in zip(kept, pools[kind]))
+        (x, selected), (kept, load) = jax.lax.scan(body, (x, selected),
+                                                   layers)
+        kept, keys = kept[:len(pools[pool])], kept[-1]
+        pools[pool] = tuple(to_pages(rows, held, run, run.kv_offset)
+                            for rows, held in zip(kept, pools[pool]))
+        if kind == "latent_index":
+            pools["index"] = (to_pages(keys, *pools["index"], run,
+                                       index_at),)
         if load is not None:
             expert_tokens.append(load)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bm,mv->bv", x[:, real_len - 1], params["lm_head"])
     lengths = cache.lengths.at[slot].set(real_len)
     return logits.astype(jnp.float32), _with_pools(
-        cache, pools, lengths), MoeLoad.of_layers(expert_tokens)
+        cache, pools, lengths), MoeLoad.of_layers(
+            expert_tokens, cfg.experts_held is not None)
 
 
 def sample_logits(logits: jax.Array, rng: jax.Array, *,

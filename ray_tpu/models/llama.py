@@ -117,6 +117,25 @@ class LlamaConfig:
     v_head_dim: int = 0
     # Rotary over adjacent pairs (2i, 2i+1) instead of the two halves.
     rope_interleave: bool = False
+    # A learned selection over the latent pool (DSA) when ``index_topk``
+    # > 0: a layer that ``indexer_types`` calls "full" scores every
+    # cached token with an indexer of its own (``index_n_heads`` queries
+    # of ``index_head_dim`` from the q bottleneck, one key a token from
+    # the layer's input, cached in the "index" pool; the first
+    # ``qk_rope_head_dim`` of both rotated; I = sum_j w_j relu(q_j . k)),
+    # keeps the ``index_topk`` best positions (``ops/sparse_attention``)
+    # and attends over those alone; a "shared" layer has no indexer and
+    # attends over the selection of the nearest "full" layer before it.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_rope_interleave: bool = False
+    indexer_types: Optional[Tuple[str, ...]] = None
+    # (first, count): the routed experts held here, of ``n_experts`` that
+    # the router scores; one chip's share of a layer that ``n_experts /
+    # count`` chips hold between them (parallel/moe.py ``held``). None:
+    # all of them.
+    experts_held: Optional[Tuple[int, int]] = None
     remat: bool = True
     # "full" (save only layer inputs), "dots" (save matmul outputs,
     # recompute elementwise), or "save_all" (save every intermediate —
@@ -170,6 +189,11 @@ class LlamaConfig:
         return self.kv_lora_rank + -(-self.qk_rope_head_dim // 128) * 128
 
     @property
+    def experts_here(self) -> int:
+        """Routed experts whose weights this program holds."""
+        return self.experts_held[1] if self.experts_held else self.n_experts
+
+    @property
     def retention(self) -> bool:
         return bool(self.layer_types) and "state" in self.layer_types
 
@@ -206,8 +230,16 @@ class LayerRun(NamedTuple):
     start: int
     n: int
     moe: bool
-    kind: str       # "full" | "window" | "latent" | "state"
+    # "full" | "window" | "latent" | "state", or a latent layer that
+    # attends over a selection: "latent_index" makes one, "latent_shared"
+    # takes the last one made. Both keep their rows in the "latent" pool.
+    kind: str
     kv_offset: int
+
+
+def pool_kind(kind: str) -> str:
+    """The KV pool a layer of ``kind`` keeps its rows in."""
+    return "latent" if kind.startswith("latent") else kind
 
 
 def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
@@ -224,6 +256,18 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
                 f"layer_types) and head_dim ({cfg.dh}) is the q.k width, "
                 f"qk_nope_head_dim + qk_rope_head_dim")
         kinds = ("latent",) * cfg.num_layers
+        if cfg.index_topk:
+            types = cfg.indexer_types or ()
+            if (len(types) != cfg.num_layers or types[0] != "full"
+                    or set(types) - {"full", "shared"}):
+                raise ValueError(
+                    f"a selection (index_topk {cfg.index_topk}): "
+                    f"indexer_types {types} must name 'full' or 'shared' "
+                    f"for each of {cfg.num_layers} layers, the first "
+                    f"'full' (a shared layer takes the selection of a "
+                    f"full one before it)")
+            kinds = tuple("latent_index" if t == "full" else "latent_shared"
+                          for t in types)
     elif cfg.retention:
         kinds = cfg.layer_types
         if (set(kinds) != {"state"} or len(kinds) != cfg.num_layers
@@ -249,9 +293,19 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
         if runs and alike[i - 1] == (moe, kind):
             runs[-1] = runs[-1]._replace(n=runs[-1].n + 1)
         else:
-            runs.append(LayerRun(i, 1, moe, kind, seen[kind]))
-        seen[kind] += 1
+            runs.append(LayerRun(i, 1, moe, kind, seen[pool_kind(kind)]))
+        seen[pool_kind(kind)] += 1
     return tuple(runs)
+
+
+def index_offsets(cfg: LlamaConfig) -> Tuple[int, ...]:
+    """For each of ``layer_runs``, where its layers begin in the "index"
+    pool (the indexing layers before it)."""
+    out, seen = [], 0
+    for run in layer_runs(cfg):
+        out.append(seen)
+        seen += run.n * (run.kind == "latent_index")
+    return tuple(out)
 
 
 def kv_layers(cfg: LlamaConfig) -> Dict[str, int]:
@@ -259,7 +313,14 @@ def kv_layers(cfg: LlamaConfig) -> Dict[str, int]:
     pools a model needs, in the order of first use."""
     out: Dict[str, int] = {}
     for run in layer_runs(cfg):
-        out[run.kind] = out.get(run.kind, 0) + run.n
+        kind = pool_kind(run.kind)
+        out[kind] = out.get(kind, 0) + run.n
+    indexing = out.get("latent") and sum(
+        run.n for run in layer_runs(cfg) if run.kind == "latent_index")
+    if indexing:
+        # The indexers' keys, one a token an indexing layer: a pool of
+        # its own that the latent pool's page table addresses.
+        out["index"] = indexing
     return out
 
 
@@ -415,9 +476,10 @@ def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
         layers.update(q_norm=norm_init((n, Dh if per_head else H * Dh)),
                       k_norm=norm_init((n, Dh if per_head else Hkv * Dh)))
     if moe:
-        E, F = cfg.n_experts, cfg.expert_size
+        # The router scores all of them; the stacks hold those held here.
+        R, E, F = cfg.n_experts, cfg.experts_here, cfg.expert_size
         layers.update(
-            router=winit(next(k), (n, M, E), M).astype(jnp.float32),
+            router=winit(next(k), (n, M, R), M).astype(jnp.float32),
             w_gate=winit(next(k), (n, E, M, F), M),
             w_up=winit(next(k), (n, E, M, F), M),
             w_down=winit(next(k), (n, E, F, M), F),
@@ -430,7 +492,7 @@ def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
         if cfg.router_bias:
             # Nonzero, so that "selects, but is not in the gate" shows.
             layers["expert_bias"] = 0.05 * jax.random.normal(
-                next(k), (n, E), dtype=jnp.float32)
+                next(k), (n, R), dtype=jnp.float32)
     else:
         F = cfg.intermediate_size
         layers.update(
@@ -446,6 +508,18 @@ def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
     if kind == "state":
         layers.update(wg=winit(next(k), (n, M, Hkv), M),
                       bg=_retention_gate_bias(n, Hkv))
+    if kind == "latent_index":
+        # The indexer: queries from the q bottleneck, one key a token
+        # and the heads' weights from the layer's input, a LayerNorm
+        # (weight and bias) on the key.
+        Hi, Di = cfg.index_n_heads, cfg.index_head_dim
+        layers.update(
+            wi_q=winit(next(k), (n, cfg.q_lora_rank, Hi, Di),
+                       cfg.q_lora_rank),
+            wi_k=winit(next(k), (n, M, Di), M),
+            wi_w=winit(next(k), (n, M, Hi), M),
+            i_k_norm=norm_init((n, Di)),
+            i_k_bias=jnp.zeros((n, Di), dtype=jnp.float32))
     return layers
 
 
@@ -454,15 +528,21 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     them, one a run (``layer_runs``), for any other."""
     M, V = cfg.hidden_size, cfg.vocab_size
     runs = layer_runs(cfg)
-    k = iter(jax.random.split(key, 16))
+
+    def keys(key):
+        # Sixteen as ever, so that a seed's weights stay what they were,
+        # and sixteen more for a stack with more leaves than that.
+        yield from jax.random.split(key, 16)
+        yield from jax.random.split(jax.random.fold_in(key, 16), 16)
+
+    k = keys(key)
     if len(runs) == 1:
         layers = _init_stack(cfg, k, cfg.num_layers, runs[0].moe,
                              runs[0].kind)
     else:
         layers = tuple(
-            _init_stack(cfg, iter(jax.random.split(
-                jax.random.fold_in(key, run.start), 16)), run.n, run.moe,
-                run.kind)
+            _init_stack(cfg, keys(jax.random.fold_in(key, run.start)),
+                        run.n, run.moe, run.kind)
             for run in runs)
 
     def winit(key, shape):
@@ -586,6 +666,12 @@ def latent_proj(cfg: LlamaConfig, lp, x, positions):
     the normed latent of ``kv_lora_rank``, then the one rotated key all
     heads share, then zeros up to the lane tile. The row is what a cache
     keeps (generation.PagedKVCache) and all that a later token needs."""
+    return _latent_parts(cfg, lp, x, positions)[:2]
+
+
+def _latent_parts(cfg: LlamaConfig, lp, x, positions):
+    """``latent_proj``'s (q, row), and behind them what an indexer reads
+    besides: the normed input ``h`` and the normed q bottleneck ``c_q``."""
     nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     with jax.named_scope("mla.q"):
@@ -601,7 +687,40 @@ def latent_proj(cfg: LlamaConfig, lp, x, positions):
         k_rope = rope(kv[..., None, rank:], positions, cfg.rope_theta,
                       cfg.rope_interleave)[..., 0, :]
         row = _latent_row(cfg, c, k_rope)
-    return q, row
+    return q, row, h, c_q
+
+
+def _rope_head(cfg: LlamaConfig, x, positions):
+    """Rotary on the first ``qk_rope_head_dim`` of an indexer's head
+    [B,S,H,D], the rest left as it is."""
+    rot = cfg.qk_rope_head_dim
+    return jnp.concatenate(
+        [rope(x[..., :rot], positions, cfg.rope_theta,
+              cfg.index_rope_interleave), x[..., rot:]], axis=-1)
+
+
+def index_proj(cfg: LlamaConfig, lp, h, c_q, positions):
+    """An indexing layer's indexer, from the layer's normed input ``h``
+    [B,S,M] and its normed q bottleneck ``c_q``: the queries
+    [B,S,``index_n_heads``,``index_head_dim``] and the token's one key
+    [B,S,``index_head_dim``] (a LayerNorm with bias on it), both rotated
+    in their first ``qk_rope_head_dim`` and in the model's dtype, and the
+    heads' weights [B,S,``index_n_heads``] float32, scaled by
+    ``index_n_heads ** -0.5 * index_head_dim ** -0.5``. The key is what
+    the "index" pool keeps; a token's score of a cached one is ``sum_j
+    w_j relu(q_j . k)`` (ops/sparse_attention.py)."""
+    with jax.named_scope("index.proj"):
+        q = jnp.einsum("bsr,rhd->bshd", c_q, lp["wi_q"])
+        k = jnp.einsum("bsm,md->bsd", h, lp["wi_k"])
+        kf = k.astype(jnp.float32)
+        kf = kf - kf.mean(axis=-1, keepdims=True)
+        kf = kf * jax.lax.rsqrt(
+            jnp.mean(kf * kf, axis=-1, keepdims=True) + cfg.rms_eps)
+        k = (kf * lp["i_k_norm"] + lp["i_k_bias"]).astype(k.dtype)
+        w = jnp.einsum("bsm,mh->bsh", h, lp["wi_w"]).astype(jnp.float32) * (
+            cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5)
+        return (_rope_head(cfg, q, positions),
+                _rope_head(cfg, k[..., None, :], positions)[..., 0, :], w)
 
 
 def latent_kv(cfg: LlamaConfig, lp, row):
@@ -678,7 +797,10 @@ def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
     experts (and the shared expert beside it), normed again where the
     model has post-norms, added to x [B,S,M].
     Returns (x, load-balancing loss, tokens assigned to each expert [E]
-    or None for a dense layer). ``token_mask`` [B,S] keeps rows (inactive
+    or None for a dense layer; of a model that holds a share of the
+    experts, ``cfg.experts_held``, the held ones' and behind them the
+    assignments that went elsewhere: ``moe_ffn``). ``token_mask`` [B,S]
+    keeps rows (inactive
     decode slots, bucket padding) away from every expert. The experts'
     weights are ``lp``'s own, or with ``expert_stack`` (the second half
     of ``split_expert_stack``) the whole run's, read at ``lp["index"]``."""
@@ -689,6 +811,7 @@ def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
             h, lp["router"], w["w_up"], w["w_down"], k=cfg.top_k,
             w_gate=w["w_gate"], token_mask=token_mask,
             layer=None if expert_stack is None else lp["index"], mesh=mesh,
+            held=cfg.experts_held,
             score=cfg.router_score, select_bias=lp.get("expert_bias"),
             renormalize=cfg.route_norm, scale=cfg.route_scale,
         )
@@ -724,13 +847,24 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
     the tokens' latent rows in k's place and no v (``latent_proj``), and
     returns [B,S,H,``v_head_dim``]; whether it rebuilds k and v
     (``latent_kv``) or absorbs the up-projections (``latent_absorb_q``,
-    ``latent_absorb_out``) is its own to choose. For "state" ``attend``
+    ``latent_absorb_out``) is its own to choose. A latent layer that
+    attends over a selection is "latent_index", whose ``attend`` is given
+    the indexer's (queries, key, weights) in v's place (``index_proj``)
+    and makes the selection, or "latent_shared", whose ``attend`` is
+    given no v and brings the selection it was handed: the block passes
+    nothing from layer to layer but the residual, the caller's layer loop
+    carries the selection in ``attend``'s state. For "state" ``attend``
     is given ``(v, log gates)`` in v's place (``qkv_proj``).
 
     Returns (x, attend's state, load-balancing loss, tokens assigned to
     each expert or None)."""
     if kind == "latent":
         (q, k), v, gate = latent_proj(cfg, lp, x, positions), None, None
+    elif kind in ("latent_index", "latent_shared"):
+        q, k, h, c_q = _latent_parts(cfg, lp, x, positions)
+        v = gate = None
+        if kind == "latent_index":
+            v = index_proj(cfg, lp, h, c_q, positions)
     else:
         q, k, v, gate = qkv_proj(cfg, lp, x)
         if cfg.rope_full_layers or kind != "full":

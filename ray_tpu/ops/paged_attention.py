@@ -301,9 +301,13 @@ _LATENT_BLOCK_TOKENS = 256
 
 
 def _latent_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, new_ref,
-                        pool_hbm, o_ref, pool_out, buf, sems, *, pmax: int,
-                        scale: float):
-    """Grid (B,). pt_ref [B * Pmax], np_ref [B] (pages to walk), len_ref
+                        *refs, pmax: int, scale: float,
+                        selected: bool = False):
+    """Grid (B,). With ``selected`` one more input behind new_ref,
+    sel_ref [blocks, block] float32: the slot attends to the positions
+    where it is not 0 (ops/sparse_attention.py).
+
+    pt_ref [B * Pmax], np_ref [B] (pages to walk), len_ref
     [B], layer_ref [1] in SMEM; q_ref [H, W] this slot's absorbed
     queries, laid as rows; new_ref [1, W] its new row; pool_hbm the pool
     [L, P, page, W] left in HBM and pool_out the same buffer as an
@@ -312,6 +316,10 @@ def _latent_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, new_ref,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    sel_ref = None
+    if selected:
+        sel_ref, *refs = refs
+    pool_hbm, o_ref, pool_out, buf, sems = refs
     b = pl.program_id(0)
     values = o_ref.shape[1]
     _, block, _ = buf.shape
@@ -375,10 +383,17 @@ def _latent_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, new_ref,
             q, rows, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)           # [H, block]
         t = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(t <= length, s * scale, _NEG_INF)
+        attends = t <= length
+        if sel_ref is not None:
+            attends &= sel_ref[pl.ds(i, 1), :] != 0.0
+        s = jnp.where(attends, s * scale, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         prob = jnp.exp(s - m_new)
+        if sel_ref is not None:
+            # A block with nothing selected leaves m_new at -1e30 and
+            # exp(0) = 1 for what was masked.
+            prob = jnp.where(attends, prob, 0.0)
         l = alpha * l + prob.sum(axis=1, keepdims=True)
         pv = jnp.dot(prob.astype(rows.dtype), rows[:, :values],
                      preferred_element_type=jnp.float32)  # [H, values]
@@ -408,11 +423,14 @@ def paged_latent_decode_attention(
     *,
     scale: float,
     values: int,
+    selected: Optional[jax.Array] = None,   # [B, Pmax * page] float32
     interpret: bool = False,
 ):
     """The latent walk. Returns ([B, H, values], pool): rows of inactive
     slots are zeros, the pool is the argument's buffer with the active
-    slots' rows written."""
+    slots' rows written. With ``selected`` (``sparse_walk``) a slot
+    walks the same pages and attends only to the positions where its
+    row of ``selected`` is not 0."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -420,39 +438,58 @@ def paged_latent_decode_attention(
     page = pool.shape[2]
     Pmax = page_table.shape[1]
     pages_per_block = max(1, _LATENT_BLOCK_TOKENS // page)
+    block = pages_per_block * page
     n_pages = jnp.where(active, jnp.minimum(lengths // page + 1, Pmax),
                         0).astype(jnp.int32)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     dtype = pool.dtype
-    kernel = functools.partial(_latent_walk_kernel, pmax=Pmax, scale=scale)
-    return pl.pallas_call(
+    kernel = functools.partial(_latent_walk_kernel, pmax=Pmax, scale=scale,
+                               selected=selected is not None)
+    slot_rows = pl.BlockSpec((None, H, values), lambda b, *_: (b, 0, 0))
+    out_rows = jax.ShapeDtypeStruct((B, H, values), q.dtype)
+    more_specs, more = [], []
+    if selected is not None:
+        blocks = -(-Pmax * page // block)
+        more.append(jnp.pad(
+            selected, ((0, 0), (0, blocks * block - Pmax * page))
+        ).reshape(B, blocks, block))
+        more_specs.append(pl.BlockSpec((None, blocks, block),
+                                       lambda b, *_: (b, 0, 0)))
+        # Four dimensions, so that the reducer's name for this call is
+        # not the latent walk's (three and four).
+        slot_rows = pl.BlockSpec((None, None, H, values),
+                                 lambda b, *_: (b, 0, 0, 0))
+        out_rows = jax.ShapeDtypeStruct((B, 1, H, values), q.dtype)
+    pool_at = 6 + len(more)
+    out, pool = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(B,),
             in_specs=[pl.BlockSpec((None, H, W), lambda b, *_: (b, 0, 0)),
                       pl.BlockSpec((None, 1, W), lambda b, *_: (b, 0, 0)),
-                      hbm],
-            out_specs=[pl.BlockSpec((None, H, values),
-                                    lambda b, *_: (b, 0, 0)), hbm],
+                      *more_specs, hbm],
+            out_specs=[slot_rows, hbm],
             scratch_shapes=[
-                pltpu.VMEM((2, pages_per_block * page, W), dtype),
+                pltpu.VMEM((2, block, W), dtype),
                 pltpu.SemaphoreType.DMA((2, 2))],
         ),
-        out_shape=[jax.ShapeDtypeStruct((B, H, values), q.dtype),
-                   jax.ShapeDtypeStruct(pool.shape, dtype)],
-        # Operands count the four prefetched scalars: the pool is 6.
-        input_output_aliases={6: 1},
+        out_shape=[out_rows, jax.ShapeDtypeStruct(pool.shape, dtype)],
+        # Operands count the four prefetched scalars: the pool is 6,
+        # behind a selection 7.
+        input_output_aliases={pool_at: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(page_table.reshape(-1).astype(jnp.int32), n_pages,
       lengths.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
-      q.astype(dtype), row_new.astype(dtype)[:, None], pool)
+      q.astype(dtype), row_new.astype(dtype)[:, None], *more, pool)
+    return out.reshape(B, H, values), pool
 
 
 def gather_latent_decode_attention(q, row_new, pool, layer, page_table,
-                                   lengths, active, *, scale, values):
+                                   lengths, active, *, scale, values,
+                                   selected=None):
     """The XLA path: the kernel's arguments and results, bar that an
     inactive slot's row of the attention is computed (and discarded by
     the caller)."""
@@ -468,6 +505,8 @@ def gather_latent_decode_attention(q, row_new, pool, layer, page_table,
     s = jnp.einsum("bhw,btw->bht", q, rows,
                    preferred_element_type=jnp.float32) * scale
     attends = jnp.arange(T)[None, :] <= lengths[:, None]
+    if selected is not None:
+        attends &= selected != 0.0
     s = jnp.where(attends[:, None], s, -jnp.inf)
     prob = jax.nn.softmax(s, axis=-1).astype(rows.dtype)
     return jnp.einsum("bht,btr->bhr", prob, rows[..., :values]), pool
@@ -561,16 +600,20 @@ def decode_attention(q, k_new, v_new, k_pool, v_pool, layer, page_table,
 
 
 def latent_decode_attention(q, row_new, pool, layer, page_table, lengths,
-                            active, *, scale: float, values: int):
+                            active, *, scale: float, values: int,
+                            selected=None):
     """Write each active slot's ``row_new`` [B, W] into the latent pool
     [L, P, page, W] at ``layer`` and position ``lengths[b]``, and attend
     the absorbed queries [B, H, W] over rows ``0 .. lengths[b]``: scores
     ``scale * q . row``, values the rows' first ``values``. Returns
     (attention [B, H, values], pool), by the path
-    :func:`decode_attention_path` names."""
+    :func:`decode_attention_path` names. With ``selected`` [B, T]
+    float32 (ops/sparse_attention.index_select_decode's) a slot attends
+    only to the positions where it is not 0: the same walk over every
+    page, ``"sparse_walk"``."""
     page, W = pool.shape[2:]
     path = (paged_latent_decode_attention
             if decode_attention_path(page, W, values) == "latent_walk"
             else gather_latent_decode_attention)
     return path(q, row_new, pool, layer, page_table, lengths, active,
-                scale=scale, values=values)
+                scale=scale, values=values, selected=selected)

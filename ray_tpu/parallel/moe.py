@@ -15,7 +15,9 @@ nothing has a capacity axis. Rows of a ``token_mask`` (inactive decode
 slots, a prefill bucket's padding) sort behind the last group and belong
 to no expert. Experts are sharded over the "ep" mesh axis by their
 weights' logical axis "expert"; GSPMD partitions the grouped matmuls (an
-all-to-all layout is ROADMAP R3's).
+all-to-all layout is ROADMAP R3's). A chip that holds a share of a
+layer's experts says which (``moe_ffn(held=)``): it routes over all of
+them, computes its own and drops the rest, with no exchange.
 
 What multiplies the groups is ops/grouped_matmul.py's to choose, from
 what it can see and nothing a caller sets
@@ -77,11 +79,25 @@ def moe_ffn(
     token_mask: Optional[jax.Array] = None,  # [B, S] 1=route, 0=ignore
     layer: Optional[jax.Array] = None,  # [] int32: the weights are stacks
     mesh=None,              # the mesh the program is partitioned over
+    held: Optional[Tuple[int, int]] = None,  # (first, count): None is all
     **routing,              # route()'s: score, select_bias, renormalize, scale
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (output [B,S,M], Switch load-balancing loss, tokens
     assigned to each expert [E] int32). Masked rows give zero, reach no
     expert and are not counted.
+
+    ``held`` = (first, count): this chip holds experts ``first ..
+    first + count`` of the router's ``E = router_w.shape[1]``, one of
+    ``E / count`` chips that share the layer, and the expert stacks hold
+    those ``count`` alone. The router's logits, the top k, the
+    renormalisation and the scale are over all E, as on every chip of
+    the deployment; an assignment to an expert held elsewhere is dropped
+    before the sort, reaches no group and adds nothing, so the output is
+    this chip's part of the layer's routed sum (the parts of all the
+    chips add up to the uncut layer's; nothing here stands in for the
+    chips that are not there). The third result is then ``[count + 1]``:
+    the held experts' tokens, and behind them the count of assignments
+    that went elsewhere.
 
     With ``layer``, ``w_in``, ``w_gate`` and ``w_out`` are all layers'
     experts ``[L, E, ...]`` and this layer's are read where they lie, as
@@ -104,6 +120,18 @@ def moe_ffn(
             "tm,me->te", xt.astype(jnp.float32), router_w.astype(jnp.float32)
         )
         probs, gates, experts = route(router_logits, k, **routing)
+        if held is not None:
+            # From here on "expert" is an index into the held stack, E
+            # their number, and ``E`` itself the expert that does not
+            # exist (below), where assignments held elsewhere go too.
+            first, count = held
+            here = (experts >= first) & (experts < first + count)
+            assigned = k * (T if token_mask is None
+                            else token_mask.sum().astype(jnp.int32))
+            experts = jnp.where(here, experts - first, count)
+            gates = gates * here
+            probs = probs[:, first:first + count]
+            E = count
         chosen = jax.nn.one_hot(experts, E, dtype=jnp.float32).sum(axis=1)
         if token_mask is not None:
             live = token_mask.reshape(T).astype(bool)
@@ -118,7 +146,8 @@ def moe_ffn(
         group_sizes = expert_tokens.astype(jnp.int32)
         order = jnp.argsort(experts.reshape(T * k))            # stable
         rows = xt[order // k]                                  # [k*T, M]
-        if token_mask is not None:
+        unrouted = token_mask is not None or held is not None
+        if unrouted:
             # Rows behind the last group: a grouped matmul leaves there
             # whatever the backend does (zeros on the CPU, not on the
             # TPU: chip run, PR 28), forward and backward, so they are
@@ -145,7 +174,7 @@ def moe_ffn(
         h = matmul(rows, (w_in,) if w_gate is None else (w_in, w_gate),
                    activate)
         y = matmul(h, (w_out,))                                # [k*T, M]
-        if token_mask is not None:
+        if unrouted:
             y = jnp.where(routed, y, 0)
     with jax.named_scope("moe.combine"):
         # Back to token order (a gather by the inverse permutation, no
@@ -154,4 +183,7 @@ def moe_ffn(
         back = jnp.argsort(order)
         out = jnp.einsum("tkm,tk->tm", y[back].reshape(T, k, M), gates,
                          preferred_element_type=jnp.float32)
+    if held is not None:
+        group_sizes = jnp.concatenate(
+            [group_sizes, (assigned - group_sizes.sum())[None]])
     return out.astype(x.dtype).reshape(B, S, M), aux, group_sizes

@@ -251,7 +251,9 @@ def serving_programs(cfg, temperature: float):
     ``slot`` (traced: one program a bucket, whatever the slot). A
     read-back is the sampled tokens ([B], of a prefill one) and, for a
     MoE model, the program's expert load behind them in the same int32
-    vector: one read a step, as for a dense model."""
+    vector (the held experts' tokens, the experts reached and, of a
+    model that holds a share of its experts, the assignments that went
+    elsewhere): one read a step, as for a dense model."""
     import jax
     import jax.numpy as jnp
 
@@ -261,7 +263,8 @@ def serving_programs(cfg, temperature: float):
         if load is None:
             return tokens
         return jnp.concatenate([
-            tokens, load.expert_tokens, load.experts_reached[None]])
+            tokens, load.expert_tokens, load.experts_reached[None],
+            *([] if load.elsewhere is None else [load.elsewhere[None]])])
 
     def decode_step(params, cache, last_tok, active, rng):
         # The chain a loop that split on the host would draw: the same
@@ -534,10 +537,13 @@ class _Runner:
                          "experts_reached": 0, "layer_steps": 0,
                          "prefill_experts_reached": 0,
                          "layer_calls": 0, "small_rows_layer_calls": 0,
-                         "expert_tokens": np.zeros(cfg.n_experts, np.int64)}
+                         "expert_tokens": np.zeros(cfg.experts_here,
+                                                   np.int64)}
+            if cfg.experts_held:
+                self._moe["assignments_elsewhere"] = 0
         # What moe_ffn asked when a program of so many tokens was traced.
         self._small_rows = lambda tokens: grouped_path(
-            tokens * cfg.top_k, cfg.n_experts) == "small_rows"
+            tokens * cfg.top_k, cfg.experts_here) == "small_rows"
         # Donate the cache: the paged pool updates IN PLACE instead of
         # being copied every step (a pool-sized copy per step would make
         # paging cost scale with pool size). Jit through the instrumented
@@ -622,6 +628,10 @@ class _Runner:
         prefill's where ``stats()`` tells them apart."""
         moe = self._moe
         if moe is not None:
+            if "assignments_elsewhere" in moe:
+                out, elsewhere = out[:-1], int(out[-1])
+                with self._lock:
+                    moe["assignments_elsewhere"] += elsewhere
             expert_tokens = out[n:-1]
             small_rows = self._small_rows(bucket or self._max_batch)
             with self._lock:
@@ -718,15 +728,17 @@ class LLMEngine:
         ``pages`` (``{kind: {"layers", "total", "free"}}``, every pool),
         ``kv_row_bytes`` (``{kind: bytes}``: what a token holds in one
         layer of that pool, the pool's bytes over its tokens and layers:
-        k and v of every KV head, or a latent pool's one row, padding
-        and all; a pool without pages has no entry),
+        k and v of every KV head, or a latent pool's one row, or under
+        "index" an indexer's one key, padding and all; a pool without
+        pages has no entry),
         ``state_slot_bytes`` (``{kind: bytes}``: what a slot holds in one
         layer of a pool that has no pages, a retention layer's state and
         normaliser, padding and all; empty for a model that has none),
         ``queued`` (submitted, not yet admitted), beside the constants
         ``platform``, ``device_kind``, ``total_pages``, ``page_size`` and
         ``decode_attention`` (``"page_walk"``, for a latent pool
-        ``"latent_walk"``, or ``"gather"``: the path of
+        ``"latent_walk"``, under a selection ``"sparse_walk"``, or
+        ``"gather"``: the path of
         ops/paged_attention.py the decode program was built with; for a
         model of retention layers ops/retention.py's ``"state_kernel"``
         or ``"xla"``).
@@ -740,7 +752,10 @@ class LLMEngine:
         tokens, on a window layer at most the window; without window
         layers ``decode_kv_tokens`` times the layers; a latent layer's
         rows are one a token, whatever the heads; a retention layer
-        reads none); ``decode_state_slot_layers`` (the states the steps
+        reads none); ``decode_kv_rows_selected`` (of those rows, the ones
+        the attention took into its softmax: all of them, but in a layer
+        that attends over a selection the ``index_topk`` it keeps at
+        most); ``decode_state_slot_layers`` (the states the steps
         read and wrote: sequences times retention layers, summed over
         decode steps; times ``state_slot_bytes``, the bytes of state a
         step moved each way);
@@ -778,6 +793,9 @@ class LLMEngine:
         of E: (token, expert) assignments each expert was given, prefills
         and decode steps, summed over layers), ``assignments`` (their
         sum) and ``decode_assignments`` (the decode steps' part of it);
+        for a model that holds a share of its experts E is the held
+        ones, and ``assignments_elsewhere`` the assignments the router
+        gave to experts on other chips, which nothing here computed;
         ``experts_reached`` ((layer, expert) pairs that a decode
         step gave at least one token, summed over decode steps) over
         ``layer_steps`` (decode steps x layers that have experts) is the

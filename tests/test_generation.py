@@ -527,6 +527,69 @@ def test_latent_cache_is_one_pool_of_rows_and_training_raises_by_name():
         layer_runs(_latent_cfg(layer_types=("full", "full")))
 
 
+def _selecting_cfg():
+    """Latent attention under a selection: an indexing layer, a layer
+    that shares its selection, an indexing layer."""
+    import dataclasses
+
+    return dataclasses.replace(
+        _latent_cfg(), num_layers=3, index_topk=24, index_n_heads=2,
+        index_head_dim=16, indexer_types=("full", "shared", "full"))
+
+
+def test_the_pool_of_indexer_keys_rides_on_the_latent_pools_page_table():
+    """A second pool, of the indexing layers' keys, beside the latent
+    rows: as many pages at the same ids, no table and no free list of
+    its own. One reservation a slot covers both; released pages are the
+    next slot's in both; a slot reused finds the table row zeroed and
+    re-laid; what a page weighs counts both pools' layers."""
+    from ray_tpu.models.generation import KVBooks
+    from ray_tpu.models.llama import index_offsets, kv_layers, layer_runs
+
+    cfg = _selecting_cfg()
+    assert [tuple(r) for r in layer_runs(cfg)] == [
+        (0, 1, False, "latent_index", 0), (1, 1, False, "latent_shared", 1),
+        (2, 1, False, "latent_index", 2)]
+    assert index_offsets(cfg) == (0, 1, 1)
+    assert kv_layers(cfg) == {"latent": 3, "index": 2}
+    geometry = (cfg, 3, 12, 16, 8)
+    assert PagedKVCache.sizes(*geometry) == {"latent": (3, 12, 8),
+                                             "index": (2, 12, 0)}
+    cache = PagedKVCache.create(*geometry)
+    assert {k: v.shape for k, v in cache.k.items()} == {
+        "latent": (3, 12, 16, 160), "index": (2, 12, 16, 16)}
+    assert cache.v == {} and cache.page_size == 16
+    assert set(cache.page_table) == {"latent"}
+    assert cache.pools("index") == (cache.k["index"],)
+    books = KVBooks(*geometry, cache)
+    assert set(books.free) == set(books.tables) == {"latent"}
+    reading = books.reading()
+    assert reading["kv_row_bytes"] == {"latent": 160 * 4, "index": 16 * 4}
+    assert reading["pages"] == {
+        "latent": {"layers": 3, "total": 12, "free": 12},
+        "index": {"layers": 2, "total": 12, "free": 12}}
+    pages, tables = books.reserve(0, 40, 32)            # 3 pages
+    assert set(pages) == set(tables) == {"latent"} and len(pages["latent"]) == 2
+    first = tables["latent"][0].copy()
+    assert np.count_nonzero(first) >= 2 and books.reserve(1, 64, 64)
+    assert books.reading()["pages"]["index"]["free"] == 12 - 3 - 4
+    # The third slot's 6 pages are not there: nothing is taken.
+    assert books.reserve(2, 96, 64) is None
+    assert books.reading()["pages"]["latent"]["free"] == 5
+    books.account([0, 1], [40, 60])
+    counts = books.counts
+    assert counts["decode_kv_rows_read"] == 3 * 100
+    assert counts["decode_kv_rows_selected"] == 3 * (24 + 24)
+    assert counts["kv_page_steps_held"] == (3 + 2) * (3 + 4)
+    books.release(0)
+    assert not books.tables["latent"][0].any()
+    assert books.reading()["pages"]["index"]["free"] == 8
+    pages, tables = books.reserve(0, 96, 64)            # the slot reused
+    assert len(pages["latent"]) == 4
+    assert set(first[:3]) <= set(tables["latent"][0][:6].tolist())
+    assert books.reading()["pages"]["latent"]["free"] == 2
+
+
 # ---- retention layers: a state a slot, no pages -----------------------------
 
 

@@ -255,3 +255,106 @@ def test_the_default_routing_is_the_program_it_was():
                        scale=1.0)
 
     assert str(jax.make_jaxpr(plain)(x)) == str(jax.make_jaxpr(named)(x))
+
+
+# ---- a chip's share of the experts (PR 54) ----------------------------------
+
+def _routing(E):
+    rng = np.random.RandomState(E)
+    return dict(score="sigmoid", renormalize=True, scale=2.5,
+                select_bias=jnp.asarray(rng.randn(E) * 0.3, jnp.float32))
+
+
+@pytest.mark.parametrize("E,k,T,chips", [(8, 2, 9, 2), (16, 4, 7, 4),
+                                         (64, 8, 6, 16)],
+                         ids=["2-chips", "4-chips", "16-chips-of-4"])
+def test_the_shares_routed_parts_add_up_to_the_uncut_layer(E, k, T, chips):
+    """``held=(first, E / n)`` on each of the n chips that share a
+    layer, each with its own slice of the expert stacks and the router
+    whole: the parts add up to what one chip holding every expert gives
+    (so the shared expert, added once by the caller, is counted once),
+    each chip's loads are its slice of the uncut layer's, and what it
+    counts as gone elsewhere is everything it did not take. Rows of a
+    mask reach no chip."""
+    router, w_up, w_gate, w_down = _weights(E, 3)
+    x = _tokens(T, 4)
+    mask = jnp.asarray(np.arange(T) != 2)[None]
+    routing = _routing(E)
+    whole, _, load = moe_ffn(x, router, w_up, w_down, k=k, w_gate=w_gate,
+                             token_mask=mask, **routing)
+    count = E // chips
+    total = jnp.zeros_like(whole)
+    for chip in range(chips):
+        here = slice(chip * count, (chip + 1) * count)
+        part, _, part_load = moe_ffn(
+            x, router, w_up[here], w_down[here], k=k, w_gate=w_gate[here],
+            token_mask=mask, held=(chip * count, count), **routing)
+        assert part_load.shape == (count + 1,)
+        assert list(np.asarray(part_load[:count])) == list(
+            np.asarray(load[here]))
+        assert int(part_load[count]) == k * (T - 1) - int(load[here].sum())
+        assert not np.asarray(part)[0, 2].any()
+        total = total + part
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               atol=2e-6, rtol=2e-6)
+    # Read in place from all layers' stacks, as the serving programs do.
+    stacked = [jnp.stack([jnp.zeros_like(w), w])[:, :count]
+               for w in (w_up, w_down, w_gate)]
+    part, _, _ = moe_ffn(x, router, stacked[0], stacked[1], k=k,
+                         w_gate=stacked[2], token_mask=mask,
+                         layer=jnp.int32(1), held=(0, count), **routing)
+    first, _, _ = moe_ffn(x, router, w_up[:count], w_down[:count], k=k,
+                          w_gate=w_gate[:count], token_mask=mask,
+                          held=(0, count), **routing)
+    np.testing.assert_array_equal(np.asarray(part), np.asarray(first))
+
+
+def test_a_share_renormalises_over_every_expert_not_over_its_own():
+    """The gates a chip applies are the uncut router's: a chip whose
+    router knew its own experts alone would renormalise over them and
+    give another layer."""
+    E, k, T = 8, 2, 9
+    router, w_up, w_gate, w_down = _weights(E, 3)
+    x = _tokens(T, 4)
+    routing = _routing(E)
+    part, _, _ = moe_ffn(x, router, w_up[:4], w_down[:4], k=k,
+                         w_gate=w_gate[:4], held=(0, 4), **routing)
+    own = dict(routing, select_bias=routing["select_bias"][:4])
+    alone, _, _ = moe_ffn(x, router[:, :4], w_up[:4], w_down[:4], k=k,
+                          w_gate=w_gate[:4], **own)
+    assert np.abs(np.asarray(part) - np.asarray(alone)).max() > 0.05
+
+
+@pytest.mark.parametrize("name,want", [
+    ("olmoe_tiny", "dbead347c9b4d8d6"), ("trinity_tiny", "a2b41b71ea20d1e8"),
+    ("joyai_tiny", "35fe74be03783b18")])
+def test_without_held_the_layer_is_bit_for_bit_what_it_was(name, want):
+    """Digests taken at the parent commit (PR 53) of ``moe_ffn``'s three
+    results on the second expert layer of each sparse fixture, read in
+    place from the stack, under a mask, with the fixture's own routing."""
+    import hashlib
+    import json
+    import os
+
+    from benchmark import arch
+    from ray_tpu.models import init_params
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "bench_harness", name, "config.json")) as f:
+        cfg = arch.program_config(json.load(f))
+    layers = init_params(cfg, jax.random.PRNGKey(7))["layers"]
+    stack = next(s for s in (layers if isinstance(layers, tuple)
+                             else (layers,)) if "router" in s)
+    x = jnp.asarray(np.random.RandomState(5).randn(2, 9, cfg.hidden_size),
+                    cfg.dtype)
+    mask = jnp.asarray(np.random.RandomState(6).rand(2, 9) < 0.8)
+    results = jax.jit(lambda x, stack, mask: moe_ffn(
+        x, stack["router"][1], stack["w_up"], stack["w_down"], k=cfg.top_k,
+        w_gate=stack["w_gate"], token_mask=mask, layer=jnp.int32(1),
+        score=cfg.router_score,
+        select_bias=stack.get("expert_bias", [None, None])[1],
+        renormalize=cfg.route_norm, scale=cfg.route_scale))(x, stack, mask)
+    digest = hashlib.sha256()
+    for result in results:
+        digest.update(np.asarray(result).tobytes())
+    assert digest.hexdigest()[:16] == want

@@ -795,6 +795,78 @@ def test_latent_engine_serves_within_tolerance_of_the_reference(latent_model):
         assert row[len(prompt) - 1:len(prompt) + 59].max() <= 1e-4
 
 
+@pytest.fixture(scope="module")
+def selecting_model():
+    """Latent attention under a learned selection of 24 positions, 4 of
+    8 experts held: the benchmark's tiny GLM-5.2 share
+    (tests/bench_harness/glm52_tiny)."""
+    import json
+    import os
+
+    from benchmark import arch
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "bench_harness", "glm52_tiny",
+                           "config.json")) as f:
+        config = json.load(f)
+    cfg = arch.program_config(config)
+    return config, cfg, init_params(cfg, jax.random.PRNGKey(3))
+
+
+def test_selecting_engine_serves_within_tolerance_of_the_reference(
+        selecting_model):
+    """Through the engine, four streams at once at different lengths, 60
+    tokens each, contexts on both sides of ``index_topk`` 24: every
+    served token's logit lies within 1e-4 of the plain reference's best
+    at its position. And the engine's account of it: two pools on one
+    table, the rows the selection kept beside the rows held, the
+    assignments that fell on the other chip's experts."""
+    import jax.numpy as jnp
+
+    from benchmark import arch
+
+    config, cfg, params = selecting_model
+    reference = arch.reference(config)
+    engine = LLMEngine(cfg, params, max_batch=4, max_len=256, page_size=16,
+                       total_pages=48)
+    try:
+        before = engine.stats()
+        assert before["pages"] == {
+            "latent": {"layers": 4, "total": 48, "free": 48},
+            "index": {"layers": 2, "total": 48, "free": 48}}
+        assert before["kv_row_bytes"] == {"latent": 160 * 4, "index": 16 * 4}
+        assert before["decode_attention"] == "gather"          # on the CPU
+        rng = np.random.RandomState(0)
+        prompts = [list(rng.randint(0, 256, n)) for n in (10, 25, 40, 100)]
+        reqs = [engine.submit(p, 60) for p in prompts]
+        outs = [r.result(timeout=300) for r in reqs]
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    seqs = np.zeros((4, max(len(p) for p in prompts) + 60), np.int32)
+    for row, prompt, out in zip(seqs, prompts, outs):
+        row[:len(prompt) + 60] = prompt + out
+    margins = np.asarray(jax.jit(
+        lambda params, seqs: reference.logit_margins(params, seqs, config))(
+            params, jnp.asarray(seqs)))
+    for row, prompt in zip(margins, prompts):
+        assert row[len(prompt) - 1:len(prompt) + 59].max() <= 1e-4
+    assert stats["pages"]["index"]["free"] == 48
+    assert stats["decode_kv_rows_read"] == 4 * stats["decode_kv_tokens"]
+    # A step at context c takes min(c, 24) rows in each of 4 layers.
+    assert 0 < stats["decode_kv_rows_selected"] < stats["decode_kv_rows_read"]
+    assert stats["decode_kv_rows_selected"] <= 4 * 24 * stats[
+        "decode_slot_steps"]
+    moe = stats["moe"]
+    assert len(moe["expert_tokens"]) == 4
+    assert moe["assignments"] == sum(moe["expert_tokens"])
+    # Three expert layers, two experts a token, every token of every
+    # prompt and every decode step: what was not held went elsewhere.
+    tokens = sum(map(len, prompts)) + stats["decode_slot_steps"]
+    assert moe["assignments"] + moe["assignments_elsewhere"] == 3 * 2 * tokens
+    assert 0.3 < moe["assignments"] / (3 * 2 * tokens) < 0.7
+
+
 def test_latent_pool_pages_are_held_from_admission_to_finish(latent_model):
     """A 100-token context (60 + 40) holds 7 pages of the one pool, of
     kind "latent", from admission to its end; they return on finish.
@@ -937,7 +1009,8 @@ def test_a_paged_engine_has_no_state_gauge_and_counts_no_states(tiny_model):
 # Written from the output of the engine as one class (PR 51's tree).
 _STATS_KEYS = [
     "active_slots", "admitted", "cache_resets", "decode_attention",
-    "decode_kv_rows_read", "decode_kv_tokens", "decode_slot_steps",
+    "decode_kv_rows_read", "decode_kv_rows_selected", "decode_kv_tokens",
+    "decode_slot_steps",
     "decode_slot_steps_discarded", "decode_state_slot_layers",
     "decode_steps", "decode_steps_ahead", "device_kind", "failed",
     "finished", "free_pages", "free_slots", "kv_page_steps_held",

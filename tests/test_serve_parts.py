@@ -288,6 +288,9 @@ def test_books_count_what_a_step_read_and_held(books):
     assert got == {
         "decode_kv_tokens": sum(contexts),
         "decode_kv_rows_read": rows,
+        # No layer of these models attends over a selection: what it
+        # read, it took.
+        "decode_kv_rows_selected": rows,
         "decode_state_slot_layers": 2 * states,
         "kv_page_steps_held": sum(
             n * min(p, columns) for p in pages.values()

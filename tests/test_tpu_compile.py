@@ -147,14 +147,16 @@ _HLO_INSTRUCTION = re.compile(
     re.MULTILINE)
 
 
-def _assert_pool_stays_in_place(compiled, pool_shape):
+def _assert_pool_stays_in_place(compiled, pool_shape, temporaries=True):
     """The guard against pool-sized copies in a decode step (ROADMAP
     S5; 70% of the step before PR 29): the program's temporaries are
-    under one layer's slice of one pool, and no instruction of the
+    under one layer's slice of one pool (``temporaries``: asked of a
+    model's largest pool), and no instruction of the
     optimized HLO but the pool's carriers and the kernel call has a
     result of the pool's or a layer slice's shape."""
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 2 * math.prod(pool_shape[1:]), f"{temp} B of temporaries"
+    assert not temporaries or temp < 2 * math.prod(pool_shape[1:]), \
+        f"{temp} B of temporaries"
     shapes = ["bf16[%s]" % ",".join(map(str, dims))
               for dims in (pool_shape, pool_shape[1:], (1, *pool_shape[1:]))]
     offenders = []
@@ -637,6 +639,90 @@ def test_joyai_weights_are_made_within_one_chip(v5e):
     drawn a layer at a time, so the float32 temporaries beside 11.1 GB
     of weights stay under the chip's 16 GB."""
     cfg, _ = _joyai()
+    compiled = jax.jit(lambda key: init_params(cfg, key)).lower(
+        _arr(v5e, (2,), jnp.uint32)).compile()
+    assert _fits_one_chip(compiled)
+
+
+def _glm52():
+    """``glm-5.2-L5-ep16`` as the benchmark builds it, and its engine."""
+    import json
+
+    from benchmark import arch
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "glm-5.2-L5-ep16.json")) as f:
+        config = json.load(f)
+    return arch.program_config(config), config["engine"]
+
+
+def _glm52_shapes(v5e):
+    cfg, engine = _glm52()
+    params, cache = _serve_shapes(
+        cfg, v5e, engine["max_batch"], engine["total_pages"],
+        engine["max_len"] // PAGE)
+    return cfg, engine, params, cache
+
+
+GLM_POOLS = {"latent": (5, 8192, PAGE, 640), "index": (2, 8192, PAGE, 128)}
+
+
+def test_glm52_decode_program_compiles_for_v5e(v5e, as_tpu):
+    """Three scans (dense+indexing, three expert layers that share its
+    selection, an expert layer that indexes) over the latent pool and
+    the pool of indexer keys on the same page table: the index walk and
+    the walk under a selection are custom calls, both pools stay in
+    place, and 16 of 256 experts a layer are read in place."""
+    cfg, engine, params, cache = _glm52_shapes(v5e)
+    assert {k: v.shape for k, v in cache.k.items()} == GLM_POOLS
+    assert cache.v == {} and set(cache.page_table) == {"latent"}
+    batch = engine["max_batch"]
+
+    def decode(params, cache, tok, active):
+        return generation.paged_decode(params, tok, cache, cfg, active=active)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (batch,), jnp.int32),
+        _arr(v5e, (batch,), jnp.bool_)).compile()
+    assert _fits_one_chip(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
+    for kind, pool in GLM_POOLS.items():
+        _assert_pool_stays_in_place(compiled, pool, kind == "latent")
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * sum(map(math.prod, GLM_POOLS.values()))
+
+
+@pytest.mark.parametrize("bucket", [2048, 8192, 16384])
+def test_glm52_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket):
+    """A bucket that selects everything (the causal flash kernel, as
+    JoyAI's) and the cell's two: the selection as int8 tiles, never a
+    float32 [bucket, bucket], beside 7.76 GB of weights."""
+    cfg, engine, params, cache = _glm52_shapes(v5e)
+
+    def prefill(params, cache, tokens, real_len, slot, pages):
+        return generation.paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages)
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (1, bucket), jnp.int32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
+        {"latent": _arr(v5e, (bucket // PAGE,), jnp.int32)},
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    selects = bucket > cfg.index_topk
+    assert not selects or f"f32[{bucket},{bucket}]" not in text
+    assert f"f32[64,{bucket},{bucket}]" not in text
+    assert (f"s8[{bucket // 128},{bucket // 512},128,512]" in text) \
+        == selects
+    assert _fits_one_chip(compiled)
+    memory = compiled.memory_analysis()
+    print(bucket, memory.temp_size_in_bytes / 2**30, "GiB of temporaries")
+
+
+def test_glm52_weights_are_made_within_one_chip(v5e):
+    cfg, _ = _glm52()
     compiled = jax.jit(lambda key: init_params(cfg, key)).lower(
         _arr(v5e, (2,), jnp.uint32)).compile()
     assert _fits_one_chip(compiled)
