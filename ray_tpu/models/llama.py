@@ -1025,7 +1025,18 @@ def _chunked_nll_sum(x: jax.Array, lm_head: jax.Array,
     projection + log-sum-exp over S/chunk slices so no [B, S, V] tensor
     ever materializes (the memory cliff behind the batch-16 collapse:
     the monolithic loss kept logits + log-softmax residuals, ~8.6 GB at
-    B=16). Each chunk is rematerialized in the backward."""
+    B=16). Each chunk is rematerialized in the backward.
+
+    ``lm_head`` is the same array in every iteration of both scans (this
+    one, and the backward one that re-runs each chunk), so it comes here
+    as every iteration needs it: whole along ``embed``
+    (causal_lm_loss gathers it over ``fsdp`` once, outside). A head that
+    came in sharded over ``fsdp`` would be carried into both ``while``
+    bodies as it lies, gathered there once a chunk, and its gradient
+    reduce-scattered once a chunk into an accumulator of the shard's
+    shape. Whole, the gradient's accumulator has the gathered layout
+    too: each chip adds its own batch rows' dW through the backward
+    scan, and the sum over ``fsdp`` is taken once, after it."""
     B, S, M = x.shape
     pad = (-S) % chunk
     if pad:
@@ -1071,7 +1082,13 @@ def causal_lm_loss(
     chunk = cfg.loss_chunk
     if chunk and chunk > 0 and targets.shape[1] > chunk:
         x, aux = hidden_forward(params, tokens[:, :-1], cfg, mesh)
-        total = _chunked_nll_sum(x, params["lm_head"], targets, chunk)
+        # Once a step, not once a loss chunk (_chunked_nll_sum): where
+        # the rules shard `embed` (fsdp > 1) this is the head's one
+        # all-gather and its gradient's one reduction; where they do not
+        # (no mesh, fsdp = 1) the head lies so already.
+        head = with_logical_constraint(
+            params["lm_head"], (None, "vocab"), mesh=mesh)
+        total = _chunked_nll_sum(x, head, targets, chunk)
         return total / targets.size + aux_weight * aux
     logits, aux = forward(params, tokens[:, :-1], cfg, mesh)
     logp = jax.nn.log_softmax(logits, axis=-1)

@@ -10,7 +10,10 @@ module is the single train-step authority (ROADMAP item 3):
 - **One XLA program** per step: forward (chunked-scan schedule,
   models/llama.py), backward, optimizer update and — under a mesh — the
   GSPMD-inserted grad all-reduces, compiled together via pjit (jax.jit
-  with shardings) so XLA schedules collectives against compute.
+  with shardings) so XLA schedules collectives against compute. The
+  chunked loss's head is the exception, placed by hand: left to GSPMD it
+  is gathered and its gradient reduced once a loss chunk, so
+  models/llama.py:causal_lm_loss asks for it whole before the scans.
 - **In-place buffer donation**: params + optimizer state donate their
   buffers into the step (``donate_argnums=(0, 1)``) — the update aliases
   the old arena instead of doubling it.

@@ -417,18 +417,21 @@ def test_olmoe_prefill_buckets_choose_their_grouped_matmul(v5e, as_tpu,
     assert ("ragged-dot" in text) is not kernel
 
 
-def _dense_programs(v5e, mesh):
-    """The texts of a dense model's train step under ``mesh`` (the Nemo
-    cell's trainer settings at tiny widths, b4 x 512) and of its decode
-    step on one device, each traced anew."""
-    from ray_tpu.train.compiled_step import CompiledTrainStep
-
-    cfg = LlamaConfig(
-        vocab_size=512, hidden_size=256, intermediate_size=512,
+def _dense_cfg(vocab_size=512):
+    """The Nemo cell's trainer settings at tiny widths."""
+    return LlamaConfig(
+        vocab_size=vocab_size, hidden_size=256, intermediate_size=512,
         num_layers=4, num_heads=2, num_kv_heads=2, head_dim=128,
         dtype=jnp.bfloat16, remat_policy="dots", scan_layers=True,
         scan_chunk=2, loss_chunk=256,
     )
+
+
+def _dense_train_step(cfg, mesh):
+    """The text of ``cfg``'s train step under ``mesh``, b4 x 512 tokens,
+    traced anew."""
+    from ray_tpu.train.compiled_step import CompiledTrainStep
+
     step = CompiledTrainStep(cfg, mesh=mesh, learning_rate=1e-5)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
     with jax.threefry_partitionable(True):
@@ -440,10 +443,17 @@ def _dense_programs(v5e, mesh):
     tokens = jax.ShapeDtypeStruct((4, 513), jnp.int32,
                                   sharding=step.token_sharding())
     train = step._step.__wrapped_jit__.lower(params, opt_state, tokens)
+    return train.compile().as_text()
+
+
+def _dense_programs(v5e, mesh):
+    """The texts of a dense model's train step under ``mesh`` and of its
+    decode step on one device, each traced anew."""
+    cfg = _dense_cfg()
     decode, _ = _decode_program(
         dataclasses.replace(cfg, remat_policy="none", scan_chunk=0), v5e,
         B, POOL_PAGES, PAGES_PER_SEQ)
-    return train.compile().as_text(), decode.as_text()
+    return _dense_train_step(cfg, mesh), decode.as_text()
 
 
 def test_programs_without_experts_are_the_same_either_way(
@@ -471,6 +481,68 @@ def test_programs_without_experts_are_the_same_either_way(
     assert "all-reduce" in train or "all-gather" in train
     assert "tpu_custom_call" in train             # the flash kernels
     assert "ragged" not in train + decode
+
+
+_COLLECTIVE = re.compile(
+    r" = \(?\w+\[([\d,]+)\]\S* "
+    r"(all-gather|all-reduce|reduce-scatter|fusion)(?:-start)?\(")
+
+
+def _head_collectives(text, shapes):
+    """``(kind, in_a_while_body)`` of every collective in a compiled
+    step's text whose result has one of ``shapes``: an all-gather, an
+    all-reduce, a reduce-scatter, or a fusion that wraps one (XLA:TPU's
+    ``all-reduce-scatter``, which carries no collective's name)."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", text))  # counted at the call
+    found, computation = [], None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            computation = line.split()[1 if line.startswith("ENTRY") else 0]
+            computation = computation.lstrip("%")
+            continue
+        m = _COLLECTIVE.search(line)
+        if not m or m.group(1) not in shapes or computation in fused:
+            continue
+        kind = m.group(2)
+        if kind == "fusion":
+            wrapped = re.search(
+                r"calls=%?[\w.\-]*(reduce-scatter|all-reduce|all-gather)",
+                line)
+            if not wrapped:
+                continue
+            kind = wrapped.group(1)
+        found.append((kind, computation in bodies))
+    return found
+
+
+def test_the_head_is_gathered_once_a_step_not_once_a_loss_chunk(
+        v5e_host, as_tpu):
+    """``train-nemo12b-4chip``'s loss at tiny widths (vocabulary 4096, so
+    that no other operand has the head's shapes; two loss chunks): under
+    ``fsdp=2 x tp=2`` ``lm_head`` ``[256, 4096]`` lies ``[128, 2048]`` a
+    chip and is ``[256, 2048]`` once gathered over ``fsdp``. No collective
+    with either shape stands inside a ``while`` body, and the whole step
+    has at most two such gathers and exactly one such reduction, the
+    gradient's over ``fsdp`` after the backward scan.
+
+    Fails on the tree before PR 55 (an all-gather in each loss scan's
+    body and an ``all-reduce-scatter`` fusion in the backward one's: the
+    head gathered and its gradient reduced once a chunk) and passes
+    since (models/llama.py:causal_lm_loss)."""
+    from ray_tpu.parallel import make_mesh
+
+    mesh = make_mesh(devices=v5e_host, dp=1, fsdp=2, tp=2)
+    cfg = _dense_cfg(vocab_size=4096)
+    M, V = cfg.hidden_size, cfg.vocab_size
+    text = _dense_train_step(cfg, mesh)
+    assert len(re.findall(r"body=", text)) >= 3   # layers and both losses
+    found = _head_collectives(text, {f"{M},{V // 2}", f"{M // 2},{V // 2}"})
+    assert found
+    assert not [kind for kind, in_body in found if in_body], found
+    kinds = [kind for kind, _ in found]
+    assert 1 <= kinds.count("all-gather") <= 2, found
+    assert len(kinds) - kinds.count("all-gather") == 1, found
 
 
 def _trinity():

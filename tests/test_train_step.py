@@ -222,6 +222,77 @@ def test_compiled_step_sharded_matches_single_device():
             == sp["layers"]["wq"].sharding)
 
 
+# The chunked loss under a mesh: lm_head is asked for whole along `embed`
+# once, before the loss's scans (models/llama.py:causal_lm_loss).
+_LOSS_MESHES = {
+    "fsdp2-tp2": {"dp": 1, "fsdp": 2, "tp": 2},
+    "dp2-fsdp2-tp2": {"dp": 2, "fsdp": 2, "tp": 2},
+    "tp2": {"dp": 1, "fsdp": 1, "tp": 2},
+    "no-mesh": None,
+}
+
+
+@pytest.mark.parametrize("seqlen", [33, 29], ids=["whole-chunks", "padded-chunk"])
+@pytest.mark.parametrize("axes", list(_LOSS_MESHES.values()),
+                         ids=list(_LOSS_MESHES))
+def test_chunked_loss_under_a_mesh_matches_the_plain_loss(axes, seqlen):
+    """``_chunked_nll_sum`` ENGAGED under a mesh (32 or 28 targets over a
+    ``loss_chunk`` of 8; the second pads its last chunk): the loss and
+    ``lm_head``'s gradient, in float32, are those of the unchunked loss
+    on one device, whether the head is gathered over ``fsdp`` first
+    (``fsdp=2``), lies whole along ``embed`` already (``tp`` alone) or
+    there is no mesh."""
+    from ray_tpu.models.llama import param_logical_axes
+    from ray_tpu.parallel import make_mesh
+    from ray_tpu.parallel.sharding import named_sharding, shard_pytree
+
+    if axes and len(jax.devices()) < axes["dp"] * axes["fsdp"] * axes["tp"]:
+        pytest.skip("needs 8 virtual devices")
+    cfg = _tiny(depth=2, scan_layers=True, loss_chunk=8)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.asarray(
+        np.random.RandomState(5).randint(0, 256, (4, seqlen)))
+    ref_loss, ref_grads = _loss_and_grads(
+        dataclasses.replace(cfg, loss_chunk=0), params, tokens)
+
+    mesh = make_mesh(**axes) if axes else None
+    if mesh is not None:
+        params = shard_pytree(params, mesh, param_logical_axes(cfg))
+        tokens = jax.device_put(
+            tokens, named_sharding(mesh, ("batch", "seq")))
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: causal_lm_loss(p, tokens, cfg, mesh)))(params)
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-6)
+    assert grads["lm_head"].dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(grads["lm_head"]), np.asarray(ref_grads["lm_head"]),
+        rtol=2e-5, atol=1e-6)
+
+
+def test_chunked_loss_asks_for_nothing_where_the_head_lies_whole():
+    """Without a mesh the chunked loss traces no sharding constraint (the
+    one-chip step is the program it was before the head was placed by
+    hand), and under ``tp`` alone the layout asked for is the one
+    ``lm_head`` already has."""
+    from ray_tpu.parallel import make_mesh
+    from ray_tpu.parallel.sharding import named_sharding
+
+    cfg = _tiny(depth=2, scan_layers=True, loss_chunk=8)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((4, 33), jnp.int32)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda p, t: causal_lm_loss(p, t, cfg)))(params, tokens))
+    assert "while" in jaxpr or "scan" in jaxpr      # the chunked branch
+    assert "sharding_constraint" not in jaxpr
+    if len(jax.devices()) >= 4:
+        tp = make_mesh(dp=1, fsdp=1, tp=2)
+        assert (named_sharding(tp, (None, "vocab"))
+                == named_sharding(tp, ("embed", "vocab")))
+        sharded = make_mesh(dp=1, fsdp=2, tp=2)
+        assert (named_sharding(sharded, (None, "vocab"))
+                != named_sharding(sharded, ("embed", "vocab")))
+
+
 # ------------------------------------------------------- HBM probe
 
 def test_fragmentation_from_stats_preference_order():
