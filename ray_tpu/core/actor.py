@@ -70,7 +70,8 @@ class ActorMethod:
             from .streaming import ObjectRefGenerator
 
             return ObjectRefGenerator(
-                spec.task_id, refs[0], retriable=spec.max_retries > 0
+                spec.task_id, refs[0], retriable=spec.max_retries > 0,
+                stream=rt.take_direct_stream(spec.task_id),
             )
         return refs[0] if num_returns == 1 else refs
 

@@ -3628,8 +3628,22 @@ class NodeManager:
         (see worker_main._note_direct_done)."""
         items = msg.get("items", ())
         self._stats["direct_done_batches"] += 1
-        self._stats["direct_calls_done"] += len(items)
         for item in items:
+            sealed = item.get("stream_item")
+            if sealed is not None:
+                # An item of a stream that went to its consumer on the
+                # direct channel (core/streaming.py): the entry a third
+                # party resolves, with the item's one pin (on the
+                # consumer's placeholder if that came first, a release
+                # that overtook this batch already taken off it), or
+                # the hold for a consumer on another node.
+                oid, loc = sealed
+                await self.put_object(oid, loc, item["refs"],
+                                      nested=item.get("nested"))
+                if item.get("held"):
+                    self.directory.add_ref(oid)
+                continue
+            self._stats["direct_calls_done"] += 1
             deltas = item.get("ref_deltas")
             if deltas:
                 await self._apply_ref_deltas(deltas)
@@ -5527,8 +5541,10 @@ class NodeManager:
         """Worker/client-side get_actor_direct request: long-polls the
         drain window off the message loop and replies when resolved."""
         try:
+            timeout = msg.get("timeout")
             desc = await self.get_actor_direct(
-                msg["actor_id"], timeout=float(msg.get("timeout") or 30.0)
+                msg["actor_id"],
+                timeout=30.0 if timeout is None else float(timeout),
             )
         except Exception:
             desc = None

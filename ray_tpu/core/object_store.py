@@ -665,8 +665,14 @@ class ObjectDirectory:
         with self._lock:
             expired = []
             for oid, t in self._zero_since.items():
-                if (now - t >= grace_s
-                        and self._refcounts.get(oid, 0) <= 0
+                count = self._refcounts.get(oid, 0)
+                # An entry BELOW zero is owed an add that is on its way
+                # on another socket (a streamed item's release overtook
+                # its producer's batched seal and pin, which would find
+                # no entry and pin a new one for ever): it is given
+                # twenty times as long.
+                if (now - t >= (grace_s if count == 0 else 20 * grace_s)
+                        and count <= 0
                         and oid not in self._borrowers):
                     expired.append(oid)
                     if len(expired) >= limit:

@@ -26,6 +26,7 @@ from .protocol import (DIRECT_BACKPRESSURE_WAIT_S, DIRECT_MAX_UNANSWERED,
 from . import frame_pump
 from .reference import ObjectRef, ref_without_registration
 from .serialization import serialize, serialize_with_refs
+from .streaming import DirectStream, release_direct_item
 from .task_spec import RefArg, TaskSpec, TaskType, ValueArg
 
 
@@ -88,6 +89,8 @@ class RefCountTable:
     def __init__(self, flush_fn, on_zero=None):
         self._local: Dict[ObjectID, int] = {}
         self._deltas: Dict[ObjectID, int] = {}
+        # Ids whose pending delta is above zero (``flush_adds``).
+        self._adds = 0
         # Re-entrant: the collector can run between two bytecodes of a
         # locked region, and an ObjectRef's __del__ then calls ``decr``
         # on the thread that holds the lock (seen: a driver's ref
@@ -103,7 +106,10 @@ class RefCountTable:
     def incr(self, oid: ObjectID):
         with self._lock:
             self._local[oid] = self._local.get(oid, 0) + 1
-            self._deltas[oid] = self._deltas.get(oid, 0) + 1
+            delta = self._deltas.get(oid, 0) + 1
+            self._deltas[oid] = delta
+            if delta == 1:
+                self._adds += 1
 
     def decr(self, oid: ObjectID):
         zero = False
@@ -112,7 +118,10 @@ class RefCountTable:
             if self._local[oid] <= 0:
                 del self._local[oid]
                 zero = True
-            self._deltas[oid] = self._deltas.get(oid, 0) - 1
+            delta = self._deltas.get(oid, 0) - 1
+            self._deltas[oid] = delta
+            if delta == 0:
+                self._adds -= 1
         if zero and self._on_zero is not None:
             self._on_zero(oid)
 
@@ -120,6 +129,16 @@ class RefCountTable:
         deltas = self.drain()
         if deltas:
             self._flush_fn(deltas)
+
+    def flush_adds(self):
+        """Flush if some id's pending delta is an ADD: what a read has
+        to have landed first (the borrow protocol). Releases alone wait
+        for the flusher's next round, so a consumer that reads a
+        carried item and drops the one before sends no frame a read."""
+        with self._lock:
+            adds = self._adds > 0
+        if adds:
+            self.flush()
 
     def drain(self) -> Dict[ObjectID, int]:
         """Take the pending deltas WITHOUT flushing them — they ride an
@@ -130,6 +149,7 @@ class RefCountTable:
         fresh: Dict[ObjectID, int] = {}
         with self._lock:
             taken, self._deltas = self._deltas, fresh
+            self._adds = 0
         return {k: v for k, v in taken.items() if v != 0}
 
 
@@ -182,6 +202,9 @@ class BaseRuntime:
         )
         self._dirty_chans: set = set()
         self._dirty_chans_lock = threading.Lock()
+        # task id -> DirectStream of a streaming call between its submit
+        # and the making of its generator (``take_direct_stream``).
+        self._direct_streams: Dict[TaskID, Any] = {}
         # Local mirror of the fallback counter for cheap introspection
         # (rtpu metrics --actors / run_actor_bench).
         self._direct_fallbacks = 0
@@ -338,9 +361,13 @@ class BaseRuntime:
             # frame must be out first (an NM-routed read may dep-wait on
             # a buffered call's seal), and side bookkeeping (seals/unpins
             # for just-resolved replies) must reach the NM before the
-            # location lookups below.
-            self._flush_direct()
-            self._direct_flush_side(force=True)
+            # location lookups below. A read whose every location this
+            # process already holds (a streamed item's, carried) looks
+            # nothing up and sends nothing.
+            cache = self._loc_cache
+            if any(oid not in cache for oid in rest_ids):
+                self._flush_direct()
+                self._direct_flush_side(force=True)
             remaining = (None if deadline is None
                          else max(0.0, deadline - time.monotonic()))
             try:
@@ -423,8 +450,8 @@ class BaseRuntime:
         # The borrow protocol requires this process's +1 deltas to land
         # before any read resolves — including cache-hit reads, where no
         # control-plane lookup (with its own flush) happens. No-op when
-        # there are no pending deltas.
-        self.refs.flush()
+        # no add is pending.
+        self.refs.flush_adds()
         cache = self._loc_cache
         # Snapshot hits while scanning: the cache is shared across
         # threads (cap clears, stale-read invalidation), so re-reading
@@ -614,9 +641,10 @@ class BaseRuntime:
         versa; see _direct_discover)."""
         # Calls carrying retries keep the NM route: its actor-restart
         # replay resubmits them in order; a direct channel can only
-        # fail them on worker death.
-        eligible = (not spec.streaming and spec.num_returns == 1
-                    and spec.retries_left == 0)
+        # fail them on worker death. A streaming call (one return, its
+        # completion) is a call like any other here: its items come back
+        # on the channel it goes out on (core/streaming.py).
+        eligible = spec.num_returns == 1 and spec.retries_left == 0
         if eligible:
             # A call chained on a still-pending direct result must not
             # ride the same connection: the worker would execute it
@@ -637,6 +665,8 @@ class BaseRuntime:
         wait_drained = None
         spawn_discovery = False
         with st["lock"]:
+            if eligible and st["status"] == "none" and not st["nm_seq"]:
+                self._direct_first_call(spec.actor_id, st)
             if eligible and st["status"] == "ready":
                 chan = st["chan"]
                 try:
@@ -733,6 +763,29 @@ class BaseRuntime:
             # second channel to the same actor, sequences split).
             st["touched"] = time.monotonic()
             return st
+
+    def _direct_first_call(self, actor_id: ActorID, st: Dict[str, Any]):
+        """This process's first call to an actor (nothing of its own
+        went the NM route that a direct frame could overtake): ask for
+        the endpoint once, now. An actor that is alive and idle at its
+        NM answers at once and the channel is ready for this very call;
+        any other answer leaves the call to the NM route and the
+        switchover to ``_direct_discover``, as ever. Without this a
+        caller whose calls leave the actor no idle moment (a serving
+        proxy under load: every stream open on the NM route keeps the
+        NM's drain gate shut) never gets its channel. Caller holds
+        ``st["lock"]``."""
+        if threading.current_thread() is getattr(
+                getattr(self, "_nm", None), "_thread", None):
+            return  # on the NM's own loop: it cannot wait for itself
+        try:
+            desc = self._direct_resolve(actor_id, 0.0)
+            if desc:
+                st["chan"] = _DirectChannel(self, actor_id, desc)
+                st["status"] = "ready"
+        # Not now: the NM route carries the call, discovery follows it.
+        except Exception:  # rtlint: disable=swallowed-failure
+            pass
 
     def _direct_discover(self, actor_id: ActorID, st: Dict[str, Any]):
         """Background switchover: resolve the actor's direct endpoint.
@@ -834,16 +887,37 @@ class BaseRuntime:
             pend.extend(calls.values())
             calls.clear()
             pend.sort(key=lambda c: c.seq)
+        # A stream that was handed a frame is not replayed (the replay
+        # would run its generator, side effects and all, a second time,
+        # and the worker's dedup knows finished calls only): it ends
+        # with the death, what came stays readable. One that was handed
+        # nothing replays like any call and goes on over the NM route.
+        streams, chan._streams = chan._streams, {}
+
+        def stream_of(call):
+            return streams.get(call.spec.task_id.binary())
+
+        if chan.closed_by_us:
+            failed, pend = pend, []
+        else:
+            failed = [c for c in pend if getattr(stream_of(c), "received", 0)]
+            pend = [c for c in pend if c not in failed]
         try:
-            if chan.closed_by_us:
-                for call in pend:
-                    call.entry.payload = {
-                        "failed": True, "results": [],
-                        "error": "actor died (direct channel closed)",
-                    }
-                    call.entry.event.set()
-                    self._direct_waiters.mark_resolved(call.oid.binary())
-                return
+            for call in failed:
+                call.entry.payload = {
+                    "failed": True, "results": [],
+                    "error": "actor died (direct channel closed)",
+                }
+                call.entry.event.set()
+                self._direct_waiters.mark_resolved(call.oid.binary())
+                if not chan.closed_by_us:
+                    # No replay re-pins the args: release the direct pin.
+                    self._direct_on_replay(call.dep_ids)
+            for call, how in ([(c, DirectStream.DIED) for c in failed]
+                              + [(c, DirectStream.REROUTED) for c in pend]):
+                stream = stream_of(call)
+                if stream is not None:
+                    stream.end(how)
             if not pend:
                 return
             self._direct_fallbacks += len(pend)
@@ -1053,6 +1127,22 @@ class BaseRuntime:
     def _direct_on_replay(self, dep_ids: list):
         """Release the direct registration's arg pins before an NM-path
         replay re-pins them."""
+
+    def _direct_on_item(self, oid: ObjectID, frame: Dict[str, Any],
+                        remote: bool):
+        """A streamed item came on a direct channel (core/streaming.py):
+        register its id with this runtime's NM, in order before any
+        release of it or any task that takes its ref: a placeholder the
+        producer's batched seal (and pin) lands on. From a ``remote``
+        producer nothing else tells this NM of it: also the pin, the
+        seal at ``frame["loc"]`` and the refs inside it,
+        ``frame["n"]``."""
+
+    def take_direct_stream(self, task_id: TaskID):
+        """The ``DirectStream`` of the streaming call just submitted, if
+        it went out on a direct channel (once: it is the caller's
+        then)."""
+        return self._direct_streams.pop(task_id, None)
 
     def _direct_flush_side(self, force: bool = False):
         """Flush buffered NM side-bookkeeping (worker/client runtimes)."""
@@ -1264,6 +1354,10 @@ class _DirectChannel:
         # only on the reader thread (GIL-atomic; no lock round).
         self.table = frame_pump.new_pending_table()
         self._calls: Dict[bytes, _PendingCall] = {}
+        # task-id bytes -> DirectStream of each streaming call in
+        # flight: the reader hands ``stream_item`` frames to it, the
+        # call's reply (or the channel's death) ends it.
+        self._streams: Dict[bytes, Any] = {}
         # GIL-handoff probe: interpreter entries the reader made vs
         # frames received (see gil_probe()).
         self.py_entries = 0
@@ -1394,16 +1488,33 @@ class _DirectChannel:
             self._calls[tidb] = _PendingCall(
                 oid, entry, dep_ids, spec, time.monotonic(), seq
             )
+            if spec.streaming:
+                stream = DirectStream(self.remote, self.store_readable,
+                                      self._release_item)
+                self._streams[tidb] = stream
+                self.rt._direct_streams[spec.task_id] = stream
             self.table.add(tidb, seq)
             self.out_buf.append(out)
             self.calls += 1
         self.rt._direct_waiters_put(oid, entry)
-        self.rt._mark_chan_dirty(self)
         # Return-slot + arg-pin registration with the caller's NM:
         # buffered/coalesced (see the runtime's _direct_on_reg hook);
         # applied before this call's completion post and before any
         # ref-delta flush.
         self.rt._direct_on_reg(spec)
+        if spec.streaming:
+            # Its consumer waits on the stream, not in a get() that
+            # would flush: the call leaves now. A send that fails is the
+            # channel's death: the reader's failure path has the call.
+            try:
+                self.flush()
+            except Exception:
+                try:
+                    self.conn.close()
+                except Exception:  # rtlint: disable=swallowed-failure
+                    pass
+        else:
+            self.rt._mark_chan_dirty(self)
 
     def flush(self, _trailer: Optional[Dict[str, Any]] = None):
         with self._flush_lock:
@@ -1511,6 +1622,10 @@ class _DirectChannel:
         entry.payload = msg
         entry.event.set()
         self.rt._direct_waiters.mark_resolved(call.oid.binary())
+        if self._streams:
+            stream = self._streams.pop(tidb, None)
+            if stream is not None:
+                stream.end(stream.DONE)
         dur = time.monotonic() - call.t0
         ctx = getattr(call.spec, "trace_ctx", None)
         if ctx is not None and call.seq % self._span_every == 0:
@@ -1557,10 +1672,36 @@ class _DirectChannel:
             for item in msg["items"]:
                 self._on_reply(item)
             self.rt._direct_flush_side()
+        elif mtype == "stream_item":
+            self._on_stream_item(msg)
         elif mtype == "fence_ack":
             ev = self._fences.pop(msg.get("msg_id"), None)
             if ev is not None:
                 ev.set()
+
+    def _on_stream_item(self, frame):
+        """One streamed item of a call of this channel: to its stream
+        (core/streaming.py, the direct route's consumer)."""
+        if self.remote:
+            loc = frame["loc"]
+            if not isinstance(loc, InlineLocation):
+                # As a remote direct result: the bytes live in the
+                # actor node's store, under a hold its NM took for this
+                # caller.
+                from .object_store import RemoteLocation
+
+                frame["loc"] = RemoteLocation(
+                    self.node_hex, getattr(loc, "size", 0), held=True)
+        stream = self._streams.get(frame["i"])
+        if stream is not None:
+            stream.put(frame)
+        else:
+            self._release_item(frame)
+
+    def _release_item(self, frame):
+        """An item nobody will take (its consumer is gone): drop the
+        pin it came with."""
+        release_direct_item(self.rt, frame, self.remote)
 
     def _reader(self):
         from .protocol import ConnectionClosed, loads_msg
@@ -1594,10 +1735,24 @@ class _DirectChannel:
                         raise
                     self.py_entries += 1
                     self.frames_rx += len(others) + (1 if dones else 0)
+                    if others and dones:
+                        # The burst's completions come apart from its
+                        # other frames: a stream's items, which were
+                        # ahead of its completion on the socket, go to
+                        # it first.
+                        rest = []
+                        for payload in others:
+                            msg = loads_msg(payload)
+                            if msg.get("type") == "stream_item":
+                                self._on_stream_item(msg)
+                            else:
+                                rest.append(msg)
+                    else:
+                        rest = [loads_msg(payload) for payload in others]
                     for item in dones:
                         self._on_reply(item, popped=True)
-                    for payload in others:
-                        self._dispatch(loads_msg(payload))
+                    for msg in rest:
+                        self._dispatch(msg)
                     if dones:
                         self.rt._direct_flush_side()
                 else:
@@ -1673,6 +1828,11 @@ class DriverRuntime(BaseRuntime):
         # direct registration's arg pins before the NM resubmit re-pins.
         self._dpost(("done", [], dep_ids, None))
 
+    def _direct_on_item(self, oid, frame, remote):
+        # Buffered like a "reg": every way a ref leaves this process
+        # (a submit, a delta flush, a location lookup) drains first.
+        self._dpost(("item", oid, frame if remote else None), wake=remote)
+
     def _dpost(self, item: tuple, wake: bool = True):
         """Queue NM bookkeeping. wake=False defers the drain to the next
         reply/delta-flush (safe for "reg" items: the buffer is FIFO so a
@@ -1714,6 +1874,14 @@ class DriverRuntime(BaseRuntime):
                                      initial_refs=0)
                 for oid in spec.pinned_ids():
                     nm._pin_ref_bg(oid)
+            elif kind == "item":
+                _, oid, frame = item
+                nm.directory.add(oid, InlineLocation(b""), initial_refs=0)
+                if frame is not None:  # from another node
+                    nm.directory.add_ref(oid)
+                    nm._seal_object(oid, frame["loc"])
+                    if frame.get("n"):
+                        nm._register_nested(oid, frame["n"])
             else:  # "done"
                 _, results, dep_ids, nested = item
                 for roid, loc in results:
@@ -1790,8 +1958,14 @@ class DriverRuntime(BaseRuntime):
         self.refs.flush()
         import asyncio
 
+        async def _locate():
+            # What this process registered and has not posted yet (a
+            # streamed item's entry) is what the lookup may be for.
+            self._drain_dposts()
+            return await self._nm.get_locations(ids, timeout)
+
         try:
-            return self._nm.call_sync(self._nm.get_locations(ids, timeout))
+            return self._nm.call_sync(_locate())
         except asyncio.TimeoutError as e:
             # py<3.11: asyncio.TimeoutError is NOT builtin TimeoutError,
             # so normalize at the boundary — callers' `except
@@ -2043,6 +2217,17 @@ class WorkerRuntime(BaseRuntime):
             for oid in dep_ids:
                 self._direct_unpins[oid] = self._direct_unpins.get(oid, 0) + 1
         self._direct_flush_side(force=True)
+
+    def _direct_on_item(self, oid, frame, remote):
+        # Leaves with the next ``direct_side`` frame: before any ref
+        # delta, submit or request of this process, on the one socket.
+        with self._direct_side_lock:
+            self._direct_side_mark_first()
+            self._direct_regs.append(([oid], [oid] if remote else []))
+            if remote:
+                self._direct_seals.append((oid, frame["loc"]))
+                if frame.get("n"):
+                    self._direct_nested.append((oid, frame["n"]))
 
     def _direct_flush_side(self, force: bool = False):
         with self._direct_side_lock:

@@ -19,7 +19,7 @@ from . import frame_pump
 from .executor import ActorContainer, execute_task
 from .function_table import FunctionCache
 from .ids import JobID, NodeID, ObjectID, TaskID, WorkerID
-from .object_store import Location
+from .object_store import InlineLocation, Location
 from .protocol import Connection, ConnectionClosed, connect_unix
 from .runtime import WorkerRuntime
 from .serialization import SerializedObject
@@ -815,7 +815,8 @@ class Worker:
                             with self._serial_lock:
                                 done = self._run_task(
                                     spec, blob, sample_resources=False,
-                                    queued_ts=recv_ts)
+                                    queued_ts=recv_ts,
+                                    caller=(conn, remote))
                             self._note_direct_done(done, spec, remote)
                             with self._dr_lock:
                                 _, buf = self._dr_bufs.setdefault(
@@ -889,7 +890,7 @@ class Worker:
     def _run_direct(self, conn, spec, function_blob, remote=False,
                     queued_ts: float = 0.0):
         done = self._run_task(spec, function_blob, sample_resources=False,
-                              queued_ts=queued_ts)
+                              queued_ts=queued_ts, caller=(conn, remote))
         self._note_direct_done(done, spec, remote)
         try:
             self._send_replies(conn, [done])
@@ -948,6 +949,28 @@ class Worker:
             # completions flush eagerly instead of debouncing.
             self._flush_nm_dones(force=True)
 
+    def _note_stream_seal(self, oid, loc, nested=None, refs: int = 1,
+                          held: bool = False):
+        """Queue the seal of a streamed item that went to its consumer
+        on the direct channel: what the node manager needs of it (the
+        entry a third party resolves, and ``refs``, the item's one pin,
+        or for a caller on another node the hold), in the debounced
+        direct_done_batch: one frame for a burst of items, off the
+        item's path. ``held`` items flush at once: the caller pulls the
+        bytes the moment the item's frame lands."""
+        item = {"stream_item": (oid, loc), "refs": refs}
+        if nested:
+            item["nested"] = nested
+        if held:
+            item["held"] = True
+        with self._nm_done_lock:
+            if not self._nm_done_buf:
+                self._nm_done_first = time.monotonic()
+            self._nm_done_buf.append(item)
+            n = len(self._nm_done_buf)
+        if held or n >= self._done_flush_batch:
+            self._flush_nm_dones(force=True)
+
     def _flush_nm_dones(self, force: bool = False):
         with self._nm_done_lock:
             n = len(self._nm_done_buf)
@@ -993,7 +1016,10 @@ class Worker:
 
     def _run_task(self, spec: TaskSpec, function_blob,
                   to_nm: bool = False, sample_resources: bool = True,
-                  queued_ts: float = 0.0) -> dict:
+                  queued_ts: float = 0.0, caller=None) -> dict:
+        """``caller``: for a call that came on a direct connection,
+        ``(that connection, whether its caller is on another node)``: a
+        streaming call's items go back on it."""
         if spec.task_type == TaskType.ACTOR_TASK:
             with self._direct_seen_lock:
                 cached = self._direct_seen.get(spec.task_id.binary())
@@ -1055,19 +1081,74 @@ class Worker:
         seals = None
         if spec.streaming:
             from ..util.metrics import ItemTally
-            from .streaming import STREAM_ITEM_SEAL_S, STREAM_ITEMS_SEALED
+            from .config import get_config
+            from .serialization import serialize_with_refs as _ser_refs
+            from .streaming import (STREAM_ITEM_SEAL_S, STREAM_ITEMS_SEALED,
+                                    consumed_upto, stream_item_id)
+
+            inline_limit = get_config().max_inline_object_size
 
             # Items this task seals and the seconds that takes its
             # thread, recorded every ItemTally.FLUSH_ITEMS items and
             # when the task ends.
             seals = ItemTally(STREAM_ITEMS_SEALED, STREAM_ITEM_SEAL_S)
 
+        def stream_item_direct(index: int, value):
+            """One streamed yield of a call that came on a direct
+            connection: ONE frame to its consumer on that connection,
+            now; the node manager hears of it in the debounced batch
+            (core/streaming.py, the direct route)."""
+            nonlocal caller
+            if caller is None:
+                return stream_item(index, value)
+            sealing = _time.perf_counter()
+            conn, remote = caller
+            oid = stream_item_id(spec.task_id, index)
+            sobj, nested = _ser_refs(value)
+            inline = sobj.total_size <= inline_limit
+            loc = (InlineLocation(sobj.to_bytes()) if inline
+                   else rt.store.put_serialized(oid, sobj))
+            frame = {"type": "stream_item", "i": spec.task_id.binary(),
+                     "x": index, "loc": loc}
+            if not remote:
+                # Pinned only if it left: a consumer that never gets
+                # the frame never releases it.
+                try:
+                    conn.send(frame)
+                except Exception:
+                    if index:
+                        # Its consumer may hold what left before: the
+                        # stream ends here (store bytes that did not
+                        # leave are sealed unpinned, for the sweep).
+                        if not inline:
+                            self._note_stream_seal(oid, loc, refs=0)
+                        raise
+                    # Nothing of this stream left: its consumer takes
+                    # the call's replay (this run's completion, by
+                    # ``_direct_seen``) and reads the node-manager
+                    # route, which this run takes from here.
+                    caller = None
+                    msg = {"type": "put", "object_id": oid, "loc": loc,
+                           "refs": 1, "pin_if_new": True}
+                    if nested:
+                        msg["nested"] = nested
+                    self.conn.send(msg)
+                else:
+                    self._note_stream_seal(oid, loc, nested)
+            else:
+                # The caller's own node manager is told of the item by
+                # the caller; this one only of bytes it has to hold for
+                # it, and first.
+                if nested:
+                    frame["n"] = nested
+                if not inline:
+                    self._note_stream_seal(oid, loc, refs=0, held=True)
+                conn.send(frame)
+            seals.item(_time.perf_counter() - sealing)
+
         def stream_item(index: int, value):
             """Seal one streamed yield: the seal is what wakes the
-            consumer (see core/streaming.py for the protocol)."""
-            from .serialization import serialize_with_refs as _ser_refs
-            from .streaming import consumed_upto, stream_item_id
-
+            consumer (see core/streaming.py, the node-manager route)."""
             sealing = _time.perf_counter()
             # A retried attempt re-runs the generator from its start:
             # what the consumer already took is not sealed, and so not
@@ -1118,7 +1199,9 @@ class Worker:
         try:
             results, failed, nested, error_info = execute_task(
                 spec, load_function, fetch, store_large, self.actor,
-                stream_item=stream_item if spec.streaming else None,
+                stream_item=(None if not spec.streaming
+                             else stream_item if caller is None
+                             else stream_item_direct),
             )
         finally:
             rt.current_task_id = None

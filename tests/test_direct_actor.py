@@ -106,9 +106,10 @@ def test_kill_fails_pending_direct_calls(rt):
 
 
 def test_streaming_call_fences_direct_traffic(rt):
-    """A streaming (NM-routed) call interleaved with direct calls must
-    not overtake them: the submit path fences the direct channel and
-    tears it down until the NM queue drains again."""
+    """A streaming call interleaved with direct calls must not overtake
+    them: it rides the channel in its sequence (since PR 56; before, the
+    submit path fenced the channel and routed it through the NM, as it
+    still does for a call that may be retried)."""
 
     @ray_tpu.remote
     class Gen:
@@ -206,3 +207,82 @@ def test_chained_pending_direct_result(rt):
     for _ in range(5):
         r = c.g.remote(r)
     assert ray_tpu.get(r, timeout=30) == 35
+
+
+def test_a_served_token_stream_rides_the_channel_and_the_node_manager_hears_in_batches(
+        monkeypatch):
+    """A tiny engine's tokens through a per-node proxy actor (a worker:
+    what it asks of the node manager crosses a socket): every token
+    comes, over the streams no ``wait`` and no ``put`` frame reaches the
+    node manager from anyone, and every item the proxy was handed came
+    on the direct channel (README "Stream delivery": the hit share)."""
+    import collections
+    import json
+    import urllib.request
+
+    from ray_tpu import serve
+    from ray_tpu.core.runtime_context import current_runtime
+    from ray_tpu.serve import http_proxy
+    from ray_tpu.serve.llm import LLMDeployment
+    from ray_tpu.util.metrics import get_metrics_report
+
+    def delivered():
+        report = get_metrics_report()
+        return tuple(
+            report.get(f"ray_tpu_stream_items{kind}_total",
+                       {"series": {}})["series"].get((), 0.0)
+            for kind in ("", "_direct"))
+
+    def tokens_of(port, new):
+        request = urllib.request.Request(
+            f"http://127.0.0.1:{port}/tiny/stream",
+            data=json.dumps({"prompt": [1, 2, 3],
+                             "max_new_tokens": new}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(request, timeout=120) as reply:
+            lines = [raw.decode().strip() for raw in reply]
+        return [json.loads(line[5:])["token"] for line in lines
+                if line.startswith("data:") and "token" in line]
+
+    ray_tpu.init(num_cpus=4, system_config={
+        "log_to_driver": False})
+    proxies = {}
+    try:
+        dep = serve.deployment(LLMDeployment).options(
+            name="tiny",
+            ray_actor_options={"max_concurrency": 8, "num_cpus": 1})
+        serve.run(dep.bind(max_batch=2, max_len=64), name="tiny")
+        proxies = http_proxy.start_per_node_proxies(port=0)
+        (_, port), = proxies.values()
+        items0, direct0 = delivered()
+        # The proxy's first call to the replica finds its channel.
+        assert len(tokens_of(port, 2)) == 2
+        frames = collections.Counter()
+        nm = current_runtime()._nm
+        real = nm._dispatch_message_op
+
+        async def counted(w, msg, clock=None):
+            frames[msg["type"]] += 1
+            return await real(w, msg, clock)
+
+        monkeypatch.setattr(nm, "_dispatch_message_op", counted)
+        n, streams = 24, 3
+        for _ in range(streams):
+            assert len(tokens_of(port, n)) == n
+        assert frames["wait"] == 0 and frames["put"] == 0, frames
+        # ~16 items a batch; the completions' seals ride them too.
+        assert 0 < frames["direct_done_batch"] <= streams * n // 4, frames
+        deadline = time.time() + 30
+        while time.time() < deadline:
+            items, direct = (a - b for a, b in zip(delivered(),
+                                                   (items0, direct0)))
+            if items >= 2 + streams * n:
+                break
+            time.sleep(0.2)
+        assert (items, direct) == (2 + streams * n, 2 + streams * n)
+    finally:
+        for actor, _ in proxies.values():
+            ray_tpu.get(actor.shutdown.remote(), timeout=30)
+            ray_tpu.kill(actor)
+        serve.shutdown()
+        ray_tpu.shutdown()

@@ -55,16 +55,64 @@ def test_streaming_generator_error_propagates(ray_tpu_start):
     assert vals == [1]
 
 
-def test_streaming_actor_method(ray_tpu_start):
+_START = {"num_prestart_workers": 2, "refcount_flush_interval_s": 0.1,
+          "gc_grace_period_s": 1.0}
+
+
+@pytest.fixture(params=["node_manager", "direct"])
+def actor_route(request):
+    """A runtime whose actors' streams take the named route
+    (core/streaming.py): the direct actor-call plane off, or on with
+    ``_on_route`` waiting for the actor's channel."""
+    config = dict(_START)
+    if request.param == "node_manager":
+        config["direct_actor_calls"] = False
+    ray_tpu.init(num_cpus=4, system_config=config)
+    yield request.param
+    ray_tpu.shutdown()
+
+
+def _on_route(actor, route, timeout=30.0):
+    """``actor`` (it has a ``ping``) with its direct channel ready, so
+    that its next stream rides it; nothing to wait for on the
+    node-manager route."""
+    from ray_tpu.core.runtime_context import current_runtime
+
+    if route == "node_manager":
+        return actor
+    state = current_runtime()._direct_state(actor._actor_id)
+    deadline = time.monotonic() + timeout
+    while state["status"] != "ready":
+        assert time.monotonic() < deadline, state
+        ray_tpu.get(actor.ping.remote(), timeout=30)
+        time.sleep(0.02)
+    return actor
+
+
+def _direct_items():
+    """Items of this process's streams that came on a direct channel."""
+    from ray_tpu.util.metrics import local_snapshot
+
+    return _series(local_snapshot(), "ray_tpu_stream_items_direct_total")
+
+
+def test_streaming_actor_method(actor_route):
     @ray_tpu.remote
     class Producer:
+        def ping(self):
+            return 1
+
         def chunks(self, n):
             for i in range(n):
                 yield {"chunk": i}
 
-    p = Producer.remote()
+    p = _on_route(Producer.remote(), actor_route)
+    before, direct0 = _stream_counters()[0], _direct_items()
     gen = p.chunks.options(num_returns="streaming").remote(3)
     assert [ray_tpu.get(r)["chunk"] for r in gen] == [0, 1, 2]
+    assert ray_tpu.get(gen.completed, timeout=30) == 3
+    assert _stream_counters()[0] - before == 3
+    assert _direct_items() - direct0 == (3 if actor_route == "direct" else 0)
 
 
 def test_streaming_empty_generator(ray_tpu_start):
@@ -234,6 +282,9 @@ def test_stream_counters_follow_the_pace(ray_tpu_start, pace):
 
 @ray_tpu.remote
 class _StreamActor:
+    def ping(self):
+        return 1
+
     def produce(self, k, fail):
         for i in range(k):
             yield i
@@ -249,17 +300,23 @@ def _stream_task(k, fail):
         raise ValueError("stream broke")
 
 
-@pytest.mark.parametrize("kind", ["task", "actor_method"])
+@pytest.mark.parametrize("kind", ["task", "actor_method",
+                                  "actor_method_direct"])
 @pytest.mark.parametrize("k,fail", [(0, False), (3, True)],
                          ids=["empty", "error_after_3"])
 def test_stream_ends_at_once(ray_tpu_start, kind, k, fail):
     """The end of a stream and a producer's error are seals like any
-    other: neither waits for ``item_timeout_s``."""
+    other (on the direct route: frames like any other): neither waits
+    for ``item_timeout_s``."""
     if kind == "task":
         gen = _stream_task.remote(k, fail)
     else:
         actor = _StreamActor.remote()
+        if kind == "actor_method_direct":
+            _on_route(actor, "direct")
         gen = actor.produce.options(num_returns="streaming").remote(k, fail)
+        if kind == "actor_method_direct":
+            assert gen._stream is not None
     gen.item_timeout_s = 120.0
     t0 = time.monotonic()
     got = []
@@ -874,9 +931,10 @@ def test_a_stream_that_ends_badly_leaves_nothing_pinned_or_kept(
 
 
 def test_a_served_streams_fetch_asks_the_node_manager_nothing(
-        ray_tpu_start, monkeypatch):
+        actor_route, monkeypatch):
     """``handle.stream``: the ``fetch`` hop, ``ray_tpu.get(ref)``, reads
-    what the wait's reply brought; the stream's end does too."""
+    what the wait's reply brought, or on the direct route what the
+    item's frame brought; the stream's end does too."""
     from ray_tpu import serve
     from ray_tpu.core.runtime_context import current_runtime
 
@@ -892,12 +950,368 @@ def test_a_served_streams_fetch_asks_the_node_manager_nothing(
     handle = serve.run(Tokens.bind(), name="toks41")
     try:
         stream = handle.options(method="stream")
-        assert [item["token"] for item in stream.stream(None)] == list(
-            range(n))  # routes resolved, replica warm
+
+        def one_stream():
+            carried0, direct0 = _carried(), _direct_items()
+            got = [item["token"] for item in stream.stream(None)]
+            assert got == list(range(n))
+            return _carried() - carried0, _direct_items() - direct0
+
+        want = (0, n) if actor_route == "direct" else (n, 0)
+        deadline = time.monotonic() + 30
+        while one_stream() != want:  # routes resolved, channel ready
+            assert time.monotonic() < deadline
         asked = _no_request_from_this_thread(current_runtime(), monkeypatch)
-        carried0 = _carried()
-        got = [item["token"] for item in stream.stream(None)]
-        assert got == list(range(n))
-        assert asked == [] and _carried() - carried0 == n
+        assert one_stream() == want
+        assert asked == []
     finally:
         serve.shutdown()
+
+
+# ---- an actor's stream on the direct channel its call went out on (PR 56) --
+
+
+@ray_tpu.remote
+class _Chunks:
+    """A producer with a ``ping`` (``_on_route``) whose streams say how
+    far they got (``sent``)."""
+
+    def __init__(self):
+        self.sent = 0
+
+    def ping(self):
+        return self.sent
+
+    def nap(self, seconds):
+        time.sleep(seconds)
+        return seconds
+
+    def hold_seals(self, hold):
+        """Keep this worker's ``direct_done_batch`` buffer from leaving
+        (whatever would flush it: its size, its age, a request of any
+        thread) until told to let it go."""
+        from ray_tpu.core.runtime_context import current_runtime
+
+        worker = current_runtime().before_block.__self__
+        if hold:
+            worker._flush_nm_dones = lambda force=False: None
+        else:
+            del worker._flush_nm_dones
+            worker._flush_nm_dones(force=True)
+
+    def produce(self, n, gap_s=0.0, fail_at=None, words=0, hold_after=None):
+        import numpy as np
+
+        for i in range(n):
+            if i == fail_at:
+                raise ValueError("stream broke")
+            time.sleep(gap_s)
+            self.sent = i + 1
+            yield np.full(words, i, dtype=np.int64) if words else i
+            if i == hold_after:
+                time.sleep(30)
+
+
+def _stream(actor, *args, **kwargs):
+    """A stream of ``_Chunks.produce`` none of whose items is waited
+    for longer than a minute (no test below can hang)."""
+    gen = actor.produce.options(num_returns="streaming").remote(
+        *args, **kwargs)
+    gen.item_timeout_s = 60.0
+    return gen
+
+
+def _store_bytes():
+    from ray_tpu.core.runtime_context import current_runtime
+
+    return current_runtime()._nm.directory.used_bytes
+
+
+def _sealed_items(task_id, n):
+    """Which of a stream's first ``n`` items the node manager holds."""
+    from ray_tpu.core.runtime_context import current_runtime
+    from ray_tpu.core.streaming import stream_item_id
+
+    ids = [stream_item_id(task_id, i) for i in range(n)]
+    return current_runtime()._wait(ids, n, 0)
+
+
+def _over_the_inline_limit():
+    from ray_tpu.core.config import get_config
+
+    return get_config().max_inline_object_size // 4  # int64s: twice it
+
+
+@pytest.mark.parametrize("case", [
+    "order_and_count", "error_mid_stream", "item_timeout",
+    "abandoned_releases_its_items", "a_ref_handed_to_a_third_task"])
+def test_an_actors_stream_on_either_route(actor_route, case):
+    """What a consumer sees of an actor's stream is the same on both
+    routes: order, count, error, timeout, what an abandoned stream
+    leaves behind (nothing) and a ref that goes on to another task."""
+    from ray_tpu.core.exceptions import GetTimeoutError
+
+    actor = _on_route(_Chunks.remote(), actor_route)
+    direct0 = _direct_items()
+    if case == "order_and_count":
+        n = 150
+        gen = _stream(actor, n)
+        assert [ray_tpu.get(r) for r in gen] == list(range(n))
+        assert ray_tpu.get(gen.completed, timeout=30) == n
+        with pytest.raises(StopIteration):
+            next(gen)
+    elif case == "error_mid_stream":
+        n, got = 3, []
+        gen = _stream(actor, 5, fail_at=3)
+        with pytest.raises(ValueError, match="stream broke"):
+            for ref in gen:
+                got.append(ray_tpu.get(ref))
+        assert got == [0, 1, 2]
+        with pytest.raises(ValueError, match="stream broke"):
+            next(gen)  # and again, as often as it is asked
+    elif case == "item_timeout":
+        n = 1
+        gen = _stream(actor, 2, hold_after=0)
+        assert ray_tpu.get(next(gen)) == 0
+        gen.item_timeout_s = 0.5
+        t0 = time.monotonic()
+        with pytest.raises(GetTimeoutError):
+            next(gen)
+        assert 0.5 <= time.monotonic() - t0 < 10
+    elif case == "abandoned_releases_its_items":
+        n, total, words = 1, 5, _over_the_inline_limit()
+        start = _store_bytes()
+        gen = _stream(actor, total, words=words)
+        task_id = gen._task_id
+        first = ray_tpu.get(next(gen))
+        assert first.shape == (words,) and (first == 0).all()
+        ray_tpu.get(gen.completed, timeout=60)
+        _wait_for(lambda: len(_sealed_items(task_id, total)),
+                  lambda k: k == total)
+        assert _store_bytes() >= start + total * words * 8
+        del gen, first
+        assert _wait_for(lambda: (_sealed_items(task_id, total),
+                                  _store_bytes()),
+                         lambda left: left == ([], start)) == ([], start)
+    else:
+        n = 4
+
+        @ray_tpu.remote
+        def double(x):
+            return 2 * x
+
+        # At once, whichever of the item's frame and its seal at the
+        # node manager is ahead.
+        doubled = [double.remote(ref) for ref in _stream(actor, n)]
+        assert ray_tpu.get(doubled, timeout=60) == [0, 2, 4, 6]
+    assert _direct_items() - direct0 == (n if actor_route == "direct" else 0)
+
+
+def test_a_release_that_overtakes_its_seal_leaves_the_count_at_zero(
+        ray_tpu_start):
+    """The consumer's release and the producer's seal come on two
+    sockets: a release that is first takes the consumer's placeholder
+    below zero, where the sweep leaves it past the grace; the seal's pin
+    brings it back to zero, and the entry, with its store bytes, goes."""
+    from ray_tpu.core.runtime_context import current_runtime
+    from ray_tpu.core.streaming import stream_item_id
+
+    rt = current_runtime()
+    counts = rt._nm.directory._refcounts
+    actor = _on_route(_Chunks.remote(), "direct")
+    ray_tpu.get(actor.hold_seals.remote(True), timeout=30)
+    start, words = _store_bytes(), _over_the_inline_limit()
+    gen = _stream(actor, 1, words=words)
+    assert gen._stream is not None
+    oid = stream_item_id(gen._task_id, 0)
+    ref = next(gen)
+    assert (ray_tpu.get(ref) == 0).all()
+    assert oid not in counts  # neither socket has told of it
+    del ref
+    rt.refs.flush()  # the release, and before it the placeholder
+    assert _wait_for(lambda: counts.get(oid), lambda c: c == -1) == -1
+    with pytest.raises(StopIteration):
+        next(gen)
+    time.sleep(2.5)  # the grace is 1 s: an entry AT zero would be gone
+    assert counts.get(oid) == -1 and _store_bytes() == start
+    ray_tpu.get(actor.hold_seals.remote(False), timeout=30)
+    # The batch: pinned on the placeholder, so at zero; then collected.
+    assert _wait_for(lambda: counts.get(oid), lambda c: c == 0) == 0
+    assert _wait_for(lambda: (counts.get(oid), _store_bytes()),
+                     lambda left: left == (None, start)) == (None, start)
+
+
+@pytest.mark.parametrize("producer", ["retriable_actor_call", "task"])
+def test_a_stream_that_may_be_retried_or_has_no_channel_stays_with_the_node_manager(
+        ray_tpu_start, monkeypatch, producer):
+    """The route is what the spec says: hit share 0, carried share 1."""
+    from ray_tpu.core.runtime_context import current_runtime
+    from ray_tpu.core.streaming import stream_key
+
+    rt, n = current_runtime(), 5
+    direct0, carried0 = _direct_items(), _carried()
+    if producer == "task":
+        gen = _stream_task.remote(n, False)
+    else:
+        actor = _on_route(_Chunks.remote(), "direct")
+        submit = rt.submit
+
+        def retriable(spec):
+            spec.max_retries = spec.retries_left = 1
+            return submit(spec)
+
+        monkeypatch.setattr(rt, "submit", retriable)
+        gen = _stream(actor, n)
+        assert gen._retriable
+    assert gen._stream is None
+    first = next(gen)
+    if producer != "task":
+        assert rt.kv_get(stream_key(gen._task_id)) == b"1"
+    assert [ray_tpu.get(r) for r in [first, *gen]] == list(range(n))
+    assert _direct_items() == direct0 and _carried() - carried0 == n
+
+
+def _kill_channel(actor):
+    """Cut the direct channel to ``actor`` as a network fault would (the
+    raw socket: not a close of ours, which fails its calls)."""
+    from ray_tpu.core.runtime_context import current_runtime
+
+    chan = current_runtime()._direct_state(actor._actor_id)["chan"]
+    chan.conn.close()
+    return chan
+
+
+def test_a_channel_killed_mid_stream_ends_it_with_the_actors_death(
+        ray_tpu_start):
+    """A stream that was handed an item is not replayed: what came stays
+    readable, the next ``next()`` raises ActorDiedError (each time), the
+    generator ran once, and the next stream falls back and completes."""
+    from ray_tpu.core.exceptions import ActorDiedError
+
+    actor = _on_route(_Chunks.remote(), "direct")
+    gen = _stream(actor, 4, gap_s=0.5)
+    first = next(gen)
+    _kill_channel(actor).drained.wait(10)
+    for _ in range(2):
+        with pytest.raises(ActorDiedError):
+            next(gen)
+    assert ray_tpu.get(first) == 0
+    direct0, carried0 = _direct_items(), _carried()
+    again = _stream(actor, 3)
+    assert again._stream is None
+    assert [ray_tpu.get(r) for r in again] == [0, 1, 2]
+    assert (_direct_items(), _carried() - carried0) == (direct0, 3)
+    # The first generator ran once and stopped where its send failed.
+    assert ray_tpu.get(actor.ping.remote(), timeout=30) == 3
+
+
+def test_a_stream_that_was_handed_nothing_is_replayed_over_the_node_manager(
+        ray_tpu_start):
+    """Its channel dies while the call still waits behind another: it
+    replays like any call and the consumer goes on over the
+    node-manager route, every item, once."""
+    actor = _on_route(_Chunks.remote(), "direct")
+    carried0, direct0 = _carried(), _direct_items()
+    nap = actor.nap.remote(1.0)
+    gen = _stream(actor, 4)
+    assert gen._stream is not None
+    time.sleep(0.2)  # both frames are at the worker, the nap running
+    _kill_channel(actor)
+    assert [ray_tpu.get(r) for r in gen] == [0, 1, 2, 3]
+    assert ray_tpu.get(nap, timeout=30) == 1.0
+    assert gen._stream is None
+    assert (_direct_items(), _carried() - carried0) == (direct0, 4)
+    assert ray_tpu.get(actor.ping.remote(), timeout=30) == 4
+
+
+def test_an_actors_stream_from_another_node_rides_the_channel_too():
+    """A producer on another node: inline items come in their frames, a
+    store put comes as a held remote location that the node manager
+    pulls (told of it by the consumer, as of a remote direct result), a
+    ref goes on to a task, and the consumer's own node manager counts
+    each taken item once until its ref drops."""
+    from ray_tpu.cluster_utils import Cluster
+    from ray_tpu.core.runtime_context import current_runtime
+
+    c = Cluster(head_resources={"CPU": 2},
+                system_config=dict(_START, log_to_driver=False))
+    try:
+        c.add_node(num_cpus=2, resources={"gadget": 1})
+        c.wait_for_nodes(2)
+        actor = _on_route(
+            _Chunks.options(resources={"gadget": 1}).remote(), "direct")
+        rt = current_runtime()
+        assert rt._direct_state(actor._actor_id)["chan"].remote
+        direct0, words = _direct_items(), _over_the_inline_limit()
+        assert [ray_tpu.get(r) for r in _stream(actor, 5)] == list(range(5))
+        refs = list(_stream(actor, 3, words=words))
+        assert [(v.shape, int(v[0])) for v in
+                (ray_tpu.get(r) for r in refs)] == [
+                    ((words,), i) for i in range(3)]
+
+        @ray_tpu.remote
+        def double(x):
+            return 2 * x
+
+        doubled = [double.remote(r) for r in _stream(actor, 3)]
+        assert ray_tpu.get(doubled, timeout=60) == [0, 2, 4]
+        assert _direct_items() - direct0 == 11
+        counts, ids = rt._nm.directory._refcounts, [r.id() for r in refs]
+        assert [counts.get(oid) for oid in ids] == [1, 1, 1]
+        del refs
+        assert _wait_for(lambda: [counts.get(oid) for oid in ids],
+                         lambda left: left == [None] * 3) == [None] * 3
+    finally:
+        c.shutdown()
+
+
+def test_a_direct_streams_two_threads_lose_nothing_under_a_short_switch_interval():
+    """``DirectStream`` alone, more threads than cores: one reader puts
+    24 streams' frames, a consumer a stream takes them; every frame
+    comes once, in order, each stream's end after its last frame, and a
+    stream abandoned half way hands the rest to ``release``."""
+    import sys
+    import threading
+
+    from ray_tpu.core.streaming import DirectStream
+
+    n, streams, released = 400, 24, []
+    made = [DirectStream(False, True, released.append)
+            for _ in range(streams)]
+    got = [[] for _ in made]
+    ends = [None] * streams
+
+    def consume(k):
+        while True:
+            frame, _ = made[k].take(30.0)
+            if frame is None:
+                ends[k] = made[k].ended
+                return
+            got[k].append(frame["x"])
+            if k == 0 and len(got[k]) == n // 2:
+                got[k].extend(f["x"] for f in made[k].abandon())
+                ends[k] = "abandoned"
+                return
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=consume, args=(k,))
+                   for k in range(streams)]
+        for t in threads:
+            t.start()
+        for x in range(n):
+            for stream in made:
+                stream.put({"i": b"", "x": x, "loc": None})
+        for stream in made:
+            stream.end(DirectStream.DONE)
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert got[1:] == [list(range(n))] * (streams - 1)
+    assert ends[1:] == [DirectStream.DONE] * (streams - 1)
+    assert ends[0] == "abandoned"
+    assert got[0] + [f["x"] for f in released] == list(range(n))
+    assert made[0].received == n
