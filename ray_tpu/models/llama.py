@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 from ..parallel.sharding import prune_spec, with_logical_constraint
 from ..parallel.mesh import mesh_axis_size
 from ..parallel.ring_attention import ring_attention
-from ..parallel.moe import moe_ffn
+from ..parallel.moe import dispatch, moe_ffn
 from ..ops.attention import mha_attention
 
 
@@ -76,11 +76,20 @@ class LlamaConfig:
     n_shared_experts: int = 0
     # The router (parallel/moe.route): "softmax" | "sigmoid" scores, a
     # learned selection bias that is not in the gate, the chosen gates
-    # renormalised, and a scale.
+    # renormalised, and a scale. What it reads: "ffn", the FFN's normed
+    # input (the residual behind the attention under ``mlp_norm``), or
+    # "attention", the attention's own normed input: ``block`` then
+    # routes and sorts BEFORE the attention (parallel/moe.dispatch), the
+    # experts still multiply the FFN's input, and nothing of the route
+    # waits for the attention (SmallThinker, PR 57).
     router_score: str = "softmax"
     router_bias: bool = False
     route_norm: bool = False
     route_scale: float = 1.0
+    router_input: str = "ffn"
+    # The routed experts' gate activation: "silu" (SwiGLU) or "relu"
+    # (ReGLU: relu(gate) * up).
+    expert_act: str = "silu"
     # A layer's attention kind, "window" or "full" (None: all full). A
     # window layer's token t attends to t - sliding_window < j <= t.
     # Or "state" for every layer: power retention of degree 2
@@ -286,6 +295,12 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
                 f"of {cfg.num_layers} layers")
     if "window" in kinds and not cfg.sliding_window:
         raise ValueError("window layers need a sliding_window")
+    if (cfg.router_input not in ("ffn", "attention")
+            or cfg.expert_act not in _EXPERT_ACTS):
+        raise ValueError(
+            f"router_input {cfg.router_input!r} is 'ffn' or 'attention' "
+            f"and expert_act {cfg.expert_act!r} one of "
+            f"{sorted(_EXPERT_ACTS)}")
     alike = [(cfg.n_experts > 0 and i >= cfg.num_dense_layers, kind)
              for i, kind in enumerate(kinds)]
     runs, seen = [], dict.fromkeys(("full", "window", "latent", "state"), 0)
@@ -618,6 +633,24 @@ def causal_attention(cfg: LlamaConfig, mesh, q, k, v, window=None):
     return mha_attention(q, k, v, causal=True, window=window)
 
 
+def prefill_attention_path(cfg: LlamaConfig, tokens: int) -> Optional[str]:
+    """What ``causal_attention`` runs in a prefill of ``tokens`` (one
+    bucket, no mesh) for this model's layers that attend over k and v
+    rows: ops/flash_attention.py's ``forward_path``, ``"einsum"`` with
+    ``use_flash`` off; None where no layer calls it for such a prompt
+    (retention layers; a selection over a longer prompt)."""
+    from ..ops.flash_attention import forward_path
+
+    if cfg.retention or (cfg.index_topk and tokens > cfg.index_topk):
+        return None
+    if not cfg.use_flash:
+        return "einsum"
+    kv_heads, dv = ((cfg.num_heads, cfg.v_head_dim) if cfg.latent
+                    else (cfg.num_kv_heads, cfg.dh))
+    return forward_path(tokens, tokens, cfg.dh, dv, cfg.num_heads, kv_heads,
+                        jnp.dtype(cfg.dtype).itemsize)
+
+
 def qkv_proj(cfg: LlamaConfig, lp, x):
     """The block's first half up to rotary: attention norm, then q
     [B,S,H,Dh] and k, v [B,S,Hkv,Dh], q and k normed where the model has
@@ -790,8 +823,27 @@ def swiglu(h, w_gate, w_up, w_down, *, mesh=None):
     return jnp.einsum("bsf,fm->bsm", h, w_down)
 
 
+_EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def route_tokens(cfg: LlamaConfig, lp, h, *, mesh=None, token_mask=None,
+                 expert_stack=None):
+    """The expert layer's route (parallel/moe.dispatch) of the normed
+    rows ``h`` [B,S,M] that the model's router reads: ``ffn`` makes it
+    from its own input, ``block`` from the attention's for a model of
+    ``router_input="attention"``."""
+    stacked = expert_stack is not None
+    return dispatch(
+        h, lp["router"], k=cfg.top_k, token_mask=token_mask,
+        layer=lp["index"] if stacked else None,
+        stack_layers=expert_stack["w_up"].shape[0] if stacked else None,
+        mesh=mesh, held=cfg.experts_held,
+        score=cfg.router_score, select_bias=lp.get("expert_bias"),
+        renormalize=cfg.route_norm, scale=cfg.route_scale)
+
+
 def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
-        expert_stack=None):
+        expert_stack=None, routed=None):
     """The block's second half: MLP norm, then the
     SiLU-gated MLP or, for a layer with a ``router``, the mixture of
     experts (and the shared expert beside it), normed again where the
@@ -803,17 +855,21 @@ def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
     keeps rows (inactive
     decode slots, bucket padding) away from every expert. The experts'
     weights are ``lp``'s own, or with ``expert_stack`` (the second half
-    of ``split_expert_stack``) the whole run's, read at ``lp["index"]``."""
+    of ``split_expert_stack``) the whole run's, read at ``lp["index"]``.
+    ``routed``: the route, where ``block`` made it ahead of the attention
+    (``route_tokens``); None: the router reads this half's normed input."""
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if "router" in lp:
         w = lp if expert_stack is None else expert_stack
+        if routed is None:
+            routed = route_tokens(cfg, lp, h, mesh=mesh,
+                                  token_mask=token_mask,
+                                  expert_stack=expert_stack)
         out, aux, expert_tokens = moe_ffn(
             h, lp["router"], w["w_up"], w["w_down"], k=cfg.top_k,
-            w_gate=w["w_gate"], token_mask=token_mask,
-            layer=None if expert_stack is None else lp["index"], mesh=mesh,
-            held=cfg.experts_held,
-            score=cfg.router_score, select_bias=lp.get("expert_bias"),
-            renormalize=cfg.route_norm, scale=cfg.route_scale,
+            w_gate=w["w_gate"], activation=_EXPERT_ACTS[cfg.expert_act],
+            layer=None if expert_stack is None else lp["index"],
+            routed=routed,
         )
         if "ws_up" in lp:
             with jax.named_scope("moe.shared"):
@@ -856,8 +912,20 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
     carries the selection in ``attend``'s state. For "state" ``attend``
     is given ``(v, log gates)`` in v's place (``qkv_proj``).
 
+    A model whose router reads the attention's input
+    (``router_input="attention"``) is routed here, first: the route, the
+    sort and the grouped matmul's walk are then in the program ahead of
+    the attention and depend on nothing it produces.
+
     Returns (x, attend's state, load-balancing loss, tokens assigned to
     each expert or None)."""
+    routed = None
+    if cfg.router_input == "attention" and "router" in lp:
+        # ``attn_norm`` of x a second time in the text (``qkv_proj``
+        # norms it too): one operation once compiled.
+        routed = route_tokens(
+            cfg, lp, rms_norm(x, lp["attn_norm"], cfg.rms_eps), mesh=mesh,
+            token_mask=token_mask, expert_stack=expert_stack)
     if kind == "latent":
         (q, k), v, gate = latent_proj(cfg, lp, x, positions), None, None
     elif kind in ("latent_index", "latent_shared"):
@@ -883,7 +951,7 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
         attn = rms_norm(attn, lp["post_attn_norm"], cfg.rms_eps)
     x = x + attn
     x, aux, expert_tokens = ffn(cfg, lp, x, mesh=mesh, token_mask=token_mask,
-                                expert_stack=expert_stack)
+                                expert_stack=expert_stack, routed=routed)
     return x, state, aux, expert_tokens
 
 

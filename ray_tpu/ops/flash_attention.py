@@ -48,7 +48,15 @@ itemsize — the largest of 128..``_MAX_BLOCK`` that divide the sequence
 and keep the kernel inside the VMEM a kernel gets without asking
 (``_VMEM_BUDGET``: K and V of one head stay whole in VMEM,
 double-buffered; for dK/dV the group's Q and dO), or None where 128 does
-not divide (the einsum's shapes). The sizes were read off a v5e (PERF.md
+not divide (the einsum's shapes). Where one head's K and V alone are
+over that budget (16,384 keys of 128 + 128 in bfloat16 are 16.8 MB
+double-buffered) the FORWARD is the streamed form (``Blocks.streamed``,
+``_flash_fwd_streamed``, PR 57): the grid's second dimension walks the
+(query block, key block) pairs in which a query attends to a key
+(``stream_visits``, made when the kernel is built), a visit brings one
+key block into VMEM, and a window layer's query block is given only the
+key blocks its window touches. Every shape that fits keeps the resident
+form and its blocks. The sizes were read off a v5e (PERF.md
 §6, PR 44): at b8 x 2048 the forward takes 13.9 ms at 128 x 128 and 4.0
 at 512 x 512, dQ 11.5 and 3.9, dK/dV 15.4 and 5.1; what pays is the
 step of the kernel's loop. ``block_q`` / ``block_k`` arguments override
@@ -87,10 +95,13 @@ def _dot(a, b, dims):
 
 
 class Blocks(NamedTuple):
-    """(block_q, block_k) of the forward, the dQ and the dK/dV kernel."""
+    """(block_q, block_k) of the forward, the dQ and the dK/dV kernel,
+    and whether the forward is the streamed form (``_flash_fwd_streamed``:
+    K and V come a block at a time) and not the resident one."""
     fwd: Tuple[int, int]
     dq: Tuple[int, int]
     dkv: Tuple[int, int]
+    streamed: bool = False
 
 
 def _largest_block(n: int) -> int:
@@ -156,7 +167,20 @@ def choose_blocks(sq: int, skv: int, d: int, dv: int, rep: int,
     bq, bk = fit(sq, skv, skv, 0, 4)
     group = rep if _dkv_grouped(rep, sq, d, itemsize) else 1
     dkv_bk, dkv_bq = fit(skv, sq, group * sq, group * sq, 5.5)
-    return Blocks(fwd=(bq, bk), dq=(bq, bk), dkv=(dkv_bq, dkv_bk))
+    # One head's K and V whole, double-buffered, beside the smallest
+    # blocks are over the budget (16,384 keys of 128 + 128 in bfloat16
+    # are 16.8 MB): the forward streams them, and what stays in VMEM is
+    # a key block where the resident form counts the head.
+    streamed = _vmem_bytes(_MIN_BLOCK, _MIN_BLOCK, skv, 0, 4, d, dv,
+                           itemsize) > _VMEM_BUDGET
+    fwd = (bq, bk)
+    if streamed:
+        fwd = _largest_block(sq), _largest_block(skv)
+        while _vmem_bytes(*fwd, fwd[1], 0, 4, d, dv,
+                          itemsize) > _VMEM_BUDGET and fwd[0] > _MIN_BLOCK:
+            fwd = fwd[0] // 2, fwd[1]
+    return Blocks(fwd=fwd, dq=(bq, bk), dkv=(dkv_bq, dkv_bk),
+                  streamed=streamed)
 
 
 def _dkv_grouped(rep: int, sq: int, d: int, itemsize: int) -> bool:
@@ -165,6 +189,14 @@ def _dkv_grouped(rep: int, sq: int, d: int, itemsize: int) -> bool:
     runs on a KV-head grid; else once a query head, summed outside."""
     return _vmem_bytes(_MIN_BLOCK, _MIN_BLOCK, rep * sq, rep * sq, 5.5, d,
                        d, itemsize) <= _VMEM_BUDGET
+
+
+def _clip(x, lo, hi):
+    """``jnp.clip``, or for plain ints a plain int (``stream_visits``
+    reckons the same bounds when the kernel is built)."""
+    if all(isinstance(i, int) for i in (x, lo, hi)):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
 
 
 def _loop_bounds(q_first, block_q, kv_offset, block_k, nk, causal, window):
@@ -177,14 +209,14 @@ def _loop_bounds(q_first, block_q, kv_offset, block_k, nk, causal, window):
     if not causal:
         return 0, nk, 0, nk
     q_last = q_first + block_q - 1
-    hi = jnp.clip((q_last - kv_offset) // block_k + 1, 0, nk)
+    hi = _clip((q_last - kv_offset) // block_k + 1, 0, nk)
     unmasked_hi = (q_first - kv_offset + 1) // block_k
     if window is None:
-        return 0, hi, 0, jnp.clip(unmasked_hi, 0, hi)
-    lo = jnp.clip((q_first - window + 1 - kv_offset) // block_k, 0, hi)
+        return 0, hi, 0, _clip(unmasked_hi, 0, hi)
+    lo = _clip((q_first - window + 1 - kv_offset) // block_k, 0, hi)
     unmasked_lo = -((-(q_last - window + 1 - kv_offset)) // block_k)
-    a = jnp.clip(unmasked_lo, lo, hi)
-    return lo, hi, a, jnp.clip(unmasked_hi, a, hi)
+    a = _clip(unmasked_lo, lo, hi)
+    return lo, hi, a, _clip(unmasked_hi, a, hi)
 
 
 def _attends(shape, q_axis, first_q, first_k, window):
@@ -304,6 +336,152 @@ def _flash_fwd(q3, k3, v3, *, heads: int, kv_heads: int, scale: float,
         ),
         interpret=interpret,
     )(q3, k3, v3)
+
+
+# What a visit of the streamed forward is, as bits of its flag: the
+# first or the last of its query block, one the diagonal or a window's
+# edge crosses (masked), or the one visit of a query block that attends
+# to no key at all (nothing to multiply: zeros are written).
+_FIRST, _LAST, _MASKED, _EMPTY = 1, 2, 4, 8
+
+
+def stream_visits(sq: int, skv: int, block_q: int, block_k: int, *,
+                  causal: bool, q_offset: int = 0, kv_offset: int = 0,
+                  window: Optional[int] = None):
+    """The streamed forward's walk, made when the kernel is built: for
+    every (query block, key block) pair in which a query attends to a
+    key, in the order the grid visits them (a query block's key blocks
+    running), its query block, its key block and its flag, each a list
+    of ints. A causal query block is given the key blocks up to its
+    diagonal, a window layer's only those that ``t - window < j <= t``
+    touches, ``_loop_bounds``' [lo, hi); nothing else is ever copied
+    into VMEM. A pure function of its arguments (the benchmark's counts
+    and tests/test_flash_attention.py read it)."""
+    nk = skv // block_k
+    q_blocks, k_blocks, flags = [], [], []
+    for i in range(sq // block_q):
+        lo, hi, a, b = _loop_bounds(q_offset + i * block_q, block_q,
+                                    kv_offset, block_k, nk, causal, window)
+        row = [(j, 0 if a <= j < b else _MASKED) for j in range(lo, hi)]
+        row = row or [(0, _EMPTY)]
+        row[0] = (row[0][0], row[0][1] | _FIRST)
+        row[-1] = (row[-1][0], row[-1][1] | _LAST)
+        q_blocks += [i] * len(row)
+        k_blocks += [j for j, _ in row]
+        flags += [f for _, f in row]
+    return q_blocks, k_blocks, flags
+
+
+def _flash_fwd_streamed_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref,
+                               v_ref, lse_ref, o_ref, m_ref, l_ref, acc_ref,
+                               *, scale: float, q_offset: int,
+                               kv_offset: int, window: Optional[int]):
+    """Grid (B*H, visits). qi_ref, kj_ref, flag_ref: ``stream_visits``,
+    in SMEM. q_ref [1, Bq, D] and the outputs stay while the visits stay
+    in the query block; k_ref / v_ref [1, Bk, D] are the visit's key
+    block, the only keys in VMEM. m_ref, l_ref [Bq, 1] and acc_ref
+    [Bq, Dv] float32 carry the online softmax from visit to visit."""
+    from jax.experimental import pallas as pl
+
+    p = pl.program_id(1)
+    flag = flag_ref[p]
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when(flag & _FIRST != 0)
+    def _first_visit_of_the_query_block():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def visit(masked):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = _dot(q, k, _NT)  # [Bq, Bk] float32, UNSCALED: scale > 0
+        if masked:
+            s = jnp.where(
+                _attends(s.shape, 0, q_offset + qi_ref[p] * block_q,
+                         kv_offset + kj_ref[p] * block_k, window),
+                s, _NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        prob = jnp.exp((s - m_new) * scale)
+        alpha = jnp.exp((m - m_new) * scale)
+        l_ref[...] = alpha * l_ref[...] + prob.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(prob.astype(v.dtype), v,
+                                                   _NN)
+        m_ref[...] = m_new
+
+    pl.when(flag & _MASKED != 0)(lambda: visit(True))
+    pl.when(flag & (_MASKED | _EMPTY) == 0)(lambda: visit(False))
+
+    @pl.when(flag & _LAST != 0)
+    def _last_visit_of_the_query_block():
+        l = l_ref[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        lse_ref[0] = jnp.transpose(m_ref[...] * scale + jnp.log(l_safe))
+
+
+def _flash_fwd_streamed(q3, k3, v3, *, heads: int, kv_heads: int,
+                        scale: float, causal: bool, q_offset: int,
+                        kv_offset: int, block_q: int, block_k: int,
+                        interpret: bool = False,
+                        window: Optional[int] = None):
+    """``_flash_fwd`` for a head whose K and V do not fit VMEM whole
+    (``Blocks.streamed``): the same arguments and results. The grid's
+    second dimension walks ``stream_visits`` and each visit brings ONE
+    key block in, so VMEM holds a query block, a key and a value block
+    (double-buffered by the pipeline) and the float32 carry, whatever
+    the sequence; what the resident form saves, the K and V of a head
+    read once for all its query blocks, is given up. The kernel is told
+    the log-sum-exp first and the output second, [B*H, 1, Sq] float32
+    then [B*H, Sq, Dv]: by that order, the reverse of the resident
+    form's, the benchmark's trace reader knows this form
+    (benchmark/readers/smallthinker.py:STREAMED) and the resident
+    form's reader does not. The walk is three int32 a visit in SMEM
+    (528 visits at 16,384 x 16,384 in blocks of 512)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, sq, d = q3.shape
+    skv, dv = k3.shape[1], v3.shape[2]
+    rep = heads // kv_heads
+    walk = stream_visits(sq, skv, block_q, block_k, causal=causal,
+                         q_offset=q_offset, kv_offset=kv_offset,
+                         window=window)
+
+    def q_index(i, p, qi, kj, flag):
+        return (i, qi[p], 0)
+
+    def kv_index(i, p, qi, kj, flag):
+        return ((i // heads) * kv_heads + (i % heads) // rep, kj[p], 0)
+
+    kernel = functools.partial(
+        _flash_fwd_streamed_kernel, scale=scale, q_offset=q_offset,
+        kv_offset=kv_offset, window=window)
+    lse, o3 = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(bh, len(walk[0])),
+            in_specs=[pl.BlockSpec((1, block_q, d), q_index),
+                      pl.BlockSpec((1, block_k, d), kv_index),
+                      pl.BlockSpec((1, block_k, dv), kv_index)],
+            out_specs=(
+                pl.BlockSpec((1, 1, block_q),
+                             lambda i, p, qi, kj, flag: (i, 0, qi[p])),
+                pl.BlockSpec((1, block_q, dv), q_index)),
+            scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32),
+                            pltpu.VMEM((block_q, dv), jnp.float32)],
+        ),
+        out_shape=(jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
+                   jax.ShapeDtypeStruct((bh, sq, dv), q3.dtype)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="flash_fwd_streamed",
+        interpret=interpret,
+    )(*(jnp.asarray(w, jnp.int32) for w in walk), q3, k3, v3)
+    return o3, lse
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -506,6 +684,10 @@ def _reference(q, k, v, *, causal, scale, q_offset, kv_offset, window=None):
                          window=window)
 
 
+def _forward_kernel(blocks: Blocks):
+    return _flash_fwd_streamed if blocks.streamed else _flash_fwd
+
+
 def _to_heads3(x):
     """[B, S, H, D] -> [B*H, S, D]."""
     B, S, H, D = x.shape
@@ -519,7 +701,7 @@ def _flash_attention_core(q, k, v, causal, scale, q_offset, kv_offset,
                           blocks, interpret=False, window=None):
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
-    o3, _lse = _flash_fwd(
+    o3, _lse = _forward_kernel(blocks)(
         _to_heads3(q), _to_heads3(k), _to_heads3(v),
         heads=H, kv_heads=Hkv, scale=scale, causal=causal,
         q_offset=q_offset, kv_offset=kv_offset,
@@ -545,7 +727,7 @@ def _core_fwd(q, k, v, causal, scale, q_offset, kv_offset, blocks,
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     q3, k3, v3 = _to_heads3(q), _to_heads3(k), _to_heads3(v)
-    o3, lse = _flash_fwd(
+    o3, lse = _forward_kernel(blocks)(
         q3, k3, v3, heads=H, kv_heads=Hkv, scale=scale, causal=causal,
         q_offset=q_offset, kv_offset=kv_offset,
         block_q=blocks.fwd[0], block_k=blocks.fwd[1], interpret=interpret,
@@ -611,11 +793,11 @@ def flash_attention(
     scale = scale if scale is not None else D ** -0.5
 
     blocks = None
-    if D > 256 or H % Hkv:
+    if block_q is None and block_k is None:
+        blocks = _blocks_of(Sq, Skv, D, v.shape[3], H, Hkv,
+                            q.dtype.itemsize)
+    elif D > 256 or H % Hkv:
         pass
-    elif block_q is None and block_k is None:
-        blocks = choose_blocks(Sq, Skv, D, v.shape[3], H // Hkv,
-                               q.dtype.itemsize)
     else:
         named = (block_q or _MIN_BLOCK, block_k or _MIN_BLOCK)
         if Sq % named[0] == 0 and Skv % named[1] == 0:
@@ -630,6 +812,27 @@ def flash_attention(
         q, k, v, causal, scale, q_offset, kv_offset, blocks, interpret,
         window,
     )
+
+
+def _blocks_of(sq, skv, d, dv, heads, kv_heads, itemsize):
+    """``choose_blocks`` for the heads the kernel takes: of at most 256
+    and whole GQA groups; None for the others."""
+    if d > 256 or heads % kv_heads:
+        return None
+    return choose_blocks(sq, skv, d, dv, heads // kv_heads, itemsize)
+
+
+def forward_path(sq: int, skv: int, d: int, dv: int, heads: int,
+                 kv_heads: int, itemsize: int) -> str:
+    """What ``flash_attention`` runs forward for these shapes where no
+    caller names a block: ``"resident"`` or ``"streamed"`` (the two
+    forms of the Pallas kernel), or ``"einsum"`` (off a TPU, and the
+    shapes the kernel does not tile). ``LLMEngine.stats()`` counts a
+    prefill's bucket by it."""
+    blocks = _blocks_of(sq, skv, d, dv, heads, kv_heads, itemsize)
+    if blocks is None or not _on_tpu():
+        return "einsum"
+    return "streamed" if blocks.streamed else "resident"
 
 
 def _on_tpu() -> bool:

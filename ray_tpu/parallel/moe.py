@@ -7,6 +7,15 @@ plus an optional selection bias that is not in the gate, the chosen
 gates renormalised to sum to one or left as they fall, and a scale. The
 default is OLMoE's: softmax, no bias, ``norm_topk_prob=false``, scale 1.
 
+Where the router reads is the model's to state too. :func:`dispatch` is
+everything that depends on the router's input alone: the logits, the
+top k, the sort into groups and the grouped matmul's walk.
+:func:`moe_ffn` makes it from its own input, the FFN's normed one, as
+OLMoE, Trinity, JoyAI and GLM route; or it is handed one made elsewhere
+(``routed=``): SmallThinker's router reads the ATTENTION's normed input,
+so ``llama.block`` dispatches before the attention and nothing of the
+route waits for it (PR 57).
+
 One path, for training, prefill and decode: the ``k * T`` (token, expert)
 assignments are sorted by expert, the tokens' rows gathered in that
 order, and the three expert matmuls run as grouped matmuls over
@@ -33,7 +42,7 @@ kernel is ``ragged_dot``'s (a ``custom_vjp``).
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +76,88 @@ def route(logits: jax.Array, k: int, *, score: str = "softmax",
     return scores, gates, experts
 
 
+class Routed(NamedTuple):
+    """What :func:`dispatch` hands the expert layer: of ``T`` tokens'
+    ``k * T`` assignments the gates [T, k] float32 (0 where an
+    assignment reaches no expert here), their stable order by expert
+    [k * T], the rows a group holds [E] int32, which sorted rows lie in
+    a group at all ([k * T, 1] bool, or None where every one does), the
+    Switch load-balancing loss, with ``held`` the assignments that went
+    elsewhere (else None), and the grouped matmul over those groups
+    (ops/grouped_matmul.py: its walk is made with it)."""
+    gates: jax.Array
+    order: jax.Array
+    group_sizes: jax.Array
+    in_a_group: Optional[jax.Array]
+    aux: jax.Array
+    elsewhere: Optional[jax.Array]
+    matmul: Callable
+
+
+def dispatch(
+    x: jax.Array,           # [B, S, M]: what the router reads
+    router_w: jax.Array,    # [M, E]
+    *,
+    k: int = 2,
+    token_mask: Optional[jax.Array] = None,  # [B, S] 1=route, 0=ignore
+    layer: Optional[jax.Array] = None,  # [] int32: the weights are stacks
+    stack_layers: Optional[int] = None,  # of this many layers
+    mesh=None,
+    held: Optional[Tuple[int, int]] = None,
+    **routing,              # route()'s: score, select_bias, renormalize, scale
+) -> Routed:
+    """The route of x's tokens, and everything of the expert layer that
+    follows from it without the experts' own input: see :class:`Routed`
+    and :func:`moe_ffn`, whose arguments these are."""
+    B, S, M = x.shape
+    E = router_w.shape[1]
+    T = B * S
+    elsewhere = None
+    with jax.named_scope("moe.route"):
+        router_logits = jnp.einsum(
+            "tm,me->te", x.reshape(T, M).astype(jnp.float32),
+            router_w.astype(jnp.float32))
+        probs, gates, experts = route(router_logits, k, **routing)
+        if held is not None:
+            # From here on "expert" is an index into the held stack, E
+            # their number, and ``E`` itself the expert that does not
+            # exist (below), where assignments held elsewhere go too.
+            first, count = held
+            here = (experts >= first) & (experts < first + count)
+            assigned = k * (T if token_mask is None
+                            else token_mask.sum().astype(jnp.int32))
+            experts = jnp.where(here, experts - first, count)
+            gates = gates * here
+            probs = probs[:, first:first + count]
+            E = count
+        chosen = jax.nn.one_hot(experts, E, dtype=jnp.float32).sum(axis=1)
+        if token_mask is not None:
+            live = token_mask.reshape(T).astype(bool)
+            chosen = chosen * live[:, None]
+            # Expert E does not exist: the masked rows sort last, behind
+            # every group.
+            experts = jnp.where(live[:, None], experts, E)
+            gates = gates * live[:, None]
+        expert_tokens = chosen.sum(axis=0)                     # [E]
+        # Switch aux loss: E * sum_e f_e * p_e (share routed x mean prob).
+        aux = E * jnp.sum(expert_tokens / (k * T) * probs.mean(axis=0))
+        group_sizes = expert_tokens.astype(jnp.int32)
+        order = jnp.argsort(experts.reshape(T * k))            # stable
+        in_a_group = None
+        if token_mask is not None or held is not None:
+            in_a_group = (jnp.arange(T * k) < group_sizes.sum())[:, None]
+        if held is not None:
+            elsewhere = assigned - group_sizes.sum()
+        groups = group_sizes
+        if layer is not None:
+            groups = jnp.zeros((stack_layers, E), jnp.int32).at[layer].set(
+                group_sizes).reshape(stack_layers * E)
+        matmul = grouped_matmul(T * k, groups, experts=E,
+                                mesh=mesh or _current_mesh())
+    return Routed(gates, order, group_sizes, in_a_group, aux, elsewhere,
+                  matmul)
+
+
 def moe_ffn(
     x: jax.Array,           # [B, S, M]
     router_w: jax.Array,    # [M, E]
@@ -80,11 +171,20 @@ def moe_ffn(
     layer: Optional[jax.Array] = None,  # [] int32: the weights are stacks
     mesh=None,              # the mesh the program is partitioned over
     held: Optional[Tuple[int, int]] = None,  # (first, count): None is all
+    routed: Optional[Routed] = None,  # a dispatch made elsewhere
     **routing,              # route()'s: score, select_bias, renormalize, scale
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (output [B,S,M], Switch load-balancing loss, tokens
     assigned to each expert [E] int32). Masked rows give zero, reach no
     expert and are not counted.
+
+    ``routed``: the :func:`dispatch` of these tokens where the router
+    read something else than ``x`` (made by the caller with the same
+    ``k``, ``token_mask``, ``layer``, ``held`` and routing; ``router_w``
+    is then unread). None: the router reads ``x``.
+
+    ``activation`` is the gate's (SiLU: SwiGLU; ``jax.nn.relu``: ReGLU),
+    applied in float32 as the grouped matmul's epilogue.
 
     ``held`` = (first, count): this chip holds experts ``first ..
     first + count`` of the router's ``E = router_w.shape[1]``, one of
@@ -112,56 +212,27 @@ def moe_ffn(
     passes its own; else the context's, as ``with_logical_constraint``):
     one of the things ``grouped_path`` chooses the grouped matmul by."""
     B, S, M = x.shape
-    E = router_w.shape[1]
     T = B * S
     xt = x.reshape(T, M)
+    if routed is None:
+        routed = dispatch(
+            x, router_w, k=k, token_mask=token_mask, layer=layer,
+            stack_layers=None if layer is None else w_in.shape[0],
+            mesh=mesh, held=held, **routing)
+    gates, order, group_sizes, in_a_group = (
+        routed.gates, routed.order, routed.group_sizes, routed.in_a_group)
     with jax.named_scope("moe.route"):
-        router_logits = jnp.einsum(
-            "tm,me->te", xt.astype(jnp.float32), router_w.astype(jnp.float32)
-        )
-        probs, gates, experts = route(router_logits, k, **routing)
-        if held is not None:
-            # From here on "expert" is an index into the held stack, E
-            # their number, and ``E`` itself the expert that does not
-            # exist (below), where assignments held elsewhere go too.
-            first, count = held
-            here = (experts >= first) & (experts < first + count)
-            assigned = k * (T if token_mask is None
-                            else token_mask.sum().astype(jnp.int32))
-            experts = jnp.where(here, experts - first, count)
-            gates = gates * here
-            probs = probs[:, first:first + count]
-            E = count
-        chosen = jax.nn.one_hot(experts, E, dtype=jnp.float32).sum(axis=1)
-        if token_mask is not None:
-            live = token_mask.reshape(T).astype(bool)
-            chosen = chosen * live[:, None]
-            # Expert E does not exist: the masked rows sort last, behind
-            # every group.
-            experts = jnp.where(live[:, None], experts, E)
-            gates = gates * live[:, None]
-        expert_tokens = chosen.sum(axis=0)                     # [E]
-        # Switch aux loss: E * sum_e f_e * p_e (share routed x mean prob).
-        aux = E * jnp.sum(expert_tokens / (k * T) * probs.mean(axis=0))
-        group_sizes = expert_tokens.astype(jnp.int32)
-        order = jnp.argsort(experts.reshape(T * k))            # stable
         rows = xt[order // k]                                  # [k*T, M]
-        unrouted = token_mask is not None or held is not None
-        if unrouted:
+        if in_a_group is not None:
             # Rows behind the last group: a grouped matmul leaves there
             # whatever the backend does (zeros on the CPU, not on the
             # TPU: chip run, PR 28), forward and backward, so they are
             # cut off on the way in and on the way out.
-            routed = (jnp.arange(T * k) < group_sizes.sum())[:, None]
-            rows = jnp.where(routed, rows, 0)
+            rows = jnp.where(in_a_group, rows, 0)
     with jax.named_scope("moe.experts"):
-        groups = group_sizes
         if layer is not None:
-            L = w_in.shape[0]
-            groups = jnp.zeros((L, E), jnp.int32).at[layer].set(
-                group_sizes).reshape(L * E)
             w_in, w_out, w_gate = (
-                None if w is None else w.reshape((L * E,) + w.shape[2:])
+                None if w is None else w.reshape((-1,) + w.shape[2:])
                 for w in (w_in, w_out, w_gate))
 
         def activate(h, g=None):
@@ -169,13 +240,11 @@ def moe_ffn(
                 return activation(h)
             return activation(g.astype(jnp.float32)).astype(h.dtype) * h
 
-        matmul = grouped_matmul(T * k, groups, experts=E,
-                                mesh=mesh or _current_mesh())
-        h = matmul(rows, (w_in,) if w_gate is None else (w_in, w_gate),
-                   activate)
-        y = matmul(h, (w_out,))                                # [k*T, M]
-        if unrouted:
-            y = jnp.where(routed, y, 0)
+        h = routed.matmul(
+            rows, (w_in,) if w_gate is None else (w_in, w_gate), activate)
+        y = routed.matmul(h, (w_out,))                         # [k*T, M]
+        if in_a_group is not None:
+            y = jnp.where(in_a_group, y, 0)
     with jax.named_scope("moe.combine"):
         # Back to token order (a gather by the inverse permutation, no
         # scatter-add), then the k results of a token weighted by its
@@ -183,7 +252,7 @@ def moe_ffn(
         back = jnp.argsort(order)
         out = jnp.einsum("tkm,tk->tm", y[back].reshape(T, k, M), gates,
                          preferred_element_type=jnp.float32)
-    if held is not None:
+    if routed.elsewhere is not None:
         group_sizes = jnp.concatenate(
-            [group_sizes, (assigned - group_sizes.sum())[None]])
-    return out.astype(x.dtype).reshape(B, S, M), aux, group_sizes
+            [group_sizes, routed.elsewhere[None]])
+    return out.astype(x.dtype).reshape(B, S, M), routed.aux, group_sizes

@@ -518,7 +518,7 @@ class _Runner:
                  new_cache, lock: threading.Lock):
         import jax
 
-        from ..models.llama import layer_runs
+        from ..models.llama import layer_runs, prefill_attention_path
         from ..ops.grouped_matmul import grouped_path
         from ..util.device_metrics import instrumented_jit
 
@@ -541,6 +541,11 @@ class _Runner:
                                                    np.int64)}
             if cfg.experts_held:
                 self._moe["assignments_elsewhere"] = 0
+        # Bucket tokens of the prefills whose attention over k and v
+        # rows ran the flash forward's streamed form.
+        self._streamed_bucket_tokens = 0
+        self._streamed = lambda bucket: prefill_attention_path(
+            cfg, bucket) == "streamed"
         # What moe_ffn asked when a program of so many tokens was traced.
         self._small_rows = lambda tokens: grouped_path(
             tokens * cfg.top_k, cfg.experts_here) == "small_rows"
@@ -593,7 +598,11 @@ class _Runner:
             {kind: jnp.asarray(ids, dtype=jnp.int32)
              for kind, ids in pages.items()},
         )
-        return int(self.unpack(np.asarray(first).reshape(-1), 1, bucket)[0])
+        first = self.unpack(np.asarray(first).reshape(-1), 1, bucket)[0]
+        if self._streamed(bucket):
+            with self._lock:
+                self._streamed_bucket_tokens += bucket
+        return int(first)
 
     def activate(self, slots) -> None:
         """``slots`` (a set of them) decode in the next step: the mask
@@ -655,6 +664,7 @@ class _Runner:
             # stats is a device number only on a "tpu".
             "platform": self._device.platform,
             "device_kind": self._device.device_kind,
+            "prefill_streamed_bucket_tokens": self._streamed_bucket_tokens,
             **({"moe": {**self._moe, "expert_tokens":
                         self._moe["expert_tokens"].tolist()}}
                if self._moe else {}),
@@ -764,7 +774,11 @@ class LLMEngine:
         ``kv_page_steps_one_table`` (what they would have held with one
         table for every layer: the same number without window layers);
         ``prefills``, ``prefill_tokens`` (real) and
-        ``prefill_bucket_tokens`` (padded to the bucket); ``submitted``,
+        ``prefill_bucket_tokens`` (padded to the bucket), of which
+        ``prefill_streamed_bucket_tokens`` in prefills whose attention
+        over k and v rows streamed K and V by block
+        (ops/flash_attention.py ``forward_path``: a bucket whose K and V
+        of a head do not fit VMEM whole; 0 off a TPU); ``submitted``,
         ``admitted``, ``finished``, ``failed`` (requests); ``cache_resets``;
         ``page_waits`` (admission rounds that stopped for want of pages);
         ``decode_steps_ahead`` (decode steps dispatched while the step

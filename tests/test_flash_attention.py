@@ -413,3 +413,137 @@ def test_flash_block_choice_is_a_pure_function_of_the_shape():
     # float32 callers and unequal lengths are tiled by the same rule.
     assert choose(256, 512, 64, 64, 2, 4).fwd == (256, 512)
     assert choose(384, 384, 128, 128, 1, 2).fwd == (128, 128)
+
+
+# ---- the streamed forward: K and V a block at a time (PR 57) ---------------
+
+def _streamed(q, k, v, *, block_q, block_k, window=None, q_offset=0,
+              kv_offset=0):
+    """``_flash_fwd_streamed`` on [B, S, H, D] arrays, as
+    ``flash_attention`` calls it; (o [B, S, H, Dv], lse [B*H, 1, S])."""
+    B, Sq, H, D = q.shape
+    o3, lse = fa._flash_fwd_streamed(
+        fa._to_heads3(q), fa._to_heads3(k), fa._to_heads3(v), heads=H,
+        kv_heads=k.shape[2], scale=D ** -0.5, causal=True,
+        q_offset=q_offset, kv_offset=kv_offset, block_q=block_q,
+        block_k=block_k, interpret=True, window=window)
+    return o3.reshape(B, H, Sq, v.shape[3]).transpose(0, 2, 1, 3), lse
+
+
+@pytest.mark.parametrize("window", [None, 1, 100, 128, 300, 4096])
+def test_streamed_forward_matches_the_masked_einsum(window):
+    """The streamed form at small blocks, groups of 7 query heads a KV
+    head: full and window masks, windows under a block, of a block and
+    over several; equal to the XLA einsum, and its output and
+    log-sum-exp bit for bit the resident form's at the same blocks."""
+    B, S, H, Hkv, D = 1, 512, 7, 1, 64
+    q, k, v = (_rand((B, S, H, D), 0), _rand((B, S, Hkv, D), 1),
+               _rand((B, S, Hkv, D), 2))
+    out, lse = _streamed(q, k, v, block_q=128, block_k=128, window=window)
+    ref = mha_attention(q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    o3, lse_resident = fa._flash_fwd(
+        fa._to_heads3(q), fa._to_heads3(k), fa._to_heads3(v), heads=H,
+        kv_heads=Hkv, scale=D ** -0.5, causal=True, q_offset=0, kv_offset=0,
+        block_q=128, block_k=128, interpret=True, window=window)
+    np.testing.assert_array_equal(
+        np.asarray(out),
+        np.asarray(o3.reshape(B, H, S, D).transpose(0, 2, 1, 3)))
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse_resident))
+
+
+@pytest.mark.parametrize("blocks,window,q_offset,kv_offset", [
+    ((128, 256), 300, 500, 0),     # a decode-like slice of queries
+    ((256, 128), None, 128, 64),   # unequal blocks, both offsets
+    ((128, 128), None, 0, 300),    # query blocks that attend to nothing
+])
+def test_streamed_forward_offsets_and_empty_blocks(blocks, window, q_offset,
+                                                   kv_offset):
+    """Global coordinates, as the resident form: offsets move the
+    diagonal, and a query block before every key is written as zeros
+    (its one visit multiplies nothing)."""
+    B, Sq, Skv, H, Hkv, D = 1, 256, 768, 2, 2, 64
+    q, k, v = (_rand((B, Sq, H, D), 0), _rand((B, Skv, Hkv, D), 1),
+               _rand((B, Skv, Hkv, D), 2))
+    out, _ = _streamed(q, k, v, block_q=blocks[0], block_k=blocks[1],
+                       window=window, q_offset=q_offset, kv_offset=kv_offset)
+    resident = flash_attention(
+        q, k, v, causal=True, window=window, q_offset=q_offset,
+        kv_offset=kv_offset, block_q=blocks[0], block_k=blocks[1],
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(resident))
+    if kv_offset > q_offset + Sq:
+        assert not np.asarray(out)[:, :kv_offset - q_offset].any()
+
+
+def test_a_window_layers_query_block_fetches_only_its_windows_blocks():
+    """The walk at the cell's shape, 16,384 x 16,384 in blocks of 512: a
+    full layer's query block i visits key blocks 0 .. i (528 visits),
+    a window-4096 layer's only those that t - 4096 < j <= t touches, nine
+    at most (252 visits); first and last visits flagged once a query
+    block, and only the blocks an edge crosses masked."""
+    full = fa.stream_visits(16384, 16384, 512, 512, causal=True)
+    assert len(full[0]) == 32 * 33 // 2
+    q_blocks, k_blocks, flags = fa.stream_visits(
+        16384, 16384, 512, 512, causal=True, window=4096)
+    assert len(q_blocks) == sum(min(i, 8) + 1 for i in range(32)) == 252
+    for i in range(32):
+        mine = [(k, f) for q, k, f in zip(q_blocks, k_blocks, flags)
+                if q == i]
+        assert [k for k, _ in mine] == list(range(max(0, i - 8), i + 1))
+        assert [bool(f & fa._FIRST) for _, f in mine] == \
+            [True] + [False] * (len(mine) - 1)
+        assert [bool(f & fa._LAST) for _, f in mine] == \
+            [False] * (len(mine) - 1) + [True]
+        # The diagonal block, and the one the window's edge crosses.
+        masked = [k for k, f in mine if f & fa._MASKED]
+        assert masked == ([i - 8, i] if i >= 8 else [i])
+    # A window that is no whole number of blocks reaches one block more.
+    assert len(fa.stream_visits(2048, 2048, 512, 512, causal=True,
+                                window=600)[0]) == 1 + 2 + 3 + 3
+
+
+def test_flash_streams_what_the_resident_form_cannot_hold():
+    """``flash_attention`` itself, at a sequence whose K and V of a head
+    are over the VMEM budget whole (float32, 8,192 keys of 128 + 128:
+    16.8 MB double-buffered): it builds the streamed call, full and
+    window, and a slice of queries at the sequence's end equals the
+    einsum; one block under that length it builds the resident call."""
+    from benchmark.readers.smallthinker import STREAMED
+    from benchmark.readers.window import FLASH
+
+    S, H, Hkv, D = 8192, 7, 1, 128
+    assert fa.choose_blocks(S, S, D, D, 7, 4) == fa.Blocks(
+        fwd=(512, 512), dq=(128, 128), dkv=(128, 128), streamed=True)
+    assert not fa.choose_blocks(S // 2, S // 2, D, D, 7, 4).streamed
+    assert fa.choose_blocks(16384, 16384, 128, 128, 7, 2) == fa.Blocks(
+        fwd=(512, 512), dq=(128, 128), dkv=(128, 128), streamed=True)
+
+    def name_of(s, window):
+        def arr(heads):
+            return jax.ShapeDtypeStruct((1, s, heads, D), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, interpret=True))(
+                arr(H), arr(Hkv), arr(Hkv))
+        (call,) = _pallas_calls(jaxpr.jaxpr)
+        return "pallas_" + "_".join(
+            "_".join(["f32", *map(str, v.aval.shape)]) for v in call.outvars)
+
+    for window in (None, 4096):
+        streamed, resident = name_of(S, window), name_of(S // 2, window)
+        assert streamed == f"pallas_f32_{H}_1_{S}_f32_{H}_{S}_{D}"
+        assert STREAMED.match(streamed) and not FLASH.match(streamed)
+        assert FLASH.match(resident) and not STREAMED.match(resident)
+
+    # The last 256 queries against all 8,192 keys: 17 visits of 512 keys.
+    q = _rand((1, 256, H, D), 0)
+    k, v = _rand((1, S, Hkv, D), 1), _rand((1, S, Hkv, D), 2)
+    assert fa.choose_blocks(256, S, D, D, 7, 4).streamed
+    for window in (None, 4096):
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              q_offset=S - 256, interpret=True)
+        ref = mha_attention(q, k, v, causal=True, window=window,
+                            q_offset=S - 256)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)

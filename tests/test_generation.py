@@ -63,6 +63,11 @@ _PAGED_CASES = {
     "layer_last_of_3": (4, 2, "float32", [20, 143], [True, True], 10, 3, 2),
     "mha_hkv16_bf16": (16, 16, "bfloat16", [31, 144, 7],
                        [True, True, False], 10, 2, 1),
+    # 28 rows of queries: no whole number of sublane tiles (8 of
+    # float32, 16 of bfloat16), and groups of 7 (SmallThinker).
+    "gqa_rep7_hkv4": (28, 4, "float32", [5, 131, 159], [True] * 3, 10, 2, 1),
+    "gqa_rep7_hkv4_bf16": (28, 4, "bfloat16", [0, 100, 159, 16],
+                           [True, True, True, False], 10, 2, 0),
 }
 
 
@@ -127,6 +132,8 @@ _WINDOW_CASES = {
     "far_over_wrapping": ([47, 48, 200, 1000], 32),
     "window_no_page_multiple": ([70, 129, 7], 40),
     "an_idle_slot": ([300, 90], 64),
+    # 28 query heads on 4 (the third and fourth entries: H, Hkv).
+    "groups_of_7": ([47, 300, 33], 32, 28, 4),
 }
 
 
@@ -144,8 +151,8 @@ def test_window_decode_attention_matches_the_masked_einsum(path, case):
     wrapped its ring many times. A slot's unused columns hold page 0."""
     from ray_tpu.ops import paged_attention as pa
 
-    lengths, window = _WINDOW_CASES[case]
-    B, H, Hkv, D, page, layer = len(lengths), 4, 2, 128, 16, 1
+    lengths, window, H, Hkv = (*_WINDOW_CASES[case], 4, 2)[:4]
+    B, D, page, layer = len(lengths), 128, 16, 1
     columns = pa.ring_pages(window, page, 4096)
     assert columns == -(-window // page) + 1
     active = np.ones(B, bool)

@@ -358,3 +358,97 @@ def test_without_held_the_layer_is_bit_for_bit_what_it_was(name, want):
     for result in results:
         digest.update(np.asarray(result).tobytes())
     assert digest.hexdigest()[:16] == want
+
+
+# ---- a route made elsewhere, and the gate's activation (PR 57) -------------
+
+def _routed_elsewhere(h, x, router, w_up, w_gate, w_down, k, act, mask=None):
+    """The per-token loop with the route read from ``h`` and the experts
+    applied to ``x``: softmax gates (not renormalised) of h's top k."""
+    ht, xt = h.reshape(-1, M), x.reshape(-1, M)
+    probs = jax.nn.softmax(ht @ router, axis=-1)
+    choices = _choices(h, router, k)
+    rows = []
+    for t in range(xt.shape[0]):
+        total = jnp.zeros((M,), jnp.float32)
+        if mask is None or mask[t]:
+            for e in choices[t]:
+                y = act(xt[t] @ w_gate[e]) * (xt[t] @ w_up[e])
+                total = total + probs[t, e] * (y @ w_down[e])
+        rows.append(total)
+    return jnp.stack(rows).reshape(x.shape), choices
+
+
+@pytest.mark.parametrize("act", ["silu", "relu"])
+@pytest.mark.parametrize("E,k,T", CASES[:3], ids=IDS[:3])
+def test_a_route_made_elsewhere_multiplies_the_layers_own_input(E, k, T, act):
+    """``dispatch(h)`` then ``moe_ffn(x, routed=)``: the experts each
+    token of h chose multiply the same token of x, weighted by h's gates;
+    the load is h's; SiLU or ReLU on the gate. Not the route of x."""
+    from ray_tpu.parallel.moe import dispatch
+
+    fn = {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
+    h, x = _tokens(T, 5), _tokens(T, 6)
+    router, w_up, w_gate, w_down = _weights(E, 7)
+    mask = np.arange(T) != 1
+
+    def layer(h, x, mask):
+        routed = dispatch(h, router, k=k, token_mask=mask)
+        return moe_ffn(x, None, w_up, w_down, k=k, w_gate=w_gate,
+                       activation=fn, routed=routed)
+
+    out, _, load = jax.jit(layer)(h, x, jnp.asarray(mask)[None])
+    want, choices = _routed_elsewhere(h, x, router, w_up, w_gate, w_down, k,
+                                      fn, mask)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-6, rtol=1e-5)
+    np.testing.assert_array_equal(
+        np.asarray(load), np.bincount(choices[mask].ravel(), minlength=E))
+    own, _, _ = moe_ffn(x, router, w_up, w_down, k=k, w_gate=w_gate,
+                        activation=fn, token_mask=jnp.asarray(mask)[None])
+    assert np.abs(np.asarray(own) - np.asarray(out)).max() > 1e-3
+
+
+def test_a_route_made_elsewhere_reads_the_stack_in_place():
+    """With ``layer`` the dispatch lays the groups at the layer's place
+    in the stack, as ``moe_ffn`` does when it routes itself: the same
+    numbers as that layer's own slice."""
+    from ray_tpu.parallel.moe import dispatch
+
+    E, k, T, L = 8, 2, 6, 3
+    h, x = _tokens(T, 8), _tokens(T, 9)
+    stacks = [_weights(E, 10 + i) for i in range(L)]
+    router = stacks[1][0]
+    w_up, w_gate, w_down = (jnp.stack([s[i] for s in stacks])
+                            for i in (1, 2, 3))
+    layer = jnp.asarray(1, jnp.int32)
+    routed = dispatch(h, router, k=k, layer=layer, stack_layers=L)
+    got, _, load = moe_ffn(x, None, w_up, w_down, k=k, w_gate=w_gate,
+                           layer=layer, routed=routed)
+    want, _, want_load = moe_ffn(
+        x, None, w_up[1], w_down[1], k=k, w_gate=w_gate[1],
+        routed=dispatch(h, router, k=k))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(want_load))
+
+
+def test_routing_its_own_input_gives_what_it_gave():
+    """``moe_ffn`` without ``routed`` is ``moe_ffn(routed=dispatch(x))``:
+    output, loss and load bit for bit, and no matmul or sort more."""
+    from ray_tpu.parallel.moe import dispatch
+
+    x = _tokens(7, 1)
+    router, w_up, w_gate, w_down = _weights(8, 2)
+
+    def own(x):
+        return moe_ffn(x, router, w_up, w_down, k=2, w_gate=w_gate)
+
+    def handed(x):
+        return moe_ffn(x, None, w_up, w_down, k=2, w_gate=w_gate,
+                       routed=dispatch(x, router, k=2))
+
+    for a, b in zip(jax.jit(own)(x), jax.jit(handed)(x)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    texts = [str(jax.make_jaxpr(f)(x)) for f in (own, handed)]
+    for op in ("dot_general", "ragged_dot", "sort", "top_k"):
+        assert texts[0].count(op) == texts[1].count(op)

@@ -1016,8 +1016,8 @@ _STATS_KEYS = [
     "finished", "free_pages", "free_slots", "kv_page_steps_held",
     "kv_page_steps_one_table", "kv_row_bytes", "page_size", "page_waits",
     "pages", "phase_s", "platform", "prefill_bucket_tokens",
-    "prefill_tokens", "prefills", "queued", "requests", "state_slot_bytes",
-    "stream", "submitted", "t", "total_pages"]
+    "prefill_streamed_bucket_tokens", "prefill_tokens", "prefills", "queued",
+    "requests", "state_slot_bytes", "stream", "submitted", "t", "total_pages"]
 _NESTED_KEYS = {
     "phase_s": ["admit", "admit_stalling", "decode", "emit", "idle",
                 "inputs", "readback"],

@@ -905,3 +905,102 @@ def test_brumby_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 4 * math.prod(STATE_POOL)
     print(bucket, memory.temp_size_in_bytes / 2**30, "GiB of temporaries")
+
+
+# ---- SmallThinker: 28 heads on 4, F S S S, a 16,384 bucket (PR 57) ---------
+
+def _smallthinker():
+    """``smallthinker-21b-a3b-L8`` as the benchmark builds it, and its
+    engine."""
+    import json
+
+    from benchmark import arch
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "smallthinker-21b-a3b-L8.json")) as f:
+        config = json.load(f)
+    return arch.program_config(config), config["engine"]
+
+
+def _smallthinker_shapes(v5e):
+    cfg, engine = _smallthinker()
+    params, cache = _serve_shapes(
+        cfg, v5e, engine["max_batch"], engine["total_pages"],
+        engine["max_len"] // PAGE)
+    return cfg, engine, params, cache
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_streamed_flash_forward_compiles_for_v5e(v5e, as_tpu, window):
+    """16,384 keys of a head are over the VMEM a kernel gets without
+    asking: the forward streams them, 28 query heads on 4, full and
+    window, and asks for no more (no ``vmem_limit_bytes``); it writes
+    the log-sum-exp first, by which the trace reader knows this form."""
+    S, heads, kv_heads = 16384, 28, 4
+    assert flash_mod.forward_path(S, S, D, D, heads, kv_heads, 2) == "streamed"
+    assert flash_mod.forward_path(8192, 8192, D, D, heads, kv_heads,
+                                  2) == "resident"
+    compiled = jax.jit(lambda q, k, v: flash_mod.flash_attention(
+        q, k, v, causal=True, window=window)).lower(
+            _arr(v5e, (1, S, heads, D)), _arr(v5e, (1, S, kv_heads, D)),
+            _arr(v5e, (1, S, kv_heads, D))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "vmem_limit_bytes" not in text
+    call, = [m for m in _HLO_INSTRUCTION.finditer(text)
+             if m["op"] == "custom-call"]
+    assert re.match(r"\(f32\[28,1,16384\]\S*, bf16\[28,16384,128\]",
+                    call["result"]), call["result"]
+
+
+def test_smallthinker_decode_program_compiles_for_v5e(v5e, as_tpu):
+    """Two pools, four scans (F, S S S, F, S S S): six window layers over
+    rings of 257 pages a slot and two full layers over the 16,384-page
+    pool, groups of 7 query heads in the page walk, 64 ReGLU experts
+    read in place. Neither pool is copied, sliced or re-stacked."""
+    cfg, engine, params, cache = _smallthinker_shapes(v5e)
+    assert {k: v.shape for k, v in cache.k.items()} == {
+        "full": (2, 4, 16384, PAGE, 128),
+        "window": (6, 4, 16 * 257, PAGE, 128)}
+    batch = engine["max_batch"]
+
+    def decode(params, cache, tok, active):
+        return generation.paged_decode(params, tok, cache, cfg, active=active)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (batch,), jnp.int32),
+        _arr(v5e, (batch,), jnp.bool_)).compile()
+    assert _fits_one_chip(compiled)
+    _assert_pool_stays_in_place(compiled, cache.k["full"].shape)
+    _assert_pool_stays_in_place(compiled, cache.k["window"].shape,
+                                temporaries=False)
+    pools = sum(2 * 2 * math.prod(p.shape) for p in cache.k.values())
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools
+
+
+@pytest.mark.parametrize("bucket", [8192, 16384])
+def test_smallthinker_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket):
+    """The resident form's largest bucket and the streamed form's: the
+    flash kernel on the full and the window layers, 6 x bucket rows
+    through the grouped matmuls, beside 7.9 GB of weights and both
+    pools, inside the chip."""
+    cfg, engine, params, cache = _smallthinker_shapes(v5e)
+
+    def prefill(params, cache, tokens, real_len, slot, pages):
+        return generation.paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages)
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (1, bucket), jnp.int32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
+        {"full": _arr(v5e, (bucket // PAGE,), jnp.int32),
+         "window": _arr(v5e, (257,), jnp.int32)},
+    ).compile()
+    text = compiled.as_text()
+    assert "vmem_limit_bytes" not in text
+    streamed = len(re.findall(r"f32\[28,1,16384\]\S*, bf16\[28,16384,128\]",
+                              text))
+    assert (streamed > 0) is (bucket == 16384)
+    assert _fits_one_chip(compiled)
+    memory = compiled.memory_analysis()
+    print(bucket, memory.temp_size_in_bytes / 2**30, "GiB of temporaries")
