@@ -65,7 +65,7 @@ import numpy as np
 
 from ..ops.paged_attention import (
     decode_attention, decode_attention_path, latent_decode_attention,
-    ring_pages,
+    ring_pages, walk_step_tokens,
 )
 from ..ops.retention import (
     retention_decode, retention_path, retention_prefill, state_shape,
@@ -300,6 +300,13 @@ class KVBooks:
                 self.decode_attention = "sparse_walk"
         else:
             self.decode_attention = decode_attention_path(page_size, cfg.dh)
+        # The tokens a compute step of the page walk covers in each pool
+        # it walks: what its buffers were sized by.
+        self.page_walk_step_tokens = {
+            kind: walk_step_tokens(cfg.num_kv_heads, cfg.dh, page_size,
+                                   cache.k[kind].dtype, columns)
+            for kind, (_, _, columns) in self._own.items()
+        } if self.decode_attention == "page_walk" else {}
         # What the decode steps read and held (LLMEngine.stats() says
         # what each means), summed as the steps are read.
         self.counts = dict.fromkeys((
@@ -408,6 +415,7 @@ class KVBooks:
             "total_pages": self.total_pages,
             "page_size": self.page_size,
             "decode_attention": self.decode_attention,
+            "page_walk_step_tokens": dict(self.page_walk_step_tokens),
         }
 
 

@@ -22,13 +22,22 @@ touches the ring while a slot decodes.
 Two implementations, one chosen by :func:`decode_attention_path` from
 what the code can see (platform and shape), never by a user:
 
-``page_walk`` — a Pallas TPU kernel, one program a slot. Both pools stay
-in HBM and come back as outputs aliased to the inputs; the program
-copies the slot's own pages at ``layer``, ``_BLOCK_TOKENS`` at a time
-and double-buffered, into VMEM and runs an online softmax over them.
-Its reads and arithmetic follow ``lengths``: a slot walks
-``lengths[b] // page + 1`` pages (rounded up to a block), an inactive
-slot none. The last of those pages is the one the new row belongs to:
+``page_walk`` — a Pallas TPU kernel, one program for all slots. Both
+pools stay in HBM and come back as outputs aliased to the inputs; the
+program copies each walking slot's own pages at ``layer`` into VMEM, a
+COMPUTE STEP at a time and double-buffered, and runs an online softmax
+over them. A step is as long as its bytes say, about a megabyte of K and
+V (:func:`walk_step_tokens`: 512 tokens at 4 KV heads of 128, 256 at 8,
+128 at 16), so that what a step costs beside its bytes (the turn of the
+loop, the waits, one serial softmax) is paid once a megabyte whatever
+the heads. The copies stay a page each and follow ``lengths``: a slot
+copies ``lengths[b] // page + 1`` pages (under a window, the pages its
+window touches), each once, an inactive slot none; a step that is not
+full copies what it holds and the mask covers the rest of its buffer.
+The slots' steps form ONE list that the program's one loop runs down, so
+while a slot's last step computes, the next walking slot's first copies
+are in flight, and a slot that walks nothing costs nothing. The last of
+a slot's pages is the one the new row belongs to:
 the program sets the row in VMEM before the scores and copies that one
 page (``[Hkv, page, Dh]``, a whole tile a head) back to the pool. That
 is a decode step's only write to the pool, and an inactive slot makes
@@ -37,11 +46,11 @@ where it is: a row is a sixteenth of a bf16 ``(16, 128)`` tile, and for
 a scatter or a ``dynamic_update_slice`` of it XLA re-lays the whole
 pool so that a row is a tile, and back (compiled for a v5e: four
 pool-sized copies a step; with the pool sliced by the layer scan, 70%
-of the step: PERF.md §6, PR 29). Slots own disjoint pages and the grid
+of the step: PERF.md §6, PR 29). Slots own disjoint pages and the list
 runs in order, so one slot's write meets no other slot's reads. All KV
-heads of a slot are served by one program from the slot's ``[H, Dh]``
+heads of a slot are served from the slot's ``[H, Dh]``
 queries: per KV head one bf16 matmul of all H query rows against that
-head's block, of which the rows of its own GQA group are kept — so K
+head's step, of which the rows of its own GQA group are kept — so K
 and V are never repeated, and no operand is narrower than a tile.
 Operands go to the MXU in the pool's dtype with float32 scores; running
 max, denominator and accumulator are float32.
@@ -58,13 +67,13 @@ key, zeros up to whole lane tiles. :func:`latent_decode_attention`
 attends every head's absorbed query, laid as such a row, over the
 slot's rows; the scores are ``q . row`` over all W and the values the
 rows' first ``values``, so a row is read once for both. ``latent_walk``
-is the page walk's scheme over such a pool (one program a slot, one
-copy a page with every "head" in it, the new row set in VMEM and its
-page copied back through the aliased output); per block of
-``_LATENT_BLOCK_TOKENS`` it is two matmuls of all H query rows, no GQA
-group to pick: 2 H (W + values) operations a row of 2 W bytes, some 60
-a byte at 32 heads over 576 + 512, where a k/v walk does 2. ``gather``
-is its XLA path.
+walks such a pool as the page walk walks k and v, but one program a
+slot and a fixed block (one copy a page with every "head" in it, the
+new row set in VMEM and its page copied back through the aliased
+output); per block of ``_LATENT_BLOCK_TOKENS`` it is two matmuls of
+all H query rows, no GQA group to pick: 2 H (W + values) operations a
+row of 2 W bytes, some 60 a byte at 32 heads over 576 + 512, where a
+k/v walk does 2. ``gather`` is its XLA path.
 """
 
 from __future__ import annotations
@@ -76,77 +85,148 @@ import jax
 import jax.numpy as jnp
 
 _NEG_INF = -1e30
-# Tokens a compute step covers: long enough that a layer is tens of
-# grid steps and some hundreds of copies, short enough that a chat
-# context of a few hundred tokens is not mostly padding.
-_BLOCK_TOKENS = 128
+# Bytes of K and V a compute step of the page walk covers
+# (``walk_step_tokens``): at 128 tokens a step whatever the heads the
+# walk paid a step's fixed costs (the turn of its loop, the waits, a
+# serial softmax) every 262 KB at 4 KV heads and ran at 40% of HBM's
+# rate (PERF.md §6, PR 58).
+_STEP_BYTES = 1 << 20
 
 
-def _page_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, *refs, pmax: int,
-                      scale: float, window=None):
-    """Grid (B,). pt_ref [B * Pmax], np_ref [B] (pages to walk), len_ref
-    [B], layer_ref [1] and, with a ``window``, first_ref [B] (the page
-    the walk starts at) in SMEM; q_ref/o_ref [H, D] this slot's rows;
-    kn_ref/vn_ref [Hkv, 1, D] its new K/V row; k_hbm/v_hbm the pools
-    [L, Hkv, P, page, D] left in HBM and k_out/v_out the same buffers as
-    outputs; k_buf/v_buf [2, Hkv, block, D] VMEM; sems [3, 2] DMA (k
-    and v in by buffer, then k and v back)."""
+def _page_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, slot_ref, at_ref,
+                      total_ref, *refs, pmax: int, scale: float, window=None):
+    """One program for every slot. In SMEM: pt_ref [B * Pmax], np_ref
+    [B] (pages to walk), len_ref [B], layer_ref [1], slot_ref [G] (the
+    slot of each compute step, every walking slot's steps in one list),
+    at_ref [B] (where a slot's steps start in that list), total_ref [1]
+    (the steps in all) and, with a ``window``, first_ref [B] (the page a
+    walk starts at). q_ref/o_ref [B, H, D] every slot's rows;
+    kn_ref/vn_ref [B, Hkv, 1, D] their new K/V rows; k_hbm/v_hbm the
+    pools [L, Hkv, P, page, D] left in HBM and k_out/v_out the same
+    buffers as outputs; k_buf/v_buf [2, Hkv, step, D] VMEM; sems [3, 2]
+    DMA (k and v in by buffer, then k and v back)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b = pl.program_id(0)
-    if window is None:
-        first = 0
-
-        def column(p):
-            return p
-    else:
-        # The slot's row of the table is a ring: page p of the sequence
-        # lies in column p % Pmax, and the walk starts at the page that
-        # holds the oldest position the new token still attends to.
+    first_ref = None
+    if window is not None:
         first_ref, *refs = refs
-        first = first_ref[b]
-
-        def column(p):
-            return jax.lax.rem(first + p, pmax)
     (q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref, k_out, v_out,
      k_buf, v_buf, sems) = refs
-    H, D = q_ref.shape
+    _, H, D = q_ref.shape
     _, Hkv, block, _ = k_buf.shape
     page = k_hbm.shape[3]
-    pages_per_block = block // page
+    step_pages = block // page
     layer = layer_ref[0]
-    n_pages = np_ref[b]
-    n_blocks = (n_pages + pages_per_block - 1) // pages_per_block
-    length = len_ref[b]
+    total = total_ref[0]
 
-    def copies(i, buf):
-        """The block's page copies. Past the slot's last page the last
-        one is read again: the buffer then never holds anything but
-        pool rows, so a masked probability of 0 meets no stale NaN."""
-        out = []
-        for j in range(pages_per_block):
-            p = jnp.minimum(i * pages_per_block + j, n_pages - 1)
-            pid = pt_ref[b * pmax + column(p)]
-            rows = pl.ds(j * page, page)
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[layer, :, pid], k_buf.at[buf, :, rows, :],
-                sems.at[0, buf]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[layer, :, pid], v_buf.at[buf, :, rows, :],
-                sems.at[1, buf]))
-        return out
+    # A step copies the pages it holds and no other, so rows of its
+    # buffer may be what no copy has written, and a probability of 0
+    # times a NaN is a NaN: v's buffers start as zeros (k's rows there
+    # only make scores that the mask replaces). A slot that walks
+    # nothing (inactive) gets zeros.
+    v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    # The page that takes the new row is the walk's last, in the last
-    # block's buffer.
-    last = n_blocks - 1
-    pid_new = pt_ref[b * pmax + column(jnp.maximum(n_pages - 1, 0))]
-    rows_new = pl.ds(pl.multiple_of(
-        (n_pages - 1 - last * pages_per_block) * page, page), page)
+    def step(g):
+        """The list's step g: (its slot, which of the slot's steps it
+        is, the pages it holds, the table column of the first)."""
+        b = slot_ref[g]
+        i = g - at_ref[b]
+        held = jnp.minimum(step_pages, np_ref[b] - i * step_pages)
+        column = i * step_pages
+        if window is not None:
+            # The slot's row of the table is a ring: page p of the
+            # sequence lies in column p % Pmax, and the walk starts at
+            # the page that holds the oldest position the new token
+            # still attends to.
+            column = column + jax.lax.rem(first_ref[b], pmax)
+        return b, i, held, column
 
-    def write_back():
-        buf = last % 2
-        return [
+    def table_cell(b, column):
+        if window is not None:
+            column = jnp.where(column >= pmax, column - pmax, column)
+        return pt_ref[b * pmax + column]
+
+    def page_copies(pid, j, buf):
+        """Page ``pid`` of both pools into page j of buffer ``buf``."""
+        rows = pl.ds(pl.multiple_of(j * page, page), page)
+        return (pltpu.make_async_copy(
+                    k_hbm.at[layer, :, pid], k_buf.at[buf, :, rows, :],
+                    sems.at[0, buf]),
+                pltpu.make_async_copy(
+                    v_hbm.at[layer, :, pid], v_buf.at[buf, :, rows, :],
+                    sems.at[1, buf]))
+
+    def start(g, unrolled=True):
+        """Start the copies of the list's step g, if it has one: the
+        pages the step holds, each once. A whole step's are ``unrolled``
+        (one straight run of descriptors), a part's are a loop."""
+        inside = g < total
+        b, _, held, column = step(jnp.where(inside, g, 0))
+        held = jnp.where(inside, held, 0)
+        buf = g % 2
+
+        def start_page(j, _):
+            for copy in page_copies(table_cell(b, column + j), j, buf):
+                copy.start()
+            return 0
+
+        if not unrolled:
+            jax.lax.fori_loop(0, held, start_page, 0)
+            return
+
+        @pl.when(held == step_pages)
+        def _whole():
+            jax.lax.fori_loop(0, step_pages, start_page, 0, unroll=True)
+
+        @pl.when(held < step_pages)
+        def _part():
+            jax.lax.fori_loop(0, held, start_page, 0)
+
+    def wait(held, buf):
+        @pl.when(held == step_pages)
+        def _whole():
+            # One wait a pool for all the step's copies: a semaphore
+            # counts bytes, whichever copies brought them.
+            for ref, sem in ((k_buf, 0), (v_buf, 1)):
+                pltpu.make_async_copy(ref.at[buf], ref.at[buf],
+                                      sems.at[sem, buf]).wait()
+
+        @pl.when(held < step_pages)
+        def _part():
+            def wait_page(j, _):
+                for copy in page_copies(0, j, buf):
+                    copy.wait()
+                return 0
+            jax.lax.fori_loop(0, held, wait_page, 0)
+
+    # own[h]: the query rows of KV head h's GQA group.
+    head = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // (H // Hkv)
+    own = [head == h for h in range(Hkv)]
+
+    # Once a call, so a plain loop: unrolled here as well it would only
+    # lengthen the program's lowering (a set-up cost, PERF.md §6, PR 58).
+    start(0, unrolled=False)
+
+    def body(g, carry):
+        b, i, held, column = step(g)
+        buf = g % 2
+        length = len_ref[b]
+        # The list runs on over the slots: while this step computes,
+        # the copies of the next are in flight, be it the next slot's
+        # first.
+        start(g + 1)
+        wait(held, buf)
+
+        # The walk's last page, in its last step, takes the new row and
+        # goes back to the pool while the step computes.
+        page_new = np_ref[b] - 1 - i * step_pages
+        last = page_new < step_pages
+        page_new = jnp.where(last, page_new, 0)
+        rows_new = pl.ds(pl.multiple_of(page_new * page, page), page)
+        pid_new = table_cell(b, column + page_new)
+        write_back = [
             pltpu.make_async_copy(k_buf.at[buf, :, rows_new, :],
                                   k_out.at[layer, :, pid_new],
                                   sems.at[2, 0]),
@@ -155,76 +235,59 @@ def _page_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, *refs, pmax: int,
                                   sems.at[2, 1]),
         ]
 
-    @pl.when(n_blocks > 0)
-    def _first():
-        for c in copies(0, 0):
-            c.start()
-
-    q = q_ref[...]
-    # own[h]: the query rows of KV head h's GQA group.
-    head = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // (H // Hkv)
-    own = [head == h for h in range(Hkv)]
-
-    def body(i, carry):
-        m, l, acc = carry
-        buf = i % 2
-
-        @pl.when(i + 1 < n_blocks)
-        def _next():
-            for c in copies(i + 1, 1 - buf):
-                c.start()
-
-        for c in copies(i, buf):
-            c.wait()
-
-        @pl.when(i == last)
+        @pl.when(last)
         def _new_row():
             is_new = jax.lax.broadcasted_iota(
                 jnp.int32, (Hkv, page, D), 1) == length % page
             for ref, new in ((k_buf, kn_ref), (v_buf, vn_ref)):
                 rows = ref[buf, :, rows_new, :]
-                ref[buf, :, rows_new, :] = jnp.where(is_new, new[...], rows)
-            for c in write_back():
-                c.start()
+                ref[buf, :, rows_new, :] = jnp.where(is_new, new[b], rows)
+            for copy in write_back:
+                copy.start()
 
-        k = k_buf[buf]                                # [Hkv, block, D]
-        v = v_buf[buf]
+        # A slot's first step starts its softmax afresh.
+        m, l, acc = carry
+        m = jnp.where(i == 0, _NEG_INF, m)
+        l = jnp.where(i == 0, 0.0, l)
+        acc = jnp.where(i == 0, 0.0, acc)
+        q = q_ref[b]
         s = jnp.zeros((H, block), jnp.float32)
         for h in range(Hkv):
             s_h = jax.lax.dot_general(
-                q, k[h], (((1,), (1,)), ((), ())),
+                q, k_buf[buf, h], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)   # [H, block]
             s = jnp.where(own[h], s_h, s)
         t = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         if window is None:
             attends = t <= length
         else:
-            t = first * page + t
+            t = first_ref[b] * page + t
             attends = (t <= length) & (t > length - window)
         s = jnp.where(attends, s * scale, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         prob = jnp.exp(s - m_new)
         l = alpha * l + prob.sum(axis=1, keepdims=True)
-        prob = prob.astype(v.dtype)
+        prob = prob.astype(v_buf.dtype)
         pv = jnp.zeros((H, D), jnp.float32)
         for h in range(Hkv):
-            pv_h = jnp.dot(prob, v[h],
+            pv_h = jnp.dot(prob, v_buf[buf, h],
                            preferred_element_type=jnp.float32)  # [H, D]
             pv = jnp.where(own[h], pv_h, pv)
-        return m_new, l, acc * alpha + pv
+        acc = acc * alpha + pv
+
+        @pl.when(last)
+        def _done():
+            o_ref[b] = (acc / l).astype(o_ref.dtype)
+            for copy in write_back:
+                copy.wait()
+
+        return m_new, l, acc
 
     m0 = jnp.full((H, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((H, 1), jnp.float32)
     acc0 = jnp.zeros((H, D), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-    # A slot that walked nothing (inactive) writes zeros.
-    o_ref[...] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
-
-    @pl.when(n_blocks > 0)
-    def _written():
-        for c in write_back():
-            c.wait()
+    jax.lax.fori_loop(0, total, body, (m0, l0, acc0))
 
 
 def paged_decode_attention(
@@ -253,20 +316,30 @@ def paged_decode_attention(
     B, H, D = q.shape
     _, Hkv, _, page, _ = k_pool.shape
     Pmax = page_table.shape[1]
-    pages_per_block = max(1, _BLOCK_TOKENS // page)
-    n_pages = jnp.minimum(lengths // page + 1, Pmax)
-    scalars = [lengths.astype(jnp.int32),
-               jnp.reshape(layer, (1,)).astype(jnp.int32)]
-    if window is not None:
-        first = jnp.maximum(lengths + 1 - window, 0) // page
-        n_pages = lengths // page + 1 - first
-        scalars.append(first.astype(jnp.int32))
-    n_pages = jnp.where(active, n_pages, 0).astype(jnp.int32)
-    slot_rows = pl.BlockSpec((None, H, D), lambda b, *_: (b, 0, 0))
-    new_row = pl.BlockSpec((None, Hkv, 1, D), lambda b, *_: (b, 0, 0, 0))
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
     dtype = k_pool.dtype
-    kv_buf = pltpu.VMEM((2, Hkv, pages_per_block * page, D), dtype)
+    block = walk_step_tokens(Hkv, D, page, dtype, Pmax)
+    step_pages = block // page
+    if window is None:
+        n_pages, windowed = jnp.minimum(lengths // page + 1, Pmax), []
+    else:
+        first = jnp.maximum(lengths + 1 - window, 0) // page
+        n_pages, windowed = lengths // page + 1 - first, [
+            first.astype(jnp.int32)]
+    n_pages = jnp.where(active, n_pages, 0).astype(jnp.int32)
+    # Every slot's compute steps in one list, slot after slot: the
+    # kernel's one loop runs down it, and an inactive slot is not in it.
+    n_steps = (n_pages + step_pages - 1) // step_pages
+    ends = jnp.cumsum(n_steps)
+    most = B * -(-Pmax // step_pages)
+    slot_of = jnp.minimum(
+        (ends[None, :] <= jnp.arange(most)[:, None]).sum(axis=1), B - 1)
+    scalars = [lengths.astype(jnp.int32),
+               jnp.reshape(layer, (1,)).astype(jnp.int32),
+               slot_of.astype(jnp.int32), (ends - n_steps).astype(jnp.int32),
+               ends[-1:].astype(jnp.int32), *windowed]
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    kv_buf = pltpu.VMEM((2, Hkv, block, D), dtype)
     kernel = functools.partial(
         _page_walk_kernel, pmax=Pmax, scale=D ** -0.5, window=window)
     n_scalars = 2 + len(scalars)
@@ -274,16 +347,16 @@ def paged_decode_attention(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_scalars,
-            grid=(B,),
-            in_specs=[slot_rows, new_row, new_row, hbm, hbm],
-            out_specs=[slot_rows, hbm, hbm],
+            grid=(1,),
+            in_specs=[whole, whole, whole, hbm, hbm],
+            out_specs=[whole, hbm, hbm],
             scratch_shapes=[kv_buf, kv_buf, pltpu.SemaphoreType.DMA((3, 2))],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, H, D), q.dtype),
                    jax.ShapeDtypeStruct(k_pool.shape, dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, dtype)],
-        # Operands count the prefetched scalars: with four of them the
-        # pools are 7 and 8.
+        # Operands count the prefetched scalars: with seven of them the
+        # pools are 10 and 11.
         input_output_aliases={n_scalars + 3: 1, n_scalars + 4: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -331,8 +404,9 @@ def _latent_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, new_ref,
     length = len_ref[b]
 
     def copies(i, at):
-        """As the page walk's: past the slot's last page the last one is
-        read again, so the buffer holds nothing but pool rows."""
+        """The block's page copies. Past the slot's last page the last
+        one is read again: the buffer then never holds anything but pool
+        rows, so a masked probability of 0 meets no stale NaN."""
         out = []
         for j in range(pages_per_block):
             p = jnp.minimum(i * pages_per_block + j, n_pages - 1)
@@ -557,6 +631,23 @@ def pageable(page: int, head_dim: int) -> bool:
     128 lanes, and a page a whole number of bf16 sublane tiles (so a
     page's copy lands on tile boundaries of the block buffer)."""
     return head_dim % 128 == 0 and page % 16 == 0
+
+
+def walk_step_tokens(kv_heads: int, head_dim: int, page: int, dtype,
+                     columns: int) -> int:
+    """Tokens one compute step of the page walk covers, from what the
+    code can see of a pool and its table: about ``_STEP_BYTES`` of K and
+    V, a token being ``2 * kv_heads * head_dim`` elements of ``dtype``,
+    so that a step's fixed costs are spread over as many bytes whatever
+    the heads (512 tokens at 4 KV heads of 128 in bfloat16, 256 at 8,
+    128 at 16); never under the 128 lanes of one tile of scores; whole
+    pages, a power of two of them, and no more than the table's
+    ``columns``, so that no step is longer than the longest walk.
+    ``LLMEngine.stats()["page_walk_step_tokens"]`` reports it."""
+    row_bytes = 2 * kv_heads * head_dim * jnp.dtype(dtype).itemsize
+    pages = min(max(_STEP_BYTES // (row_bytes * page), 128 // page, 1),
+                columns)
+    return page * (1 << (pages.bit_length() - 1))
 
 
 def decode_attention_path(page: int, head_dim: int,

@@ -751,7 +751,10 @@ class LLMEngine:
         ``"gather"``: the path of
         ops/paged_attention.py the decode program was built with; for a
         model of retention layers ops/retention.py's ``"state_kernel"``
-        or ``"xla"``).
+        or ``"xla"``) and ``page_walk_step_tokens`` (``{kind: tokens}``:
+        what a compute step of the page walk covers in each pool it
+        walks, ops/paged_attention.py ``walk_step_tokens``; empty on
+        any other path).
 
         Counts: ``decode_steps``; ``decode_slot_steps`` (sequences, summed
         over decode steps) and ``decode_kv_tokens`` (their cached tokens,

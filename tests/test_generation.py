@@ -38,11 +38,12 @@ def _reference_decode_attention(q, ck, cv, page_table, lengths):
 
 
 # name: (H, Hkv, pool dtype, lengths, active, pages a slot, layers, the
-# layer decoded). Pages are 16 tokens and the kernel's block 8 pages, so
-# 10 pages a slot make a second, partly filled block. ``lengths[b]`` is
-# where the new row goes: 16 is the first row of a fresh page, 128 and
-# 256 the first of a fresh block, 159 of 10 pages the last cell of the
-# slot's last page.
+# layer decoded). Pages are 16 tokens and, under a table of 10 or 17
+# columns, the kernel's step 8 or 16 pages (``walk_step_tokens``), so
+# 10 pages a slot make a second, partly filled step. ``lengths[b]`` is
+# where the new row goes: 16 is the first row of a fresh page, 128 (of
+# 10 pages) and 256 (of 17) the first of a fresh step, 159 of 10 pages
+# the last cell of the slot's last page.
 _PAGED_CASES = {
     "length_0": (4, 2, "float32", [0], [True], 4, 2, 1),
     "length_15_page_end": (4, 2, "float32", [15], [True], 4, 2, 0),
@@ -68,6 +69,27 @@ _PAGED_CASES = {
     "gqa_rep7_hkv4": (28, 4, "float32", [5, 131, 159], [True] * 3, 10, 2, 1),
     "gqa_rep7_hkv4_bf16": (28, 4, "bfloat16", [0, 100, 159, 16],
                            [True, True, True, False], 10, 2, 0),
+    # A compute step is as long as its bytes say (``walk_step_tokens``):
+    # 512 tokens at 4 KV heads of bfloat16 or 2 of float32, 256 at 8,
+    # 128 at 16. 511 is the last row of a step, 512 the first of the
+    # next, which then holds one page; 1024 opens a third step.
+    "step_edges_hkv2": (4, 2, "float32", [511, 512, 513, 1023, 1024],
+                        [True] * 5, 65, 1, 0),
+    "step_edges_hkv4_bf16": (28, 4, "bfloat16", [511, 512, 513, 1023, 1024],
+                             [True] * 5, 65, 2, 1),
+    "step_edges_hkv8_bf16": (32, 8, "bfloat16", [255, 256, 257, 511, 512],
+                             [True] * 5, 33, 2, 0),
+    # A slot of 3 tokens beside one of 1,500, between them an idle slot
+    # with the row and the length its last request left: no step of the
+    # short slot reads past its one page, nothing of the idle one moves.
+    "short_idle_long_hkv4_bf16": (28, 4, "bfloat16", [3, 700, 1500],
+                                  [True, False, True], 96, 2, 1),
+    "short_idle_long_hkv8_bf16": (32, 8, "bfloat16", [3, 700, 1500],
+                                  [True, False, True], 96, 2, 0),
+    "short_idle_long_hkv16_bf16": (16, 16, "bfloat16", [3, 700, 1500],
+                                   [True, False, True], 96, 1, 0),
+    "short_idle_long_hkv2": (4, 2, "float32", [3, 700, 1500],
+                             [True, False, True], 96, 1, 0),
 }
 
 
@@ -134,6 +156,12 @@ _WINDOW_CASES = {
     "an_idle_slot": ([300, 90], 64),
     # 28 query heads on 4 (the third and fourth entries: H, Hkv).
     "groups_of_7": ([47, 300, 33], 32, 28, 4),
+    # A window of 64 pages under steps of 32 (float32, 2 KV heads): rings
+    # of 65 columns that wrapped four times and once, walks of 65 pages
+    # (two steps and one of a single page) from columns 53 and 1 and of
+    # 64 from column 54, so each step crosses what a step was on the
+    # ring's first turn, and the first two the ring's end.
+    "wrapped_ring_of_steps": ([5000, 5007, 2090, 40], 1024),
 }
 
 
@@ -245,6 +273,34 @@ def test_decode_attention_path_follows_platform_and_shape(monkeypatch):
     assert pa.decode_attention_path(16, 128) == "page_walk"
     assert pa.decode_attention_path(16, 64) == "gather"
     assert pa.decode_attention_path(8, 128) == "gather"
+
+
+@pytest.mark.parametrize("kv_heads,dtype,columns,tokens", [
+    (4, "bfloat16", 257, 512),     # SmallThinker's rings (28 on 4)
+    (4, "bfloat16", 1024, 512),    # ... and its tables of 16k
+    (4, "bfloat16", 129, 512),     # Trinity's rings (32 on 4)
+    (4, "bfloat16", 512, 512),
+    (8, "bfloat16", 128, 256),     # Mistral (32 on 8)
+    (16, "bfloat16", 128, 128),    # OLMoE (16 on 16): what it had
+    (32, "bfloat16", 128, 128),    # never under a lane tile of scores
+    (2, "float32", 65, 512),       # the float32 pools of these tests
+    (4, "bfloat16", 20, 256),      # a table shorter than a step:
+    (4, "bfloat16", 8, 128),       # whole pages, a power of two of them,
+    (4, "bfloat16", 4, 64),        # never more than the columns
+    (4, "bfloat16", 1, 16),
+])
+def test_walk_step_follows_the_bytes_of_a_token(kv_heads, dtype, columns,
+                                                tokens):
+    """The page walk's compute step, from shapes alone: about a
+    megabyte of K and V (``2 * kv_heads * 128 * itemsize`` bytes a
+    token), at least the 128 lanes of a score tile, a power of two of
+    pages, never longer than the table's columns."""
+    from ray_tpu.ops import paged_attention as pa
+
+    got = pa.walk_step_tokens(kv_heads, 128, 16, dtype, columns)
+    assert got == tokens
+    assert got <= columns * 16 and got % 16 == 0
+    assert (got // 16) & (got // 16 - 1) == 0
 
 
 def _tiny_olmoe():

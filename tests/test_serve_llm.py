@@ -1015,7 +1015,7 @@ _STATS_KEYS = [
     "decode_steps", "decode_steps_ahead", "device_kind", "failed",
     "finished", "free_pages", "free_slots", "kv_page_steps_held",
     "kv_page_steps_one_table", "kv_row_bytes", "page_size", "page_waits",
-    "pages", "phase_s", "platform", "prefill_bucket_tokens",
+    "page_walk_step_tokens", "pages", "phase_s", "platform", "prefill_bucket_tokens",
     "prefill_streamed_bucket_tokens", "prefill_tokens", "prefills", "queued",
     "requests", "state_slot_bytes", "stream", "submitted", "t", "total_pages"]
 _NESTED_KEYS = {
@@ -1028,7 +1028,7 @@ _NESTED_KEYS = {
             "prefill_experts_reached", "small_rows_layer_calls"]}
 _NOT_INT = {"decode_attention": str, "device_kind": str, "platform": str,
             "t": float, "requests": list, "kv_row_bytes": dict,
-            "pages": dict, "phase_s": dict, "state_slot_bytes": dict,
+            "page_walk_step_tokens": dict, "pages": dict, "phase_s": dict, "state_slot_bytes": dict,
             "stream": dict, "moe": dict}
 
 

@@ -308,6 +308,37 @@ def test_books_count_what_a_step_read_and_held(books):
         assert got["kv_page_steps_held"] < got["kv_page_steps_one_table"]
 
 
+def test_books_say_what_the_page_walk_was_built_with(monkeypatch):
+    """``page_walk_step_tokens``: one number a pool the page walk walks,
+    the rule ``paged_decode_attention`` sizes its buffers by at that
+    pool's table (a ring of 3 columns holds a step of 2 pages); nothing
+    where the decode program was built with another attention."""
+    import dataclasses
+    import importlib
+
+    from ray_tpu.models.generation import KVBooks, PagedKVCache
+    from ray_tpu.ops.paged_attention import walk_step_tokens
+
+    def reading(cfg):
+        geometry = (cfg, BATCH, TOTAL, PAGE, MAX_LEN // PAGE)
+        books = KVBooks(*geometry, PagedKVCache.create(*geometry))
+        return books, books.reading()["page_walk_step_tokens"]
+
+    cfg = dataclasses.replace(_tiny("trinity_tiny"), head_dim=128)
+    assert reading(cfg)[1] == {}                         # a CPU: "gather"
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    books, steps = reading(cfg)
+    assert books.decode_attention == "page_walk"
+    assert steps == {
+        kind: walk_step_tokens(cfg.num_kv_heads, 128, PAGE, cfg.dtype,
+                               columns)
+        for kind, (_, _, columns) in books.pools.items()}
+    assert steps == {"window": 2 * PAGE, "full": MAX_LEN}
+    for other in ("joyai_tiny", "brumby_tiny"):          # no k/v pool
+        assert reading(_tiny(other))[1] == {}
+
+
 # ---- (c) what serve/llm.py may not name -------------------------------------
 
 def _tree(path):

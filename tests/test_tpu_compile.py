@@ -191,6 +191,41 @@ def test_paged_decode_kernel_compiles_for_v5e(v5e, batch, pages_per_seq,
     _assert_pool_stays_in_place(compiled, pool)
 
 
+@pytest.mark.parametrize("batch,heads,columns,window,layers", [
+    (16, 28, 257, 4096, 6), (16, 28, 257, None, 6),
+    (16, 28, 1024, None, 2), (16, 28, 1024, 4096, 2),
+    (32, 32, 129, 2048, 5), (32, 32, 512, None, 1)],
+    ids=["smallthinker-ring", "smallthinker-ring-as-table",
+         "smallthinker-16k", "smallthinker-16k-window",
+         "trinity-ring", "trinity-8k"])
+def test_page_walk_compiles_at_the_long_context_cells_shapes(
+        v5e, batch, heads, columns, window, layers):
+    """The walk at 4 KV heads, where a compute step is 512 tokens
+    (``walk_step_tokens``): SmallThinker's 28 query rows on 4 over rings
+    of 257 columns and tables of 1,024, Trinity's 32 on 4 over 129 and
+    512, with and without a ``window``; the buffers of such steps fit
+    the VMEM a kernel has by default (nothing asks for more), and the
+    pools come back through the aliased outputs."""
+    assert paged_attention.walk_step_tokens(
+        4, D, PAGE, jnp.bfloat16, columns) == 512
+    pool = (layers, 4, batch * columns, PAGE, D)
+    compiled = jax.jit(
+        functools.partial(paged_attention.paged_decode_attention,
+                          window=window),
+        donate_argnums=(3, 4),
+    ).lower(
+        _arr(v5e, (batch, heads, D)),
+        _arr(v5e, (batch, 4, D)), _arr(v5e, (batch, 4, D)),
+        _arr(v5e, pool), _arr(v5e, pool), _arr(v5e, (), jnp.int32),
+        _arr(v5e, (batch, columns), jnp.int32),
+        _arr(v5e, (batch,), jnp.int32),
+        _arr(v5e, (batch,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "vmem_limit_bytes" not in text
+    _assert_pool_stays_in_place(compiled, pool)
+
+
 def _decode_program(cfg, v5e, batch, pool_pages, pages_per_seq):
     params, cache = _serve_shapes(cfg, v5e, batch, pool_pages,
                                   pages_per_seq)
