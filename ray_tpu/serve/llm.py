@@ -58,9 +58,41 @@ import math
 import queue
 import threading
 import time
+from bisect import bisect_right
 from typing import Any, Dict, Iterator, List, Optional
 
 import numpy as np
+
+
+# The upper edges, in seconds, of the buckets of the three histograms of
+# ``stats()["stream"]``: 2% apart from 10 us to 63 s, so that a quantile
+# read back from one lies within 2% of the samples' own whatever they
+# were. A histogram is one count more than the edges: ``counts[i]`` is
+# the samples in ``[edges[i - 1], edges[i])``, the first everything
+# under ``edges[0]``, the last everything from ``edges[-1]`` on: the
+# layout ``util/tsdb.quantile_from_histogram(edges, counts, q)`` reads.
+HIST_EDGES_S = tuple(1e-5 * 1.02 ** i for i in range(792))
+
+
+# Of a stream's holds, which are also timed on the thread's CPU clock:
+# the first of every eight. ``time.thread_time()`` is a system call, two
+# a timed hold, and where one costs microseconds (6.6 us on the hosts
+# the benchmark runs on) sixteen stream threads pay them under one GIL.
+_CPU_TIMED_HOLD = 8
+
+
+def _new_hist() -> List[int]:
+    return [0] * (len(HIST_EDGES_S) + 1)
+
+
+def _merge(into: Dict[str, Any], account: Dict[str, Any]) -> None:
+    """Add a request's account to sums of accounts: numbers, and
+    histograms count by count into a new list."""
+    for key, value in account.items():
+        if isinstance(value, list):
+            into[key] = [a + b for a, b in zip(into[key], value)]
+        else:
+            into[key] += value
 
 
 class _Streams:
@@ -73,7 +105,10 @@ class _Streams:
     def __init__(self):
         self.lock = threading.Lock()
         self.running: set = set()
-        self.ended = {"tokens_taken": 0, "taken_lag_s": 0.0, "held_s": 0.0}
+        self.ended = {"tokens_taken": 0, "taken_lag_s": 0.0, "held_s": 0.0,
+                      "held_cpu_s": 0.0, "held_timed_s": 0.0,
+                      "taken_lag_hist": _new_hist(),
+                      "held_hist": _new_hist()}
 
     def begin(self, req: "_Request") -> None:
         with self.lock:
@@ -83,8 +118,7 @@ class _Streams:
         with self.lock:
             if req in self.running:
                 self.running.remove(req)
-                for key, value in req.taken_account().items():
-                    self.ended[key] += value
+                _merge(self.ended, req.taken_account())
 
     def read(self) -> Dict[str, Any]:
         """The sums over ended and running iterators, and ``backlog``:
@@ -94,8 +128,7 @@ class _Streams:
             backlog = 0
             for req in self.running:
                 account = req.taken_account()
-                for key, value in account.items():
-                    out[key] += value
+                _merge(out, account)
                 backlog += max(
                     0, len(req.output) - account["tokens_taken"])
         out["backlog"] = backlog
@@ -128,14 +161,23 @@ class _Request:
         # token with the ``time.time()`` at which the loop put it; None
         # is the end-of-stream sentinel.
         self._live: "queue.Queue[Optional[tuple]]" = queue.Queue()
+        # When the loop last put a token on ``_live``: the next one's
+        # gap is counted from it (the loop thread's alone).
+        self.t_emit: Optional[float] = None
         # The way back, accounted by the thread that runs ``tokens()``
         # and written by it alone: tokens taken, seconds from emitted to
         # taken, seconds from handing a token over to being asked for
-        # the next, and when the consumer came back after the last one.
+        # the next (of every eighth such hold, its seconds and those the
+        # thread was on the CPU), each token's two waits counted into a
+        # histogram, and when the consumer came back after the last one.
         self._streams = streams
         self.taken = 0
         self.taken_lag_s = 0.0
         self.held_s = 0.0
+        self.held_cpu_s = 0.0
+        self.held_timed_s = 0.0
+        self.taken_lag_hist = _new_hist()
+        self.held_hist = _new_hist()
         self.t_last_put: Optional[float] = None
         # The row ``_close`` kept for stats(): ``t_last_put`` is known
         # only later, and is written into it in place.
@@ -155,7 +197,10 @@ class _Request:
 
     def taken_account(self) -> Dict[str, Any]:
         return {"tokens_taken": self.taken, "taken_lag_s": self.taken_lag_s,
-                "held_s": self.held_s}
+                "held_s": self.held_s, "held_cpu_s": self.held_cpu_s,
+                "held_timed_s": self.held_timed_s,
+                "taken_lag_hist": self.taken_lag_hist,
+                "held_hist": self.held_hist}
 
     def record_spans(self, parent: Optional[tuple] = None) -> None:
         """The engine's part of this request as ``core/timeline`` spans
@@ -184,8 +229,13 @@ class _Request:
     def tokens(self, timeout: Optional[float] = None) -> Iterator[int]:
         """Yield tokens as the decode loop produces them, and account
         for the way back on the calling thread (``stats()["stream"]``):
-        three clock reads and four adds a token, no lock."""
-        wall, clock = time.time, time.perf_counter
+        three clock reads, two bucket lookups and six adds a token, no
+        lock; every eighth hold two reads of the thread's CPU clock
+        more. ``held_cpu_s`` is the CPU time of the thread that resumes
+        the iterator: one thread a stream, as the worker runs one."""
+        wall, clock, cpu = time.time, time.perf_counter, time.thread_time
+        edges, lag_hist, held_hist = (
+            HIST_EDGES_S, self.taken_lag_hist, self.held_hist)
         self._streams.begin(self)
         back = None  # perf_counter() when the consumer last came back
         try:
@@ -201,12 +251,22 @@ class _Request:
                         raise self.error
                     return
                 tok, emitted = item
-                self.taken_lag_s += wall() - emitted
+                lag = wall() - emitted
+                self.taken_lag_s += lag
+                lag_hist[bisect_right(edges, lag)] += 1
+                timed = not self.taken % _CPU_TIMED_HOLD
                 self.taken += 1
                 handed = clock()
+                if timed:
+                    handed_cpu = cpu()
                 yield tok
                 back = clock()
-                self.held_s += back - handed
+                held = back - handed
+                self.held_s += held
+                held_hist[bisect_right(edges, held)] += 1
+                if timed:
+                    self.held_cpu_s += cpu() - handed_cpu
+                    self.held_timed_s += held
         finally:
             self._streams.end(self)
 
@@ -221,6 +281,10 @@ _COUNTERS = ("decode_slot_steps", "prefills", "prefill_tokens",
              "decode_slot_steps_discarded")
 _PHASES = ("admit", "admit_stalling", "inputs", "decode", "readback",
            "emit", "idle")
+# What a decode step's dispatch found: the device with work queued, or
+# empty behind a late loop, behind a prefill that open streams waited
+# through, or after a lull.
+_FEEDS = ("fed", "starved_host", "starved_prefill", "starved_lull")
 _REQUEST_ROWS = 1024
 # Where a ``requests`` row keeps ``t_last_put``.
 _LAST_PUT = 7
@@ -319,6 +383,9 @@ class _Scheduler:
         # The way back (stats()["stream"]): tokens put on a request's
         # ``_live``, counted by the loop thread, and who has taken them.
         self._tokens_emitted = 0
+        # Seconds between two tokens of one request as the loop put
+        # them, a histogram over every request; a first token opens none.
+        self._emit_gaps = _new_hist()
         self.streams = _Streams()
 
     def bucket(self, n: int) -> int:
@@ -388,7 +455,7 @@ class _Scheduler:
         """The picked request's prefill gave its first token."""
         slot, req = self._admitting
         counts = self.counts
-        req.t_first = time.time()
+        req.t_first = req.t_emit = time.time()
         counts["prefills"] += 1
         counts["prefill_tokens"] += req.prompt_len
         counts["prefill_bucket_tokens"] += req.bucket
@@ -421,7 +488,7 @@ class _Scheduler:
         """A step's tokens ``nxt`` (one a slot) to their requests, once
         read: the counters, the finishes. ``queued`` is the step
         dispatched after it."""
-        counts = self.counts
+        counts, gaps, edges = self.counts, self._emit_gaps, HIST_EDGES_S
         now = time.time()  # the step's tokens are emitted now
         self._steps += 1
         counts["decode_steps_ahead"] += step.ahead
@@ -438,6 +505,8 @@ class _Scheduler:
             tok = int(nxt[slot])
             req.output.append(tok)
             req._live.put((tok, now))
+            gaps[bisect_right(edges, now - req.t_emit)] += 1
+            req.t_emit = now
             if self._ended(req, tok):
                 self._finish(slot, req)
                 if queued is not None and slot in queued.slots:
@@ -467,7 +536,9 @@ class _Scheduler:
         return {
             **self.counts,
             "decode_steps": self._steps,
-            "stream": {"tokens_emitted": self._tokens_emitted, **taken},
+            "stream": {"tokens_emitted": self._tokens_emitted,
+                       "emit_gap_hist": list(self._emit_gaps),
+                       "hist_edges_s": list(HIST_EDGES_S), **taken},
             "queued": self._queue.qsize() + len(self._waiting),
             "requests": list(self._finished_rows) + [
                 req.row() for req in self._slot_req.values()],
@@ -624,6 +695,11 @@ class _Runner:
         out.copy_to_host_async()
         return out
 
+    def done(self, out) -> bool:
+        """Whether the step of this read-back has finished on the
+        device, without waiting for it."""
+        return out.is_ready()
+
     def fetch(self, out) -> np.ndarray:
         """Block for a step's read-back."""
         return np.asarray(out)
@@ -712,6 +788,10 @@ class LLMEngine:
         self._stop = False
         self._flying: Optional[_Step] = None
         self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        # ``admit_stalling`` is a part of ``admit`` that no lap closes.
+        self._phase_cpu_s = dict.fromkeys(
+            (p for p in _PHASES if p != "admit_stalling"), 0.0)
+        self._dispatch = dict.fromkeys(_FEEDS, 0)
         self._cache_resets = 0
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
@@ -805,6 +885,34 @@ class LLMEngine:
         device was given the next step first, so this is DEVICE-BOUND
         waiting, about a step's time where the device sets the pace,
         and no sign of a slow host), ``emit``, ``idle`` (the 2 ms poll).
+        ``phase_cpu_s``: of each phase but ``admit_stalling``, the seconds
+        the loop thread was ON the CPU (``time.thread_time()`` at the same
+        boundaries). A phase's wall time less its CPU time is what the
+        thread spent runnable or blocked and not running: in ``inputs``
+        and ``emit``, which are Python alone, waiting for the GIL; in
+        ``decode`` the GIL and whatever the dispatch blocks on;
+        ``readback`` and ``idle`` wait by design. The CPU clock is the
+        kernel's: where it ticks (10 ms on some hosts) and books a
+        thread's time when the thread next enters the kernel, a phase
+        may hold what the phase before it burnt, so read sums of
+        neighbouring phases over tens of seconds, not one phase of one
+        step.
+
+        ``decode_dispatch``: every decode step dispatched, counted at its
+        dispatch by what it found: ``fed`` (the step before it was still
+        running: the device had work queued and never waited),
+        ``starved_host`` (the step before it had ALREADY finished and no
+        prefill ran in the round: the loop itself was late, and over
+        ``decode_steps`` this is "my host is the bottleneck"),
+        ``starved_prefill`` (a step was in flight and the round's
+        admission ran a prefill, whose first token the loop waits for
+        behind it: the device is empty when it comes, and every open
+        stream stood still) and ``starved_lull`` (no step was in flight:
+        nobody was open, this is the first step behind the prefill that
+        ended a lull). Their sum is ``decode_steps`` plus the steps
+        dispatched and not yet emitted (at most two in a reading) plus
+        those a cache reset threw away. The class rides on the step's
+        ``engine.decode`` annotation as ``feed``.
 
         ``moe``, for a model with experts only: ``expert_tokens`` (a list
         of E: (token, expert) assignments each expert was given, prefills
@@ -847,6 +955,22 @@ class LLMEngine:
         to the consumer asking for the next: what ``LLMDeployment.stream``,
         the executor and the seal cost on that thread), and the gauge
         ``backlog`` (emitted to a running iterator, not yet taken).
+        ``held_timed_s`` and ``held_cpu_s``: the part of ``held_s`` in
+        every eighth hold of an iterator, from its first, and of that
+        the seconds the taking thread was on the CPU; the rest it
+        waited, for the GIL above all (a sample: the CPU clock is a
+        system call). Three
+        histograms over ``hist_edges_s`` (``HIST_EDGES_S``: upper edges
+        2% apart from 10 us to 63 s, one count more than edges, the first
+        and last open; subtract two readings count by count for a
+        window, and ``util/tsdb.quantile_from_histogram(edges, counts,
+        0.9)`` reads its p90 to within 2.5% of the samples' own):
+        ``emit_gap_hist``, seconds between two tokens of one request as
+        the loop put them (a first token opens no gap, as a client
+        counts: the cadence the engine made, a prefill's stand-still in
+        every open stream included), ``taken_lag_hist`` and
+        ``held_hist``, each taken token's part of ``taken_lag_s`` and
+        ``held_s`` (a hold is counted when the consumer comes back).
         Stamps are ``time.time()`` of one process; a token's is its
         step's, one clock read a step."""
         # Telemetry read: publish whatever the decode tap ring has
@@ -860,6 +984,8 @@ class LLMEngine:
                 **self.runner.reading(),
                 "t": time.time(),
                 "phase_s": dict(self._phase_s),
+                "phase_cpu_s": dict(self._phase_cpu_s),
+                "decode_dispatch": dict(self._dispatch),
                 "cache_resets": self._cache_resets,
             }
 
@@ -884,16 +1010,19 @@ class LLMEngine:
         self.runner.reset()
         self._flying = None
 
-    def _admit(self):
+    def _admit(self) -> int:
         """One admission round: prefill queued requests into free slots
-        until slots, pages or the queue run out. A prefill queues
-        behind the decode step in flight and this thread waits for its
-        first token, so that step's tokens are emitted after it."""
+        until slots, pages or the queue run out, and say how many
+        prefills ran. A prefill queues behind the decode step in flight
+        and this thread waits for its first token, so that step's
+        tokens are emitted after it, and the device has nothing queued
+        when the round returns."""
         scheduler, runner = self.scheduler, self.runner
+        prefills = 0
         while True:
             picked = scheduler.pick()
             if picked is None:
-                return
+                return prefills
             import jax
 
             slot, prompt, bucket, (pages, tables) = picked
@@ -908,6 +1037,7 @@ class LLMEngine:
                 scheduler.prefill_failed(e)
                 self._reset(e)
                 continue
+            prefills += 1
             scheduler.first_token(first)
 
     def _loop(self):
@@ -916,27 +1046,33 @@ class LLMEngine:
         safe). Each phase is a profiler annotation (inert unless a
         ``jax.profiler`` trace is open; then it lands in the trace's
         host plane, on the device trace's clock) and, from the same
-        ``perf_counter()`` boundaries, a running total in ``phase_s``."""
+        ``perf_counter()`` boundaries, a running total in ``phase_s``
+        and, of this thread's CPU time, in ``phase_cpu_s``. Each decode
+        dispatch is counted by what it found on the device
+        (``decode_dispatch``) and says so on its annotation (``feed``)."""
         import jax
 
         scheduler, runner = self.scheduler, self.runner
         span = jax.profiler.TraceAnnotation
-        clock = time.perf_counter
-        phase_s = self._phase_s
-        t = clock()
+        clock, cpu = time.perf_counter, time.thread_time
+        phase_s, phase_cpu_s = self._phase_s, self._phase_cpu_s
+        dispatch = self._dispatch
+        t, t_cpu = clock(), cpu()
 
         def lap(phase: str) -> float:
             """Close ``phase`` at a boundary shared with the next one."""
-            nonlocal t
-            now = clock()
+            nonlocal t, t_cpu
+            now, now_cpu = clock(), cpu()
             dt, t = now - t, now
             phase_s[phase] += dt
+            phase_cpu_s[phase] += now_cpu - t_cpu
+            t_cpu = now_cpu
             return dt
 
         while not self._stop:
             stalling = scheduler.streaming()
             with span("engine.admit"):
-                self._admit()
+                prefilled = self._admit()
             dt = lap("admit")
             if stalling:
                 phase_s["admit_stalling"] += dt
@@ -947,8 +1083,18 @@ class LLMEngine:
                     with span("engine.inputs"):
                         runner.activate(queued.slots.keys())
                     lap("inputs")
-                    with span("engine.decode"):
+                    # Nothing in flight: nobody was open, this step
+                    # follows the prefill that ended the lull. A
+                    # prefill's first token was waited for behind the
+                    # step in flight; a step in flight whose tokens are
+                    # ready left the device empty before this turn.
+                    feed = ("starved_lull" if flying is None
+                            else "starved_prefill" if prefilled
+                            else "starved_host" if runner.done(flying.out)
+                            else "fed")
+                    with span("engine.decode", feed=feed):
                         queued.out = runner.step()
+                    dispatch[feed] += 1
                     lap("decode")
                 if flying is not None:
                     with span("engine.readback"):

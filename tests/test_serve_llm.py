@@ -557,6 +557,9 @@ def test_a_failed_decode_with_a_step_in_flight_fails_each_request_once(
         def copy_to_host_async(self):
             pass
 
+        def is_ready(self):     # what the next dispatch asks of it
+            return True
+
         def __array__(self, *args, **kwargs):
             raise RuntimeError("decode fell over")
 
@@ -1009,27 +1012,34 @@ def test_a_paged_engine_has_no_state_gauge_and_counts_no_states(tiny_model):
 # Written from the output of the engine as one class (PR 51's tree).
 _STATS_KEYS = [
     "active_slots", "admitted", "cache_resets", "decode_attention",
-    "decode_kv_rows_read", "decode_kv_rows_selected", "decode_kv_tokens",
+    "decode_dispatch", "decode_kv_rows_read", "decode_kv_rows_selected", "decode_kv_tokens",
     "decode_slot_steps",
     "decode_slot_steps_discarded", "decode_state_slot_layers",
     "decode_steps", "decode_steps_ahead", "device_kind", "failed",
     "finished", "free_pages", "free_slots", "kv_page_steps_held",
     "kv_page_steps_one_table", "kv_row_bytes", "page_size", "page_waits",
-    "page_walk_step_tokens", "pages", "phase_s", "platform", "prefill_bucket_tokens",
+    "page_walk_step_tokens", "pages", "phase_cpu_s", "phase_s", "platform",
+    "prefill_bucket_tokens",
     "prefill_streamed_bucket_tokens", "prefill_tokens", "prefills", "queued",
     "requests", "state_slot_bytes", "stream", "submitted", "t", "total_pages"]
 _NESTED_KEYS = {
     "phase_s": ["admit", "admit_stalling", "decode", "emit", "idle",
                 "inputs", "readback"],
-    "stream": ["backlog", "held_s", "taken_lag_s", "tokens_emitted",
-               "tokens_taken"],
+    "phase_cpu_s": ["admit", "decode", "emit", "idle", "inputs", "readback"],
+    "decode_dispatch": ["fed", "starved_host", "starved_lull",
+                        "starved_prefill"],
+    "stream": ["backlog", "emit_gap_hist", "held_cpu_s", "held_hist",
+               "held_s", "held_timed_s", "hist_edges_s", "taken_lag_hist",
+               "taken_lag_s",
+               "tokens_emitted", "tokens_taken"],
     "moe": ["assignments", "decode_assignments", "expert_tokens",
             "experts_reached", "layer_calls", "layer_steps",
             "prefill_experts_reached", "small_rows_layer_calls"]}
 _NOT_INT = {"decode_attention": str, "device_kind": str, "platform": str,
             "t": float, "requests": list, "kv_row_bytes": dict,
-            "page_walk_step_tokens": dict, "pages": dict, "phase_s": dict, "state_slot_bytes": dict,
-            "stream": dict, "moe": dict}
+            "page_walk_step_tokens": dict, "pages": dict, "phase_s": dict,
+            "phase_cpu_s": dict, "decode_dispatch": dict,
+            "state_slot_bytes": dict, "stream": dict, "moe": dict}
 
 
 @pytest.mark.parametrize("model, pools, slot_pools, moe", [

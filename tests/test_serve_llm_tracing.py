@@ -4,9 +4,12 @@ loop's profiler annotations. CPU, tiny model."""
 
 import glob
 import os
+import random
+import statistics
 import sys
 import threading
 import time
+from bisect import bisect_right
 
 import pytest
 
@@ -14,11 +17,28 @@ jax = pytest.importorskip("jax")
 
 import ray_tpu.core.timeline  # noqa: E402,F401
 from ray_tpu.models import LlamaConfig, init_params  # noqa: E402
-from ray_tpu.serve.llm import LLMDeployment, LLMEngine  # noqa: E402
+from ray_tpu.serve.llm import (  # noqa: E402
+    HIST_EDGES_S, LLMDeployment, LLMEngine)
+from ray_tpu.util.tsdb import quantile_from_histogram  # noqa: E402
 
 # The package exports a function of the same name over the module.
 timeline = sys.modules["ray_tpu.core.timeline"]
 LOOP_PHASES = ("admit", "inputs", "decode", "readback", "emit", "idle")
+FEEDS = ("fed", "starved_host", "starved_prefill", "starved_lull")
+
+
+def hist_quantile(hist, q):
+    """Percentile ``q`` of a histogram of ``stats()["stream"]``, as an
+    operator reads it."""
+    return quantile_from_histogram(HIST_EDGES_S, hist, q / 100.0)
+
+
+HISTS = ("emit_gap_hist", "taken_lag_hist", "held_hist")
+# ``stats()["stream"]`` of an engine that has made no token, but for its
+# histograms (all zeros then) and their edges.
+NO_TOKENS = {"tokens_emitted": 0, "tokens_taken": 0, "taken_lag_s": 0.0,
+             "held_s": 0.0, "held_timed_s": 0.0, "held_cpu_s": 0.0,
+             "backlog": 0}
 
 
 @pytest.fixture(scope="module")
@@ -30,6 +50,24 @@ def tiny_model():
 
 def _admit_waits(stats):
     return [row[1] - row[0] for row in sorted(stats["requests"])]
+
+
+def _numbers(stream):
+    """``stats()["stream"]`` without its histograms and their edges."""
+    return {key: value for key, value in stream.items()
+            if not isinstance(value, list)}
+
+
+def _window(after, before, name):
+    """A histogram of ``stats()["stream"]`` between two readings."""
+    return [a - b for a, b in zip(after["stream"][name],
+                                  before["stream"][name])]
+
+
+def _counted_from(hist, seconds):
+    """Samples of ``seconds`` or more, by the buckets that lie over it."""
+    return sum(count for count, low in zip(hist[1:], HIST_EDGES_S)
+               if low >= seconds)
 
 
 def test_stats_conserve_requests_tokens_and_time(tiny_model):
@@ -136,9 +174,10 @@ def test_a_failed_prefill_is_counted_and_its_row_kept(tiny_model):
     assert req.ttft_s is None
     # No token was made: none emitted, none taken, nothing put.
     assert (rid, t_last_put) == (None, None)
-    assert stats["stream"] == {"tokens_emitted": 0, "tokens_taken": 0,
-                               "taken_lag_s": 0.0, "held_s": 0.0,
-                               "backlog": 0}
+    assert _numbers(stats["stream"]) == NO_TOKENS
+    assert not any(sum(stats["stream"][name]) for name in HISTS)
+    # ... and no decode step was dispatched.
+    assert stats["decode_dispatch"] == dict.fromkeys(FEEDS, 0)
 
 
 # ---- the way back: stats()["stream"], "t", a row's id and t_last_put ------
@@ -165,9 +204,10 @@ def test_stream_counts_conserve_against_steps_and_prefills(tiny_model):
     engine = LLMEngine(cfg, params, max_batch=2, max_len=64)
     try:
         first = engine.stats()
-        assert first["stream"] == {"tokens_emitted": 0, "tokens_taken": 0,
-                                   "taken_lag_s": 0.0, "held_s": 0.0,
-                                   "backlog": 0}
+        assert _numbers(first["stream"]) == NO_TOKENS
+        assert first["stream"]["hist_edges_s"] == list(HIST_EDGES_S)
+        assert all(first["stream"][name] == [0] * (len(HIST_EDGES_S) + 1)
+                   for name in HISTS)
         news = [3, 7, 5, 4]
         reqs = [engine.submit([1, 2, 3, 4 + i], n, request_id=f"r{i}")
                 for i, n in enumerate(news)]
@@ -266,6 +306,198 @@ def test_rows_keep_six_fields_and_gain_the_callers_id_and_the_last_put(
     assert by_call[6:] == [None, None]
 
 
+# ---- a gap taken apart: cadence, the hops' tails, CPU time, the feed ------
+
+
+def _slow_prefill(engine, seconds):
+    """Every prefill from now on takes ``seconds`` longer."""
+    real = engine.runner.prefill
+
+    def slow(*args):
+        time.sleep(seconds)
+        return real(*args)
+
+    engine.runner.prefill = slow
+
+
+def test_a_prefill_is_one_long_gap_for_every_open_stream_and_none_for_its_own(
+        tiny_model):
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, max_batch=3, max_len=64)
+    try:
+        engine.generate([9, 9, 9], 2, timeout=120)  # programs compiled
+        before = engine.stats()
+        open_streams = [engine.submit([1, 2, 3, 4 + i], 56) for i in range(2)]
+        takers = [r.tokens(timeout=120) for r in open_streams]
+        firsts = [next(t) for t in takers]  # both stream, both prefills done
+        _slow_prefill(engine, 0.5)
+        late = engine.submit([7, 8, 9], 6)
+        late.result(timeout=120)
+        outputs = [[first] + list(t) for first, t in zip(firsts, takers)]
+        after = engine.stats()
+    finally:
+        engine.shutdown()
+    assert outputs == [r.output for r in open_streams]
+    gaps = _window(after, before, "emit_gap_hist")
+    # A request's first token opens no gap, as a client counts them.
+    emitted = after["stream"]["tokens_emitted"] \
+        - before["stream"]["tokens_emitted"]
+    assert emitted == 56 + 56 + 6 and sum(gaps) == emitted - 3
+    # Both open streams stood still through the late one's prefill; its
+    # own gaps are a step each.
+    assert _counted_from(gaps, 0.4) == 2
+    assert hist_quantile(gaps, 50) < 0.1 < 0.4 < hist_quantile(gaps, 99.5)
+    assert after["decode_dispatch"]["starved_prefill"] \
+        - before["decode_dispatch"]["starved_prefill"] == 1
+
+
+def test_hop_histograms_merge_ended_and_running_iterators(tiny_model):
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=64)
+    try:
+        engine.generate([9, 9, 9], 2, timeout=120)  # programs compiled
+        base = engine.stats()
+        assert len(_take(engine.submit([1, 2, 3], 10))) == 10   # ended
+        quick = engine.stats()
+        slow = engine.submit([1, 2, 3], 10)
+        taker = slow.tokens(timeout=120)
+        for _ in range(4):      # running: its fourth token is in hand
+            next(taker)
+            time.sleep(0.05)
+        mid = engine.stats()["stream"]
+        assert len(list(taker)) == 6
+        done = engine.stats()
+    finally:
+        engine.shutdown()
+    assert mid["tokens_taken"] - base["stream"]["tokens_taken"] == 14
+    assert sum(mid["taken_lag_hist"]) == mid["tokens_taken"]
+    assert sum(mid["held_hist"]) == mid["tokens_taken"] - 1
+    stream = done["stream"]
+    assert sum(stream["taken_lag_hist"]) == sum(stream["held_hist"]) \
+        == stream["tokens_taken"] == base["stream"]["tokens_taken"] + 20
+    # The consumer that slept with a token in hand: four holds of 50 ms
+    # among its ten, none among the quick one's; and it slept off the CPU.
+    assert _counted_from(_window(done, quick, "held_hist"), 0.045) == 4
+    assert _counted_from(_window(quick, base, "held_hist"), 0.045) == 0
+    assert stream["held_s"] - quick["stream"]["held_s"] > 4 * 0.045
+    # Of an iterator's holds the first and every eighth behind it are
+    # also timed on the thread's CPU clock: here the slow one's first,
+    # 50 ms asleep, and its ninth.
+    for stats in (base, quick, done):
+        timed = stats["stream"]
+        assert 0 <= timed["held_cpu_s"] <= timed["held_timed_s"] \
+            <= timed["held_s"]
+    assert 0.045 < stream["held_timed_s"] - quick["stream"]["held_timed_s"] \
+        < stream["held_s"] - quick["stream"]["held_s"] - 3 * 0.045
+    assert stream["held_cpu_s"] - quick["stream"]["held_cpu_s"] < 0.02
+
+
+def test_phase_cpu_is_a_part_of_each_phase_and_waiting_is_not_in_it(tiny_model):
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=64)
+    try:
+        reqs = [engine.submit([1, 2, 3, 4 + i], 24) for i in range(2)]
+        for r in reqs:
+            r.result(timeout=120)
+        time.sleep(0.3)   # the loop polls, asleep
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    phase_s, phase_cpu_s = stats["phase_s"], stats["phase_cpu_s"]
+    assert set(phase_cpu_s) == set(LOOP_PHASES)
+    for phase in LOOP_PHASES:
+        # Two clocks: a tick of room.
+        assert 0 <= phase_cpu_s[phase] <= phase_s[phase] * 1.05 + 0.02
+    assert phase_s["idle"] > 0.25 and phase_cpu_s["idle"] < 0.5 * phase_s["idle"]
+    assert sum(phase_cpu_s.values()) > 0
+
+
+def _dispatched(after, before):
+    return {feed: after["decode_dispatch"][feed]
+            - before["decode_dispatch"][feed] for feed in FEEDS}
+
+
+def test_every_decode_dispatch_is_counted_by_what_it_found(tiny_model):
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, max_batch=2, max_len=64)
+    try:
+        engine.generate([9, 9, 9], 2, timeout=120)  # programs compiled
+        start = engine.stats()
+        # After a lull: the step behind the prefill finds nothing in flight.
+        engine.generate([1, 2, 3], 12, timeout=120)
+        alone = engine.stats()
+        # A prefill with a stream open; then the loop held past a step's
+        # end once: the step in flight is done before the next is sent.
+        first = engine.submit([1, 2, 3], 56)
+        taker = first.tokens(timeout=120)
+        next(taker)
+        engine.submit([4, 5, 6], 8).result(timeout=120)
+        real = engine.scheduler.next_step
+        held = []
+
+        def late(flying):
+            if flying is not None and not held:
+                held.append(time.sleep(0.2))
+            return real(flying)
+
+        engine.scheduler.next_step = late
+        mid = engine.stats()   # a step may be in flight
+        first.result(timeout=120)
+        deadline = time.monotonic() + 60
+        while engine.stats()["free_slots"] < 2:
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        end = engine.stats()
+    finally:
+        engine.shutdown()
+    found = _dispatched(alone, start)
+    assert found["starved_lull"] == 1 and found["starved_prefill"] == 0
+    assert sum(found.values()) == alone["decode_steps"] - start["decode_steps"] \
+        == 11
+    found = _dispatched(end, alone)
+    assert held and (found["starved_lull"], found["starved_prefill"]) == (1, 1)
+    assert found["starved_host"] >= 1 and found["fed"] >= 1
+    assert sum(found.values()) == end["decode_steps"] - alone["decode_steps"]
+    # Counted at the dispatch, where ``decode_steps`` counts at the
+    # emit: a reading holds the steps dispatched and not emitted yet,
+    # the one in flight and the one being read before it.
+    assert sum(_dispatched(mid, start).values()) \
+        - (mid["decode_steps"] - start["decode_steps"]) in (0, 1, 2)
+
+
+@pytest.mark.parametrize("q", [50, 90, 99])
+@pytest.mark.parametrize("shape", ["cadence", "lognormal", "two_modes"])
+def test_a_quantile_read_from_a_histogram_is_the_samples_own(shape, q):
+    rng = random.Random(f"{shape}-{q}")
+    if shape == "cadence":      # a step, its jitter, and a prefill in 3%
+        samples = [0.0098 * rng.uniform(0.99, 1.01)
+                   + (rng.uniform(0.05, 0.4) if rng.random() < 0.03 else
+                      rng.expovariate(1 / 0.0005))
+                   for _ in range(40_000)]
+    elif shape == "lognormal":  # a hop: tens of microseconds to seconds
+        samples = [rng.lognormvariate(-6.5, 1.5) for _ in range(40_000)]
+    else:
+        samples = [rng.gauss(0.0021, 0.00002) if rng.random() < 0.8
+                   else rng.gauss(0.0234, 0.0004) for _ in range(40_000)]
+    hist = [0] * (len(HIST_EDGES_S) + 1)
+    for sample in samples:
+        hist[bisect_right(HIST_EDGES_S, sample)] += 1
+    exact = statistics.quantiles(samples, n=1000)[q * 10 - 1]
+    assert hist_quantile(hist, q) == pytest.approx(exact, rel=0.025)
+
+
+def test_the_histograms_edges_and_their_open_ends():
+    assert HIST_EDGES_S[0] <= 100e-6 and HIST_EDGES_S[-1] >= 60.0
+    assert all(1.0 < b / a <= 1.025
+               for a, b in zip(HIST_EDGES_S, HIST_EDGES_S[1:]))
+    hist = [0] * (len(HIST_EDGES_S) + 1)
+    assert hist_quantile(hist, 50) is None
+    hist[bisect_right(HIST_EDGES_S, -0.001)] += 1   # a clock set back
+    assert hist[0] == 1 and 0 <= hist_quantile(hist, 50) <= HIST_EDGES_S[0]
+    hist[bisect_right(HIST_EDGES_S, 3600.0)] += 2
+    assert hist[-1] == 2 and hist_quantile(hist, 90) == HIST_EDGES_S[-1]
+
+
 @pytest.mark.parametrize("entry", ["stream", "call"])
 def test_request_spans_join_the_callers_trace_from_its_own_thread(
         tiny_model, monkeypatch, entry):
@@ -341,5 +573,9 @@ def test_loop_phases_land_in_a_profiler_trace_and_tokens_are_the_same_without(
     assert names.count("engine.admit") >= 4
     (prefill,) = [s for name, s in events if name == "engine.prefill"]
     assert prefill["bucket"] == 16 and prefill["slot"] in (0, 1)
-    # Only the prefill span carries arguments: none on a per-step span.
-    assert all(not s for name, s in events if name == "engine.decode")
+    # A decode dispatch says what it found on the device, as the
+    # counters do: the step behind the prefill found nothing in flight.
+    feeds = [s["feed"] for name, s in events if name == "engine.decode"]
+    assert feeds[0] == "starved_lull" and set(feeds) <= set(FEEDS)
+    assert all(not s for name, s in events
+               if name in ("engine.inputs", "engine.readback", "engine.emit"))
