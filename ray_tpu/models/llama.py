@@ -36,6 +36,9 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.sharding import prune_spec, with_logical_constraint
 from ..parallel.mesh import mesh_axis_size
+from ..parallel.collective_matmul import (
+    gather_matmul, matmul_scatter, ring_size,
+)
 from ..parallel.ring_attention import ring_attention
 from ..parallel.moe import dispatch, moe_ffn
 from ..ops.attention import mha_attention
@@ -651,19 +654,35 @@ def prefill_attention_path(cfg: LlamaConfig, tokens: int) -> Optional[str]:
                         jnp.dtype(cfg.dtype).itemsize)
 
 
-def qkv_proj(cfg: LlamaConfig, lp, x):
+# What a ring matmul hands back is named, and the "dots" remat policy
+# keeps it as it keeps a plain matmul's output (to the policy the island
+# is no dot): the backward runs no ring a second time.
+_RING_SAVED = "tp_ring_matmul"
+
+
+def _ring_saved(out):
+    return jax.tree.map(lambda y: checkpoint_name(y, _RING_SAVED), out)
+
+
+def qkv_proj(cfg: LlamaConfig, lp, x, *, mesh=None):
     """The block's first half up to rotary: attention norm, then q
     [B,S,H,Dh] and k, v [B,S,Hkv,Dh], q and k normed where the model has
     a QK-norm (over their whole projection, or over each head's ``dh``),
     and the output gate sigmoid(h wg) [B,S,H,Dh] where it has one (else
     None); for a retention layer the fourth is the tokens' log gates
     log_sigmoid(h wg + bg) [B,S,Hkv] float32, which go to its attention
-    and not behind it."""
+    and not behind it. Under a mesh whose ``tp`` ring runs
+    (``ring_size``) x comes with its rows over ``tp`` and q, k, v leave
+    whole along them: the gather rides beside the three matmuls."""
     B, S, _ = x.shape
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
-    k = jnp.einsum("bsm,mhd->bshd", h, lp["wk"])
-    v = jnp.einsum("bsm,mhd->bshd", h, lp["wv"])
+    if ring_size(mesh, S) > 1:
+        q, k, v = _ring_saved(
+            gather_matmul(mesh, h, (lp["wq"], lp["wk"], lp["wv"])))
+    else:
+        q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
+        k = jnp.einsum("bsm,mhd->bshd", h, lp["wk"])
+        v = jnp.einsum("bsm,mhd->bshd", h, lp["wv"])
     if cfg.qk_norm and cfg.qk_norm_per_head:
         q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
@@ -809,9 +828,15 @@ def split_expert_stack(layers):
 
 def swiglu(h, w_gate, w_up, w_down, *, mesh=None):
     """The SiLU-gated MLP of h [B,S,M]: a dense layer's, a shared
-    expert's."""
-    up = jnp.einsum("bsm,mf->bsf", h, w_up)
-    gate = jnp.einsum("bsm,mf->bsf", h, w_gate)
+    expert's. Where the mesh's ``tp`` ring runs (``ring_size``) h comes
+    and the result leaves with its rows over ``tp``: the gather rides
+    beside ``w_up|w_gate``, the reduction beside ``w_down``."""
+    ring = ring_size(mesh, h.shape[1]) > 1
+    if ring:
+        up, gate = _ring_saved(gather_matmul(mesh, h, (w_up, w_gate)))
+    else:
+        up = jnp.einsum("bsm,mf->bsf", h, w_up)
+        gate = jnp.einsum("bsm,mf->bsf", h, w_gate)
     # Named for the selective "mlp" remat policy: saving these two
     # outputs (the widest matmuls — ~45% of a layer's forward FLOPs)
     # removes their backward recompute at a fraction of checkpoint_dots'
@@ -820,6 +845,8 @@ def swiglu(h, w_gate, w_up, w_down, *, mesh=None):
     gate = checkpoint_name(gate, "mlp_gate")
     h = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
     h = with_logical_constraint(h, ("batch", "seq", "mlp"), mesh=mesh)
+    if ring:
+        return _ring_saved(matmul_scatter(mesh, h, w_down))
     return jnp.einsum("bsf,fm->bsm", h, w_down)
 
 
@@ -860,6 +887,11 @@ def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
     (``route_tokens``); None: the router reads this half's normed input."""
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if "router" in lp:
+        if ring_size(mesh, x.shape[1]) > 1:
+            # The experts' dispatch takes whole rows: gathered by the
+            # partitioner here, and ``x + out`` below keeps x's layout.
+            h = with_logical_constraint(h, ("batch", "seq", "embed"),
+                                        mesh=mesh)
         w = lp if expert_stack is None else expert_stack
         if routed is None:
             routed = route_tokens(cfg, lp, h, mesh=mesh,
@@ -934,7 +966,7 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
         if kind == "latent_index":
             v = index_proj(cfg, lp, h, c_q, positions)
     else:
-        q, k, v, gate = qkv_proj(cfg, lp, x)
+        q, k, v, gate = qkv_proj(cfg, lp, x, mesh=mesh)
         if cfg.rope_full_layers or kind != "full":
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
@@ -946,7 +978,10 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
         attn, state = attend(q, k, v)
         if gate is not None:
             attn = attn * gate
-    attn = jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
+    if ring_size(mesh, x.shape[1]) > 1:
+        attn = _ring_saved(matmul_scatter(mesh, attn, lp["wo"]))
+    else:
+        attn = jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
     if cfg.post_norms:
         attn = rms_norm(attn, lp["post_attn_norm"], cfg.rms_eps)
     x = x + attn
@@ -981,7 +1016,9 @@ def remat_policy(cfg: LlamaConfig):
     if cfg.remat_policy == "dots":
         # Save ALL matmul outputs — least recompute, largest
         # footprint (OOMs the 8B-shaped bench: ~10 G HLO temp).
-        return jax.checkpoint_policies.checkpoint_dots
+        return jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.checkpoint_dots,
+            jax.checkpoint_policies.save_only_these_names(_RING_SAVED))
     if cfg.remat_policy == "mlp":
         # Selective (scaling-playbook style): save only the two
         # widest matmuls' outputs (up/gate, ~45% of forward
@@ -1017,8 +1054,12 @@ def hidden_forward(
     (hidden [B, S, M] after final_norm, moe_aux_loss scalar)."""
     require_uniform(cfg, "hidden_forward")
     B, S = tokens.shape
+    # Between the layers' matmul pairs the residual's rows lie over ``tp``
+    # where its ring runs (parallel/collective_matmul.py), else as ever.
+    ring = ring_size(mesh, S) > 1
+    residual = ("batch", "seq_tp" if ring else "seq", "embed")
     x = embed_tokens(params, tokens, cfg)
-    x = with_logical_constraint(x, ("batch", "seq", "embed"), mesh=mesh)
+    x = with_logical_constraint(x, residual, mesh=mesh)
     positions = jnp.arange(S)
     policy = remat_policy(cfg)
 
@@ -1036,7 +1077,7 @@ def hidden_forward(
             out, aux = jax.checkpoint(layer, policy=policy)(x, lp)
         else:
             out, aux = layer(x, lp)
-        out = with_logical_constraint(out, ("batch", "seq", "embed"), mesh=mesh)
+        out = with_logical_constraint(out, residual, mesh=mesh)
         return out, aux
 
     if cfg.scan_layers:
@@ -1066,13 +1107,9 @@ def hidden_forward(
                 chunk_fn = jax.checkpoint(chunk_fn, policy=policy)
 
             def chunk_body(x_, cp):
-                x_ = with_logical_constraint(
-                    x_, ("batch", "seq", "embed"), mesh=mesh
-                )
+                x_ = with_logical_constraint(x_, residual, mesh=mesh)
                 out, aux = chunk_fn(x_, cp)
-                out = with_logical_constraint(
-                    out, ("batch", "seq", "embed"), mesh=mesh
-                )
+                out = with_logical_constraint(out, residual, mesh=mesh)
                 return out, aux
 
             x, aux = jax.lax.scan(chunk_body, x, chunked)
@@ -1084,6 +1121,9 @@ def hidden_forward(
             x, a = body(x, lp)
             aux = aux + a
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    if ring:
+        # The head reads whole rows: gathered once, not once a loss chunk.
+        x = with_logical_constraint(x, ("batch", "seq", "embed"), mesh=mesh)
     return x, aux
 
 

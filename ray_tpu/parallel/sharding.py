@@ -24,7 +24,12 @@ Rule = Tuple[str, Union[str, Tuple[str, ...], None]]
 # Default rules for transformer training:
 #  - batch splits over dp+fsdp (each fsdp rank sees different data)
 #  - sequence splits over sp (ring attention axis)
-#  - attention heads + mlp hidden split over tp (Megatron-style)
+#  - attention heads + mlp hidden split over tp (Megatron-style); between a
+#    row-parallel matmul and the next column-parallel one the residual
+#    stream's rows (its sequence, "seq_tp") lie split over tp too, so the
+#    pair's all-reduce is a reduce-scatter behind the one and an
+#    all-gather before the other (parallel/collective_matmul.py; the model
+#    names "seq_tp" only where ring_size() says the ring runs)
 #  - embed (params' fsdp shard dim) splits over fsdp: ZeRO-3-equivalent
 #  - experts split over ep
 #  - layer stages split over pp (for stacked-layer pipeline params)
@@ -32,6 +37,7 @@ DEFAULT_RULES: Tuple[Rule, ...] = (
     ("batch", ("dp", "fsdp")),
     ("seq", "sp"),
     ("kv_seq", "sp"),
+    ("seq_tp", "tp"),
     ("embed", "fsdp"),
     ("heads", "tp"),
     ("kv_heads", "tp"),

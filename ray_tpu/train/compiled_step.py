@@ -10,10 +10,17 @@ module is the single train-step authority (ROADMAP item 3):
 - **One XLA program** per step: forward (chunked-scan schedule,
   models/llama.py), backward, optimizer update and — under a mesh — the
   GSPMD-inserted grad all-reduces, compiled together via pjit (jax.jit
-  with shardings) so XLA schedules collectives against compute. The
-  chunked loss's head is the exception, placed by hand: left to GSPMD it
+  with shardings) so XLA schedules collectives against compute. Two
+  things are placed by hand. The chunked loss's head: left to GSPMD it
   is gathered and its gradient reduced once a loss chunk, so
-  models/llama.py:causal_lm_loss asks for it whole before the scans.
+  models/llama.py:causal_lm_loss asks for it whole before the scans. And
+  ``tp``'s collectives round the layers' matmul pairs: left to GSPMD
+  each is one all-reduce with nothing beside it, so under a mesh whose
+  ``tp`` axis is over 1 the residual lies split along the sequence over
+  it and the reduce-scatter and all-gather go round a ring a chunk at a
+  time beside the matmuls (parallel/collective_matmul.py, called from
+  models/llama.py). ``fsdp``'s weight gathers and gradient reductions
+  and every collective of the loss are GSPMD's.
 - **In-place buffer donation**: params + optimizer state donate their
   buffers into the step (``donate_argnums=(0, 1)``) — the update aliases
   the old arena instead of doubling it.

@@ -580,6 +580,85 @@ def test_the_head_is_gathered_once_a_step_not_once_a_loss_chunk(
     assert len(kinds) - kinds.count("all-gather") == 1, found
 
 
+_MOVES_ROWS = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+
+
+def _collectives_in_loops(text):
+    """``(kind, result shapes, body)`` of every collective that stands in
+    a ``while`` body of a compiled step's text (an asynchronous one at
+    its ``-start``), a fusion that wraps one under the wrapped kind."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    found, computation = [], None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            computation = line.split()[1 if line.startswith("ENTRY") else 0]
+            computation = computation.lstrip("%")
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if computation not in bodies or not m:
+            continue
+        result, opcode = m["result"], m["op"]
+        kind = opcode.removesuffix("-start")
+        if opcode == "fusion":
+            wrapped = re.search(
+                r"calls=%?[\w.\-]*(reduce-scatter|all-reduce|all-gather)",
+                line)
+            kind = wrapped.group(1) if wrapped else None
+        if kind in _MOVES_ROWS:
+            found.append((kind, set(re.findall(r"\w+\[([\d,]+)\]", result)),
+                          computation))
+    return found
+
+
+def test_the_residual_lies_over_tp_between_the_matmul_pairs(
+        v5e_host, as_tpu):
+    """``train-nemo12b-4chip``'s layers at tiny widths under
+    ``fsdp=2 x tp=2``: a chip's residual is ``[b, S, M]`` = [2, 512, 256].
+    No all-reduce (nor a fusion that wraps one) with that result stands
+    in a ``while`` body, forward or backward: between a row-parallel
+    matmul and the next column-parallel one the rows lie split over
+    ``tp``, and what moves them there has ``[b, S/2, M]``, a
+    collective-permute beside the matmuls in both of the layer scans'
+    bodies (parallel/collective_matmul.py).
+
+    Fails on the tree before PR 61: there each of the two bodies holds
+    four all-reduces of ``[2, 512, 256]`` (``wo``'s and ``w_down``'s
+    outputs summed whole, nothing running beside them) and nothing of
+    ``[2, 256, 256]``."""
+    from ray_tpu.parallel import make_mesh
+
+    mesh = make_mesh(devices=v5e_host, dp=1, fsdp=2, tp=2)
+    cfg = _dense_cfg()
+    b, S, M = 4 // 2, 512, cfg.hidden_size
+    found = _collectives_in_loops(_dense_train_step(cfg, mesh))
+    whole, half = f"{b},{S},{M}", f"{b},{S // 2},{M}"
+    assert not [(kind, body) for kind, shapes, body in found
+                if kind == "all-reduce" and whole in shapes], found
+    halves = [(kind, body) for kind, shapes, body in found
+              if half in shapes]
+    assert {kind for kind, _ in halves} <= {
+        "collective-permute", "reduce-scatter", "all-gather"}, halves
+    # Forward and backward scan, a hop a matmul site a layer or more.
+    per_body = {body: sum(1 for _, at in halves if at == body)
+                for _, body in halves}
+    assert len(per_body) == 2 and min(per_body.values()) >= 8, per_body
+
+
+def test_one_device_step_holds_no_collective(v5e_host, as_tpu):
+    """``train-mistral7b-1chip``'s side of the same rule: on a mesh of
+    one described v5e device every axis is pruned, the ring's size is 1
+    and the compiled step's text holds no collective of any kind."""
+    from ray_tpu.parallel import make_mesh
+    from ray_tpu.parallel.collective_matmul import ring_size
+
+    mesh = make_mesh(devices=v5e_host[:1], dp=1, fsdp=1, tp=1)
+    assert ring_size(mesh, 512) == 1
+    text = _dense_train_step(_dense_cfg(), mesh)
+    assert "tpu_custom_call" in text              # the flash kernels
+    assert not re.search("|".join(_MOVES_ROWS), text)
+
+
 def _trinity():
     """``trinity-mini-L6`` as the benchmark builds it, and its engine."""
     import json

@@ -2,6 +2,8 @@
 and the HBM/fragmentation probe plumbing (ISSUE 10 tentpole)."""
 
 import dataclasses
+import functools
+import math
 
 import numpy as np
 import pytest
@@ -291,6 +293,141 @@ def test_chunked_loss_asks_for_nothing_where_the_head_lies_whole():
         sharded = make_mesh(dp=1, fsdp=2, tp=2)
         assert (named_sharding(sharded, (None, "vocab"))
                 != named_sharding(sharded, ("embed", "vocab")))
+
+
+# The residual stream under `tp`: its rows lie split over the axis between
+# a row-parallel matmul and the next column-parallel one, and the chunks
+# go round the ring beside the matmuls (parallel/collective_matmul.py).
+_RING_CASES = {
+    # The four-chip cell's own mesh, its remat policy and chunked scan.
+    "fsdp2-tp2-dots-chunk2": ({"dp": 1, "fsdp": 2, "tp": 2}, "dots", 2, 4),
+    "fsdp2-tp2-full-scan": ({"dp": 1, "fsdp": 2, "tp": 2}, "full", 0, 4),
+    "dp2-fsdp2-tp2-mlp": ({"dp": 2, "fsdp": 2, "tp": 2}, "mlp", 1, 4),
+    # A ring of four: three hops a matmul, 32 rows in chunks of 8.
+    "tp4-dots-unrolled": ({"dp": 1, "fsdp": 1, "tp": 4}, "dots", 4, 4),
+}
+
+
+@pytest.mark.parametrize("axes,policy,chunk,kv_heads",
+                         list(_RING_CASES.values()), ids=list(_RING_CASES))
+def test_step_with_the_residual_over_tp_matches_the_unsharded_step(
+        axes, policy, chunk, kv_heads):
+    """Under a mesh whose ``tp`` ring runs, the loss AND every gradient
+    leaf, in float32, are the unsharded step's: the ring's sums are the
+    all-reduce's sums, and a weight's gradient is one contraction over
+    all the rows."""
+    from ray_tpu.models.llama import param_logical_axes
+    from ray_tpu.parallel import make_mesh
+    from ray_tpu.parallel.collective_matmul import ring_size
+    from ray_tpu.parallel.sharding import named_sharding, shard_pytree
+
+    if len(jax.devices()) < axes["dp"] * axes["fsdp"] * axes["tp"]:
+        pytest.skip("needs 8 virtual devices")
+    cfg = _tiny(depth=4, scan_layers=True, scan_chunk=chunk, loss_chunk=8,
+                remat_policy=policy, num_kv_heads=kv_heads)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.asarray(np.random.RandomState(6).randint(0, 256, (8, 33)))
+    ref_loss, ref_grads = _loss_and_grads(cfg, params, tokens)
+
+    mesh = make_mesh(**axes)
+    assert ring_size(mesh, 32) == axes["tp"]
+    params = shard_pytree(params, mesh, param_logical_axes(cfg))
+    tokens = jax.device_put(tokens, named_sharding(mesh, ("batch", "seq")))
+    step = jax.jit(jax.value_and_grad(
+        lambda p: causal_lm_loss(p, tokens, cfg, mesh)))
+    assert "collective-permute" in step.lower(params).compile().as_text()
+    loss, grads = step(params)
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-6)
+    for path, got in jax.tree_util.tree_leaves_with_path(grads):
+        want = functools.reduce(lambda t, k: t[k.key], path, ref_grads)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-6,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_expert_layers_train_under_the_ring_as_without_a_mesh():
+    """A layer with a ``router`` takes the ring for its attention half
+    and whole rows for its experts (``ffn`` asks for them): loss and
+    gradients under ``fsdp=2 x tp=2`` are the unsharded ones."""
+    from ray_tpu.models.llama import param_logical_axes
+    from ray_tpu.parallel import make_mesh
+    from ray_tpu.parallel.sharding import named_sharding, shard_pytree
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    cfg = dataclasses.replace(LlamaConfig.tiny(moe=True), num_layers=2,
+                              num_kv_heads=4, loss_chunk=8)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.asarray(np.random.RandomState(7).randint(0, 256, (4, 33)))
+    ref_loss, ref_grads = _loss_and_grads(cfg, params, tokens)
+    mesh = make_mesh(dp=1, fsdp=2, tp=2)
+    params = shard_pytree(params, mesh, param_logical_axes(cfg))
+    tokens = jax.device_put(tokens, named_sharding(mesh, ("batch", "seq")))
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: causal_lm_loss(p, tokens, cfg, mesh)))(params)
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
+    for name in ("wq", "wo", "router", "w_up", "w_down"):
+        np.testing.assert_allclose(
+            np.asarray(grads["layers"][name]),
+            np.asarray(ref_grads["layers"][name]), rtol=5e-4, atol=5e-6,
+            err_msg=name)
+
+
+# (mesh axes or None, rows): where the ring must not run.
+_NO_RING = {
+    "no-mesh": (None, 32),
+    "one-device": ({"dp": 1, "fsdp": 1, "tp": 1}, 32),
+    "fsdp-only": ({"dp": 1, "fsdp": 4, "tp": 1}, 32),
+    "dp-fsdp": ({"dp": 2, "fsdp": 2, "tp": 1}, 32),
+    # Ring attention has the sequence: its layout is left as it was.
+    "sp-tp": ({"dp": 1, "sp": 2, "tp": 2}, 32),
+    # 33 rows do not divide by two.
+    "tp-odd-rows": ({"dp": 1, "fsdp": 1, "tp": 2}, 33),
+}
+
+
+@pytest.mark.parametrize("axes,rows", list(_NO_RING.values()),
+                         ids=list(_NO_RING))
+def test_the_ring_is_absent_where_tp_has_no_rows_to_split(axes, rows):
+    """No ``tp`` axis (or none of size over 1), an ``sp`` axis, or rows
+    that do not divide: ``ring_size`` is 1 and the model traces no
+    island (no ``shard_map`` and no ``ppermute`` but ring attention's
+    own). Where the mesh has no ``tp`` to split over, the rule's axis is
+    pruned and the residual's spec is the one it had before there was a
+    ring. With no mesh at all a constraint is the identity and the step
+    traces none."""
+    from ray_tpu.parallel import make_mesh
+    from ray_tpu.parallel.collective_matmul import ring_size
+    from ray_tpu.parallel.sharding import (
+        logical_to_spec, prune_spec, with_logical_constraint,
+    )
+
+    cfg = _tiny(depth=2, scan_layers=True, scan_chunk=2, loss_chunk=8,
+                remat_policy="dots", num_kv_heads=4, use_flash=False)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((8, rows + 1), jnp.int32)
+    mesh, ring_attention = None, False
+    if axes is None:
+        x = jnp.ones((2, 4, 8))
+        assert with_logical_constraint(
+            x, ("batch", "seq_tp", "embed"), mesh=None) is x
+    else:
+        size = math.prod(axes.values())
+        if len(jax.devices()) < size:
+            pytest.skip("needs 8 virtual devices")
+        mesh = make_mesh(devices=jax.devices()[:size], **axes)
+        ring_attention = axes.get("sp", 1) > 1
+        if axes["tp"] == 1:
+            assert (prune_spec(mesh, logical_to_spec(
+                        ("batch", "seq_tp", "embed")))
+                    == prune_spec(mesh, logical_to_spec(
+                        ("batch", "seq", "embed"))))
+    assert ring_size(mesh, rows) == 1
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda p, t: causal_lm_loss(p, t, cfg, mesh)))(params, tokens))
+    assert ring_attention or "ppermute" not in jaxpr
+    assert ring_attention or "shard_map" not in jaxpr
+    assert ("sharding_constraint" in jaxpr) is (mesh is not None)
 
 
 # ------------------------------------------------------- HBM probe
