@@ -1,9 +1,9 @@
 """The serving programs of the Llama family over the paged KV cache.
 
 The engine's cache is the paged one (``PagedKVCache``): for each of the
-four attention kinds a model may have ("full", "window", "latent",
-"state") a pool, and for the three that keep a row a token a shared
-pool of token pages and a page table a slot. Two jitted programs use
+five attention kinds a model may have ("full", "window", "latent",
+"state", "delta") a pool, and for the three that keep a row a token a
+shared pool of token pages and a page table a slot. Two jitted programs use
 it, both
 built on the one transformer block (``llama.block``) with an attention
 of their own, both a layer scan for each run of alike layers
@@ -47,6 +47,17 @@ padding masked out of it (a gate of 1, a key of 0), and lays the state
 it is left with into the slot; the decode step updates every active
 slot's state in place and reads the token's output from it.
 
+A delta layer (kind "delta", ops/delta_attention.py) keeps a state a
+slot as well, [heads, width, width] float32, and beside it the last
+``delta_conv - 1`` input rows of its short convolution; its layers stand
+AMONG latent ones, so one request holds a slot of the two "delta" pools
+and pages of the latent pool, under one admission and one release. The
+prefill lays both from one prompt: the chunked delta rule from an empty
+state with the bucket's padding masked out of it (no decay, no write),
+the convolution's history taken at the last real token, not at the
+bucket's end. The decode step shifts the history by the token's row and
+updates each active slot's state in place.
+
 What the pools hold for whom is kept on the host by ``KVBooks``; the
 serving engine reserves and releases through it and names no kind.
 
@@ -67,6 +78,10 @@ from ..ops.paged_attention import (
     decode_attention, decode_attention_path, latent_decode_attention,
     ring_pages, walk_step_tokens,
 )
+from ..ops.delta_attention import (
+    delta_decode, delta_path, delta_prefill,
+    state_shape as delta_state_shape,
+)
 from ..ops.retention import (
     retention_decode, retention_path, retention_prefill, state_shape,
 )
@@ -75,10 +90,14 @@ from ..ops.sparse_attention import (
     sparse_prefill_attention,
 )
 from .llama import (
-    LlamaConfig, block, causal_attention, embed_tokens, index_offsets,
-    kv_layers, latent_absorb_out, latent_absorb_q, latent_kv, layer_runs,
-    layer_stacks, pool_kind, rms_norm, split_expert_stack,
+    LlamaConfig, block, causal_attention, delta_mix, embed_tokens,
+    index_offsets, kv_layers, latent_absorb_out, latent_absorb_q, latent_kv,
+    layer_runs, layer_stacks, pool_kind, rms_norm, split_expert_stack,
 )
+
+# The kinds whose pools hold a slot's state and no row a token: no pages,
+# no table column, admission by slot alone.
+SLOT_KINDS = ("state", "delta")
 
 
 class MoeLoad(NamedTuple):
@@ -141,6 +160,12 @@ class PagedKVCache(NamedTuple):
     table has no column), a slot is all a request needs of it, a prefill
     overwrites the slot's state whole and nothing is zeroed at release.
     The class keeps its name though such a model pages nothing.
+    A "delta" layer (the delta rule) keeps a state a slot likewise,
+    ``k["delta"]`` [L, B, H, D, D] float32, and under ``v["delta"]`` the
+    convolution's history, [L, taps - 1, B, ``cfg.delta_row``] in the
+    model's dtype (the slots second to last: a layer's slice of a tap is
+    whole tiles); no pages either. Its layers lie among latent ones, so
+    such a model has these two pools BESIDE the latent pool and its table.
 
     A k/v pool is HEAD-MAJOR
     ([L_kind, Hkv, P_kind, page, Dh]): one copy brings a page of every KV
@@ -155,7 +180,8 @@ class PagedKVCache(NamedTuple):
     step before PR 29, PERF.md §6)."""
 
     k: Dict[str, jax.Array]            # kind -> [L_kind, Hkv, P, page, Dh]
-    v: Dict[str, jax.Array]            # ("latent", "index", "state": k alone)
+    v: Dict[str, jax.Array]            # ("latent", "index", "state": k alone;
+    #                                    "delta": the convolution's history)
     page_table: Dict[str, jax.Array]   # kind -> [B, columns] int32 page ids
     lengths: jax.Array                 # [B] int32 valid tokens per slot
 
@@ -166,11 +192,11 @@ class PagedKVCache(NamedTuple):
     def page_size(self) -> Optional[int]:
         """Tokens a page holds; None for a model that pages nothing."""
         return next((pool.shape[-2] for kind, pool in self.k.items()
-                     if kind != "state"), None)
+                     if kind not in SLOT_KINDS), None)
 
     def pools(self, kind: str) -> Tuple[jax.Array, ...]:
         """The pools of ``kind``: (k, v), or the one of latent rows, or
-        the one of states."""
+        the one of states, or (states, convolution histories)."""
         return tuple(d[kind] for d in (self.k, self.v) if kind in d)
 
     @staticmethod
@@ -184,7 +210,7 @@ class PagedKVCache(NamedTuple):
                 ring = ring_pages(cfg.sliding_window, page_size,
                                   max_pages_per_seq)
                 out[kind] = (layers, batch * ring, ring)
-            elif kind == "state":
+            elif kind in SLOT_KINDS:
                 out[kind] = (layers, 0, 0)
             elif kind in PagedKVCache.RIDES:
                 out[kind] = (layers, total_pages, 0)
@@ -204,6 +230,10 @@ class PagedKVCache(NamedTuple):
             if kind == "state":
                 return jnp.zeros(state_shape(layers, batch, cfg.num_kv_heads,
                                              cfg.dh), dtype=jnp.float32)
+            if kind == "delta":
+                return jnp.zeros(delta_state_shape(
+                    layers, batch, cfg.delta_heads, cfg.delta_head_dim),
+                    dtype=jnp.float32)
             if kind == "latent":
                 return jnp.zeros((layers, pages, page_size, cfg.latent_row),
                                  dtype=cfg.dtype)
@@ -215,13 +245,18 @@ class PagedKVCache(NamedTuple):
                 (layers, cfg.num_kv_heads, pages, page_size, cfg.dh),
                 dtype=cfg.dtype)
 
-        def pools(one_only):
-            return {kind: pool(kind, layers, pages)
-                    for kind, (layers, pages, _) in sizes.items()
-                    if one_only or kind not in ("latent", "index", "state")}
-
+        first = {kind: pool(kind, layers, pages)
+                 for kind, (layers, pages, _) in sizes.items()}
+        # A v pool beside a k pool of rows a head; beside the delta
+        # states the convolution's histories.
+        second = {kind: jnp.zeros_like(first[kind])
+                  for kind in first if kind in ("full", "window")}
+        if "delta" in sizes:
+            second["delta"] = jnp.zeros(
+                (sizes["delta"][0], cfg.delta_conv - 1, batch,
+                 cfg.delta_row), dtype=cfg.dtype)
         return PagedKVCache(
-            k=pools(True), v=pools(False),
+            k=first, v=second,
             page_table={kind: jnp.zeros((batch, columns), dtype=jnp.int32)
                         for kind, (_, _, columns) in sizes.items()
                         if kind not in PagedKVCache.RIDES},
@@ -288,9 +323,15 @@ class KVBooks:
             layers for layers, pages, _ in self.pools.values() if not pages)
         # ``free_pages``: of the pool that keeps everything, or the only.
         self._gauge = "full" if "full" in self.pools else next(
-            iter(self.pools))
+            (kind for kind, (_, pages, _) in self._own.items() if pages),
+            next(iter(self.pools)))
         # What the decode program is built with: the same call
         # paged_decode's attention makes when the program is traced.
+        # A model's delta layers: ops/delta_attention.py's path; "none"
+        # for a model that has none.
+        self.decode_delta = (
+            delta_path(cfg.delta_head_dim, cfg.delta_heads)
+            if "delta" in self.pools else "none")
         if cfg.retention:
             self.decode_attention = retention_path(cfg.dh)
         elif cfg.latent:
@@ -415,6 +456,7 @@ class KVBooks:
             "total_pages": self.total_pages,
             "page_size": self.page_size,
             "decode_attention": self.decode_attention,
+            "decode_delta": self.decode_delta,
             "page_walk_step_tokens": dict(self.page_walk_step_tokens),
         }
 
@@ -447,7 +489,8 @@ def paged_decode(
     as they are, and it reaches no expert: the experts a step reads
     follow the live sequences. A "state" layer's pool is carried the
     same way; its step updates each active slot's state and leaves an
-    inactive slot's as it is."""
+    inactive slot's as it is; a "delta" layer's two pools likewise, so
+    that a model of delta layers among latent ones steps all three."""
     x = embed_tokens(params, tokens, cfg)[:, None]
     pools = {kind: cache.pools(kind) for kind in cache.k}
     expert_tokens = []
@@ -515,11 +558,31 @@ def paged_decode(
                         lp["index"] + run.kv_offset, active)
                 return out[:, None], ((pool,), keys, selected)
 
+            def attend_delta(q, k, packed):
+                # The convolution's history shifted by the token's row,
+                # then the state's step: each its own pool, both at the
+                # layer, an idle slot's left as they are.
+                v, log_a, beta = packed
+                states, histories = held
+                layer = lp["index"] + run.kv_offset
+                history = histories[layer]            # [taps-1, B, row]
+                q, k, v, rows = delta_mix(cfg, lp, q, k, v,
+                                          history.transpose(1, 0, 2))
+                with jax.named_scope("kda.conv"):
+                    histories = histories.at[layer].set(jnp.where(
+                        active[None, :, None],
+                        rows[:, 1:].transpose(1, 0, 2), history))
+                with jax.named_scope("attn.delta"):
+                    out, states = delta_decode(
+                        q[:, 0], k[:, 0], v[:, 0], log_a[:, 0], beta[:, 0],
+                        states, layer, active)
+                return out[:, None], ((states, histories), keys, selected)
+
             # The load-balancing loss is a training-only term: dropped.
             x, kept, _aux, load = block(
                 cfg, lp, x, cache.lengths[:, None],
-                {"latent": attend_latent, "state": attend_state}.get(
-                    pool, attend),
+                {"latent": attend_latent, "state": attend_state,
+                 "delta": attend_delta}.get(pool, attend),
                 token_mask=active[:, None], expert_stack=expert_stack,
                 kind=kind)
             return (x,) + kept, load
@@ -564,7 +627,10 @@ def paged_prefill(
     ``real_len - 1``; for "state" none: what a retention layer keeps
     is the state after token ``real_len - 1``, laid into the slot whole,
     and the padding never reaches it (a padded token's gate is 1 and
-    its key 0), so that a prompt leaves the same state in any bucket.
+    its key 0), so that a prompt leaves the same state in any bucket;
+    for "delta" none either: the state after token ``real_len - 1`` and
+    the convolution's last ``delta_conv - 1`` real input rows, both laid
+    into the slot whole, beside the latent layers' rows in their pages.
     Returns the run's ``MoeLoad`` too (None for a dense model)."""
     S = tokens.shape[1]
     page = cache.page_size
@@ -583,6 +649,14 @@ def paged_prefill(
             # [n, Hkv, T, R, Dh]: the slot's states of the run's layers.
             return jax.lax.dynamic_update_slice(
                 pool, rows[:, None], (offset, slot, 0, 0, 0, 0))
+        if run.kind == "delta":
+            # The slot's states [n, H, D, D], whole, or its convolution
+            # histories [n, taps - 1, row], the slots second to last.
+            rows, at = ((rows[:, None], (offset, slot, 0, 0, 0))
+                        if rows.ndim == 4 else
+                        (rows[:, :, None], (offset, 0, slot, 0)))
+            return jax.lax.dynamic_update_slice(
+                pool, rows.astype(pool.dtype), at)
         ids = pages[pool_kind(run.kind)]
         whole = run.n == pool.shape[0]
         at = slice(None) if whole else slice(offset, offset + run.n)
@@ -653,10 +727,29 @@ def paged_prefill(
                         v[0], jnp.where(real[:, None], log_g[0], 0.0))
                 return out[None], ((state,), selected)
 
+            def attend_delta(q, k, packed):
+                # From nothing before the prompt; the padding neither
+                # decays nor writes, and the history kept is the last
+                # real tokens' rows (token t lies at row t + taps - 1).
+                v, log_a, beta = packed
+                real = positions < real_len
+                taps = cfg.delta_conv
+                q, k, v, rows = delta_mix(
+                    cfg, lp, q, k, v,
+                    jnp.zeros((1, taps - 1, cfg.delta_row), q.dtype))
+                history = jax.lax.dynamic_slice_in_dim(
+                    rows[0], real_len, taps - 1)
+                with jax.named_scope("attn.delta"):
+                    out, state = delta_prefill(
+                        q[0], k[0], v[0],
+                        jnp.where(real[:, None, None], log_a[0], 0.0),
+                        jnp.where(real[:, None], beta[0], 0.0))
+                return out[None], ((state, history), selected)
+
             x, (kept, selected), _aux, load = block(
                 cfg, lp, x, positions,
-                {"latent": attend_latent, "state": attend_state}.get(
-                    pool, attend),
+                {"latent": attend_latent, "state": attend_state,
+                 "delta": attend_delta}.get(pool, attend),
                 token_mask=token_mask, expert_stack=expert_stack, kind=kind)
             return (x, selected), (kept, load)
 

@@ -127,8 +127,24 @@ class LlamaConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # ``q_lora_rank`` 0: no q bottleneck, one ``wq`` from the layer's
+    # input. ``latent_rope`` off: the ``qk_rope_head_dim`` values of q and
+    # of the shared key are left unrotated (a model whose positions come
+    # from its other layers).
+    latent_rope: bool = True
     # Rotary over adjacent pairs (2i, 2i+1) instead of the two halves.
     rope_interleave: bool = False
+    # Layers of kind "delta" among latent ones (``layer_types`` names
+    # each layer "latent" or "delta"): a gated delta rule with a decay a
+    # channel (ops/delta_attention.py) behind a causal depthwise
+    # convolution of ``delta_conv`` taps on q, k and v, ``delta_heads``
+    # heads of ``delta_head_dim``, no rotary. What such a layer keeps is
+    # a state [heads, width, width] float32 a slot and the convolution's
+    # last ``delta_conv - 1`` input rows, no row a token
+    # (generation.PagedKVCache's "delta" pools, beside the "latent" one).
+    delta_heads: int = 0
+    delta_head_dim: int = 0
+    delta_conv: int = 0
     # A learned selection over the latent pool (DSA) when ``index_topk``
     # > 0: a layer that ``indexer_types`` calls "full" scores every
     # cached token with an indexer of its own (``index_n_heads`` queries
@@ -209,6 +225,12 @@ class LlamaConfig:
     def retention(self) -> bool:
         return bool(self.layer_types) and "state" in self.layer_types
 
+    @property
+    def delta_row(self) -> int:
+        """Values of a token's row into a delta layer's convolution:
+        its q, k and v projections side by side."""
+        return 3 * self.delta_heads * self.delta_head_dim
+
     def window(self, kind: str) -> Optional[int]:
         """The attention window of a layer of ``kind``; None for none."""
         return self.sliding_window if kind == "window" else None
@@ -242,9 +264,10 @@ class LayerRun(NamedTuple):
     start: int
     n: int
     moe: bool
-    # "full" | "window" | "latent" | "state", or a latent layer that
-    # attends over a selection: "latent_index" makes one, "latent_shared"
-    # takes the last one made. Both keep their rows in the "latent" pool.
+    # "full" | "window" | "latent" | "state" | "delta", or a latent
+    # layer that attends over a selection: "latent_index" makes one,
+    # "latent_shared" takes the last one made. Both keep their rows in
+    # the "latent" pool.
     kind: str
     kv_offset: int
 
@@ -258,16 +281,25 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
     """The model's layers as an ordered list of uniform runs, from what
     the config states; everything about a layer that a program needs to
     know when it is traced. Llama, Mistral, OLMoE: one run, "full". A
-    model with latent attention: every layer "latent"; one with power
-    retention: every layer "state"."""
+    model with latent attention: every layer "latent", or each layer
+    "latent" or "delta" as ``layer_types`` says (a state a slot beside
+    the latent pool); one with power retention: every layer "state"."""
     if cfg.latent:
-        if cfg.layer_types or cfg.dh != (cfg.qk_nope_head_dim
-                                          + cfg.qk_rope_head_dim):
+        kinds = cfg.layer_types or ("latent",) * cfg.num_layers
+        if (len(kinds) != cfg.num_layers or set(kinds) - {"latent", "delta"}
+                or cfg.dh != cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                or (cfg.index_topk and "delta" in kinds)):
             raise ValueError(
-                f"latent attention: every layer is of the one kind (no "
-                f"layer_types) and head_dim ({cfg.dh}) is the q.k width, "
-                f"qk_nope_head_dim + qk_rope_head_dim")
-        kinds = ("latent",) * cfg.num_layers
+                f"latent attention: layer_types {cfg.layer_types} names "
+                f"'latent' or 'delta' for each of {cfg.num_layers} layers "
+                f"(none: all latent; no delta layer under a selection) and "
+                f"head_dim ({cfg.dh}) is the q.k width, qk_nope_head_dim + "
+                f"qk_rope_head_dim")
+        if "delta" in kinds and not (cfg.delta_heads and cfg.delta_head_dim
+                                     and cfg.delta_conv > 1):
+            raise ValueError(
+                "delta layers need delta_heads, delta_head_dim and a "
+                "delta_conv of two taps or more")
         if cfg.index_topk:
             types = cfg.indexer_types or ()
             if (len(types) != cfg.num_layers or types[0] != "full"
@@ -286,8 +318,10 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
                 or cfg.attn_gate or cfg.num_heads % cfg.num_kv_heads):
             raise ValueError(
                 f"power retention: every one of {cfg.num_layers} layers is "
-                f"of kind 'state' (a state beside a KV cache in one model "
-                f"is not implemented: ROADMAP R7), whole groups of query "
+                f"of kind 'state' (power retention beside another kind "
+                f"in one model is not implemented, ROADMAP R7; a 'delta' "
+                f"layer's state does lie beside a latent pool), whole "
+                f"groups of query "
                 f"heads a KV head, and no attn_gate (its wg is the "
                 f"retention gate's name); got {kinds}")
     else:
@@ -306,7 +340,8 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
             f"{sorted(_EXPERT_ACTS)}")
     alike = [(cfg.n_experts > 0 and i >= cfg.num_dense_layers, kind)
              for i, kind in enumerate(kinds)]
-    runs, seen = [], dict.fromkeys(("full", "window", "latent", "state"), 0)
+    runs, seen = [], dict.fromkeys(
+        ("full", "window", "latent", "state", "delta"), 0)
     for i, (moe, kind) in enumerate(alike):
         if runs and alike[i - 1] == (moe, kind):
             runs[-1] = runs[-1]._replace(n=runs[-1].n + 1)
@@ -354,16 +389,18 @@ def require_uniform(cfg: LlamaConfig, what: str) -> None:
     """Training scans ONE stack with ONE causal attention, and the flash
     backward kernels take no window and one width for q, k and v: a
     stack in runs, a window layer, a latent-attention layer or a
-    retention layer (kind "state": its chunked scan has no backward)
-    trains nowhere yet (ROADMAP R3, R5, R7), and says so by name."""
+    retention or delta-rule layer (kinds "state", "delta": their chunked
+    scans have no backward) trains nowhere yet (ROADMAP R3, R5, R7), and
+    says so by name."""
     if len(layer_runs(cfg)) > 1 or set(kv_layers(cfg)) - {"full"}:
         raise NotImplementedError(
             f"{what}: training a model whose layer stack is not uniform "
             f"(dense layers before expert layers, window beside full "
             f"attention), whose attention is latent (q.k and v of "
-            f"unequal widths) or whose layers are of kind 'state' (power "
-            f"retention: the chunked scan has no backward pass) is not "
-            f"implemented; it is served only (models/generation.py)")
+            f"unequal widths) or whose layers are of kind 'state' or "
+            f"'delta' (power retention, the delta rule: the chunked scans "
+            f"have no backward pass) is not implemented; it is served only "
+            f"(models/generation.py)")
 
 
 # Logical axes for each parameter leaf (maps through DEFAULT_RULES:
@@ -447,6 +484,48 @@ def _retention_gate_bias(n: int, kv_heads: int) -> jax.Array:
     return jnp.broadcast_to(jnp.log(tau - 1.0), (n, kv_heads))
 
 
+def _init_delta(cfg: LlamaConfig, k, n: int) -> Dict[str, Any]:
+    """The attention half of ``n`` delta layers: q, k, v and o, the
+    convolution's taps (``conv_w`` [taps, q|k|v channels]), the decay
+    gate through a bottleneck of the head's width (``wf_a``, ``wf_b``,
+    ``a_log`` a head, ``dt_bias`` a channel), the write strength
+    (``wb``), the output gate through the same bottleneck (``wg_a``,
+    ``wg_b``) and the norm on each head's output.
+
+    log a = -exp(a_log) softplus(f + dt_bias): ``a_log`` is log U(1, 16)
+    and ``dt_bias`` the inverse softplus of a step log-uniform in
+    [1e-3, 0.1], so that with f = 0 a channel forgets over between a few
+    tokens and a thousand (a decay drawn around 1/2 would exercise
+    neither a long context nor the float32 state)."""
+    M, H, D, dt = (cfg.hidden_size, cfg.delta_heads, cfg.delta_head_dim,
+                   cfg.dtype)
+
+    def winit(shape, fan_in):
+        return _normal(next(k), shape, fan_in ** -0.5, dt)
+
+    layers = {
+        "attn_norm": jnp.ones((n, M), jnp.float32),
+        "wq": winit((n, M, H, D), M),
+        "wk": winit((n, M, H, D), M),
+        "wv": winit((n, M, H, D), M),
+        "wo": winit((n, H, D, M), H * D),
+        "mlp_norm": jnp.ones((n, M), jnp.float32),
+        "conv_w": winit((n, cfg.delta_conv, cfg.delta_row), cfg.delta_conv),
+        "wf_a": winit((n, M, D), M),
+        "wf_b": winit((n, D, H, D), D),
+        "a_log": jnp.log(jax.random.uniform(
+            next(k), (n, H), jnp.float32, 1.0, 16.0)),
+        "wb": winit((n, M, H), M),
+        "wg_a": winit((n, M, D), M),
+        "wg_b": winit((n, D, H, D), D),
+        "o_norm": jnp.ones((n, D), jnp.float32),
+    }
+    step = jnp.exp(jax.random.uniform(
+        next(k), (n, H, D), jnp.float32, math.log(1e-3), math.log(0.1)))
+    layers["dt_bias"] = step + jnp.log(-jnp.expm1(-step))
+    return layers
+
+
 def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
                 kind: str = "full") -> Dict[str, Any]:
     """``n`` alike layers' weights, stacked ``[n, ...]``, drawing keys
@@ -462,16 +541,20 @@ def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
     def winit(key, shape, fan_in):
         return _normal(key, shape, fan_in ** -0.5, dt)
 
-    if cfg.latent:
+    if kind == "delta":
+        layers = _init_delta(cfg, k, n)
+    elif cfg.latent:
         # The down-projections and their norms, the up-projections a
         # head (``wk_b`` and ``wv_b`` are the two halves of the
         # published kv_b_proj, W_UK and W_UV), the output projection.
+        # Without a q bottleneck (``q_lora_rank`` 0) one ``wq``.
         Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
         layers: Dict[str, Any] = {
             "attn_norm": norm_init((n, M)),
-            "wq_a": winit(next(k), (n, M, Rq), M),
-            "q_a_norm": norm_init((n, Rq)),
-            "wq_b": winit(next(k), (n, Rq, H, Dh), Rq),
+            **({"wq_a": winit(next(k), (n, M, Rq), M),
+                "q_a_norm": norm_init((n, Rq)),
+                "wq_b": winit(next(k), (n, Rq, H, Dh), Rq)} if Rq else
+               {"wq": winit(next(k), (n, M, H, Dh), M)}),
             "wkv_a": winit(next(k), (n, M, Rkv + cfg.qk_rope_head_dim), M),
             "kv_a_norm": norm_init((n, Rkv)),
             "wk_b": winit(next(k), (n, Rkv, H, cfg.qk_nope_head_dim), Rkv),
@@ -726,20 +809,85 @@ def _latent_parts(cfg: LlamaConfig, lp, x, positions):
     besides: the normed input ``h`` and the normed q bottleneck ``c_q``."""
     nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+
+    def turned(x):
+        if not cfg.latent_rope:
+            return x
+        return rope(x, positions, cfg.rope_theta, cfg.rope_interleave)
+
     with jax.named_scope("mla.q"):
-        c_q = rms_norm(jnp.einsum("bsm,mr->bsr", h, lp["wq_a"]),
-                       lp["q_a_norm"], cfg.rms_eps)
-        q = jnp.einsum("bsr,rhd->bshd", c_q, lp["wq_b"])
-        q = jnp.concatenate(
-            [q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta,
-                                 cfg.rope_interleave)], axis=-1)
+        c_q = None
+        if cfg.q_lora_rank:
+            c_q = rms_norm(jnp.einsum("bsm,mr->bsr", h, lp["wq_a"]),
+                           lp["q_a_norm"], cfg.rms_eps)
+            q = jnp.einsum("bsr,rhd->bshd", c_q, lp["wq_b"])
+        else:
+            q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
+        q = jnp.concatenate([q[..., :nope], turned(q[..., nope:])], axis=-1)
     with jax.named_scope("mla.kv"):
         kv = jnp.einsum("bsm,mr->bsr", h, lp["wkv_a"])
         c = rms_norm(kv[..., :rank], lp["kv_a_norm"], cfg.rms_eps)
-        k_rope = rope(kv[..., None, rank:], positions, cfg.rope_theta,
-                      cfg.rope_interleave)[..., 0, :]
+        k_rope = turned(kv[..., None, rank:])[..., 0, :]
         row = _latent_row(cfg, c, k_rope)
     return q, row, h, c_q
+
+
+def delta_proj(cfg: LlamaConfig, lp, x):
+    """A delta layer's first half up to its convolution: attention norm,
+    then q, k and v [B,S,H,D] as projected (``delta_mix`` takes them on),
+    the token's log decays [B,S,H,D] float32, a channel of k each,
+    ``-exp(a_log) softplus((h wf_a) wf_b + dt_bias)``, its write strength
+    sigmoid(h wb) [B,S,H] float32, and the output gate
+    sigmoid((h wg_a) wg_b) [B,S,H,D]. Returns (q, k, (v, log decays,
+    write strength), gate): what ``block`` hands to ``attend``, and the
+    gate for behind it."""
+    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+    with jax.named_scope("kda.proj"):
+        q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
+        k = jnp.einsum("bsm,mhd->bshd", h, lp["wk"])
+        v = jnp.einsum("bsm,mhd->bshd", h, lp["wv"])
+    with jax.named_scope("kda.gate"):
+        f = jnp.einsum("bsr,rhd->bshd",
+                       jnp.einsum("bsm,mr->bsr", h, lp["wf_a"]), lp["wf_b"])
+        log_a = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(
+            f.astype(jnp.float32) + lp["dt_bias"])
+        beta = jax.nn.sigmoid(
+            jnp.einsum("bsm,mh->bsh", h, lp["wb"]).astype(jnp.float32))
+        g = jnp.einsum("bsr,rhd->bshd",
+                       jnp.einsum("bsm,mr->bsr", h, lp["wg_a"]), lp["wg_b"])
+        gate = jax.nn.sigmoid(g.astype(jnp.float32)).astype(g.dtype)
+    return q, k, (v, log_a, beta), gate
+
+
+def delta_mix(cfg: LlamaConfig, lp, q, k, v, history):
+    """The convolution and what follows it, on ``delta_proj``'s q, k, v
+    [B,S,H,D]: each channel's causal convolution of ``delta_conv`` taps
+    (y_t = sum_i w_i x_{t-taps+1+i}, no bias), SiLU, then q and k
+    normed to length 1 a head and q scaled by D ** -0.5. ``history``
+    [B, taps-1, ``delta_row``]: the rows (q|k|v as projected) of the
+    tokens just before these, zeros before a prompt's first. Returns
+    (q, k, v) for the delta rule and the rows [B, taps-1+S, delta_row]
+    of history and tokens together, of which a cache keeps the last
+    ``taps - 1`` real ones."""
+    B, S, H, D = q.shape
+    taps = cfg.delta_conv
+    with jax.named_scope("kda.conv"):
+        tokens = jnp.concatenate(
+            [x.reshape(B, S, H * D) for x in (q, k, v)], axis=-1)
+        rows = jnp.concatenate([history.astype(q.dtype), tokens], axis=1)
+        w = lp["conv_w"].astype(jnp.float32)
+        y = sum(rows[:, i:i + S].astype(jnp.float32) * w[i]
+                for i in range(taps))
+        y = jax.nn.silu(y).reshape(B, S, 3, H, D)
+        q, k, v = y[:, :, 0], y[:, :, 1], y[:, :, 2]
+
+        def unit(x):
+            return x * jax.lax.rsqrt(
+                jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+        q, k = unit(q) * D ** -0.5, unit(k)
+    return (q.astype(rows.dtype), k.astype(rows.dtype),
+            v.astype(rows.dtype), rows)
 
 
 def _rope_head(cfg: LlamaConfig, x, positions):
@@ -942,7 +1090,12 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
     given no v and brings the selection it was handed: the block passes
     nothing from layer to layer but the residual, the caller's layer loop
     carries the selection in ``attend``'s state. For "state" ``attend``
-    is given ``(v, log gates)`` in v's place (``qkv_proj``).
+    is given ``(v, log gates)`` in v's place (``qkv_proj``). For "delta"
+    ``attend`` is given q and k as projected and ``(v, log decays, write
+    strength)`` in v's place (``delta_proj``), owns the convolution and
+    its history with the rest of its state (``delta_mix``), and returns
+    [B,S,``delta_heads``,``delta_head_dim``], which is normed a head and
+    gated here.
 
     A model whose router reads the attention's input
     (``router_input="attention"``) is routed here, first: the route, the
@@ -965,6 +1118,8 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
         v = gate = None
         if kind == "latent_index":
             v = index_proj(cfg, lp, h, c_q, positions)
+    elif kind == "delta":
+        q, k, v, gate = delta_proj(cfg, lp, x)
     else:
         q, k, v, gate = qkv_proj(cfg, lp, x, mesh=mesh)
         if cfg.rope_full_layers or kind != "full":
@@ -976,6 +1131,8 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
         attn, state = attend(q, k, (v, gate))
     else:
         attn, state = attend(q, k, v)
+        if kind == "delta":
+            attn = rms_norm(attn, lp["o_norm"], cfg.rms_eps)
         if gate is not None:
             attn = attn * gate
     if ring_size(mesh, x.shape[1]) > 1:

@@ -822,8 +822,10 @@ class LLMEngine:
         "index" an indexer's one key, padding and all; a pool without
         pages has no entry),
         ``state_slot_bytes`` (``{kind: bytes}``: what a slot holds in one
-        layer of a pool that has no pages, a retention layer's state and
-        normaliser, padding and all; empty for a model that has none),
+        layer of a pool that has no pages: under "state" a retention
+        layer's state and normaliser, padding and all; under "delta" a
+        delta layer's state and its convolution's history; empty for a
+        model that has neither),
         ``queued`` (submitted, not yet admitted), beside the constants
         ``platform``, ``device_kind``, ``total_pages``, ``page_size`` and
         ``decode_attention`` (``"page_walk"``, for a latent pool
@@ -831,7 +833,10 @@ class LLMEngine:
         ``"gather"``: the path of
         ops/paged_attention.py the decode program was built with; for a
         model of retention layers ops/retention.py's ``"state_kernel"``
-        or ``"xla"``) and ``page_walk_step_tokens`` (``{kind: tokens}``:
+        or ``"xla"``), ``decode_delta`` (ops/delta_attention.py's
+        ``"delta_kernel"`` or ``"xla"`` for a model with delta layers,
+        beside the latent layers' ``decode_attention``; ``"none"`` for
+        any other) and ``page_walk_step_tokens`` (``{kind: tokens}``:
         what a compute step of the page walk covers in each pool it
         walks, ops/paged_attention.py ``walk_step_tokens``; empty on
         any other path).
@@ -849,8 +854,8 @@ class LLMEngine:
         the attention took into its softmax: all of them, but in a layer
         that attends over a selection the ``index_topk`` it keeps at
         most); ``decode_state_slot_layers`` (the states the steps
-        read and wrote: sequences times retention layers, summed over
-        decode steps; times ``state_slot_bytes``, the bytes of state a
+        read and wrote: sequences times retention or delta layers, summed
+        over decode steps; times ``state_slot_bytes``, the bytes of state a
         step moved each way);
         ``kv_page_steps_held`` (pages the live sequences held, each
         times its pool's layers, summed over decode steps) and

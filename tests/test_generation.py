@@ -816,3 +816,71 @@ def test_state_step_kernel_matches_the_xla_path_and_skips_idle_slots(active):
     assert np.allclose(np.asarray(got, np.float32)[busy],
                        np.asarray(want, np.float32)[busy], rtol=0.02,
                        atol=0.02)
+
+
+# ---- delta-rule layers among latent ones (PR 62) ----------------------------
+
+def _hybrid_model():
+    cfg = LlamaConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=4,
+        num_heads=4, num_kv_heads=4, head_dim=24, dtype=jnp.float32,
+        q_lora_rank=0, latent_rope=False, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        layer_types=("delta", "delta", "latent", "delta"),
+        delta_heads=4, delta_head_dim=16, delta_conv=4)
+    return cfg, init_params(cfg, jax.random.PRNGKey(2))
+
+
+def _hybrid_prefill(cfg, params, cache, prompt, bucket, slot, pages):
+    padded = np.full((1, bucket), 9, np.int32)
+    padded[0, :len(prompt)] = prompt
+    return paged_prefill(
+        params, jnp.asarray(padded), jnp.int32(len(prompt)), cache, cfg,
+        jnp.int32(slot), {"latent": jnp.asarray(pages, jnp.int32),
+                          "delta": jnp.zeros((0,), jnp.int32)})
+
+
+def test_delta_cache_is_two_pools_of_slots_beside_the_latent_pool():
+    cfg, _ = _hybrid_model()
+    assert PagedKVCache.sizes(cfg, 4, 99, 16, 8) == {
+        "delta": (3, 0, 0), "latent": (1, 99, 8)}
+    cache = PagedKVCache.create(cfg, 4, 99, 16, 8)
+    assert set(cache.k) == {"delta", "latent"} and set(cache.v) == {"delta"}
+    # States [L, B, H, D, D] float32; histories [L, taps - 1, B, 3 H D].
+    assert cache.k["delta"].shape == (3, 4, 4, 16, 16)
+    assert cache.k["delta"].dtype == jnp.float32
+    assert cache.v["delta"].shape == (3, 3, 4, 3 * 64)
+    assert cache.k["latent"].shape == (1, 99, 16, 32 + 128)
+    assert cache.page_table["delta"].shape == (4, 0)
+    assert cache.page_table["latent"].shape == (4, 8)
+    assert cache.page_size == 16
+    assert cache.pools("delta") == (cache.k["delta"], cache.v["delta"])
+
+
+@pytest.mark.parametrize("prompt_len", [2, 9, 31])
+def test_a_prompt_leaves_the_same_delta_pools_in_any_bucket(prompt_len):
+    """Padding must reach neither the state nor the convolution's
+    history: the same prompt in a bucket of 32, 64 and 128 (the last a
+    whole chunk of the delta prefill) leaves the same states, the same
+    three history rows, those of the last REAL tokens (zeros where the
+    prompt is shorter than the history), and the same logits, and
+    touches no other slot."""
+    cfg, params = _hybrid_model()
+    prompt = np.random.default_rng(prompt_len).integers(0, 256, prompt_len)
+    got = []
+    for bucket in (32, 64, 128):
+        cache = PagedKVCache.create(cfg, 2, 16, 16, 8)
+        out, cache, _ = _hybrid_prefill(cfg, params, cache, prompt, bucket,
+                                        1, np.arange(bucket // 16))
+        assert int(cache.lengths[1]) == prompt_len
+        assert not np.asarray(cache.k["delta"])[:, 0].any()
+        assert not np.asarray(cache.v["delta"])[:, :, 0].any()
+        got.append([np.asarray(x) for x in (
+            cache.k["delta"][:, 1], cache.v["delta"][:, :, 1], out)])
+    for states, history, logits in got[1:]:
+        assert np.abs(states - got[0][0]).max() < 1e-5
+        assert np.abs(history - got[0][1]).max() < 1e-5
+        assert np.abs(logits - got[0][2]).max() < 1e-5
+    history = got[0][1]
+    assert history[:, -min(prompt_len, 3):].any()
+    assert not history[:, :max(3 - prompt_len, 0)].any()

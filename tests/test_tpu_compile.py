@@ -1118,3 +1118,142 @@ def test_smallthinker_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket):
     assert _fits_one_chip(compiled)
     memory = compiled.memory_analysis()
     print(bucket, memory.temp_size_in_bytes / 2**30, "GiB of temporaries")
+
+
+# ---- Kimi-Linear: delta states beside a latent pool, all 27 layers (PR 62) --
+
+def _kimi():
+    """``kimi-linear-48b-a3b-ep16`` as the benchmark builds it, and its
+    engine."""
+    import json
+
+    from benchmark import arch
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "kimi-linear-48b-a3b-ep16.json")) as f:
+        config = json.load(f)
+    return arch.program_config(config), config["engine"]
+
+
+def _kimi_shapes(v5e):
+    cfg, engine = _kimi()
+    params, cache = _serve_shapes(
+        cfg, v5e, engine["max_batch"], engine["total_pages"],
+        engine["max_len"] // PAGE)
+    return cfg, engine, params, cache
+
+
+# 20 layers, 16 slots, 32 heads of 128 x 128: 0.67 GB.
+DELTA_POOL = (20, 16, 32, 128, 128)
+
+
+def test_delta_step_kernel_compiles_for_v5e(v5e):
+    """The decode delta-rule kernel at the published shapes: a slot's 32
+    states of 64 KB go through VMEM as one block of 2 MB and come back
+    through the output aliased to the pool; it writes four dimensions
+    and five, by which the trace reader knows it."""
+    from ray_tpu.ops import delta_attention
+
+    assert delta_attention.state_shape(20, 16, 32, 128) == DELTA_POOL
+    compiled = jax.jit(delta_attention.delta_step, donate_argnums=(5,)).lower(
+        _arr(v5e, (16, 32, 128)), _arr(v5e, (16, 32, 128)),
+        _arr(v5e, (16, 32, 128)), _arr(v5e, (16, 32, 128), jnp.float32),
+        _arr(v5e, (16, 32), jnp.float32), _arr(v5e, DELTA_POOL, jnp.float32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (16,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    call, = [m for m in _HLO_INSTRUCTION.finditer(text)
+             if m["op"] == "custom-call"]
+    assert re.match(r"\(f32\[16,1,32,128\]\S*, f32\[20,16,32,128,128\]",
+                    call["result"]), call["result"]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4 * math.prod(DELTA_POOL)
+    # Beside the pool: the heads' vectors as columns, no second pool.
+    assert memory.temp_size_in_bytes < 4 * math.prod(DELTA_POOL[1:])
+
+
+@pytest.mark.parametrize("bucket", [4096, 16384])
+def test_delta_scan_kernel_compiles_for_v5e(v5e, bucket):
+    """The chunked prefill kernel at the cell's smallest and largest
+    bucket: a head's state stays in VMEM over its chunks; float32
+    matmuls at "highest" and the product against a turned operand lower
+    for the chip."""
+    from ray_tpu.ops import delta_attention
+
+    compiled = jax.jit(delta_attention.delta_scan).lower(
+        _arr(v5e, (bucket, 32, 128)), _arr(v5e, (bucket, 32, 128)),
+        _arr(v5e, (bucket, 32, 128)),
+        _arr(v5e, (bucket, 32, 128), jnp.float32),
+        _arr(v5e, (bucket, 32), jnp.float32),
+    ).compile()
+    calls = [m["result"] for m in _HLO_INSTRUCTION.finditer(
+        compiled.as_text()) if m["op"] == "custom-call"]
+    assert any(re.match(rf"\(bf16\[32,{bucket},128\]\S*, f32\[32,128,128\]",
+                        call) for call in calls), calls
+
+
+def test_kimi_decode_program_compiles_for_v5e(v5e, as_tpu):
+    """Fifteen scans over three pools: the 20 delta layers' states and
+    convolution histories and the 7 latent layers' rows, each carried
+    whole and updated in place beside 8.6 GB of weights."""
+    cfg, engine, params, cache = _kimi_shapes(v5e)
+    assert {k: v.shape for k, v in cache.k.items()} == {
+        "delta": DELTA_POOL, "latent": (7, 16384, PAGE, 640)}
+    assert {k: v.shape for k, v in cache.v.items()} == {
+        "delta": (20, 3, 16, 12288)}
+    assert cache.page_table["delta"].shape == (16, 0)
+    assert cache.page_table["latent"].shape == (16, 1024)
+    batch = engine["max_batch"]
+
+    def decode(params, cache, tok, active):
+        return generation.paged_decode(params, tok, cache, cfg, active=active)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (batch,), jnp.int32),
+        _arr(v5e, (batch,), jnp.bool_)).compile()
+    assert _fits_one_chip(compiled)
+    text = compiled.as_text()
+    assert "f32[16,1,32,128]" in text           # the delta step
+    _assert_pool_stays_in_place(compiled, cache.k["latent"].shape)
+    memory = compiled.memory_analysis()
+    pools = (4 * math.prod(DELTA_POOL)
+             + 2 * math.prod(cache.k["latent"].shape)
+             + 2 * math.prod(cache.v["delta"].shape))
+    assert memory.alias_size_in_bytes >= pools
+    # Nothing the size of the states beside them: a copy would be 0.67 GB.
+    assert memory.temp_size_in_bytes < 2 * math.prod(DELTA_POOL)
+    print("decode", memory.temp_size_in_bytes / 2**30, "GiB of temporaries",
+          memory.argument_size_in_bytes / 2**30, "GiB of arguments")
+
+
+@pytest.mark.parametrize("bucket", [4096, 8192, 16384])
+def test_kimi_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket):
+    """The cell's three buckets: the chunked delta rule in 20 layers and
+    the flash kernel (q.k 192 beside v 128) in 7, both pools of a slot
+    laid from one prompt, beside 11.7 GB of weights, states and rows."""
+    cfg, engine, params, cache = _kimi_shapes(v5e)
+
+    def prefill(params, cache, tokens, real_len, slot, pages):
+        return generation.paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages)
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (1, bucket), jnp.int32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
+        {"delta": _arr(v5e, (0,), jnp.int32),
+         "latent": _arr(v5e, (bucket // PAGE,), jnp.int32)},
+    ).compile()
+    text = compiled.as_text()
+    assert f"bf16[32,{bucket},128]" in text     # the delta scan
+    assert _fits_one_chip(compiled)
+    memory = compiled.memory_analysis()
+    print(bucket, memory.temp_size_in_bytes / 2**30, "GiB of temporaries",
+          memory.argument_size_in_bytes / 2**30, "GiB of arguments")
+
+
+def test_kimi_weights_are_made_within_one_chip(v5e):
+    cfg, _ = _kimi()
+    compiled = jax.jit(lambda key: init_params(cfg, key)).lower(
+        _arr(v5e, (2,), jnp.uint32)).compile()
+    assert _fits_one_chip(compiled)
