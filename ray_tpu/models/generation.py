@@ -341,13 +341,23 @@ class KVBooks:
                 self.decode_attention = "sparse_walk"
         else:
             self.decode_attention = decode_attention_path(page_size, cfg.dh)
-        # The tokens a compute step of the page walk covers in each pool
-        # it walks: what its buffers were sized by.
-        self.page_walk_step_tokens = {
-            kind: walk_step_tokens(cfg.num_kv_heads, cfg.dh, page_size,
-                                   cache.k[kind].dtype, columns)
-            for kind, (_, _, columns) in self._own.items()
-        } if self.decode_attention == "page_walk" else {}
+        # The tokens a compute step of the walk covers in each pool it
+        # walks: what its buffers were sized by. A token is a k and a v
+        # row of every KV head, or one latent row.
+        def step_tokens(walks: bool, elements: int) -> Dict[str, int]:
+            return {
+                kind: walk_step_tokens(
+                    elements * cache.k[kind].dtype.itemsize, page_size,
+                    columns)
+                for kind, (_, pages, columns) in self._own.items() if pages
+            } if walks else {}
+
+        self.page_walk_step_tokens = step_tokens(
+            self.decode_attention == "page_walk",
+            2 * cfg.num_kv_heads * cfg.dh)
+        self.latent_walk_step_tokens = step_tokens(
+            self.decode_attention in ("latent_walk", "sparse_walk"),
+            cfg.latent_row)
         # What the decode steps read and held (LLMEngine.stats() says
         # what each means), summed as the steps are read.
         self.counts = dict.fromkeys((
@@ -458,6 +468,7 @@ class KVBooks:
             "decode_attention": self.decode_attention,
             "decode_delta": self.decode_delta,
             "page_walk_step_tokens": dict(self.page_walk_step_tokens),
+            "latent_walk_step_tokens": dict(self.latent_walk_step_tokens),
         }
 
 

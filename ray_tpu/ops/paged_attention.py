@@ -67,13 +67,19 @@ key, zeros up to whole lane tiles. :func:`latent_decode_attention`
 attends every head's absorbed query, laid as such a row, over the
 slot's rows; the scores are ``q . row`` over all W and the values the
 rows' first ``values``, so a row is read once for both. ``latent_walk``
-walks such a pool as the page walk walks k and v, but one program a
-slot and a fixed block (one copy a page with every "head" in it, the
-new row set in VMEM and its page copied back through the aliased
-output); per block of ``_LATENT_BLOCK_TOKENS`` it is two matmuls of
-all H query rows, no GQA group to pick: 2 H (W + values) operations a
-row of 2 W bytes, some 60 a byte at 32 heads over 576 + 512, where a
-k/v walk does 2. ``gather`` is its XLA path.
+walks such a pool as the page walk walks k and v, by the same scheme:
+one program for all slots down one list of compute steps
+(:func:`_step_list`), a step as long as its bytes say
+(:func:`walk_step_tokens` of a row's bytes: 1,024 tokens of a bfloat16
+row of 640), one copy a page with every "head" in it and each page
+once, one wait a whole step, the next walking slot's first copies in
+flight while a slot's last step computes, the new row set in VMEM in
+the slot's last step and its page copied back through the aliased
+output. A step is two matmuls of all H query rows, no GQA group to
+pick: 2 H (W + values) operations a row of 2 W bytes, some 60 a byte at
+32 heads over 576 + 512, where a k/v walk does 2. With ``selected`` a
+slot walks the same pages and attends to the selected positions alone
+(``sparse_walk``). ``gather`` is its XLA path.
 """
 
 from __future__ import annotations
@@ -317,7 +323,8 @@ def paged_decode_attention(
     _, Hkv, _, page, _ = k_pool.shape
     Pmax = page_table.shape[1]
     dtype = k_pool.dtype
-    block = walk_step_tokens(Hkv, D, page, dtype, Pmax)
+    block = walk_step_tokens(
+        2 * Hkv * D * jnp.dtype(dtype).itemsize, page, Pmax)
     step_pages = block // page
     if window is None:
         n_pages, windowed = jnp.minimum(lengths // page + 1, Pmax), []
@@ -326,17 +333,9 @@ def paged_decode_attention(
         n_pages, windowed = lengths // page + 1 - first, [
             first.astype(jnp.int32)]
     n_pages = jnp.where(active, n_pages, 0).astype(jnp.int32)
-    # Every slot's compute steps in one list, slot after slot: the
-    # kernel's one loop runs down it, and an inactive slot is not in it.
-    n_steps = (n_pages + step_pages - 1) // step_pages
-    ends = jnp.cumsum(n_steps)
-    most = B * -(-Pmax // step_pages)
-    slot_of = jnp.minimum(
-        (ends[None, :] <= jnp.arange(most)[:, None]).sum(axis=1), B - 1)
     scalars = [lengths.astype(jnp.int32),
                jnp.reshape(layer, (1,)).astype(jnp.int32),
-               slot_of.astype(jnp.int32), (ends - n_steps).astype(jnp.int32),
-               ends[-1:].astype(jnp.int32), *windowed]
+               *_step_list(n_pages, step_pages, Pmax), *windowed]
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     kv_buf = pltpu.VMEM((2, Hkv, block, D), dtype)
@@ -366,26 +365,21 @@ def paged_decode_attention(
       v_new.astype(dtype)[:, :, None], k_pool, v_pool)
 
 
-# Tokens a compute step of the latent walk covers: the rows are narrow
-# (one for all heads), so a block is made long enough that a step's
-# fixed costs are spread over some hundred kilobytes of them; contexts
-# here are thousands of tokens.
-_LATENT_BLOCK_TOKENS = 256
-
-
-def _latent_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, new_ref,
-                        *refs, pmax: int, scale: float,
-                        selected: bool = False):
-    """Grid (B,). With ``selected`` one more input behind new_ref,
-    sel_ref [blocks, block] float32: the slot attends to the positions
-    where it is not 0 (ops/sparse_attention.py).
-
-    pt_ref [B * Pmax], np_ref [B] (pages to walk), len_ref
-    [B], layer_ref [1] in SMEM; q_ref [H, W] this slot's absorbed
-    queries, laid as rows; new_ref [1, W] its new row; pool_hbm the pool
-    [L, P, page, W] left in HBM and pool_out the same buffer as an
-    output; o_ref [H, values]; buf [2, block, W] VMEM; sems [2, 2] DMA
-    (rows in by buffer, then the new row's page back)."""
+def _latent_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, slot_ref, at_ref,
+                        total_ref, q_ref, new_ref, *refs, pmax: int,
+                        scale: float, selected: bool = False):
+    """One program for every slot, the page walk's scheme over one pool
+    of rows. In SMEM: pt_ref [B * Pmax], np_ref [B] (pages to walk),
+    len_ref [B], layer_ref [1], slot_ref [G], at_ref [B], total_ref [1]
+    (the list of compute steps, :func:`_step_list`). q_ref [B, H, W]
+    every slot's absorbed queries, laid as rows; new_ref [B, 1, W] their
+    new rows; with ``selected`` one more input behind new_ref, sel_ref
+    [B, steps, step] float32: a slot attends to the positions where it
+    is not 0 (ops/sparse_attention.py). pool_hbm the pool [L, P, page,
+    W] left in HBM and pool_out the same buffer as an output; o_ref
+    [B, H, values] (under a selection [B, 1, H, values]); buf [2, step,
+    W] VMEM; sems [2, 2] DMA (rows in by buffer, then the new row's
+    page back)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -393,97 +387,145 @@ def _latent_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, q_ref, new_ref,
     if selected:
         sel_ref, *refs = refs
     pool_hbm, o_ref, pool_out, buf, sems = refs
-    b = pl.program_id(0)
-    values = o_ref.shape[1]
+    _, H, _ = q_ref.shape
+    values = o_ref.shape[-1]
     _, block, _ = buf.shape
     page = pool_hbm.shape[2]
-    pages_per_block = block // page
+    step_pages = block // page
     layer = layer_ref[0]
-    n_pages = np_ref[b]
-    n_blocks = (n_pages + pages_per_block - 1) // pages_per_block
-    length = len_ref[b]
+    total = total_ref[0]
 
-    def copies(i, at):
-        """The block's page copies. Past the slot's last page the last
-        one is read again: the buffer then never holds anything but pool
-        rows, so a masked probability of 0 meets no stale NaN."""
-        out = []
-        for j in range(pages_per_block):
-            p = jnp.minimum(i * pages_per_block + j, n_pages - 1)
-            out.append(pltpu.make_async_copy(
-                pool_hbm.at[layer, pt_ref[b * pmax + p]],
-                buf.at[at, pl.ds(j * page, page), :], sems.at[0, at]))
-        return out
+    # A step copies the pages it holds and no other, and a row is both
+    # key and value: the buffers start as zeros, so that a masked
+    # probability of 0 meets a pool's row or a zero, never a NaN. A slot
+    # that walks nothing (inactive) gets zeros.
+    buf[...] = jnp.zeros(buf.shape, buf.dtype)
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    last = n_blocks - 1
-    pid_new = pt_ref[b * pmax + jnp.maximum(n_pages - 1, 0)]
-    rows_new = pl.ds(pl.multiple_of(
-        (n_pages - 1 - last * pages_per_block) * page, page), page)
+    def step(g):
+        """The list's step g: (its slot, which of the slot's steps it
+        is, the pages it holds)."""
+        b = slot_ref[g]
+        i = g - at_ref[b]
+        return b, i, jnp.minimum(step_pages, np_ref[b] - i * step_pages)
 
-    def write_back():
-        return pltpu.make_async_copy(buf.at[last % 2, rows_new, :],
-                                     pool_out.at[layer, pid_new],
-                                     sems.at[1, 0])
+    def page_copy(pid, j, at):
+        """Page ``pid`` of the pool into page j of buffer ``at``."""
+        rows = pl.ds(pl.multiple_of(j * page, page), page)
+        return pltpu.make_async_copy(
+            pool_hbm.at[layer, pid], buf.at[at, rows, :], sems.at[0, at])
 
-    @pl.when(n_blocks > 0)
-    def _first():
-        for c in copies(0, 0):
-            c.start()
+    def start(g, unrolled=True):
+        """Start the copies of the list's step g, if it has one: the
+        pages the step holds, each once. A whole step's are ``unrolled``
+        (one straight run of descriptors), a part's are a loop."""
+        inside = g < total
+        b, i, held = step(jnp.where(inside, g, 0))
+        held = jnp.where(inside, held, 0)
+        first = b * pmax + i * step_pages
 
-    q = q_ref[...]
+        def start_page(j, _):
+            page_copy(pt_ref[first + j], j, g % 2).start()
+            return 0
 
-    def body(i, carry):
-        m, l, acc = carry
-        at = i % 2
+        if not unrolled:
+            jax.lax.fori_loop(0, held, start_page, 0)
+            return
 
-        @pl.when(i + 1 < n_blocks)
-        def _next():
-            for c in copies(i + 1, 1 - at):
-                c.start()
+        @pl.when(held == step_pages)
+        def _whole():
+            jax.lax.fori_loop(0, step_pages, start_page, 0, unroll=True)
 
-        for c in copies(i, at):
-            c.wait()
+        @pl.when(held < step_pages)
+        def _part():
+            jax.lax.fori_loop(0, held, start_page, 0)
 
-        @pl.when(i == last)
+    def wait(held, at):
+        @pl.when(held == step_pages)
+        def _whole():
+            # One wait for all the step's copies: a semaphore counts
+            # bytes, whichever copies brought them.
+            pltpu.make_async_copy(buf.at[at], buf.at[at],
+                                  sems.at[0, at]).wait()
+
+        @pl.when(held < step_pages)
+        def _part():
+            def wait_page(j, _):
+                page_copy(0, j, at).wait()
+                return 0
+            jax.lax.fori_loop(0, held, wait_page, 0)
+
+    # Once a call, so a plain loop (the page walk's reason).
+    start(0, unrolled=False)
+
+    def body(g, carry):
+        b, i, held = step(g)
+        at = g % 2
+        length = len_ref[b]
+        # The list runs on over the slots: while this step computes,
+        # the copies of the next are in flight, be it the next slot's
+        # first.
+        start(g + 1)
+        wait(held, at)
+
+        # The walk's last page, in its last step, takes the new row and
+        # goes back to the pool while the step computes.
+        page_new = np_ref[b] - 1 - i * step_pages
+        last = page_new < step_pages
+        page_new = jnp.where(last, page_new, 0)
+        rows_new = pl.ds(pl.multiple_of(page_new * page, page), page)
+        write_back = pltpu.make_async_copy(
+            buf.at[at, rows_new, :],
+            pool_out.at[layer, pt_ref[b * pmax + i * step_pages + page_new]],
+            sems.at[1, 0])
+
+        @pl.when(last)
         def _new_row():
-            held = buf[at, rows_new, :]
+            held_rows = buf[at, rows_new, :]
             is_new = jax.lax.broadcasted_iota(
-                jnp.int32, held.shape, 0) == length % page
-            buf[at, rows_new, :] = jnp.where(is_new, new_ref[...], held)
-            write_back().start()
+                jnp.int32, held_rows.shape, 0) == length % page
+            buf[at, rows_new, :] = jnp.where(is_new, new_ref[b], held_rows)
+            write_back.start()
 
-        rows = buf[at]                                    # [block, W]
+        # A slot's first step starts its softmax afresh.
+        m, l, acc = carry
+        m = jnp.where(i == 0, _NEG_INF, m)
+        l = jnp.where(i == 0, 0.0, l)
+        acc = jnp.where(i == 0, 0.0, acc)
+        rows = buf[at]                                    # [step, W]
         s = jax.lax.dot_general(
-            q, rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [H, block]
+            q_ref[b], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [H, step]
         t = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         attends = t <= length
         if sel_ref is not None:
-            attends &= sel_ref[pl.ds(i, 1), :] != 0.0
+            attends &= sel_ref[b, pl.ds(i, 1), :] != 0.0
         s = jnp.where(attends, s * scale, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         prob = jnp.exp(s - m_new)
         if sel_ref is not None:
-            # A block with nothing selected leaves m_new at -1e30 and
+            # A step with nothing selected leaves m_new at -1e30 and
             # exp(0) = 1 for what was masked.
             prob = jnp.where(attends, prob, 0.0)
         l = alpha * l + prob.sum(axis=1, keepdims=True)
         pv = jnp.dot(prob.astype(rows.dtype), rows[:, :values],
                      preferred_element_type=jnp.float32)  # [H, values]
-        return m_new, l, acc * alpha + pv
+        acc = acc * alpha + pv
 
-    H = q.shape[0]
+        @pl.when(last)
+        def _done():
+            # Under a selection that selects nothing the slot gets zeros.
+            out = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+            o_ref[b] = out.reshape(o_ref.shape[1:])
+            write_back.wait()
+
+        return m_new, l, acc
+
     m0 = jnp.full((H, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((H, 1), jnp.float32)
     acc0 = jnp.zeros((H, values), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-    # A slot that walked nothing (inactive) writes zeros.
-    o_ref[...] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
-
-    @pl.when(n_blocks > 0)
-    def _written():
-        write_back().wait()
+    jax.lax.fori_loop(0, total, body, (m0, l0, acc0))
 
 
 def paged_latent_decode_attention(
@@ -511,52 +553,47 @@ def paged_latent_decode_attention(
     B, H, W = q.shape
     page = pool.shape[2]
     Pmax = page_table.shape[1]
-    pages_per_block = max(1, _LATENT_BLOCK_TOKENS // page)
-    block = pages_per_block * page
+    dtype = pool.dtype
+    block = walk_step_tokens(W * jnp.dtype(dtype).itemsize, page, Pmax)
     n_pages = jnp.where(active, jnp.minimum(lengths // page + 1, Pmax),
                         0).astype(jnp.int32)
+    scalars = [lengths.astype(jnp.int32),
+               jnp.reshape(layer, (1,)).astype(jnp.int32),
+               *_step_list(n_pages, block // page, Pmax)]
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    dtype = pool.dtype
     kernel = functools.partial(_latent_walk_kernel, pmax=Pmax, scale=scale,
                                selected=selected is not None)
-    slot_rows = pl.BlockSpec((None, H, values), lambda b, *_: (b, 0, 0))
     out_rows = jax.ShapeDtypeStruct((B, H, values), q.dtype)
-    more_specs, more = [], []
+    more = []
     if selected is not None:
-        blocks = -(-Pmax * page // block)
+        steps = -(-Pmax * page // block)
         more.append(jnp.pad(
-            selected, ((0, 0), (0, blocks * block - Pmax * page))
-        ).reshape(B, blocks, block))
-        more_specs.append(pl.BlockSpec((None, blocks, block),
-                                       lambda b, *_: (b, 0, 0)))
+            selected, ((0, 0), (0, steps * block - Pmax * page))
+        ).reshape(B, steps, block))
         # Four dimensions, so that the reducer's name for this call is
         # not the latent walk's (three and four).
-        slot_rows = pl.BlockSpec((None, None, H, values),
-                                 lambda b, *_: (b, 0, 0, 0))
         out_rows = jax.ShapeDtypeStruct((B, 1, H, values), q.dtype)
-    pool_at = 6 + len(more)
+    n_scalars = 2 + len(scalars)
     out, pool = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(B,),
-            in_specs=[pl.BlockSpec((None, H, W), lambda b, *_: (b, 0, 0)),
-                      pl.BlockSpec((None, 1, W), lambda b, *_: (b, 0, 0)),
-                      *more_specs, hbm],
-            out_specs=[slot_rows, hbm],
+            num_scalar_prefetch=n_scalars,
+            grid=(1,),
+            in_specs=[whole] * (2 + len(more)) + [hbm],
+            out_specs=[whole, hbm],
             scratch_shapes=[
                 pltpu.VMEM((2, block, W), dtype),
                 pltpu.SemaphoreType.DMA((2, 2))],
         ),
         out_shape=[out_rows, jax.ShapeDtypeStruct(pool.shape, dtype)],
-        # Operands count the four prefetched scalars: the pool is 6,
-        # behind a selection 7.
-        input_output_aliases={pool_at: 1},
+        # Operands count the seven prefetched scalars: the pool is 9,
+        # behind a selection 10.
+        input_output_aliases={n_scalars + 2 + len(more): 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table.reshape(-1).astype(jnp.int32), n_pages,
-      lengths.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+    )(page_table.reshape(-1).astype(jnp.int32), n_pages, *scalars,
       q.astype(dtype), row_new.astype(dtype)[:, None], *more, pool)
     return out.reshape(B, H, values), pool
 
@@ -633,21 +670,43 @@ def pageable(page: int, head_dim: int) -> bool:
     return head_dim % 128 == 0 and page % 16 == 0
 
 
-def walk_step_tokens(kv_heads: int, head_dim: int, page: int, dtype,
-                     columns: int) -> int:
-    """Tokens one compute step of the page walk covers, from what the
-    code can see of a pool and its table: about ``_STEP_BYTES`` of K and
-    V, a token being ``2 * kv_heads * head_dim`` elements of ``dtype``,
+def walk_step_tokens(token_bytes: int, page: int, columns: int) -> int:
+    """Tokens one compute step of a walk covers, from what the code can
+    see of a pool and its table: about ``_STEP_BYTES`` of it, a token
+    being ``token_bytes`` in one layer (a k and a v row of every KV
+    head, ``2 * kv_heads * head_dim`` elements; one latent row, ``W``),
     so that a step's fixed costs are spread over as many bytes whatever
-    the heads (512 tokens at 4 KV heads of 128 in bfloat16, 256 at 8,
-    128 at 16); never under the 128 lanes of one tile of scores; whole
-    pages, a power of two of them, and no more than the table's
-    ``columns``, so that no step is longer than the longest walk.
-    ``LLMEngine.stats()["page_walk_step_tokens"]`` reports it."""
-    row_bytes = 2 * kv_heads * head_dim * jnp.dtype(dtype).itemsize
-    pages = min(max(_STEP_BYTES // (row_bytes * page), 128 // page, 1),
-                columns)
-    return page * (1 << (pages.bit_length() - 1))
+    the pool; never under the 128 lanes of one tile of scores; whole
+    pages, the power of two of them nearest in ratio, and no more than
+    the table's ``columns``, so that no step is longer than the longest
+    walk. 512 tokens at 4 KV heads of 128 in bfloat16, 256 at 8, 128 at
+    16, a megabyte each; 1,024 for a latent row of 640 (51 pages to the
+    megabyte: 64, 1.3 MB, and not 32: the latent walk measured 9-14%
+    faster at 1,024 than at 512 and no faster at 2,048, where the score
+    tile ``[H, step]`` and its ``exp`` grow with the step; PERF.md §6,
+    PR 63). ``LLMEngine.stats()`` reports it as
+    ``page_walk_step_tokens`` and ``latent_walk_step_tokens``."""
+    pages = max(_STEP_BYTES // (token_bytes * page), 128 // page, 1)
+    power = 1 << (pages.bit_length() - 1)
+    if pages * pages >= 2 * power * power:
+        power *= 2
+    return page * min(power, 1 << (columns.bit_length() - 1))
+
+
+def _step_list(n_pages: jax.Array, step_pages: int, columns: int):
+    """Every slot's compute steps in one list, slot after slot, for a
+    walk's one loop to run down: (the slot of each step [G], where each
+    slot's steps start in the list [B], the steps in all [1]), from the
+    pages each slot walks ``n_pages`` [B]. A slot that walks nothing
+    (inactive) is not in it."""
+    B = n_pages.shape[0]
+    n_steps = (n_pages + step_pages - 1) // step_pages
+    ends = jnp.cumsum(n_steps)
+    most = B * -(-columns // step_pages)
+    slot_of = jnp.minimum(
+        (ends[None, :] <= jnp.arange(most)[:, None]).sum(axis=1), B - 1)
+    return [slot_of.astype(jnp.int32), (ends - n_steps).astype(jnp.int32),
+            ends[-1:].astype(jnp.int32)]
 
 
 def decode_attention_path(page: int, head_dim: int,

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 
 _NEG_INF = -1e30
 _INT_MIN = -(2 ** 31)
-# Tokens a compute step of the index walk covers (as the latent walk's).
+# Tokens a compute step of the index walk covers.
 _INDEX_BLOCK_TOKENS = 256
 # A prefill's blocks: queries that are scored, selected and attended
 # together, and keys a step.

@@ -836,10 +836,11 @@ class LLMEngine:
         or ``"xla"``), ``decode_delta`` (ops/delta_attention.py's
         ``"delta_kernel"`` or ``"xla"`` for a model with delta layers,
         beside the latent layers' ``decode_attention``; ``"none"`` for
-        any other) and ``page_walk_step_tokens`` (``{kind: tokens}``:
-        what a compute step of the page walk covers in each pool it
-        walks, ops/paged_attention.py ``walk_step_tokens``; empty on
-        any other path).
+        any other), ``page_walk_step_tokens`` and
+        ``latent_walk_step_tokens`` (``{kind: tokens}``: what a compute
+        step of the page walk, or of the latent walk with or without a
+        selection, covers in each pool it walks, ops/paged_attention.py
+        ``walk_step_tokens``; each empty on any other path).
 
         Counts: ``decode_steps``; ``decode_slot_steps`` (sequences, summed
         over decode steps) and ``decode_kv_tokens`` (their cached tokens,

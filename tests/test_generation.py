@@ -275,29 +275,40 @@ def test_decode_attention_path_follows_platform_and_shape(monkeypatch):
     assert pa.decode_attention_path(8, 128) == "gather"
 
 
-@pytest.mark.parametrize("kv_heads,dtype,columns,tokens", [
-    (4, "bfloat16", 257, 512),     # SmallThinker's rings (28 on 4)
-    (4, "bfloat16", 1024, 512),    # ... and its tables of 16k
-    (4, "bfloat16", 129, 512),     # Trinity's rings (32 on 4)
-    (4, "bfloat16", 512, 512),
-    (8, "bfloat16", 128, 256),     # Mistral (32 on 8)
-    (16, "bfloat16", 128, 128),    # OLMoE (16 on 16): what it had
-    (32, "bfloat16", 128, 128),    # never under a lane tile of scores
-    (2, "float32", 65, 512),       # the float32 pools of these tests
-    (4, "bfloat16", 20, 256),      # a table shorter than a step:
-    (4, "bfloat16", 8, 128),       # whole pages, a power of two of them,
-    (4, "bfloat16", 4, 64),        # never more than the columns
-    (4, "bfloat16", 1, 16),
+@pytest.mark.parametrize("elements,dtype,columns,tokens", [
+    # a k and a v row of every KV head of 128: 2 * kv_heads * 128
+    (1024, "bfloat16", 257, 512),  # SmallThinker's rings (28 on 4)
+    (1024, "bfloat16", 1024, 512),  # ... and its tables of 16k
+    (1024, "bfloat16", 129, 512),  # Trinity's rings (32 on 4)
+    (1024, "bfloat16", 512, 512),
+    (2048, "bfloat16", 128, 256),  # Mistral (32 on 8)
+    (4096, "bfloat16", 128, 128),  # OLMoE (16 on 16): what it had
+    (8192, "bfloat16", 128, 128),  # never under a lane tile of scores
+    (512, "float32", 65, 512),     # the float32 pools of these tests
+    (1024, "bfloat16", 20, 256),   # a table shorter than a step:
+    (1024, "bfloat16", 8, 128),    # whole pages, a power of two of them,
+    (1024, "bfloat16", 4, 64),     # never more than the columns
+    (1024, "bfloat16", 1, 16),
+    # one latent row for all heads (512 of latent, the rotary key's tile)
+    # (51 pages to the megabyte: 64, the power of two nearest in ratio)
+    (640, "bfloat16", 1024, 1024),  # Kimi-Linear's and GLM-5.2's 16k
+    (640, "bfloat16", 512, 1024),  # JoyAI's 8k
+    (640, "bfloat16", 40, 512),    # a table shorter than a step
+    (256, "float32", 170, 1024),   # the float32 pools of these tests
+    (720, "bfloat16", 1024, 512),  # 45 pages to the megabyte: 32
+    (736, "bfloat16", 1024, 512),  # 44 pages
+    (704, "bfloat16", 1024, 1024), # 46 pages: 64
 ])
-def test_walk_step_follows_the_bytes_of_a_token(kv_heads, dtype, columns,
+def test_walk_step_follows_the_bytes_of_a_token(elements, dtype, columns,
                                                 tokens):
-    """The page walk's compute step, from shapes alone: about a
-    megabyte of K and V (``2 * kv_heads * 128 * itemsize`` bytes a
-    token), at least the 128 lanes of a score tile, a power of two of
-    pages, never longer than the table's columns."""
+    """A walk's compute step, from shapes alone: about a megabyte of the
+    pool (``elements * itemsize`` bytes a token in one layer), at least
+    the 128 lanes of a score tile, a power of two of pages, never longer
+    than the table's columns."""
     from ray_tpu.ops import paged_attention as pa
 
-    got = pa.walk_step_tokens(kv_heads, 128, 16, dtype, columns)
+    got = pa.walk_step_tokens(elements * jnp.dtype(dtype).itemsize, 16,
+                              columns)
     assert got == tokens
     assert got <= columns * 16 and got % 16 == 0
     assert (got // 16) & (got // 16 - 1) == 0
@@ -467,12 +478,35 @@ def test_dense_programs_return_no_expert_load():
 # ---- latent attention: one row a token for all heads (PR 42) ---------------
 
 _LATENT_CASES = {
-    # lengths, active, pages a slot, layers, layer
+    # lengths, active, pages a slot, layers, layer[, pool dtype]. A
+    # compute step is as long as the row's bytes and the table's columns
+    # say (``walk_step_tokens``): of these float32 rows of 256, 256
+    # tokens under 16 columns, 512 under 32, 1,024 under 64 or more.
     "mid_page_and_page_ends": ([37, 0, 255, 16], [True, True, True, True],
                                16, 2, 1),
     "an_inactive_slot": ([37, 200, 90], [True, False, True], 16, 3, 0),
     "all_inactive": ([5, 70], [False, False], 8, 1, 0),
     "over_a_block": ([300, 511, 256], [True, True, True], 32, 2, 1),
+    # Under a step, idle, a step to the row, idle, two steps and a part,
+    # a step and the first row of the next, idle: the list of steps runs
+    # on from a slot's last step to the next walking slot's first.
+    "mixed_steps_and_idle_slots": (
+        [100, 700, 1023, 5, 2600, 1024, 33],
+        [True, False, True, False, True, True, False], 170, 2, 1),
+    # The new row's page is the first of its step (the write-back's row
+    # offset is 0 in a step that holds one page), its last row and its
+    # first; 511 closes a step.
+    "last_page_opens_a_step": ([512, 527, 511, 1039], [True] * 4, 66, 1, 0),
+    "first_and_last_slot_idle": ([900, 64, 1500, 2047, 10],
+                                 [False, True, True, True, False], 128, 3,
+                                 2),
+    "one_walking_slot_of_many": ([0, 0, 1300, 0, 0],
+                                 [False, False, True, False, False], 96, 2,
+                                 0),
+    # The cells' own row: 640 of bfloat16, steps of 1,024 tokens.
+    "bf16_rows_of_640": ([3, 1023, 700, 1024, 2100],
+                         [True, True, False, True, True], 140, 2, 1,
+                         "bfloat16"),
 }
 
 
@@ -485,17 +519,21 @@ def test_latent_decode_attention_matches_reference(path, case):
     every other cell of the pool is bit-identical (an inactive slot
     writes nothing); the attention is, by hand, every head's softmax of
     ``scale * q . row`` over rows ``0 .. len`` times the rows' first
-    ``values``. Contexts end mid-page, on a page's last row and past a
-    block of 256; pages are walked out of order."""
+    ``values``. Contexts end mid-page, on a page's last row, on a
+    step's last row and past several steps; pages are walked out of
+    order; an inactive slot keeps the length its last request left."""
     from ray_tpu.ops import paged_attention as pa
 
-    lengths, active, pmax, n_layers, layer = _LATENT_CASES[case]
-    B, H, W, values, page, scale = len(lengths), 8, 256, 128, 16, 0.07
+    lengths, active, pmax, n_layers, layer, *dtype = _LATENT_CASES[case]
+    dtype = jnp.dtype(*dtype or ["float32"])
+    B, H, page, scale = len(lengths), 8, 16, 0.07
+    W, values, tol = (256, 128, 2e-5) if dtype == jnp.float32 else (
+        640, 512, 2e-2)
     n_pool = B * pmax
     rng = np.random.RandomState(len(case))
-    q = jnp.asarray(rng.randn(B, H, W), jnp.float32)
-    new = jnp.asarray(rng.randn(B, W), jnp.float32)
-    pool = jnp.asarray(rng.randn(n_layers, n_pool, page, W), jnp.float32)
+    q = jnp.asarray(rng.randn(B, H, W), dtype)
+    new = jnp.asarray(rng.randn(B, W), dtype)
+    pool = jnp.asarray(rng.randn(n_layers, n_pool, page, W), dtype)
     table = rng.permutation(n_pool).reshape(B, pmax).astype(np.int32)
     active = np.asarray(active)
     want = np.array(pool)
@@ -506,19 +544,20 @@ def test_latent_decode_attention_matches_reference(path, case):
     if path == "latent_walk":
         out, got = pa.paged_latent_decode_attention(
             *args, scale=scale, values=values, interpret=True)
-        assert not np.asarray(out)[~active].any()
+        assert not np.asarray(out, np.float32)[~active].any()
     else:
         out, got = pa.gather_latent_decode_attention(
             *args, scale=scale, values=values)
     np.testing.assert_array_equal(np.asarray(got), want)
     assert out.shape == (B, H, values) and got.dtype == pool.dtype
     for b in np.flatnonzero(active):
-        rows = want[layer][table[b]].reshape(pmax * page, W)[:lengths[b] + 1]
-        s = np.asarray(q)[b] @ rows.T * scale
+        rows = want[layer][table[b]].reshape(pmax * page, W)[
+            :lengths[b] + 1].astype(np.float32)
+        s = np.asarray(q, np.float32)[b] @ rows.T * scale
         p = np.exp(s - s.max(-1, keepdims=True))
         ref = (p / p.sum(-1, keepdims=True)) @ rows[:, :values]
-        np.testing.assert_allclose(np.asarray(out)[b], ref, atol=2e-5,
-                                   rtol=2e-5)
+        np.testing.assert_allclose(np.asarray(out, np.float32)[b], ref,
+                                   atol=tol, rtol=tol)
 
 
 def _latent_cfg(**changes):

@@ -1017,7 +1017,8 @@ _STATS_KEYS = [
     "decode_slot_steps_discarded", "decode_state_slot_layers",
     "decode_steps", "decode_steps_ahead", "device_kind", "failed",
     "finished", "free_pages", "free_slots", "kv_page_steps_held",
-    "kv_page_steps_one_table", "kv_row_bytes", "page_size", "page_waits",
+    "kv_page_steps_one_table", "kv_row_bytes", "latent_walk_step_tokens",
+    "page_size", "page_waits",
     "page_walk_step_tokens", "pages", "phase_cpu_s", "phase_s", "platform",
     "prefill_bucket_tokens",
     "prefill_streamed_bucket_tokens", "prefill_tokens", "prefills", "queued",
@@ -1037,6 +1038,7 @@ _NESTED_KEYS = {
             "prefill_experts_reached", "small_rows_layer_calls"]}
 _NOT_INT = {"decode_attention": str, "decode_delta": str, "device_kind": str, "platform": str,
             "t": float, "requests": list, "kv_row_bytes": dict,
+            "latent_walk_step_tokens": dict,
             "page_walk_step_tokens": dict, "pages": dict, "phase_s": dict,
             "phase_cpu_s": dict, "decode_dispatch": dict,
             "state_slot_bytes": dict, "stream": dict, "moe": dict}

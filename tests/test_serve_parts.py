@@ -316,6 +316,8 @@ def test_books_say_what_the_page_walk_was_built_with(monkeypatch):
     import dataclasses
     import importlib
 
+    import jax.numpy as jnp
+
     from ray_tpu.models.generation import KVBooks, PagedKVCache
     from ray_tpu.ops.paged_attention import walk_step_tokens
 
@@ -331,12 +333,52 @@ def test_books_say_what_the_page_walk_was_built_with(monkeypatch):
     books, steps = reading(cfg)
     assert books.decode_attention == "page_walk"
     assert steps == {
-        kind: walk_step_tokens(cfg.num_kv_heads, 128, PAGE, cfg.dtype,
-                               columns)
+        kind: walk_step_tokens(
+            2 * cfg.num_kv_heads * 128 * jnp.dtype(cfg.dtype).itemsize,
+            PAGE, columns)
         for kind, (_, _, columns) in books.pools.items()}
     assert steps == {"window": 2 * PAGE, "full": MAX_LEN}
+    assert books.reading()["latent_walk_step_tokens"] == {}
     for other in ("joyai_tiny", "brumby_tiny"):          # no k/v pool
         assert reading(_tiny(other))[1] == {}
+
+
+@pytest.mark.parametrize("tiny,path,pools", [
+    ("joyai_tiny", "latent_walk", {"latent"}),
+    ("glm52_tiny", "sparse_walk", {"latent", "index"}),
+    ("kimi_tiny", "latent_walk", {"latent", "delta"}),
+])
+def test_books_say_what_the_latent_walk_was_built_with(monkeypatch, tiny,
+                                                       path, pools):
+    """``latent_walk_step_tokens``: the one number of the latent pool,
+    the rule ``paged_latent_decode_attention`` sizes its buffer by (a
+    row's bytes, the page, the table's columns), with or without a
+    selection; a pool that rides on the latent pool's table or has no
+    pages has none; nothing on a CPU, where the path is the gather."""
+    import dataclasses
+    import importlib
+
+    import jax.numpy as jnp
+
+    from ray_tpu.models.generation import KVBooks, PagedKVCache
+    from ray_tpu.ops.paged_attention import walk_step_tokens
+
+    # The tiny configurations' rows are narrower than a lane tile.
+    cfg = dataclasses.replace(_tiny(tiny), kv_lora_rank=128)
+    geometry = (cfg, BATCH, TOTAL, PAGE, MAX_LEN // PAGE)
+
+    def reading():
+        return KVBooks(*geometry, PagedKVCache.create(*geometry)).reading()
+
+    assert reading()["latent_walk_step_tokens"] == {}    # a CPU: "gather"
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    got = reading()
+    assert got["decode_attention"] == path and set(got["pages"]) == pools
+    assert got["page_walk_step_tokens"] == {}
+    assert got["latent_walk_step_tokens"] == {"latent": walk_step_tokens(
+        cfg.latent_row * jnp.dtype(cfg.dtype).itemsize, PAGE,
+        MAX_LEN // PAGE)} == {"latent": MAX_LEN}
 
 
 # ---- (c) what serve/llm.py may not name -------------------------------------

@@ -102,13 +102,30 @@ def test_the_index_walk_is_the_gather(case):
                                       np.asarray(gathered)[b])
 
 
-@pytest.mark.parametrize("case", list(_INDEX_CASES))
+_SELECTED_WALK_CASES = {
+    **{name: case[:5] for name, case in _INDEX_CASES.items()},
+    # lengths, active, pages a slot, layers, layer. Under 64 columns or
+    # more a compute step of these rows is 1,024 tokens: under a step,
+    # a step to the row, steps and a part, the first row of a step, and
+    # idle slots between walking ones.
+    "mixed_steps_and_idle_slots": (
+        [100, 700, 1023, 5, 2600, 1024, 33],
+        [True, False, True, False, True, True, False], 170, 2, 1),
+    "last_page_opens_a_step": ([512, 527, 511, 1039], [True] * 4, 66, 1, 0),
+    "first_and_last_slot_idle": ([900, 64, 1500, 2047, 10],
+                                 [False, True, True, True, False], 128, 3,
+                                 2),
+}
+
+
+@pytest.mark.parametrize("case", list(_SELECTED_WALK_CASES))
 def test_the_walk_under_a_selection_is_the_gather_under_it(case):
     """``paged_latent_decode_attention(selected=)`` interpreted against
     the XLA gather with the same selection and against a softmax by hand
-    over the selected rows alone; the pool written as without one. Some
-    slots select whole blocks away."""
-    lengths, active, pmax, n_layers, layer, _ = _INDEX_CASES[case]
+    over the selected rows alone; the pool written as without one. One
+    slot selects its new row alone, the longest selects nothing in its
+    first compute step nor, if it has three, in its second."""
+    lengths, active, pmax, n_layers, layer = _SELECTED_WALK_CASES[case]
     B, H, W, values, page, scale = len(lengths), 8, 256, 128, 16, 0.07
     n_pool = B * pmax
     rng = np.random.RandomState(len(case) + 1)
@@ -118,11 +135,16 @@ def test_the_walk_under_a_selection_is_the_gather_under_it(case):
     table = rng.permutation(n_pool).reshape(B, pmax).astype(np.int32)
     active = np.asarray(active)
     T = pmax * page
+    step = pa.walk_step_tokens(W * 4, page, pmax)
     selected = rng.rand(B, T) < 0.3
-    selected[0, :] = False
-    selected[0, lengths[0]] = True              # the new row alone
-    selected[-1, :256] = False                  # a whole block unselected
-    selected[-1, lengths[-1] - 3] = True
+    alone, longest = np.flatnonzero(active)[0], np.argmax(
+        np.where(active, lengths, -1))
+    selected[alone, :] = False
+    selected[alone, lengths[alone]] = True      # the new row alone
+    selected[longest, :step] = False            # a whole step unselected
+    if lengths[longest] >= 2 * step:
+        selected[longest, step:2 * step] = False
+    selected[longest, lengths[longest] - 3] = True
     args = (q, new, pool, jnp.asarray(layer, jnp.int32), jnp.asarray(table),
             jnp.asarray(lengths, jnp.int32), jnp.asarray(active))
     sel = jnp.asarray(selected, jnp.float32)

@@ -207,7 +207,7 @@ def test_page_walk_compiles_at_the_long_context_cells_shapes(
     the VMEM a kernel has by default (nothing asks for more), and the
     pools come back through the aliased outputs."""
     assert paged_attention.walk_step_tokens(
-        4, D, PAGE, jnp.bfloat16, columns) == 512
+        2 * 4 * D * 2, PAGE, columns) == 512
     pool = (layers, 4, batch * columns, PAGE, D)
     compiled = jax.jit(
         functools.partial(paged_attention.paged_decode_attention,
