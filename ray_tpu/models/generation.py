@@ -58,6 +58,16 @@ the convolution's history taken at the last real token, not at the
 bucket's end. The decode step shifts the history by the token's row and
 updates each active slot's state in place.
 
+A looped model (``cfg.passes`` > 1, Ouro) runs its stack that many
+times over ONE set of weights, and each (pass, layer) attends over keys
+and values of its own: its pools are ``passes`` times as deep as the
+stack (``llama.kv_layers``), pass ``t`` of a pool's layer ``l`` at ``t *
+llama.kv_layers_a_pass + l``, and both programs walk the runs once a
+pass (``_passes``), the final norm behind every pass. Pages, tables and
+admission are what they are for any model: a page is simply that much
+deeper. A model with an exit gate returns the exit distribution over
+the passes beside its logits; every token runs every pass.
+
 What the pools hold for whom is kept on the host by ``KVBooks``; the
 serving engine reserves and releases through it and names no kind.
 
@@ -91,7 +101,8 @@ from ..ops.sparse_attention import (
 )
 from .llama import (
     LlamaConfig, block, causal_attention, delta_mix, embed_tokens,
-    index_offsets, kv_layers, latent_absorb_out, latent_absorb_q, latent_kv,
+    exit_distribution, exit_gate_logit, index_offsets, kv_layers,
+    kv_layers_a_pass, latent_absorb_out, latent_absorb_q, latent_kv,
     layer_runs, layer_stacks, pool_kind, rms_norm, split_expert_stack,
 )
 
@@ -136,9 +147,11 @@ class PagedKVCache(NamedTuple):
     All shapes static for XLA.
 
     One manager, a pool and a page table for each attention KIND the
-    model has (``llama.kv_layers``): ``k``, ``v`` and ``page_table`` are
-    dicts by kind, ``{"full": ...}`` alone for a model without window
-    layers. A "full" layer keeps every token, so its table has a column
+    model has (``llama.kv_layers``: a pool's layers are the stack's
+    layers of that kind, once for every pass of a looped model): ``k``,
+    ``v`` and ``page_table`` are dicts by kind, ``{"full": ...}`` alone
+    for a model without window layers. A "full" layer keeps every
+    token, so its table has a column
     for every page of the longest sequence and its pool as many pages as
     the caller gives it. A "window" layer keeps the last
     ``sliding_window`` tokens: its table's row is a ring of
@@ -283,7 +296,8 @@ class KVBooks:
                  page_size: int, max_pages_per_seq: int,
                  cache: PagedKVCache):
         self.page_size, self.total_pages = page_size, total_pages
-        self._batch, self._layers = batch, cfg.num_layers
+        # Layers a token is kept by: the stack's, once for every pass.
+        self._batch, self._layers = batch, cfg.num_layers * cfg.passes
         # {kind: (layers, pool pages, table columns)}
         self.pools = PagedKVCache.sizes(cfg, batch, total_pages, page_size,
                                         max_pages_per_seq)
@@ -451,6 +465,14 @@ class KVBooks:
         counts["kv_page_steps_one_table"] += sum(
             map(self._one_table.__getitem__, slots))
 
+    @property
+    def kv_token_bytes(self) -> int:
+        """What a token holds over all the pools that keep a row a
+        token, while every one of them keeps it: each pool's row times
+        its layers, as allocated."""
+        return sum(row * self.pools[kind][0]
+                   for kind, row in self._row_bytes.items())
+
     def reading(self) -> Dict[str, Any]:
         """The counts and the gauges, as ``LLMEngine.stats()`` shows
         and documents them."""
@@ -481,6 +503,45 @@ def _with_pools(cache: PagedKVCache, pools, lengths) -> PagedKVCache:
         cache.page_table, lengths)
 
 
+def _exit_gate(cfg: LlamaConfig, params, h):
+    """The exit gate's logit of the pass whose normed output of one
+    token a sequence is ``h`` [B, M]; None for a model without a gate."""
+    return exit_gate_logit(params, h) if cfg.exit_gate else None
+
+
+def _passes(cfg: LlamaConfig, one_pass, x, pools):
+    """A program's walk of the stack: ``one_pass(t, x, pools)`` ->
+    ``(x, pools, expert_tokens, gate)`` once for a model of one pass,
+    which is then the program it always was; for a looped model an
+    outer ``lax.scan`` over the passes around it, the residual and the
+    pools its carry (the pools whole, as in the layer scans inside),
+    the gates its ys: one body to compile whatever the passes. (Either
+    way, this or four scans in a row, XLA re-lays the stacked q, k and
+    v weights once a program run, outside the loops that share them:
+    1.1 GiB of temporaries, tests/test_tpu_compile.py; PERF.md section
+    7, Open after PR 65.) Returns ``(x, pools, expert_tokens, gates)``,
+    the gates [B, passes] or None."""
+    if cfg.passes == 1:
+        return one_pass(0, x, pools)[:3] + (None,)
+
+    def body(carry, t):
+        with jax.named_scope("pass"):
+            x, pools, _, gate = one_pass(t, *carry)
+        return (x, pools), gate
+
+    (x, pools), gates = jax.lax.scan(body, (x, pools),
+                                     jnp.arange(cfg.passes))
+    return x, pools, [], None if gates is None else gates.T
+
+
+def _with_exit(gates, *out):
+    """A program's results, and behind them the exit distribution
+    [B, passes] of a model with an exit gate."""
+    if gates is None:
+        return out
+    return out + (exit_distribution(gates),)
+
+
 def paged_decode(
     params: Dict[str, Any],
     tokens: jax.Array,          # [B] one token per slot
@@ -492,7 +553,9 @@ def paged_decode(
     """One decode step over the paged pools: write each active slot's
     token into its current page cell, attend over its pages, return
     [B, V] logits, the updated cache and the step's ``MoeLoad`` (None
-    for a dense model). One layer scan a run of alike layers
+    for a dense model); of a model with an exit gate a fourth, the
+    token's exit distribution over the passes [B, passes] float32
+    (``llama.exit_distribution``). One layer scan a run of alike layers
     (``llama.layer_runs``), each over the pool of its attention kind,
     which it CARRIES whole: as its
     xs and ys a pool would be sliced and re-stacked, pool-sized copies
@@ -501,115 +564,134 @@ def paged_decode(
     follow the live sequences. A "state" layer's pool is carried the
     same way; its step updates each active slot's state and leaves an
     inactive slot's as it is; a "delta" layer's two pools likewise, so
-    that a model of delta layers among latent ones steps all three."""
+    that a model of delta layers among latent ones steps all three.
+
+    A looped model (``cfg.passes``) walks the runs that many times over
+    the same stacked weights, the scans of one pass behind those of the
+    pass before in one program: pass ``t`` writes and walks its OWN
+    layers of each pool, ``t`` times a pass's layers further in, the
+    final norm stands behind every pass and its output is the next
+    pass's input. The pools are carried whole through every scan of
+    every pass, the decode kernel their only reader and writer."""
     x = embed_tokens(params, tokens, cfg)[:, None]
     pools = {kind: cache.pools(kind) for kind in cache.k}
-    expert_tokens = []
-    # The last selection made, [B, T] float32 (``index_select_decode``):
-    # an indexing layer replaces it, the layers that share it take it
-    # as the layer loop hands it on.
-    selected = None
-    for run, stack, index_at in zip(layer_runs(cfg), layer_stacks(params),
-                                    index_offsets(cfg)):
-        layers, expert_stack = split_expert_stack(stack)
-        kind, pool = run.kind, pool_kind(run.kind)
-        table = cache.page_table[pool]
-        if kind == "latent_index" and selected is None:
-            selected = jnp.zeros(
-                (tokens.shape[0], table.shape[1] * cache.page_size),
-                jnp.float32)
+    runs = layer_runs(cfg)
 
-        def body(carry, lp):
-            x, held, keys, selected = carry
+    def one_pass(t, x, pools):
+        """The runs walked once, as pass ``t``, and the final norm."""
+        pools, expert_tokens = dict(pools), []
+        # The last selection made, [B, T] float32 (``index_select_decode``):
+        # an indexing layer replaces it, the layers that share it take it
+        # as the layer loop hands it on.
+        selected = None
+        for run, stack, index_at in zip(runs, layer_stacks(params),
+                                        index_offsets(cfg)):
+            layers, expert_stack = split_expert_stack(stack)
+            kind, pool = run.kind, pool_kind(run.kind)
+            table = cache.page_table[pool]
+            # Where the run's layers begin in the pool, in this pass.
+            kv_at = t * kv_layers_a_pass(cfg)[pool] + run.kv_offset
+            if kind == "latent_index" and selected is None:
+                selected = jnp.zeros(
+                    (tokens.shape[0], table.shape[1] * cache.page_size),
+                    jnp.float32)
 
-            def attend(q, k, v):
-                # The token's K/V row goes to ``decode_attention``, which
-                # writes it at position ``lengths[b]`` of each active slot
-                # and attends. Nothing else in the step reads or writes
-                # the pools (a second reader of what goes into the
-                # kernel's aliased call would make XLA copy them): the
-                # block never sees them.
-                with jax.named_scope(f"attn.{kind}"):
-                    out, *new = decode_attention(
-                        q[:, 0], k[:, 0], v[:, 0], *held,
-                        lp["index"] + run.kv_offset, table, cache.lengths,
-                        active, window=cfg.window(kind))
-                return out[:, None], (tuple(new), keys, selected)
+            def body(carry, lp):
+                x, held, keys, selected = carry
 
-            def attend_latent(q, row, index, selected=selected, keys=keys):
-                # Absorbed: every head's query against the rows as they
-                # are cached, the values the rows' own latent part; of a
-                # layer with a selection, against the selected rows.
-                if index is not None:
-                    q_i, k_i, w_i = index
-                    with jax.named_scope("index.score"):
-                        selected, key_pool = index_select_decode(
-                            q_i[:, 0], w_i[:, 0], k_i[:, 0], *keys,
-                            lp["index"] + index_at, table, cache.lengths,
-                            active, topk=cfg.index_topk)
-                    keys = (key_pool,)
-                sparse = kind != "latent"
-                q_lat = latent_absorb_q(cfg, lp, q)
-                with jax.named_scope(
-                        "attn.sparse" if sparse else "attn.latent"):
-                    out, pool = latent_decode_attention(
-                        q_lat[:, 0], row[:, 0], *held,
-                        lp["index"] + run.kv_offset, table, cache.lengths,
-                        active, scale=cfg.dh ** -0.5,
-                        values=cfg.kv_lora_rank,
-                        selected=selected if sparse else None)
-                return (latent_absorb_out(cfg, lp, out[:, None]),
-                        ((pool,), keys, selected))
+                def attend(q, k, v):
+                    # The token's K/V row goes to ``decode_attention``, which
+                    # writes it at position ``lengths[b]`` of each active slot
+                    # and attends. Nothing else in the step reads or writes
+                    # the pools (a second reader of what goes into the
+                    # kernel's aliased call would make XLA copy them): the
+                    # block never sees them.
+                    with jax.named_scope(f"attn.{kind}"):
+                        out, *new = decode_attention(
+                            q[:, 0], k[:, 0], v[:, 0], *held,
+                            lp["index"] + kv_at, table, cache.lengths,
+                            active, window=cfg.window(kind))
+                    return out[:, None], (tuple(new), keys, selected)
 
-            def attend_state(q, k, v_gate):
-                v, log_g = v_gate
-                with jax.named_scope("attn.state"):
-                    out, pool = retention_decode(
-                        q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], *held,
-                        lp["index"] + run.kv_offset, active)
-                return out[:, None], ((pool,), keys, selected)
+                def attend_latent(q, row, index, selected=selected, keys=keys):
+                    # Absorbed: every head's query against the rows as they
+                    # are cached, the values the rows' own latent part; of a
+                    # layer with a selection, against the selected rows.
+                    if index is not None:
+                        q_i, k_i, w_i = index
+                        with jax.named_scope("index.score"):
+                            selected, key_pool = index_select_decode(
+                                q_i[:, 0], w_i[:, 0], k_i[:, 0], *keys,
+                                lp["index"] + index_at, table, cache.lengths,
+                                active, topk=cfg.index_topk)
+                        keys = (key_pool,)
+                    sparse = kind != "latent"
+                    q_lat = latent_absorb_q(cfg, lp, q)
+                    with jax.named_scope(
+                            "attn.sparse" if sparse else "attn.latent"):
+                        out, pool = latent_decode_attention(
+                            q_lat[:, 0], row[:, 0], *held,
+                            lp["index"] + kv_at, table, cache.lengths,
+                            active, scale=cfg.dh ** -0.5,
+                            values=cfg.kv_lora_rank,
+                            selected=selected if sparse else None)
+                    return (latent_absorb_out(cfg, lp, out[:, None]),
+                            ((pool,), keys, selected))
 
-            def attend_delta(q, k, packed):
-                # The convolution's history shifted by the token's row,
-                # then the state's step: each its own pool, both at the
-                # layer, an idle slot's left as they are.
-                v, log_a, beta = packed
-                states, histories = held
-                layer = lp["index"] + run.kv_offset
-                history = histories[layer]            # [taps-1, B, row]
-                q, k, v, rows = delta_mix(cfg, lp, q, k, v,
-                                          history.transpose(1, 0, 2))
-                with jax.named_scope("kda.conv"):
-                    histories = histories.at[layer].set(jnp.where(
-                        active[None, :, None],
-                        rows[:, 1:].transpose(1, 0, 2), history))
-                with jax.named_scope("attn.delta"):
-                    out, states = delta_decode(
-                        q[:, 0], k[:, 0], v[:, 0], log_a[:, 0], beta[:, 0],
-                        states, layer, active)
-                return out[:, None], ((states, histories), keys, selected)
+                def attend_state(q, k, v_gate):
+                    v, log_g = v_gate
+                    with jax.named_scope("attn.state"):
+                        out, pool = retention_decode(
+                            q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], *held,
+                            lp["index"] + kv_at, active)
+                    return out[:, None], ((pool,), keys, selected)
 
-            # The load-balancing loss is a training-only term: dropped.
-            x, kept, _aux, load = block(
-                cfg, lp, x, cache.lengths[:, None],
-                {"latent": attend_latent, "state": attend_state,
-                 "delta": attend_delta}.get(pool, attend),
-                token_mask=active[:, None], expert_stack=expert_stack,
-                kind=kind)
-            return (x,) + kept, load
+                def attend_delta(q, k, packed):
+                    # The convolution's history shifted by the token's row,
+                    # then the state's step: each its own pool, both at the
+                    # layer, an idle slot's left as they are.
+                    v, log_a, beta = packed
+                    states, histories = held
+                    layer = lp["index"] + kv_at
+                    history = histories[layer]            # [taps-1, B, row]
+                    q, k, v, rows = delta_mix(cfg, lp, q, k, v,
+                                              history.transpose(1, 0, 2))
+                    with jax.named_scope("kda.conv"):
+                        histories = histories.at[layer].set(jnp.where(
+                            active[None, :, None],
+                            rows[:, 1:].transpose(1, 0, 2), history))
+                    with jax.named_scope("attn.delta"):
+                        out, states = delta_decode(
+                            q[:, 0], k[:, 0], v[:, 0], log_a[:, 0], beta[:, 0],
+                            states, layer, active)
+                    return out[:, None], ((states, histories), keys, selected)
 
-        (x, pools[pool], keys, selected), load = jax.lax.scan(
-            body, (x, pools[pool], pools.get("index", ()), selected), layers)
-        if keys:
-            pools["index"] = keys
-        if load is not None:
-            expert_tokens.append(load)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+                # The load-balancing loss is a training-only term: dropped.
+                x, kept, _aux, load = block(
+                    cfg, lp, x, cache.lengths[:, None],
+                    {"latent": attend_latent, "state": attend_state,
+                     "delta": attend_delta}.get(pool, attend),
+                    token_mask=active[:, None], expert_stack=expert_stack,
+                    kind=kind)
+                return (x,) + kept, load
+
+            (x, pools[pool], keys, selected), load = jax.lax.scan(
+                body, (x, pools[pool], pools.get("index", ()), selected),
+                layers)
+            if keys:
+                pools["index"] = keys
+            if load is not None:
+                expert_tokens.append(load)
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        return x, pools, expert_tokens, _exit_gate(cfg, params, x[:, 0])
+
+    x, pools, expert_tokens, gates = _passes(cfg, one_pass, x, pools)
     logits = jnp.einsum("bm,mv->bv", x[:, 0], params["lm_head"])
     lengths = jnp.where(active, cache.lengths + 1, cache.lengths)
-    return logits.astype(jnp.float32), _with_pools(
-        cache, pools, lengths), MoeLoad.of_layers(
-            expert_tokens, cfg.experts_held is not None)
+    return _with_exit(
+        gates, logits.astype(jnp.float32),
+        _with_pools(cache, pools, lengths),
+        MoeLoad.of_layers(expert_tokens, cfg.experts_held is not None))
 
 
 def paged_prefill(
@@ -642,19 +724,23 @@ def paged_prefill(
     for "delta" none either: the state after token ``real_len - 1`` and
     the convolution's last ``delta_conv - 1`` real input rows, both laid
     into the slot whole, beside the latent layers' rows in their pages.
-    Returns the run's ``MoeLoad`` too (None for a dense model)."""
+    Returns the run's ``MoeLoad`` too (None for a dense model), and of
+    a model with an exit gate the last real token's exit distribution
+    [1, passes]. A looped model's passes are walked as ``paged_decode``
+    walks them, each pass's k and v laid into its own layers of the
+    pool."""
     S = tokens.shape[1]
     page = cache.page_size
     x = embed_tokens(params, tokens, cfg)
     positions = jnp.arange(S)
     token_mask = positions[None] < real_len if cfg.n_experts > 0 else None
     pools = {kind: cache.pools(kind) for kind in cache.k}
-    expert_tokens = []
 
     def to_pages(rows, pool, run, offset):
         """[n, 1, S, Hkv, Dh] -> [n, Hkv, S // page, page, Dh], the
         pool's layout, set at the run's layers (from ``offset`` of the
-        pool's) and the slot's page ids; latent rows and indexer keys
+        pool's: a number, or in a pass of a looped model a traced one)
+        and the slot's page ids; latent rows and indexer keys
         [n, 1, S, W] -> [n, S // page, page, W] likewise."""
         if run.kind == "state":
             # [n, Hkv, T, R, Dh]: the slot's states of the run's layers.
@@ -670,6 +756,7 @@ def paged_prefill(
                 pool, rows.astype(pool.dtype), at)
         ids = pages[pool_kind(run.kind)]
         whole = run.n == pool.shape[0]
+        looped = not isinstance(offset, int)
         at = slice(None) if whole else slice(offset, offset + run.n)
         if rows.ndim == 4:
             # One row a token for all heads: latent rows, indexer keys.
@@ -685,101 +772,122 @@ def paged_prefill(
                              S // page - len(ids))
             paged = jax.lax.dynamic_slice_in_dim(paged, first, len(ids), 2)
             ids = ids[(first + jnp.arange(len(ids))) % len(ids)]
+        if looped:
+            # A pass of a looped model, its number known when the
+            # program runs: the layers are indices as the pages are, and
+            # the two index arrays come first in what is set.
+            at = (offset + jnp.arange(run.n))[:, None]
+            return pool.at[at, :, ids[None]].set(
+                paged.transpose(0, 2, 1, 3, 4).astype(pool.dtype))
         return pool.at[at, :, ids].set(paged.astype(pool.dtype))
 
-    # A prompt no longer than the selection keeps selects every token
-    # before it: None, and the attention is the causal one of a latent
-    # layer without an indexer. Else the last selection made
-    # (``prefill_select``'s), handed on by the layer loop.
-    selects = cfg.index_topk and S > cfg.index_topk
-    selected = (empty_selection(S, cfg.index_head_dim) if selects else None)
-    for run, stack, index_at in zip(layer_runs(cfg), layer_stacks(params),
-                                    index_offsets(cfg)):
-        layers, expert_stack = split_expert_stack(stack)
-        kind, pool = run.kind, pool_kind(run.kind)
+    runs = layer_runs(cfg)
 
-        def body(carry, lp):
-            x, selected = carry
+    def one_pass(t, x, pools):
+        """The runs walked once, as pass ``t``, each run's rows laid
+        into the pass's layers of its pool, and the final norm."""
+        pools, expert_tokens = dict(pools), []
+        # A prompt no longer than the selection keeps selects every token
+        # before it: None, and the attention is the causal one of a latent
+        # layer without an indexer. Else the last selection made
+        # (``prefill_select``'s), handed on by the layer loop.
+        selects = cfg.index_topk and S > cfg.index_topk
+        selected = (empty_selection(S, cfg.index_head_dim) if selects
+                    else None)
+        for run, stack, index_at in zip(runs, layer_stacks(params),
+                                        index_offsets(cfg)):
+            layers, expert_stack = split_expert_stack(stack)
+            kind, pool = run.kind, pool_kind(run.kind)
 
-            def attend(q, k, v):
-                with jax.named_scope(f"attn.{kind}"):
-                    out = causal_attention(cfg, None, q, k, v,
-                                           window=cfg.window(kind))
-                return out, ((k, v), selected)
+            def body(carry, lp):
+                x, selected = carry
 
-            def attend_latent(q, row, index, selected=selected):
-                # Rebuilt: k and v of every head from the rows, for this
-                # attention alone; what is kept is the rows, and of an
-                # indexing layer its keys.
-                kept = (row,)
-                if index is not None:
-                    q_i, k_i, w_i = index
-                    kept = (row, k_i)
-                    if selects:
-                        selected = prefill_select(
-                            q_i[0], k_i[0], w_i[0], topk=cfg.index_topk)
-                k, v = latent_kv(cfg, lp, row)
-                if kind == "latent" or not selects:
-                    with jax.named_scope("attn.latent"):
-                        out = causal_attention(cfg, None, q, k, v)
-                else:
-                    with jax.named_scope("attn.sparse"):
-                        out = sparse_prefill_attention(
-                            q[0], k[0], v[0], selected,
-                            scale=cfg.dh ** -0.5)[None]
-                return out, (kept, selected)
+                def attend(q, k, v):
+                    with jax.named_scope(f"attn.{kind}"):
+                        out = causal_attention(cfg, None, q, k, v,
+                                               window=cfg.window(kind))
+                    return out, ((k, v), selected)
 
-            def attend_state(q, k, v_gate):
-                v, log_g = v_gate
-                real = positions < real_len
-                with jax.named_scope("attn.state"):
-                    out, state = retention_prefill(
-                        q[0], jnp.where(real[:, None, None], k[0], 0),
-                        v[0], jnp.where(real[:, None], log_g[0], 0.0))
-                return out[None], ((state,), selected)
+                def attend_latent(q, row, index, selected=selected):
+                    # Rebuilt: k and v of every head from the rows, for this
+                    # attention alone; what is kept is the rows, and of an
+                    # indexing layer its keys.
+                    kept = (row,)
+                    if index is not None:
+                        q_i, k_i, w_i = index
+                        kept = (row, k_i)
+                        if selects:
+                            selected = prefill_select(
+                                q_i[0], k_i[0], w_i[0], topk=cfg.index_topk)
+                    k, v = latent_kv(cfg, lp, row)
+                    if kind == "latent" or not selects:
+                        with jax.named_scope("attn.latent"):
+                            out = causal_attention(cfg, None, q, k, v)
+                    else:
+                        with jax.named_scope("attn.sparse"):
+                            out = sparse_prefill_attention(
+                                q[0], k[0], v[0], selected,
+                                scale=cfg.dh ** -0.5)[None]
+                    return out, (kept, selected)
 
-            def attend_delta(q, k, packed):
-                # From nothing before the prompt; the padding neither
-                # decays nor writes, and the history kept is the last
-                # real tokens' rows (token t lies at row t + taps - 1).
-                v, log_a, beta = packed
-                real = positions < real_len
-                taps = cfg.delta_conv
-                q, k, v, rows = delta_mix(
-                    cfg, lp, q, k, v,
-                    jnp.zeros((1, taps - 1, cfg.delta_row), q.dtype))
-                history = jax.lax.dynamic_slice_in_dim(
-                    rows[0], real_len, taps - 1)
-                with jax.named_scope("attn.delta"):
-                    out, state = delta_prefill(
-                        q[0], k[0], v[0],
-                        jnp.where(real[:, None, None], log_a[0], 0.0),
-                        jnp.where(real[:, None], beta[0], 0.0))
-                return out[None], ((state, history), selected)
+                def attend_state(q, k, v_gate):
+                    v, log_g = v_gate
+                    real = positions < real_len
+                    with jax.named_scope("attn.state"):
+                        out, state = retention_prefill(
+                            q[0], jnp.where(real[:, None, None], k[0], 0),
+                            v[0], jnp.where(real[:, None], log_g[0], 0.0))
+                    return out[None], ((state,), selected)
 
-            x, (kept, selected), _aux, load = block(
-                cfg, lp, x, positions,
-                {"latent": attend_latent, "state": attend_state,
-                 "delta": attend_delta}.get(pool, attend),
-                token_mask=token_mask, expert_stack=expert_stack, kind=kind)
-            return (x, selected), (kept, load)
+                def attend_delta(q, k, packed):
+                    # From nothing before the prompt; the padding neither
+                    # decays nor writes, and the history kept is the last
+                    # real tokens' rows (token t lies at row t + taps - 1).
+                    v, log_a, beta = packed
+                    real = positions < real_len
+                    taps = cfg.delta_conv
+                    q, k, v, rows = delta_mix(
+                        cfg, lp, q, k, v,
+                        jnp.zeros((1, taps - 1, cfg.delta_row), q.dtype))
+                    history = jax.lax.dynamic_slice_in_dim(
+                        rows[0], real_len, taps - 1)
+                    with jax.named_scope("attn.delta"):
+                        out, state = delta_prefill(
+                            q[0], k[0], v[0],
+                            jnp.where(real[:, None, None], log_a[0], 0.0),
+                            jnp.where(real[:, None], beta[0], 0.0))
+                    return out[None], ((state, history), selected)
 
-        (x, selected), (kept, load) = jax.lax.scan(body, (x, selected),
-                                                   layers)
-        kept, keys = kept[:len(pools[pool])], kept[-1]
-        pools[pool] = tuple(to_pages(rows, held, run, run.kv_offset)
-                            for rows, held in zip(kept, pools[pool]))
-        if kind == "latent_index":
-            pools["index"] = (to_pages(keys, *pools["index"], run,
-                                       index_at),)
-        if load is not None:
-            expert_tokens.append(load)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+                x, (kept, selected), _aux, load = block(
+                    cfg, lp, x, positions,
+                    {"latent": attend_latent, "state": attend_state,
+                     "delta": attend_delta}.get(pool, attend),
+                    token_mask=token_mask, expert_stack=expert_stack,
+                    kind=kind)
+                return (x, selected), (kept, load)
+
+            (x, selected), (kept, load) = jax.lax.scan(body, (x, selected),
+                                                       layers)
+            kept, keys = kept[:len(pools[pool])], kept[-1]
+            kv_at = t * kv_layers_a_pass(cfg)[pool] + run.kv_offset
+            pools[pool] = tuple(to_pages(rows, held, run, kv_at)
+                                for rows, held in zip(kept, pools[pool]))
+            if kind == "latent_index":
+                pools["index"] = (to_pages(keys, *pools["index"], run,
+                                           index_at),)
+            if load is not None:
+                expert_tokens.append(load)
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        return x, pools, expert_tokens, _exit_gate(
+            cfg, params, x[:, real_len - 1])
+
+    x, pools, expert_tokens, gates = _passes(cfg, one_pass, x, pools)
     logits = jnp.einsum("bm,mv->bv", x[:, real_len - 1], params["lm_head"])
     lengths = cache.lengths.at[slot].set(real_len)
-    return logits.astype(jnp.float32), _with_pools(
-        cache, pools, lengths), MoeLoad.of_layers(
-            expert_tokens, cfg.experts_held is not None)
+    return _with_exit(
+        gates, logits.astype(jnp.float32),
+        _with_pools(cache, pools, lengths),
+        MoeLoad.of_layers(expert_tokens, cfg.experts_held is not None))
 
 
 def sample_logits(logits: jax.Array, rng: jax.Array, *,

@@ -164,6 +164,21 @@ class LlamaConfig:
     # count`` chips hold between them (parallel/moe.py ``held``). None:
     # all of them.
     experts_held: Optional[Tuple[int, int]] = None
+    # A looped model (Ouro): the whole stack runs ``passes`` times over
+    # the ONE set of layer weights, ``final_norm`` behind every pass and
+    # its output the next pass's input; the last pass's goes to the head.
+    # Each (pass, layer) attends over keys and values of its own, so a
+    # paged pool is ``passes`` times as deep as the stack
+    # (``kv_layers``): pass t of a pool's layer l lies at ``t *
+    # kv_layers_a_pass + l``. ``exit_gate``: behind every pass's norm a
+    # gate ``h . exit_w + exit_b`` says how likely a token is to leave
+    # there (``exit_distribution``); a token leaves at the first pass
+    # whose cumulative probability reaches ``exit_threshold``, at 1.0
+    # the last one, always: the only threshold the programs are built
+    # for (serve/llm.py ``serving_programs`` says what another needs).
+    passes: int = 1
+    exit_gate: bool = False
+    exit_threshold: float = 1.0
     remat: bool = True
     # "full" (save only layer inputs), "dots" (save matmul outputs,
     # recompute elementwise), or "save_all" (save every intermediate —
@@ -332,6 +347,7 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
                 f"of {cfg.num_layers} layers")
     if "window" in kinds and not cfg.sliding_window:
         raise ValueError("window layers need a sliding_window")
+    _check_loop(cfg, kinds)
     if (cfg.router_input not in ("ffn", "attention")
             or cfg.expert_act not in _EXPERT_ACTS):
         raise ValueError(
@@ -351,6 +367,36 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
     return tuple(runs)
 
 
+def _check_loop(cfg: LlamaConfig, kinds) -> None:
+    """What a stack that runs ``cfg.passes`` times may be made of: the
+    kinds whose rows lie in pages, "full" and "window", where a pass
+    more is layers more of the same pool. Anything else is refused by
+    name, with what would have to exist."""
+    if cfg.passes < 1 or (cfg.exit_gate and cfg.passes == 1):
+        raise ValueError(
+            f"passes is {cfg.passes}: a stack runs once or more, and an "
+            f"exit gate chooses among two passes or more")
+    if cfg.passes == 1:
+        return
+    missing = [why for kind, why in (
+        ("state", "a retention state a slot a PASS"),
+        ("delta", "a delta-rule state and a convolution history a slot a "
+                  "PASS"),
+        ("latent", "a latent row a token a PASS, and an absorbed decode "
+                   "that walks the pass's layers of the latent pool"),
+        ("latent_index", "a selection a pass: indexer keys a token a PASS"),
+    ) if kind in kinds]
+    if cfg.n_experts > 0:
+        missing.append("the expert-load counters a pass (MoeLoad sums one "
+                       "walk of the stack)")
+    if missing:
+        raise NotImplementedError(
+            f"passes={cfg.passes} over layers of kinds {sorted(set(kinds))}"
+            f"{' with experts' if cfg.n_experts > 0 else ''} is not "
+            f"implemented: a looped stack is 'full' and 'window' layers "
+            f"with dense FFNs; it would need " + "; ".join(missing))
+
+
 def index_offsets(cfg: LlamaConfig) -> Tuple[int, ...]:
     """For each of ``layer_runs``, where its layers begin in the "index"
     pool (the indexing layers before it)."""
@@ -362,8 +408,18 @@ def index_offsets(cfg: LlamaConfig) -> Tuple[int, ...]:
 
 
 def kv_layers(cfg: LlamaConfig) -> Dict[str, int]:
+    """Layers each KV pool holds, {kind: count}, in the order of first
+    use: the layers of that kind, once for every pass of a looped model
+    (each (pass, layer) keeps rows of its own)."""
+    return {kind: n * cfg.passes
+            for kind, n in kv_layers_a_pass(cfg).items()}
+
+
+def kv_layers_a_pass(cfg: LlamaConfig) -> Dict[str, int]:
     """Layers of each attention kind present, {kind: count}: the KV
-    pools a model needs, in the order of first use."""
+    pools a model needs and what one walk of the stack fills of each.
+    Pass t of a looped model keeps its rows ``t`` times this further
+    into the pool (``LayerRun.kv_offset`` is a layer's place in a pass)."""
     out: Dict[str, int] = {}
     for run in layer_runs(cfg):
         kind = pool_kind(run.kind)
@@ -392,7 +448,7 @@ def require_uniform(cfg: LlamaConfig, what: str) -> None:
     retention or delta-rule layer (kinds "state", "delta": their chunked
     scans have no backward) trains nowhere yet (ROADMAP R3, R5, R7), and
     says so by name."""
-    if len(layer_runs(cfg)) > 1 or set(kv_layers(cfg)) - {"full"}:
+    if len(layer_runs(cfg)) > 1 or set(kv_layers_a_pass(cfg)) - {"full"}:
         raise NotImplementedError(
             f"{what}: training a model whose layer stack is not uniform "
             f"(dense layers before expert layers, window beside full "
@@ -446,6 +502,8 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         "layers": layer,
         "final_norm": ("norm",),
         "lm_head": ("embed", "vocab"),
+        **({"exit_w": ("embed", None), "exit_b": (None,)}
+           if cfg.exit_gate else {}),
     }
 
 
@@ -649,12 +707,19 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     def winit(key, shape):
         return _normal(key, shape, M ** -0.5, cfg.dtype)
 
-    return {
+    params = {
         "embed": winit(next(k), (V, M)),
         "layers": layers,
         "final_norm": jnp.ones((M,), dtype=jnp.float32),
         "lm_head": winit(next(k), (M, V)),
     }
+    if cfg.exit_gate:
+        # A Linear(hidden, 1) with a bias. Against a normed state of
+        # unit RMS the gate's logit is then about N(0, 1): sigmoid of it
+        # spread about 1/2, and the exit distribution not degenerate.
+        params.update(exit_w=winit(next(k), (M, 1)),
+                      exit_b=jnp.zeros((1,), dtype=jnp.float32))
+    return params
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
@@ -1147,6 +1212,28 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
     return x, state, aux, expert_tokens
 
 
+def exit_gate_logit(params, h: jax.Array) -> jax.Array:
+    """The exit gate of a pass, from the pass's normed output ``h``
+    [..., M]: ``h . exit_w + exit_b`` [...] float32."""
+    with jax.named_scope("exit.gate"):
+        g = jnp.einsum("...m,mo->...o", h, params["exit_w"],
+                       preferred_element_type=jnp.float32)
+        return (g + params["exit_b"])[..., 0]
+
+
+def exit_distribution(gates: jax.Array) -> jax.Array:
+    """From the passes' gate logits [..., T] the probability of leaving
+    at each pass, [..., T] float32: with ``lambda_t = sigmoid(g_t)``,
+    ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for t < T - 1 and what
+    is left for the last pass, ``p_{T-1} = prod_{j<T-1} (1 - lambda_j)``
+    (the last pass's own gate decides nothing)."""
+    lam = jax.nn.sigmoid(gates.astype(jnp.float32))[..., :-1]
+    stay = jnp.cumprod(1.0 - lam, axis=-1)        # prod_{j<=t}, t < T - 1
+    before = jnp.concatenate(
+        [jnp.ones_like(stay[..., :1]), stay[..., :-1]], axis=-1)
+    return jnp.concatenate([lam * before, stay[..., -1:]], axis=-1)
+
+
 def embed_tokens(params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
     """The residual stream's first value: the tokens' embedding rows in
     the model's dtype, scaled where the model scales them."""
@@ -1208,7 +1295,8 @@ def hidden_forward(
     mesh=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Transformer trunk WITHOUT the lm_head projection: returns
-    (hidden [B, S, M] after final_norm, moe_aux_loss scalar)."""
+    (hidden [B, S, M] after final_norm, moe_aux_loss scalar); of a
+    looped model the last pass's (``cfg.passes``)."""
     require_uniform(cfg, "hidden_forward")
     B, S = tokens.shape
     # Between the layers' matmul pairs the residual's rows lie over ``tp``
@@ -1237,47 +1325,56 @@ def hidden_forward(
         out = with_logical_constraint(out, residual, mesh=mesh)
         return out, aux
 
-    if cfg.scan_layers:
-        K, n_chunks = scan_chunks(cfg)
-        if K == 1:
-            x, aux = jax.lax.scan(body, x, params["layers"])
-        else:
-            # Layer-chunked schedule: scan over [L/K, ...] stacks of
-            # K-layer chunks. ONE checkpoint per chunk (the policy's
-            # save-set covers the whole unrolled chunk body), and the
-            # carry re-annotated each step so GSPMD keeps the scan body's
-            # layout resident instead of resharding per iteration.
-            chunked = jax.tree.map(
-                lambda p: p.reshape((n_chunks, K) + p.shape[1:]),
-                params["layers"],
-            )
+    def layers(x):
+        """One walk of the stack."""
+        if cfg.scan_layers:
+            K, n_chunks = scan_chunks(cfg)
+            if K == 1:
+                x, aux = jax.lax.scan(body, x, params["layers"])
+            else:
+                # Layer-chunked schedule: scan over [L/K, ...] stacks of
+                # K-layer chunks. ONE checkpoint per chunk (the policy's
+                # save-set covers the whole unrolled chunk body), and the
+                # carry re-annotated each step so GSPMD keeps the scan
+                # body's layout resident instead of resharding per
+                # iteration.
+                chunked = jax.tree.map(
+                    lambda p: p.reshape((n_chunks, K) + p.shape[1:]),
+                    params["layers"],
+                )
 
-            def chunk_fn(x_, cp):
-                aux = jnp.zeros((), dtype=jnp.float32)
-                for k in range(K):
-                    lp = jax.tree.map(lambda p: p[k], cp)
-                    x_, a = layer(x_, lp)
-                    aux = aux + a
-                return x_, aux
+                def chunk_fn(x_, cp):
+                    aux = jnp.zeros((), dtype=jnp.float32)
+                    for k in range(K):
+                        lp = jax.tree.map(lambda p: p[k], cp)
+                        x_, a = layer(x_, lp)
+                        aux = aux + a
+                    return x_, aux
 
-            if cfg.remat:
-                chunk_fn = jax.checkpoint(chunk_fn, policy=policy)
+                if cfg.remat:
+                    chunk_fn = jax.checkpoint(chunk_fn, policy=policy)
 
-            def chunk_body(x_, cp):
-                x_ = with_logical_constraint(x_, residual, mesh=mesh)
-                out, aux = chunk_fn(x_, cp)
-                out = with_logical_constraint(out, residual, mesh=mesh)
-                return out, aux
+                def chunk_body(x_, cp):
+                    x_ = with_logical_constraint(x_, residual, mesh=mesh)
+                    out, aux = chunk_fn(x_, cp)
+                    out = with_logical_constraint(out, residual, mesh=mesh)
+                    return out, aux
 
-            x, aux = jax.lax.scan(chunk_body, x, chunked)
-        aux = aux.sum()
-    else:
+                x, aux = jax.lax.scan(chunk_body, x, chunked)
+            return x, aux.sum()
         aux = jnp.zeros((), jnp.float32)
         for i in range(cfg.num_layers):
             lp = jax.tree.map(lambda p: p[i], params["layers"])
             x, a = body(x, lp)
             aux = aux + a
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        return x, aux
+
+    # A looped model walks the same weights ``passes`` times, the final
+    # norm behind every walk and its output the next walk's input (it
+    # has no experts, ``_check_loop``: every walk's ``aux`` is zero).
+    for _ in range(cfg.passes):
+        x, aux = layers(x)
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     if ring:
         # The head reads whole rows: gathered once, not once a loss chunk.
         x = with_logical_constraint(x, ("batch", "seq", "embed"), mesh=mesh)
@@ -1343,6 +1440,15 @@ def causal_lm_loss(
     """Next-token cross entropy (tokens shifted internally). With
     cfg.loss_chunk > 0 the head projection + softmax stream over
     sequence chunks (identical math, a fraction of the peak memory)."""
+    if cfg.passes > 1:
+        raise NotImplementedError(
+            f"causal_lm_loss: training a looped model (passes="
+            f"{cfg.passes}) is not implemented: its objective weights "
+            f"EVERY pass's next-token loss by the exit distribution "
+            f"(llama.exit_distribution) less an entropy term, and needs "
+            f"the head behind every pass; the last pass's loss alone "
+            f"would train another model. It is served only "
+            f"(models/generation.py)")
     targets = tokens[:, 1:]
     chunk = cfg.loss_chunk
     if chunk and chunk > 0 and targets.shape[1] > chunk:

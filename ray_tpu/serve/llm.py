@@ -317,37 +317,64 @@ def serving_programs(cfg, temperature: float):
     MoE model, the program's expert load behind them in the same int32
     vector (the held experts' tokens, the experts reached and, of a
     model that holds a share of its experts, the assignments that went
-    elsewhere): one read a step, as for a dense model."""
+    elsewhere): one read a step, as for a dense model. For a model with
+    an exit gate (``cfg.exit_gate``) two values more at the vector's
+    end: the expected exit pass ``sum_t (t + 1) p_t`` summed over the
+    tokens the program made (a decode step's active slots, a prefill's
+    one; float32, its bits as an int32) and how many those were.
+
+    The gate decides nothing here: every token runs every pass, which
+    is what ``exit_threshold`` 1.0 says. Under a lower one the
+    sequences of a batch would leave at different passes: the step
+    would need a batch that thins from pass to pass, and a token that
+    left early would still owe the skipped passes' K and V to the
+    tokens behind it. Neither exists, so another threshold is refused."""
     import jax
     import jax.numpy as jnp
 
     from ..models.generation import paged_decode, paged_prefill, sample_logits
 
-    def with_load(tokens, load):
-        if load is None:
-            return tokens
-        return jnp.concatenate([
-            tokens, load.expert_tokens, load.experts_reached[None],
-            *([] if load.elsewhere is None else [load.elsewhere[None]])])
+    if cfg.exit_gate and cfg.exit_threshold != 1.0:
+        raise NotImplementedError(
+            f"exit_threshold={cfg.exit_threshold}: only 1.0 is built "
+            f"(every token runs all {cfg.passes} passes). A lower one "
+            f"needs a decode batch whose sequences stop at different "
+            f"passes, and the K/V of the passes a token skipped, which "
+            f"the tokens behind it attend to")
+
+    def with_load(tokens, load, exits, made):
+        tail = []
+        if load is not None:
+            tail += [load.expert_tokens, load.experts_reached[None]]
+            tail += [] if load.elsewhere is None else [load.elsewhere[None]]
+        for p in exits:
+            # The one [B, passes] of a model with an exit gate, of which
+            # the rows ``made`` [B] bool are tokens.
+            passes = jnp.arange(1, p.shape[-1] + 1, dtype=jnp.float32)
+            expected = jnp.where(made, p @ passes, 0.0).sum()
+            tail += [jax.lax.bitcast_convert_type(expected, jnp.int32)[None],
+                     made.sum(dtype=jnp.int32)[None]]
+        return jnp.concatenate([tokens, *tail]) if tail else tokens
 
     def decode_step(params, cache, last_tok, active, rng):
         # The chain a loop that split on the host would draw: the same
         # seed, the same tokens.
         rng, key = jax.random.split(rng)
-        logits, cache, load = paged_decode(
+        logits, cache, load, *exits = paged_decode(
             params, last_tok, cache, cfg, active=active
         )
         nxt = sample_logits(logits, key, temperature=temperature)
-        return with_load(nxt, load), cache, nxt, rng
+        return with_load(nxt, load, exits, active), cache, nxt, rng
 
     def prefill(params, cache, last_tok, tokens, real_len, slot, pages):
-        logits, cache, load = paged_prefill(
+        logits, cache, load, *exits = paged_prefill(
             params, tokens, real_len, cache, cfg, slot, pages
         )
         nxt = sample_logits(logits, jax.random.PRNGKey(0),
                             temperature=temperature)
-        return (cache, last_tok.at[slot].set(nxt[0]),
-                nxt[0] if load is None else with_load(nxt, load))
+        last_tok = last_tok.at[slot].set(nxt[0])
+        out = with_load(nxt, load, exits, jnp.ones((1,), dtype=bool))
+        return cache, last_tok, nxt[0] if out is nxt else out
 
     return decode_step, prefill
 
@@ -612,6 +639,13 @@ class _Runner:
                                                    np.int64)}
             if cfg.experts_held:
                 self._moe["assignments_elsewhere"] = 0
+        # A looped model's counters (stats()["loop"]); None for a model
+        # of one pass. Its gate's two values ride behind each program's
+        # read-back (``serving_programs``).
+        self._passes, self._exit_gate = cfg.passes, cfg.exit_gate
+        self._loop: Optional[Dict[str, Any]] = (
+            {"passes": 0, "exit_tokens": 0, "exit_pass_sum": 0.0}
+            if cfg.passes > 1 else None)
         # Bucket tokens of the prefills whose attention over k and v
         # rows ran the flash forward's streamed form.
         self._streamed_bucket_tokens = 0
@@ -710,7 +744,17 @@ class _Runner:
         decode step's or with ``bucket`` that bucket's prefill's; what a
         MoE model's program packed behind them (``with_load``) goes to
         the expert-load counters, a decode step's apart from a
-        prefill's where ``stats()`` tells them apart."""
+        prefill's where ``stats()`` tells them apart; a looped model's
+        passes and its gate's two values go to the loop's counters."""
+        if self._loop is not None:
+            with self._lock:
+                self._loop["passes"] += self._passes * (bucket is None)
+                if self._exit_gate:
+                    self._loop["exit_pass_sum"] += float(
+                        out[-2:-1].view(np.float32)[0])
+                    self._loop["exit_tokens"] += int(out[-1])
+            if self._exit_gate:
+                out = out[:-2]
         moe = self._moe
         if moe is not None:
             if "assignments_elsewhere" in moe:
@@ -744,6 +788,7 @@ class _Runner:
             **({"moe": {**self._moe, "expert_tokens":
                         self._moe["expert_tokens"].tolist()}}
                if self._moe else {}),
+            **({"loop": dict(self._loop)} if self._loop else {}),
         }
 
 
@@ -937,6 +982,19 @@ class LLMEngine:
         was built with the grouped matmul for few rows a group
         (ops/grouped_matmul.py: the same rule, by the program's rows).
 
+        ``loop``, for a looped model only (``cfg.passes`` > 1): ``passes``
+        (passes over the stack the decode steps ran: a step adds the
+        model's passes, every one of them, since no token leaves early),
+        ``exit_tokens`` and ``exit_pass_sum`` (of a model with an exit
+        gate: the tokens made, a prefill's first and a decode step's one
+        an active slot, and over them the sum of the pass each would
+        leave at in expectation under the gate's own distribution,
+        ``sum_t (t + 1) p_t``, formed on the device; their quotient lies
+        between 1 and the passes) and the gauge ``kv_token_bytes`` (what
+        a token holds over all the pools that keep a row a token, as
+        allocated: ``kv_row_bytes`` times each pool's layers, which are
+        the stack's once for every pass).
+
         ``requests``: the newest requests that have finished and those now
         decoding, each ``[t_submit, t_admit, t_first, t_done or None,
         prompt_len, bucket, id, t_last_put or None]`` in ``time.time()``
@@ -984,7 +1042,7 @@ class LLMEngine:
         self.runner.decode_step.flush_taps()
         taken = self.scheduler.streams.read()
         with self._lock:
-            return {
+            out = {
                 **self.scheduler.reading(taken),
                 **self.books.reading(),
                 **self.runner.reading(),
@@ -994,6 +1052,9 @@ class LLMEngine:
                 "decode_dispatch": dict(self._dispatch),
                 "cache_resets": self._cache_resets,
             }
+        if "loop" in out:
+            out["loop"]["kv_token_bytes"] = self.books.kv_token_bytes
+        return out
 
     def shutdown(self):
         self._stop = True

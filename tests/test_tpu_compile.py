@@ -1257,3 +1257,93 @@ def test_kimi_weights_are_made_within_one_chip(v5e):
     compiled = jax.jit(lambda key: init_params(cfg, key)).lower(
         _arr(v5e, (2,), jnp.uint32)).compile()
     assert _fits_one_chip(compiled)
+
+
+# ---- Ouro-2.6B: 48 layers four times over, a pool 192 layers deep (PR 65) --
+
+def _ouro():
+    """``ouro-2.6b`` as the benchmark builds it, and its engine."""
+    import json
+
+    from benchmark import arch
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "ouro-2.6b.json")) as f:
+        config = json.load(f)
+    return arch.program_config(config), config["engine"]
+
+
+def _ouro_shapes(v5e):
+    cfg, engine = _ouro()
+    params, cache = _serve_shapes(
+        cfg, v5e, engine["max_batch"], engine["total_pages"],
+        engine["max_len"] // PAGE)
+    return cfg, engine, params, cache
+
+
+def test_ouro_decode_program_compiles_for_v5e(v5e, as_tpu):
+    """A scan over the four passes around the layer scan, over ONE set
+    of stacked weights, each pass walking its own 48 layers of a pool of
+    192: 8.05 GB carried whole through both scans and updated in place
+    beside 5.34 GB of weights; the exit distribution comes back beside
+    the logits. The temporaries are NOT under a layer's slice as in every
+    other decode program: XLA moves a re-layout of the stacked q, k and v
+    weights (3 x 0.2 GB, and as much again beside them) out of the pass
+    loop, 1.13 GiB copied every step (PERF.md section 7, Open after
+    PR 65); the bound here is what keeps a second such copy, or one of
+    the pool, from passing unseen."""
+    cfg, engine, params, cache = _ouro_shapes(v5e)
+    pool = (4 * 48, 16, engine["total_pages"], PAGE, 128)
+    assert {k: v.shape for k, v in cache.k.items()} == {"full": pool}
+    assert cache.page_table["full"].shape == (8, 40)
+    batch = engine["max_batch"]
+
+    def decode(params, cache, tok, active):
+        return generation.paged_decode(params, tok, cache, cfg, active=active)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (batch,), jnp.int32),
+        _arr(v5e, (batch,), jnp.bool_)).compile()
+    assert _fits_one_chip(compiled)
+    _assert_pool_stays_in_place(compiled, pool, temporaries=False)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool)
+    assert memory.temp_size_in_bytes < 1.25 * 2**30
+    out = jax.eval_shape(decode, params, cache,
+                         _arr(v5e, (batch,), jnp.int32),
+                         _arr(v5e, (batch,), jnp.bool_))
+    assert out[3].shape == (batch, 4) and out[3].dtype == jnp.float32
+    print("decode", memory.temp_size_in_bytes / 2**30, "GiB of temporaries",
+          memory.argument_size_in_bytes / 2**30, "GiB of arguments")
+
+
+@pytest.mark.parametrize("bucket,flash", [(64, False), (256, True)])
+def test_ouro_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket, flash):
+    """The cell's smallest and largest bucket: every pass's k and v of
+    48 layers laid into that pass's layers of the slot's pages, beside
+    13.4 GB of weights and pool; the 256 bucket through the flash
+    kernel at 16 x 128."""
+    cfg, engine, params, cache = _ouro_shapes(v5e)
+
+    def prefill(params, cache, tokens, real_len, slot, pages):
+        return generation.paged_prefill(
+            params, tokens, real_len, cache, cfg, slot, pages)
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, _arr(v5e, (1, bucket), jnp.int32),
+        _arr(v5e, (), jnp.int32), _arr(v5e, (), jnp.int32),
+        {"full": _arr(v5e, (bucket // PAGE,), jnp.int32)},
+    ).compile()
+    assert _fits_one_chip(compiled)
+    assert ("tpu_custom_call" in compiled.as_text()) == flash
+    memory = compiled.memory_analysis()
+    print(bucket, memory.temp_size_in_bytes / 2**30, "GiB of temporaries",
+          memory.argument_size_in_bytes / 2**30, "GiB of arguments")
+
+
+def test_ouro_weights_are_made_within_one_chip(v5e):
+    cfg, _ = _ouro()
+    compiled = jax.jit(lambda key: init_params(cfg, key)).lower(
+        _arr(v5e, (2,), jnp.uint32)).compile()
+    assert _fits_one_chip(compiled)
