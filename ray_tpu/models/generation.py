@@ -518,7 +518,7 @@ def _passes(cfg: LlamaConfig, one_pass, x, pools):
     the gates its ys: one body to compile whatever the passes. (Either
     way, this or four scans in a row, XLA re-lays the stacked q, k and
     v weights once a program run, outside the loops that share them:
-    1.1 GiB of temporaries, tests/test_tpu_compile.py; PERF.md section
+    1.1 GiB of temporaries, tests/test_tpu_compile_ouro.py; PERF.md section
     7, Open after PR 65.) Returns ``(x, pools, expert_tokens, gates)``,
     the gates [B, passes] or None."""
     if cfg.passes == 1:

@@ -1,8 +1,7 @@
 """Llama-family transformer, TPU-first.
 
-The flagship model (BASELINE.json configs: Llama-3 8B/70B; a mixture of
-experts via ``n_experts``, routed as OLMoE routes: parallel/moe.py).
-Design choices for TPU/XLA:
+A mixture of experts via ``n_experts``, routed as OLMoE routes
+(parallel/moe.py). Design choices for TPU/XLA:
 
 - Pure-functional: params are a pytree of arrays; sharding is declared as a
   matching pytree of logical axes (parallel/sharding.py rules) — pjit/GSPMD
@@ -60,10 +59,6 @@ class LlamaConfig:
     # renormalised over the chosen ones (parallel/moe.py; dropless).
     n_experts: int = 0
     top_k: int = 2
-    # Read by nothing since the dropless dispatch (PR 28). It stays only
-    # because tests/bench_harness/moe_tiny pins it and a model_config PR
-    # may not edit the benchmark's files; ROADMAP D5 removes both.
-    capacity_factor: float = 1.25
     # RMSNorm over the whole q and the whole k projection, before the
     # split into heads and before rotary (OLMoE's q_norm / k_norm).
     qk_norm: bool = False
@@ -249,17 +244,6 @@ class LlamaConfig:
     def window(self, kind: str) -> Optional[int]:
         """The attention window of a layer of ``kind``; None for none."""
         return self.sliding_window if kind == "window" else None
-
-    @staticmethod
-    def llama3_8b() -> "LlamaConfig":
-        return LlamaConfig()
-
-    @staticmethod
-    def llama3_70b() -> "LlamaConfig":
-        return LlamaConfig(
-            hidden_size=8192, intermediate_size=28_672, num_layers=80,
-            num_heads=64, num_kv_heads=8,
-        )
 
     @staticmethod
     def tiny(vocab: int = 256, moe: bool = False) -> "LlamaConfig":

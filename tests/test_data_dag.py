@@ -119,25 +119,49 @@ def test_streaming_split_over_union(ray_tpu_start):
     assert got[0] and got[1]  # both shards actually consumed
 
 
-def test_store_backpressure_bounds_producer():
-    """A slow consumer must bound producer memory: with the store-usage
-    policy active, in-store bytes stay under the cap while blocks are
-    consumed one at a time (ref: resource-aware backpressure policies)."""
+def test_store_backpressure_bounds_producer(monkeypatch):
+    """A consumer that sits on its blocks must bound producer memory:
+    with the store-usage policy active, in-store bytes stay under the cap
+    plus the in-flight window, and the policy is what held the producer
+    back (ref: resource-aware backpressure policies).
+
+    Nothing here races: the consumer pulls a block, waits until it is
+    sealed and KEEPS it while the store is under the cap, so the store
+    crosses the cap after a number of pulls that depends on no timing,
+    and pulls once more over it, so the policy is asked there; then it
+    lets go of what it kept, waits for the store to fall under the cap
+    again and consumes the rest a block at a time. Submissions the
+    policy refused are counted at the policy itself."""
     import ray_tpu
     from ray_tpu.core.runtime_context import current_runtime
+    from ray_tpu.data.streaming_executor import StoreUsagePolicy
 
     ray_tpu.init(num_cpus=2, object_store_memory=256 * 1024 * 1024,
                  system_config={"log_to_driver": False,
+                                "refcount_flush_interval_s": 0.1,
                                 "gc_grace_period_s": 0.5})
     ctx = DataContext.get_current()
     old_frac, old_inflight = (ctx.store_usage_cap_fraction,
                               ctx.max_in_flight_tasks)
     ctx.store_usage_cap_fraction = 0.25
-    ctx.max_in_flight_tasks = 16  # without the store policy: way ahead
+    # Small enough that 32 blocks are kept (the cap) before all 40 are
+    # submitted: the policy is asked while there is something to hold.
+    ctx.max_in_flight_tasks = 4
+    held_back = []
+    can_submit = StoreUsagePolicy.can_submit
+
+    def counted(self, num_inflight):
+        ok = can_submit(self, num_inflight)
+        if not ok:
+            held_back.append(num_inflight)
+        return ok
+
+    monkeypatch.setattr(StoreUsagePolicy, "can_submit", counted)
     try:
         nm = current_runtime()._nm
         cap = nm.directory.capacity_bytes
         assert cap > 0
+        limit = cap * 0.25
         block_bytes = 2 * 1024 * 1024
         nblocks = 40
         window = ctx.max_in_flight_tasks
@@ -150,26 +174,45 @@ def test_store_backpressure_bounds_producer():
                 gen_block, batch_size=None
             )
             peak = seen = 0
+            kept, crossed = [], False
             for ref in ds.iter_blocks_refs():
+                ray_tpu.wait([ref], num_returns=1, timeout=60)
                 peak = max(peak, nm.directory.used_bytes)
                 seen += 1
-                time.sleep(0.04)  # slow consumer
+                if crossed:
+                    kept.clear()
+                else:
+                    kept.append(ref)
+                    if nm.directory.used_bytes >= limit:
+                        crossed = True  # the next pull finds it over the cap
+                        continue
                 del ref
+                if crossed:
+                    # One block at a time from here: the store lets go
+                    # of what was consumed before the next is pulled.
+                    deadline = time.monotonic() + 60
+                    while nm.directory.used_bytes >= limit:
+                        assert time.monotonic() < deadline, "nothing freed"
+                        time.sleep(0.02)
             assert seen == nblocks
-            return peak
+            return peak, crossed
 
-        peak_on = run_consumer()
+        peak_on, crossed = run_consumer()
+        assert crossed and nblocks * block_bytes > limit + window * block_bytes
         # Hard bound: once usage crosses cap*frac, submission stops;
         # only the already-in-flight window can still land.
         assert peak_on <= cap * 0.25 + window * block_bytes, (
             f"peak {peak_on} vs cap {cap}*0.25 + {window} blocks"
         )
-        # Contrast: without the store policy the producer free-runs and
-        # its peak footprint is materially higher.
+        # The policy did it: it refused submissions, each with work in
+        # flight (its progress guarantee never refuses the first).
+        assert held_back and min(held_back) >= 1, held_back
+        # Contrast: without the store policy nothing is ever held back.
         ctx.store_usage_cap_fraction = 0.0
+        del held_back[:]
         time.sleep(1.5)  # let the previous run's blocks GC
-        peak_off = run_consumer()
-        assert peak_off > peak_on, (peak_off, peak_on)
+        run_consumer()
+        assert held_back == []
     finally:
         ctx.store_usage_cap_fraction = old_frac
         ctx.max_in_flight_tasks = old_inflight

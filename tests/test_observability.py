@@ -242,9 +242,18 @@ def test_serve_request_telemetry(serve_cluster):
     def double(x):
         return x * 2
 
+    def recorded(text):
+        """HTTP requests of ``double`` the proxy has recorded: the
+        counter it bumps last, behind the latency's observation."""
+        count = re.search(
+            r'ray_tpu_serve_requests_total\{code="200",deployment="double"'
+            r',protocol="http"\} (\d+)', text)
+        return int(count.group(1)) if count else 0
+
     handle = serve.run(double.bind(), route_prefix="double")
     futs = [handle.remote(i) for i in range(8)]
     assert [f.result(timeout=30) for f in futs] == [i * 2 for i in range(8)]
+    before = recorded(prometheus.render())
     for _ in range(3):
         req = urllib.request.Request(
             f"http://127.0.0.1:{handle.http_port}/double",
@@ -257,7 +266,13 @@ def test_serve_request_telemetry(serve_cluster):
     import jax  # noqa: F401 — device sampling is gated on jax presence
 
     device_metrics._last_sample = 0.0  # defeat the sampling throttle
-    text = prometheus.render()
+    # The proxy records a request after it has written the reply: the
+    # third one's observation may trail the read of its body.
+    def page():
+        text = prometheus.render()
+        return text if recorded(text) >= before + 3 else None
+
+    text = _poll(page)
     assert re.search(
         r'ray_tpu_serve_request_latency_seconds_bucket\{deployment="double"'
         r',protocol="http",le="0\.005"\} \d+', text), text[:2000]

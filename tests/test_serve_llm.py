@@ -1,4 +1,7 @@
-"""Continuous-batching LLM engine tests."""
+"""Continuous-batching LLM engine tests: the engine on the tiny dense and
+mixture-of-experts models, the loop a step ahead of its read-back, and
+``stats()``'s key tree. An attention kind's engine is
+tests/test_serve_llm_<kind>.py; the models are ``tests/conftest.py``'s."""
 
 import numpy as np
 import pytest
@@ -7,13 +10,6 @@ jax = pytest.importorskip("jax")
 
 from ray_tpu.models import LlamaConfig, init_params  # noqa: E402
 from ray_tpu.serve.llm import LLMEngine  # noqa: E402
-
-
-@pytest.fixture(scope="module")
-def tiny_model():
-    cfg = LlamaConfig.tiny()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    return cfg, params
 
 
 def test_engine_single_request_matches_naive_greedy(tiny_model, naive_greedy):
@@ -447,7 +443,7 @@ def test_sampling_engine_draws_the_host_split_chain(tiny_model):
 
 
 def test_eos_drops_the_step_in_flight_and_the_next_tenant_decodes_right(
-        tiny_model, naive_greedy):
+        wait_until, tiny_model, naive_greedy):
     """An ``eos_token`` is known only at the read-back: the step
     already queued for the slot is thrown away (counted), nothing is
     emitted after the token, and slot and pages come back behind that
@@ -463,7 +459,7 @@ def test_eos_drops_the_step_in_flight_and_the_next_tenant_decodes_right(
         req = engine.submit(prompt, 8, eos_token=expected[2])
         assert list(req.tokens(timeout=120)) == expected[:3]
         assert req.result(timeout=1) == expected[:3]
-        _wait_until(lambda: engine.stats()["free_pages"] == 2)
+        wait_until(lambda: engine.stats()["free_pages"] == 2)
         stats = engine.stats()
         assert stats["decode_slot_steps_discarded"] == 1
         # Two served, one thrown away: the device ran all three.
@@ -479,8 +475,8 @@ def test_eos_drops_the_step_in_flight_and_the_next_tenant_decodes_right(
         engine.shutdown()
 
 
-def test_no_step_writes_past_the_pages_a_slot_holds(tiny_model,
-                                                    naive_greedy):
+def test_no_step_writes_past_the_pages_a_slot_holds(
+        wait_until, tiny_model, naive_greedy):
     """A request whose prompt and answer end exactly on a page edge
     ends by its count while the other slot holds page 0, which is what
     a column past a slot's pages reads: it is out of the step queued
@@ -500,7 +496,7 @@ def test_no_step_writes_past_the_pages_a_slot_holds(tiny_model,
         reqs = [engine.submit(edge, 6), engine.submit(other, 12)]
         gate.set()
         outs = [r.result(timeout=180) for r in reqs]
-        _wait_until(lambda: engine.stats()["free_pages"] == 3)
+        wait_until(lambda: engine.stats()["free_pages"] == 3)
         assert outs == [naive_greedy(params, edge, cfg, 6),
                         naive_greedy(params, other, cfg, 12)]
         # Rows written: the prompt's and every token's but the last.
@@ -512,7 +508,8 @@ def test_no_step_writes_past_the_pages_a_slot_holds(tiny_model,
 
 
 @pytest.mark.parametrize("at", ["lull", "shutdown"])
-def test_moe_counters_are_whole_with_a_step_in_flight(tiny_model, at):
+def test_moe_counters_are_whole_with_a_step_in_flight(wait_until,
+                                                      tiny_model, at):
     """The expert load is read a step behind the dispatch: at a lull
     the last step has been read, at a shutdown the step in flight is in
     no counter, so the counters agree with each other whenever read."""
@@ -525,9 +522,9 @@ def test_moe_counters_are_whole_with_a_step_in_flight(tiny_model, at):
         if at == "lull":
             for r in reqs:
                 r.result(timeout=180)
-            _wait_until(lambda: engine.stats()["active_slots"] == 0)
+            wait_until(lambda: engine.stats()["active_slots"] == 0)
         else:
-            _wait_until(lambda: engine.stats()["decode_steps"] >= 5)
+            wait_until(lambda: engine.stats()["decode_steps"] >= 5)
     finally:
         engine.shutdown()
     stats = engine.stats()
@@ -590,108 +587,6 @@ def test_a_failed_decode_with_a_step_in_flight_fails_each_request_once(
         engine.shutdown()
 
 
-# ---- a model with window layers: one allocator, a pool a kind (PR 38) ------
-
-@pytest.fixture(scope="module")
-def window_model():
-    """1 dense + 4 expert layers of kinds S S F S S, window 32: the
-    benchmark's tiny Trinity (tests/bench_harness/trinity_tiny)."""
-    import json
-    import os
-
-    from benchmark import arch
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "bench_harness", "trinity_tiny",
-                           "config.json")) as f:
-        config = json.load(f)
-    cfg = arch.program_config(config)
-    return config, cfg, jax.jit(lambda key: init_params(cfg, key))(
-        jax.random.PRNGKey(3))
-
-
-def _wait_until(predicate, timeout=60.0):
-    import time
-
-    deadline = time.time() + timeout
-    while not predicate():
-        assert time.time() < deadline, "timed out"
-        time.sleep(0.01)
-
-
-def test_window_engine_serves_within_tolerance_of_the_reference(window_model):
-    """Through the engine, four streams at once: prompts under the
-    window, crossing it while decoding, over it and far over it, 90
-    tokens each. Every served token's logit lies within 1e-4 of the
-    plain reference's best at its position (teacher-forced, one full
-    forward, no cache, no ring)."""
-    import jax.numpy as jnp
-
-    from benchmark import arch
-
-    config, cfg, params = window_model
-    reference = arch.reference(config)
-    engine = LLMEngine(cfg, params, max_batch=4, max_len=256, page_size=16,
-                       total_pages=48)
-    try:
-        rng = np.random.RandomState(0)
-        prompts = [list(rng.randint(0, 256, n)) for n in (10, 25, 40, 100)]
-        reqs = [engine.submit(p, 90) for p in prompts]
-        outs = [r.result(timeout=300) for r in reqs]
-    finally:
-        engine.shutdown()
-    # One forward of the reference for all four, padded behind their ends.
-    seqs = np.zeros((4, max(len(p) for p in prompts) + 90), np.int32)
-    for row, prompt, out in zip(seqs, prompts, outs):
-        row[:len(prompt) + 90] = prompt + out
-    margins = np.asarray(jax.jit(
-        lambda params, seqs: reference.logit_margins(params, seqs, config))(
-            params, jnp.asarray(seqs)))
-    for row, prompt in zip(margins, prompts):
-        assert row[len(prompt) - 1:len(prompt) + 89].max() <= 1e-4
-
-
-def test_window_pool_holds_a_ring_and_the_full_pool_everything(window_model):
-    """A 200-token context (120 + 80) holds window / page + 1 = 3 pages
-    in the window pool and 13 in the full one, from admission to its
-    end; both return on finish. The counters' arithmetic by hand: the
-    step at context c reads c rows in the full layer and min(c, 32) in
-    each of 4 window layers, and holds 13 + 4 x 3 pages against 5 x 13
-    with one table."""
-    _, cfg, params = window_model
-    engine = LLMEngine(cfg, params, max_batch=2, max_len=256, page_size=16,
-                       total_pages=32)
-    try:
-        assert engine.stats()["pages"] == {
-            "window": {"layers": 4, "total": 2 * 3, "free": 6},
-            "full": {"layers": 1, "total": 32, "free": 32}}
-        req = engine.submit(list(range(120)), max_new_tokens=80)
-        _wait_until(lambda: engine.stats()["active_slots"] == 1)
-        held = engine.stats()
-        assert held["pages"]["window"]["free"] == 6 - 3
-        assert held["pages"]["full"]["free"] == 32 - 13
-        assert held["free_pages"] == 32 - 13       # the pool that keeps all
-        assert len(req.result(timeout=300)) == 80
-        stats = engine.stats()
-        assert stats["pages"]["window"]["free"] == 6
-        assert stats["pages"]["full"]["free"] == stats["free_pages"] == 32
-        # 79 decode steps, at contexts 121 .. 199 (the first token came
-        # from the prefill).
-        steps = stats["decode_steps"]
-        assert steps == 79 and stats["decode_slot_steps"] == 79
-        contexts = range(121, 200)
-        assert stats["decode_kv_tokens"] == sum(contexts)
-        assert stats["decode_kv_rows_read"] == sum(
-            c + 4 * min(c, 32) for c in contexts)
-        assert stats["kv_page_steps_held"] == steps * (13 + 4 * 3)
-        assert stats["kv_page_steps_one_table"] == steps * 5 * 13
-        # Experts: 4 of the 5 layers have them.
-        assert stats["moe"]["layer_steps"] == steps * 4
-        assert stats["moe"]["decode_assignments"] == steps * 4 * cfg.top_k
-    finally:
-        engine.shutdown()
-
-
 def test_a_uniform_model_counts_rows_as_tokens_times_layers(tiny_model):
     """One definition: without window layers ``decode_kv_rows_read`` is
     ``decode_kv_tokens`` x L, and the pages held are one table's."""
@@ -710,287 +605,12 @@ def test_a_uniform_model_counts_rows_as_tokens_times_layers(tiny_model):
         engine.shutdown()
 
 
-def test_a_pool_that_cannot_hold_a_request_refuses_it_at_submit(window_model):
-    _, cfg, params = window_model
-    engine = LLMEngine(cfg, params, max_batch=2, max_len=256, page_size=16,
-                       total_pages=8)
-    try:
-        with pytest.raises(ValueError, match="full pool has only 8"):
-            engine.submit(list(range(100)), max_new_tokens=100)
-        # Its ring it could have had: the window pool refuses nothing
-        # that fits a slot.
-        assert len(engine.generate(list(range(100)), max_new_tokens=20)) == 20
-    finally:
-        engine.shutdown()
-
-
-@pytest.mark.parametrize("short", ["full", "window"])
-def test_admission_waits_on_whichever_pool_is_short(window_model, short):
-    """Two requests, and one of the pools can hold only one of them at
-    a time: the second waits for the first's pages (``page_waits``
-    counts the rounds) and both finish. The window pool is sized for
-    every slot, so it is short only with pages taken out of it."""
-    _, cfg, params = window_model
-    engine = LLMEngine(cfg, params, max_batch=2, max_len=128, page_size=16,
-                       total_pages=5 if short == "full" else 16)
-    try:
-        if short == "window":
-            del engine.books.free["window"][3:]     # one ring is left
-        a = engine.submit(list(range(40)), max_new_tokens=30)   # 5 pages
-        b = engine.submit(list(range(40, 80)), max_new_tokens=30)
-        assert len(a.result(timeout=300)) == 30
-        assert len(b.result(timeout=300)) == 30
-        stats = engine.stats()
-        assert stats["page_waits"] >= 1
-        assert stats["pages"][short]["free"] == (5 if short == "full" else 3)
-    finally:
-        engine.shutdown()
-
-
-# ---- a model with latent attention: one pool of rows (PR 42) ---------------
-
-@pytest.fixture(scope="module")
-def latent_model():
-    """1 dense + 3 expert layers, q.k 24 beside v 12, ranks 24 and 32:
-    the benchmark's tiny JoyAI (tests/bench_harness/joyai_tiny)."""
-    import json
-    import os
-
-    from benchmark import arch
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "bench_harness", "joyai_tiny",
-                           "config.json")) as f:
-        config = json.load(f)
-    cfg = arch.program_config(config)
-    return config, cfg, jax.jit(lambda key: init_params(cfg, key))(
-        jax.random.PRNGKey(3))
-
-
-def test_latent_engine_serves_within_tolerance_of_the_reference(latent_model):
-    """Through the engine, four streams at once at different lengths, 60
-    tokens each: prefill rebuilds k and v, decode attends absorbed over
-    the latent pool. Every served token's logit lies within 1e-4 of the
-    plain reference's best at its position (teacher-forced, one full
-    forward, no cache, no absorption)."""
-    import jax.numpy as jnp
-
-    from benchmark import arch
-
-    config, cfg, params = latent_model
-    reference = arch.reference(config)
-    engine = LLMEngine(cfg, params, max_batch=4, max_len=256, page_size=16,
-                       total_pages=48)
-    try:
-        rng = np.random.RandomState(0)
-        prompts = [list(rng.randint(0, 256, n)) for n in (10, 25, 40, 100)]
-        reqs = [engine.submit(p, 60) for p in prompts]
-        outs = [r.result(timeout=300) for r in reqs]
-    finally:
-        engine.shutdown()
-    seqs = np.zeros((4, max(len(p) for p in prompts) + 60), np.int32)
-    for row, prompt, out in zip(seqs, prompts, outs):
-        row[:len(prompt) + 60] = prompt + out
-    margins = np.asarray(jax.jit(
-        lambda params, seqs: reference.logit_margins(params, seqs, config))(
-            params, jnp.asarray(seqs)))
-    for row, prompt in zip(margins, prompts):
-        assert row[len(prompt) - 1:len(prompt) + 59].max() <= 1e-4
-
-
-@pytest.fixture(scope="module")
-def selecting_model():
-    """Latent attention under a learned selection of 24 positions, 4 of
-    8 experts held: the benchmark's tiny GLM-5.2 share
-    (tests/bench_harness/glm52_tiny)."""
-    import json
-    import os
-
-    from benchmark import arch
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "bench_harness", "glm52_tiny",
-                           "config.json")) as f:
-        config = json.load(f)
-    cfg = arch.program_config(config)
-    return config, cfg, init_params(cfg, jax.random.PRNGKey(3))
-
-
-def test_selecting_engine_serves_within_tolerance_of_the_reference(
-        selecting_model):
-    """Through the engine, four streams at once at different lengths, 60
-    tokens each, contexts on both sides of ``index_topk`` 24: every
-    served token's logit lies within 1e-4 of the plain reference's best
-    at its position. And the engine's account of it: two pools on one
-    table, the rows the selection kept beside the rows held, the
-    assignments that fell on the other chip's experts."""
-    import jax.numpy as jnp
-
-    from benchmark import arch
-
-    config, cfg, params = selecting_model
-    reference = arch.reference(config)
-    engine = LLMEngine(cfg, params, max_batch=4, max_len=256, page_size=16,
-                       total_pages=48)
-    try:
-        before = engine.stats()
-        assert before["pages"] == {
-            "latent": {"layers": 4, "total": 48, "free": 48},
-            "index": {"layers": 2, "total": 48, "free": 48}}
-        assert before["kv_row_bytes"] == {"latent": 160 * 4, "index": 16 * 4}
-        assert before["decode_attention"] == "gather"          # on the CPU
-        rng = np.random.RandomState(0)
-        prompts = [list(rng.randint(0, 256, n)) for n in (10, 25, 40, 100)]
-        reqs = [engine.submit(p, 60) for p in prompts]
-        outs = [r.result(timeout=300) for r in reqs]
-        stats = engine.stats()
-    finally:
-        engine.shutdown()
-    seqs = np.zeros((4, max(len(p) for p in prompts) + 60), np.int32)
-    for row, prompt, out in zip(seqs, prompts, outs):
-        row[:len(prompt) + 60] = prompt + out
-    margins = np.asarray(jax.jit(
-        lambda params, seqs: reference.logit_margins(params, seqs, config))(
-            params, jnp.asarray(seqs)))
-    for row, prompt in zip(margins, prompts):
-        assert row[len(prompt) - 1:len(prompt) + 59].max() <= 1e-4
-    assert stats["pages"]["index"]["free"] == 48
-    assert stats["decode_kv_rows_read"] == 4 * stats["decode_kv_tokens"]
-    # A step at context c takes min(c, 24) rows in each of 4 layers.
-    assert 0 < stats["decode_kv_rows_selected"] < stats["decode_kv_rows_read"]
-    assert stats["decode_kv_rows_selected"] <= 4 * 24 * stats[
-        "decode_slot_steps"]
-    moe = stats["moe"]
-    assert len(moe["expert_tokens"]) == 4
-    assert moe["assignments"] == sum(moe["expert_tokens"])
-    # Three expert layers, two experts a token, every token of every
-    # prompt and every decode step: what was not held went elsewhere.
-    tokens = sum(map(len, prompts)) + stats["decode_slot_steps"]
-    assert moe["assignments"] + moe["assignments_elsewhere"] == 3 * 2 * tokens
-    assert 0.3 < moe["assignments"] / (3 * 2 * tokens) < 0.7
-
-
-def test_latent_pool_pages_are_held_from_admission_to_finish(latent_model):
-    """A 100-token context (60 + 40) holds 7 pages of the one pool, of
-    kind "latent", from admission to its end; they return on finish.
-    The counters: a step at context c reads c rows in each of 4 layers;
-    a row is 32 + 128 float32 values."""
-    _, cfg, params = latent_model
-    engine = LLMEngine(cfg, params, max_batch=2, max_len=256, page_size=16,
-                       total_pages=20)
-    try:
-        stats = engine.stats()
-        assert stats["pages"] == {
-            "latent": {"layers": 4, "total": 20, "free": 20}}
-        assert stats["kv_row_bytes"] == {"latent": 160 * 4}
-        assert stats["decode_attention"] == "gather"          # on the CPU
-        req = engine.submit(list(range(60)), max_new_tokens=40)
-        _wait_until(lambda: engine.stats()["active_slots"] == 1)
-        held = engine.stats()
-        assert held["pages"]["latent"]["free"] == held["free_pages"] == 20 - 7
-        assert len(req.result(timeout=300)) == 40
-        stats = engine.stats()
-        assert stats["pages"]["latent"]["free"] == stats["free_pages"] == 20
-        contexts = range(61, 100)        # 39 decode steps after the prefill
-        assert stats["decode_steps"] == 39
-        assert stats["decode_kv_tokens"] == sum(contexts)
-        assert stats["decode_kv_rows_read"] == 4 * sum(contexts)
-        assert stats["kv_page_steps_held"] == \
-            stats["kv_page_steps_one_table"] == 39 * 4 * 7
-        assert stats["moe"]["layer_steps"] == 39 * 3
-    finally:
-        engine.shutdown()
-
-
 def test_a_k_and_v_pool_says_what_its_rows_hold(tiny_model):
     cfg, params = tiny_model
     engine = LLMEngine(cfg, params, max_batch=2, max_len=64, page_size=16)
     try:
         # k and v, 2 KV heads of 16 float32 values each.
         assert engine.stats()["kv_row_bytes"] == {"full": 2 * 2 * 16 * 4}
-    finally:
-        engine.shutdown()
-
-
-@pytest.fixture(scope="module")
-def state_model():
-    """3 retention layers, 4 query heads on 2 KV heads of 16: the
-    benchmark's tiny Brumby (tests/bench_harness/brumby_tiny)."""
-    import json
-    import os
-
-    from benchmark import arch
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "bench_harness", "brumby_tiny",
-                           "config.json")) as f:
-        config = json.load(f)
-    cfg = arch.program_config(config)
-    return config, cfg, jax.jit(lambda key: init_params(cfg, key))(
-        jax.random.PRNGKey(3))
-
-
-def test_state_engine_serves_within_tolerance_of_the_reference(state_model):
-    """Through the engine, six requests over four slots (two slots are
-    taken again, one after a longer request), 40 tokens each: the
-    chunked scan lays a state into the slot, decode updates it in place.
-    Every served token's logit lies within 1e-3 of the plain reference's
-    best at its position (teacher-forced, the attention form, no
-    state)."""
-    import jax.numpy as jnp
-
-    from benchmark import arch
-
-    config, cfg, params = state_model
-    reference = arch.reference(config)
-    engine = LLMEngine(cfg, params, **config["engine"])
-    try:
-        rng = np.random.RandomState(0)
-        prompts = [list(rng.randint(0, 256, n))
-                   for n in (10, 25, 150, 100, 17, 64)]
-        reqs = [engine.submit(p, 40) for p in prompts]
-        outs = [r.result(timeout=300) for r in reqs]
-    finally:
-        engine.shutdown()
-    seqs = np.zeros((6, 257), np.int32)
-    for row, prompt, out in zip(seqs, prompts, outs):
-        row[:len(prompt) + 40] = prompt + out
-    margins = np.asarray(jax.jit(
-        lambda params, seqs: reference.logit_margins(params, seqs, config))(
-            params, jnp.asarray(seqs)))
-    for row, prompt in zip(margins, prompts):
-        assert row[len(prompt) - 1:len(prompt) + 39].max() <= 1e-3
-
-
-def test_a_state_engine_admits_by_slots_alone_and_counts_states(state_model):
-    """A pool of states has no pages: nothing to reserve, wait for or
-    return; two slots admit two requests whatever their lengths and the
-    third waits for a slot. The gauge says what a slot holds in a layer,
-    the counter how many states the decode steps moved."""
-    _, cfg, params = state_model
-    engine = LLMEngine(cfg, params, max_batch=2, max_len=256, page_size=16,
-                       total_pages=1)
-    try:
-        stats = engine.stats()
-        assert stats["pages"] == {
-            "state": {"layers": 3, "total": 0, "free": 0}}
-        assert stats["kv_row_bytes"] == {} and stats["free_pages"] == 0
-        # 2 KV heads x 9 turns x 24 rows x 16 float32.
-        assert stats["state_slot_bytes"] == {"state": 2 * 9 * 24 * 16 * 4}
-        assert stats["decode_attention"] == "xla"             # on the CPU
-        reqs = [engine.submit(list(range(n)), max_new_tokens=m)
-                for n, m in ((200, 40), (9, 30), (60, 20))]
-        assert [len(r.result(timeout=300)) for r in reqs] == [40, 30, 20]
-        stats = engine.stats()
-        assert stats["page_waits"] == 0 and stats["finished"] == 3
-        assert stats["free_slots"] == 2
-        assert stats["decode_slot_steps"] == 39 + 29 + 19
-        assert stats["decode_state_slot_layers"] == 3 * (39 + 29 + 19)
-        assert stats["decode_kv_rows_read"] == 0
-        assert stats["kv_page_steps_held"] == 0
-        # Longer than max_len is still refused: the positions' bound.
-        with pytest.raises(ValueError, match="max_len"):
-            engine.submit(list(range(250)), max_new_tokens=10)
     finally:
         engine.shutdown()
 
@@ -1044,98 +664,6 @@ _NOT_INT = {"decode_attention": str, "decode_delta": str, "device_kind": str, "p
             "state_slot_bytes": dict, "stream": dict, "moe": dict}
 
 
-@pytest.fixture(scope="module")
-def hybrid_model():
-    """Delta layers among latent ones, K K K M K K M, the first FFN
-    dense, 2 of 32 experts held: the benchmark's tiny Kimi-Linear
-    (tests/bench_harness/kimi_tiny)."""
-    import json
-    import os
-
-    from benchmark import arch
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "bench_harness", "kimi_tiny",
-                           "config.json")) as f:
-        config = json.load(f)
-    cfg = arch.program_config(config)
-    return config, cfg, jax.jit(lambda key: init_params(cfg, key))(
-        jax.random.PRNGKey(3))
-
-
-def test_hybrid_engine_serves_within_tolerance_of_the_reference(hybrid_model):
-    """Through the engine, seven requests over four slots (three slots
-    are taken again, one after a longer request), 40 tokens each: a
-    prefill lays a slot's delta states, its convolution histories and
-    its latent pages from one prompt, decode steps all three. Every
-    served token's logit lies within 1e-4 of the plain reference's best
-    at its position (teacher-forced, the delta rule token by token, no
-    cache)."""
-    import jax.numpy as jnp
-
-    from benchmark import arch
-
-    config, cfg, params = hybrid_model
-    reference = arch.reference(config)
-    engine = LLMEngine(cfg, params, **config["engine"])
-    try:
-        rng = np.random.RandomState(0)
-        prompts = [list(rng.randint(0, 256, n))
-                   for n in (10, 25, 150, 100, 17, 64, 3)]
-        reqs = [engine.submit(p, 40) for p in prompts]
-        outs = [r.result(timeout=300) for r in reqs]
-    finally:
-        engine.shutdown()
-    seqs = np.zeros((len(prompts), 257), np.int32)
-    for row, prompt, out in zip(seqs, prompts, outs):
-        row[:len(prompt) + 40] = prompt + out
-    margins = np.asarray(jax.jit(
-        lambda params, seqs: reference.logit_margins(params, seqs, config))(
-            params, jnp.asarray(seqs)))
-    for row, prompt in zip(margins, prompts):
-        assert row[len(prompt) - 1:len(prompt) + 39].max() <= 1e-4
-
-
-def test_a_hybrid_engine_admits_by_slot_and_latent_pages_together(
-        hybrid_model):
-    """One admission: a slot (the delta pools have no pages) and the
-    latent pool's pages. Two slots and pages for 288 tokens: the third
-    request waits for a slot; the gauges say what a token and a slot
-    hold, the counters what the steps read of each."""
-    _, cfg, params = hybrid_model
-    engine = LLMEngine(cfg, params, max_batch=2, max_len=256, page_size=16,
-                       total_pages=18)
-    try:
-        stats = engine.stats()
-        assert stats["pages"] == {
-            "delta": {"layers": 5, "total": 0, "free": 0},
-            "latent": {"layers": 2, "total": 18, "free": 18}}
-        assert stats["free_pages"] == 18
-        # A latent row: 32 + 8 values on a lane tile of their own.
-        assert stats["kv_row_bytes"] == {"latent": (32 + 128) * 4}
-        # 4 heads of 16 x 16 float32, and 3 rows of q|k|v of 4 x 16.
-        assert stats["state_slot_bytes"] == {
-            "delta": 4 * 16 * 16 * 4 + 3 * 3 * 64 * 4}
-        assert (stats["decode_attention"], stats["decode_delta"]) == (
-            "gather", "xla")                                  # on the CPU
-        reqs = [engine.submit(list(range(n)), max_new_tokens=m)
-                for n, m in ((200, 40), (9, 30), (60, 20))]
-        assert [len(r.result(timeout=300)) for r in reqs] == [40, 30, 20]
-        stats = engine.stats()
-        assert stats["finished"] == 3 and stats["free_slots"] == 2
-        assert stats["pages"]["latent"]["free"] == 18
-        steps = 39 + 29 + 19
-        assert stats["decode_slot_steps"] == steps
-        assert stats["decode_state_slot_layers"] == 5 * steps
-        contexts = sum(n + i for n, m in ((200, 40), (9, 30), (60, 20))
-                       for i in range(1, m))
-        assert stats["decode_kv_tokens"] == contexts
-        assert stats["decode_kv_rows_read"] == 2 * contexts
-        assert stats["moe"]["assignments_elsewhere"] > 0
-    finally:
-        engine.shutdown()
-
-
 @pytest.mark.parametrize("model, pools, slot_pools, moe", [
     ("tiny_model", ["full"], [], False),
     ("tiny_moe", ["full"], [], True),
@@ -1172,90 +700,6 @@ def test_stats_keys_and_types_are_the_one_class_engines(
     (row,) = stats["requests"]
     assert [type(field) for field in row] == [
         float, float, float, float, int, int, type(None), type(None)]
-
-
-# ---- a looped model: passes, a deeper pool, an exit gate -------------------
-
-@pytest.fixture(scope="module")
-def looped_model():
-    """3 layers run 4 times, four norms a layer, an exit gate: the
-    benchmark's tiny Ouro (tests/bench_harness/ouro_tiny)."""
-    import json
-    import os
-
-    from benchmark import arch
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "bench_harness", "ouro_tiny",
-                           "config.json")) as f:
-        config = json.load(f)
-    cfg = arch.program_config(config)
-    return config, cfg, jax.jit(lambda key: init_params(cfg, key))(
-        jax.random.PRNGKey(3))
-
-
-def test_looped_engine_serves_the_reference_and_counts_its_loop(looped_model):
-    """Through the engine, three streams at once over a pool 12 layers
-    deep: every served token's logit within 1e-4 of the plain
-    reference's best at its position, and ``stats()["loop"]``: four
-    passes a decode step, one token counted a prefill and a slot a step,
-    the mean exit pass the reference's own exit distribution gives for
-    the served tokens, a token's bytes over all 12 pool layers; rows
-    read and pages held are counted at that depth."""
-    import jax.numpy as jnp
-
-    from benchmark import arch
-
-    config, cfg, params = looped_model
-    reference = arch.reference(config)
-    engine = LLMEngine(cfg, params, max_batch=4, max_len=128, page_size=16,
-                       total_pages=24)
-    new = 30
-    try:
-        rng = np.random.RandomState(0)
-        prompts = [list(rng.randint(0, 256, n)) for n in (10, 25, 60)]
-        reqs = [engine.submit(p, new) for p in prompts]
-        outs = [r.result(timeout=300) for r in reqs]
-        stats = engine.stats()
-    finally:
-        engine.shutdown()
-    seqs = np.zeros((3, max(len(p) for p in prompts) + new), np.int32)
-    for row, prompt, out in zip(seqs, prompts, outs):
-        row[:len(prompt) + new] = prompt + out
-    margins, exits = jax.jit(lambda params, seqs: (
-        reference.logit_margins(params, seqs, config),
-        reference.exit_distribution(params, seqs[:, :-1], config)))(
-            params, jnp.asarray(seqs))
-    margins, exits = np.asarray(margins), np.asarray(exits)
-    expected = 0.0
-    for row, p, prompt in zip(margins, exits, prompts):
-        made = slice(len(prompt) - 1, len(prompt) + new - 1)
-        assert row[made].max() <= 1e-4
-        expected += (p[made] * np.arange(1, 5)).sum()
-    loop = stats["loop"]
-    assert sorted(loop) == ["exit_pass_sum", "exit_tokens", "kv_token_bytes",
-                            "passes"]
-    assert loop["passes"] == 4 * stats["decode_steps"]
-    assert loop["exit_tokens"] == 3 * new == (
-        stats["prefills"] + stats["decode_slot_steps"])
-    assert loop["exit_pass_sum"] == pytest.approx(expected, rel=1e-4)
-    assert 1.0 < loop["exit_pass_sum"] / loop["exit_tokens"] < 4.0
-    # 12 pool layers of a key and a value row of 4 heads of 16, float32.
-    assert stats["kv_row_bytes"] == {"full": 2 * 4 * 16 * 4}
-    assert loop["kv_token_bytes"] == 12 * 512
-    assert stats["pages"]["full"]["layers"] == 12
-    assert stats["decode_kv_rows_read"] == 12 * stats["decode_kv_tokens"]
-    assert stats["kv_page_steps_held"] == stats["kv_page_steps_one_table"]
-    assert stats["free_pages"] == 24
-
-
-def test_an_exit_threshold_under_one_is_refused_by_name(looped_model):
-    import dataclasses
-
-    _, cfg, params = looped_model
-    with pytest.raises(NotImplementedError, match="stop at different passes"):
-        LLMEngine(dataclasses.replace(cfg, exit_threshold=0.9), params,
-                  max_batch=2, max_len=64, page_size=16)
 
 
 def test_a_model_of_one_pass_has_no_loop_in_its_stats(tiny_model):

@@ -16,7 +16,6 @@ import pytest
 jax = pytest.importorskip("jax")
 
 import ray_tpu.core.timeline  # noqa: E402,F401
-from ray_tpu.models import LlamaConfig, init_params  # noqa: E402
 from ray_tpu.serve.llm import (  # noqa: E402
     HIST_EDGES_S, LLMDeployment, LLMEngine)
 from ray_tpu.util.tsdb import quantile_from_histogram  # noqa: E402
@@ -39,13 +38,6 @@ HISTS = ("emit_gap_hist", "taken_lag_hist", "held_hist")
 NO_TOKENS = {"tokens_emitted": 0, "tokens_taken": 0, "taken_lag_s": 0.0,
              "held_s": 0.0, "held_timed_s": 0.0, "held_cpu_s": 0.0,
              "backlog": 0}
-
-
-@pytest.fixture(scope="module")
-def tiny_model():
-    cfg = LlamaConfig.tiny()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    return cfg, params
 
 
 def _admit_waits(stats):
@@ -108,27 +100,62 @@ def test_stats_conserve_requests_tokens_and_time(tiny_model):
         ran_s, rel=0.1)
 
 
+def _count_admission_rounds(engine):
+    """``rounds``, to which every admission round from now on appends
+    ``(a stream was open when it was entered, the prefills it made, the
+    seconds they took by the loop's clock)``, and ``gate``: while it is
+    clear the loop stops at the top of its next round, so that requests
+    submitted meanwhile are all in the queue when admission next runs."""
+    rounds, gate = [], threading.Event()
+    gate.set()
+    admit, streaming = engine._admit, engine.scheduler.streaming
+
+    def counted():
+        gate.wait()
+        stalling, began = streaming(), time.perf_counter()
+        made = admit()
+        rounds.append((stalling, made, time.perf_counter() - began))
+        return made
+
+    engine._admit = counted
+    return rounds, gate
+
+
 def test_late_requests_wait_for_a_slot_and_admission_stalls_streams(
         tiny_model):
     cfg, params = tiny_model
     engine = LLMEngine(cfg, params, max_batch=2, max_len=64)
     try:
+        rounds, gate = _count_admission_rounds(engine)
         engine.generate([9, 9, 9], 2, timeout=120)  # both programs compiled
-        before = engine.stats()
+        before, alone = engine.stats(), list(rounds)
         # The second request ends first, so the third is prefilled while
         # the first still streams.
+        gate.clear()
         reqs = [engine.submit([1, 2, 3, 4 + i], n)
                 for i, n in enumerate([12, 3, 3, 3])]
+        gate.set()
         for r in reqs:
             r.result(timeout=120)
         stats = engine.stats()
     finally:
+        gate.set()
         engine.shutdown()
-    # One stream alone stalls behind no prefill: only the empty round
-    # entered while its last token was in flight.
-    assert before["phase_s"]["admit_stalling"] < 1e-3
+    # One stream alone stalls behind no prefill: only the empty rounds
+    # entered while its last token was in flight. Rounds, not seconds: a
+    # loaded machine stretches an empty round as it does a prefill.
+    assert sum(made for _, made, _ in alone) == 1
+    assert not any(made for stalling, made, _ in alone if stalling)
+    # The third and the fourth request were prefilled in rounds that the
+    # first one's stream sat through, and the seconds those prefills took
+    # (each inside its round's lap) are in the stall.
+    late = rounds[len(alone):]
+    assert sum(made for _, made, _ in late) == 4
+    assert sum(made for stalling, made, _ in late if stalling) >= 2
     assert stats["phase_s"]["admit_stalling"] \
-        > 2 * before["phase_s"]["admit_stalling"]
+        - before["phase_s"]["admit_stalling"] \
+        >= sum(took for stalling, made, took in late if stalling and made) \
+        > 0
     waits = _admit_waits(stats)[-4:]
     assert min(waits[2:]) > max(waits[:2])
 

@@ -175,7 +175,7 @@ def _tiny(name):
         return arch.program_config(json.load(f))
 
 
-# Fixture of tests/test_serve_llm.py -> the benchmark's tiny preset.
+# Fixture of tests/conftest.py -> the benchmark's tiny preset.
 MODELS = {"tiny_model": "tiny_model", "window_model": "trinity_tiny",
           "latent_model": "joyai_tiny", "state_model": "brumby_tiny"}
 BATCH, TOTAL, PAGE, MAX_LEN = 3, 40, 16, 256
