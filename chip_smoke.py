@@ -426,7 +426,8 @@ def _llm_deployment():
             i32 = jax.numpy.int32
             shape = jax.ShapeDtypeStruct
             prefill = eng.runner.prefill.__wrapped_jit__.lower(
-                eng.params, eng.runner.cache, shape((eng.max_batch,), i32),
+                eng.runner.params, eng.runner.cache,
+                shape((eng.max_batch,), i32),
                 shape((1, bucket), i32), shape((), i32), shape((), i32),
                 {kind: shape((min(bucket // eng.page_size, columns),), i32)
                  for kind, (_, _, columns) in eng.books.pools.items()},

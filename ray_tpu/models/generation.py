@@ -288,8 +288,8 @@ class KVBooks:
     rides on another's pages (``PagedKVCache.RIDES``) has no account of
     its own: what is reserved in the pool it rides on is its too. Built
     from the
-    five arguments ``cache`` was created with, and ``cache`` for what
-    its pools weigh. One thread writes, the engine's loop; a ``reading``
+    five arguments ``cache`` was created with, and ``cache`` (or its
+    shapes) for what its pools weigh. One thread writes, the engine's loop; a ``reading``
     on another holds the engine's lock."""
 
     def __init__(self, cfg: LlamaConfig, batch: int, total_pages: int,
@@ -310,7 +310,8 @@ class KVBooks:
             kind: layers + sum(self.pools[r][0] for r, on in rides.items()
                                if on == kind and r in self.pools)
             for kind, (layers, _, _) in self._own.items()}
-        held = {kind: sum(pool.nbytes for pool in cache.pools(kind))
+        held = {kind: sum(pool.size * pool.dtype.itemsize
+                          for pool in cache.pools(kind))
                 for kind in self.pools}
         # What a token holds in one layer of each pool that has pages,
         # and what a slot holds in one layer of one that has none, as

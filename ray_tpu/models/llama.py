@@ -796,6 +796,91 @@ def _ring_saved(out):
     return jax.tree.map(lambda y: checkpoint_name(y, _RING_SAVED), out)
 
 
+# The stacked projections a serving engine keeps in another order than
+# the published one (serve/llm.py ``_Runner``), each with the order: the
+# published layer's axes as the serving tree lays them, the heads first
+# and LAST the axis the decode step contracts. Given so the TPU compiler
+# reads a layer's slice where it lies; given as published ([M, H, D]) it
+# copies every one of these leaves into that order inside every decode
+# step (Ouro: 3 x 1.22 ms of a 40 ms step; tests/tpu_rehearsal.py
+# ``assert_projections_stay_in_place`` holds each leaf to it). ``wk_b``
+# is the one leaf two programs contract differently: the decode step's
+# absorbed form contracts the head's width, a prefill ``kv_lora_rank``;
+# the decode step decides. A leaf turns only as a stack of [M, H, D]
+# layers (a retention layer's ``wg`` [M, Hkv] stays as it is), and lies
+# in the serving tree under its name and ``TURNED``.
+SERVING_ORDER = {
+    "wq": (1, 2, 0), "wk": (1, 2, 0), "wv": (1, 2, 0), "wg": (1, 2, 0),
+    "wq_b": (1, 2, 0), "wi_q": (1, 2, 0), "wv_b": (1, 2, 0),
+    "wk_b": (1, 0, 2), "wf_b": (1, 2, 0), "wg_b": (1, 2, 0),
+}
+TURNED = "_t"
+
+
+def turning_leaves(params, back: bool = False):
+    """Of each of ``layer_stacks(params)`` the leaves that the serving
+    tree keeps in another order, ``{name: leaf}``: of a published tree
+    those that turn, with ``back`` of a serving tree those that were
+    turned."""
+    def turns(name, w):
+        if back:
+            return name.endswith(TURNED) and name[:-len(TURNED)] in SERVING_ORDER
+        return name in SERVING_ORDER and w.ndim == 4
+
+    return tuple({name: w for name, w in stack.items() if turns(name, w)}
+                 for stack in layer_stacks(params))
+
+
+def turn_leaves(leaves, back: bool = False):
+    """``turning_leaves``' dicts with every leaf transposed into the
+    serving order and named ``name + TURNED``, or ``back`` into the
+    published one under its published name. Traced: the caller jits it,
+    one program whatever the stacks and leaves."""
+    def turned(name, w):
+        if back:
+            name = name[:-len(TURNED)]
+            order = SERVING_ORDER[name]
+            order = tuple(order.index(a) for a in range(len(order)))
+            new = name
+        else:
+            order, new = SERVING_ORDER[name], name + TURNED
+        return new, jnp.transpose(w, (0, *(a + 1 for a in order)))
+
+    return tuple(dict(turned(name, w) for name, w in stack.items())
+                 for stack in leaves)
+
+
+def serving_tree(params, turn=turn_leaves, back: bool = False):
+    """The published tree ``params`` as a serving engine keeps it, or
+    with ``back`` such a tree as it was published: the leaves of
+    ``SERVING_ORDER`` through ``turn`` (``turn_leaves``, or the caller's
+    jitted form of it); every other leaf is the same array, shared and
+    not copied."""
+    old = turning_leaves(params, back)
+    new = turn(old, back) if any(old) else old
+    stacks = tuple({**{k: v for k, v in stack.items() if k not in gone},
+                    **come}
+                   for stack, gone, come in zip(layer_stacks(params), old, new))
+    uniform = not isinstance(params["layers"], (tuple, list))
+    return {**params, "layers": stacks[0] if uniform else stacks}
+
+
+def project(lp, name, x, spec):
+    """``einsum(spec, x, w)`` with this layer's slice ``w`` of the stacked
+    projection ``name``, ``spec`` written for the published order of its
+    axes: the one place that reads a leaf of ``SERVING_ORDER``. A
+    published tree holds it under ``name`` and is multiplied as it
+    always was; a serving tree holds it turned under ``name + TURNED``,
+    and the same product is asked for with the leaf's letters in that
+    order."""
+    if name in lp:
+        return jnp.einsum(spec, x, lp[name])
+    operands, out = spec.split("->")
+    xs, ws = operands.split(",")
+    ws = "".join(ws[a] for a in SERVING_ORDER[name])
+    return jnp.einsum(f"{xs},{ws}->{out}", x, lp[name + TURNED])
+
+
 def qkv_proj(cfg: LlamaConfig, lp, x, *, mesh=None):
     """The block's first half up to rotary: attention norm, then q
     [B,S,H,Dh] and k, v [B,S,Hkv,Dh], q and k normed where the model has
@@ -812,9 +897,9 @@ def qkv_proj(cfg: LlamaConfig, lp, x, *, mesh=None):
         q, k, v = _ring_saved(
             gather_matmul(mesh, h, (lp["wq"], lp["wk"], lp["wv"])))
     else:
-        q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
-        k = jnp.einsum("bsm,mhd->bshd", h, lp["wk"])
-        v = jnp.einsum("bsm,mhd->bshd", h, lp["wv"])
+        q = project(lp, "wq", h, "bsm,mhd->bshd")
+        k = project(lp, "wk", h, "bsm,mhd->bshd")
+        v = project(lp, "wv", h, "bsm,mhd->bshd")
     if cfg.qk_norm and cfg.qk_norm_per_head:
         q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
@@ -825,7 +910,7 @@ def qkv_proj(cfg: LlamaConfig, lp, x, *, mesh=None):
                      cfg.rms_eps).reshape(k.shape)
     gate = None
     if cfg.attn_gate:
-        g = jnp.einsum("bsm,mhd->bshd", h, lp["wg"])
+        g = project(lp, "wg", h, "bsm,mhd->bshd")
         gate = jax.nn.sigmoid(g.astype(jnp.float32)).astype(g.dtype)
     elif cfg.retention:
         with jax.named_scope("ret.gate"):
@@ -869,9 +954,9 @@ def _latent_parts(cfg: LlamaConfig, lp, x, positions):
         if cfg.q_lora_rank:
             c_q = rms_norm(jnp.einsum("bsm,mr->bsr", h, lp["wq_a"]),
                            lp["q_a_norm"], cfg.rms_eps)
-            q = jnp.einsum("bsr,rhd->bshd", c_q, lp["wq_b"])
+            q = project(lp, "wq_b", c_q, "bsr,rhd->bshd")
         else:
-            q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
+            q = project(lp, "wq", h, "bsm,mhd->bshd")
         q = jnp.concatenate([q[..., :nope], turned(q[..., nope:])], axis=-1)
     with jax.named_scope("mla.kv"):
         kv = jnp.einsum("bsm,mr->bsr", h, lp["wkv_a"])
@@ -892,18 +977,18 @@ def delta_proj(cfg: LlamaConfig, lp, x):
     gate for behind it."""
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     with jax.named_scope("kda.proj"):
-        q = jnp.einsum("bsm,mhd->bshd", h, lp["wq"])
-        k = jnp.einsum("bsm,mhd->bshd", h, lp["wk"])
-        v = jnp.einsum("bsm,mhd->bshd", h, lp["wv"])
+        q = project(lp, "wq", h, "bsm,mhd->bshd")
+        k = project(lp, "wk", h, "bsm,mhd->bshd")
+        v = project(lp, "wv", h, "bsm,mhd->bshd")
     with jax.named_scope("kda.gate"):
-        f = jnp.einsum("bsr,rhd->bshd",
-                       jnp.einsum("bsm,mr->bsr", h, lp["wf_a"]), lp["wf_b"])
+        f = project(lp, "wf_b", jnp.einsum("bsm,mr->bsr", h, lp["wf_a"]),
+                    "bsr,rhd->bshd")
         log_a = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(
             f.astype(jnp.float32) + lp["dt_bias"])
         beta = jax.nn.sigmoid(
             jnp.einsum("bsm,mh->bsh", h, lp["wb"]).astype(jnp.float32))
-        g = jnp.einsum("bsr,rhd->bshd",
-                       jnp.einsum("bsm,mr->bsr", h, lp["wg_a"]), lp["wg_b"])
+        g = project(lp, "wg_b", jnp.einsum("bsm,mr->bsr", h, lp["wg_a"]),
+                    "bsr,rhd->bshd")
         gate = jax.nn.sigmoid(g.astype(jnp.float32)).astype(g.dtype)
     return q, k, (v, log_a, beta), gate
 
@@ -959,7 +1044,7 @@ def index_proj(cfg: LlamaConfig, lp, h, c_q, positions):
     the "index" pool keeps; a token's score of a cached one is ``sum_j
     w_j relu(q_j . k)`` (ops/sparse_attention.py)."""
     with jax.named_scope("index.proj"):
-        q = jnp.einsum("bsr,rhd->bshd", c_q, lp["wi_q"])
+        q = project(lp, "wi_q", c_q, "bsr,rhd->bshd")
         k = jnp.einsum("bsm,md->bsd", h, lp["wi_k"])
         kf = k.astype(jnp.float32)
         kf = kf - kf.mean(axis=-1, keepdims=True)
@@ -980,8 +1065,8 @@ def latent_kv(cfg: LlamaConfig, lp, row):
     rank, rot = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     with jax.named_scope("mla.kv"):
         c = row[..., :rank]
-        k_nope = jnp.einsum("bsr,rhd->bshd", c, lp["wk_b"])
-        v = jnp.einsum("bsr,rhd->bshd", c, lp["wv_b"])
+        k_nope = project(lp, "wk_b", c, "bsr,rhd->bshd")
+        v = project(lp, "wv_b", c, "bsr,rhd->bshd")
         k_rope = jnp.broadcast_to(
             row[..., None, rank:rank + rot],
             k_nope.shape[:-1] + (rot,))
@@ -995,7 +1080,7 @@ def latent_absorb_q(cfg: LlamaConfig, lp, q):
     k_nope ever made. Returns [B,S,H,``latent_row``], laid as a row."""
     nope = cfg.qk_nope_head_dim
     with jax.named_scope("mla.absorb"):
-        q_lat = jnp.einsum("bshd,rhd->bshr", q[..., :nope], lp["wk_b"])
+        q_lat = project(lp, "wk_b", q[..., :nope], "bshd,rhd->bshr")
         return _latent_row(cfg, q_lat, q[..., nope:])
 
 
@@ -1003,7 +1088,7 @@ def latent_absorb_out(cfg: LlamaConfig, lp, attn):
     """W_UV behind the attention: probabilities times latent rows
     [B,S,H,kv_lora_rank] to a head's [B,S,H,``v_head_dim``]."""
     with jax.named_scope("mla.absorb"):
-        return jnp.einsum("bshr,rhd->bshd", attn, lp["wv_b"])
+        return project(lp, "wv_b", attn, "bshr,rhd->bshd")
 
 
 EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")

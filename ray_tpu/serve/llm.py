@@ -304,6 +304,33 @@ class _Step:
         self.dropped: List[int] = []
 
 
+def serving_weights(params, turn):
+    """``(serving tree, counter)``: the tree the serving programs take,
+    made once from the published tree an engine is given
+    (``llama.serving_tree``), and what making it cost
+    (``stats()["weights"]``). Every stacked projection of
+    ``llama.SERVING_ORDER`` is stored with the axis the decode step
+    contracts last (why: there), by ONE call of ``turn`` (the runner's
+    jitted ``llama.turn_leaves``) over those leaves, one program more at
+    set-up whatever the runs and leaves; every other leaf is the given
+    array, shared. Nothing is donated: the given tree is its owner's,
+    and serves other engines."""
+    import jax
+
+    from ..models import llama
+
+    published = llama.turning_leaves(params)
+    started = time.perf_counter()
+    serving = jax.block_until_ready(llama.serving_tree(params, turn))
+    return serving, {
+        "leaves_turned": sum(len(stack) for stack in published),
+        "bytes_turned": sum(int(w.nbytes) for stack in published
+                            for w in stack.values()),
+        # Trace, compile and run of the one call.
+        "turn_s": time.perf_counter() - started,
+    }
+
+
 def serving_programs(cfg, temperature: float):
     """``(decode_step, prefill)``, the two functions the engine jits.
 
@@ -610,17 +637,25 @@ class _Runner:
     tokens, the PRNG key, the active mask), donation and the rebuild
     after a donated call failed, and the read-back's format, which
     ``with_load`` packs and ``unpack`` alone takes apart, with the MoE
-    counters it feeds. Slots, token lists and page ids: no request."""
+    counters it feeds. Slots, token lists and page ids: no request.
+    ``params`` is the SERVING tree (``serving_weights``), the only tree
+    the engine holds; ``published_params`` turns it back for whoever
+    reads weights as published (a reference's ``forward``)."""
 
     def __init__(self, cfg, params, temperature: float, max_batch: int,
                  new_cache, lock: threading.Lock):
         import jax
 
+        from ..models import llama
         from ..models.llama import layer_runs, prefill_attention_path
         from ..ops.grouped_matmul import grouped_path
         from ..util.device_metrics import instrumented_jit
 
-        self.params = params
+        # The serving tree, before the pool is allocated: the engine's
+        # one resident copy of each weight (the published leaves that
+        # turned are the caller's to let go).
+        self._turn = instrumented_jit(llama.turn_leaves, static_argnums=1)
+        self.params, self._weights = serving_weights(params, self._turn)
         self._max_batch = max_batch
         self._new_cache = new_cache
         self._lock = lock
@@ -678,11 +713,26 @@ class _Runner:
         import jax
         import jax.numpy as jnp
 
-        self.cache = self._new_cache()
+        self._cache = None
         self._last_tok = jnp.zeros((self._max_batch,), dtype=jnp.int32)
         self._rng = jax.random.PRNGKey(0)
         self._active = jnp.zeros((self._max_batch,), dtype=bool)
         self._active_slots: frozenset = frozenset()
+
+    @property
+    def cache(self):
+        """The KV cache the programs carry, allocated when first asked
+        for (a request's prefill; ``stats()`` and the books need its
+        sizes alone): whoever built the engine still holds the published
+        leaves that turned while its constructor runs, and the pool is
+        not made to stand beside two copies of a weight."""
+        if self._cache is None:
+            self._cache = self._new_cache()
+        return self._cache
+
+    @cache.setter
+    def cache(self, cache) -> None:
+        self._cache = cache
 
     def run_prefill(self, slot: int, prompt: List[int], bucket: int,
                     pages: Dict[str, List[int]], tables) -> int:
@@ -778,12 +828,22 @@ class _Runner:
                     moe["prefill_experts_reached"] += int(out[-1])
         return out[:n]
 
+    def published_params(self):
+        """The tree the engine was given, leaf for leaf and bit for bit:
+        the serving tree with its turned leaves turned back, made anew
+        at every call and the caller's to keep or drop (the engine
+        holds the serving tree alone); the other leaves are shared."""
+        from ..models import llama
+
+        return llama.serving_tree(self.params, self._turn, back=True)
+
     def reading(self) -> Dict[str, Any]:
         return {
             # Where the engine's programs run: a rate read from these
             # stats is a device number only on a "tpu".
             "platform": self._device.platform,
             "device_kind": self._device.device_kind,
+            "weights": dict(self._weights),
             "prefill_streamed_bucket_tokens": self._streamed_bucket_tokens,
             **({"moe": {**self._moe, "expert_tokens":
                         self._moe["expert_tokens"].tolist()}}
@@ -800,10 +860,11 @@ class LLMEngine:
     def __init__(self, cfg, params, *, max_batch: int = 8,
                  max_len: int = 512, temperature: float = 0.0,
                  page_size: int = 16, total_pages: Optional[int] = None):
+        import jax
+
         from ..models.generation import KVBooks, PagedKVCache
 
         self.cfg = cfg
-        self.params = params
         self.max_batch = max_batch
         self.max_len = max_len
         self.temperature = temperature
@@ -822,11 +883,14 @@ class LLMEngine:
         self.total_pages = total_pages or max_batch * max_pages_per_seq
         geometry = (cfg, max_batch, self.total_pages, page_size,
                     max_pages_per_seq)
+
+        def new_cache():
+            return PagedKVCache.create(*geometry)
+
         self._lock = threading.Lock()
         self.runner = _Runner(cfg, params, temperature, max_batch,
-                              lambda: PagedKVCache.create(*geometry),
-                              self._lock)
-        self.books = KVBooks(*geometry, self.runner.cache)
+                              new_cache, self._lock)
+        self.books = KVBooks(*geometry, jax.eval_shape(new_cache))
         self.scheduler = _Scheduler(
             self.books, self._lock, max_batch=max_batch, max_len=max_len,
             min_bucket=page_size)
@@ -842,6 +906,14 @@ class LLMEngine:
         self._thread.start()
 
     # ---- public API --------------------------------------------------------
+
+    @property
+    def params(self):
+        """The published tree the engine was given (what a reference's
+        ``forward`` reads), turned back from the serving tree when asked:
+        ``_Runner.published_params``. The programs' own tree is
+        ``runner.params``."""
+        return self.runner.published_params()
 
     def submit(self, prompt: List[int], max_new_tokens: int = 32,
                eos_token: Optional[int] = None,
@@ -981,6 +1053,15 @@ class LLMEngine:
         prefills) and ``small_rows_layer_calls``, those whose program
         was built with the grouped matmul for few rows a group
         (ops/grouped_matmul.py: the same rule, by the program's rows).
+
+        ``weights``, constants of the engine's start (``serving_weights``):
+        ``leaves_turned`` (stacked projections of the given tree that the
+        engine stores in the serving order, ``llama.SERVING_ORDER``, a
+        run of layers counting its own), ``bytes_turned`` (theirs; the
+        engine's own copy of them, every other leaf being the given
+        array) and ``turn_s`` (the seconds the one jitted call over
+        them took, traced, compiled and run, before the pool was
+        allocated).
 
         ``loop``, for a looped model only (``cfg.passes`` > 1): ``passes``
         (passes over the stack the decode steps ran: a step adds the

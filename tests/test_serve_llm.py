@@ -642,7 +642,8 @@ _STATS_KEYS = [
     "page_walk_step_tokens", "pages", "phase_cpu_s", "phase_s", "platform",
     "prefill_bucket_tokens",
     "prefill_streamed_bucket_tokens", "prefill_tokens", "prefills", "queued",
-    "requests", "state_slot_bytes", "stream", "submitted", "t", "total_pages"]
+    "requests", "state_slot_bytes", "stream", "submitted", "t", "total_pages",
+    "weights"]
 _NESTED_KEYS = {
     "phase_s": ["admit", "admit_stalling", "decode", "emit", "idle",
                 "inputs", "readback"],
@@ -653,6 +654,7 @@ _NESTED_KEYS = {
                "held_s", "held_timed_s", "hist_edges_s", "taken_lag_hist",
                "taken_lag_s",
                "tokens_emitted", "tokens_taken"],
+    "weights": ["bytes_turned", "leaves_turned", "turn_s"],
     "moe": ["assignments", "decode_assignments", "expert_tokens",
             "experts_reached", "layer_calls", "layer_steps",
             "prefill_experts_reached", "small_rows_layer_calls"]}
@@ -661,7 +663,8 @@ _NOT_INT = {"decode_attention": str, "decode_delta": str, "device_kind": str, "p
             "latent_walk_step_tokens": dict,
             "page_walk_step_tokens": dict, "pages": dict, "phase_s": dict,
             "phase_cpu_s": dict, "decode_dispatch": dict,
-            "state_slot_bytes": dict, "stream": dict, "moe": dict}
+            "state_slot_bytes": dict, "stream": dict, "moe": dict,
+            "weights": dict}
 
 
 @pytest.mark.parametrize("model, pools, slot_pools, moe", [

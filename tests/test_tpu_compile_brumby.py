@@ -10,7 +10,8 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from tpu_rehearsal import (  # noqa: E402
-    arr, cell_shapes, decode_program, fits_one_chip, prefill_program)
+    arr, assert_projections_stay_in_place, cell_shapes, decode_program,
+    fits_one_chip, prefill_program)
 
 # 6 layers, 16 slots, 8 KV heads, 65 turns of 136 rows of 128: 3.48 GB.
 STATE_POOL = (6, 16, 8, 65, 136, 128)
@@ -63,6 +64,7 @@ def test_brumby_decode_program_compiles_for_v5e(v5e, as_tpu, brumby):
     assert {k: v.shape for k, v in cache.k.items()} == {"state": STATE_POOL}
     assert cache.v == {} and cache.page_table["state"].shape == (16, 0)
     compiled = decode_program(cfg, v5e, params, cache)
+    assert_projections_stay_in_place(compiled, params)
     assert fits_one_chip(compiled)
     assert "tpu_custom_call" in compiled.as_text()
     memory = compiled.memory_analysis()

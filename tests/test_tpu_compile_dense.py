@@ -11,8 +11,9 @@ import jax.numpy as jnp  # noqa: E402
 from ray_tpu.ops import paged_attention  # noqa: E402
 from tpu_rehearsal import (  # noqa: E402
     CHAT_CELL, CHAT_POOL_PAGES, D, HLO_INSTRUCTION, PAGE, arr,
-    assert_pool_stays_in_place, decode_program, decode_shapes, olmoe_cfg,
-    prefill_program, serve_cfg, serve_shapes)
+    assert_pool_stays_in_place, assert_projections_stay_in_place,
+    decode_program, decode_shapes, olmoe_cfg, prefill_program, serve_cfg,
+    serve_shapes)
 
 BUCKET = 512
 
@@ -27,6 +28,7 @@ def test_paged_decode_program_compiles_for_v5e(v5e, as_tpu, batch,
     cfg = serve_cfg()
     params, cache = serve_shapes(cfg, v5e, batch, pool_pages, pages_per_seq)
     compiled = decode_program(cfg, v5e, params, cache)
+    assert_projections_stay_in_place(compiled, params)
     pool = cache.k["full"].shape
     assert "tpu_custom_call" in compiled.as_text()
     assert_pool_stays_in_place(compiled, pool)

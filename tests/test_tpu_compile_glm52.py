@@ -10,8 +10,9 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from tpu_rehearsal import (  # noqa: E402
-    PAGE, assert_pool_stays_in_place, cell_shapes, decode_program,
-    fits_one_chip, prefill_program, weights_program)
+    PAGE, assert_pool_stays_in_place, assert_projections_stay_in_place,
+    cell_shapes, decode_program, fits_one_chip, prefill_program,
+    weights_program)
 
 GLM_POOLS = {"latent": (5, 8192, PAGE, 640), "index": (2, 8192, PAGE, 128)}
 
@@ -31,6 +32,7 @@ def test_glm52_decode_program_compiles_for_v5e(v5e, as_tpu, glm52):
     assert {k: v.shape for k, v in cache.k.items()} == GLM_POOLS
     assert cache.v == {} and set(cache.page_table) == {"latent"}
     compiled = decode_program(cfg, v5e, params, cache)
+    assert_projections_stay_in_place(compiled, params)
     assert fits_one_chip(compiled)
     assert "tpu_custom_call" in compiled.as_text()
     for kind, pool in GLM_POOLS.items():

@@ -12,8 +12,9 @@ import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.ops import paged_attention  # noqa: E402
 from tpu_rehearsal import (  # noqa: E402
-    PAGE, arr, assert_pool_stays_in_place, cell_shapes, decode_program,
-    fits_one_chip, prefill_program, weights_program)
+    PAGE, arr, assert_pool_stays_in_place, assert_projections_stay_in_place,
+    cell_shapes, decode_program, fits_one_chip, prefill_program,
+    weights_program)
 
 LATENT_POOL = (5, 8192, PAGE, 640)
 
@@ -51,6 +52,7 @@ def test_joyai_decode_program_compiles_for_v5e(v5e, as_tpu, joyai):
     assert {k: v.shape for k, v in cache.k.items()} == {"latent": LATENT_POOL}
     assert cache.v == {}
     compiled = decode_program(cfg, v5e, params, cache)
+    assert_projections_stay_in_place(compiled, params)
     assert fits_one_chip(compiled)
     assert "tpu_custom_call" in compiled.as_text()
     assert_pool_stays_in_place(compiled, LATENT_POOL)

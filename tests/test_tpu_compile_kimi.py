@@ -12,8 +12,9 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from tpu_rehearsal import (  # noqa: E402
-    HLO_INSTRUCTION, PAGE, arr, assert_pool_stays_in_place, cell_shapes,
-    decode_program, fits_one_chip, prefill_program, weights_program)
+    HLO_INSTRUCTION, PAGE, arr, assert_pool_stays_in_place,
+    assert_projections_stay_in_place, cell_shapes, decode_program,
+    fits_one_chip, prefill_program, weights_program)
 
 # 20 layers, 16 slots, 32 heads of 128 x 128: 0.67 GB.
 DELTA_POOL = (20, 16, 32, 128, 128)
@@ -81,6 +82,7 @@ def test_kimi_decode_program_compiles_for_v5e(v5e, as_tpu, kimi):
     assert cache.page_table["delta"].shape == (16, 0)
     assert cache.page_table["latent"].shape == (16, 1024)
     compiled = decode_program(cfg, v5e, params, cache)
+    assert_projections_stay_in_place(compiled, params)
     assert fits_one_chip(compiled)
     text = compiled.as_text()
     assert "f32[16,1,32,128]" in text           # the delta step

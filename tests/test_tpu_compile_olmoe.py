@@ -8,7 +8,8 @@ jax = pytest.importorskip("jax")
 
 from tpu_rehearsal import (  # noqa: E402
     CHAT_CELL, CHAT_POOL_PAGES, HLO_INSTRUCTION, assert_pool_stays_in_place,
-    decode_program, fits_one_chip, olmoe_cfg, prefill_program, serve_shapes)
+    assert_projections_stay_in_place, decode_program, fits_one_chip, olmoe_cfg,
+    prefill_program, serve_shapes)
 
 
 @pytest.fixture(scope="module")
@@ -25,6 +26,7 @@ def test_olmoe_decode_program_compiles_for_v5e(v5e, as_tpu, olmoe):
     page walk at ``Hkv`` 16 leaves this pool in place too."""
     cfg, params, cache = olmoe
     compiled = decode_program(cfg, v5e, params, cache)
+    assert_projections_stay_in_place(compiled, params)
     assert "tpu_custom_call" in compiled.as_text()  # the page walk
     assert fits_one_chip(compiled)
     assert_pool_stays_in_place(compiled, cache.k["full"].shape)

@@ -10,8 +10,8 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from tpu_rehearsal import (  # noqa: E402
-    PAGE, assert_pool_stays_in_place, cell_shapes, decode_program,
-    fits_one_chip, prefill_program)
+    PAGE, assert_pool_stays_in_place, assert_projections_stay_in_place,
+    cell_shapes, decode_program, fits_one_chip, prefill_program)
 
 
 @pytest.fixture(scope="module")
@@ -30,6 +30,7 @@ def test_smallthinker_decode_program_compiles_for_v5e(v5e, as_tpu,
         "full": (2, 4, 16384, PAGE, 128),
         "window": (6, 4, 16 * 257, PAGE, 128)}
     compiled = decode_program(cfg, v5e, params, cache)
+    assert_projections_stay_in_place(compiled, params)
     assert fits_one_chip(compiled)
     assert_pool_stays_in_place(compiled, cache.k["full"].shape)
     assert_pool_stays_in_place(compiled, cache.k["window"].shape,

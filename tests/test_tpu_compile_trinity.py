@@ -8,8 +8,8 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from tpu_rehearsal import (  # noqa: E402
-    PAGE, assert_pool_stays_in_place, cell_shapes, decode_program,
-    fits_one_chip, prefill_program)
+    PAGE, assert_pool_stays_in_place, assert_projections_stay_in_place,
+    cell_shapes, decode_program, fits_one_chip, prefill_program)
 
 
 @pytest.fixture(scope="module")
@@ -26,6 +26,7 @@ def test_trinity_decode_program_compiles_for_v5e(v5e, as_tpu, trinity):
     assert {k: v.shape for k, v in cache.k.items()} == {
         "window": (5, 4, 32 * 129, PAGE, 128), "full": (1, 4, 8192, PAGE, 128)}
     compiled = decode_program(cfg, v5e, params, cache)
+    assert_projections_stay_in_place(compiled, params)
     assert fits_one_chip(compiled)
     # The window pool is the larger: the temporaries' bound is its slice.
     assert_pool_stays_in_place(compiled, cache.k["window"].shape)

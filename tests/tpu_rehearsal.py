@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import LlamaConfig, generation, init_params
+from ray_tpu.models import LlamaConfig, generation, init_params, llama
 
 B, S, H, HKV, D = 8, 2048, 32, 8, 128
 PAGE, POOL_PAGES, PAGES_PER_SEQ = 16, 4096, 64
@@ -72,8 +72,9 @@ def olmoe_cfg():
 
 def serve_shapes(cfg, sharding, batch=B, pool_pages=POOL_PAGES,
                  pages_per_seq=PAGES_PER_SEQ):
+    """``(params, cache)`` as an engine holds them: the serving tree."""
     params = jax.eval_shape(
-        lambda: init_params(cfg, jax.random.PRNGKey(0))
+        lambda: llama.serving_tree(init_params(cfg, jax.random.PRNGKey(0)))
     )
     cache = jax.eval_shape(
         lambda: generation.PagedKVCache.create(
@@ -161,8 +162,8 @@ def fits_one_chip(compiled):
 _POOL_CARRIERS = {"parameter", "get-tuple-element", "tuple", "bitcast",
                   "while"}
 HLO_INSTRUCTION = re.compile(
-    r"^\s*(?:ROOT )?%?[\w.-]+ = (?P<result>.*?) (?P<op>[\w-]+)\((?P<rest>.*)$",
-    re.MULTILINE)
+    r"^\s*(?:ROOT )?%?(?P<name>[\w.-]+) = (?P<result>.*?) "
+    r"(?P<op>[\w-]+)\((?P<rest>.*)$", re.MULTILINE)
 
 
 def assert_pool_stays_in_place(compiled, pool_shape, temporaries=True):
@@ -185,4 +186,46 @@ def assert_pool_stays_in_place(compiled, pool_shape, temporaries=True):
         if m["op"] == "custom-call" and "tpu_custom_call" in m["rest"]:
             continue
         offenders.append(m[0].strip()[:160])
+    assert not offenders, offenders
+
+
+_HLO_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+
+
+def _array_sizes(text):
+    """``{(dtype, elements)}`` of every array type written in ``text``."""
+    return {(dtype, math.prod(int(d) for d in dims.split(",") if d))
+            for dtype, dims in _HLO_ARRAY.findall(text)}
+
+
+def assert_projections_stay_in_place(compiled, params):
+    """The guard against a re-laid weight in a serving program (ROADMAP
+    S10(vii); Ouro's 3 x 1.22 ms of a 40 ms step before PR 69): no
+    ``copy`` and no copy fusion (``copy_bitcast_fusion``) of the
+    optimized HLO has a stacked projection of ``llama.SERVING_ORDER`` as
+    its operand or result: whole, or one layer's slice, told by the
+    element type and the count of elements so that a copy of a reshaped
+    leaf is seen too. An asynchronous ``copy-start`` of a layer's slice
+    is a prefetch into another memory space in the SAME layout, and is
+    not counted. ``params`` is the tree the program was compiled for."""
+    stacks = (llama.turning_leaves(params, back=True),
+              llama.turning_leaves(params))
+    sizes = set()
+    for w in (w for kind in stacks for stack in kind
+              for w in stack.values()):
+        dtype = {"bfloat16": "bf16", "float32": "f32"}[w.dtype.name]
+        sizes |= {(dtype, math.prod(w.shape)), (dtype, math.prod(w.shape[1:]))}
+    assert sizes, "the tree has no stacked projection"
+    text = compiled.as_text()
+    results = {m["name"]: m["result"] for m in HLO_INSTRUCTION.finditer(text)}
+    offenders = []
+    for m in HLO_INSTRUCTION.finditer(text):
+        if m["op"] != "copy" and not (m["op"] == "fusion"
+                                      and "copy" in m["name"]):
+            continue
+        touched = _array_sizes(m["result"])
+        for operand in re.findall(r"%([\w.-]+)", m["rest"].split(")")[0]):
+            touched |= _array_sizes(results.get(operand, ""))
+        if touched & sizes:
+            offenders.append(f"{m['name']} = {m['result'][:80]}")
     assert not offenders, offenders
