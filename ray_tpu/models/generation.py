@@ -1,9 +1,9 @@
 """The serving programs of the Llama family over the paged KV cache.
 
 The engine's cache is the paged one (``PagedKVCache``): for each of the
-five attention kinds a model may have ("full", "window", "latent",
-"state", "delta") a pool, and for the three that keep a row a token a
-shared pool of token pages and a page table a slot. Two jitted programs use
+six attention kinds a model may have ("full", "window", "latent",
+"state", "delta", "linear") a pool, and for the three that keep a row a
+token a shared pool of token pages and a page table a slot. Two jitted programs use
 it, both
 built on the one transformer block (``llama.block``) with an attention
 of their own, both a layer scan for each run of alike layers
@@ -58,6 +58,18 @@ the convolution's history taken at the last real token, not at the
 bucket's end. The decode step shifts the history by the token's row and
 updates each active slot's state in place.
 
+A Lightning layer (kind "linear", ops/lightning_attention.py) keeps a
+state a slot too, [heads, width, width] float32, decayed by a constant a
+head; its layers stand AMONG "full" ones, so one request holds a slot of
+the "linear" pool and pages of the "full" pool under one admission. A
+model may select BLOCKS of its "full" pool (``cfg.block_select``, kind
+"blocks", ops/block_attention.py): beside each page's k and v it keeps
+the page's mean key, in the "mean" pool that rides on the "full" pool's
+page table, with each slot's open page as a running sum; a decode step
+scores the slot's page means, keeps the best blocks a KV head and copies
+those pages alone; a prefill selects and attends a block of queries at a
+time, and lays the means of the pages it fills beside their rows.
+
 A looped model (``cfg.passes`` > 1, Ouro) runs its stack that many
 times over ONE set of weights, and each (pass, layer) attends over keys
 and values of its own: its pools are ``passes`` times as deep as the
@@ -88,9 +100,17 @@ from ..ops.paged_attention import (
     decode_attention, decode_attention_path, latent_decode_attention,
     ring_pages, walk_step_tokens,
 )
+from ..ops.block_attention import (
+    block_decode_attention, block_prefill_attention, block_select_decode,
+    block_walk_path, page_means, prefill_block_select,
+)
 from ..ops.delta_attention import (
     delta_decode, delta_path, delta_prefill,
     state_shape as delta_state_shape,
+)
+from ..ops.lightning_attention import (
+    lightning_decode, lightning_path, lightning_prefill,
+    state_shape as linear_state_shape,
 )
 from ..ops.retention import (
     retention_decode, retention_path, retention_prefill, state_shape,
@@ -101,14 +121,14 @@ from ..ops.sparse_attention import (
 )
 from .llama import (
     LlamaConfig, block, causal_attention, delta_mix, embed_tokens,
-    exit_distribution, exit_gate_logit, index_offsets, kv_layers,
+    exit_distribution, exit_gate_logit, head_input, index_offsets, kv_layers,
     kv_layers_a_pass, latent_absorb_out, latent_absorb_q, latent_kv,
     layer_runs, layer_stacks, pool_kind, rms_norm, split_expert_stack,
 )
 
 # The kinds whose pools hold a slot's state and no row a token: no pages,
 # no table column, admission by slot alone.
-SLOT_KINDS = ("state", "delta")
+SLOT_KINDS = ("state", "delta", "linear")
 
 
 class MoeLoad(NamedTuple):
@@ -179,6 +199,13 @@ class PagedKVCache(NamedTuple):
     model's dtype (the slots second to last: a layer's slice of a tap is
     whole tiles); no pages either. Its layers lie among latent ones, so
     such a model has these two pools BESIDE the latent pool and its table.
+    A "linear" layer (Lightning attention) keeps a state a slot, ``k["linear"]``
+    [L, B, H, D, D] float32, no pages, beside the "full" pool of the
+    layers it stands among. A model that selects blocks of its "full"
+    pool (``cfg.block_select``) keeps besides ``k["mean"]`` [L, P, Hkv *
+    Dh], a page's mean key a KV head, at the page's own id (it RIDES on
+    the "full" table as "index" rides on "latent"), and ``v["mean"]``
+    [L, B, Hkv * Dh] float32, the running sum of each slot's open page.
 
     A k/v pool is HEAD-MAJOR
     ([L_kind, Hkv, P_kind, page, Dh]): one copy brings a page of every KV
@@ -193,13 +220,14 @@ class PagedKVCache(NamedTuple):
     step before PR 29, PERF.md §6)."""
 
     k: Dict[str, jax.Array]            # kind -> [L_kind, Hkv, P, page, Dh]
-    v: Dict[str, jax.Array]            # ("latent", "index", "state": k alone;
-    #                                    "delta": the convolution's history)
+    v: Dict[str, jax.Array]            # ("latent", "index", "state", "linear":
+    #                                    k alone; "delta": the convolution's
+    #                                    history; "mean": the open pages' sums)
     page_table: Dict[str, jax.Array]   # kind -> [B, columns] int32 page ids
     lengths: jax.Array                 # [B] int32 valid tokens per slot
 
     # A pool whose pages are another pool's, at the same ids.
-    RIDES = {"index": "latent"}
+    RIDES = {"index": "latent", "mean": "full"}
 
     @property
     def page_size(self) -> Optional[int]:
@@ -247,6 +275,13 @@ class PagedKVCache(NamedTuple):
                 return jnp.zeros(delta_state_shape(
                     layers, batch, cfg.delta_heads, cfg.delta_head_dim),
                     dtype=jnp.float32)
+            if kind == "linear":
+                return jnp.zeros(linear_state_shape(
+                    layers, batch, cfg.linear_heads, cfg.linear_head_dim),
+                    dtype=jnp.float32)
+            if kind == "mean":
+                return jnp.zeros((layers, pages, cfg.num_kv_heads * cfg.dh),
+                                 dtype=cfg.dtype)
             if kind == "latent":
                 return jnp.zeros((layers, pages, page_size, cfg.latent_row),
                                  dtype=cfg.dtype)
@@ -268,6 +303,12 @@ class PagedKVCache(NamedTuple):
             second["delta"] = jnp.zeros(
                 (sizes["delta"][0], cfg.delta_conv - 1, batch,
                  cfg.delta_row), dtype=cfg.dtype)
+        if "mean" in sizes:
+            # Beside the page means each slot's open page: the running
+            # sum of its keys, which becomes a mean when the page fills.
+            second["mean"] = jnp.zeros(
+                (sizes["mean"][0], batch, cfg.num_kv_heads * cfg.dh),
+                dtype=jnp.float32)
         return PagedKVCache(
             k=first, v=second,
             page_table={kind: jnp.zeros((batch, columns), dtype=jnp.int32)
@@ -329,11 +370,16 @@ class KVBooks:
                         if run.kind in ("latent_index", "latent_shared"))
         self._reads = [(layers, cfg.window(kind), None)
                        for kind, (layers, pages, _) in self._own.items()
-                       if pages]
+                       if pages and not (kind == "full" and cfg.block_select)]
         if selecting:
             (layers, _, _), = self._reads
             self._reads = [(layers - selecting, None, None),
                            (selecting, None, cfg.index_topk)]
+        # A model that selects blocks: its "full" layers read the pages
+        # of the kept blocks (``account`` counts them from the contexts).
+        self._blocks = cfg.block_select
+        if self._blocks:
+            self._blocks.check(page_size)
         self._state_layers = sum(
             layers for layers, pages, _ in self.pools.values() if not pages)
         # ``free_pages``: of the pool that keeps everything, or the only.
@@ -347,7 +393,12 @@ class KVBooks:
         self.decode_delta = (
             delta_path(cfg.delta_head_dim, cfg.delta_heads)
             if "delta" in self.pools else "none")
-        if cfg.retention:
+        self.decode_linear = (
+            lightning_path(cfg.linear_head_dim, cfg.linear_heads)
+            if "linear" in self.pools else "none")
+        if self._blocks:
+            self.decode_attention = block_walk_path(page_size, cfg.dh)
+        elif cfg.retention:
             self.decode_attention = retention_path(cfg.dh)
         elif cfg.latent:
             self.decode_attention = decode_attention_path(
@@ -380,6 +431,18 @@ class KVBooks:
             "decode_kv_rows_selected", "decode_state_slot_layers",
             "kv_page_steps_held",
             "kv_page_steps_one_table"), 0)
+        # ``stats()["blocks"]`` and ``stats()["linear"]``: what the
+        # steps of a model that selects blocks read of what they held,
+        # and the Lightning states they stepped; None for a model
+        # without such layers.
+        self.block_counts = dict.fromkeys(
+            ("pages_read", "pages_held", "steps_dense", "steps_selected"),
+            0) if self._blocks else None
+        self.linear_counts = ({"slot_layers": 0}
+                              if "linear" in self.pools else None)
+        self._mean_row_bytes = (
+            cache.k["mean"].shape[-1] * cache.k["mean"].dtype.itemsize
+            if "mean" in cache.k else 0)
         self.reset()
 
     def reset(self) -> None:
@@ -459,12 +522,37 @@ class KVBooks:
             counts["decode_kv_rows_selected"] += layers * (
                 read if most is None
                 else sum(min(c, most) for c in contexts))
+        if self._blocks:
+            self._account_blocks(contexts)
+        if self.linear_counts:
+            self.linear_counts["slot_layers"] += (
+                len(contexts) * self.pools["linear"][0])
         counts["decode_state_slot_layers"] += (
             len(contexts) * self._state_layers)
         counts["kv_page_steps_held"] += sum(
             map(self._held.__getitem__, slots))
         counts["kv_page_steps_one_table"] += sum(
             map(self._one_table.__getitem__, slots))
+
+    def _account_blocks(self, contexts: List[int]) -> None:
+        """A decode step of a model that selects blocks: in each "full"
+        layer a token at position ``t`` (its context) reads, a KV head,
+        the pages up to its own of the blocks it keeps (all of them
+        before ``dense_len``, ``topk`` after), of the ``t // page + 1``
+        the slot holds there."""
+        sizes, counts = self._blocks, self.block_counts
+        layers = self.pools["full"][0]
+        for t in contexts:
+            blocks = t // sizes.block + 1
+            dense = t < sizes.dense_len
+            kept = blocks if dense else min(sizes.topk, blocks)
+            pages = ((kept - 1) * sizes.ratio
+                     + t % sizes.block // sizes.stride + 1)
+            counts["pages_read"] += layers * pages
+            counts["pages_held"] += layers * (t // sizes.stride + 1)
+            counts["steps_dense" if dense else "steps_selected"] += 1
+            for key in ("decode_kv_rows_read", "decode_kv_rows_selected"):
+                self.counts[key] += layers * pages * sizes.stride
 
     @property
     def kv_token_bytes(self) -> int:
@@ -490,6 +578,16 @@ class KVBooks:
             "page_size": self.page_size,
             "decode_attention": self.decode_attention,
             "decode_delta": self.decode_delta,
+            "decode_linear": self.decode_linear,
+            **({"blocks": {**self.block_counts,
+                           "mean_row_bytes": self._mean_row_bytes,
+                           "topk": self._blocks.topk,
+                           "dense_len": self._blocks.dense_len}}
+               if self._blocks else {}),
+            **({"linear": {**self.linear_counts,
+                           "slot_bytes": self._slot_bytes["linear"]
+                           * self.pools["linear"][0]}}
+               if self.linear_counts else {}),
             "page_walk_step_tokens": dict(self.page_walk_step_tokens),
             "latent_walk_step_tokens": dict(self.latent_walk_step_tokens),
         }
@@ -577,6 +675,9 @@ def paged_decode(
     x = embed_tokens(params, tokens, cfg)[:, None]
     pools = {kind: cache.pools(kind) for kind in cache.k}
     runs = layer_runs(cfg)
+    # The pools that ride on another's table, carried beside every run's
+    # own: a model has the indexers' keys, the page means, or neither.
+    riding = next((kind for kind in PagedKVCache.RIDES if kind in pools), None)
 
     def one_pass(t, x, pools):
         """The runs walked once, as pass ``t``, and the final norm."""
@@ -598,6 +699,8 @@ def paged_decode(
                     jnp.float32)
 
             def body(carry, lp):
+                # ``keys``: the pools that ride on this model's table,
+                # the indexers' keys or the page means and open sums.
                 x, held, keys, selected = carry
 
                 def attend(q, k, v):
@@ -647,6 +750,32 @@ def paged_decode(
                             lp["index"] + kv_at, active)
                     return out[:, None], ((pool,), keys, selected)
 
+                def attend_blocks(q, k, v, keys=keys):
+                    # The open page's sum and, where the token fills it,
+                    # the page's mean; the selection from the slot's page
+                    # means; then the token's k and v written and the
+                    # attention over the pages of the kept blocks alone.
+                    layer = lp["index"] + kv_at
+                    with jax.named_scope("attn.blocks"):
+                        chosen, *keys = block_select_decode(
+                            q[:, 0], k[:, 0], *keys, layer, table,
+                            cache.lengths, active, sizes=cfg.block_select)
+                        out, *new = block_decode_attention(
+                            q[:, 0], k[:, 0], v[:, 0], *held, layer, table,
+                            cache.lengths, active, chosen,
+                            sizes=cfg.block_select)
+                    return out[:, None], (tuple(new), tuple(keys), selected)
+
+                def attend_linear(q, k, v):
+                    log_g = jnp.broadcast_to(lp["log_decay"], q.shape[:1]
+                                             + lp["log_decay"].shape)
+                    with jax.named_scope("attn.linear"):
+                        out, pool = lightning_decode(
+                            q[:, 0], k[:, 0], v[:, 0], log_g, *held,
+                            lp["index"] + kv_at, active,
+                            scale=cfg.linear_head_dim ** -0.5)
+                    return out[:, None], ((pool,), keys, selected)
+
                 def attend_delta(q, k, packed):
                     # The convolution's history shifted by the token's row,
                     # then the state's step: each its own pool, both at the
@@ -670,24 +799,27 @@ def paged_decode(
                 # The load-balancing loss is a training-only term: dropped.
                 x, kept, _aux, load = block(
                     cfg, lp, x, cache.lengths[:, None],
+                    attend_blocks if kind == "blocks" else
                     {"latent": attend_latent, "state": attend_state,
-                     "delta": attend_delta}.get(pool, attend),
+                     "delta": attend_delta, "linear": attend_linear,
+                     }.get(pool, attend),
                     token_mask=active[:, None], expert_stack=expert_stack,
                     kind=kind)
                 return (x,) + kept, load
 
             (x, pools[pool], keys, selected), load = jax.lax.scan(
-                body, (x, pools[pool], pools.get("index", ()), selected),
+                body, (x, pools[pool], pools.get(riding, ()), selected),
                 layers)
             if keys:
-                pools["index"] = keys
+                pools[riding] = keys
             if load is not None:
                 expert_tokens.append(load)
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         return x, pools, expert_tokens, _exit_gate(cfg, params, x[:, 0])
 
     x, pools, expert_tokens, gates = _passes(cfg, one_pass, x, pools)
-    logits = jnp.einsum("bm,mv->bv", x[:, 0], params["lm_head"])
+    logits = jnp.einsum("bm,mv->bv", head_input(cfg, x[:, 0]),
+                        params["lm_head"])
     lengths = jnp.where(active, cache.lengths + 1, cache.lengths)
     return _with_exit(
         gates, logits.astype(jnp.float32),
@@ -747,7 +879,7 @@ def paged_prefill(
             # [n, Hkv, T, R, Dh]: the slot's states of the run's layers.
             return jax.lax.dynamic_update_slice(
                 pool, rows[:, None], (offset, slot, 0, 0, 0, 0))
-        if run.kind == "delta":
+        if run.kind in ("delta", "linear"):
             # The slot's states [n, H, D, D], whole, or its convolution
             # histories [n, taps - 1, row], the slots second to last.
             rows, at = ((rows[:, None], (offset, slot, 0, 0, 0))
@@ -781,6 +913,16 @@ def paged_prefill(
             return pool.at[at, :, ids[None]].set(
                 paged.transpose(0, 2, 1, 3, 4).astype(pool.dtype))
         return pool.at[at, :, ids].set(paged.astype(pool.dtype))
+
+    def to_means(means, sums, pool, open_sums, run, offset):
+        """A run's page means [n, S // page, W] set at the slot's pages
+        of the "mean" pool (the k/v pool's own ids) and the sums of the
+        page the prompt leaves open [n, W] at the slot."""
+        at = slice(offset, offset + run.n)
+        return (pool.at[at, pages["full"]].set(means.astype(pool.dtype)),
+                jax.lax.dynamic_update_slice(
+                    open_sums, sums[:, None].astype(open_sums.dtype),
+                    (offset, slot, 0)))
 
     runs = layer_runs(cfg)
 
@@ -840,6 +982,38 @@ def paged_prefill(
                             v[0], jnp.where(real[:, None], log_g[0], 0.0))
                     return out[None], ((state,), selected)
 
+                def attend_blocks(q, k, v):
+                    # Kept beside k and v: every page's mean key, and the
+                    # sum of the keys of the page the prompt leaves open.
+                    sizes = cfg.block_select
+                    means = page_means(k[0], page)
+                    opened = ((positions >= real_len // page * page)
+                              & (positions < real_len))
+                    sums = jnp.where(opened[:, None, None],
+                                     k[0].astype(jnp.float32), 0.0).sum(axis=0)
+                    with jax.named_scope("attn.blocks"):
+                        if S <= sizes.dense_len:
+                            out = causal_attention(cfg, None, q, k, v)
+                        else:
+                            chosen = prefill_block_select(q[0], means,
+                                                          sizes=sizes)
+                            out = block_prefill_attention(
+                                q[0], k[0], v[0], chosen, sizes=sizes)[None]
+                    return out, ((k, v, means.reshape(S // page, -1),
+                                  sums.reshape(-1)), selected)
+
+                def attend_linear(q, k, v):
+                    # From nothing before the prompt; the padding neither
+                    # decays nor writes.
+                    real = positions < real_len
+                    with jax.named_scope("attn.linear"):
+                        out, state = lightning_prefill(
+                            q[0], jnp.where(real[:, None, None], k[0], 0),
+                            v[0], jnp.where(real[:, None],
+                                            lp["log_decay"][None], 0.0),
+                            scale=cfg.linear_head_dim ** -0.5)
+                    return out[None], ((state,), selected)
+
                 def attend_delta(q, k, packed):
                     # From nothing before the prompt; the padding neither
                     # decays nor writes, and the history kept is the last
@@ -861,21 +1035,25 @@ def paged_prefill(
 
                 x, (kept, selected), _aux, load = block(
                     cfg, lp, x, positions,
+                    attend_blocks if kind == "blocks" else
                     {"latent": attend_latent, "state": attend_state,
-                     "delta": attend_delta}.get(pool, attend),
+                     "delta": attend_delta, "linear": attend_linear,
+                     }.get(pool, attend),
                     token_mask=token_mask, expert_stack=expert_stack,
                     kind=kind)
                 return (x, selected), (kept, load)
 
             (x, selected), (kept, load) = jax.lax.scan(body, (x, selected),
                                                        layers)
-            kept, keys = kept[:len(pools[pool])], kept[-1]
+            kept, keys, riders = kept[:len(pools[pool])], kept[-1], kept[2:]
             kv_at = t * kv_layers_a_pass(cfg)[pool] + run.kv_offset
             pools[pool] = tuple(to_pages(rows, held, run, kv_at)
                                 for rows, held in zip(kept, pools[pool]))
             if kind == "latent_index":
                 pools["index"] = (to_pages(keys, *pools["index"], run,
                                            index_at),)
+            if kind == "blocks":
+                pools["mean"] = to_means(*riders, *pools["mean"], run, kv_at)
             if load is not None:
                 expert_tokens.append(load)
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
@@ -883,7 +1061,8 @@ def paged_prefill(
             cfg, params, x[:, real_len - 1])
 
     x, pools, expert_tokens, gates = _passes(cfg, one_pass, x, pools)
-    logits = jnp.einsum("bm,mv->bv", x[:, real_len - 1], params["lm_head"])
+    logits = jnp.einsum("bm,mv->bv", head_input(cfg, x[:, real_len - 1]),
+                        params["lm_head"])
     lengths = cache.lengths.at[slot].set(real_len)
     return _with_exit(
         gates, logits.astype(jnp.float32),

@@ -41,6 +41,7 @@ from ..parallel.collective_matmul import (
 from ..parallel.ring_attention import ring_attention
 from ..parallel.moe import dispatch, moe_ffn
 from ..ops.attention import mha_attention
+from ..ops.block_attention import BlockSizes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,6 +160,33 @@ class LlamaConfig:
     # count`` chips hold between them (parallel/moe.py ``held``). None:
     # all of them.
     experts_held: Optional[Tuple[int, int]] = None
+    # What each sub-block adds to the residual stream times this, and
+    # the head's input divided by ``logit_divisor`` (muP: ``scale_depth /
+    # sqrt(layers)`` and ``hidden_size / dim_model_base``).
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
+    # Layers of kind "linear" among "full" ones (``layer_types`` names
+    # each): Lightning attention (ops/lightning_attention.py), a linear
+    # attention of ``linear_heads`` heads of ``linear_head_dim``, a key
+    # and a value head for every query head, decayed by a constant a
+    # head, ``lightning_attention.log_decays`` of the layer's PUBLISHED
+    # index: ``linear_decay_layers`` is (this stack's first layer's
+    # published index, the published stack's depth), so that a cut of
+    # the depth keeps each layer's own decays. Rotated as a window layer
+    # is (``rope_full_layers`` off: on these layers alone), an RMSNorm a
+    # head on the output (``o_norm``). What such a layer keeps is a
+    # state [heads, width, width] float32 a slot, no row a token
+    # (generation.PagedKVCache's "linear" pool, beside the "full" one).
+    linear_heads: int = 0
+    linear_head_dim: int = 0
+    linear_decay_layers: Optional[Tuple[int, int]] = None
+    # A selection of BLOCKS over the paged k/v pool
+    # (ops/block_attention.py, whose ``BlockSizes`` this is): every
+    # "full" layer is then of kind "blocks", scores the mean keys of its
+    # pages (the "mean" pool, which rides on the "full" pool's page
+    # table), keeps ``topk`` blocks a KV head and attends over those;
+    # before ``dense_len`` over everything.
+    block_select: Optional[BlockSizes] = None
     # A looped model (Ouro): the whole stack runs ``passes`` times over
     # the ONE set of layer weights, ``final_norm`` behind every pass and
     # its output the next pass's input; the last pass's goes to the head.
@@ -266,71 +294,118 @@ class LayerRun(NamedTuple):
     # "full" | "window" | "latent" | "state" | "delta", or a latent
     # layer that attends over a selection: "latent_index" makes one,
     # "latent_shared" takes the last one made. Both keep their rows in
-    # the "latent" pool.
+    # the "latent" pool. "linear": a Lightning state a slot. "blocks": a
+    # "full" layer that attends over a selection of blocks; its rows lie
+    # in the "full" pool.
     kind: str
     kv_offset: int
 
 
 def pool_kind(kind: str) -> str:
     """The KV pool a layer of ``kind`` keeps its rows in."""
+    if kind == "blocks":
+        return "full"
     return "latent" if kind.startswith("latent") else kind
+
+
+def _kind_rules(cfg: LlamaConfig) -> Dict[str, Tuple[Any, str, frozenset]]:
+    """For each kind ``layer_types`` may name: (whether the config has
+    what such a layer needs, what that is in words, the kinds it may
+    stand beside in one model). What no rule lets through cannot be
+    run: power retention ("state", degree 2) beside any other kind (its
+    ``wg`` is the retention gate's name, its programs scan one pool of
+    states: ROADMAP R7), a delta-rule layer beside k/v rows ("delta"
+    beside "full": the delta layers' convolution histories and the
+    latent pool are what generation.py lays together), and a selective
+    scan, which is no kind at all (a state whose decay and write are
+    functions of the token needs an ``attend`` and a pool of its own)."""
+    rows = not cfg.latent
+    return {
+        "full": (rows, "keeps k and v rows a head, and this model's "
+                 "attention is latent (kv_lora_rank > 0: each layer is "
+                 "'latent' or 'delta', head_dim the q.k width, "
+                 "qk_nope_head_dim + qk_rope_head_dim)",
+                 frozenset(("full", "window", "linear"))),
+        "window": (rows and bool(cfg.sliding_window),
+                   "needs a sliding_window and k and v rows a head (no "
+                   "kv_lora_rank)", frozenset(("full", "window"))),
+        "latent": (cfg.latent and cfg.dh == cfg.qk_nope_head_dim
+                   + cfg.qk_rope_head_dim,
+                   f"needs latent attention (kv_lora_rank > 0) with "
+                   f"head_dim ({cfg.dh}) the q.k width, qk_nope_head_dim + "
+                   f"qk_rope_head_dim; without it a layer is 'full' or "
+                   f"'window', 'linear' or 'state'",
+                   frozenset(("latent", "delta"))),
+        "delta": (cfg.latent and bool(cfg.delta_heads and cfg.delta_head_dim)
+                  and cfg.delta_conv > 1 and not cfg.index_topk,
+                  "stands among latent layers (kv_lora_rank > 0; without "
+                  "it a layer is 'full' or 'window', 'linear' or 'state': "
+                  "a delta-rule state beside k/v rows is not implemented) "
+                  "and needs delta_heads, delta_head_dim and a delta_conv "
+                  "of two taps or more, and no selection (index_topk)",
+                  frozenset(("latent", "delta"))),
+        "state": (rows and not cfg.attn_gate
+                  and cfg.num_heads % cfg.num_kv_heads == 0,
+                  "is power retention: whole groups of query heads a KV "
+                  "head and no attn_gate (its wg is the retention gate's "
+                  "name)", frozenset(("state",))),
+        "linear": (rows and bool(cfg.linear_heads and cfg.linear_head_dim
+                                 and cfg.linear_decay_layers),
+                   "needs linear_heads, linear_head_dim and "
+                   "linear_decay_layers, and k and v rows a head in the "
+                   "layers beside it (no kv_lora_rank)",
+                   frozenset(("full", "linear"))),
+    }
 
 
 def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
     """The model's layers as an ordered list of uniform runs, from what
     the config states; everything about a layer that a program needs to
-    know when it is traced. Llama, Mistral, OLMoE: one run, "full". A
-    model with latent attention: every layer "latent", or each layer
-    "latent" or "delta" as ``layer_types`` says (a state a slot beside
-    the latent pool); one with power retention: every layer "state"."""
-    if cfg.latent:
-        kinds = cfg.layer_types or ("latent",) * cfg.num_layers
-        if (len(kinds) != cfg.num_layers or set(kinds) - {"latent", "delta"}
-                or cfg.dh != cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-                or (cfg.index_topk and "delta" in kinds)):
+    know when it is traced. ``layer_types`` names each layer's kind
+    (none: every layer "latent" for a model with latent attention, else
+    "full"), and ONE rule reads each (``_kind_rules``): a kind needs
+    what it needs of the config and may stand beside the kinds it names.
+    Llama, Mistral, OLMoE: one run, "full". "window" beside "full";
+    "delta" (a state a slot) among "latent"; "linear" (a state a slot)
+    among "full"; every layer "state". Under a selection a "latent"
+    layer is "latent_index" or "latent_shared" (``index_topk``), a
+    "full" one "blocks" (``block_select``)."""
+    kinds = cfg.layer_types or (
+        ("latent" if cfg.latent else "full",) * cfg.num_layers)
+    rules = _kind_rules(cfg)
+    if len(kinds) != cfg.num_layers or set(kinds) - set(rules):
+        raise ValueError(
+            f"layer_types {kinds} must name one of {sorted(rules)} for each "
+            f"of {cfg.num_layers} layers")
+    for kind in dict.fromkeys(kinds):
+        has, needs, beside = rules[kind]
+        if not has:
+            raise ValueError(f"layer_types {kinds}: a '{kind}' layer {needs}")
+        if set(kinds) - beside:
             raise ValueError(
-                f"latent attention: layer_types {cfg.layer_types} names "
-                f"'latent' or 'delta' for each of {cfg.num_layers} layers "
-                f"(none: all latent; no delta layer under a selection) and "
-                f"head_dim ({cfg.dh}) is the q.k width, qk_nope_head_dim + "
-                f"qk_rope_head_dim")
-        if "delta" in kinds and not (cfg.delta_heads and cfg.delta_head_dim
-                                     and cfg.delta_conv > 1):
+                f"layer_types {kinds}: a '{kind}' layer stands beside "
+                f"{sorted(beside - {kind}) or 'no other kind'} only; "
+                f"{sorted(set(kinds) - beside)} beside it in one model is "
+                f"not implemented (ROADMAP R7: power retention beside "
+                f"another kind, a delta-rule layer beside k/v rows)")
+    if cfg.index_topk:
+        types = cfg.indexer_types or ()
+        if (not cfg.latent or len(types) != cfg.num_layers
+                or types[0] != "full" or set(types) - {"full", "shared"}):
             raise ValueError(
-                "delta layers need delta_heads, delta_head_dim and a "
-                "delta_conv of two taps or more")
-        if cfg.index_topk:
-            types = cfg.indexer_types or ()
-            if (len(types) != cfg.num_layers or types[0] != "full"
-                    or set(types) - {"full", "shared"}):
-                raise ValueError(
-                    f"a selection (index_topk {cfg.index_topk}): "
-                    f"indexer_types {types} must name 'full' or 'shared' "
-                    f"for each of {cfg.num_layers} layers, the first "
-                    f"'full' (a shared layer takes the selection of a "
-                    f"full one before it)")
-            kinds = tuple("latent_index" if t == "full" else "latent_shared"
-                          for t in types)
-    elif cfg.retention:
-        kinds = cfg.layer_types
-        if (set(kinds) != {"state"} or len(kinds) != cfg.num_layers
-                or cfg.attn_gate or cfg.num_heads % cfg.num_kv_heads):
+                f"a selection (index_topk {cfg.index_topk}) is over a "
+                f"latent pool: indexer_types {types} must name 'full' or "
+                f"'shared' for each of {cfg.num_layers} layers, the first "
+                f"'full' (a shared layer takes the selection of a full "
+                f"one before it)")
+        kinds = tuple("latent_index" if t == "full" else "latent_shared"
+                      for t in types)
+    if cfg.block_select:
+        if "full" not in kinds:
             raise ValueError(
-                f"power retention: every one of {cfg.num_layers} layers is "
-                f"of kind 'state' (power retention beside another kind "
-                f"in one model is not implemented, ROADMAP R7; a 'delta' "
-                f"layer's state does lie beside a latent pool), whole "
-                f"groups of query "
-                f"heads a KV head, and no attn_gate (its wg is the "
-                f"retention gate's name); got {kinds}")
-    else:
-        kinds = cfg.layer_types or ("full",) * cfg.num_layers
-        if len(kinds) != cfg.num_layers or set(kinds) - {"full", "window"}:
-            raise ValueError(
-                f"layer_types {kinds} must name 'full' or 'window' for each "
-                f"of {cfg.num_layers} layers")
-    if "window" in kinds and not cfg.sliding_window:
-        raise ValueError("window layers need a sliding_window")
+                f"a selection of blocks (block_select) is over the k/v "
+                f"pool of 'full' layers; layer_types {kinds} has none")
+        kinds = tuple("blocks" if k == "full" else k for k in kinds)
     _check_loop(cfg, kinds)
     if (cfg.router_input not in ("ffn", "attention")
             or cfg.expert_act not in _EXPERT_ACTS):
@@ -341,7 +416,7 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
     alike = [(cfg.n_experts > 0 and i >= cfg.num_dense_layers, kind)
              for i, kind in enumerate(kinds)]
     runs, seen = [], dict.fromkeys(
-        ("full", "window", "latent", "state", "delta"), 0)
+        ("full", "window", "latent", "state", "delta", "linear"), 0)
     for i, (moe, kind) in enumerate(alike):
         if runs and alike[i - 1] == (moe, kind):
             runs[-1] = runs[-1]._replace(n=runs[-1].n + 1)
@@ -369,6 +444,8 @@ def _check_loop(cfg: LlamaConfig, kinds) -> None:
         ("latent", "a latent row a token a PASS, and an absorbed decode "
                    "that walks the pass's layers of the latent pool"),
         ("latent_index", "a selection a pass: indexer keys a token a PASS"),
+        ("linear", "a Lightning state a slot a PASS"),
+        ("blocks", "a selection a pass: page means a PASS"),
     ) if kind in kinds]
     if cfg.n_experts > 0:
         missing.append("the expert-load counters a pass (MoeLoad sums one "
@@ -405,15 +482,20 @@ def kv_layers_a_pass(cfg: LlamaConfig) -> Dict[str, int]:
     Pass t of a looped model keeps its rows ``t`` times this further
     into the pool (``LayerRun.kv_offset`` is a layer's place in a pass)."""
     out: Dict[str, int] = {}
-    for run in layer_runs(cfg):
+    runs = layer_runs(cfg)
+    for run in runs:
         kind = pool_kind(run.kind)
         out[kind] = out.get(kind, 0) + run.n
     indexing = out.get("latent") and sum(
-        run.n for run in layer_runs(cfg) if run.kind == "latent_index")
+        run.n for run in runs if run.kind == "latent_index")
     if indexing:
         # The indexers' keys, one a token an indexing layer: a pool of
         # its own that the latent pool's page table addresses.
         out["index"] = indexing
+    if cfg.block_select:
+        # A mean key a page a KV head, of every layer that selects
+        # blocks: all the "full" pool's, layer for layer, on its table.
+        out["mean"] = out["full"]
     return out
 
 
@@ -435,12 +517,13 @@ def require_uniform(cfg: LlamaConfig, what: str) -> None:
     if len(layer_runs(cfg)) > 1 or set(kv_layers_a_pass(cfg)) - {"full"}:
         raise NotImplementedError(
             f"{what}: training a model whose layer stack is not uniform "
-            f"(dense layers before expert layers, window beside full "
-            f"attention), whose attention is latent (q.k and v of "
-            f"unequal widths) or whose layers are of kind 'state' or "
-            f"'delta' (power retention, the delta rule: the chunked scans "
-            f"have no backward pass) is not implemented; it is served only "
-            f"(models/generation.py)")
+            f"(dense layers before expert layers, window or linear beside "
+            f"full attention), whose attention is latent (q.k and v of "
+            f"unequal widths) or over a selection of blocks, or whose "
+            f"layers are of kind 'state', 'delta' or 'linear' (power "
+            f"retention, the delta rule, Lightning attention: the chunked "
+            f"scans have no backward pass) is not implemented; it is "
+            f"served only (models/generation.py)")
 
 
 # Logical axes for each parameter leaf (maps through DEFAULT_RULES:
@@ -569,13 +652,17 @@ def _init_delta(cfg: LlamaConfig, k, n: int) -> Dict[str, Any]:
 
 
 def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
-                kind: str = "full") -> Dict[str, Any]:
+                kind: str = "full", start: int = 0) -> Dict[str, Any]:
     """``n`` alike layers' weights, stacked ``[n, ...]``, drawing keys
     from the iterator ``k`` in an order that never changes for a leaf
     that is there (new leaves draw last): a seed's weights stay what
     they were."""
     M, H, Hkv, Dh, dt = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
                          cfg.dh, cfg.dtype)
+    if kind == "linear":
+        # A key and a value head for every query head, of its own width.
+        H = Hkv = cfg.linear_heads
+        Dh = cfg.linear_head_dim
 
     def norm_init(shape):
         return jnp.ones(shape, dtype=jnp.float32)
@@ -651,6 +738,17 @@ def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
     if kind == "state":
         layers.update(wg=winit(next(k), (n, M, Hkv), M),
                       bg=_retention_gate_bias(n, Hkv))
+    if kind == "linear":
+        # The norm on each head's output, and each layer's decays a
+        # head, from the layer's published index: no learned value, kept
+        # with the layer so that the layer scan slices them.
+        from ..ops.lightning_attention import log_decays
+
+        first, published = cfg.linear_decay_layers
+        layers.update(
+            o_norm=norm_init((n, Dh)),
+            log_decay=jnp.stack([log_decays(H, first + start + i, published)
+                                 for i in range(n)]))
     if kind == "latent_index":
         # The indexer: queries from the q bottleneck, one key a token
         # and the heads' weights from the layer's input, a LayerNorm
@@ -681,11 +779,11 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     k = keys(key)
     if len(runs) == 1:
         layers = _init_stack(cfg, k, cfg.num_layers, runs[0].moe,
-                             runs[0].kind)
+                             runs[0].kind, 0)
     else:
         layers = tuple(
             _init_stack(cfg, keys(jax.random.fold_in(key, run.start)),
-                        run.n, run.moe, run.kind)
+                        run.n, run.moe, run.kind, run.start)
             for run in runs)
 
     def winit(key, shape):
@@ -773,10 +871,12 @@ def prefill_attention_path(cfg: LlamaConfig, tokens: int) -> Optional[str]:
     bucket, no mesh) for this model's layers that attend over k and v
     rows: ops/flash_attention.py's ``forward_path``, ``"einsum"`` with
     ``use_flash`` off; None where no layer calls it for such a prompt
-    (retention layers; a selection over a longer prompt)."""
+    (retention layers; a selection, of rows or of blocks, over a longer
+    prompt)."""
     from ..ops.flash_attention import forward_path
 
-    if cfg.retention or (cfg.index_topk and tokens > cfg.index_topk):
+    if (cfg.retention or (cfg.index_topk and tokens > cfg.index_topk)
+            or (cfg.block_select and tokens > cfg.block_select.dense_len)):
         return None
     if not cfg.use_flash:
         return "einsum"
@@ -1151,6 +1251,22 @@ def route_tokens(cfg: LlamaConfig, lp, h, *, mesh=None, token_mask=None,
         renormalize=cfg.route_norm, scale=cfg.route_scale)
 
 
+def _residual(cfg: LlamaConfig, out):
+    """What a sub-block adds to the residual stream: its output, times
+    the model's ``residual_scale`` where it has one."""
+    if cfg.residual_scale == 1.0:
+        return out
+    return out * jnp.asarray(cfg.residual_scale, out.dtype)
+
+
+def head_input(cfg: LlamaConfig, h):
+    """The normed hidden state as the head multiplies it: divided by the
+    model's ``logit_divisor`` where it has one."""
+    if cfg.logit_divisor == 1.0:
+        return h
+    return h * jnp.asarray(1.0 / cfg.logit_divisor, h.dtype)
+
+
 def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
         expert_stack=None, routed=None):
     """The block's second half: MLP norm, then the
@@ -1194,7 +1310,7 @@ def ffn(cfg: LlamaConfig, lp, x, *, mesh=None, token_mask=None,
         aux, expert_tokens = jnp.zeros((), dtype=jnp.float32), None
     if cfg.post_norms:
         out = rms_norm(out, lp["post_mlp_norm"], cfg.rms_eps)
-    return x + out, aux, expert_tokens
+    return x + _residual(cfg, out), aux, expert_tokens
 
 
 def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
@@ -1229,7 +1345,12 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
     strength)`` in v's place (``delta_proj``), owns the convolution and
     its history with the rest of its state (``delta_mix``), and returns
     [B,S,``delta_heads``,``delta_head_dim``], which is normed a head and
-    gated here.
+    gated here. For "linear" ``attend`` is given rotated q, k and v of
+    ``linear_heads`` heads each and the layer's decays are its own to
+    read (``lp["log_decay"]``); its output is normed a head and gated
+    here. For "blocks" ``attend`` is given what a "full" layer's is,
+    unrotated where the model rotates its other layers alone, and owns
+    the selection and the page means it is made from.
 
     A model whose router reads the attention's input
     (``router_input="attention"``) is routed here, first: the route, the
@@ -1256,7 +1377,7 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
         q, k, v, gate = delta_proj(cfg, lp, x)
     else:
         q, k, v, gate = qkv_proj(cfg, lp, x, mesh=mesh)
-        if cfg.rope_full_layers or kind != "full":
+        if cfg.rope_full_layers or kind not in ("full", "blocks"):
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
     q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"),
@@ -1265,7 +1386,7 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
         attn, state = attend(q, k, (v, gate))
     else:
         attn, state = attend(q, k, v)
-        if kind == "delta":
+        if kind in ("delta", "linear"):
             attn = rms_norm(attn, lp["o_norm"], cfg.rms_eps)
         if gate is not None:
             attn = attn * gate
@@ -1275,7 +1396,7 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
         attn = jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
     if cfg.post_norms:
         attn = rms_norm(attn, lp["post_attn_norm"], cfg.rms_eps)
-    x = x + attn
+    x = x + _residual(cfg, attn)
     x, aux, expert_tokens = ffn(cfg, lp, x, mesh=mesh, token_mask=token_mask,
                                 expert_stack=expert_stack, routed=routed)
     return x, state, aux, expert_tokens
@@ -1447,7 +1568,7 @@ def hidden_forward(
     if ring:
         # The head reads whole rows: gathered once, not once a loss chunk.
         x = with_logical_constraint(x, ("batch", "seq", "embed"), mesh=mesh)
-    return x, aux
+    return head_input(cfg, x), aux
 
 
 def _chunked_nll_sum(x: jax.Array, lm_head: jax.Array,

@@ -624,10 +624,13 @@ def gather_latent_decode_attention(q, row_new, pool, layer, page_table,
 
 
 def gather_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
-                            page_table, lengths, active, *, window=None):
+                            page_table, lengths, active, *, window=None,
+                            selected=None):
     """The XLA path: the kernel's arguments and results, bar that an
     inactive slot's row of the attention is computed (and discarded by
-    the caller)."""
+    the caller). With ``selected`` [B, Hkv, T] bool a KV head's group
+    attends only to the positions where it is true
+    (ops/block_attention.py)."""
     B, H, D = q.shape
     _, Hkv, n_pool, page, _ = k_pool.shape
     Pmax = page_table.shape[1]
@@ -657,7 +660,10 @@ def gather_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
     attends = position <= lengths[:, None]                    # [B, T]
     if window is not None:
         attends &= (position > lengths[:, None] - window) & (position >= 0)
-    s = jnp.where(attends[:, None, None], s, -jnp.inf)
+    attends = attends[:, None]                                # [B, 1|Hkv, T]
+    if selected is not None:
+        attends &= selected
+    s = jnp.where(attends[:, :, None], s, -jnp.inf)
     prob = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     attn = jnp.einsum("bhgt,hbtd->bhgd", prob, v).reshape(B, H, D)
     return attn, k_pool, v_pool

@@ -974,7 +974,17 @@ class LLMEngine:
         most); ``decode_state_slot_layers`` (the states the steps
         read and wrote: sequences times retention or delta layers, summed
         over decode steps; times ``state_slot_bytes``, the bytes of state a
-        step moved each way);
+        step moved each way); of a model that selects blocks of its k/v
+        pool ``blocks`` (absent otherwise): ``pages_read`` of
+        ``pages_held`` (the pages of the kept blocks a KV head, of the
+        pages a sequence held, each times the selecting layers, summed
+        over decode steps and sequences), ``steps_dense`` and
+        ``steps_selected`` (sequence-steps before and past
+        ``dense_len``), ``mean_row_bytes`` (a page's mean key, every KV
+        head's, in one layer); of a model with Lightning layers
+        ``linear`` (absent otherwise): ``slot_layers`` (states stepped:
+        sequences times such layers, summed over decode steps) and
+        ``slot_bytes`` (a slot's states over all of them);
         ``kv_page_steps_held`` (pages the live sequences held, each
         times its pool's layers, summed over decode steps) and
         ``kv_page_steps_one_table`` (what they would have held with one

@@ -242,6 +242,6 @@ def test_a_retention_stack_is_one_run_of_kind_state_and_inits_its_gate():
     # The gates remember 32 and 4,096 tokens: sigmoid(bg) = 1 - 1/tau.
     assert np.allclose(1 / (1 - jax.nn.sigmoid(layers["bg"][0])),
                        [32.0, 4096.0], rtol=1e-3)
-    with pytest.raises(ValueError, match="every one of 3 layers"):
+    with pytest.raises(ValueError, match="power retention beside another"):
         layer_runs(LlamaConfig(num_layers=3,
                                layer_types=("state", "full", "state")))

@@ -633,7 +633,7 @@ def test_a_paged_engine_has_no_state_gauge_and_counts_no_states(tiny_model):
 _STATS_KEYS = [
     "active_slots", "admitted", "cache_resets", "decode_attention",
     "decode_delta", "decode_dispatch", "decode_kv_rows_read", "decode_kv_rows_selected", "decode_kv_tokens",
-    "decode_slot_steps",
+    "decode_linear", "decode_slot_steps",
     "decode_slot_steps_discarded", "decode_state_slot_layers",
     "decode_steps", "decode_steps_ahead", "device_kind", "failed",
     "finished", "free_pages", "free_slots", "kv_page_steps_held",
@@ -658,7 +658,8 @@ _NESTED_KEYS = {
     "moe": ["assignments", "decode_assignments", "expert_tokens",
             "experts_reached", "layer_calls", "layer_steps",
             "prefill_experts_reached", "small_rows_layer_calls"]}
-_NOT_INT = {"decode_attention": str, "decode_delta": str, "device_kind": str, "platform": str,
+_NOT_INT = {"decode_attention": str, "decode_delta": str,
+            "decode_linear": str, "device_kind": str, "platform": str,
             "t": float, "requests": list, "kv_row_bytes": dict,
             "latent_walk_step_tokens": dict,
             "page_walk_step_tokens": dict, "pages": dict, "phase_s": dict,
