@@ -67,7 +67,8 @@ model may select BLOCKS of its "full" pool (``cfg.block_select``, kind
 the page's mean key, in the "mean" pool that rides on the "full" pool's
 page table, with each slot's open page as a running sum; a decode step
 scores the slot's page means, keeps the best blocks a KV head and copies
-those pages alone; a prefill selects and attends a block of queries at a
+those alone, a block of one head a copy (its pages are one aligned run
+of ids: ``KVBooks``); a prefill selects and attends a block of queries at a
 time, and lays the means of the pages it fills beside their rows.
 
 A looped model (``cfg.passes`` > 1, Ouro) runs its stack that many
@@ -206,6 +207,12 @@ class PagedKVCache(NamedTuple):
     Dh], a page's mean key a KV head, at the page's own id (it RIDES on
     the "full" table as "index" rides on "latent"), and ``v["mean"]``
     [L, B, Hkv * Dh] float32, the running sum of each slot's open page.
+    The "full" pool and table of such a model keep one contract more: *a
+    block's pages are one aligned ascending run* (columns ``ratio b ..
+    ratio b + ratio - 1`` of a slot hold ids ``p .. p + ratio - 1``, ``p
+    % ratio == 0``; ``KVBooks`` allocates so), by which the decode walk
+    copies a block of one head as one region (ops/block_attention.py).
+    The mean pool, at the same ids, gets the runs for free.
 
     A k/v pool is HEAD-MAJOR
     ([L_kind, Hkv, P_kind, page, Dh]): one copy brings a page of every KV
@@ -327,11 +334,24 @@ class KVBooks:
     columns, of a pool of states nothing: admission is then by slot
     alone), and nothing is allocated or freed in between. A pool that
     rides on another's pages (``PagedKVCache.RIDES``) has no account of
-    its own: what is reserved in the pool it rides on is its too. Built
-    from the
-    five arguments ``cache`` was created with, and ``cache`` (or its
-    shapes) for what its pools weigh. One thread writes, the engine's loop; a ``reading``
-    on another holds the engine's lock."""
+    its own: what is reserved in the pool it rides on is its too.
+
+    A pool's unit of allocation is its model's: pages are handed out,
+    counted and given back in RUNS, a run one page but in the "full"
+    pool of a model that selects blocks (``cfg.block_select``), where it
+    is a block's ``ratio`` pages. A run's first id is a multiple of the
+    run and its ids go into the slot's table in ascending order: *a
+    block's pages are one aligned ascending run*, so columns ``ratio b
+    .. ratio b + ratio - 1`` of every slot hold ``p, p + 1, ..`` with
+    ``p % ratio == 0`` and the block walk copies a block of one head as
+    ONE region of the pool (ops/block_attention.py). A request's need is
+    rounded up to whole runs; pages of the pool beyond its last whole
+    run are never handed out (``refusal`` says so).
+
+    Built from the five arguments ``cache`` was created with, and
+    ``cache`` (or its shapes) for what its pools weigh. One thread
+    writes, the engine's loop; a ``reading`` on another holds the
+    engine's lock."""
 
     def __init__(self, cfg: LlamaConfig, batch: int, total_pages: int,
                  page_size: int, max_pages_per_seq: int,
@@ -347,6 +367,15 @@ class KVBooks:
         # the layers that hold a page of each (its riders' too).
         self._own = {kind: sizes for kind, sizes in self.pools.items()
                      if kind not in rides}
+        # A model that selects blocks: its "full" layers read the pages
+        # of the kept blocks (``account`` counts them from the contexts),
+        # and its "full" pool is handed out a block's run at a time.
+        self._blocks = cfg.block_select
+        if self._blocks:
+            self._blocks.check(page_size)
+        self._run = {kind: self._blocks.ratio
+                     if self._blocks and kind == "full" else 1
+                     for kind in self._own}
         self._page_layers = {
             kind: layers + sum(self.pools[r][0] for r, on in rides.items()
                                if on == kind and r in self.pools)
@@ -375,11 +404,6 @@ class KVBooks:
             (layers, _, _), = self._reads
             self._reads = [(layers - selecting, None, None),
                            (selecting, None, cfg.index_topk)]
-        # A model that selects blocks: its "full" layers read the pages
-        # of the kept blocks (``account`` counts them from the contexts).
-        self._blocks = cfg.block_select
-        if self._blocks:
-            self._blocks.check(page_size)
         self._state_layers = sum(
             layers for layers, pages, _ in self.pools.values() if not pages)
         # ``free_pages``: of the pool that keeps everything, or the only.
@@ -436,8 +460,8 @@ class KVBooks:
         # and the Lightning states they stepped; None for a model
         # without such layers.
         self.block_counts = dict.fromkeys(
-            ("pages_read", "pages_held", "steps_dense", "steps_selected"),
-            0) if self._blocks else None
+            ("pages_read", "pages_held", "copies", "steps_dense",
+             "steps_selected"), 0) if self._blocks else None
         self.linear_counts = ({"slot_layers": 0}
                               if "linear" in self.pools else None)
         self._mean_row_bytes = (
@@ -446,9 +470,11 @@ class KVBooks:
         self.reset()
 
     def reset(self) -> None:
-        """Every page free, every table zero, no slot holding."""
+        """Every run free, every table zero, no slot holding."""
+        # A pool's free runs, each by its first page: whole runs only.
         self.free: Dict[str, List[int]] = {
-            kind: list(range(pages))
+            kind: list(range(0, pages - pages % self._run[kind],
+                             self._run[kind]))
             for kind, (_, pages, _) in self._own.items()}
         self.tables: Dict[str, np.ndarray] = {
             kind: np.zeros((self._batch, columns), dtype=np.int32)
@@ -461,22 +487,27 @@ class KVBooks:
         self._one_table: Dict[int, int] = {}
 
     def _need(self, tokens: int, bucket: int) -> Dict[str, int]:
-        """Pages of each pool a context of ``tokens``, prefilled in
-        ``bucket``, holds to its end: the bucket's or the context's,
-        whichever is more, of a ring no more than the ring, and of a
-        pool of states, whose table has no column, none."""
+        """Runs of each pool a context of ``tokens``, prefilled in
+        ``bucket``, holds to its end: the bucket's pages or the
+        context's, whichever is more, of a ring no more than the ring,
+        in whole runs; of a pool of states, whose table has no column,
+        none."""
         span = max(bucket // self.page_size, -(-tokens // self.page_size))
-        return {kind: min(span, columns)
+        return {kind: -(-min(span, columns) // self._run[kind])
                 for kind, (_, _, columns) in self._own.items()}
 
     def refusal(self, tokens: int, bucket: int) -> Optional[str]:
         """Why such a context could never be held, whatever is released;
         None where it could."""
         for kind, need in self._need(tokens, bucket).items():
-            if need > self.pools[kind][1]:
-                return (f"request needs {need} pages but the {kind} pool "
-                        f"has only {self.pools[kind][1]} "
-                        f"(page_size={self.page_size})")
+            run, pages = self._run[kind], self.pools[kind][1]
+            if need > pages // run:
+                return (f"request needs {need * run} pages but the {kind} "
+                        f"pool has only {pages - pages % run} "
+                        f"(page_size={self.page_size})" + (
+                            f" in whole runs of {run}: {pages % run} of "
+                            f"its {pages} are never handed out"
+                            if pages % run else ""))
         return None
 
     def reserve(self, slot: int, tokens: int,
@@ -488,13 +519,17 @@ class KVBooks:
         need = self._need(tokens, bucket)
         if any(n > len(self.free[kind]) for kind, n in need.items()):
             return None
-        pages = {kind: [self.free[kind].pop() for _ in range(n)]
+        pages = {kind: [first + i
+                        for first in (self.free[kind].pop() for _ in range(n))
+                        for i in range(self._run[kind])]
                  for kind, n in need.items()}
         self._pages[slot] = pages
         self._held[slot] = sum(
-            self._page_layers[kind] * n for kind, n in need.items())
-        self._one_table[slot] = self._layers * max(need.values())
+            self._page_layers[kind] * len(ids) for kind, ids in pages.items())
+        self._one_table[slot] = self._layers * max(map(len, pages.values()))
         for kind, ids in pages.items():
+            # (A table whose columns are no whole runs cuts the last.)
+            ids = ids[:self.tables[kind].shape[1]]
             self.tables[kind][slot, :] = 0
             self.tables[kind][slot, :len(ids)] = ids
         return ({kind: ids[: bucket // self.page_size]
@@ -504,7 +539,7 @@ class KVBooks:
         self._held.pop(slot, None)
         self._one_table.pop(slot, None)
         for kind, ids in self._pages.pop(slot, {}).items():
-            self.free[kind].extend(ids)
+            self.free[kind].extend(ids[::self._run[kind]])
             self.tables[kind][slot, :] = 0
 
     def account(self, slots: Iterable[int], contexts: List[int]) -> None:
@@ -539,7 +574,8 @@ class KVBooks:
         layer a token at position ``t`` (its context) reads, a KV head,
         the pages up to its own of the blocks it keeps (all of them
         before ``dense_len``, ``topk`` after), of the ``t // page + 1``
-        the slot holds there."""
+        the slot holds there, and the walk issues a copy a kept block
+        (a KV head, a pool): a block is one run."""
         sizes, counts = self._blocks, self.block_counts
         layers = self.pools["full"][0]
         for t in contexts:
@@ -550,6 +586,7 @@ class KVBooks:
                      + t % sizes.block // sizes.stride + 1)
             counts["pages_read"] += layers * pages
             counts["pages_held"] += layers * (t // sizes.stride + 1)
+            counts["copies"] += layers * kept
             counts["steps_dense" if dense else "steps_selected"] += 1
             for key in ("decode_kv_rows_read", "decode_kv_rows_selected"):
                 self.counts[key] += layers * pages * sizes.stride
@@ -562,15 +599,18 @@ class KVBooks:
         return sum(row * self.pools[kind][0]
                    for kind, row in self._row_bytes.items())
 
+    def _free_pages(self, kind: str) -> int:
+        return len(self.free[kind]) * self._run[kind]
+
     def reading(self) -> Dict[str, Any]:
         """The counts and the gauges, as ``LLMEngine.stats()`` shows
         and documents them."""
         return {
             **self.counts,
-            "free_pages": len(self.free[self._gauge]),
+            "free_pages": self._free_pages(self._gauge),
             "pages": {kind: {"layers": layers, "total": total,
-                             "free": len(self.free[
-                                 PagedKVCache.RIDES.get(kind, kind)])}
+                             "free": self._free_pages(
+                                 PagedKVCache.RIDES.get(kind, kind))}
                       for kind, (layers, total, _) in self.pools.items()},
             "kv_row_bytes": dict(self._row_bytes),
             "state_slot_bytes": dict(self._slot_bytes),

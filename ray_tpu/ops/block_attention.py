@@ -38,9 +38,21 @@ steps in plain XLA.
 attends under that selection: ``block_walk``, a Pallas TPU kernel by the
 page walk's scheme (one program, one list of compute steps, a step sized
 by its bytes) whose unit is a (slot, KV head) and which COPIES THE
-SELECTED PAGES ONLY, a page of one head a copy (the pool is head-major:
-``[page, d]`` is one tile), or ``gather``, plain XLA over every page with
-the selection as a mask.
+SELECTED BLOCKS ONLY, a block of one head a copy, or ``gather``, plain
+XLA over every page with the selection as a mask.
+
+**The pool's contract.** *A block's pages are one aligned ascending
+run*: columns ``ratio b .. ratio b + ratio - 1`` of every slot's table
+hold the ids ``p, p + 1, .., p + ratio - 1`` with ``p % ratio == 0``
+(``generation.KVBooks`` hands the "full" pool of a model that selects
+blocks out a run at a time). The pool is head-major, ``[L, Hkv, P, page,
+d]``, so a block of one head is ONE region of it, ``[ratio, page, d]``
+from its first page on, and the walk reads it with one descriptor where
+a page at a time took ``ratio`` (a layer's call of 16 slots is its
+descriptors' issue on the scalar core: PERF.md section 6, PR 71). The
+walk knows a block by its first page alone; ``gather`` reads the table
+column by column and holds for any table, which is why the tests
+compare the two on tables of runs.
 
 **Prefill.** :func:`prefill_block_select` selects for a block of queries
 at a time (XLA); :func:`block_prefill_attention` is ``block_flash``, a
@@ -350,52 +362,60 @@ def selectable(page: int, head_dim: int, pages: int,
             and sizes.ratio & (sizes.ratio - 1) == 0)
 
 
-def _block_walk_kernel(pid_ref, np_ref, len_ref, layer_ref, unit_ref, at_ref,
-                       total_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
-                       k_out, v_out, k_buf, v_buf, sems, *, cap: int,
-                       scale: float):
+def _block_walk_kernel(pid_ref, nb_ref, last_ref, len_ref, layer_ref,
+                       unit_ref, at_ref, total_ref, q_ref, kn_ref, vn_ref,
+                       k_hbm, v_hbm, o_ref, k_out, v_out, k_buf, v_buf, sems,
+                       *, cap: int, ratio: int, scale: float):
     """One program for every (slot, KV head): a unit. In SMEM: pid_ref
-    [U * cap] each unit's selected pages' ids in the pool, in the order
-    of the sequence (the last is the page of the new token); np_ref [U]
-    how many; len_ref [B]; layer_ref [1]; unit_ref [steps] (the unit of
-    each compute step, every unit's steps in one list), at_ref [U]
-    (where a unit's steps start in it), total_ref [1]. q_ref/o_ref [B,
-    Hkv, G, D] a unit's group of query rows; kn_ref/vn_ref [B, Hkv, 1,
-    D] its new K/V row; k_hbm/v_hbm the pools [L, Hkv, P, page, D] left
-    in HBM and k_out/v_out the same buffers as outputs; k_buf/v_buf [2,
-    step, D] VMEM; sems [3, 2] DMA (k and v in by buffer, then k and v
-    back)."""
+    [U * cap] the id in the pool of the first page of each block a unit
+    keeps, in the order of the sequence (the last is the block of the
+    new token); nb_ref [U] how many blocks, last_ref [U] the pages of
+    the last one up to the token's own; len_ref [B]; layer_ref [1];
+    unit_ref [steps] (the unit of each compute step, every unit's steps
+    in one list), at_ref [U] (where a unit's steps start in it),
+    total_ref [1]. q_ref/o_ref [B, Hkv, G, D] a unit's group of query
+    rows; kn_ref/vn_ref [B, Hkv, 1, D] its new K/V row; k_hbm/v_hbm the
+    pools [L, Hkv, P, page, D] left in HBM and k_out/v_out the same
+    buffers as outputs; k_buf/v_buf [2, step's pages, page, D] VMEM, a
+    block ``ratio`` pages of it; sems [3, 2] DMA (k and v in by buffer,
+    then k and v back)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     _, Hkv, G, D = q_ref.shape
-    _, block, _ = k_buf.shape
-    page = k_hbm.shape[3]
-    step_pages = block // page
+    _, step_pages, page, _ = k_buf.shape
+    step_blocks = step_pages // ratio
     layer = layer_ref[0]
     total = total_ref[0]
 
     # The page walk's reason: rows no copy has written meet a
-    # probability of 0, and 0 times a NaN is a NaN.
+    # probability of 0, and 0 times a NaN is a NaN. A unit's last block
+    # is copied whole, the pages behind the token's own with it: they
+    # are the slot's (a block is one run, reserved whole), hold zeros or
+    # an earlier request's finite rows, and lie behind ``attends``.
     v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
     o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
     def step(g):
         """The list's step g: (its unit, which of the unit's steps it
-        is, the pages it holds, where they start in ``pid_ref``)."""
+        is, the blocks it holds, where they start in ``pid_ref``)."""
         u = unit_ref[g]
         i = g - at_ref[u]
-        held = jnp.minimum(step_pages, np_ref[u] - i * step_pages)
-        return u, i, held, u * cap + i * step_pages
+        held = jnp.minimum(step_blocks, nb_ref[u] - i * step_blocks)
+        return u, i, held, u * cap + i * step_blocks
 
-    def page_copies(head, pid, j, buf):
-        """Page ``pid`` of ``head`` in both pools into page j of ``buf``."""
-        rows = pl.ds(pl.multiple_of(j * page, page), page)
+    def block_copies(head, pid, j, buf):
+        """The block of ``head`` whose first page is ``pid``, in both
+        pools, into block j of ``buf``: its pages are one aligned
+        ascending run of ids (generation.KVBooks), so one region of
+        the head-major pool and ONE descriptor a pool."""
+        run = pl.ds(pl.multiple_of(pid, ratio), ratio)
+        pages = pl.ds(pl.multiple_of(j * ratio, ratio), ratio)
         return (pltpu.make_async_copy(
-                    k_hbm.at[layer, head, pid], k_buf.at[buf, rows, :],
+                    k_hbm.at[layer, head, run], k_buf.at[buf, pages],
                     sems.at[0, buf]),
                 pltpu.make_async_copy(
-                    v_hbm.at[layer, head, pid], v_buf.at[buf, rows, :],
+                    v_hbm.at[layer, head, run], v_buf.at[buf, pages],
                     sems.at[1, buf]))
 
     def start(g, unrolled=True):
@@ -405,35 +425,35 @@ def _block_walk_kernel(pid_ref, np_ref, len_ref, layer_ref, unit_ref, at_ref,
         head = jax.lax.rem(u, Hkv)
         buf = g % 2
 
-        def start_page(j, _):
-            for copy in page_copies(head, pid_ref[first + j], j, buf):
+        def start_block(j, _):
+            for copy in block_copies(head, pid_ref[first + j], j, buf):
                 copy.start()
             return 0
 
         if not unrolled:
-            jax.lax.fori_loop(0, held, start_page, 0)
+            jax.lax.fori_loop(0, held, start_block, 0)
             return
 
-        @pl.when(held == step_pages)
+        @pl.when(held == step_blocks)
         def _whole():
-            # Runs of eight descriptors: a whole step's (128 pages of a
+            # Runs of eight descriptors: a whole step's (32 blocks of a
             # head, k and v) in one straight run would be the program's
             # length for nothing.
-            run = 8 if step_pages % 8 == 0 else 1
+            run = 8 if step_blocks % 8 == 0 else 1
 
             def start_run(r, _):
                 for j in range(run):
-                    start_page(r * run + j, 0)
+                    start_block(r * run + j, 0)
                 return 0
 
-            jax.lax.fori_loop(0, step_pages // run, start_run, 0)
+            jax.lax.fori_loop(0, step_blocks // run, start_run, 0)
 
-        @pl.when(held < step_pages)
+        @pl.when(held < step_blocks)
         def _part():
-            jax.lax.fori_loop(0, held, start_page, 0)
+            jax.lax.fori_loop(0, held, start_block, 0)
 
     def wait(held, buf):
-        @pl.when(held == step_pages)
+        @pl.when(held == step_blocks)
         def _whole():
             # One wait a pool for all the step's copies: a semaphore
             # counts bytes, whichever copies brought them.
@@ -441,13 +461,13 @@ def _block_walk_kernel(pid_ref, np_ref, len_ref, layer_ref, unit_ref, at_ref,
                 pltpu.make_async_copy(ref.at[buf], ref.at[buf],
                                       sems.at[sem, buf]).wait()
 
-        @pl.when(held < step_pages)
+        @pl.when(held < step_blocks)
         def _part():
-            def wait_page(j, _):
-                for copy in page_copies(0, 0, j, buf):
+            def wait_block(j, _):
+                for copy in block_copies(0, 0, j, buf):
                     copy.wait()
                 return 0
-            jax.lax.fori_loop(0, held, wait_page, 0)
+            jax.lax.fori_loop(0, held, wait_block, 0)
 
     start(0, unrolled=False)
 
@@ -460,17 +480,18 @@ def _block_walk_kernel(pid_ref, np_ref, len_ref, layer_ref, unit_ref, at_ref,
         wait(held, buf)
 
         # The unit's last page, in its last step, takes the new row and
-        # goes back to the pool while the step computes.
-        page_new = np_ref[u] - 1 - i * step_pages
-        last = page_new < step_pages
-        page_new = jnp.where(last, page_new, 0)
-        rows_new = pl.ds(pl.multiple_of(page_new * page, page), page)
-        pid_new = pid_ref[first + page_new]
+        # goes back to the pool while the step computes: one page, as
+        # the token's own is one.
+        block_new = nb_ref[u] - 1 - i * step_blocks
+        last = block_new < step_blocks
+        block_new = jnp.where(last, block_new, 0)
+        page_new = block_new * ratio + last_ref[u] - 1
+        pid_new = pid_ref[first + block_new] + last_ref[u] - 1
         write_back = [
-            pltpu.make_async_copy(k_buf.at[buf, rows_new, :],
+            pltpu.make_async_copy(k_buf.at[buf, page_new],
                                   k_out.at[layer, head, pid_new],
                                   sems.at[2, 0]),
-            pltpu.make_async_copy(v_buf.at[buf, rows_new, :],
+            pltpu.make_async_copy(v_buf.at[buf, page_new],
                                   v_out.at[layer, head, pid_new],
                                   sems.at[2, 1]),
         ]
@@ -480,8 +501,8 @@ def _block_walk_kernel(pid_ref, np_ref, len_ref, layer_ref, unit_ref, at_ref,
             is_new = jax.lax.broadcasted_iota(
                 jnp.int32, (page, D), 0) == length % page
             for ref, new in ((k_buf, kn_ref), (v_buf, vn_ref)):
-                rows = ref[buf, rows_new, :]
-                ref[buf, rows_new, :] = jnp.where(is_new, new[b, head], rows)
+                rows = ref[buf, page_new]
+                ref[buf, page_new] = jnp.where(is_new, new[b, head], rows)
             for copy in write_back:
                 copy.start()
 
@@ -489,18 +510,23 @@ def _block_walk_kernel(pid_ref, np_ref, len_ref, layer_ref, unit_ref, at_ref,
         m = jnp.where(i == 0, _NEG_INF, m)
         l = jnp.where(i == 0, 0.0, l)
         acc = jnp.where(i == 0, 0.0, acc)
+        # A page is whole tiles of the buffer: its rows one after another.
+        k = k_buf[buf].reshape(step_pages * page, D)
+        v = v_buf[buf].reshape(step_pages * page, D)
         s = jax.lax.dot_general(
-            q_ref[b, head], k_buf[buf], (((1,), (1,)), ((), ())),
+            q_ref[b, head], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)           # [G, step]
         # Every page but the unit's last is full and before the token.
-        at = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        attends = at < (np_ref[u] - 1) * page + length % page + 1
+        at = i * step_pages * page + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        pages = (nb_ref[u] - 1) * ratio + last_ref[u]
+        attends = at < (pages - 1) * page + length % page + 1
         s = jnp.where(attends, s * scale, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         prob = jnp.exp(s - m_new)
         l = alpha * l + prob.sum(axis=1, keepdims=True)
-        acc = acc * alpha + jnp.dot(prob.astype(v_buf.dtype), v_buf[buf],
+        acc = acc * alpha + jnp.dot(prob.astype(v.dtype), v,
                                     preferred_element_type=jnp.float32)
 
         @pl.when(last)
@@ -520,53 +546,62 @@ def _block_walk_kernel(pid_ref, np_ref, len_ref, layer_ref, unit_ref, at_ref,
 def selected_pages(kept, page_table, lengths, active, sizes: BlockSizes):
     """From the pages each (slot, KV head) keeps, [B, Hkv, Pmax] bool
     (:func:`block_select_decode`'s: whole blocks up to the new token's
-    own page), their ids in the pool in the order of the sequence: (ids
-    [B, Hkv, cap], how many [B, Hkv]); ``cap`` is ``sizes.most_pages()``.
-    None of an inactive slot. A block's first page says whether it is
-    kept, so what is put in order is blocks, a quarter as many."""
+    own page), the kept blocks in the order of the sequence: (the id in
+    the pool of each block's FIRST page [B, Hkv, cap], how many blocks
+    [B, Hkv], the pages of the last one up to the token's own [B, Hkv]);
+    ``cap`` is ``sizes.most_pages()`` in blocks. A block's pages are one
+    aligned ascending run of ids (generation.KVBooks), so its first says
+    where all of it lies. None of an inactive slot. A block's first page
+    says whether it is kept, so what is put in order is blocks, a
+    quarter as many."""
     B, Hkv, pages = kept.shape
     ratio = sizes.ratio
     blocks = kept[..., ::ratio]
     n_blocks = blocks.shape[-1]
-    cap_blocks = min(-(-sizes.most_pages() // ratio), n_blocks)
+    cap = min(-(-sizes.most_pages() // ratio), n_blocks)
     held = jnp.sort(jnp.where(blocks & active[:, None, None],
                               jnp.arange(n_blocks, dtype=jnp.int32),
-                              n_blocks), axis=-1)[..., :cap_blocks]
-    column = (held[..., None] * ratio
-              + jnp.arange(ratio, dtype=jnp.int32)).reshape(B, Hkv, -1)
-    count = ((held[..., None] < n_blocks)
-             & (column.reshape(B, Hkv, -1, ratio)
-                <= (lengths // sizes.stride)[:, None, None, None])
-             ).sum(axis=(-1, -2))
-    pids = jnp.take_along_axis(page_table[:, None, :],
-                               jnp.minimum(column, pages - 1), axis=-1)
-    return pids.astype(jnp.int32), count.astype(jnp.int32)
+                              n_blocks), axis=-1)[..., :cap]
+    count = (held < n_blocks).sum(axis=-1)
+    # The last kept block is the token's own (always kept).
+    last = jnp.where(count > 0,
+                     (lengths // sizes.stride % ratio + 1)[:, None], 0)
+    first = jnp.take_along_axis(
+        page_table[:, None, :], jnp.minimum(held * ratio, pages - 1), axis=-1)
+    return (first.astype(jnp.int32), count.astype(jnp.int32),
+            last.astype(jnp.int32))
 
 
 def paged_block_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
                                  page_table, lengths, active, selected, *,
                                  sizes: BlockSizes, interpret: bool = False):
     """The block walk. Arguments and results as
-    :func:`block_decode_attention`."""
+    :func:`block_decode_attention`; ``page_table`` holds each block's
+    pages as one aligned ascending run of ids."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
     _, Hkv, _, page, _ = k_pool.shape
     dtype = k_pool.dtype
-    pids, n_pages = selected_pages(selected, page_table, lengths, active,
-                                   sizes)
-    cap = pids.shape[-1]
-    block = walk_step_tokens(2 * D * jnp.dtype(dtype).itemsize, page, cap)
-    scalars = [lengths.astype(jnp.int32),
+    ratio = sizes.ratio
+    first, n_blocks, last = selected_pages(selected, page_table, lengths,
+                                           active, sizes)
+    cap = first.shape[-1]
+    # A step is sized by its bytes and holds whole blocks.
+    step_blocks = max(walk_step_tokens(
+        2 * D * jnp.dtype(dtype).itemsize, page, cap * ratio)
+        // sizes.block, 1)
+    scalars = [last.reshape(-1), lengths.astype(jnp.int32),
                jnp.reshape(layer, (1,)).astype(jnp.int32),
-               *_step_list(n_pages.reshape(-1), block // page, cap)]
+               *_step_list(n_blocks.reshape(-1), step_blocks, cap)]
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    kv_buf = pltpu.VMEM((2, block, D), dtype)
+    kv_buf = pltpu.VMEM((2, step_blocks * ratio, page, D), dtype)
     n_scalars = 2 + len(scalars)
     out, k_pool, v_pool = pl.pallas_call(
-        functools.partial(_block_walk_kernel, cap=cap, scale=D ** -0.5),
+        functools.partial(_block_walk_kernel, cap=cap, ratio=ratio,
+                          scale=D ** -0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_scalars,
             grid=(1,),
@@ -579,13 +614,13 @@ def paged_block_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
         out_shape=[jax.ShapeDtypeStruct((B, Hkv, H // Hkv, D), q.dtype),
                    jax.ShapeDtypeStruct(k_pool.shape, dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, dtype)],
-        # Operands count the seven prefetched scalars: the pools are 10
-        # and 11.
+        # Operands count the eight prefetched scalars: the pools are 11
+        # and 12.
         input_output_aliases={n_scalars + 3: 1, n_scalars + 4: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(pids.reshape(-1), n_pages.reshape(-1), *scalars,
+    )(first.reshape(-1), n_blocks.reshape(-1), *scalars,
       q.astype(dtype).reshape(B, Hkv, H // Hkv, D),
       k_new.astype(dtype)[:, :, None], v_new.astype(dtype)[:, :, None],
       k_pool, v_pool)

@@ -978,7 +978,13 @@ class LLMEngine:
         pool ``blocks`` (absent otherwise): ``pages_read`` of
         ``pages_held`` (the pages of the kept blocks a KV head, of the
         pages a sequence held, each times the selecting layers, summed
-        over decode steps and sequences), ``steps_dense`` and
+        over decode steps and sequences), ``copies`` (the descriptors
+        the steps' walks issued for those reads, counted as the pages
+        are, a KV head and a pool: a kept block is one, its pages one
+        aligned run of the pool, so ``copies / pages_read`` is a little
+        over one in ``ratio`` pages a block: the last block's copy
+        counts one for the 1 to ``ratio`` pages up to the token's own),
+        ``steps_dense`` and
         ``steps_selected`` (sequence-steps before and past
         ``dense_len``), ``mean_row_bytes`` (a page's mean key, every KV
         head's, in one layer); of a model with Lightning layers

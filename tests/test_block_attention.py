@@ -2,7 +2,8 @@
 (ties, fewer blocks than ``topk``, every forced block), a page's mean
 written exactly when its page fills, the XLA attention paths against a
 direct softmax, and both Pallas kernels interpreted against the XLA
-paths."""
+paths, the walk over tables whose blocks are aligned ascending runs of
+page ids, as ``generation.KVBooks`` hands them out."""
 
 import numpy as np
 import pytest
@@ -119,18 +120,27 @@ def test_a_page_mean_is_written_exactly_when_its_page_fills():
                                atol=1e-5)
 
 
-def _decode_case(seed, dtype=jnp.float32, d=16, H=4, Hkv=2):
+def _run_table(rng, slots, columns, pages, ratio=4):
+    """A table as ``KVBooks`` fills one for a pool that selects blocks:
+    a permutation of the pool's RUNS, each block's pages one aligned
+    ascending run of ids."""
+    runs = rng.permutation(pages // ratio)[:slots * columns // ratio]
+    return jnp.asarray((runs[:, None] * ratio + np.arange(ratio)).reshape(
+        slots, columns), jnp.int32)
+
+
+def _decode_case(seed, dtype=jnp.float32, d=16, H=4, Hkv=2,
+                 lengths=(300, 37, 511), pmax=32, keep=0.4):
     rng = np.random.default_rng(seed)
-    B, pmax, pages = 3, 32, 128
+    B, pages = 3, 3 * pmax + 32
     k_pool, v_pool = _pools(rng, 2, Hkv, pages, d, dtype)
-    table = jnp.asarray(rng.permutation(pages)[:B * pmax].reshape(B, pmax),
-                        jnp.int32)
-    lengths = jnp.asarray([300, 37, 511], jnp.int32)
+    table = _run_table(rng, B, pmax, pages)
+    lengths = jnp.asarray(lengths, jnp.int32)
     active = jnp.asarray([True, True, True])
     q = jnp.asarray(rng.normal(size=(B, H, d)), dtype)
     k_new = jnp.asarray(rng.normal(size=(B, Hkv, d)), dtype)
     v_new = jnp.asarray(rng.normal(size=(B, Hkv, d)), dtype)
-    chosen = jnp.asarray(rng.random((B, Hkv, pmax * PAGE // 64)) < 0.4)
+    chosen = jnp.asarray(rng.random((B, Hkv, pmax * PAGE // 64)) < keep)
     own = (lengths // 64)[:, None, None]
     blocks = jnp.arange(chosen.shape[-1])[None, None]
     chosen = (chosen | (blocks == own) | (blocks == 0)) & (blocks <= own)
@@ -160,16 +170,40 @@ def test_gather_attends_to_the_kept_blocks_alone():
                 out[b, h], (p / p.sum()) @ rows_v[g][keep], atol=1e-5)
 
 
-@pytest.mark.parametrize("idle", [None, 1])
-def test_block_walk_is_the_gather(idle):
-    """The walk interpreted, heads of 128: the selected pages copied a
-    head a page, the new row written, an idle slot left alone; a unit of
-    one page and one of several steps."""
+@pytest.mark.parametrize("case", [
+    dict(), dict(idle=1),
+    # The unit's last block holds 1, 2 and 3 pages; 4, 1 (a unit of one
+    # page) and 2.
+    dict(lengths=(261, 280, 300)), dict(lengths=(319, 5, 90)),
+    # Rows an earlier request left behind the token's own, in its page
+    # and in the pages of its block behind it: finite, and masked.
+    dict(lengths=(261, 280, 300), stale=1e4),
+    # A table of 128 pages, a step of 64: units of two steps, one whose
+    # second step holds its last block alone, and one of one step.
+    dict(lengths=(2047, 1040, 70), pmax=128, keep=0.95, topk=32,
+         dense_len=2048),
+], ids=["all", "idle", "last-1-2-3", "last-4-1-2", "stale", "steps"])
+def test_block_walk_is_the_gather(case):
+    """The walk interpreted, heads of 128, over tables of runs: the kept
+    blocks copied a head a block, the unit's last block whole, the new
+    row written as one page, an idle slot left alone."""
+    idle, stale = case.get("idle"), case.get("stale")
     q, k_new, v_new, k_pool, v_pool, table, lengths, active, chosen = \
-        _decode_case(1, d=128)
+        _decode_case(1, d=128, **{k: case[k] for k in
+                                  ("lengths", "pmax", "keep") if k in case})
     if idle is not None:
         active = active.at[idle].set(False)
-    sizes = SIZES._replace(topk=24, dense_len=512)
+    if stale:
+        def soiled(pool):
+            for b, t in enumerate(np.asarray(lengths)):
+                behind = table[b, t // PAGE + 1:(t // 64 + 1) * 4]
+                assert len(behind) == 3 - t // PAGE % 4
+                pool = pool.at[:, :, behind].set(stale)
+                pool = pool.at[:, :, table[b, t // PAGE], t % PAGE:].set(stale)
+            return pool
+        k_pool, v_pool = soiled(k_pool), soiled(v_pool)
+    sizes = SIZES._replace(topk=case.get("topk", 24),
+                           dense_len=case.get("dense_len", 512))
     chosen = ba.pages_of(chosen, table.shape[1], lengths, sizes)
     want, want_k, want_v = ba.gather_block_decode_attention(
         q, k_new, v_new, k_pool, v_pool, 1, table, lengths, active, chosen,
@@ -202,20 +236,28 @@ def test_select_kernel_is_the_xla_path(lengths):
 
 
 def test_selected_pages_are_in_order_and_end_at_the_tokens_own():
+    """A kept block is its FIRST page's id, in the order of the
+    sequence; the last is the token's own block, of which the pages up
+    to the token's own count."""
     *_, table, lengths, active, chosen = _decode_case(2)
     sizes = SIZES._replace(topk=8)       # as many as ``chosen`` may keep
     kept = ba.pages_of(chosen, table.shape[1], lengths, sizes)
-    pids, n = ba.selected_pages(kept, table, lengths, active, sizes)
+    first, n, last = ba.selected_pages(kept, table, lengths, active, sizes)
+    assert first.shape == (3, 2, 8)
     for b in range(3):
         for g in range(2):
-            cols = [4 * blk + i for blk in np.flatnonzero(chosen[b, g])
-                    for i in range(4) if 4 * blk + i <= int(lengths[b]) // 16]
-            assert int(n[b, g]) == len(cols)
-            np.testing.assert_array_equal(pids[b, g, :len(cols)],
-                                          np.asarray(table[b])[cols])
-    _, none = ba.selected_pages(kept, table, lengths, jnp.zeros(3, bool),
-                                sizes)
-    assert not np.asarray(none).any()
+            blocks = np.flatnonzero(chosen[b, g])
+            assert int(n[b, g]) == len(blocks)
+            assert blocks[-1] == int(lengths[b]) // 64
+            np.testing.assert_array_equal(first[b, g, :len(blocks)],
+                                          np.asarray(table[b])[4 * blocks])
+            assert int(last[b, g]) == int(lengths[b]) // 16 % 4 + 1
+            # What ``pages_of`` keeps, counted through the blocks.
+            assert int(kept[b, g].sum()) == 4 * (len(blocks) - 1) + int(
+                last[b, g])
+    _, none, no_last = ba.selected_pages(kept, table, lengths,
+                                         jnp.zeros(3, bool), sizes)
+    assert not np.asarray(none).any() and not np.asarray(no_last).any()
 
 
 def _prefill_case(seed, S, H, Hkv, d, dtype=jnp.float32):

@@ -75,7 +75,7 @@ def test_a_selecting_hybrid_engine_admits_by_slot_and_pages_together(
         assert (stats["decode_attention"], stats["decode_linear"],
                 stats["decode_delta"]) == ("gather", "xla", "none")
         assert stats["blocks"] == {
-            "pages_read": 0, "pages_held": 0, "steps_dense": 0,
+            "pages_read": 0, "pages_held": 0, "copies": 0, "steps_dense": 0,
             "steps_selected": 0, "mean_row_bytes": 2 * 16 * 4, "topk": 4,
             "dense_len": 320}
         assert stats["linear"] == {"slot_layers": 0,
@@ -102,6 +102,16 @@ def test_a_selecting_hybrid_engine_admits_by_slot_and_pages_together(
                     for i in range(1, m))
         assert blocks["pages_read"] == read + short
         assert stats["decode_kv_rows_read"] == 16 * (read + short)
+        # The walk's copies: a kept block is one (a KV head, a pool, a
+        # layer), 4 of them past dense_len, every block before.
+        copies = 3 * 4 * 39 + sum(
+            3 * ((n + i) // 64 + 1) for n, m in ((9, 30), (60, 20))
+            for i in range(1, m))
+        assert blocks["copies"] == copies
+        # A quarter of the pages, and the last block's copy for 1-4 pages.
+        units = 3 * steps
+        assert (blocks["pages_read"] / 4 <= copies
+                <= blocks["pages_read"] / 4 + units)
     finally:
         engine.shutdown()
 

@@ -69,29 +69,63 @@ def test_lightning_scan_kernel_compiles_for_v5e(v5e):
                         call) for call in _calls(compiled)), _calls(compiled)
 
 
+def _dma_starts(jaxpr, loops=()):
+    """Every ``dma_start`` of a traced function, each with the loops it
+    stands in: ((primitive, static length or None) .., the equation)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dma_start":
+            yield loops, str(eqn)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _dma_starts(inner, loops + (
+                        (eqn.primitive.name, eqn.params.get("length")),))
+
+
 def test_block_walk_kernel_compiles_for_v5e(v5e):
     """The walk under a selection at the cell's geometry: 32 (slot, KV
-    head) units of up to 512 pages, a page of one head a copy; the
-    pools come back through aliased outputs; it writes four dimensions
-    and five, five."""
+    head) units of up to 128 blocks, a block of one head a copy: a whole
+    step of 128 pages issues 32 descriptors a pool, each a run of 4
+    pages, where a page a descriptor was 128; the pools come back
+    through aliased outputs; it writes four dimensions and five, five."""
     from ray_tpu.ops import block_attention as ba
 
     sizes = ba.BlockSizes(32, 16, 64, 1, 2048, 64, 8192)
     walk = lambda q, k, v, kp, vp, layer, table, lengths, active, chosen: (  # noqa: E731
         ba.paged_block_decode_attention(q, k, v, kp, vp, layer, table,
                                         lengths, active, chosen, sizes=sizes))
-    compiled = jax.jit(walk, donate_argnums=(3, 4)).lower(
+    operands = (
         arr(v5e, (16, 32, 128)), arr(v5e, (16, 2, 128)),
         arr(v5e, (16, 2, 128)), arr(v5e, KV_POOL), arr(v5e, KV_POOL),
         arr(v5e, (), jnp.int32), arr(v5e, (16, 2176), jnp.int32),
         arr(v5e, (16,), jnp.int32), arr(v5e, (16,), jnp.bool_),
-        arr(v5e, (16, 2, 2176), jnp.bool_)).compile()
+        arr(v5e, (16, 2, 2176), jnp.bool_))
+    compiled = jax.jit(walk, donate_argnums=(3, 4)).lower(*operands).compile()
     assert any(re.match(
         r"\(bf16\[16,2,16,128\]\S*, bf16\[3,2,34816,16,128\]", call)
         for call in _calls(compiled)), _calls(compiled)
     assert_pool_stays_in_place(compiled, KV_POOL, temporaries=False)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 4 * math.prod(KV_POOL)
+    # The copies, from the traced text: every read of a pool is a run of
+    # ``ratio`` 4 pages of one head into 4 pages of a step's buffer, and
+    # the whole step's straight runs (the one loop of a static length)
+    # issue a step's pages over the ratio, k and v.
+    from ray_tpu.ops.paged_attention import walk_step_tokens
+
+    step_pages = walk_step_tokens(2 * 128 * 2, PAGE, 512) // PAGE
+    assert step_pages == 128
+    starts = list(_dma_starts(jax.make_jaxpr(walk)(*operands).jaxpr))
+    a_run = re.compile(r" \w+\[\w+,\w+,(\w+):\1\+4,:,:\] -> "
+                       r"\w+\[\w+,(\w+):\2\+4,:,:\] ")
+    a_page = re.compile(r" \w+\[\w+,\w+,:,:\] -> \w+\[\w+,\w+,\w+,:,:\] ")
+    reads = [loops for loops, text in starts if a_run.search(text)]
+    whole = [loops[-1][1] for loops in reads if loops[-1][1] is not None]
+    assert sum(whole) == 2 * step_pages // sizes.ratio == 64
+    # What is no run is the new row's page going back alone, k and v.
+    assert [bool(a_page.search(text)) for _, text in starts
+            if not a_run.search(text)] == [True, True]
 
 
 def test_block_select_kernel_compiles_for_v5e(v5e):
