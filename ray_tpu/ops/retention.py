@@ -44,9 +44,13 @@ platform and shape, never by a user: Pallas TPU kernels for heads of
 128 (``state_step``: grid (slot, KV head), the state block brought in
 and written back by the pipeline through an output aliased to the pool,
 an idle slot's steps pointed at a neighbour's block so that nothing is
-moved for them; ``chunk_scan``: grid (KV head, chunk), the state
-resident in the output block across a head's chunks), and plain XLA for
-any platform and shape (tier-1 runs it on the CPU).
+moved for them; it is handed q and k as they are and makes ``phi`` of
+both itself, a lane rotation a turn, and it takes a few row tiles at a
+time through all the turns so that the read-outs' sums stay in vector
+registers and a register of state is loaded once and stored once;
+``chunk_scan``: grid (KV head, chunk), the state resident in the output
+block across a head's chunks), and plain XLA for any platform and shape
+(tier-1 runs it on the CPU).
 """
 
 from __future__ import annotations
@@ -62,6 +66,11 @@ EPS = 1e-6
 CHUNK = 256
 # float32 sublanes: the rows of a turn are padded to a multiple.
 _ROW_PAD = 8
+# The decode kernel's inner loop: turns it writes out an iteration (as
+# many of these as divide the turns: 65 at a head of 128), and the vector
+# registers, of 64, its carried values may take.
+_TURN_UNROLL = 5
+_PASS_REGISTERS = 48
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -131,36 +140,84 @@ def xla_retention_decode(q, k, v, log_g, pool, layer, active):
     return y.reshape(B, H, d).astype(q.dtype), pool.at[layer].set(new)
 
 
-def _state_step_kernel(slot_ref, head_ref, act_ref, layer_ref, phiq_ref,
-                       phik_ref, gv_ref, s_in, y_ref, s_out):
+def _state_step_kernel(slot_ref, head_ref, act_ref, layer_ref, qk_ref,
+                       gv_ref, s_in, y_ref, s_out, phi_ref, acc_ref):
     """Grid (B, Hkv). slot_ref, head_ref [B]: the state block an idle
     slot's steps are pointed at (``_idle_blocks``); act_ref [B]; layer_ref
-    [1]. phiq_ref [T, G, d], phik_ref [T, 1, d], gv_ref [R, 2] (column 0
-    the extended v, column 1 the gate); s_in/s_out [T, R, d], the same
-    block of the pool; y_ref [G, d] float32: each query head's read-out."""
+    [1]. qk_ref [Q, d] float32: rows ``0 .. G-1`` the group's queries, row
+    ``G`` the key, zeros up to whole tiles; gv_ref [R, 2] (column 0 the
+    extended v, column 1 the gate); s_in/s_out [T, R, d], the same block
+    of the pool; y_ref [G, d] float32: each query head's read-out.
+    Scratch: phi_ref [T, Q, d], ``phi`` of every row of qk_ref, made
+    here; acc_ref [G, R, d], the read-outs' sums over the turns, still to
+    be summed over lanes.
+
+    The turns are the INNER loop: a pass takes a few row tiles (8 rows,
+    one vector register a turn) through all T turns, so that its sums
+    (G a tile) stay in registers from the first turn to the last, and a
+    state register is loaded once, stored once and multiplied in place
+    (5, 5, 5 and 2 of the 17 tiles at G = 5)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
-    T, G, d = phiq_ref.shape
-    R = s_in.shape[1]
+    G = y_ref.shape[0]
+    T, R, d = s_in.shape
+    c_edge, c_mid = d ** -0.5, math.sqrt(2.0) * d ** -0.5
 
     @pl.when(act_ref[b] == 1)
     def _step():
-        v_col = gv_ref[:, 0:1]                            # [R, 1]
-        g_col = gv_ref[:, 1:2]
+        x = qk_ref[...]
 
-        def body(t, acc):
-            new = g_col * s_in[t] + v_col * phik_ref[t]   # [R, d]
-            s_out[t] = new
-            pq = phiq_ref[t]                              # [G, d]
-            return tuple(a + new * pq[h:h + 1] for h, a in enumerate(acc))
+        def turn(t, _):
+            coef = jnp.where((t == 0) | (t == T - 1), c_edge, c_mid)
+            phi_ref[t] = x * pltpu.roll(x, t, 1) * coef
+            return 0
 
-        acc = jax.lax.fori_loop(
-            0, T, body, tuple(jnp.zeros((R, d), jnp.float32)
-                              for _ in range(G)))
-        for h, a in enumerate(acc):
+        jax.lax.fori_loop(0, T, turn, 0)
+        gate = jnp.broadcast_to(gv_ref[0:_ROW_PAD, 1:2], (_ROW_PAD, d))
+        # (Mosaic lowers a loop whole or not unrolled at all.)
+        unroll = math.gcd(T, _TURN_UNROLL)
+
+        def row(t, h):
+            return jnp.broadcast_to(phi_ref[t, h:h + 1, :], (_ROW_PAD, d))
+
+        # A pass holds G sums, v and the new state of each of its tiles
+        # across the turns, and a turn's G + 1 rows of phi and the gate:
+        # within the 64 vector registers with room for the compiler's.
+        most = max(1, (_PASS_REGISTERS - G - 2) // (G + 2))
+        for first in range(0, R, most * _ROW_PAD):
+            tiles = [pl.ds(at, _ROW_PAD) for at in range(
+                first, min(first + most * _ROW_PAD, R), _ROW_PAD)]
+            v = [jnp.broadcast_to(gv_ref[rows, 0:1], (_ROW_PAD, d))
+                 for rows in tiles]
+
+            def body(t, acc):
+                pk = row(t, G)
+                new = []
+                for rows, v_tile in zip(tiles, v):
+                    new.append(gate * s_in[t, rows, :] + v_tile * pk)
+                    s_out[t, rows, :] = new[-1]
+                return tuple(
+                    tuple(a + tile * pq for a, tile in zip(sums, new))
+                    for sums, pq in zip(acc, (row(t, h) for h in range(G))))
+
+            def several(i, acc):
+                for j in range(unroll):
+                    acc = body(i * unroll + j, acc)
+                return acc
+
+            acc = jax.lax.fori_loop(
+                0, T // unroll, several,
+                tuple(tuple(jnp.zeros((_ROW_PAD, d), jnp.float32)
+                            for _ in tiles) for _ in range(G)))
+            for h, sums in enumerate(acc):
+                for rows, a in zip(tiles, sums):
+                    acc_ref[h, rows, :] = a
+        for h in range(G):
             # Sums over lanes come out as columns; turned, the d
             # numerators are one row of lanes.
+            a = acc_ref[h]
             num = jnp.sum(a[0:d].T, axis=0, keepdims=True)    # [1, d]
             den = jnp.sum(a[d:d + 1], axis=1, keepdims=True)  # [1, 1]
             y_ref[h:h + 1, :] = num / (den + EPS)
@@ -201,14 +258,18 @@ def state_step(q, k, v, log_g, pool, layer, active, *, interpret=False):
     Hkv = k.shape[1]
     G, T, R = H // Hkv, turns(d), state_rows(d)
     slot, last = _idle_blocks(active)
-    phiq = phi(q).reshape(B, Hkv, G, T, d).transpose(0, 1, 3, 2, 4)
-    phik = phi(k)[:, :, :, None, :]                       # [B,Hkv,T,1,d]
+    # A KV head's queries and its key as rows of whole float32 tiles.
+    Q = -(-(G + 1) // _ROW_PAD) * _ROW_PAD
+    qk = jnp.concatenate(
+        [q.reshape(B, Hkv, G, d), k[:, :, None, :],
+         jnp.zeros((B, Hkv, Q - G - 1, d), q.dtype)],
+        axis=2).astype(jnp.float32)
     gate = jnp.broadcast_to(
         jnp.exp(log_g.astype(jnp.float32))[..., None], (B, Hkv, R))
     gv = jnp.stack([_extended(v), gate], axis=-1)         # [B,Hkv,R,2]
 
     def own(b, n, *_):
-        return (b, n, 0, 0, 0)
+        return (b, n, 0, 0)
 
     def state_block(b, n, slot_ref, head_ref, act_ref, layer_ref):
         idle_head = head_ref[b] * (Hkv - 1)
@@ -221,25 +282,23 @@ def state_step(q, k, v, log_g, pool, layer, active, *, interpret=False):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(B, Hkv),
-            in_specs=[pl.BlockSpec((None, None, T, G, d), own),
-                      pl.BlockSpec((None, None, T, 1, d), own),
-                      pl.BlockSpec((None, None, R, 2),
-                                   lambda b, n, *_: (b, n, 0, 0)),
+            in_specs=[pl.BlockSpec((None, None, Q, d), own),
+                      pl.BlockSpec((None, None, R, 2), own),
                       state_spec],
-            out_specs=[pl.BlockSpec((None, None, G, d),
-                                    lambda b, n, *_: (b, n, 0, 0)),
-                       state_spec],
+            out_specs=[pl.BlockSpec((None, None, G, d), own), state_spec],
+            scratch_shapes=[pltpu.VMEM((T, Q, d), jnp.float32),
+                            pltpu.VMEM((G, R, d), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, Hkv, G, d), jnp.float32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        # Operands count the four prefetched scalars: the pool is 7.
-        input_output_aliases={7: 1},
+        # Operands count the four prefetched scalars: the pool is 6.
+        input_output_aliases={6: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=64 * 2 ** 20),
         interpret=interpret,
     )(slot, last, active.astype(jnp.int32),
-      jnp.reshape(layer, (1,)).astype(jnp.int32), phiq, phik, gv, pool)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), qk, gv, pool)
     return y.reshape(B, H, d).astype(q.dtype), pool
 
 

@@ -167,14 +167,17 @@ def test_chunk_scan_kernel_matches_the_xla_path():
     (True, True, True), (False, True, False), (True, False, True),
     (False, False, True), (True, False, False), (False, False, False)],
     ids=lambda a: "".join("x" if s else "-" for s in a))
-def test_state_step_kernel_matches_the_xla_path_and_skips_idle_slots(active):
+@pytest.mark.parametrize("group", [2, 5], ids=lambda g: f"g{g}")
+def test_state_step_kernel_matches_the_xla_path_and_skips_idle_slots(
+        active, group):
     """The Pallas decode kernel, interpreted: every pattern of idle
-    slots before, between and after active ones, and nobody active.
-    An idle slot's state is what it was, bit for bit, and so is every
-    other layer."""
+    slots before, between and after active ones, and nobody active, at
+    two and at the cell's five query heads a KV head (which divide
+    nothing a pass over the turns is cut by). An idle slot's state is
+    what it was, bit for bit, and so is every other layer."""
     from ray_tpu.ops import retention
 
-    q, k, v, log_g = _kernel_inputs(3, 4, 2, seed=1)
+    q, k, v, log_g = _kernel_inputs(3, 2 * group, 2, seed=1)
     pool = jax.random.normal(jax.random.PRNGKey(7),
                              retention.state_shape(2, 3, 2, 128)) + 3.0
     on = jnp.asarray(active)

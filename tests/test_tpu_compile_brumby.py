@@ -3,6 +3,7 @@ its two kernels alone, compiled for a described v5e
 (tests/tpu_rehearsal.py)."""
 
 import math
+import re
 
 import pytest
 
@@ -26,7 +27,9 @@ def test_state_step_kernel_compiles_for_v5e(v5e):
     """The decode retention kernel at the published shapes: 40 query
     heads on 8 KV heads of 128, 16 slots; a (slot, KV head)'s state
     block of 4.5 MB goes through VMEM and comes back through the output
-    aliased to the pool."""
+    aliased to the pool; q and k go in as they are."""
+    from benchmark import trace_reduce
+    from benchmark.readers import state
     from ray_tpu.ops import retention
 
     assert retention.state_shape(6, 16, 8, 128) == STATE_POOL
@@ -36,11 +39,25 @@ def test_state_step_kernel_compiles_for_v5e(v5e):
         arr(v5e, STATE_POOL, jnp.float32), arr(v5e, (), jnp.int32),
         arr(v5e, (16,), jnp.bool_),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 4 * math.prod(STATE_POOL)
-    # Beside the pool: phi(q), phi(k) and the like, no second pool.
+    # Beside the pool: the operands' rows, no second pool.
     assert memory.temp_size_in_bytes < 4 * math.prod(STATE_POOL[1:])
+    # The call writes the read-outs first and the pool second, float32
+    # both and nothing else: the name the benchmark finds it by in a
+    # trace (benchmark/readers/state.py).
+    call, = (line.strip() for line in text.splitlines()
+             if "custom-call(" in line)
+    name = trace_reduce.stable_name(call)
+    assert name == "pallas_f32_16_8_5_128_f32_6_16_8_65_136_128"
+    assert state.STATE_STEP.match(name)
+    # phi(q) and phi(k) are made inside it (PR 72): nothing but the pool
+    # has an axis of the 65 turns.
+    shapes = {tuple(map(int, dims.split(",")))
+              for dims in re.findall(r"\[([0-9]+(?:,[0-9]+)*)\]", text)}
+    assert {shape for shape in shapes if 65 in shape} == {STATE_POOL}
 
 
 @pytest.mark.parametrize("bucket", [4096, 16384])
