@@ -1,11 +1,10 @@
 """The serving programs of the Llama family over the paged KV cache.
 
 The engine's cache is the paged one (``PagedKVCache``): for each of the
-six attention kinds a model may have ("full", "window", "latent",
-"state", "delta", "linear") a pool, and for the three that keep a row a
-token a shared pool of token pages and a page table a slot. Two jitted programs use
-it, both
-built on the one transformer block (``llama.block``) with an attention
+seven kinds of layer a model may have ("full", "window", "latent",
+"state", "delta", "linear", "conv") a pool, and for the three that keep
+a row a token a shared pool of token pages and a page table a slot.
+Two jitted programs use it, both built on the one transformer block (``llama.block``) with an attention
 of their own, both a layer scan for each run of alike layers
 (``llama.layer_runs``: one run for a uniform model), and all their
 shapes are static:
@@ -71,6 +70,20 @@ those alone, a block of one head a copy (its pages are one aligned run
 of ids: ``KVBooks``); a prefill selects and attends a block of queries at a
 time, and lays the means of the pages it fills beside their rows.
 
+A gated short convolution (kind "conv", ``llama.conv_proj`` /
+``conv_mix``) attends to nothing and keeps no state: all a later token
+needs of the request is the last ``conv_taps - 1`` rows of one
+hidden-wide product, the layer's whole cache, a slot and no pages
+(``k["conv"]``). Its layers stand AMONG "full" ones, so one request holds
+a slot of histories and pages of the few layers that attend, under one
+admission. The prefill lays the history taken at the last real token,
+not at the bucket's end, and overwrites the slot's whole (a slot taken
+again needs no zeroing; a prompt shorter than the taps reach leaves
+zeros in front); the decode step shifts it by the token's row
+(``_shifted``, a delta layer's too). The "full" pool of a model with
+heads of 64 lays two heads to a row (``kv_pool_row``), so that a token
+holds the bytes the model states.
+
 A looped model (``cfg.passes`` > 1, Ouro) runs its stack that many
 times over ONE set of weights, and each (pass, layer) attends over keys
 and values of its own: its pools are ``passes`` times as deep as the
@@ -99,7 +112,7 @@ import numpy as np
 
 from ..ops.paged_attention import (
     decode_attention, decode_attention_path, latent_decode_attention,
-    ring_pages, walk_step_tokens,
+    pool_row, ring_pages, walk_step_tokens,
 )
 from ..ops.block_attention import (
     block_decode_attention, block_prefill_attention, block_select_decode,
@@ -121,15 +134,26 @@ from ..ops.sparse_attention import (
     sparse_prefill_attention,
 )
 from .llama import (
-    LlamaConfig, block, causal_attention, delta_mix, embed_tokens,
-    exit_distribution, exit_gate_logit, head_input, index_offsets, kv_layers,
-    kv_layers_a_pass, latent_absorb_out, latent_absorb_q, latent_kv,
-    layer_runs, layer_stacks, pool_kind, rms_norm, split_expert_stack,
+    LlamaConfig, block, causal_attention, conv_mix, delta_mix, embed_tokens,
+    exit_distribution, exit_gate_logit, head_input, head_logits,
+    index_offsets, kv_layers, kv_layers_a_pass, latent_absorb_out,
+    latent_absorb_q, latent_kv, layer_runs, layer_stacks, pool_kind,
+    rms_norm, split_expert_stack,
 )
 
 # The kinds whose pools hold a slot's state and no row a token: no pages,
 # no table column, admission by slot alone.
-SLOT_KINDS = ("state", "delta", "linear")
+SLOT_KINDS = ("state", "delta", "linear", "conv")
+
+
+def kv_pool_row(cfg: LlamaConfig) -> Tuple[int, int]:
+    """(heads, width) of a token's row in this model's k/v pools
+    (ops/paged_attention.py ``pool_row``: heads of 64 two to a row). A
+    model that selects blocks keeps a head a row: its kernels and its
+    page means read a page a KV head (ops/block_attention.py)."""
+    if cfg.block_select:
+        return cfg.num_kv_heads, cfg.dh
+    return pool_row(cfg.num_kv_heads, cfg.dh)
 
 
 class MoeLoad(NamedTuple):
@@ -213,11 +237,16 @@ class PagedKVCache(NamedTuple):
     % ratio == 0``; ``KVBooks`` allocates so), by which the decode walk
     copies a block of one head as one region (ops/block_attention.py).
     The mean pool, at the same ids, gets the runs for free.
+    A "conv" layer (a gated short convolution) keeps the last
+    ``conv_taps - 1`` rows of its gated product a slot, ``k["conv"]``
+    [L, taps - 1, B, hidden] in the model's dtype, no ``v``, no pages,
+    beside the "full" pool of the layers it stands among.
 
     A k/v pool is HEAD-MAJOR
-    ([L_kind, Hkv, P_kind, page, Dh]): one copy brings a page of every KV
-    head to the decode attention (ops/paged_attention.py), which reads a
-    slot's own pages where they lie and, on a TPU, is also what writes a
+    ([L_kind, Hkv, P_kind, page, Dh]; heads of 64 two to a row, [L_kind,
+    Hkv / 2, P_kind, page, 128], ``kv_pool_row``): one copy brings a page
+    of every KV head to the decode attention (ops/paged_attention.py),
+    which reads a slot's own pages where they lie and, on a TPU, is also what writes a
     decode step's token: one page a slot a layer, through an output
     aliased to the pool. A decode step never slices, re-stacks or
     re-lays the pool. XLA cannot write one token's row in place (a row
@@ -227,9 +256,10 @@ class PagedKVCache(NamedTuple):
     step before PR 29, PERF.md §6)."""
 
     k: Dict[str, jax.Array]            # kind -> [L_kind, Hkv, P, page, Dh]
-    v: Dict[str, jax.Array]            # ("latent", "index", "state", "linear":
-    #                                    k alone; "delta": the convolution's
-    #                                    history; "mean": the open pages' sums)
+    v: Dict[str, jax.Array]            # ("latent", "index", "state", "linear",
+    #                                    "conv": k alone; "delta": the
+    #                                    convolution's history; "mean": the
+    #                                    open pages' sums)
     page_table: Dict[str, jax.Array]   # kind -> [B, columns] int32 page ids
     lengths: jax.Array                 # [B] int32 valid tokens per slot
 
@@ -286,6 +316,11 @@ class PagedKVCache(NamedTuple):
                 return jnp.zeros(linear_state_shape(
                     layers, batch, cfg.linear_heads, cfg.linear_head_dim),
                     dtype=jnp.float32)
+            if kind == "conv":
+                # The last ``conv_taps - 1`` rows of z, the slots second
+                # to last as a delta layer's histories are.
+                return jnp.zeros((layers, cfg.conv_taps - 1, batch,
+                                  cfg.hidden_size), dtype=cfg.dtype)
             if kind == "mean":
                 return jnp.zeros((layers, pages, cfg.num_kv_heads * cfg.dh),
                                  dtype=cfg.dtype)
@@ -296,9 +331,9 @@ class PagedKVCache(NamedTuple):
                 return jnp.zeros(
                     (layers, pages, page_size, cfg.index_head_dim),
                     dtype=cfg.dtype)
-            return jnp.zeros(
-                (layers, cfg.num_kv_heads, pages, page_size, cfg.dh),
-                dtype=cfg.dtype)
+            heads, width = kv_pool_row(cfg)
+            return jnp.zeros((layers, heads, pages, page_size, width),
+                             dtype=cfg.dtype)
 
         first = {kind: pool(kind, layers, pages)
                  for kind, (layers, pages, _) in sizes.items()}
@@ -430,7 +465,8 @@ class KVBooks:
             if selecting and self.decode_attention == "latent_walk":
                 self.decode_attention = "sparse_walk"
         else:
-            self.decode_attention = decode_attention_path(page_size, cfg.dh)
+            self.decode_attention = decode_attention_path(
+                page_size, kv_pool_row(cfg)[1])
         # The tokens a compute step of the walk covers in each pool it
         # walks: what its buffers were sized by. A token is a k and a v
         # row of every KV head, or one latent row.
@@ -464,6 +500,10 @@ class KVBooks:
              "steps_selected"), 0) if self._blocks else None
         self.linear_counts = ({"slot_layers": 0}
                               if "linear" in self.pools else None)
+        # ``stats()["conv"]``: the convolution histories the steps
+        # shifted; None for a model without such layers.
+        self.conv_counts = ({"slot_layers": 0}
+                            if "conv" in self.pools else None)
         self._mean_row_bytes = (
             cache.k["mean"].shape[-1] * cache.k["mean"].dtype.itemsize
             if "mean" in cache.k else 0)
@@ -562,6 +602,9 @@ class KVBooks:
         if self.linear_counts:
             self.linear_counts["slot_layers"] += (
                 len(contexts) * self.pools["linear"][0])
+        if self.conv_counts:
+            self.conv_counts["slot_layers"] += (
+                len(contexts) * self.pools["conv"][0])
         counts["decode_state_slot_layers"] += (
             len(contexts) * self._state_layers)
         counts["kv_page_steps_held"] += sum(
@@ -628,9 +671,25 @@ class KVBooks:
                            "slot_bytes": self._slot_bytes["linear"]
                            * self.pools["linear"][0]}}
                if self.linear_counts else {}),
+            **({"conv": {**self.conv_counts,
+                         "slot_bytes": self._slot_bytes["conv"]
+                         * self.pools["conv"][0],
+                         "layers": self.pools["conv"][0],
+                         "layers_in_all": self._layers}}
+               if self.conv_counts else {}),
             "page_walk_step_tokens": dict(self.page_walk_step_tokens),
             "latent_walk_step_tokens": dict(self.latent_walk_step_tokens),
         }
+
+
+def _shifted(histories, layer, history, rows, active):
+    """A decode step's write of a convolution's histories [L, taps - 1,
+    B, C]: at ``layer`` each active slot's becomes the last ``taps - 1``
+    of ``rows`` [B, taps, C], its ``history`` [taps - 1, B, C] and the
+    token's row behind it; an idle slot's stays as it is. A delta
+    layer's and a "conv" layer's alike."""
+    return histories.at[layer].set(jnp.where(
+        active[None, :, None], rows[:, 1:].transpose(1, 0, 2), history))
 
 
 def _with_pools(cache: PagedKVCache, pools, lengths) -> PagedKVCache:
@@ -827,14 +886,27 @@ def paged_decode(
                     q, k, v, rows = delta_mix(cfg, lp, q, k, v,
                                               history.transpose(1, 0, 2))
                     with jax.named_scope("kda.conv"):
-                        histories = histories.at[layer].set(jnp.where(
-                            active[None, :, None],
-                            rows[:, 1:].transpose(1, 0, 2), history))
+                        histories = _shifted(histories, layer, history, rows,
+                                             active)
                     with jax.named_scope("attn.delta"):
                         out, states = delta_decode(
                             q[:, 0], k[:, 0], v[:, 0], log_a[:, 0], beta[:, 0],
                             states, layer, active)
                     return out[:, None], ((states, histories), keys, selected)
+
+                def attend_conv(z, _k, _v):
+                    # The taps over the slot's history and the token's
+                    # row, then the history shifted by that row: the
+                    # layer's whole cache, an idle slot's left as it is.
+                    (histories,) = held
+                    layer = lp["index"] + kv_at
+                    history = histories[layer]            # [taps-1, B, M]
+                    with jax.named_scope("attn.conv"):
+                        y, rows = conv_mix(lp, z, history.transpose(1, 0, 2))
+                        with jax.named_scope("conv.mix"):
+                            histories = _shifted(histories, layer, history,
+                                                 rows, active)
+                    return y, ((histories,), keys, selected)
 
                 # The load-balancing loss is a training-only term: dropped.
                 x, kept, _aux, load = block(
@@ -842,7 +914,7 @@ def paged_decode(
                     attend_blocks if kind == "blocks" else
                     {"latent": attend_latent, "state": attend_state,
                      "delta": attend_delta, "linear": attend_linear,
-                     }.get(pool, attend),
+                     "conv": attend_conv}.get(pool, attend),
                     token_mask=active[:, None], expert_stack=expert_stack,
                     kind=kind)
                 return (x,) + kept, load
@@ -858,8 +930,7 @@ def paged_decode(
         return x, pools, expert_tokens, _exit_gate(cfg, params, x[:, 0])
 
     x, pools, expert_tokens, gates = _passes(cfg, one_pass, x, pools)
-    logits = jnp.einsum("bm,mv->bv", head_input(cfg, x[:, 0]),
-                        params["lm_head"])
+    logits = head_logits(params, head_input(cfg, x[:, 0]))
     lengths = jnp.where(active, cache.lengths + 1, cache.lengths)
     return _with_exit(
         gates, logits.astype(jnp.float32),
@@ -919,7 +990,7 @@ def paged_prefill(
             # [n, Hkv, T, R, Dh]: the slot's states of the run's layers.
             return jax.lax.dynamic_update_slice(
                 pool, rows[:, None], (offset, slot, 0, 0, 0, 0))
-        if run.kind in ("delta", "linear"):
+        if run.kind in ("delta", "linear", "conv"):
             # The slot's states [n, H, D, D], whole, or its convolution
             # histories [n, taps - 1, row], the slots second to last.
             rows, at = ((rows[:, None], (offset, slot, 0, 0, 0))
@@ -935,8 +1006,10 @@ def paged_prefill(
             # One row a token for all heads: latent rows, indexer keys.
             paged = rows[:, 0].reshape(run.n, S // page, page, -1)
             return pool.at[at, ids].set(paged.astype(pool.dtype))
+        # A token's row as the pool lays it (``kv_pool_row``: the KV
+        # heads, or heads of 64 two to a row).
         paged = rows[:, 0].reshape(
-            run.n, S // page, page, cfg.num_kv_heads, cfg.dh
+            run.n, S // page, page, pool.shape[1], pool.shape[-1]
         ).transpose(0, 3, 1, 2, 4)
         if run.kind == "window":
             # The newest ``len(ids)`` pages up to the last real token's
@@ -1073,12 +1146,25 @@ def paged_prefill(
                             jnp.where(real[:, None], beta[0], 0.0))
                     return out[None], ((state, history), selected)
 
+                def attend_conv(z, _k, _v):
+                    # From zeros before the prompt; the history kept is
+                    # the last real tokens' rows, not the bucket's end
+                    # (token t lies at row t + taps - 1), zeros in front
+                    # of a prompt shorter than the taps reach.
+                    taps = cfg.conv_taps
+                    with jax.named_scope("attn.conv"):
+                        y, rows = conv_mix(lp, z, jnp.zeros(
+                            (1, taps - 1, z.shape[-1]), z.dtype))
+                        history = jax.lax.dynamic_slice_in_dim(
+                            rows[0], real_len, taps - 1)
+                    return y, ((history,), selected)
+
                 x, (kept, selected), _aux, load = block(
                     cfg, lp, x, positions,
                     attend_blocks if kind == "blocks" else
                     {"latent": attend_latent, "state": attend_state,
                      "delta": attend_delta, "linear": attend_linear,
-                     }.get(pool, attend),
+                     "conv": attend_conv}.get(pool, attend),
                     token_mask=token_mask, expert_stack=expert_stack,
                     kind=kind)
                 return (x, selected), (kept, load)
@@ -1101,8 +1187,7 @@ def paged_prefill(
             cfg, params, x[:, real_len - 1])
 
     x, pools, expert_tokens, gates = _passes(cfg, one_pass, x, pools)
-    logits = jnp.einsum("bm,mv->bv", head_input(cfg, x[:, real_len - 1]),
-                        params["lm_head"])
+    logits = head_logits(params, head_input(cfg, x[:, real_len - 1]))
     lengths = cache.lengths.at[slot].set(real_len)
     return _with_exit(
         gates, logits.astype(jnp.float32),

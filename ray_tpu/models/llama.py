@@ -103,6 +103,15 @@ class LlamaConfig:
     rope_full_layers: bool = True
     # ``qk_norm`` over each head's ``dh`` instead of the whole projection.
     qk_norm_per_head: bool = False
+    # What ``init_params`` draws the weights of ``q_norm`` and ``k_norm``
+    # around: 1.0 makes them ones, as every other norm's. A trained
+    # model's grow well above 1 (normed q and k bound a score by
+    # ``sqrt(dh)``, and the weights are what let a softmax over thousands
+    # of keys peak); seeded weights with ones attend almost evenly, and
+    # an attention layer's output then hardly reaches the logits. With g
+    # here each weight is ``g (1 + 0.2 n)``, n normal from the seed, so
+    # that scores are about ``N(0, g**4)`` and a weight differs a channel.
+    qk_norm_init: float = 1.0
     # The attention output times sigmoid(h wg), before ``wo``.
     attn_gate: bool = False
     # A norm behind the attention and one behind the FFN, on what each
@@ -187,6 +196,21 @@ class LlamaConfig:
     # table), keeps ``topk`` blocks a KV head and attends over those;
     # before ``dense_len`` over everything.
     block_select: Optional[BlockSizes] = None
+    # Layers of kind "conv" among "full" ones (``layer_types`` names
+    # each): a gated short convolution and no attention. ``w_in`` makes
+    # three gates of the hidden size from the layer's normed input, (B,
+    # C, X) in this order; z = B * X goes through a causal depthwise
+    # convolution of ``conv_taps`` taps (``conv_w`` [taps, hidden], no
+    # bias, no activation), and ``w_out`` multiplies C times its output.
+    # No rotary, no k and v: what such a layer keeps of a request is the
+    # last ``conv_taps - 1`` rows of z, a slot, no row a token and no
+    # state either (generation.PagedKVCache's "conv" pool, beside the
+    # "full" one).
+    conv_taps: int = 0
+    # The head is the embedding's transpose: ``params`` has no
+    # ``lm_head`` and the logits are ``h . embed^T`` (``head_logits``).
+    # Served only: the training programs read ``lm_head``.
+    tied_head: bool = False
     # A looped model (Ouro): the whole stack runs ``passes`` times over
     # the ONE set of layer weights, ``final_norm`` behind every pass and
     # its output the next pass's input; the last pass's goes to the head.
@@ -296,7 +320,8 @@ class LayerRun(NamedTuple):
     # "latent_shared" takes the last one made. Both keep their rows in
     # the "latent" pool. "linear": a Lightning state a slot. "blocks": a
     # "full" layer that attends over a selection of blocks; its rows lie
-    # in the "full" pool.
+    # in the "full" pool. "conv": a gated short convolution, its last
+    # inputs a slot.
     kind: str
     kv_offset: int
 
@@ -316,7 +341,13 @@ def _kind_rules(cfg: LlamaConfig) -> Dict[str, Tuple[Any, str, frozenset]]:
     ``wg`` is the retention gate's name, its programs scan one pool of
     states: ROADMAP R7), a delta-rule layer beside k/v rows ("delta"
     beside "full": the delta layers' convolution histories and the
-    latent pool are what generation.py lays together), and a selective
+    latent pool are what generation.py lays together), a gated short
+    convolution ("conv") beside anything but "full" layers and itself
+    (beside "window" the pools would lie together as they do beside
+    "full", and no configuration has asked; beside "latent" or a kind
+    that keeps a state, "state", "delta", "linear", generation.py's
+    layer loop hands one run's ``attend`` the pools of one kind and the
+    decode programs' tests cover none of those pairs), and a selective
     scan, which is no kind at all (a state whose decay and write are
     functions of the token needs an ``attend`` and a pool of its own)."""
     rows = not cfg.latent
@@ -325,7 +356,7 @@ def _kind_rules(cfg: LlamaConfig) -> Dict[str, Tuple[Any, str, frozenset]]:
                  "attention is latent (kv_lora_rank > 0: each layer is "
                  "'latent' or 'delta', head_dim the q.k width, "
                  "qk_nope_head_dim + qk_rope_head_dim)",
-                 frozenset(("full", "window", "linear"))),
+                 frozenset(("full", "window", "linear", "conv"))),
         "window": (rows and bool(cfg.sliding_window),
                    "needs a sliding_window and k and v rows a head (no "
                    "kv_lora_rank)", frozenset(("full", "window"))),
@@ -355,6 +386,10 @@ def _kind_rules(cfg: LlamaConfig) -> Dict[str, Tuple[Any, str, frozenset]]:
                    "linear_decay_layers, and k and v rows a head in the "
                    "layers beside it (no kv_lora_rank)",
                    frozenset(("full", "linear"))),
+        "conv": (rows and cfg.conv_taps > 1,
+                 "needs conv_taps of two or more, and k and v rows a head "
+                 "in the layers beside it (no kv_lora_rank)",
+                 frozenset(("full", "conv"))),
     }
 
 
@@ -367,7 +402,8 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
     what it needs of the config and may stand beside the kinds it names.
     Llama, Mistral, OLMoE: one run, "full". "window" beside "full";
     "delta" (a state a slot) among "latent"; "linear" (a state a slot)
-    among "full"; every layer "state". Under a selection a "latent"
+    among "full"; "conv" (a convolution's history a slot) among "full";
+    every layer "state". Under a selection a "latent"
     layer is "latent_index" or "latent_shared" (``index_topk``), a
     "full" one "blocks" (``block_select``)."""
     kinds = cfg.layer_types or (
@@ -387,7 +423,8 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
                 f"{sorted(beside - {kind}) or 'no other kind'} only; "
                 f"{sorted(set(kinds) - beside)} beside it in one model is "
                 f"not implemented (ROADMAP R7: power retention beside "
-                f"another kind, a delta-rule layer beside k/v rows)")
+                f"another kind, a delta-rule layer beside k/v rows, a short "
+                f"convolution beside a window, a latent pool or a state)")
     if cfg.index_topk:
         types = cfg.indexer_types or ()
         if (not cfg.latent or len(types) != cfg.num_layers
@@ -416,7 +453,7 @@ def layer_runs(cfg: LlamaConfig) -> Tuple[LayerRun, ...]:
     alike = [(cfg.n_experts > 0 and i >= cfg.num_dense_layers, kind)
              for i, kind in enumerate(kinds)]
     runs, seen = [], dict.fromkeys(
-        ("full", "window", "latent", "state", "delta", "linear"), 0)
+        ("full", "window", "latent", "state", "delta", "linear", "conv"), 0)
     for i, (moe, kind) in enumerate(alike):
         if runs and alike[i - 1] == (moe, kind):
             runs[-1] = runs[-1]._replace(n=runs[-1].n + 1)
@@ -445,6 +482,7 @@ def _check_loop(cfg: LlamaConfig, kinds) -> None:
                    "that walks the pass's layers of the latent pool"),
         ("latent_index", "a selection a pass: indexer keys a token a PASS"),
         ("linear", "a Lightning state a slot a PASS"),
+        ("conv", "a convolution history a slot a PASS"),
         ("blocks", "a selection a pass: page means a PASS"),
     ) if kind in kinds]
     if cfg.n_experts > 0:
@@ -510,20 +548,33 @@ def layer_stacks(params) -> Tuple[Dict[str, Any], ...]:
 def require_uniform(cfg: LlamaConfig, what: str) -> None:
     """Training scans ONE stack with ONE causal attention, and the flash
     backward kernels take no window and one width for q, k and v: a
-    stack in runs, a window layer, a latent-attention layer or a
-    retention or delta-rule layer (kinds "state", "delta": their chunked
-    scans have no backward) trains nowhere yet (ROADMAP R3, R5, R7), and
-    says so by name."""
+    stack in runs, a window layer, a latent-attention layer, a
+    retention, delta-rule or Lightning layer (kinds "state", "delta",
+    "linear": their chunked scans have no backward) or a short
+    convolution (kind "conv": its backward is a correlation of
+    ``conv_taps`` taps, the cheapest of these to bring, but its layers
+    stand among "full" ones in runs) trains nowhere yet (ROADMAP R3, R5,
+    R7), and says so by name; nor does a tied head (``tied_head``: the
+    loss reads ``lm_head``)."""
+    if cfg.tied_head:
+        raise NotImplementedError(
+            f"{what}: training a model whose head is its embedding's "
+            f"transpose (tied_head) is not implemented: the loss reads "
+            f"params['lm_head'] and would need the embedding's gradient "
+            f"summed from both uses; it is served only "
+            f"(models/generation.py)")
     if len(layer_runs(cfg)) > 1 or set(kv_layers_a_pass(cfg)) - {"full"}:
         raise NotImplementedError(
             f"{what}: training a model whose layer stack is not uniform "
-            f"(dense layers before expert layers, window or linear beside "
-            f"full attention), whose attention is latent (q.k and v of "
-            f"unequal widths) or over a selection of blocks, or whose "
-            f"layers are of kind 'state', 'delta' or 'linear' (power "
-            f"retention, the delta rule, Lightning attention: the chunked "
-            f"scans have no backward pass) is not implemented; it is "
-            f"served only (models/generation.py)")
+            f"(dense layers before expert layers, window, linear or conv "
+            f"beside full attention), whose attention is latent (q.k and v "
+            f"of unequal widths) or over a selection of blocks, or whose "
+            f"layers are of kind 'state', 'delta', 'linear' or 'conv' "
+            f"(power retention, the delta rule, Lightning attention: the "
+            f"chunked scans have no backward pass; a short convolution: "
+            f"its backward, a correlation of conv_taps taps, is not "
+            f"written) is not implemented; it is served only "
+            f"(models/generation.py)")
 
 
 # Logical axes for each parameter leaf (maps through DEFAULT_RULES:
@@ -672,6 +723,16 @@ def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
 
     if kind == "delta":
         layers = _init_delta(cfg, k, n)
+    elif kind == "conv":
+        # The three gates' projection (B | C | X), the taps a channel
+        # and the output projection: no q, k, v, no norm a head.
+        layers = {
+            "attn_norm": norm_init((n, M)),
+            "w_in": winit(next(k), (n, M, 3 * M), M),
+            "conv_w": winit(next(k), (n, cfg.conv_taps, M), cfg.conv_taps),
+            "w_out": winit(next(k), (n, M, M), M),
+            "mlp_norm": norm_init((n, M)),
+        }
     elif cfg.latent:
         # The down-projections and their norms, the up-projections a
         # head (``wk_b`` and ``wv_b`` are the two halves of the
@@ -701,7 +762,7 @@ def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
             "wo": winit(next(k), (n, H, Dh, M), H * Dh),
             "mlp_norm": norm_init((n, M)),
         }
-    if cfg.qk_norm:
+    if cfg.qk_norm and kind != "conv":
         per_head = cfg.qk_norm_per_head
         layers.update(q_norm=norm_init((n, Dh if per_head else H * Dh)),
                       k_norm=norm_init((n, Dh if per_head else Hkv * Dh)))
@@ -730,7 +791,11 @@ def _init_stack(cfg: LlamaConfig, k, n: int, moe: bool,
             w_up=winit(next(k), (n, M, F), M),
             w_down=winit(next(k), (n, F, M), F),
         )
-    if cfg.attn_gate:
+    if cfg.qk_norm and cfg.qk_norm_init != 1.0 and kind != "conv":
+        for name in ("q_norm", "k_norm"):
+            layers[name] = cfg.qk_norm_init * (1 + 0.2 * jax.random.normal(
+                next(k), layers[name].shape, dtype=jnp.float32))
+    if cfg.attn_gate and kind != "conv":
         layers["wg"] = winit(next(k), (n, M, H, Dh), M)
     if cfg.post_norms:
         layers.update(post_attn_norm=norm_init((n, M)),
@@ -793,8 +858,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "embed": winit(next(k), (V, M)),
         "layers": layers,
         "final_norm": jnp.ones((M,), dtype=jnp.float32),
-        "lm_head": winit(next(k), (M, V)),
     }
+    if not cfg.tied_head:
+        params["lm_head"] = winit(next(k), (M, V))
     if cfg.exit_gate:
         # A Linear(hidden, 1) with a bias. Against a normed state of
         # unit RMS the gate's logit is then about N(0, 1): sigmoid of it
@@ -1066,6 +1132,44 @@ def _latent_parts(cfg: LlamaConfig, lp, x, positions):
     return q, row, h, c_q
 
 
+def causal_taps(w, tokens, history):
+    """A causal depthwise convolution of ``tokens`` [B, S, C] behind
+    ``history`` [B, taps - 1, C], the rows of the tokens just before
+    them (zeros before a prompt's first): ``y_t = sum_i w_i
+    x_{t-taps+1+i}`` with ``w`` [taps, C], no bias, summed in float32.
+    Returns (y [B, S, C] float32, the rows [B, taps - 1 + S, C] of
+    history and tokens together, of which a cache keeps the last ``taps
+    - 1`` real ones): what a delta layer's and a "conv" layer's mix
+    share."""
+    S = tokens.shape[1]
+    rows = jnp.concatenate([history.astype(tokens.dtype), tokens], axis=1)
+    w = w.astype(jnp.float32)
+    y = sum(rows[:, i:i + S].astype(jnp.float32) * w[i]
+            for i in range(w.shape[0]))
+    return y, rows
+
+
+def conv_proj(cfg: LlamaConfig, lp, x):
+    """A "conv" layer's first half: attention norm, ``w_in`` to the
+    three gates (B, C, X), each ``hidden_size`` wide and in this order,
+    and z = B * X [B, S, M], what the convolution mixes and all that a
+    later token needs of this one. Returns (z, C)."""
+    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+    with jax.named_scope("conv.proj"):
+        b, c, xg = jnp.split(jnp.einsum("bsm,mn->bsn", h, lp["w_in"]), 3,
+                             axis=-1)
+        return b * xg, c
+
+
+def conv_mix(lp, z, history):
+    """The ``conv_taps`` taps over z [B, S, M] behind ``history`` [B,
+    taps - 1, M] (``causal_taps``; no activation). Returns (y [B, S, M]
+    in z's dtype, the rows of history and tokens together)."""
+    with jax.named_scope("conv.mix"):
+        y, rows = causal_taps(lp["conv_w"], z, history)
+        return y.astype(z.dtype), rows
+
+
 def delta_proj(cfg: LlamaConfig, lp, x):
     """A delta layer's first half up to its convolution: attention norm,
     then q, k and v [B,S,H,D] as projected (``delta_mix`` takes them on),
@@ -1104,14 +1208,10 @@ def delta_mix(cfg: LlamaConfig, lp, q, k, v, history):
     of history and tokens together, of which a cache keeps the last
     ``taps - 1`` real ones."""
     B, S, H, D = q.shape
-    taps = cfg.delta_conv
     with jax.named_scope("kda.conv"):
         tokens = jnp.concatenate(
             [x.reshape(B, S, H * D) for x in (q, k, v)], axis=-1)
-        rows = jnp.concatenate([history.astype(q.dtype), tokens], axis=1)
-        w = lp["conv_w"].astype(jnp.float32)
-        y = sum(rows[:, i:i + S].astype(jnp.float32) * w[i]
-                for i in range(taps))
+        y, rows = causal_taps(lp["conv_w"], tokens, history)
         y = jax.nn.silu(y).reshape(B, S, 3, H, D)
         q, k, v = y[:, :, 0], y[:, :, 1], y[:, :, 2]
 
@@ -1348,7 +1448,11 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
     gated here. For "linear" ``attend`` is given rotated q, k and v of
     ``linear_heads`` heads each and the layer's decays are its own to
     read (``lp["log_decay"]``); its output is normed a head and gated
-    here. For "blocks" ``attend`` is given what a "full" layer's is,
+    here. For "conv" there is no attention: ``attend`` is given z = B *
+    X [B,S,M] in q's place and nothing else (``conv_proj``), owns the
+    taps and their history (``conv_mix``) and returns y [B,S,M], which
+    is gated by C and goes through ``w_out`` here. For "blocks"
+    ``attend`` is given what a "full" layer's is,
     unrotated where the model rotates its other layers alone, and owns
     the selection and the page means it is made from.
 
@@ -1375,25 +1479,34 @@ def block(cfg: LlamaConfig, lp, x, positions, attend, *, mesh=None,
             v = index_proj(cfg, lp, h, c_q, positions)
     elif kind == "delta":
         q, k, v, gate = delta_proj(cfg, lp, x)
+    elif kind == "conv":
+        (q, gate), k, v = conv_proj(cfg, lp, x), None, None
     else:
         q, k, v, gate = qkv_proj(cfg, lp, x, mesh=mesh)
         if cfg.rope_full_layers or kind not in ("full", "blocks"):
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
-    q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"),
-                                mesh=mesh)
-    if kind == "state":
-        attn, state = attend(q, k, (v, gate))
+    if kind == "conv":
+        # z in q's place; what comes back is gated and goes through
+        # ``w_out``, which stands where another layer's ``wo`` does.
+        y, state = attend(q, k, v)
+        with jax.named_scope("conv.out"):
+            attn = jnp.einsum("bsm,mn->bsn", gate * y, lp["w_out"])
     else:
-        attn, state = attend(q, k, v)
-        if kind in ("delta", "linear"):
-            attn = rms_norm(attn, lp["o_norm"], cfg.rms_eps)
-        if gate is not None:
-            attn = attn * gate
-    if ring_size(mesh, x.shape[1]) > 1:
-        attn = _ring_saved(matmul_scatter(mesh, attn, lp["wo"]))
-    else:
-        attn = jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
+        q = with_logical_constraint(
+            q, ("batch", "seq", "heads", "head_dim"), mesh=mesh)
+        if kind == "state":
+            attn, state = attend(q, k, (v, gate))
+        else:
+            attn, state = attend(q, k, v)
+            if kind in ("delta", "linear"):
+                attn = rms_norm(attn, lp["o_norm"], cfg.rms_eps)
+            if gate is not None:
+                attn = attn * gate
+        if ring_size(mesh, x.shape[1]) > 1:
+            attn = _ring_saved(matmul_scatter(mesh, attn, lp["wo"]))
+        else:
+            attn = jnp.einsum("bshd,hdm->bsm", attn, lp["wo"])
     if cfg.post_norms:
         attn = rms_norm(attn, lp["post_attn_norm"], cfg.rms_eps)
     x = x + _residual(cfg, attn)
@@ -1431,6 +1544,17 @@ def embed_tokens(params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
     if cfg.embed_scale != 1.0:
         x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
     return x
+
+
+def head_logits(params, h: jax.Array) -> jax.Array:
+    """The logits [B, V] of the normed hidden rows ``h`` [B, M] (behind
+    ``head_input``), in h's dtype: ``h . lm_head``, or for a model whose
+    head is tied (``tied_head``: the tree has no ``lm_head``) ``h .
+    embed^T``, the embedding contracted along its last axis, where it
+    lies."""
+    if "lm_head" in params:
+        return jnp.einsum("bm,mv->bv", h, params["lm_head"])
+    return jnp.einsum("bm,vm->bv", h, params["embed"])
 
 
 def forward(

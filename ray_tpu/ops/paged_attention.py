@@ -55,6 +55,23 @@ and V are never repeated, and no operand is narrower than a tile.
 Operands go to the MXU in the pool's dtype with float32 scores; running
 max, denominator and accumulator are float32.
 
+Heads of 64 (PR 73). A page of one head of 64 is ``(16, 64)``, half a
+lane tile, which the device pads to a whole one: the pool, and every
+copy of the walk, at twice the bytes the model states. Where the KV
+heads pair up the pool therefore lays two to a row (:func:`pool_row`:
+``[L, Hkv / 2, P, page, 128]``, heads ``2j | 2j + 1`` side by side), a
+page of a pair is the tile a page of one head of 128 is, and the walk
+runs the SAME program over it, copies, buffers and step length those of
+``Hkv / 2`` heads of 128. Only the operands are laid for it, outside the
+kernel (``_to_lanes``, ``_from_lanes``): a query goes to its own KV
+head's half of a 128-wide row, zeros in the other, so that its product
+with a pair's row is its product with its own head's key; the
+probabilities multiply the pair's whole value rows and each head keeps
+its half. The MXU multiplies the zeros too, half its work wasted, in a
+kernel that waits for its copies; the bytes are the model's, 2 x Hkv x
+64 a token, no row padded. Heads of 128 take the program they always
+did.
+
 ``gather`` — plain XLA, for any platform and shape (tier-1 runs it on
 CPU): scatter the rows into the whole pool, gather every slot's
 ``Pmax`` pages of the layer, attend densely with the GQA group as a
@@ -312,15 +329,20 @@ def paged_decode_attention(
 ):
     """The page-walk kernel. Returns ([B, H, D], k_pool, v_pool): rows
     of inactive slots are zeros, the pools are the arguments' buffers
-    with the active slots' rows written. With ``window`` a slot's row of
-    the table is a ring (module docstring) and the walk starts at the
-    page of position ``lengths[b] + 1 - window``, masking the rows
+    with the active slots' rows written. The pools' rows may hold two
+    heads of 64 side by side (``pool_row``), q, k_new and v_new then
+    being 64 wide; scores are scaled by the heads' own width. With
+    ``window`` a slot's row of the table is a ring (module docstring)
+    and the walk starts at the page of position ``lengths[b] + 1 - window``, masking the rows
     before it; ``window=None`` is the walk over everything."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, D = q.shape
-    _, Hkv, _, page, _ = k_pool.shape
+    B, H, width = q.shape
+    _, Hkv, _, page, D = k_pool.shape
+    # Heads narrower than the pool's rows lie side by side in them
+    # (``pool_row``): the queries go to their own head's lanes.
+    q, k_new, v_new = _to_lanes(q, k_new, v_new, Hkv, D)
     Pmax = page_table.shape[1]
     dtype = k_pool.dtype
     block = walk_step_tokens(
@@ -340,9 +362,9 @@ def paged_decode_attention(
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     kv_buf = pltpu.VMEM((2, Hkv, block, D), dtype)
     kernel = functools.partial(
-        _page_walk_kernel, pmax=Pmax, scale=D ** -0.5, window=window)
+        _page_walk_kernel, pmax=Pmax, scale=width ** -0.5, window=window)
     n_scalars = 2 + len(scalars)
-    return pl.pallas_call(
+    out, k_pool, v_pool = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_scalars,
@@ -363,6 +385,51 @@ def paged_decode_attention(
     )(page_table.reshape(-1).astype(jnp.int32), n_pages, *scalars,
       q.astype(dtype), k_new.astype(dtype)[:, :, None],
       v_new.astype(dtype)[:, :, None], k_pool, v_pool)
+    return _from_lanes(out, width, Hkv), k_pool, v_pool
+
+
+def pool_row(kv_heads: int, head_dim: int):
+    """(heads, width) of a token's k (or v) row as a pool lays it,
+    ``[L, heads, P, page, width]``: the model's KV heads of ``head_dim``,
+    but heads of 64 two to a row of 128 where they pair up, KV heads
+    ``2j`` and ``2j + 1`` side by side (module docstring: why). An odd
+    number of KV heads of 64, and every other width, lies as it is."""
+    pack = 2 if head_dim == 64 and kv_heads % 2 == 0 else 1
+    return kv_heads // pack, head_dim * pack
+
+
+def _lane_of(H: int, rows: int, pack: int) -> jax.Array:
+    """[H, pack] bool: the part of its KV head's row of the pool that
+    query head h's own KV head lies in (``rows`` rows of ``pack``)."""
+    kv_head = jnp.arange(H) // (H // (rows * pack))
+    return (kv_head % pack)[:, None] == jnp.arange(pack)
+
+
+def _to_lanes(q, k_new, v_new, rows: int, lanes: int):
+    """The walk's operands for a pool whose rows hold ``lanes // D``
+    heads side by side (``pool_row``): each query [B, H, D] in its own KV
+    head's part of a row of ``lanes`` and zeros in the rest, so that its
+    product with the row is its product with its own head's key; the new
+    k and v rows [B, Hkv, D] as the pool lays them, [B, rows, lanes].
+    Heads as wide as the pool's rows come back as they are."""
+    B, H, D = q.shape
+    pack = lanes // D
+    if pack == 1:
+        return q, k_new, v_new
+    q = jnp.where(_lane_of(H, rows, pack)[None, :, :, None], q[:, :, None],
+                  0).reshape(B, H, lanes)
+    return q, k_new.reshape(B, rows, lanes), v_new.reshape(B, rows, lanes)
+
+
+def _from_lanes(out, D: int, rows: int):
+    """Of the walk's [B, H, lanes], probabilities times whole rows, each
+    head's own part [B, H, D]."""
+    B, H, lanes = out.shape
+    pack = lanes // D
+    if pack == 1:
+        return out
+    return jnp.where(_lane_of(H, rows, pack)[None, :, :, None],
+                     out.reshape(B, H, pack, D), 0).sum(axis=2)
 
 
 def _latent_walk_kernel(pt_ref, np_ref, len_ref, layer_ref, slot_ref, at_ref,
@@ -632,7 +699,13 @@ def gather_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
     attends only to the positions where it is true
     (ops/block_attention.py)."""
     B, H, D = q.shape
-    _, Hkv, n_pool, page, _ = k_pool.shape
+    _, rows, n_pool, page, lanes = k_pool.shape
+    # A pool's row may hold several heads side by side (``pool_row``).
+    pack = lanes // D
+    Hkv = rows * pack
+    if pack > 1:
+        k_new = k_new.reshape(B, rows, lanes)
+        v_new = v_new.reshape(B, rows, lanes)
     Pmax = page_table.shape[1]
     T = Pmax * page
     last = lengths // page                     # the new row's page
@@ -652,8 +725,11 @@ def gather_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
     at = (layer, slice(None), drop, lengths % page)
     k_pool = k_pool.at[at].set(k_new.astype(k_pool.dtype), mode="drop")
     v_pool = v_pool.at[at].set(v_new.astype(v_pool.dtype), mode="drop")
-    k = jnp.take(k_pool[layer], page_table, axis=1).reshape(Hkv, B, T, D)
-    v = jnp.take(v_pool[layer], page_table, axis=1).reshape(Hkv, B, T, D)
+    k = jnp.take(k_pool[layer], page_table, axis=1).reshape(rows, B, T, lanes)
+    v = jnp.take(v_pool[layer], page_table, axis=1).reshape(rows, B, T, lanes)
+    if pack > 1:
+        k, v = (x.reshape(rows, B, T, pack, D).transpose(0, 3, 1, 2, 4)
+                .reshape(Hkv, B, T, D) for x in (k, v))
     qg = q.reshape(B, Hkv, H // Hkv, D)
     s = jnp.einsum("bhgd,hbtd->bhgt", qg, k,
                    preferred_element_type=jnp.float32) * (D ** -0.5)
@@ -670,9 +746,11 @@ def gather_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
 
 
 def pageable(page: int, head_dim: int) -> bool:
-    """Whether the kernel tiles these shapes: head_dim a multiple of the
-    128 lanes, and a page a whole number of bf16 sublane tiles (so a
-    page's copy lands on tile boundaries of the block buffer)."""
+    """Whether the kernel tiles these shapes: head_dim, the width of a
+    pool's row (``pool_row``: heads of 64 lie two to a row of 128 where
+    they pair up), a multiple of the 128 lanes, and a page a whole number
+    of bf16 sublane tiles (so a page's copy lands on tile boundaries of
+    the block buffer)."""
     return head_dim % 128 == 0 and page % 16 == 0
 
 
@@ -718,10 +796,12 @@ def _step_list(n_pages: jax.Array, step_pages: int, columns: int):
 def decode_attention_path(page: int, head_dim: int,
                           values: Optional[int] = None) -> str:
     """``"page_walk"`` or ``"gather"``: what :func:`decode_attention`
-    runs for this pool here; for a latent pool, whose rows are
-    ``head_dim`` wide and hold ``values`` of latent, ``"latent_walk"``
-    or ``"gather"``: what :func:`latent_decode_attention` runs.
-    ``LLMEngine.stats()`` reports it."""
+    runs here for a pool whose rows are ``head_dim`` wide (``pool_row``
+    says how a model's heads lie in them); for a latent pool, whose rows
+    are ``head_dim`` wide and hold ``values`` of latent,
+    ``"latent_walk"`` or ``"gather"``: what
+    :func:`latent_decode_attention` runs. ``LLMEngine.stats()`` reports
+    it."""
     from .flash_attention import _on_tpu
 
     if not (_on_tpu() and pageable(page, head_dim)):
@@ -747,9 +827,9 @@ def decode_attention(q, k_new, v_new, k_pool, v_pool, layer, page_table,
     .. lengths[b]``, or with ``window`` over the last ``window`` of
     them: (attention [B, H, D], k_pool, v_pool), by the path
     :func:`decode_attention_path` names."""
-    page, D = k_pool.shape[3:]
+    page, lanes = k_pool.shape[3:]
     path = (paged_decode_attention
-            if decode_attention_path(page, D) == "page_walk"
+            if decode_attention_path(page, lanes) == "page_walk"
             else gather_decode_attention)
     return path(q, k_new, v_new, k_pool, v_pool, layer, page_table,
                 lengths, active, window=window)

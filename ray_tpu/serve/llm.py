@@ -941,8 +941,9 @@ class LLMEngine:
         ``state_slot_bytes`` (``{kind: bytes}``: what a slot holds in one
         layer of a pool that has no pages: under "state" a retention
         layer's state and normaliser, padding and all; under "delta" a
-        delta layer's state and its convolution's history; empty for a
-        model that has neither),
+        delta layer's state and its convolution's history; under "conv"
+        a short convolution's history alone; empty for a model that has
+        none of them),
         ``queued`` (submitted, not yet admitted), beside the constants
         ``platform``, ``device_kind``, ``total_pages``, ``page_size`` and
         ``decode_attention`` (``"page_walk"``, for a latent pool
@@ -990,7 +991,13 @@ class LLMEngine:
         head's, in one layer); of a model with Lightning layers
         ``linear`` (absent otherwise): ``slot_layers`` (states stepped:
         sequences times such layers, summed over decode steps) and
-        ``slot_bytes`` (a slot's states over all of them);
+        ``slot_bytes`` (a slot's states over all of them); of a model
+        with short-convolution layers ``conv`` (absent otherwise):
+        ``slot_layers`` (histories shifted: sequences times such layers,
+        summed over decode steps), ``slot_bytes`` (a slot's histories
+        over all of them, the last ``conv_taps - 1`` rows a layer in the
+        model's dtype: all such a layer keeps of a request), ``layers``
+        (the layers that keep no pages) of ``layers_in_all``;
         ``kv_page_steps_held`` (pages the live sequences held, each
         times its pool's layers, summed over decode steps) and
         ``kv_page_steps_one_table`` (what they would have held with one
