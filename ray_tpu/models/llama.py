@@ -227,8 +227,9 @@ class LlamaConfig:
     exit_gate: bool = False
     exit_threshold: float = 1.0
     remat: bool = True
-    # "full" (save only layer inputs), "dots" (save matmul outputs,
-    # recompute elementwise), or "save_all" (save every intermediate —
+    # "full" (save only layer inputs), "dots" (save matmul outputs and
+    # the flash forward's o and lse, recompute elementwise), "mlp" (save
+    # the up and gate matmuls), or "save_all" (save every intermediate —
     # no backward recompute). "dots"/"save_all" trade HBM for less
     # backward recompute where memory allows.
     remat_policy: str = "full"
@@ -1572,11 +1573,17 @@ def forward(
 def remat_policy(cfg: LlamaConfig):
     """The jax.checkpoint policy selected by cfg.remat_policy."""
     if cfg.remat_policy == "dots":
-        # Save ALL matmul outputs — least recompute, largest
-        # footprint (OOMs the 8B-shaped bench: ~10 G HLO temp).
+        # Save ALL matmul outputs, and what stands for one: a ring
+        # matmul's result and the flash forward kernel's ``o`` and
+        # ``lse`` — least recompute, largest footprint (PERF.md
+        # section 5 has the two train cells' peaks beside the chip's
+        # 15.75 GiB).
+        from ..ops.flash_attention import FLASH_SAVED
+
         return jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.checkpoint_dots,
-            jax.checkpoint_policies.save_only_these_names(_RING_SAVED))
+            jax.checkpoint_policies.save_only_these_names(
+                _RING_SAVED, FLASH_SAVED))
     if cfg.remat_policy == "mlp":
         # Selective (scaling-playbook style): save only the two
         # widest matmuls' outputs (up/gate, ~45% of forward

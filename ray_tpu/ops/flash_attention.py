@@ -70,6 +70,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 _NEG_INF = -1e30
 _MIN_BLOCK = 128
@@ -711,6 +712,21 @@ def _flash_attention_core(q, k, v, causal, scale, q_offset, kv_offset,
     return o3.reshape(B, H, Sq, v.shape[3]).transpose(0, 2, 1, 3)
 
 
+# What the forward kernel hands the backward ones is named: a remat
+# policy that saves the name (models/llama.py's "dots") keeps ``o3`` and
+# ``lse``, ``out`` is a re-laying of the kept ``o3``, and the forward
+# kernel is dead code in the rematerialised forward. To a policy that
+# does not, a Pallas call is no dot and the kernel runs a second time.
+# Kernel, names and re-laying are ONE jitted call: ``jax.checkpoint``
+# hands a kept value that another equation of the forward reads on
+# through a ``reduce_precision`` to its own precision (against XLA's
+# excess precision), which behind a custom call is a pass of its own
+# over ``o`` (0.41 ms a layer at b8 x 2048 x 32 x 128 on a v5e;
+# PERF.md, PR 74). A call's outputs that only the backward reads get
+# none, and the kernel has rounded already.
+FLASH_SAVED = "flash_fwd_residuals"
+
+
 def _core_fwd(q, k, v, causal, scale, q_offset, kv_offset, blocks,
               interpret=False, window=None):
     if window is not None:
@@ -727,12 +743,20 @@ def _core_fwd(q, k, v, causal, scale, q_offset, kv_offset, blocks,
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     q3, k3, v3 = _to_heads3(q), _to_heads3(k), _to_heads3(v)
-    o3, lse = _forward_kernel(blocks)(
-        q3, k3, v3, heads=H, kv_heads=Hkv, scale=scale, causal=causal,
-        q_offset=q_offset, kv_offset=kv_offset,
-        block_q=blocks.fwd[0], block_k=blocks.fwd[1], interpret=interpret,
-    )
-    out = o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+
+    @jax.jit
+    def forward(q3, k3, v3):
+        o3, lse = _forward_kernel(blocks)(
+            q3, k3, v3, heads=H, kv_heads=Hkv, scale=scale, causal=causal,
+            q_offset=q_offset, kv_offset=kv_offset,
+            block_q=blocks.fwd[0], block_k=blocks.fwd[1],
+            interpret=interpret,
+        )
+        o3 = checkpoint_name(o3, FLASH_SAVED)
+        lse = checkpoint_name(lse, FLASH_SAVED)
+        return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3), o3, lse
+
+    out, o3, lse = forward(q3, k3, v3)
     return out, (q3, k3, v3, o3, lse, B, H, Hkv)
 
 

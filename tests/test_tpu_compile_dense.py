@@ -49,6 +49,38 @@ def test_paged_prefill_program_compiles_for_v5e(v5e, as_tpu, bucket, flash):
     assert ("tpu_custom_call" in compiled.as_text()) is flash
 
 
+def test_prefill_program_carries_nothing_of_the_backwards_naming(
+        v5e, as_tpu, monkeypatch):
+    """``ops/flash_attention.py:_core_fwd`` names the kernel's ``o3`` and
+    ``lse`` for the ``"dots"`` remat policy (PR 74). A serving prefill
+    differentiates nothing and runs the primal, which names nothing: the
+    512 bucket's jaxpr holds the kernel's call and no equation of that
+    name, and its compiled text is the same with the naming taken away."""
+    import importlib
+
+    from ray_tpu.models import generation
+
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    cfg = serve_cfg()
+    params, cache = serve_shapes(cfg, v5e)
+    pages = {kind: arr(v5e, (BUCKET // PAGE,), jnp.int32)
+             for kind in cache.page_table}
+    jaxpr = str(jax.make_jaxpr(
+        lambda params, cache, tokens, real_len, slot, pages:
+        generation.paged_prefill(params, tokens, real_len, cache, cfg, slot,
+                                 pages))(
+        params, cache, arr(v5e, (1, BUCKET), jnp.int32),
+        arr(v5e, (), jnp.int32), arr(v5e, (), jnp.int32), pages))
+    assert "pallas_call" in jaxpr
+    assert fa.FLASH_SAVED not in jaxpr
+    texts = []
+    for naming in (fa.checkpoint_name, lambda x, name: x):
+        monkeypatch.setattr(fa, "checkpoint_name", naming)
+        texts.append(  # one call site: the text holds its line
+            prefill_program(cfg, v5e, params, cache, BUCKET).as_text())
+    assert texts[0] == texts[1]
+
+
 # What would take a step's inputs or outputs through the host.
 _HOST_OPS = {"send", "send-done", "recv", "recv-done", "infeed", "outfeed"}
 

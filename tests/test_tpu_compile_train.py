@@ -11,6 +11,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.models import LlamaConfig  # noqa: E402
+from benchmark import trace_reduce  # noqa: E402
 from tpu_rehearsal import (  # noqa: E402
     B, HLO_INSTRUCTION, PAGES_PER_SEQ, POOL_PAGES, decode_program,
     serve_shapes)
@@ -26,21 +27,23 @@ def _dense_cfg(vocab_size=512):
     )
 
 
-def _dense_train_step(cfg, mesh):
+def _dense_train_step(cfg, mesh, sharding=None):
     """The text of ``cfg``'s train step under ``mesh``, b4 x 512 tokens,
-    traced anew."""
+    traced anew; without a mesh (the one-chip cell's own path: no
+    ``shard_map`` round the kernels) with every argument on ``sharding``."""
     from ray_tpu.train.compiled_step import CompiledTrainStep
 
     step = CompiledTrainStep(cfg, mesh=mesh, learning_rate=1e-5)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
     with jax.threefry_partitionable(True):
         state = jax.eval_shape(step._init, key)
-        shardings = step._init.lower(key).compile().output_shardings
+        shardings = (jax.tree.map(lambda _: sharding, state) if mesh is None
+                     else step._init.lower(key).compile().output_shardings)
     params, opt_state = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
         state, shardings)
-    tokens = jax.ShapeDtypeStruct((4, 513), jnp.int32,
-                                  sharding=step.token_sharding())
+    tokens = jax.ShapeDtypeStruct(
+        (4, 513), jnp.int32, sharding=step.token_sharding() or sharding)
     train = step._step.__wrapped_jit__.lower(params, opt_state, tokens)
     return train.compile().as_text()
 
@@ -232,3 +235,63 @@ def test_one_device_step_holds_no_collective(v5e_host, as_tpu):
     text = _dense_train_step(_dense_cfg(), mesh)
     assert "tpu_custom_call" in text              # the flash kernels
     assert not re.search("|".join(_MOVES_ROWS), text)
+
+
+def _flash_calls(text, rows):
+    """How often each of the three flash kernels stands in a compiled
+    step's text, ``(forward, dq, dkv)``, each known by the name the
+    benchmark's trace reader gives its custom call: what it writes, for
+    ``rows`` = batch x heads a device of 512 tokens by 128."""
+    o = f"bf16_{rows}_512_128"
+    names = [trace_reduce.stable_name(line.strip())
+             for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    counts = tuple(names.count(name) for name in (
+        f"pallas_{o}_f32_{rows}_1_512", f"pallas_{o}", f"pallas_{o}_{o}"))
+    assert sum(counts) == len(names), names
+    return counts
+
+
+@pytest.mark.parametrize("policy,devices,forwards", [
+    ("dots", 1, 2), ("dots", 4, 2), ("mlp", 1, 4), ("mlp", 4, 4),
+    ("full", 1, 4)])
+def test_the_flash_forward_runs_once_a_layer_under_dots(
+        v5e_host, as_tpu, policy, devices, forwards):
+    """Both train cells' step at tiny widths (``scan_chunk=2``: two
+    layers unrolled in each scan's body; b4 x 512, two heads of 128):
+    under ``"dots"`` the flash forward kernel's call stands once a layer
+    of the chunk, in the forward scan's body, on one described v5e device
+    as under ``fsdp=2 x tp=2`` (there inside the ``shard_map``, two rows a
+    device). The policy keeps the ``o`` and ``lse`` that
+    ``ops/flash_attention.py:_core_fwd`` names, so the backward scan's
+    body holds the two backward kernels a layer and no forward. Under
+    ``"mlp"`` and ``"full"``, which do not save the name, the backward
+    body runs the forward again: twice a layer, four in the text.
+
+    Fails on the tree before PR 74, where ``"dots"`` too reads
+    ``(4, 2, 2)``: a Pallas call is no dot."""
+    from ray_tpu.parallel import make_mesh
+
+    split = 2 if devices == 4 else 1
+    mesh = make_mesh(devices=v5e_host[:devices], dp=1, fsdp=split, tp=split)
+    text = _dense_train_step(
+        dataclasses.replace(_dense_cfg(), remat_policy=policy), mesh)
+    assert _flash_calls(text, 4 * 2 // devices) == (forwards, 2, 2)
+
+
+def test_the_kept_o_costs_no_pass_of_its_own_without_a_mesh(v5e, as_tpu):
+    """``train-mistral7b-1chip`` builds its step with no mesh, so the
+    kernels stand in the chunk itself and not in a ``shard_map``. There
+    too the forward runs once a layer under ``"dots"``, and the kept
+    ``o`` ``[8, 512, 128]`` comes out of ``_core_fwd``'s one jitted call
+    with nothing of the forward reading it: ``jax.checkpoint`` puts no
+    ``reduce_precision`` behind it, which behind a custom call is no
+    fusion's epilogue but a pass over ``o`` (1.6 ms of the cell's step,
+    PERF.md, PR 74; under a mesh the ``shard_map``'s own boundary did
+    the same).
+
+    Fails with ``out`` re-laid from the named ``o3`` outside that call:
+    two such passes, one a layer of the chunk."""
+    text = _dense_train_step(_dense_cfg(), None, v5e)
+    assert _flash_calls(text, 4 * 2) == (2, 2, 2)
+    assert not re.findall(r"bf16\[8,512,128\]\S* reduce-precision\(", text)

@@ -430,6 +430,104 @@ def test_the_ring_is_absent_where_tp_has_no_rows_to_split(axes, rows):
     assert ("sharding_constraint" in jaxpr) is (mesh is not None)
 
 
+# ------------------------------------- what "dots" keeps of the flash kernel
+
+@pytest.fixture
+def flash_interpreted(monkeypatch):
+    """``causal_attention`` takes the Pallas flash kernels, interpreted
+    (on the CPU ``flash_attention`` would take the einsum)."""
+    import importlib
+
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+
+
+def _flash_cfg(**kw):
+    """Two layers in one chunk, as the train cells' chunk of four; 128
+    tokens are one block of the kernels."""
+    return _tiny(depth=2, **{"scan_layers": True, "scan_chunk": 2,
+                             "use_flash": True, **kw})
+
+
+def _flash_tokens():
+    return jnp.asarray(np.random.RandomState(7).randint(0, 256, (2, 129)))
+
+
+@pytest.mark.parametrize("policy", ["dots", "mlp", "full"])
+@pytest.mark.parametrize("kv_heads", [2, 4], ids=["gqa", "mha"])
+def test_flash_gradients_are_the_unrematerialised_ones_bit_for_bit(
+        flash_interpreted, policy, kv_heads):
+    """What a remat policy keeps of the flash forward (``"dots"``: the
+    named ``o3`` and ``lse``) or makes again (``"mlp"``, ``"full"``: the
+    kernel runs a second time) is the same numbers: the loss and every
+    gradient leaf of a two-layer chunk equal those of ``remat=False``,
+    where nothing is made twice, to the bit."""
+    cfg = _flash_cfg(remat_policy=policy, num_kv_heads=kv_heads)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tokens = _flash_tokens()
+    loss, grads = _loss_and_grads(cfg, params, tokens)
+    want_loss, want = _loss_and_grads(
+        dataclasses.replace(cfg, remat=False), params, tokens)
+    assert float(loss) == float(want_loss)
+    jax.tree.map(np.testing.assert_array_equal, grads, want)
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["chunk", "unrolled"])
+def test_dots_keeps_the_flash_forwards_o_and_lse_and_no_second_out(
+        flash_interpreted, scan):
+    """The residuals ``jax.checkpoint`` keeps under ``"dots"``, a layer:
+    the kernel's ``o3`` ``[B*H, S, D]`` in the model's dtype and ``lse``
+    ``[B*H, 1, S]`` float32, which ``ops/flash_attention.py:_core_fwd``
+    names, and of ``out``'s shape ``[B, S, H, D]`` q alone: ``out`` is a
+    re-laying of the kept ``o3``, not a residual of its own. Under
+    ``"mlp"`` neither is kept (the backward runs the kernel again).
+    The chunked scan stacks a layer's residuals behind one more axis and
+    says only "output of scan"; the unrolled loop says where each is made.
+
+    Fails on the tree before PR 74: ``"dots"`` keeps neither."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from ray_tpu.models.llama import hidden_forward
+
+    cfg = _flash_cfg(remat_policy="dots", scan_layers=scan)
+    B, S, H, D = 2, 128, cfg.num_heads, cfg.dh
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tokens = _flash_tokens()[:, :S]
+
+    def kept(cfg):
+        found = saved_residuals(
+            lambda p: hidden_forward(p, tokens, cfg)[0].sum(), params)
+        lead = (1,) if cfg.scan_layers else ()
+        return [(aval.shape[len(lead):], aval.dtype, why)
+                for aval, why in found if aval.shape[:len(lead)] == lead]
+
+    def count(found, shape):
+        return sum(1 for got, dtype, _ in found
+                   if got == shape and dtype == jnp.float32)
+
+    found = kept(cfg)
+    layers = cfg.num_layers
+    assert count(found, (B * H, S, D)) == layers          # o3
+    assert count(found, (B * H, 1, S)) == layers          # lse
+    assert count(found, (B, S, H, D)) == layers           # q, and no out
+    if not scan:
+        # Both come out of ``_core_fwd``'s one jitted call, which also
+        # re-lays ``out``: no other equation of the forward reads them,
+        # so jax hands neither on through a ``reduce_precision`` (a pass
+        # of its own behind a custom call: 1.6 ms of the one-chip cell's
+        # step when ``out`` was made from ``o3`` outside the call).
+        from_kernel = [(got, why) for got, _, why in found
+                       if "flash_attention.py" in why]
+        assert sorted(got for got, _ in from_kernel) == sorted(
+            [(B * H, S, D), (B * H, 1, S)] * layers)
+        assert all("jitted function 'forward'" in why
+                   for _, why in from_kernel), from_kernel
+    other = kept(dataclasses.replace(cfg, remat_policy="mlp"))
+    assert count(other, (B * H, S, D)) == 0
+    assert count(other, (B * H, 1, S)) == 0
+
+
 # ------------------------------------------------------- HBM probe
 
 def test_fragmentation_from_stats_preference_order():
